@@ -3,9 +3,8 @@
 // The serving loop's host stage (runtime/engine.py::_start_batch) does
 // latest-wins dedup by tx_id, key folding, µs-epoch splitting, cents→f32
 // amounts, and the single-array packing of core/batch.py::pack_batch.
-// The NumPy pipeline for that runs ~3.2M rows/s on one core — fine over a
-// remote tunnel (the wire is slower), but the bottleneck for a locally
-// attached chip whose projected loop rate is >3.5M rows/s. This unit is
+// The NumPy pipeline for that runs ~3.2M rows/s on one CPU core, which a
+// locally attached chip can outrun. This unit is
 // the same math as the NumPy path, one pass each, allocation-free:
 //
 //   latest_wins_keep — reference ROW_NUMBER() PARTITION BY tx_id ORDER BY

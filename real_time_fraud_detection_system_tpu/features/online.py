@@ -28,6 +28,7 @@ from real_time_fraud_detection_system_tpu.ops.cms import (
     cms_update,
 )
 from real_time_fraud_detection_system_tpu.ops.hashing import slot_of
+from real_time_fraud_detection_system_tpu.ops.numerics import div_ieee
 from real_time_fraud_detection_system_tpu.ops.keydir import (
     EMPTY_KEY,
     KeyDirectory,
@@ -299,8 +300,11 @@ def update_and_featurize(
     t_count, _, t_fraud = query_windows(
         terminal, term_slot, batch.day, windows, delay=cfg.delay_days
     )
-    c_avg = jnp.where(c_count > 0, c_amount / jnp.maximum(c_count, 1.0), 0.0)
-    t_risk = jnp.where(t_count > 0, t_fraud / jnp.maximum(t_count, 1.0), 0.0)
+    # div_ieee: averages bit-equal to NumPy's and to the fused kernels'
+    c_avg = jnp.where(
+        c_count > 0, div_ieee(c_amount, jnp.maximum(c_count, 1.0)), 0.0)
+    t_risk = jnp.where(
+        t_count > 0, div_ieee(t_fraud, jnp.maximum(t_count, 1.0)), 0.0)
 
     is_weekend, is_night = _flags(batch, cfg)
     features = _assemble(batch, cfg, c_count, c_avg, t_count, t_risk,
@@ -372,8 +376,11 @@ def update_and_featurize_exact(
     t_count = jnp.where(t_adm[:, None], tc_t, tc_s)
     t_fraud = jnp.where(t_adm[:, None], tf_t, tf_s)
 
-    c_avg = jnp.where(c_count > 0, c_amount / jnp.maximum(c_count, 1.0), 0.0)
-    t_risk = jnp.where(t_count > 0, t_fraud / jnp.maximum(t_count, 1.0), 0.0)
+    # div_ieee: averages bit-equal to NumPy's and to the fused kernels'
+    c_avg = jnp.where(
+        c_count > 0, div_ieee(c_amount, jnp.maximum(c_count, 1.0)), 0.0)
+    t_risk = jnp.where(
+        t_count > 0, div_ieee(t_fraud, jnp.maximum(t_count, 1.0)), 0.0)
     is_weekend, is_night = _flags(batch, cfg)
     features = _assemble(batch, cfg, c_count, c_avg, t_count, t_risk,
                          is_weekend, is_night)

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from real_time_fraud_detection_system_tpu.ops.numerics import sum_fixed_order
+
 
 class TreeEnsemble(NamedTuple):
     """Flat node tables, padded to (T trees × N nodes). Leaves self-loop."""
@@ -261,7 +263,7 @@ def resolve_z_mode(mode: str | None) -> str:
     float mode CPU XLA lowers natively). Every mode is decision-exact by
     the contract documented on :func:`gemm_leaf_sum`; int8 is
     additionally BIT-identical to f32 (integer z arithmetic, same
-    onehot, same f32-HIGHEST leaf contraction)."""
+    leaf match, same pinned-order leaf sum)."""
     if mode is None or mode == "auto":
         return "int8" if jax.default_backend() == "tpu" else "f32"
     if mode not in ("f32", "bf16", "int8"):
@@ -294,15 +296,43 @@ def gemm_leaf_sum(
           (no BF16×BF16→F32 dot thunk there, so ``"bf16"`` silently
           degrades to f32 off-TPU — same values by construction).
           CPU default.
-    - the leaf gather keeps leaf_val in f32 (probabilities are not
-      bf16-exact; onehot is 0/1 so f32 HIGHEST here is exact and cheap —
-      L ≪ I·L work).
+    - the leaf gather is a select, not a contraction: each tree's one
+      matching leaf value is picked exactly (f32, no MXU pass), and the
+      T per-tree values are added in a pinned order — the result does
+      not depend on how a compiler tiles a reduction, so one chip and
+      the mesh agree to the bit on equal features.
     """
-    hi = jax.lax.Precision.HIGHEST
     if z_mode is None:
         z_mode = "bf16" if jax.default_backend() == "tpu" else "f32"
     if z_mode not in ("bf16", "int8", "f32"):
         raise ValueError(f"unknown z_mode {z_mode!r}")
+    b = x.shape[0]
+    if b <= LEAF_SLAB_ROWS:
+        return _leaf_sum_slab(g, x, z_mode)
+    pad = -b % LEAF_SLAB_ROWS
+    if pad:
+        x = jnp.concatenate([x, jnp.zeros((pad, x.shape[1]), x.dtype)])
+    slabs = x.reshape(-1, LEAF_SLAB_ROWS, x.shape[1])
+    return jax.lax.map(lambda xs: _leaf_sum_slab(g, xs, z_mode),
+                       slabs).reshape(-1)[:b]
+
+
+# Rows per pass of the three contractions. The [B, T, L] match tensor is
+# never materialized, but the v5e compiler (libtpu 0.0.34) addresses it:
+# once its f32 extent passed 2^31 bytes — 32,768 rows at T=100, L=256 —
+# the fused [B, T, L] → [B, T] reduce returned wrong per-tree values for
+# EVERY row (chip_smoke.py's oracle check: probabilities off by up to
+# 0.73; a scratch run on the chip: right at 16,384 rows, wrong from
+# 32,768, and right again over 8,192-row slabs at any batch size). No
+# compile test and no CPU test can see that. Slabs also bound the step's
+# temporaries, and measured 20% faster than one pass at 65,536 rows
+# (19.2 ms vs 24.1 ms, same scratch run, PR 21).
+LEAF_SLAB_ROWS = 8192
+
+
+def _leaf_sum_slab(g: GemmEnsemble, x: jnp.ndarray, z_mode: str):
+    """:func:`gemm_leaf_sum` for one slab of at most ``LEAF_SLAB_ROWS``."""
+    hi = jax.lax.Precision.HIGHEST
     proj = jnp.einsum("bf,tfi->bti", x, g.sel, precision=hi)
     if z_mode == "int8":
         d = (proj <= g.thresh[None]).astype(jnp.int8)
@@ -310,7 +340,7 @@ def gemm_leaf_sum(
             "bti,til->btl", d, g.path.astype(jnp.int8),
             preferred_element_type=jnp.int32,
         )
-        onehot = (z == g.target.astype(jnp.int32)[None]).astype(jnp.float32)
+        match = z == g.target.astype(jnp.int32)[None]
     else:
         on_tpu = jax.default_backend() == "tpu"
         zdt = jnp.bfloat16 if (z_mode == "bf16" and on_tpu) else jnp.float32
@@ -319,8 +349,13 @@ def gemm_leaf_sum(
             "bti,til->btl", d, g.path.astype(zdt),
             preferred_element_type=jnp.float32,
         )
-        onehot = (jnp.abs(z - g.target[None]) < 0.5).astype(jnp.float32)
-    return jnp.einsum("btl,tl->b", onehot, g.leaf_val, precision=hi)
+        match = jnp.abs(z - g.target[None]) < 0.5
+    # Exactly one leaf per tree matches, so the sum over L adds zeros to
+    # one value: exact in any order. The sum over T is the only rounding
+    # step, and its order is pinned (sum_fixed_order), so two programs
+    # that see bit-equal features emit bit-equal probabilities.
+    per_tree = jnp.sum(jnp.where(match, g.leaf_val[None], 0.0), axis=2)
+    return sum_fixed_order(per_tree, axis=1)
 
 
 def gemm_predict_proba(

@@ -13,6 +13,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from real_time_fraud_detection_system_tpu.ops.numerics import div_ieee
+
 
 class Scaler(NamedTuple):
     mean: jnp.ndarray  # float32 [F]
@@ -31,7 +33,9 @@ def fit_scaler(x: np.ndarray) -> Scaler:
 
 
 def transform(scaler: Scaler, x: jnp.ndarray) -> jnp.ndarray:
-    return (x - scaler.mean) / scaler.scale
+    # div_ieee: the chip's own f32 divide is 1 ulp off sklearn's in ~32%
+    # of values, enough to flip a tree's vote on a threshold-sitting row
+    return div_ieee(x - scaler.mean, scaler.scale)
 
 
 def inverse_transform(scaler: Scaler, x: jnp.ndarray) -> jnp.ndarray:

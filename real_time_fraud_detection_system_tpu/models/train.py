@@ -151,7 +151,23 @@ class TrainedModel:
             object.__setattr__(self, "_dev_cache", dev)
         return dev
 
+    # Rows per device call of :meth:`predict_proba`. The GEMM-form tree
+    # contraction materializes [B, T, I] / [B, T, L] f32 intermediates
+    # (100 KB per row at T=100, depth 8): the whole 170k-row test split in
+    # one call asked a 16 GB v5e for a 6.84 GB buffer on top of what it
+    # held and was refused (PR 21's first chip run). 8,192 rows keep a
+    # call under 1 GB per intermediate on any backend.
+    PREDICT_BLOCK_ROWS = 8192
+
     def predict_proba(self, features: np.ndarray) -> np.ndarray:
+        n = int(np.shape(features)[0])
+        blk = self.PREDICT_BLOCK_ROWS
+        if n <= blk:
+            return self._predict_block(features)
+        return np.concatenate([self._predict_block(features[i:i + blk])
+                               for i in range(0, n, blk)])
+
+    def _predict_block(self, features: np.ndarray) -> np.ndarray:
         import jax.numpy as jnp
 
         x = transform(self.scaler, jnp.asarray(features, dtype=jnp.float32))

@@ -33,12 +33,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from real_time_fraud_detection_system_tpu.ops.numerics import (
+    div_ieee,
+    sum_fixed_order,
+)
+
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    """The default for ``interpret``: compiled on the chip, interpreted
+    everywhere else. No fallback — a backend that cannot be asked is an
+    error, not a reason to interpret."""
+    return jax.default_backend() == "tpu"
 
 
 def assemble_features(
@@ -72,15 +77,18 @@ def assemble_features(
     for w in windows:
         sel = jnp.where(live_c & (age_c < w), 1.0, 0.0)
         cnt = jnp.sum(c_cnt * sel, axis=1, keepdims=True)
-        amt = jnp.sum(c_amt * sel, axis=1, keepdims=True)
+        # dollars: the order is pinned, as in query_gathered
+        amt = sum_fixed_order(c_amt * sel, axis=1, keepdims=True)
         cols.append(cnt)
-        cols.append(jnp.where(cnt > 0, amt / jnp.maximum(cnt, 1.0), 0.0))
+        cols.append(jnp.where(
+            cnt > 0, div_ieee(amt, jnp.maximum(cnt, 1.0)), 0.0))
     for w in windows:
         sel = jnp.where(live_t & (age_t < w), 1.0, 0.0)
         cnt = jnp.sum(t_cnt * sel, axis=1, keepdims=True)
         frd = jnp.sum(t_frd * sel, axis=1, keepdims=True)
         cols.append(cnt)
-        cols.append(jnp.where(cnt > 0, frd / jnp.maximum(cnt, 1.0), 0.0))
+        cols.append(jnp.where(
+            cnt > 0, div_ieee(frd, jnp.maximum(cnt, 1.0)), 0.0))
     return jnp.concatenate(cols, axis=1)  # [Bt, F]
 
 
@@ -121,7 +129,7 @@ def _score_kernel(
     scale = pvec_ref[1:2, :]
     w_row = pvec_ref[2:3, :]
     bias = pvec_ref[3:4, 0:1]
-    x = (feats - mean) / scale
+    x = div_ieee(feats - mean, scale)
     z = jnp.sum(x * w_row, axis=1, keepdims=True) + bias
     probs_ref[:] = jax.nn.sigmoid(z) * valid
 
@@ -141,10 +149,16 @@ def fused_featurize_score(
     delay: int = 7,
     weekend_start: int = 5,
     night_end: int = 6,
-    block_rows: int = 1024,
+    block_rows: int = 512,
     interpret: bool | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (probs [B], features [B, F]); batch tiled over a 1-D grid."""
+    """Returns (probs [B], features [B, F]); batch tiled over a 1-D grid.
+
+    ``block_rows=512``: every row operand is lane-padded to 128 in VMEM,
+    and at 1024-row tiles the v5e compiler counted 16.45 MB of scoped VMEM
+    against its 16 MB limit at 65,536 rows (the same arithmetic as
+    ``pallas_forest.FUSED_BLOCK_ROWS``; ``tests/test_tpu_compile.py``
+    asks the compiler)."""
     c_bd, c_cnt, c_amt = c_rows
     t_bd, t_cnt, t_frd = t_rows
     bsz, nb = c_bd.shape

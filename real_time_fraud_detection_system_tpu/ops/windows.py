@@ -31,6 +31,8 @@ from typing import NamedTuple, Sequence, Tuple
 
 import jax.numpy as jnp
 
+from real_time_fraud_detection_system_tpu.ops.numerics import sum_fixed_order
+
 
 class WindowState(NamedTuple):
     """Ring-buffer day aggregates for one key space (pytree of [cap, NB])."""
@@ -159,8 +161,11 @@ def query_gathered(
     out_c, out_a, out_f = [], [], []
     for w in windows:
         sel = (live & (age < w)).astype(jnp.float32)
+        # counts and fraud labels are integers: exact in any order. The
+        # dollar amounts are not, so their order is pinned — the fused
+        # kernels (assemble_features) add the same tree.
         out_c.append(jnp.sum(count * sel, axis=1))
-        out_a.append(jnp.sum(amount * sel, axis=1))
+        out_a.append(sum_fixed_order(amount * sel, axis=1))
         out_f.append(jnp.sum(fraud * sel, axis=1))
     return (
         jnp.stack(out_c, axis=1),

@@ -32,19 +32,11 @@ from real_time_fraud_detection_system_tpu.features.online import FeatureState
 
 
 def compat_shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions, replication checks off (our
-    specs declare replication explicitly; the checker predates several
-    of the collectives used here)."""
-    try:
-        from jax import shard_map as _sm  # jax >= 0.8
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    """``jax.shard_map`` with the replication checker off (our specs
+    declare replication explicitly; the checker predates several of the
+    collectives used here)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(n_devices: int = 0, axis: str = "data") -> Mesh:

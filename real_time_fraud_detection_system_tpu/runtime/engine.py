@@ -83,10 +83,12 @@ from real_time_fraud_detection_system_tpu.utils.xla_telemetry import (
 PHASES = ("source_poll", "host_prep", "dispatch", "result_wait",
           "sink_write")
 
-# One double-buffered Pallas tree block must sit well inside ~16MB VMEM
-# next to the row tile and [Bt, 128·k] intermediates (ops/pallas_forest).
-# Decided at TRACE time from the live params' static shapes, so a
-# checkpoint restore that swaps in a deeper ensemble retraces into the
+# Budget for ONE double-buffered Pallas tree block — the ensemble-
+# dependent VMEM term (ops/pallas_forest.admit_block). It bounds the
+# tables only; that the whole kernel (row tiles, intermediates) fits the
+# chip's ~16MB scoped VMEM is what tests/test_tpu_compile.py asks the
+# compiler. Decided at TRACE time from the live params' static shapes, so
+# a checkpoint restore that swaps in a deeper ensemble retraces into the
 # XLA composition instead of a VMEM-overflowing kernel.
 _PALLAS_BLOCK_BUDGET = 4 * 2 ** 20
 
@@ -396,6 +398,7 @@ class ScoringEngine:
                 raise ValueError(
                     "emit_threshold has no effect for kind='sequence' "
                     "(no feature matrix leaves the device); keep 0")
+            self._announce_pallas(params)
             self._init_sequence(cfg, params, scaler, feature_state,
                                 feature_cache)
             return
@@ -472,45 +475,14 @@ class ScoringEngine:
         fcfg = cfg.features
         z_mode = self.z_mode
 
-        # Both FUSED featurize→score kernels read gathered hot-tier rows
-        # directly and know nothing of the sketch fallback, so the
-        # tiered exact mode keeps the XLA composition (the pure predict
-        # swap in _maybe_use_pallas_forest still applies — it consumes
-        # the already-assembled feature matrix).
-        use_pallas = (
-            cfg.runtime.use_pallas
-            and kind == "logreg"
-            and cfg.features.customer_source == "table"
-            and not self._exact
-        )
-        # Fused featurize→score forest step (ops/pallas_forest.py): the
-        # round-9 kernel that keeps the feature block VMEM-resident past
-        # the scatter boundary. Gated like the logreg fused kernel (table
-        # source — the CMS query has its own sketch layout) plus, at
-        # TRACE time inside the step, on GEMM-form params whose tables
-        # fit the VMEM block budget — so a hot reload to an oversized or
-        # descent-form ensemble retraces into the XLA composition.
-        use_pallas_forest = (
-            cfg.runtime.use_pallas
-            and kind in ("tree", "forest")
-            and cfg.features.customer_source == "table"
-            and self.scorer != "cpu"
-            and not self._exact
-        )
-        if use_pallas_forest:
-            from real_time_fraud_detection_system_tpu.models.forest import (
-                GemmEnsemble,
-            )
-            from real_time_fraud_detection_system_tpu.ops.pallas_forest \
-                import admit_block, to_pallas
-        self._maybe_use_pallas_forest(kind, params)
-
-        def _fused_forest_fits(p) -> bool:
-            # trace-time gate (static shapes only — see use_pallas_forest);
-            # admit_block is the SAME predicate rtfdsverify proves, so the
-            # served gate and the verified budget cannot drift
-            return (use_pallas_forest and isinstance(p, GemmEnsemble)
-                    and admit_block(p, z_mode, _PALLAS_BLOCK_BUDGET).fits)
+        # What --use-pallas serves is decided in ONE place,
+        # _pallas_choice(params). The step below and the predict swap
+        # (_maybe_use_pallas_forest) take its answer through
+        # _announce_pallas at TRACE time, so the gauge / WARNING are
+        # written by the code that makes the choice — here at build, on
+        # every params swap, and whenever the step retraces.
+        self._maybe_use_pallas_forest(kind)
+        self._announce_pallas(params)
 
         exact = self._exact
 
@@ -527,14 +499,24 @@ class ScoringEngine:
             # the unpack is free bitcasts inside the fused program.
             batch = unpack_batch(packed)
             tier = None
-            if use_pallas:
+            # `kernel` is a trace-time fact: _pallas_choice reads the
+            # config and params' pytree FORM and static shapes, never a
+            # traced value. A retrace when a reload or an in-place
+            # restore changes the form is intended, and the gate reports
+            # its own choice as it makes it.
+            kernel = self._announce_pallas(params)
+            # rtfdslint: disable=jit-recompile-hazard (kernel is a str computed from static facts only — config, isinstance on the params pytree, admit_block over static .shape; no traced VALUE is branched on)
+            if kernel == "fused_logreg":
                 fstate, probs, feats = update_and_score_pallas(
                     fstate, batch, fcfg, scaler.mean, scaler.scale,
                     params.w, params.b,
                 )
                 x = transform(scaler, feats)
-            # rtfdslint: disable=jit-recompile-hazard (trace-time gate on STATIC facts only: isinstance on the params pytree structure + pallas_block_bytes over params' static .shape tuple — no traced VALUE is branched on, and a retrace when a hot reload changes the params FORM is the intended XLA-fallback behavior, same contract as _maybe_use_pallas_forest)
-            elif _fused_forest_fits(params):
+            # rtfdslint: disable=jit-recompile-hazard (same static str as the branch above)
+            elif kernel == "fused_forest":
+                from real_time_fraud_detection_system_tpu.ops.pallas_forest \
+                    import to_pallas
+
                 pf = to_pallas(params, z_mode)
                 fstate, leaf, feats = update_and_score_pallas_forest(
                     fstate, batch, fcfg, scaler.mean, scaler.scale, pf,
@@ -712,8 +694,10 @@ class ScoringEngine:
             g.set(1.0 if m == self.z_mode else 0.0)
         self._m_use_pallas = reg.gauge(
             "rtfds_use_pallas",
-            "1 when the opt-in fused Pallas scoring path is enabled")
-        self._m_use_pallas.set(1.0 if self.cfg.runtime.use_pallas else 0.0)
+            "1 when a Pallas kernel is in the SERVED step (fused "
+            "featurize-score or classify-only); 0 when use_pallas is off "
+            "or the engine serves the XLA composition instead")
+        self._pallas_kernel = None  # set by _announce_pallas
         self._m_reloads = {
             o: reg.counter(
                 "rtfds_model_reloads_total",
@@ -1381,6 +1365,7 @@ class ScoringEngine:
         """Hot-reload hook: keep AOT serving only while the swapped-in
         params match the precompiled shape family; otherwise drop the
         cache (fall back to jit, which retraces — slower, correct)."""
+        self._announce_pallas(params)
         if not self._aot:
             return params
         params = jax.tree.map(jnp.asarray, params)
@@ -1543,51 +1528,127 @@ class ScoringEngine:
             self._m_fetch_overlap.inc(
                 max(0.0, time.perf_counter() - ti))
 
-    def _maybe_use_pallas_forest(self, kind: str, params) -> None:
-        """Swap the tree-ensemble scorer for the fused Pallas kernel.
+    # The single-chip step can take the fused featurize→score kernels;
+    # the mesh step (ShardedScoringEngine) only takes the predict swap.
+    _FUSED_STEP = True
 
-        Gated on ``RuntimeConfig.use_pallas``, GEMM-form params, and the
-        padded tables fitting comfortably inside VMEM
-        (``ops/pallas_forest.py``). A pure predict swap: engine state (and
-        checkpoints) keep the ``GemmEnsemble``, and the padded kernel
-        tables are re-derived from the LIVE params inside the jitted step
-        (µs of pad writes) — so a checkpoint restore that overwrites
-        ``state.params`` in place is served, never a stale build-time copy.
-        """
-        if not self.cfg.runtime.use_pallas or self.scorer == "cpu":
-            return
-        if kind not in ("tree", "forest", "gbt"):
-            return  # keep the pallas import lazy for non-ensemble kinds
+    def _pallas_choice(self, params):
+        """THE gate on ``use_pallas`` → (kernel, refusal).
+
+        ``kernel`` names the Pallas kernel the step serves for ``params``
+        (``"fused_logreg"``, ``"fused_forest"``, ``"forest_classify"``)
+        or is None for the XLA composition; ``refusal`` is why the
+        asked-for (fused) path is NOT what is served, or None. Static
+        facts only (config, the params pytree's form and shapes), so it
+        answers the same for live arrays and for tracers: the jitted step
+        and the swapped predict branch on it at trace time, and
+        :meth:`_announce_pallas` reports it — one predicate, so the
+        served kernel and the reported one cannot drift."""
+        kind = self.kind
+        if not self.cfg.runtime.use_pallas:
+            return None, None
+        if kind not in ("logreg", "tree", "forest", "gbt"):
+            return None, f"kind={kind!r} has no Pallas kernel"
+        # Both fused featurize→score kernels read gathered hot-tier rows
+        # and know nothing of a sketch: CMS sources and the tiered exact
+        # store keep the XLA featurize (the classify swap still applies).
+        fc = self.cfg.features
+        no_fused = None
+        if not self._FUSED_STEP:
+            no_fused = "the sharded step has no fused featurize kernel"
+        elif fc.customer_source != "table":
+            no_fused = (f"customer_source={fc.customer_source!r} has its "
+                        "own sketch layout")
+        elif fc.key_mode == "exact":
+            no_fused = ("key_mode='exact' serves admission misses from the "
+                        "sketch tier, which the fused kernels do not read")
+        if kind == "logreg":
+            return (None, no_fused) if no_fused else ("fused_logreg", None)
+        if self.scorer == "cpu":
+            return None, "scorer=cpu classifies on the host"
         from real_time_fraud_detection_system_tpu.models.forest import (
             GemmEnsemble,
         )
-        from real_time_fraud_detection_system_tpu.models.gbt import GBTModel
         from real_time_fraud_detection_system_tpu.ops.pallas_forest import (
             admit_block,
+        )
+
+        trees = getattr(params, "trees", params)
+        # rtfdslint: disable=jit-recompile-hazard (called at trace time by design: isinstance on the params pytree's FORM, not a traced value)
+        if not isinstance(trees, GemmEnsemble):
+            return None, ("the ensemble is in descent form (too deep for "
+                          "the GEMM tables)")
+        adm = admit_block(trees, self.z_mode, _PALLAS_BLOCK_BUDGET)
+        # rtfdslint: disable=jit-recompile-hazard (admit_block reads static .shape tuples only — the same predicate rtfdsverify proves)
+        if not adm.fits:
+            return None, (
+                f"admit_block refused the tree tables (block "
+                f"{adm.block_bytes} B, budget {adm.budget} B, "
+                f"aligned={adm.tiles_aligned})")
+        if kind == "gbt" or no_fused:  # the predict swap is what is left
+            return "forest_classify", None if kind == "gbt" else no_fused
+        return "fused_forest", None
+
+    def _announce_pallas(self, params) -> Optional[str]:
+        """Ask :meth:`_pallas_choice` and report its answer → the kernel.
+
+        Every consumer of the choice comes through here (engine build,
+        params swap, the step and the swapped predict at trace time), so
+        the gauge carries the served fact, never the config flag, and an
+        asked-for kernel the engine does not serve is said at WARNING with
+        the reason — once per change of answer, not once per caller."""
+        choice = self._pallas_choice(params)
+        # rtfdslint: disable=jit-recompile-hazard (choice is a pair of str/None computed from static facts — see _pallas_choice; reached at trace time by design)
+        if choice == getattr(self, "_pallas_said", None):
+            return choice[0]
+        self._pallas_said = choice
+        self._pallas_kernel, refusal = choice
+        self._m_use_pallas.set(1.0 if self._pallas_kernel else 0.0)
+        # rtfdslint: disable=jit-recompile-hazard (refusal is a str or None, never a traced value)
+        if refusal:
+            from real_time_fraud_detection_system_tpu.utils import (
+                get_logger,
+            )
+
+            get_logger("engine").warning(
+                "use_pallas was asked but %s: %s",
+                "only the classify kernel is served (featurize stays in "
+                "XLA)" if self._pallas_kernel else
+                "the step serves the XLA composition", refusal)
+        return self._pallas_kernel
+
+    def _maybe_use_pallas_forest(self, kind: str) -> None:
+        """Swap the tree-ensemble scorer for the Pallas classify kernel
+        wherever :meth:`_pallas_choice` says ``"forest_classify"``.
+
+        A pure predict swap: engine state (and checkpoints) keep the
+        ``GemmEnsemble``, and the padded kernel tables are re-derived from
+        the LIVE params inside the jitted step (µs of pad writes) — so a
+        checkpoint restore that overwrites ``state.params`` in place is
+        served, never a stale build-time copy.
+        """
+        if not self.cfg.runtime.use_pallas or kind not in (
+                "tree", "forest", "gbt"):
+            return  # keep the pallas import lazy for non-ensemble kinds
+        from real_time_fraud_detection_system_tpu.ops.pallas_forest import (
             pallas_leaf_sum,
             pallas_predict_proba,
             to_pallas,
         )
 
-        budget = _PALLAS_BLOCK_BUDGET
         xla_predict = self._predict
         z_mode = self.z_mode
 
-        if kind in ("tree", "forest") and isinstance(params, GemmEnsemble):
-            def _pred(p, x):
-                if admit_block(p, z_mode, budget).fits:
-                    return pallas_predict_proba(to_pallas(p, z_mode), x)
+        def _pred(p, x):
+            if self._announce_pallas(p) != "forest_classify":
                 return xla_predict(p, x)
-            self._predict = _pred
-        elif (kind == "gbt" and isinstance(params, GBTModel)
-                and isinstance(params.trees, GemmEnsemble)):
-            def _pred(p, x):
-                if admit_block(p.trees, z_mode, budget).fits:
-                    return jax.nn.sigmoid(
-                        p.base_score
-                        + pallas_leaf_sum(to_pallas(p.trees, z_mode), x))
-                return xla_predict(p, x)
-            self._predict = _pred
+            if kind == "gbt":
+                return jax.nn.sigmoid(
+                    p.base_score
+                    + pallas_leaf_sum(to_pallas(p.trees, z_mode), x))
+            return pallas_predict_proba(to_pallas(p, z_mode), x)
+
+        self._predict = _pred
 
     def _init_sequence(self, cfg, params, scaler, feature_state,
                        feature_cache):
@@ -2091,8 +2152,8 @@ class ScoringEngine:
         ``device_put`` and dispatched while batch N's device step still
         runs — H2D and dispatch overhead overlap compute (SURVEY §2.3
         item 3; depth 2 is classic double-buffering, deeper depths keep
-        the device fed when per-dispatch overhead such as a remote-tunnel
-        RTT exceeds step compute). ``runtime.coalesce_rows`` further
+        the device fed when per-dispatch overhead exceeds step
+        compute). ``runtime.coalesce_rows`` further
         merges consecutive polls into one device batch. The pipeline
         drains to depth 0 before every checkpoint save, so a saved
         (offsets, state) pair never includes an in-flight batch's effects

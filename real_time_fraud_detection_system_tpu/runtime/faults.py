@@ -189,7 +189,7 @@ class Heartbeat:
 class HangingSource:
     """Wraps a source; scripted poll indices HANG (block silently) instead
     of raising — the failure mode retries can't see and only a watchdog
-    catches (a dead TPU tunnel, a wedged Kafka client, a stuck NFS read).
+    catches (a wedged Kafka client, a stuck NFS read).
 
     Each scripted hang fires once: the incarnation that hit it stays
     blocked (until ``release`` or ``max_hang_s``), and the restarted

@@ -87,6 +87,8 @@ class ShardedScoringEngine(ScoringEngine):
     ceil(max_shard_load / rows_per_shard) sub-steps.
     """
 
+    _FUSED_STEP = False  # the mesh step takes the predict swap only
+
     def __init__(
         self,
         cfg: Config,

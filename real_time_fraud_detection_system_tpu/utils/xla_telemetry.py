@@ -57,18 +57,13 @@ _compile_count = 0
 
 def install_compile_telemetry() -> bool:
     """Register the ``jax.monitoring`` listener (idempotent; one per
-    process). Returns True when the listener is active, False when jax
-    (or its monitoring API) is unavailable in this process."""
+    process). Returns True once the listener is active."""
     global _installed
     with _install_lock:
         if _installed:
             return True
-        try:
-            import jax.monitoring as monitoring
-        except (ImportError, AttributeError, RuntimeError):
-            # RuntimeError: mismatched jax/jaxlib raises at import time —
-            # telemetry answers "unavailable", it never crashes the host
-            return False
+        import jax.monitoring as monitoring
+
         reg = get_registry()
         m_compiles = reg.counter(
             "rtfds_xla_compiles_total",
@@ -246,16 +241,9 @@ class DeviceMemoryTelemetry:
         if self._dead:
             return
         if self._devices is None:
-            try:
-                import jax
+            import jax
 
-                self._devices = jax.local_devices()
-            except (ImportError, RuntimeError, AttributeError):
-                # AttributeError: partially-broken jax (import succeeds,
-                # local_devices missing) — this runs per batch on the
-                # loop thread, so telemetry self-disables, never crashes
-                self._dead = True
-                return
+            self._devices = jax.local_devices()
         any_stats = False
         for i, d in enumerate(self._devices):
             try:

@@ -371,7 +371,7 @@ def _drain_zombies(release, timeout_s: float = 15.0):
 def test_watchdog_recovers_from_silent_hang(small_dataset, tmp_path):
     """A source that HANGS (never raises) must be detected by the stall
     watchdog and recovered via restart — the round-2 gap: a Heartbeat
-    nobody watched meant a wedged tunnel stalled the engine forever.
+    nobody watched meant a wedged source stalled the engine forever.
 
     The stall budget must exceed worst-case step latency (a restarted
     incarnation re-traces its jitted step, seconds on CPU) or slow

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from real_time_fraud_detection_system_tpu.ops import (
     cms_init,
@@ -224,3 +225,79 @@ def test_pack_unpack_batch_bitexact():
         assert np.asarray(c).dtype == np.asarray(a).dtype, name
         np.testing.assert_array_equal(np.asarray(a), np.asarray(c),
                                       err_msg=name)
+
+
+def test_div_ieee_equals_numpy_division_bit_for_bit():
+    """On the CPU the plain quotient is already correctly rounded and the
+    correction adds nothing; on the chip (chip_smoke.py's oracle and
+    kernels phases) it is what makes the quotient equal NumPy's."""
+    import jax.numpy as jnp
+
+    from real_time_fraud_detection_system_tpu.ops.numerics import div_ieee
+
+    rng = np.random.default_rng(0)
+    a = (rng.normal(size=(4096, 15)) * rng.choice(
+        [1e-3, 1.0, 1e3, 1e6], size=(4096, 15))).astype(np.float32)
+    b = rng.uniform(1e-3, 3e3, size=(15,)).astype(np.float32)
+    got = np.asarray(div_ieee(jnp.asarray(a), jnp.asarray(b)))
+    assert np.array_equal(got, a / b)
+    # integer-valued operands (window counts, fraud counts): exact too
+    cnt = rng.integers(1, 500, size=(4096, 1)).astype(np.float32)
+    frd = rng.integers(0, 500, size=(4096, 1)).astype(np.float32)
+    assert np.array_equal(
+        np.asarray(div_ieee(jnp.asarray(frd), jnp.asarray(cnt))), frd / cnt)
+
+
+def test_div_ieee_keeps_the_meaning_of_nonfinite_quotients():
+    """x/0 stays ±inf and 0/0 stays NaN: the nan-guard's quarantine (a
+    degenerate scaler column) keys on exactly that."""
+    import jax.numpy as jnp
+
+    from real_time_fraud_detection_system_tpu.ops.numerics import div_ieee
+
+    a = jnp.asarray([1.0, -2.0, 0.0, np.inf, 3.0], jnp.float32)
+    b = jnp.asarray([0.0, 0.0, 0.0, 2.0, np.inf], jnp.float32)
+    got = np.asarray(div_ieee(a, b))
+    assert got[0] == np.inf and got[1] == -np.inf
+    assert np.isnan(got[2])
+    assert got[3] == np.inf and got[4] == 0.0
+
+
+@pytest.mark.parametrize("n", [1, 2, 5, 40, 64, 100])
+def test_sum_fixed_order_is_the_written_tree(n):
+    """`sum_fixed_order` is an explicit balanced tree of adds over the
+    zero-padded axis — the same bits as that tree written out in NumPy,
+    under jit or not, for any axis, and exact for integer-valued data."""
+    import jax
+
+    from real_time_fraud_detection_system_tpu.ops.numerics import (
+        sum_fixed_order,
+    )
+
+    rng = np.random.default_rng(n)
+    x = (rng.normal(size=(7, n)) * 1e3).astype(np.float32)
+
+    def written(a):
+        p = 1 << max(n - 1, 0).bit_length()
+        a = np.concatenate(
+            [a, np.zeros((a.shape[0], p - n), np.float32)], axis=1)
+        while p > 1:
+            p //= 2
+            a = a[:, :p] + a[:, p:]
+        return a[:, 0]
+
+    want = written(x)
+    assert np.array_equal(np.asarray(sum_fixed_order(jnp.asarray(x), 1)),
+                          want)
+    assert np.array_equal(
+        np.asarray(jax.jit(lambda a: sum_fixed_order(a, axis=0))(
+            jnp.asarray(x.T))), want)
+    kept = sum_fixed_order(jnp.asarray(x), axis=1, keepdims=True)
+    assert kept.shape == (7, 1) and np.array_equal(
+        np.asarray(kept)[:, 0], want)
+    np.testing.assert_allclose(want, x.astype(np.float64).sum(axis=1),
+                               rtol=1e-5)
+    counts = rng.integers(0, 1000, size=(7, n)).astype(np.float32)
+    assert np.array_equal(
+        np.asarray(sum_fixed_order(jnp.asarray(counts), 1)),
+        counts.sum(axis=1))

@@ -1,0 +1,245 @@
+"""Ask the installed TPU compiler — no chip needed — whether the serving
+programs of the main path compile for a v5e at the real sizes.
+
+The sandbox has no accelerator, but libtpu can compile for a chip that is
+DESCRIBED (``v5e:2x2``) and not attached. Interpret-mode parity tests
+cannot see what this sees: a kernel whose VMEM demand the chip's compiler
+refuses, a program that does not fit HBM, a step that cannot be
+partitioned. (PR 21 found one: the fused forest kernel was refused at
+65,536 rows with 1024-row tiles.) A compile that passes here is not a
+chip run — ``chip_smoke.py`` is.
+
+Rules this file keeps (one process may hold libtpu; xdist workers each
+import every test file): the topology is described inside a
+module-scoped fixture, never at import / in ``skipif`` / in
+``parametrize`` arguments; everything built from it is built in a fixture
+or a test; the compile runs in the test's own process; the persistent
+compilation cache is off around the compiles (an entry written for a
+described chip cannot be read back without one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+CUSTOMER_SLOTS = 1 << 20
+TERMINAL_SLOTS = 1 << 21
+N_TREES, DEPTH, N_FEAT = 100, 8, 15
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    # whatever libtpu raises when it cannot describe a chip here
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def as_on_chip(monkeypatch):
+    """The repo's own backend checks (``resolve_z_mode``, the kernels'
+    ``interpret`` default, the bf16 z dtype) see the CPU here; steer them
+    in the test — not through a new option — to the branch the chip
+    takes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _on(sharding, tree):
+    """Shape-only templates of ``tree`` placed by ``sharding``."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _fcfg():
+    from real_time_fraud_detection_system_tpu.config import FeatureConfig
+
+    return FeatureConfig(customer_capacity=CUSTOMER_SLOTS,
+                         terminal_capacity=TERMINAL_SLOTS)
+
+
+def _state_shapes(fcfg, **kw):
+    from real_time_fraud_detection_system_tpu.features.online import (
+        init_feature_state,
+    )
+
+    return jax.eval_shape(lambda: init_feature_state(fcfg, **kw))
+
+
+def _forest():
+    """The flagship ensemble's shapes: T=100, depth 8, 15 features."""
+    from real_time_fraud_detection_system_tpu.models.forest import (
+        synthetic_ensemble,
+        to_gemm,
+    )
+
+    return to_gemm(synthetic_ensemble(N_TREES, DEPTH, N_FEAT), N_FEAT)
+
+
+def _vec(sharding):
+    return jax.ShapeDtypeStruct((N_FEAT,), jnp.float32, sharding=sharding)
+
+
+def _packed(rows, sharding):
+    return jax.ShapeDtypeStruct((7, rows), jnp.int32, sharding=sharding)
+
+
+@pytest.mark.parametrize("bucket", [256, 4096, 65536])
+def test_xla_forest_step_compiles(topo, one_chip, as_on_chip, bucket):
+    """The DEFAULT served step (`rtfds score`, no flags) of the engine
+    itself, at 2^20 + 2^21 state slots and the on-chip z_mode."""
+    from real_time_fraud_detection_system_tpu.config import (
+        Config,
+        RuntimeConfig,
+    )
+    from real_time_fraud_detection_system_tpu.models.forest import (
+        resolve_z_mode,
+    )
+    from real_time_fraud_detection_system_tpu.models.scaler import Scaler
+    from real_time_fraud_detection_system_tpu.runtime import ScoringEngine
+
+    assert resolve_z_mode("auto") == "int8"  # what the chip resolves
+    fcfg = _fcfg()
+    cfg = Config(features=fcfg, runtime=RuntimeConfig(
+        z_mode="int8", batch_buckets=(bucket,), max_batch_rows=bucket))
+    eng = ScoringEngine(
+        cfg, kind="forest", params=_forest(),
+        scaler=Scaler(mean=np.zeros(N_FEAT, np.float32),
+                      scale=np.ones(N_FEAT, np.float32)),
+        # shapes only: nothing is allocated
+        feature_state=_on(one_chip, _state_shapes(fcfg)))
+    (sig,) = eng.dispatch_inventory()
+    assert sig.bucket == bucket and sig.z_mode == "int8"
+    compiled = eng.signature_step(sig).lower(
+        *_on(one_chip, eng.signature_templates(sig))).compile()
+    mem = compiled.memory_analysis()
+    # the state alone is ~1.9 GB of arguments; all of it fits one chip
+    assert mem.argument_size_in_bytes > 1.8e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16e9
+    assert "tpu_custom_call" not in compiled.as_text()  # pure XLA
+
+
+@pytest.mark.parametrize("z_mode", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("bucket", [4096, 65536])
+def test_fused_forest_step_compiles(topo, one_chip, as_on_chip, bucket,
+                                    z_mode):
+    """``fused_forest_leaf_sum`` via ``update_and_score_pallas_forest`` —
+    refused by the v5e compiler at 65,536 rows before PR 21 (16.23 MB of
+    scoped VMEM against a 16 MB limit, 1024-row tiles)."""
+    from real_time_fraud_detection_system_tpu.core.batch import unpack_batch
+    from real_time_fraud_detection_system_tpu.features.online import (
+        update_and_score_pallas_forest,
+    )
+    from real_time_fraud_detection_system_tpu.ops.pallas_forest import (
+        to_pallas,
+    )
+
+    fcfg = _fcfg()
+
+    def step(fstate, g, mean, scale, packed):
+        pf = to_pallas(g, z_mode)
+        fstate, leaf, feats = update_and_score_pallas_forest(
+            fstate, unpack_batch(packed), fcfg, mean, scale, pf)
+        return fstate, leaf / pf.n_trees, feats
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        _on(one_chip, _state_shapes(fcfg)), _on(one_chip, _forest()),
+        _vec(one_chip), _vec(one_chip), _packed(bucket, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_leaf_sum_compiles(topo, one_chip, as_on_chip):
+    """The classify-only kernel (the predict swap `--use-pallas` serves
+    in exact/CMS/sharded modes) at the largest default bucket."""
+    from real_time_fraud_detection_system_tpu.ops.pallas_forest import (
+        pallas_leaf_sum,
+        to_pallas,
+    )
+
+    x = jax.ShapeDtypeStruct((65536, N_FEAT), jnp.float32,
+                             sharding=one_chip)
+    compiled = jax.jit(
+        lambda g, x: pallas_leaf_sum(to_pallas(g, "int8"), x)).lower(
+            _on(one_chip, _forest()), x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_logreg_step_compiles(topo, one_chip, as_on_chip):
+    from real_time_fraud_detection_system_tpu.core.batch import unpack_batch
+    from real_time_fraud_detection_system_tpu.features.online import (
+        update_and_score_pallas,
+    )
+
+    fcfg = _fcfg()
+
+    def step(fstate, mean, scale, w, b, packed):
+        return update_and_score_pallas(
+            fstate, unpack_batch(packed), fcfg, mean, scale, w, b)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        _on(one_chip, _state_shapes(fcfg)), _vec(one_chip), _vec(one_chip),
+        _vec(one_chip),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip),
+        _packed(65536, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
+    """What `score --devices 4` serves for a 65,536-row micro-batch: the
+    shard_map step over a four-device mesh (chunk = 4 × 2 × 16,384 rows),
+    with the terminal exchange's two all_to_alls and a quarter of the
+    state on each device."""
+    from real_time_fraud_detection_system_tpu.config import Config
+    from real_time_fraud_detection_system_tpu.models.scaler import Scaler
+    from real_time_fraud_detection_system_tpu.parallel.step import (
+        make_sharded_step,
+    )
+    from real_time_fraud_detection_system_tpu.runtime.engine import (
+        predict_fn_for,
+    )
+
+    n_dev = 4
+    mesh = Mesh(np.asarray(topo.devices[:n_dev]), ("data",))
+    fcfg = _fcfg()
+    cfg = Config(features=fcfg)
+    rows_per_shard = 2 * (cfg.runtime.max_batch_rows // n_dev)
+    fstate = _state_shapes(fcfg)
+    rows = NamedSharding(mesh, P("data", None))
+    rep = NamedSharding(mesh, P())
+    fstate = fstate._replace(customer=_on(rows, fstate.customer),
+                             terminal=_on(rows, fstate.terminal))
+    params, scaler = _on(rep, _forest()), Scaler(mean=_vec(rep),
+                                                 scale=_vec(rep))
+    packed = _packed(n_dev * rows_per_shard,
+                     NamedSharding(mesh, P(None, "data")))
+    build = make_sharded_step(cfg, predict_fn_for("forest", z_mode="int8"),
+                              mesh=mesh, axis="data", packed=True)
+    compiled = build(fstate, params, scaler, packed).lower(
+        fstate, params, scaler, packed).compile()
+    text = compiled.as_text()
+    assert text.count("all-to-all") >= 2
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert 0.2 * 1.9e9 < per_device < 0.3 * 2.1e9  # ~a quarter of the state

@@ -488,34 +488,101 @@ def test_log_level_env_honored(monkeypatch):
         monkeypatch.setattr(ulog, "_configured", True)
 
 
-def test_compilation_cache_failure_is_logged(monkeypatch):
+@pytest.fixture()
+def cache_config():
+    """Restore jax's cache settings after a test of the cache rule."""
+    import jax
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    was = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in was.items():
+        jax.config.update(n, v)
+
+
+def _recorded_updates(monkeypatch):
+    import jax
+
+    calls = []
+    real = jax.config.update
+
+    def update(name, value):
+        calls.append(name)
+        real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", update)
+    return calls
+
+
+def test_compilation_cache_env_dir_wins_and_nothing_is_set_in_code(
+        monkeypatch, cache_config, tmp_path):
+    from real_time_fraud_detection_system_tpu.utils.tracing import (
+        enable_compilation_cache,
+    )
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    calls = _recorded_updates(monkeypatch)
+    enable_compilation_cache()
+    assert "jax_compilation_cache_dir" not in calls
+    assert calls  # the threshold is still lowered
+
+
+def test_compilation_cache_default_is_one_fixed_path_in_the_checkout(
+        monkeypatch, cache_config, tmp_path):
+    """Unset: ``<checkout>/.jax_cache`` — the same for two calls and for
+    two processes started from different directories (the directory is
+    part of the cache key: one that moves never hits)."""
+    import subprocess
+    import sys
+
+    from real_time_fraud_detection_system_tpu.utils.tracing import (
+        enable_compilation_cache,
+    )
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first, second = enable_compilation_cache(), enable_compilation_cache()
+    assert first == second == os.path.join(repo, ".jax_cache")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    code = ("from real_time_fraud_detection_system_tpu.utils import "
+            "enable_compilation_cache as e; print(e())")
+    seen = set()
+    for cwd in (str(tmp_path), repo):
+        r = subprocess.run([sys.executable, "-c", code], cwd=cwd, env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-500:]
+        seen.add(r.stdout.strip().splitlines()[-1])
+    assert seen == {first}
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_compilation_cache_threshold_admits_the_step_programs(
+        monkeypatch, cache_config):
+    """The forest step compiles for a v5e in 2-6 s and the small buckets
+    in well under a second: the minimum-compile-time floor sits below
+    them (and above zero, so one-op eager programs stay out), and a cache
+    that cannot be enabled raises (no broad except)."""
     import jax
 
     from real_time_fraud_detection_system_tpu.utils.tracing import (
         enable_compilation_cache,
     )
 
-    seen = []
-    handler = logging.Handler()
-    handler.emit = lambda record: seen.append(record)
-    log = logging.getLogger("rtfds.tracing")
-    log.addHandler(handler)
-    try:
-        def boom(*a, **k):
-            raise RuntimeError("no such config")
+    enable_compilation_cache()
+    # step programs (0.3-6 s) are written, one-op eager programs are not
+    assert 0 < jax.config.jax_persistent_cache_min_compile_time_secs <= 0.25
 
-        monkeypatch.setattr(jax.config, "update", boom)
-        enable_compilation_cache("/tmp/rtfds-cache-test")
-    finally:
-        log.removeHandler(handler)
-    assert seen, "cache-enable failure must be logged, not swallowed"
-    assert seen[0].levelno == logging.WARNING
-    assert "compilation cache" in seen[0].getMessage()
+    def boom(*a, **k):
+        raise RuntimeError("no such config")
 
+    with monkeypatch.context() as m, pytest.raises(RuntimeError):
+        m.setattr(jax.config, "update", boom)
+        enable_compilation_cache()
 
-# ---------------------------------------------------------------------------
-# CLI: rtfds trace subcommand
-# ---------------------------------------------------------------------------
 
 def test_cli_trace_subcommand(tmp_path, capsys):
     from real_time_fraud_detection_system_tpu import cli

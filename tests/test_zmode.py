@@ -3,7 +3,7 @@
 ``gemm_leaf_sum``'s dominant z contraction is exact in EVERY reduced-
 precision mode (d is 0/1, path is ±1/0, z counts ≤ depth), and the int8
 mode is additionally BIT-identical to f32: integer z arithmetic, the same
-onehot, the same f32-HIGHEST proj and leaf contractions. These tests pin
+leaf match, the same f32-HIGHEST proj and pinned-order leaf sum. These tests pin
 that contract across every configured batch-bucket size — including
 threshold-edge inputs — and re-assert the engine-level AOT≡jit parity
 with ``z_mode="int8"`` forced, so the serving default flip on TPU
@@ -62,6 +62,31 @@ def test_int8_bit_identical_to_f32_every_bucket(gemm_forest, rows):
     # the exact contraction: BIT identity, not tolerance
     assert float(np.abs(p_i8 - p_f32).max()) == 0.0
     assert np.array_equal(p_i8 >= 0.5, p_f32 >= 0.5)
+
+
+@pytest.mark.parametrize("z_mode", ["f32", "int8"])
+def test_leaf_sum_row_slabs_equal_one_pass(gemm_forest, z_mode):
+    """Past LEAF_SLAB_ROWS the three contractions run over row slabs (the
+    v5e compiler returned wrong per-tree values for one pass at 32,768+
+    rows): every row's sum is bit-equal to scoring that row in a small
+    batch, ragged tail included."""
+    from real_time_fraud_detection_system_tpu.models.forest import (
+        LEAF_SLAB_ROWS,
+        gemm_leaf_sum,
+    )
+
+    g = gemm_forest
+    rows = 2 * LEAF_SLAB_ROWS + 37
+    rng = np.random.default_rng(9)
+    x = rng.normal(size=(rows, N_FEAT)).astype(np.float32)
+    x[:64] = _edge_rows(g, rng, 64)
+    whole = np.asarray(gemm_leaf_sum(g, jnp.asarray(x), z_mode))
+    assert whole.shape == (rows,)
+    step = LEAF_SLAB_ROWS // 2 + 1  # chunks that never line up with slabs
+    parts = np.concatenate([
+        np.asarray(gemm_leaf_sum(g, jnp.asarray(x[i:i + step]), z_mode))
+        for i in range(0, rows, step)])
+    assert np.array_equal(whole, parts)
 
 
 def test_bf16_decision_identical_every_bucket(gemm_forest):
@@ -225,3 +250,119 @@ def test_run_stats_and_gauges_surface_z_mode(tree_params):
     # /healthz device_plane block reads the gauges
     _, body = MetricsServer(registry=reg).health()
     assert body["device_plane"] == {"z_mode": "int8", "use_pallas": False}
+
+
+@pytest.mark.parametrize("kind,key_mode,served,warns", [
+    ("logreg", "exact", None, True),              # XLA composition
+    ("forest", "exact", "forest_classify", True),  # predict swap only
+    ("forest", "direct", "fused_forest", False),   # what was asked
+])
+def test_use_pallas_reports_what_is_served(tree_params, kind, key_mode,
+                                           served, warns):
+    """`--use-pallas` falling back to XLA says so once at WARNING with
+    the reason, and rtfds_use_pallas reports the SERVED step, not the
+    config flag."""
+    import logging
+
+    from real_time_fraud_detection_system_tpu.models.logreg import (
+        init_logreg,
+    )
+    from real_time_fraud_detection_system_tpu.runtime import ScoringEngine
+    from real_time_fraud_detection_system_tpu.utils.metrics import (
+        MetricsRegistry,
+    )
+
+    base = _forest_cfg("f32")
+    cfg = base.replace(
+        features=dataclasses.replace(base.features, key_mode=key_mode),
+        runtime=dataclasses.replace(base.runtime, use_pallas=True))
+    seen = []
+    handler = logging.Handler()
+    handler.emit = seen.append
+    log = logging.getLogger("rtfds.engine")
+    log.addHandler(handler)
+    reg = MetricsRegistry()
+    try:
+        eng = ScoringEngine(
+            cfg, kind=kind,
+            params=tree_params if kind == "forest" else init_logreg(N_FEAT),
+            scaler=Scaler(mean=jnp.zeros(N_FEAT), scale=jnp.ones(N_FEAT)),
+            metrics=reg)
+    finally:
+        log.removeHandler(handler)
+    warnings = [r for r in seen if r.levelno == logging.WARNING
+                and "use_pallas was asked" in r.getMessage()]
+    assert len(warnings) == (1 if warns else 0)
+    if warns:
+        assert "key_mode='exact'" in warnings[0].getMessage()
+    assert eng._pallas_kernel == served
+    assert reg.get("rtfds_use_pallas").value == (1.0 if served else 0.0)
+
+
+def test_use_pallas_reload_reannounces(tree_params):
+    """What the gate serves is re-announced whenever it changes. A hot
+    reload (`_note_params_swap`) to descent-form trees, which no Pallas
+    kernel takes, is said like a build-time refusal: one WARNING, gauge
+    to 0, `_pallas_kernel` cleared, and the retraced step really is the
+    XLA composition's answer. An in-place restore (`state.params = ...`,
+    what the checkpointer does) has no hook: there the step's own
+    trace-time gate reports the change."""
+    import logging
+
+    from real_time_fraud_detection_system_tpu.models.forest import (
+        ensemble_predict_proba,
+    )
+    from real_time_fraud_detection_system_tpu.runtime import ScoringEngine
+    from real_time_fraud_detection_system_tpu.utils.metrics import (
+        MetricsRegistry,
+    )
+
+    base = _forest_cfg("f32")
+    cfg = base.replace(
+        runtime=dataclasses.replace(base.runtime, use_pallas=True))
+    reg = MetricsRegistry()
+    eng = ScoringEngine(
+        cfg, kind="forest", params=tree_params,
+        scaler=Scaler(mean=jnp.zeros(N_FEAT), scale=jnp.ones(N_FEAT)),
+        metrics=reg)
+    gemm = eng.state.params
+    assert eng._pallas_kernel == "fused_forest"
+    assert reg.get("rtfds_use_pallas").value == 1.0
+    eng.process_batch(_cols(np.random.default_rng(1), 60))
+
+    seen = []
+    handler = logging.Handler()
+    handler.emit = seen.append
+    log = logging.getLogger("rtfds.engine")
+    log.addHandler(handler)
+
+    def said():
+        return [r.getMessage() for r in seen if r.levelno == logging.WARNING
+                and "use_pallas was asked" in r.getMessage()]
+
+    try:
+        # in place, as a restore does: no hook runs, so the step's own
+        # trace-time gate is what reports it (the raw node tables are
+        # what a reload of a too-deep ensemble holds)
+        eng.state.params = tree_params
+        assert reg.get("rtfds_use_pallas").value == 1.0
+        res = eng.process_batch(_cols(np.random.default_rng(2), 60, at=60))
+        assert len(said()) == 1 and "descent form" in said()[0]
+        assert eng._pallas_kernel is None
+        assert reg.get("rtfds_use_pallas").value == 0.0
+        want = np.asarray(ensemble_predict_proba(
+            tree_params, jnp.asarray(res.features)))
+        np.testing.assert_allclose(res.probs, want, rtol=1e-6, atol=1e-7)
+
+        # through the reload hook: said at the swap, before any batch
+        eng.state.params = eng._note_params_swap(gemm)
+        assert eng._pallas_kernel == "fused_forest"
+        assert reg.get("rtfds_use_pallas").value == 1.0
+        assert len(said()) == 1  # serving what was asked: nothing to say
+        eng.state.params = eng._note_params_swap(tree_params)
+        eng.state.params = eng._note_params_swap(tree_params)  # no repeat
+        assert len(said()) == 2
+        assert eng._pallas_kernel is None
+        assert reg.get("rtfds_use_pallas").value == 0.0
+    finally:
+        log.removeHandler(handler)

@@ -1,7 +1,6 @@
 """Elastic-fleet spike absorption: autoscaled fleet vs fixed control.
 
-ROADMAP item 4's proof shape (``bench.py`` records it as
-``detail.elastic_absorb``): drive a 10x ingest spike (a replay backlog
+ROADMAP item 4's proof shape: drive a 10x ingest spike (a replay backlog
 ten times the overload ladder's lag high-water mark) into
 
 - an ELASTIC fleet: ``tools/multihost_launcher.py --autoscale`` starts
@@ -26,9 +25,14 @@ worker registry dumps, the launcher's own metric snapshot):
   arms: fleet rows_total == stream rows).
 
 Exactness across the resize is pinned in ``tests/test_elastic_smoke.py``;
-this bench measures absorption. Prints ONE JSON line. Run standalone
-(``python tools/elastic_absorb_bench.py [--quick]``) or let ``bench.py``
-spawn it.
+this bench measures absorption.
+
+Fleets of OS processes have run on the CPU backend only (workers are pinned
+to ``JAX_PLATFORMS=cpu``); they are **not run on chip** — a chip belongs to
+one process, and giving each worker its own is future work.
+
+Prints ONE JSON line. Run by hand:
+``python tools/elastic_absorb_bench.py [--quick]``.
 """
 
 from __future__ import annotations

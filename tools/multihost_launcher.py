@@ -31,6 +31,10 @@ Prints ONE JSON line: per-worker stats (parsed from each worker's own
 stats line) plus fleet totals. Exit 0 iff every worker of the final
 generation exited 0.
 
+Fleets of OS processes have run on the CPU backend only (workers are pinned
+to ``JAX_PLATFORMS=cpu``); they are **not run on chip** — a chip belongs to
+one process, and giving each worker its own is future work.
+
 Usage::
 
     python tools/multihost_launcher.py --processes 2 -- \\

@@ -1,7 +1,6 @@
 """Multi-host scaling matrix: real OS processes × one shared stream.
 
-ROADMAP item 1's proof shape (``bench.py`` records it as
-``detail.multihost_scaling``): launch 1, 2 and 4 REAL serving processes
+ROADMAP item 1's proof shape: launch 1, 2 and 4 REAL serving processes
 (``tools/multihost_launcher.py`` → ``rtfds score`` workers with
 ``jax.distributed`` coordination where the backend allows it) over one
 co-partitioned synthetic stream, under ``--precompile``, and show the
@@ -22,9 +21,12 @@ speedup — does not happen:
 Bit-identity multi ≡ single-process is pinned in
 ``tests/test_multihost_smoke.py``; this matrix measures scaling.
 
-Prints ONE JSON line. Run standalone
-(``python tools/multihost_scaling_bench.py [--quick]``) or let
-``bench.py`` spawn it.
+Fleets of OS processes have run on the CPU backend only (workers are pinned
+to ``JAX_PLATFORMS=cpu``); they are **not run on chip** — a chip belongs to
+one process, and giving each worker its own is future work.
+
+Prints ONE JSON line. Run by hand:
+``python tools/multihost_scaling_bench.py [--quick]``.
 """
 
 from __future__ import annotations

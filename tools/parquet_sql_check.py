@@ -226,8 +226,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     sys.exit(main())

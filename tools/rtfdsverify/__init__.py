@@ -17,7 +17,8 @@ step ever executes, no weights are needed. Per signature it proves:
   (``rtfds_xla_recompiles_total`` stays the backstop);
 * **zmode-exactness** — the PR-9 arithmetic-exactness contract as a
   checked theorem: integer z arithmetic survives in the int8 path,
-  decision/leaf contractions stay f32 pinned to HIGHEST, and no
+  every float contraction (the decision projection) stays f32 pinned
+  to HIGHEST, and no
   laundered downcast (f32→bf16/f16) enters the scoring program;
 * **donation-safety** — the nan-guard's donation-off dance and the
   donate-only-the-feature-state rule, cross-checked against what the

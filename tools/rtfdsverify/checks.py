@@ -129,8 +129,9 @@ class ZModeExactnessCheck:
 
     name = "zmode-exactness"
     doc = ("int8/bf16 z arithmetic stays exact by construction: integer "
-           "z contraction survives, decision/leaf contractions stay "
-           "f32-HIGHEST, and no laundered downcast enters the scoring "
+           "z contraction survives, every float contraction (the decision "
+           "projection; the leaf pick is a select) stays f32-HIGHEST, and "
+           "no laundered downcast enters the scoring "
            "program")
 
     #: dots whose operands are provably tiny integers (bool-derived
@@ -323,9 +324,11 @@ class PallasAdmissionCheck:
     pallas_call is present iff admitted)."""
 
     name = "pallas-admission"
-    doc = ("pallas_block_bytes ≤ VMEM budget and MXU tile alignment "
-           "hold statically for every use_pallas signature, and the "
-           "traced program agrees with the admission verdict")
+    doc = ("the tree-block TABLE bytes fit their VMEM budget and the "
+           "padded layout tiles the MXU for every use_pallas signature, "
+           "and the traced program agrees with the admission verdict "
+           "(row tiles are not bounded here: the chip compiler's answer "
+           "in tests/test_tpu_compile.py is that proof)")
 
     def run(self, target: VerifyTarget, inventory, traced
             ) -> Iterable[Finding]:

@@ -18,8 +18,10 @@ Prints ONE JSON line:
      "single_chip_rows_per_s": ...,
      "by_devices": {"1": ..., "2": ..., "4": ..., "8": ...}}
 
-Run standalone (``python tools/sharded_scaling_bench.py [--quick]``) or
-let ``bench.py`` spawn it (recorded under ``detail.sharded_scaling``).
+This tool pins itself to the virtual CPU mesh: its figures are CPU
+wall-clock, never device rates, and it is **not run on chip** (the
+four-chip path is ``python chip_smoke.py --chips 4``). Run by hand:
+``python tools/sharded_scaling_bench.py [--quick]``.
 """
 
 from __future__ import annotations

@@ -1,7 +1,6 @@
 """Sharded tiered-feature-store scale matrix: shards × key universe.
 
-The sharded half of ROADMAP item 2's proof shape (``bench.py`` records
-it as ``detail.sharded_state_scale``): drive the SHARDED exact engine
+The sharded half of ROADMAP item 2's proof shape: drive the SHARDED exact engine
 (per-shard key directories + sketch replicas, ``key_mode="exact"``)
 over a Zipf-skewed stream while the key universe grows 64k → 1M → 10M
 with the hot tier FIXED, at 2 and 4 virtual devices, under
@@ -20,9 +19,11 @@ with the hot tier FIXED, at 2 and 4 virtual devices, under
 All widths run on the same host cores (virtual CPU mesh), so the claim
 is flat rows/s per width across universes — not wall-clock speedup.
 
-Prints ONE JSON line. Run standalone
-(``python tools/sharded_state_scale_bench.py [--quick]``) or let
-``bench.py`` spawn it.
+This tool pins itself to the virtual CPU mesh: its figures are CPU
+wall-clock, never device rates, and it is **not run on chip**.
+
+Prints ONE JSON line. Run by hand:
+``python tools/sharded_state_scale_bench.py [--quick]``.
 """
 
 from __future__ import annotations
