@@ -1,0 +1,12 @@
+"""The benchmark's own tests run on the CPU: `python -m pytest
+benchmark/tests -q` from the root of the repo. They are not part of the
+repo's tier-1 suite."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)

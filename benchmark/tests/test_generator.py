@@ -1,0 +1,113 @@
+"""The traffic generator: seeded, real envelopes, open-loop polls."""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+from benchmark import harness
+from benchmark.generators import debezium_cards as gen
+
+ROOT = harness.ROOT
+
+
+def _files():
+    over = harness.load_json(os.path.join(
+        ROOT, "benchmark", "tests", "data", "toy_overrides.json"))
+    config = harness.merge(harness.load_json(os.path.join(
+        ROOT, "benchmark", "configs", "forest-rf100-d8.json")),
+        over["config"])
+    traffic = harness.merge(harness.load_json(os.path.join(
+        ROOT, "benchmark", "traffic", "steady-0.8.json")), over["traffic"])
+    return config, traffic
+
+
+def _decode(msgs, ts):
+    from real_time_fraud_detection_system_tpu.core.envelope import (
+        decode_transaction_envelopes,
+    )
+
+    return decode_transaction_envelopes(msgs, ts)
+
+
+def test_same_seed_same_schedule_other_seed_another():
+    config, traffic = _files()
+    a = gen.build(traffic, config, 5_000_000_007, 2.0, _decode)
+    b = gen.build(traffic, config, 5_000_000_007, 2.0, _decode)
+    c = gen.build(traffic, config, 5_000_000_008, 2.0, _decode)
+    for k in ("fill_customer", "fill_terminal", "fill_cents", "fill_us",
+              "win_customer", "win_terminal", "win_cents", "due_s"):
+        assert np.array_equal(getattr(a, k), getattr(b, k)), k
+        assert not np.array_equal(getattr(a, k), getattr(c, k)), k
+    # the same sizes and (nearly) the same number of arrivals for any seed
+    assert len(a.fill_us) == len(c.fill_us)
+    assert abs(len(a.due_s) - len(c.due_s)) < 0.05 * len(a.due_s)
+    assert a.fill_customer.max() < config["key_universe"]["customers"]
+    assert a.win_terminal.max() < config["key_universe"]["terminals"]
+
+
+def test_envelopes_are_the_programs_wire_format():
+    """The program's own decoder reads the benchmark's envelopes back to the
+    values encoded, and they have the program's encoder's width."""
+    from real_time_fraud_detection_system_tpu.core.envelope import (
+        encode_transaction_envelopes,
+    )
+
+    rng = np.random.default_rng(3)
+    n = 200
+    cols = (np.arange(n, dtype=np.int64),
+            1_749_636_000_000_000 + rng.integers(0, 10**9, n),
+            rng.integers(0, 1 << 22, n), rng.integers(0, 1 << 23, n),
+            rng.integers(1, 10**6, n))
+    mine = gen.encode_envelopes(*cols)
+    assert mine == encode_transaction_envelopes(*cols)
+    got, invalid = _decode(mine, (cols[1] // 1000).tolist())
+    assert not invalid.any()
+    for k, v in zip(("tx_id", "tx_datetime_us", "customer_id", "terminal_id",
+                     "tx_amount_cents"), cols):
+        assert np.array_equal(got[k], v), k
+
+
+def test_open_loop_poll_takes_what_is_due_and_serves_the_rest_after_close():
+    config, traffic = _files()
+    t = gen.build(traffic, config, 11, 0.05, _decode)
+    fill = t.fill_source()
+    first = fill.poll_batch()
+    assert len(first["tx_id"]) == t.fill_batch_rows
+    assert (first["tx_datetime_us"] // gen.US_PER_DAY).max() \
+        == t.start_us // gen.US_PER_DAY - t.fill_batches
+    fired = []
+    w = t.window_source([(0.0, lambda: fired.append("open"))])
+    served = []
+    while (cols := w.poll_batch()) is not None:
+        if cols:
+            served.append(cols)
+    assert fired == ["open"]
+    ids = np.concatenate([c["tx_id"] for c in served])
+    # every row due inside the window is served exactly once, in order
+    assert np.array_equal(ids, t.n_fill + np.arange(len(t.due_s)))
+    assert t.rows_due() == len(t.due_s) == w.rows_polled
+    # no row is served before it is due
+    for (rel, s, n) in w.polls:
+        if rel < t.seconds:
+            assert t.due_s[s + n - 1] <= rel
+    look = t.lookup(ids)
+    assert np.array_equal(look["tx_datetime_us"],
+                          np.concatenate([c["tx_datetime_us"]
+                                          for c in served]))
+    q = t.queue_stats()
+    assert q["poll_lag_p50_ms"] >= 0.0 and q["backlog_rows_end"] >= 0.0
+
+
+def test_late_rows_are_refused_not_ignored():
+    config, traffic = _files()
+    with pytest.raises(ValueError):
+        gen.build(dict(traffic, late_share=0.02), config, 1, 1.0, _decode)
+
+
+def test_steady_rate_is_a_number_in_its_file():
+    traffic = json.load(open(os.path.join(
+        ROOT, "benchmark", "traffic", "steady-0.8.json")))
+    assert traffic["rate_rows_per_s"] > 0
+    assert traffic["rate_rows_per_s"] % 10_000 == 0
