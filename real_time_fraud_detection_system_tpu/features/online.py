@@ -43,6 +43,7 @@ from real_time_fraud_detection_system_tpu.ops.windows import (
     query_windows,
     update_windows,
 )
+from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
 
 class FeatureState(NamedTuple):
@@ -200,32 +201,37 @@ def _update_state_exact(
     row (they shadow the full stream), so a key's sketch estimate stays
     a valid overestimate whether or not it currently holds a hot slot.
     """
-    fraud = jnp.maximum(batch.label, 0).astype(jnp.float32)
+    with step_scope("terminal"):
+        fraud = jnp.maximum(batch.label, 0).astype(jnp.float32)
     probes = cfg.keydir_probes
     if cfg.customer_source == "cms":
         customer, customer_dir = state.customer, None
         cust_slot = jnp.zeros_like(batch.day)
         c_adm = jnp.zeros_like(batch.valid)
     else:
-        customer_dir, cust_slot, c_adm = admit_slots(
-            state.customer_dir, batch.customer_key, batch.valid,
+        with step_scope("customer"):
+            customer_dir, cust_slot, c_adm = admit_slots(
+                state.customer_dir, batch.customer_key, batch.valid,
+                n_probes=probes)
+            customer = update_windows(
+                state.customer, cust_slot, batch.day, batch.amount, fraud,
+                batch.valid & c_adm, track_fraud=False,
+            )
+    with step_scope("terminal"):
+        terminal_dir, term_slot, t_adm = admit_slots(
+            state.terminal_dir, batch.terminal_key, batch.valid,
             n_probes=probes)
-        customer = update_windows(
-            state.customer, cust_slot, batch.day, batch.amount, fraud,
-            batch.valid & c_adm, track_fraud=False,
+        terminal = update_windows(
+            state.terminal, term_slot, batch.day, batch.amount, fraud,
+            batch.valid & t_adm, track_amount=False,
         )
-    terminal_dir, term_slot, t_adm = admit_slots(
-        state.terminal_dir, batch.terminal_key, batch.valid,
-        n_probes=probes)
-    terminal = update_windows(
-        state.terminal, term_slot, batch.day, batch.amount, fraud,
-        batch.valid & t_adm, track_amount=False,
-    )
-    cms = cms_update(state.cms, batch.customer_key, batch.amount,
-                     batch.day, batch.valid)
-    terminal_cms = cms_update(state.terminal_cms, batch.terminal_key,
-                              batch.amount, batch.day, batch.valid,
-                              fraud=fraud)
+    with step_scope("customer"):
+        cms = cms_update(state.cms, batch.customer_key, batch.amount,
+                         batch.day, batch.valid)
+    with step_scope("terminal"):
+        terminal_cms = cms_update(state.terminal_cms, batch.terminal_key,
+                                  batch.amount, batch.day, batch.valid,
+                                  fraud=fraud)
     new_state = FeatureState(
         customer=customer, terminal=terminal, cms=cms,
         customer_dir=customer_dir, terminal_dir=terminal_dir,
@@ -243,26 +249,35 @@ def _update_state(
     (``batch.label >= 0``) also scatter fraud counts into the terminal state
     (the feedback path); unlabeled rows contribute 0.
     """
-    cust_slot = _slot(batch.customer_key, cfg.customer_capacity, cfg.key_mode)
-    term_slot = _slot(batch.terminal_key, cfg.terminal_capacity, cfg.key_mode)
-    fraud = jnp.maximum(batch.label, 0).astype(jnp.float32)
+    with step_scope("customer"):
+        cust_slot = _slot(batch.customer_key, cfg.customer_capacity,
+                          cfg.key_mode)
+    with step_scope("terminal"):
+        term_slot = _slot(batch.terminal_key, cfg.terminal_capacity,
+                          cfg.key_mode)
+        # only the terminal table tracks fraud sums
+        fraud = jnp.maximum(batch.label, 0).astype(jnp.float32)
     if cfg.customer_source == "cms":
         customer = state.customer  # unused in cms mode: skip the scatter
     else:
         # track_fraud=False: no feature reads customer fraud sums (spec is
         # count+avg for customers) — one fewer 1M-update scatter (~7 ms).
-        customer = update_windows(
-            state.customer, cust_slot, batch.day, batch.amount, fraud,
-            batch.valid, track_fraud=False,
-        )
+        with step_scope("customer"):
+            customer = update_windows(
+                state.customer, cust_slot, batch.day, batch.amount, fraud,
+                batch.valid, track_fraud=False,
+            )
     # track_amount=False symmetrically: terminal features are count+risk.
-    terminal = update_windows(
-        state.terminal, term_slot, batch.day, batch.amount, fraud,
-        batch.valid, track_amount=False,
-    )
+    with step_scope("terminal"):
+        terminal = update_windows(
+            state.terminal, term_slot, batch.day, batch.amount, fraud,
+            batch.valid, track_amount=False,
+        )
     cms = state.cms
     if cms is not None:
-        cms = cms_update(cms, batch.customer_key, batch.amount, batch.day, batch.valid)
+        with step_scope("customer"):
+            cms = cms_update(cms, batch.customer_key, batch.amount,
+                             batch.day, batch.valid)
     return FeatureState(customer=customer, terminal=terminal, cms=cms), cust_slot, term_slot
 
 
@@ -290,40 +305,43 @@ def update_and_featurize(
             )
         from real_time_fraud_detection_system_tpu.ops.cms import cms_query
 
-        c_count, c_amount = cms_query(
-            state.cms, batch.customer_key, batch.day, windows
-        )
+        with step_scope("customer"):
+            c_count, c_amount = cms_query(
+                state.cms, batch.customer_key, batch.day, windows
+            )
     else:
-        c_count, c_amount, _ = query_windows(
-            customer, cust_slot, batch.day, windows
+        with step_scope("customer"):
+            c_count, c_amount, _ = query_windows(
+                customer, cust_slot, batch.day, windows
+            )
+    with step_scope("terminal"):
+        t_count, _, t_fraud = query_windows(
+            terminal, term_slot, batch.day, windows, delay=cfg.delay_days
         )
-    t_count, _, t_fraud = query_windows(
-        terminal, term_slot, batch.day, windows, delay=cfg.delay_days
-    )
-    # div_ieee: averages bit-equal to NumPy's and to the fused kernels'
-    c_avg = jnp.where(
-        c_count > 0, div_ieee(c_amount, jnp.maximum(c_count, 1.0)), 0.0)
-    t_risk = jnp.where(
-        t_count > 0, div_ieee(t_fraud, jnp.maximum(t_count, 1.0)), 0.0)
-
-    is_weekend, is_night = _flags(batch, cfg)
-    features = _assemble(batch, cfg, c_count, c_avg, t_count, t_risk,
-                         is_weekend, is_night)
+    features = _assemble(batch, cfg, c_count, c_amount, t_count, t_fraud)
     return state, features
 
 
-def _assemble(batch, cfg, c_count, c_avg, t_count, t_risk,
-              is_weekend, is_night) -> jnp.ndarray:
-    # Feature order must match features/spec.py::FEATURE_NAMES.
+def _assemble(batch, cfg, c_count, c_amount, t_count, t_fraud) -> jnp.ndarray:
+    """Window sums → the [B, 15] matrix: the two averages, the calendar
+    flags, the column stack."""
     windows = tuple(cfg.windows)
-    cols = [batch.amount, is_weekend, is_night]
-    for i in range(len(windows)):
-        cols.append(c_count[:, i])
-        cols.append(c_avg[:, i])
-    for i in range(len(windows)):
-        cols.append(t_count[:, i])
-        cols.append(t_risk[:, i])
-    return jnp.stack(cols, axis=1)
+    with step_scope("assemble"):
+        # div_ieee: averages bit-equal to NumPy's and to the fused kernels'
+        c_avg = jnp.where(
+            c_count > 0, div_ieee(c_amount, jnp.maximum(c_count, 1.0)), 0.0)
+        t_risk = jnp.where(
+            t_count > 0, div_ieee(t_fraud, jnp.maximum(t_count, 1.0)), 0.0)
+        is_weekend, is_night = _flags(batch, cfg)
+        # Feature order must match features/spec.py::FEATURE_NAMES.
+        cols = [batch.amount, is_weekend, is_night]
+        for i in range(len(windows)):
+            cols.append(c_count[:, i])
+            cols.append(c_avg[:, i])
+        for i in range(len(windows)):
+            cols.append(t_count[:, i])
+            cols.append(t_risk[:, i])
+        return jnp.stack(cols, axis=1)
 
 
 def update_and_featurize_exact(
@@ -352,38 +370,35 @@ def update_and_featurize_exact(
     if cfg.customer_source == "cms":
         from real_time_fraud_detection_system_tpu.ops.cms import cms_query
 
-        c_count, c_amount = cms_query(
-            state.cms, batch.customer_key, batch.day, windows)
+        with step_scope("customer"):
+            c_count, c_amount = cms_query(
+                state.cms, batch.customer_key, batch.day, windows)
         c_tier_rows = jnp.zeros((), jnp.float32)  # no dense customer tier
         c_miss_rows = jnp.zeros((), jnp.float32)
     else:
         from real_time_fraud_detection_system_tpu.ops.cms import cms_query
 
-        cc_t, ca_t, _ = query_windows(
-            state.customer, cust_slot, batch.day, windows)
-        cc_s, ca_s = cms_query(
-            state.cms, batch.customer_key, batch.day, windows)
-        c_count = jnp.where(c_adm[:, None], cc_t, cc_s)
-        c_amount = jnp.where(c_adm[:, None], ca_t, ca_s)
+        with step_scope("customer"):
+            cc_t, ca_t, _ = query_windows(
+                state.customer, cust_slot, batch.day, windows)
+            cc_s, ca_s = cms_query(
+                state.cms, batch.customer_key, batch.day, windows)
+            c_count = jnp.where(c_adm[:, None], cc_t, cc_s)
+            c_amount = jnp.where(c_adm[:, None], ca_t, ca_s)
         c_tier_rows = jnp.sum((batch.valid & c_adm).astype(jnp.float32))
         c_miss_rows = jnp.sum((batch.valid & ~c_adm).astype(jnp.float32))
 
-    tc_t, _, tf_t = query_windows(
-        state.terminal, term_slot, batch.day, windows, delay=cfg.delay_days)
-    tc_s, _, tf_s = cms_query_fraud(
-        state.terminal_cms, batch.terminal_key, batch.day, windows,
-        delay=cfg.delay_days)
-    t_count = jnp.where(t_adm[:, None], tc_t, tc_s)
-    t_fraud = jnp.where(t_adm[:, None], tf_t, tf_s)
+    with step_scope("terminal"):
+        tc_t, _, tf_t = query_windows(
+            state.terminal, term_slot, batch.day, windows,
+            delay=cfg.delay_days)
+        tc_s, _, tf_s = cms_query_fraud(
+            state.terminal_cms, batch.terminal_key, batch.day, windows,
+            delay=cfg.delay_days)
+        t_count = jnp.where(t_adm[:, None], tc_t, tc_s)
+        t_fraud = jnp.where(t_adm[:, None], tf_t, tf_s)
 
-    # div_ieee: averages bit-equal to NumPy's and to the fused kernels'
-    c_avg = jnp.where(
-        c_count > 0, div_ieee(c_amount, jnp.maximum(c_count, 1.0)), 0.0)
-    t_risk = jnp.where(
-        t_count > 0, div_ieee(t_fraud, jnp.maximum(t_count, 1.0)), 0.0)
-    is_weekend, is_night = _flags(batch, cfg)
-    features = _assemble(batch, cfg, c_count, c_avg, t_count, t_risk,
-                         is_weekend, is_night)
+    features = _assemble(batch, cfg, c_count, c_amount, t_count, t_fraud)
     dense = c_tier_rows + jnp.sum((batch.valid & t_adm).astype(jnp.float32))
     cms_rows = c_miss_rows + jnp.sum(
         (batch.valid & ~t_adm).astype(jnp.float32))
@@ -415,22 +430,25 @@ def update_and_score_pallas(
     )
 
     state, cust_slot, term_slot = _update_state(state, batch, cfg)
-    c_bd, c_cnt, c_amt, _ = gather_state_rows(state.customer, cust_slot)
-    t_bd, t_cnt, _, t_frd = gather_state_rows(state.terminal, term_slot)
-    probs, feats = fused_featurize_score(
-        (c_bd, c_cnt, c_amt),
-        (t_bd, t_cnt, t_frd),
-        batch.day,
-        batch.tod_s,
-        batch.amount,
-        batch.valid,
-        scaler_mean, scaler_scale, w, b,
-        windows=tuple(cfg.windows),
-        delay=cfg.delay_days,
-        weekend_start=cfg.weekend_start_weekday,
-        night_end=cfg.night_end_hour,
-        interpret=interpret,
-    )
+    with step_scope("customer"):
+        c_bd, c_cnt, c_amt, _ = gather_state_rows(state.customer, cust_slot)
+    with step_scope("terminal"):
+        t_bd, t_cnt, _, t_frd = gather_state_rows(state.terminal, term_slot)
+    with step_scope("fused_step"):
+        probs, feats = fused_featurize_score(
+            (c_bd, c_cnt, c_amt),
+            (t_bd, t_cnt, t_frd),
+            batch.day,
+            batch.tod_s,
+            batch.amount,
+            batch.valid,
+            scaler_mean, scaler_scale, w, b,
+            windows=tuple(cfg.windows),
+            delay=cfg.delay_days,
+            weekend_start=cfg.weekend_start_weekday,
+            night_end=cfg.night_end_hour,
+            interpret=interpret,
+        )
     return state, probs, feats
 
 
@@ -462,22 +480,25 @@ def update_and_score_pallas_forest(
     )
 
     state, cust_slot, term_slot = _update_state(state, batch, cfg)
-    c_bd, c_cnt, c_amt, _ = gather_state_rows(state.customer, cust_slot)
-    t_bd, t_cnt, _, t_frd = gather_state_rows(state.terminal, term_slot)
-    leaf_sum, feats = fused_forest_leaf_sum(
-        pf,
-        (c_bd, c_cnt, c_amt),
-        (t_bd, t_cnt, t_frd),
-        batch.day,
-        batch.tod_s,
-        batch.amount,
-        scaler_mean, scaler_scale,
-        windows=tuple(cfg.windows),
-        delay=cfg.delay_days,
-        weekend_start=cfg.weekend_start_weekday,
-        night_end=cfg.night_end_hour,
-        interpret=interpret,
-    )
+    with step_scope("customer"):
+        c_bd, c_cnt, c_amt, _ = gather_state_rows(state.customer, cust_slot)
+    with step_scope("terminal"):
+        t_bd, t_cnt, _, t_frd = gather_state_rows(state.terminal, term_slot)
+    with step_scope("fused_step"):
+        leaf_sum, feats = fused_forest_leaf_sum(
+            pf,
+            (c_bd, c_cnt, c_amt),
+            (t_bd, t_cnt, t_frd),
+            batch.day,
+            batch.tod_s,
+            batch.amount,
+            scaler_mean, scaler_scale,
+            windows=tuple(cfg.windows),
+            delay=cfg.delay_days,
+            weekend_start=cfg.weekend_start_weekday,
+            night_end=cfg.night_end_hour,
+            interpret=interpret,
+        )
     return state, leaf_sum, feats
 
 

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from real_time_fraud_detection_system_tpu.ops.numerics import div_ieee
+from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
 
 class Scaler(NamedTuple):
@@ -35,7 +36,8 @@ def fit_scaler(x: np.ndarray) -> Scaler:
 def transform(scaler: Scaler, x: jnp.ndarray) -> jnp.ndarray:
     # div_ieee: the chip's own f32 divide is 1 ulp off sklearn's in ~32%
     # of values, enough to flip a tree's vote on a threshold-sitting row
-    return div_ieee(x - scaler.mean, scaler.scale)
+    with step_scope("scale"):
+        return div_ieee(x - scaler.mean, scaler.scale)
 
 
 def inverse_transform(scaler: Scaler, x: jnp.ndarray) -> jnp.ndarray:

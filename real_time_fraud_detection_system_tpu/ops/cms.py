@@ -23,6 +23,7 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax.numpy as jnp
 
 from real_time_fraud_detection_system_tpu.ops.hashing import multi_hash
+from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
 
 class CountMinSketch(NamedTuple):
@@ -72,35 +73,37 @@ def cms_update(
     valid: jnp.ndarray,  # bool [B]
     fraud: Optional[jnp.ndarray] = None,  # float32 [B] 0/1 (labeled rows)
 ) -> CountMinSketch:
-    nd, depth, width = sk.count.shape
-    sl = jnp.remainder(day, nd)  # [B]
-    day_in = jnp.where(valid, day, -1).astype(jnp.int32)
-    new_slice_day = sk.slice_day.at[sl].max(day_in)
+    with step_scope("cms"):
+        nd, depth, width = sk.count.shape
+        sl = jnp.remainder(day, nd)  # [B]
+        day_in = jnp.where(valid, day, -1).astype(jnp.int32)
+        new_slice_day = sk.slice_day.at[sl].max(day_in)
 
-    # Reset slices that advanced to a newer day.
-    advanced = (new_slice_day > sk.slice_day)[:, None, None]
-    count = jnp.where(advanced, 0.0, sk.count)
-    amt = jnp.where(advanced, 0.0, sk.amount)
+        # Reset slices that advanced to a newer day.
+        advanced = (new_slice_day > sk.slice_day)[:, None, None]
+        count = jnp.where(advanced, 0.0, sk.count)
+        amt = jnp.where(advanced, 0.0, sk.amount)
 
-    fresh = valid & (day_in == new_slice_day[sl])
-    w = fresh.astype(jnp.float32)  # [B]
-    cols = multi_hash(key, depth, width)  # [depth, B]
-    rows = jnp.broadcast_to(jnp.arange(depth, dtype=jnp.int32)[:, None], cols.shape)
-    slc = jnp.broadcast_to(sl[None, :], cols.shape)
-    wb = jnp.broadcast_to(w[None, :], cols.shape)
-    count = count.at[slc, rows, cols].add(wb)
-    amt = amt.at[slc, rows, cols].add(wb * amount[None, :])
-    frd = sk.fraud
-    if frd is not None:
-        # Same slice-reset + fresh-mask discipline as count/amount; a
-        # sketch without the column (every pre-tiering config) takes a
-        # bit-identical count/amount path through this function.
-        frd = jnp.where(advanced, 0.0, frd)
-        f_in = (jnp.zeros_like(w) if fraud is None
-                else fraud.astype(jnp.float32))
-        frd = frd.at[slc, rows, cols].add(wb * f_in[None, :])
-    return CountMinSketch(slice_day=new_slice_day, count=count, amount=amt,
-                          fraud=frd)
+        fresh = valid & (day_in == new_slice_day[sl])
+        w = fresh.astype(jnp.float32)  # [B]
+        cols = multi_hash(key, depth, width)  # [depth, B]
+        rows = jnp.broadcast_to(
+            jnp.arange(depth, dtype=jnp.int32)[:, None], cols.shape)
+        slc = jnp.broadcast_to(sl[None, :], cols.shape)
+        wb = jnp.broadcast_to(w[None, :], cols.shape)
+        count = count.at[slc, rows, cols].add(wb)
+        amt = amt.at[slc, rows, cols].add(wb * amount[None, :])
+        frd = sk.fraud
+        if frd is not None:
+            # Same slice-reset + fresh-mask discipline as count/amount; a
+            # sketch without the column (every pre-tiering config) takes a
+            # bit-identical count/amount path through this function.
+            frd = jnp.where(advanced, 0.0, frd)
+            f_in = (jnp.zeros_like(w) if fraud is None
+                    else fraud.astype(jnp.float32))
+            frd = frd.at[slc, rows, cols].add(wb * f_in[None, :])
+        return CountMinSketch(slice_day=new_slice_day, count=count,
+                              amount=amt, fraud=frd)
 
 
 def cms_add_fraud(
@@ -154,24 +157,26 @@ def _cms_query_tables(
     [day-delay-w+1, day-delay] — the same delay-shift semantics as
     :func:`..windows.query_windows` (``delay=0`` is the historical
     count/amount path, bit-identical arithmetic)."""
-    nd, depth, width = sk.count.shape
-    max_w = max(windows)
-    offsets = jnp.arange(max_w, dtype=jnp.int32)  # [W]
-    wanted = day[:, None] - jnp.int32(delay) - offsets[None, :]  # [B, W]
-    sl = jnp.remainder(wanted, nd)  # [B, W]
-    live = (sk.slice_day[sl] == wanted) & (wanted >= 0)  # [B, W]
+    with step_scope("cms"):
+        nd, depth, width = sk.count.shape
+        max_w = max(windows)
+        offsets = jnp.arange(max_w, dtype=jnp.int32)  # [W]
+        # [B, W]
+        wanted = day[:, None] - jnp.int32(delay) - offsets[None, :]
+        sl = jnp.remainder(wanted, nd)  # [B, W]
+        live = (sk.slice_day[sl] == wanted) & (wanted >= 0)  # [B, W]
 
-    cols = multi_hash(key, depth, width)  # [depth, B]
-    sel = jnp.stack(
-        [(offsets < w).astype(jnp.float32) for w in windows], axis=0
-    )  # [NW, W]
-    out = []
-    for t in tables:
-        # Gather [depth, B, W] then min over depth.
-        g = t[sl[None, :, :], jnp.arange(depth)[:, None, None],
-              cols[:, :, None]]
-        out.append((jnp.min(g, axis=0) * live) @ sel.T)
-    return tuple(out)
+        cols = multi_hash(key, depth, width)  # [depth, B]
+        sel = jnp.stack(
+            [(offsets < w).astype(jnp.float32) for w in windows], axis=0
+        )  # [NW, W]
+        out = []
+        for t in tables:
+            # Gather [depth, B, W] then min over depth.
+            g = t[sl[None, :, :], jnp.arange(depth)[:, None, None],
+                  cols[:, :, None]]
+            out.append((jnp.min(g, axis=0) * live) @ sel.T)
+        return tuple(out)
 
 
 def cms_query(

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from real_time_fraud_detection_system_tpu.ops.hashing import hash_u32
+from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
 # np scalar, NOT jnp: a module-level jnp constant would run a JAX
 # computation at import time, which breaks jax.distributed.initialize
@@ -178,72 +179,73 @@ def admit_slots(
     slot 0 and MUST be masked out of dense-tier scatters (the caller
     serves them from the sketch tier).
     """
-    dir_cap = kd.dir_capacity
-    slot_cap = kd.slot_capacity
-    key = _canon(key)
-    B = int(key.shape[0])
-    pos = _probe_positions(key, dir_cap, n_probes)  # [B, P]
-    keys = kd.keys
-    # FULL-depth lookup FIRST, claims only for keys with no existing
-    # entry: reclaim_entries can vacate a position on a live key's
-    # probe-path PREFIX, and a claim-as-you-probe insert would grab that
-    # vacancy before ever reaching the key's real entry — duplicating
-    # the key, resetting its window history, and leaking its old slot.
-    # (lookup_slots scans all P positions for the same reason; this is
-    # the insert-side half of the no-tombstones argument.)
-    found = keys[pos] == key[:, None]  # [B, P]
-    pidx = jnp.argmax(found, axis=1)
-    hit0 = found.any(axis=1) & valid
-    entry = jnp.where(
-        hit0, jnp.take_along_axis(pos, pidx[:, None], axis=1)[:, 0], 0)
-    placed = ~valid | hit0
-    claimed = jnp.zeros(B, dtype=bool)  # matched via a claim made NOW
-    for j in range(n_probes):
-        p = pos[:, j]
-        cur = keys[p]
-        # batch duplicates of a key claimed in an EARLIER round match
-        # here (pre-call lookup could not see that claim)
-        hit = (~placed) & (cur == key)
-        entry = jnp.where(hit, p, entry)
-        placed = placed | hit
-        # Claim attempt: scatter-min our key into still-empty positions;
-        # among racing writers the smallest key wins, losers re-probe.
-        want = (~placed) & (cur == EMPTY_KEY)
-        cand = jnp.where(want, key, EMPTY_KEY)
-        keys = keys.at[p].min(cand)
-        won = want & (keys[p] == key)
-        entry = jnp.where(won, p, entry)
-        claimed = claimed | won
-        placed = placed | won
-    # One owner per newly claimed entry (batch duplicates of one new key
-    # all carry claimed=True on the same entry; exactly one pops a slot).
-    rows = jnp.arange(B, dtype=jnp.int32)
-    owner = jnp.full((dir_cap,), B, jnp.int32).at[
-        jnp.where(claimed, entry, dir_cap)].min(rows, mode="drop")
-    new = claimed & (owner[entry] == rows)
-    # Grant free slots to owners in row order; owners past the stack
-    # height roll their claim back (their duplicates then miss too).
-    rank = jnp.cumsum(new.astype(jnp.int32)) - 1  # [B]
-    avail = kd.free_top
-    has = new & (rank < avail)
-    slot_new = kd.free[jnp.clip(avail - 1 - rank, 0, slot_cap - 1)]
-    slots = kd.slots.at[jnp.where(has, entry, dir_cap)].set(
-        slot_new, mode="drop")
-    revert = new & ~(rank < avail)
-    keys = keys.at[jnp.where(revert, entry, dir_cap)].set(
-        EMPTY_KEY, mode="drop")
-    free_top = avail - jnp.sum(has.astype(jnp.int32))
-    # Final resolution covers every case at once: hits, fresh grants,
-    # batch duplicates of grants, rolled-back claims (keys[entry] no
-    # longer matches), and rows that never placed (probe overflow).
-    slot = slots[entry]
-    admitted = placed & valid & (keys[entry] == key) & (slot >= 0)
-    return (
-        KeyDirectory(keys=keys, slots=slots, free=kd.free,
-                     free_top=free_top),
-        jnp.where(admitted, slot, 0),
-        admitted,
-    )
+    with step_scope("keydir"):
+        dir_cap = kd.dir_capacity
+        slot_cap = kd.slot_capacity
+        key = _canon(key)
+        B = int(key.shape[0])
+        pos = _probe_positions(key, dir_cap, n_probes)  # [B, P]
+        keys = kd.keys
+        # FULL-depth lookup FIRST, claims only for keys with no existing
+        # entry: reclaim_entries can vacate a position on a live key's
+        # probe-path PREFIX, and a claim-as-you-probe insert would grab that
+        # vacancy before ever reaching the key's real entry — duplicating
+        # the key, resetting its window history, and leaking its old slot.
+        # (lookup_slots scans all P positions for the same reason; this is
+        # the insert-side half of the no-tombstones argument.)
+        found = keys[pos] == key[:, None]  # [B, P]
+        pidx = jnp.argmax(found, axis=1)
+        hit0 = found.any(axis=1) & valid
+        entry = jnp.where(
+            hit0, jnp.take_along_axis(pos, pidx[:, None], axis=1)[:, 0], 0)
+        placed = ~valid | hit0
+        claimed = jnp.zeros(B, dtype=bool)  # matched via a claim made NOW
+        for j in range(n_probes):
+            p = pos[:, j]
+            cur = keys[p]
+            # batch duplicates of a key claimed in an EARLIER round match
+            # here (pre-call lookup could not see that claim)
+            hit = (~placed) & (cur == key)
+            entry = jnp.where(hit, p, entry)
+            placed = placed | hit
+            # Claim attempt: scatter-min our key into still-empty positions;
+            # among racing writers the smallest key wins, losers re-probe.
+            want = (~placed) & (cur == EMPTY_KEY)
+            cand = jnp.where(want, key, EMPTY_KEY)
+            keys = keys.at[p].min(cand)
+            won = want & (keys[p] == key)
+            entry = jnp.where(won, p, entry)
+            claimed = claimed | won
+            placed = placed | won
+        # One owner per newly claimed entry (batch duplicates of one new key
+        # all carry claimed=True on the same entry; exactly one pops a slot).
+        rows = jnp.arange(B, dtype=jnp.int32)
+        owner = jnp.full((dir_cap,), B, jnp.int32).at[
+            jnp.where(claimed, entry, dir_cap)].min(rows, mode="drop")
+        new = claimed & (owner[entry] == rows)
+        # Grant free slots to owners in row order; owners past the stack
+        # height roll their claim back (their duplicates then miss too).
+        rank = jnp.cumsum(new.astype(jnp.int32)) - 1  # [B]
+        avail = kd.free_top
+        has = new & (rank < avail)
+        slot_new = kd.free[jnp.clip(avail - 1 - rank, 0, slot_cap - 1)]
+        slots = kd.slots.at[jnp.where(has, entry, dir_cap)].set(
+            slot_new, mode="drop")
+        revert = new & ~(rank < avail)
+        keys = keys.at[jnp.where(revert, entry, dir_cap)].set(
+            EMPTY_KEY, mode="drop")
+        free_top = avail - jnp.sum(has.astype(jnp.int32))
+        # Final resolution covers every case at once: hits, fresh grants,
+        # batch duplicates of grants, rolled-back claims (keys[entry] no
+        # longer matches), and rows that never placed (probe overflow).
+        slot = slots[entry]
+        admitted = placed & valid & (keys[entry] == key) & (slot >= 0)
+        return (
+            KeyDirectory(keys=keys, slots=slots, free=kd.free,
+                         free_top=free_top),
+            jnp.where(admitted, slot, 0),
+            admitted,
+        )
 
 
 def reclaim_entries(

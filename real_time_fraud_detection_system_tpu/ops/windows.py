@@ -32,6 +32,7 @@ from typing import NamedTuple, Sequence, Tuple
 import jax.numpy as jnp
 
 from real_time_fraud_detection_system_tpu.ops.numerics import sum_fixed_order
+from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
 
 class WindowState(NamedTuple):
@@ -91,39 +92,48 @@ def update_windows(
     """
     nb = state.n_buckets
     cap = state.capacity
-    bucket = jnp.remainder(day, nb)
-    flat = (slot * nb + bucket).astype(jnp.int32)
+    with step_scope("update"):
+        bucket = jnp.remainder(day, nb)
+        flat = (slot * nb + bucket).astype(jnp.int32)
+        # invalid rows stamp -1 which never wins
+        day_in = jnp.where(valid, day, -1).astype(jnp.int32)
 
-    # Day stamp each touched bucket with max(existing, incoming) — invalid
-    # rows stamp -1 which never wins.
-    day_in = jnp.where(valid, day, -1).astype(jnp.int32)
-    bd = state.bucket_day.reshape(-1)
-    new_bd = bd.at[flat].max(day_in)
+        with step_scope("relayout"):
+            bd = state.bucket_day.reshape(-1)
+            count = state.count.reshape(-1)
+            amt = state.amount.reshape(-1)
+            frd = state.fraud.reshape(-1)
 
-    # Buckets whose stamp advanced hold a stale (older) day: reset aggregates.
-    advanced = new_bd > bd
-    count = jnp.where(advanced, 0.0, state.count.reshape(-1))
+        # Day stamp each touched bucket with max(existing, incoming).
+        with step_scope("stamp"):
+            new_bd = bd.at[flat].max(day_in)
 
-    # A row contributes only if its day is the bucket's (possibly new) stamp.
-    fresh = valid & (day_in == new_bd[flat])
-    w = fresh.astype(jnp.float32)
-    count = count.at[flat].add(w)
+        # Buckets whose stamp advanced hold a stale (older) day: reset
+        # aggregates.
+        with step_scope("reset"):
+            advanced = new_bd > bd
+            count = jnp.where(advanced, 0.0, count)
+            amt = jnp.where(advanced, 0.0, amt)
+            frd = jnp.where(advanced, 0.0, frd)
 
-    amt = jnp.where(advanced, 0.0, state.amount.reshape(-1))
-    if track_amount:
-        amt = amt.at[flat].add(amount * w)
-    frd = jnp.where(advanced, 0.0, state.fraud.reshape(-1))
-    if track_fraud:
-        frd = frd.at[flat].add(fraud * w)
-    amt = amt.reshape(cap, nb)
-    frd = frd.reshape(cap, nb)
+        with step_scope("scatter"):
+            # A row contributes only if its day is the bucket's (possibly
+            # new) stamp.
+            fresh = valid & (day_in == new_bd[flat])
+            w = fresh.astype(jnp.float32)
+            count = count.at[flat].add(w)
+            if track_amount:
+                amt = amt.at[flat].add(amount * w)
+            if track_fraud:
+                frd = frd.at[flat].add(fraud * w)
 
-    return WindowState(
-        bucket_day=new_bd.reshape(cap, nb),
-        count=count.reshape(cap, nb),
-        amount=amt,
-        fraud=frd,
-    )
+        with step_scope("relayout"):
+            return WindowState(
+                bucket_day=new_bd.reshape(cap, nb),
+                count=count.reshape(cap, nb),
+                amount=amt.reshape(cap, nb),
+                fraud=frd.reshape(cap, nb),
+            )
 
 
 def gather_state_rows(
@@ -131,12 +141,13 @@ def gather_state_rows(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One row-gather per table: (bucket_day, count, amount, fraud)[slot],
     each [B, NB]. The single embedding-style gather the query needs."""
-    return (
-        state.bucket_day[slot],
-        state.count[slot],
-        state.amount[slot],
-        state.fraud[slot],
-    )
+    with step_scope("query"), step_scope("gather"):
+        return (
+            state.bucket_day[slot],
+            state.count[slot],
+            state.amount[slot],
+            state.fraud[slot],
+        )
 
 
 def query_gathered(
@@ -156,22 +167,23 @@ def query_gathered(
     one [B, NB] age computation + a [B, NB] @ [NB→NW] masked contraction,
     entirely VPU/MXU-friendly (and the form the Pallas fused kernel uses).
     """
-    age = day[:, None] - jnp.int32(delay) - bucket_day  # [B, NB]
-    live = (bucket_day >= 0) & (age >= 0)
-    out_c, out_a, out_f = [], [], []
-    for w in windows:
-        sel = (live & (age < w)).astype(jnp.float32)
-        # counts and fraud labels are integers: exact in any order. The
-        # dollar amounts are not, so their order is pinned — the fused
-        # kernels (assemble_features) add the same tree.
-        out_c.append(jnp.sum(count * sel, axis=1))
-        out_a.append(sum_fixed_order(amount * sel, axis=1))
-        out_f.append(jnp.sum(fraud * sel, axis=1))
-    return (
-        jnp.stack(out_c, axis=1),
-        jnp.stack(out_a, axis=1),
-        jnp.stack(out_f, axis=1),
-    )
+    with step_scope("query"), step_scope("sum"):
+        age = day[:, None] - jnp.int32(delay) - bucket_day  # [B, NB]
+        live = (bucket_day >= 0) & (age >= 0)
+        out_c, out_a, out_f = [], [], []
+        for w in windows:
+            sel = (live & (age < w)).astype(jnp.float32)
+            # counts and fraud labels are integers: exact in any order. The
+            # dollar amounts are not, so their order is pinned — the fused
+            # kernels (assemble_features) add the same tree.
+            out_c.append(jnp.sum(count * sel, axis=1))
+            out_a.append(sum_fixed_order(amount * sel, axis=1))
+            out_f.append(jnp.sum(fraud * sel, axis=1))
+        return (
+            jnp.stack(out_c, axis=1),
+            jnp.stack(out_a, axis=1),
+            jnp.stack(out_f, axis=1),
+        )
 
 
 def query_windows(

@@ -44,15 +44,15 @@ from real_time_fraud_detection_system_tpu.config import Config
 from real_time_fraud_detection_system_tpu.core.batch import TxBatch
 from real_time_fraud_detection_system_tpu.features.online import (
     FeatureState,
-    _flags,
+    _assemble,
 )
 from real_time_fraud_detection_system_tpu.features.spec import N_FEATURES
 from real_time_fraud_detection_system_tpu.models.scaler import Scaler, transform
-from real_time_fraud_detection_system_tpu.ops.numerics import div_ieee
 from real_time_fraud_detection_system_tpu.ops.windows import (
     query_windows,
     update_windows,
 )
+from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
 
 def partition_batch_spill(
@@ -177,10 +177,11 @@ def _make_xchg(axis, n_dev: int, cap: int):
 
     def xchg(x):
         rest = x.shape[1:]
-        return jax.lax.all_to_all(
-            x.reshape((n_dev, cap) + rest), axis, split_axis=0,
-            concat_axis=0, tiled=False,
-        ).reshape((n_dev * cap,) + rest)
+        with step_scope("exchange"):
+            return jax.lax.all_to_all(
+                x.reshape((n_dev, cap) + rest), axis, split_axis=0,
+                concat_axis=0, tiled=False,
+            ).reshape((n_dev * cap,) + rest)
 
     return xchg
 
@@ -405,30 +406,32 @@ def make_sharded_step(
 
             def customer_fn_x(st, c_key, c_day, c_amt, c_fraud, c_valid):
                 kd, customer, lcms, cnt = st
-                if kd is None:
-                    # customer_source="cms": sketch-only velocity (no
-                    # dense customer tier, no tier accounting — matching
-                    # the single-chip exact engine)
+                with step_scope("customer"):
+                    if kd is None:
+                        # customer_source="cms": sketch-only velocity (no
+                        # dense customer tier, no tier accounting —
+                        # matching the single-chip exact engine)
+                        lcms = cms_update(lcms, c_key, c_amt, c_day,
+                                          c_valid)
+                        cc, ca = cms_query(lcms, c_key, c_day, windows)
+                        return (kd, customer, lcms, cnt), jnp.concatenate(
+                            [cc, ca], axis=1)
+                    kd, c_slot, c_adm = admit_slots(kd, c_key, c_valid,
+                                                    n_probes=probes)
+                    customer = update_windows(
+                        customer, c_slot, c_day, c_amt, c_fraud,
+                        c_valid & c_adm, track_fraud=False)
                     lcms = cms_update(lcms, c_key, c_amt, c_day, c_valid)
-                    cc, ca = cms_query(lcms, c_key, c_day, windows)
+                    cc_t, ca_t, _ = query_windows(customer, c_slot, c_day,
+                                                  windows)
+                    cc_s, ca_s = cms_query(lcms, c_key, c_day, windows)
+                    cc = jnp.where(c_adm[:, None], cc_t, cc_s)
+                    ca = jnp.where(c_adm[:, None], ca_t, ca_s)
+                    cnt = cnt + jnp.stack([
+                        jnp.sum((c_valid & c_adm).astype(jnp.float32)),
+                        jnp.sum((c_valid & ~c_adm).astype(jnp.float32))])
                     return (kd, customer, lcms, cnt), jnp.concatenate(
                         [cc, ca], axis=1)
-                kd, c_slot, c_adm = admit_slots(kd, c_key, c_valid,
-                                                n_probes=probes)
-                customer = update_windows(
-                    customer, c_slot, c_day, c_amt, c_fraud,
-                    c_valid & c_adm, track_fraud=False)
-                lcms = cms_update(lcms, c_key, c_amt, c_day, c_valid)
-                cc_t, ca_t, _ = query_windows(customer, c_slot, c_day,
-                                              windows)
-                cc_s, ca_s = cms_query(lcms, c_key, c_day, windows)
-                cc = jnp.where(c_adm[:, None], cc_t, cc_s)
-                ca = jnp.where(c_adm[:, None], ca_t, ca_s)
-                cnt = cnt + jnp.stack([
-                    jnp.sum((c_valid & c_adm).astype(jnp.float32)),
-                    jnp.sum((c_valid & ~c_adm).astype(jnp.float32))])
-                return (kd, customer, lcms, cnt), jnp.concatenate(
-                    [cc, ca], axis=1)
 
             st0 = (c_kd, fstate.customer, local_cms, zero2)
             if route_customers:
@@ -444,25 +447,26 @@ def make_sharded_step(
             def terminal_fn_x(st, t_key, t_day, t_amt, t_fraud_in,
                               t_valid):
                 kd, terminal, tcms, cnt = st
-                kd, t_slot, t_adm = admit_slots(kd, t_key, t_valid,
-                                                n_probes=probes)
-                terminal = update_windows(
-                    terminal, t_slot, t_day, t_amt, t_fraud_in,
-                    t_valid & t_adm, track_amount=False)
-                tcms = cms_update(tcms, t_key, t_amt, t_day, t_valid,
-                                  fraud=t_fraud_in)
-                tc_t, _, tf_t = query_windows(
-                    terminal, t_slot, t_day, windows,
-                    delay=fcfg.delay_days)
-                tc_s, _, tf_s = cms_query_fraud(
-                    tcms, t_key, t_day, windows, delay=fcfg.delay_days)
-                tc = jnp.where(t_adm[:, None], tc_t, tc_s)
-                tf = jnp.where(t_adm[:, None], tf_t, tf_s)
-                cnt = cnt + jnp.stack([
-                    jnp.sum((t_valid & t_adm).astype(jnp.float32)),
-                    jnp.sum((t_valid & ~t_adm).astype(jnp.float32))])
-                return (kd, terminal, tcms, cnt), jnp.concatenate(
-                    [tc, tf], axis=1)
+                with step_scope("terminal"):
+                    kd, t_slot, t_adm = admit_slots(kd, t_key, t_valid,
+                                                    n_probes=probes)
+                    terminal = update_windows(
+                        terminal, t_slot, t_day, t_amt, t_fraud_in,
+                        t_valid & t_adm, track_amount=False)
+                    tcms = cms_update(tcms, t_key, t_amt, t_day, t_valid,
+                                      fraud=t_fraud_in)
+                    tc_t, _, tf_t = query_windows(
+                        terminal, t_slot, t_day, windows,
+                        delay=fcfg.delay_days)
+                    tc_s, _, tf_s = cms_query_fraud(
+                        tcms, t_key, t_day, windows, delay=fcfg.delay_days)
+                    tc = jnp.where(t_adm[:, None], tc_t, tc_s)
+                    tf = jnp.where(t_adm[:, None], tf_t, tf_s)
+                    cnt = cnt + jnp.stack([
+                        jnp.sum((t_valid & t_adm).astype(jnp.float32)),
+                        jnp.sum((t_valid & ~t_adm).astype(jnp.float32))])
+                    return (kd, terminal, tcms, cnt), jnp.concatenate(
+                        [tc, tf], axis=1)
 
             (t_kd, terminal, t_cms, t_cnt), tb = exchanged_compute(
                 batch.terminal_key, terminal_fn_x,
@@ -480,26 +484,28 @@ def make_sharded_step(
             """Owner-side customer velocity: sketch/window update + query
             on the rows this device owns; returns [*, 2·NW] aggregates."""
             local_cms, customer = st
-            if local_cms is not None:
-                local_cms = cms_update(local_cms, c_key, c_amt, c_day,
-                                       c_valid)
-            if use_cms:
-                # BASELINE config 3 × config 5: unbounded-key velocity
-                # from the per-device sketch (each sketch holds only this
-                # device's customers — fewer collisions than one global
-                # sketch).
-                cc, ca = cms_query(local_cms, c_key, c_day, windows)
-            else:
-                c_slot = ((c_key // jnp.uint32(n_dev))
-                          & jnp.uint32(c_cap_local - 1)).astype(jnp.int32)
-                customer = update_windows(
-                    customer, c_slot, c_day, c_amt, c_fraud, c_valid,
-                    track_fraud=False,  # customer features: count+avg
-                )
-                cc, ca, _ = query_windows(customer, c_slot, c_day,
-                                          windows)
-            return (local_cms, customer), jnp.concatenate([cc, ca],
-                                                          axis=1)
+            with step_scope("customer"):
+                if local_cms is not None:
+                    local_cms = cms_update(local_cms, c_key, c_amt, c_day,
+                                           c_valid)
+                if use_cms:
+                    # BASELINE config 3 × config 5: unbounded-key velocity
+                    # from the per-device sketch (each sketch holds only
+                    # this device's customers — fewer collisions than one
+                    # global sketch).
+                    cc, ca = cms_query(local_cms, c_key, c_day, windows)
+                else:
+                    c_slot = ((c_key // jnp.uint32(n_dev))
+                              & jnp.uint32(c_cap_local - 1)
+                              ).astype(jnp.int32)
+                    customer = update_windows(
+                        customer, c_slot, c_day, c_amt, c_fraud, c_valid,
+                        track_fraud=False,  # customer features: count+avg
+                    )
+                    cc, ca, _ = query_windows(customer, c_slot, c_day,
+                                              windows)
+                return (local_cms, customer), jnp.concatenate([cc, ca],
+                                                              axis=1)
 
         if route_customers:
             (local_cms, customer), cb = exchanged_compute(
@@ -516,16 +522,18 @@ def make_sharded_step(
         # ---- terminal windows: always routed to owner over ICI ----------
         def terminal_fn(terminal, t_key, t_day, t_amt, t_fraud_in,
                         t_valid):
-            t_slot = ((t_key // jnp.uint32(n_dev))
-                      & jnp.uint32(t_cap_local - 1)).astype(jnp.int32)
-            terminal = update_windows(
-                terminal, t_slot, t_day, t_amt, t_fraud_in, t_valid,
-                track_amount=False,  # terminal features: count+risk
-            )
-            t_count, _, t_fraud = query_windows(
-                terminal, t_slot, t_day, windows, delay=fcfg.delay_days
-            )
-            return terminal, jnp.concatenate([t_count, t_fraud], axis=1)
+            with step_scope("terminal"):
+                t_slot = ((t_key // jnp.uint32(n_dev))
+                          & jnp.uint32(t_cap_local - 1)).astype(jnp.int32)
+                terminal = update_windows(
+                    terminal, t_slot, t_day, t_amt, t_fraud_in, t_valid,
+                    track_amount=False,  # terminal features: count+risk
+                )
+                t_count, _, t_fraud = query_windows(
+                    terminal, t_slot, t_day, windows,
+                    delay=fcfg.delay_days)
+                return terminal, jnp.concatenate([t_count, t_fraud],
+                                                 axis=1)
 
         terminal, tb = exchanged_compute(
             batch.terminal_key, terminal_fn, fstate.terminal)
@@ -545,35 +553,25 @@ def make_sharded_step(
         new-state pytree — identical math for the direct/hash and exact
         state planes, so the tiered store cannot drift the scoring
         arithmetic."""
-        # ---- assemble the 15-feature matrix (order = features/spec.py)
-        c_avg = jnp.where(
-            c_count > 0, div_ieee(c_amount, jnp.maximum(c_count, 1.0)), 0.0)
-        t_risk = jnp.where(
-            t_count_l > 0,
-            div_ieee(t_fraud_l, jnp.maximum(t_count_l, 1.0)), 0.0)
-        is_weekend, is_night = _flags(batch, fcfg)
-        cols = [batch.amount, is_weekend, is_night]
-        for i in range(nw):
-            cols.append(c_count[:, i])
-            cols.append(c_avg[:, i])
-        for i in range(nw):
-            cols.append(t_count_l[:, i])
-            cols.append(t_risk[:, i])
-        feats = jnp.stack(cols, axis=1)
+        feats = _assemble(batch, fcfg, c_count, c_amount, t_count_l,
+                          t_fraud_l)
 
         # ---- score (+ optional online SGD with psum'd grads)
         x = transform(scaler, feats)
-        probs = jnp.where(batch.valid, predict_fn(params, x), 0.0)
+        with step_scope("classify"):
+            probs = jnp.where(batch.valid, predict_fn(params, x), 0.0)
         if online_lr > 0.0 and loss_fn is not None:
-            labeled = batch.valid & (batch.label >= 0)
-            y = jnp.maximum(batch.label, 0)
-            g = jax.grad(loss_fn)(params, x, y, labeled)
-            g = jax.tree.map(lambda gi: jax.lax.psum(gi, axis) / n_dev, g)
-            has = jnp.any(
-                jax.lax.psum(labeled.astype(jnp.int32), axis) > 0
-            ).astype(jnp.float32)
-            params = jax.tree.map(lambda p, gi: p - online_lr * has * gi,
-                                  params, g)
+            with step_scope("learn"):
+                labeled = batch.valid & (batch.label >= 0)
+                y = jnp.maximum(batch.label, 0)
+                g = jax.grad(loss_fn)(params, x, y, labeled)
+                g = jax.tree.map(
+                    lambda gi: jax.lax.psum(gi, axis) / n_dev, g)
+                has = jnp.any(
+                    jax.lax.psum(labeled.astype(jnp.int32), axis) > 0
+                ).astype(jnp.float32)
+                params = jax.tree.map(
+                    lambda p, gi: p - online_lr * has * gi, params, g)
 
         new_state = FeatureState(customer=customer, terminal=terminal,
                                  cms=cms, customer_dir=customer_dir,
@@ -582,7 +580,8 @@ def make_sharded_step(
         if cfg.runtime.emit_dtype == "bfloat16":
             # halve the emitted matrix's D2H bytes; the classifier above
             # already consumed the f32 features (predictions unaffected)
-            feats = feats.astype(jnp.bfloat16)
+            with step_scope("emit"):
+                feats = feats.astype(jnp.bfloat16)
         if tier is not None:
             return new_state, params, probs, feats, tier
         return new_state, params, probs, feats
@@ -637,7 +636,8 @@ def make_sharded_step(
         cap_frac = cfg.runtime.emit_cap_fraction
 
         def outer(fstate, params, scaler, batch_in):
-            batch = unpack_batch(batch_in) if packed else batch_in
+            with step_scope("unpack"):
+                batch = unpack_batch(batch_in) if packed else batch_in
             out = fn(fstate, params, scaler, batch)
             tier = out[4] if exact else None
             fstate, params, probs, feats = out[:4]
@@ -654,13 +654,14 @@ def make_sharded_step(
             # for any chunk ≤ 2^24 slots.
             pad = batch.valid.shape[0]
             cap = max(8, int(pad * cap_frac))
-            flagged = batch.valid & (probs >= thresh)
-            idx = jnp.nonzero(flagged, size=cap, fill_value=0)[0]
-            count = jnp.sum(flagged).astype(jnp.float32)
-            packed_out = jnp.concatenate([
-                probs, count[None], idx.astype(jnp.float32),
-                feats[idx].reshape(-1),
-            ])
+            with step_scope("emit"):
+                flagged = batch.valid & (probs >= thresh)
+                idx = jnp.nonzero(flagged, size=cap, fill_value=0)[0]
+                count = jnp.sum(flagged).astype(jnp.float32)
+                packed_out = jnp.concatenate([
+                    probs, count[None], idx.astype(jnp.float32),
+                    feats[idx].reshape(-1),
+                ])
             emit = {"packed": packed_out, "full": feats}
             if exact:
                 return fstate, params, probs, emit, tier

@@ -68,7 +68,13 @@ from real_time_fraud_detection_system_tpu.utils.metrics import (
     get_registry,
 )
 from real_time_fraud_detection_system_tpu.utils.timing import LatencyTracker
-from real_time_fraud_detection_system_tpu.utils.trace import get_tracer
+from real_time_fraud_detection_system_tpu.utils.trace import (
+    get_tracer,
+    step_scope,
+)
+from real_time_fraud_detection_system_tpu.utils.tracing import (
+    keep_scopes_in_cache_key,
+)
 from real_time_fraud_detection_system_tpu.utils.xla_telemetry import (
     DeviceMemoryTelemetry,
     RecompileDetector,
@@ -497,7 +503,8 @@ class ScoringEngine:
         def step(fstate: FeatureState, params, scaler: Scaler, packed):
             # One packed H2D array per batch (see core.batch.pack_batch):
             # the unpack is free bitcasts inside the fused program.
-            batch = unpack_batch(packed)
+            with step_scope("unpack"):
+                batch = unpack_batch(packed)
             tier = None
             # `kernel` is a trace-time fact: _pallas_choice reads the
             # config and params' pytree FORM and static shapes, never a
@@ -522,7 +529,8 @@ class ScoringEngine:
                     fstate, batch, fcfg, scaler.mean, scaler.scale, pf,
                 )
                 x = transform(scaler, feats)
-                probs = jnp.where(batch.valid, leaf / pf.n_trees, 0.0)
+                with step_scope("fused_step"):
+                    probs = jnp.where(batch.valid, leaf / pf.n_trees, 0.0)
             elif self.scorer == "cpu":
                 # Oracle serving: the classifier runs host-side on the
                 # returned features (process_batch), so don't burn device
@@ -533,20 +541,23 @@ class ScoringEngine:
             else:
                 fstate, feats, tier = _featurize(fstate, batch)
                 x = transform(scaler, feats)
-                probs = self._predict(params, x)
-                probs = jnp.where(batch.valid, probs, 0.0)
+                with step_scope("classify"):
+                    probs = self._predict(params, x)
+                    probs = jnp.where(batch.valid, probs, 0.0)
             if self.online_lr > 0.0 and self._loss is not None:
-                labeled = batch.valid & (batch.label >= 0)
-                y = jnp.maximum(batch.label, 0)
-                g = jax.grad(self._loss)(params, x, y, labeled)
-                has = jnp.any(labeled).astype(jnp.float32)
-                params = jax.tree.map(
-                    lambda p, gi: p - self.online_lr * has * gi, params, g
-                )
+                with step_scope("learn"):
+                    labeled = batch.valid & (batch.label >= 0)
+                    y = jnp.maximum(batch.label, 0)
+                    g = jax.grad(self._loss)(params, x, y, labeled)
+                    has = jnp.any(labeled).astype(jnp.float32)
+                    params = jax.tree.map(
+                        lambda p, gi: p - self.online_lr * has * gi,
+                        params, g)
             if cfg.runtime.emit_dtype == "bfloat16":
                 # halve the emitted matrix's D2H bytes; the classifier
                 # above consumed the f32 features (predictions unaffected)
-                feats = feats.astype(jnp.bfloat16)
+                with step_scope("emit"):
+                    feats = feats.astype(jnp.bfloat16)
             if self._selective:
                 # On-device compaction: gather the flagged rows' feature
                 # vectors into a fixed-capacity buffer, then pack
@@ -559,15 +570,16 @@ class ScoringEngine:
                 # HBM until fetched) as the overflow fallback.
                 pad = batch.valid.shape[0]
                 cap = max(8, int(pad * cfg.runtime.emit_cap_fraction))
-                flagged = batch.valid & (probs >= thresh)
-                idx = jnp.nonzero(flagged, size=cap, fill_value=0)[0]
-                count = jnp.sum(flagged).astype(jnp.float32)
-                packed_out = jnp.concatenate([
-                    probs,
-                    count[None],
-                    idx.astype(jnp.float32),
-                    feats[idx].reshape(-1),
-                ])
+                with step_scope("emit"):
+                    flagged = batch.valid & (probs >= thresh)
+                    idx = jnp.nonzero(flagged, size=cap, fill_value=0)[0]
+                    count = jnp.sum(flagged).astype(jnp.float32)
+                    packed_out = jnp.concatenate([
+                        probs,
+                        count[None],
+                        idx.astype(jnp.float32),
+                        feats[idx].reshape(-1),
+                    ])
                 emit = {"packed": packed_out, "full": feats}
             else:
                 emit = feats
@@ -630,6 +642,7 @@ class ScoringEngine:
         # the recompile alarm and memory gauges honor THIS registry.
         self.tracer = get_tracer()
         install_compile_telemetry()
+        keep_scopes_in_cache_key()
         self._recompile = RecompileDetector(registry=reg)
         self._devmem = DeviceMemoryTelemetry(reg)
         # AOT-precompiled step executables (see precompile()): dispatch
@@ -1683,7 +1696,8 @@ class ScoringEngine:
         fcfg = cfg.features
 
         def step(hstate, params, scaler, packed):
-            batch = unpack_batch(packed)
+            with step_scope("unpack"):
+                batch = unpack_batch(packed)
             hstate, probs = update_and_score(hstate, params, batch, fcfg)
             feats = jnp.zeros((batch.size, N_FEATURES), jnp.float32)
             return hstate, params, probs, feats

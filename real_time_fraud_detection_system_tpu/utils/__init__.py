@@ -8,7 +8,6 @@ from real_time_fraud_detection_system_tpu.utils.logging import (  # noqa: F401
 )
 from real_time_fraud_detection_system_tpu.utils.tracing import (  # noqa: F401
     enable_compilation_cache,
-    trace_span,
     profile_to,
 )
 from real_time_fraud_detection_system_tpu.utils.metrics import (  # noqa: F401

@@ -51,6 +51,8 @@ __all__ = [
     "Tracer",
     "get_tracer",
     "current_ids",
+    "STEP_SCOPES",
+    "step_scope",
     "summarize_chrome",
 ]
 
@@ -342,6 +344,34 @@ def current_ids() -> Tuple[str, int]:
     """(trace_id, batch_index) of the default tracer's current batch —
     the log formatter's hook (see ``utils/logging.py``)."""
     return _default_tracer.current_ids()
+
+
+# The host spans above stop at the dispatch; these carry the same
+# ``rtfds.`` vocabulary down into the jitted step. Each name is one
+# component of a device op's HLO ``op_name`` (components nest, e.g.
+# ``.../rtfds.terminal/rtfds.update/rtfds.reset/select_n``), which is what a
+# profiler trace's device events are grouped by. Metadata only: a scope
+# changes no instruction. Where each is opened: PERF.md section 3.
+STEP_SCOPES = (
+    "unpack",                                      # engine.step
+    "customer", "terminal",                        # which table
+    "update", "relayout", "stamp", "reset", "scatter",  # ops/windows
+    "query", "gather", "sum",                      # ops/windows
+    "keydir", "cms",                               # ops/keydir, ops/cms
+    "assemble",                                    # features/online
+    "scale",                                       # models/scaler
+    "classify", "fused_step", "learn", "emit",     # engine.step
+    "exchange",                                    # parallel/step
+)
+
+
+def step_scope(name: str):
+    """``jax.named_scope("rtfds.<name>")`` for one stage of the step."""
+    if name not in STEP_SCOPES:
+        raise ValueError(f"{name!r} is not one of STEP_SCOPES")
+    import jax
+
+    return jax.named_scope("rtfds." + name)
 
 
 # ---------------------------------------------------------------------------

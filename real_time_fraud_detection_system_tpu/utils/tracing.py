@@ -6,8 +6,10 @@ unconfigured). Here any run can capture an XLA/TensorBoard trace::
     with profile_to("/tmp/trace"):
         engine.run(...)
 
-and individual host-side phases can be annotated with ``trace_span`` so they
-show up on the profiler timeline next to device ops.
+The capture holds the engine's host spans (``rtfds.<span>#<batch>``, from
+``utils/trace.Tracer``) over device ops whose ``op_name`` carries the step's
+stages (``rtfds.<stage>``, ``utils/trace.STEP_SCOPES``): one clock, one
+vocabulary, in TensorBoard/xprof.
 """
 
 from __future__ import annotations
@@ -52,28 +54,45 @@ def enable_compilation_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", _repo_cache_dir())
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       CACHE_MIN_COMPILE_S)
+    keep_scopes_in_cache_key()
     return jax.config.jax_compilation_cache_dir
+
+
+def keep_scopes_in_cache_key() -> None:
+    """Hash a program for the persistent cache WITH its debug metadata.
+
+    jax's default strips it first, so an executable cached by a build
+    whose step carried other ``jax.named_scope``s (or none) is served to
+    this one, and a profiler trace then shows that build's ``op_name``s:
+    the stage metrics read nothing. The price: a program whose source
+    lines moved compiles once more (the metadata holds file and line)."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 @contextlib.contextmanager
 def profile_to(log_dir: Optional[str]) -> Iterator[None]:
-    """Capture a jax.profiler trace into ``log_dir`` (no-op when None)."""
+    """Capture a jax.profiler trace into ``log_dir`` (no-op when None),
+    with the process-wide ``Tracer`` on for the capture's duration."""
     if not log_dir:
         yield
         return
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    from real_time_fraud_detection_system_tpu.utils.trace import get_tracer
+
+    tracer = get_tracer()
+    was_enabled = tracer.enabled
+    # the engine's spans go into the capture as TraceAnnotations
+    tracer.configure(enabled=True)
+    # the Python tracer would log every call of the serving loop: a trace
+    # too large to load, and the loop slowed by the logging
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def trace_span(name: str) -> Iterator[None]:
-    """Named host-side span on the profiler timeline."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
+        tracer.configure(enabled=was_enabled)

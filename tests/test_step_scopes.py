@@ -1,0 +1,199 @@
+"""The step's stages are named from inside the program.
+
+``utils/trace.STEP_SCOPES`` is the one vocabulary; every stage of the
+jitted step opens its ``rtfds.<stage>`` with ``jax.named_scope`` where the
+work is written, so a device op's HLO ``op_name`` reads e.g.
+``jit(step)/rtfds.terminal/rtfds.update/rtfds.reset/select_n`` and the
+benchmark's ``readers/device_scopes.py`` can split ``device_step_ms`` by
+stage. For every variant of the step that compiles here at toy size:
+
+- the lowered HLO (what the program says, before any compiler folds a
+  reshape into a bitcast) carries every scope the variant should have,
+  and no ``rtfds.`` component outside the vocabulary;
+- in the COMPILED HLO every ``rtfds.update`` op sits under exactly one of
+  ``rtfds.customer`` / ``rtfds.terminal``;
+- scopes are metadata: the step's outputs are bit-equal to a build with
+  the scopes patched to no-ops.
+"""
+
+import contextlib
+import dataclasses as dc
+import re
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+from real_time_fraud_detection_system_tpu.config import (
+    Config,
+    FeatureConfig,
+    RuntimeConfig,
+)
+from real_time_fraud_detection_system_tpu.features.spec import N_FEATURES
+from real_time_fraud_detection_system_tpu.models.forest import (
+    for_device,
+    synthetic_ensemble,
+)
+from real_time_fraud_detection_system_tpu.models.logreg import init_logreg
+from real_time_fraud_detection_system_tpu.models.scaler import Scaler
+from real_time_fraud_detection_system_tpu.runtime import (
+    ScoringEngine,
+    ShardedScoringEngine,
+)
+from real_time_fraud_detection_system_tpu.utils.metrics import (
+    MetricsRegistry,
+)
+from real_time_fraud_detection_system_tpu.utils.trace import (
+    STEP_SCOPES,
+    get_tracer,
+    step_scope,
+)
+
+PKG = "real_time_fraud_detection_system_tpu"
+TABLE = {"customer", "terminal"}
+UPDATE = {"update", "relayout", "stamp", "reset", "scatter"}
+QUERY = {"query", "gather", "sum"}
+COMMON = {"unpack", "assemble", "scale", "classify"} | TABLE | UPDATE | QUERY
+
+# variant → (kind, FeatureConfig overrides, RuntimeConfig overrides,
+#            sharded over n virtual devices, scopes it must carry)
+VARIANTS = {
+    "forest": ("forest", {}, {}, 0, COMMON),
+    "logreg": ("logreg", {}, {}, 0, COMMON),
+    "exact": ("logreg", {"key_mode": "exact"}, {}, 0,
+              COMMON | {"keydir", "cms"}),
+    "cms": ("logreg", {"customer_source": "cms"}, {}, 0,
+            COMMON | {"cms"}),
+    "selective": ("forest", {}, {"emit_threshold": 0.4}, 0,
+                  COMMON | {"emit"}),
+    "online_sgd": ("logreg", {}, {"online_lr": 0.01}, 0,
+                   COMMON | {"learn"}),
+    "sharded": ("forest", {}, {}, 2, COMMON | {"exchange"}),
+}
+
+
+def _engine(variant):
+    kind, feat, run, n_dev, _ = VARIANTS[variant]
+    run = dict(run)
+    online_lr = run.pop("online_lr", 0.0)
+    cfg = Config(
+        features=FeatureConfig(customer_capacity=128, terminal_capacity=256,
+                               cms_width=1 << 10, **feat),
+        runtime=dc.replace(
+            RuntimeConfig(batch_buckets=(64,), max_batch_rows=64), **run))
+    params = (init_logreg(N_FEATURES) if kind == "logreg" else
+              for_device(synthetic_ensemble(4, 3, N_FEATURES), N_FEATURES))
+    scaler = Scaler(mean=np.full(N_FEATURES, 0.5, np.float32),
+                    scale=np.full(N_FEATURES, 2.0, np.float32))
+    kw = dict(kind=kind, params=params, scaler=scaler,
+              metrics=MetricsRegistry(), online_lr=online_lr)
+    if n_dev:
+        return ShardedScoringEngine(cfg, n_devices=n_dev,
+                                    rows_per_shard=32, **kw)
+    return ScoringEngine(cfg, **kw)
+
+
+def _op_names(hlo_text):
+    return re.findall(r'op_name="([^"]*)"', hlo_text)
+
+
+def _scopes(op_name):
+    """The ``rtfds.`` components of one op_name, in order, bare."""
+    return [c[len("rtfds."):] for c in op_name.split("/")
+            if c.startswith("rtfds.")]
+
+
+def _lowered_steps(eng):
+    return [eng.signature_step(sig).lower(*eng.signature_templates(sig))
+            for sig in eng.dispatch_inventory()
+            if sig.variant not in ("compact", "promote")]
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_step_hlo_carries_the_variants_scopes(variant):
+    lowered = _lowered_steps(_engine(variant))
+    assert lowered
+    want = VARIANTS[variant][4]
+    for low in lowered:
+        said = {s for n in _op_names(
+            low.as_text(dialect="hlo", debug_info=True)) for s in _scopes(n)}
+        assert said <= set(STEP_SCOPES)
+        assert want <= said, sorted(want - said)
+        compiled = _op_names(low.compile().as_text())
+        kept = {s for n in compiled for s in _scopes(n)}
+        # the compiler keeps the names on what it keeps of the ops (on the
+        # CPU a flat reshape is a bitcast, so `relayout` may be gone)
+        assert (want - {"relayout", "unpack"}) <= kept, \
+            sorted(want - kept)
+        updates = [_scopes(n) for n in compiled if "rtfds.update" in n]
+        assert updates
+        for path in updates:
+            assert len(TABLE & set(path)) == 1, path
+            assert path.index("update") > min(
+                path.index(t) for t in TABLE & set(path)), path
+
+
+def test_unknown_scope_is_refused():
+    with pytest.raises(ValueError):
+        step_scope("windows")
+
+
+def _cols(n, day):
+    rng = np.random.default_rng(day)
+    us = (day * 86400 + np.arange(n) * 60).astype(np.int64) * 1_000_000
+    return {
+        "tx_id": np.arange(n, dtype=np.int64) + day * 1000,
+        "tx_datetime_us": us,
+        "customer_id": rng.integers(0, 100, n).astype(np.int64),
+        "terminal_id": rng.integers(0, 200, n).astype(np.int64),
+        "tx_amount_cents": rng.integers(100, 90_000, n).astype(np.int64),
+        "kafka_ts_ms": us // 1000,
+    }
+
+
+def _run_two_batches(variant):
+    eng = _engine(variant)
+    out = []
+    for day in (20_000, 20_001):
+        res = eng.process_batch(_cols(50, day))
+        out += [np.asarray(res.probs), np.asarray(res.features)]
+    out += [np.asarray(x) for x in jax.tree.leaves(
+        eng.state.feature_state)]
+    return out
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_scopes_change_no_output_bit(variant, monkeypatch):
+    with_scopes = _run_two_batches(variant)
+    for name, mod in list(sys.modules.items()):
+        if name.startswith(PKG) and hasattr(mod, "step_scope"):
+            monkeypatch.setattr(
+                mod, "step_scope", lambda name: contextlib.nullcontext())
+    low = _lowered_steps(_engine(variant))[0]
+    assert not any(_scopes(n) for n in _op_names(
+        low.as_text(dialect="hlo", debug_info=True)))  # the patch took
+    without = _run_two_batches(variant)
+    assert len(with_scopes) == len(without)
+    for a, b in zip(with_scopes, without):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+
+
+def test_profile_to_turns_the_tracer_on_for_the_capture(tmp_path):
+    from real_time_fraud_detection_system_tpu.utils import profile_to
+
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.configure(enabled=False)
+    try:
+        with profile_to(str(tmp_path / "trace")):
+            assert tracer.enabled
+            with tracer.span("dispatch"):
+                pass
+        assert not tracer.enabled
+        assert list((tmp_path / "trace").rglob("*.xplane.pb"))
+        with profile_to(None):  # no directory: nothing is touched
+            assert not tracer.enabled
+    finally:
+        tracer.configure(enabled=was)
