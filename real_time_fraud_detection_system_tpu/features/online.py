@@ -13,6 +13,7 @@ label updates never contaminate the queried window.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -68,8 +69,8 @@ def init_feature_state(
     n_shards: int = 1,
 ) -> FeatureState:
     """``n_shards > 1`` builds the SHARDED exact layout: the window
-    tables stay flat ``[capacity, NB]`` (placed ``P(axis, None)``, so
-    shard s owns rows ``[s*cap/n, (s+1)*cap/n)``), but each shard gets
+    tables stay global ``[capacity · NB]`` columns (placed ``P(axis)``, so
+    shard s owns slots ``[s*cap/n, (s+1)*cap/n)``), but each shard gets
     its OWN key directory over its local slot range — stacked
     ``[n_shards, ...]`` leaves (:func:`~..ops.keydir.
     init_stacked_keydir`). Sketches keep the single-chip layout here;
@@ -261,7 +262,8 @@ def _update_state(
         customer = state.customer  # unused in cms mode: skip the scatter
     else:
         # track_fraud=False: no feature reads customer fraud sums (spec is
-        # count+avg for customers) — one fewer 1M-update scatter (~7 ms).
+        # count+avg for customers) — one fewer scatter (~6 ms a batch of
+        # 65,536 rows on a v5e; ledger, PR 24: step_scatter_ms).
         with step_scope("customer"):
             customer = update_windows(
                 state.customer, cust_slot, batch.day, batch.amount, fraud,
@@ -548,7 +550,7 @@ def compact_feature_state(
             counts.append(jnp.int32(0))
             payload[ws_name] = None
             continue
-        newest = jnp.max(ws.bucket_day, axis=1)  # [slot_cap]
+        newest = jnp.max(ws.tables()[0], axis=1)  # [slot_cap]
         slot_idx = jnp.clip(kd.slots, 0, ws.capacity - 1)
         live = kd.slots >= 0
         newest_e = newest[slot_idx]
@@ -567,12 +569,7 @@ def compact_feature_state(
             old_slots = kd.slots  # pre-clear ids (reclaim vacates them)
             kd, dead, n = reclaim_entries(kd, dead_entry)
             tgt = jnp.where(dead, old_slots, ws.capacity)
-            ws = WindowState(
-                bucket_day=ws.bucket_day.at[tgt].set(-1, mode="drop"),
-                count=ws.count.at[tgt].set(0.0, mode="drop"),
-                amount=ws.amount.at[tgt].set(0.0, mode="drop"),
-                fraud=ws.fraud.at[tgt].set(0.0, mode="drop"),
-            )
+            ws = ws.set_rows(tgt, -1, 0.0, 0.0, 0.0)
             payload[ws_name] = None
         out[dir_name] = kd
         out[ws_name] = ws
@@ -660,20 +657,14 @@ def _demote_oldest(
     keys = jnp.where(lane_live, kd.keys[eidx_c], jnp.uint32(EMPTY_KEY))
     slot_g = jnp.clip(kd.slots[eidx_c], 0, slot_cap - 1)
     m = lane_live[:, None]
-    bd = jnp.where(m, ws.bucket_day[slot_g], jnp.int32(-1))
-    cnt = jnp.where(m, ws.count[slot_g], 0.0)
-    amt = jnp.where(m, ws.amount[slot_g], 0.0)
-    frd = jnp.where(m, ws.fraud[slot_g], 0.0)
+    bd, cnt, amt, frd = (
+        jnp.where(m, row, fill)
+        for row, fill in zip(ws.rows(slot_g), (jnp.int32(-1), 0.0, 0.0, 0.0)))
     # One combined vacate: dead history + demoted entries.
     old_slots = kd.slots
     kd, dead, n = reclaim_entries(kd, dead_entry | sel)
     tgt = jnp.where(dead, old_slots, slot_cap)
-    ws = WindowState(
-        bucket_day=ws.bucket_day.at[tgt].set(-1, mode="drop"),
-        count=ws.count.at[tgt].set(0.0, mode="drop"),
-        amount=ws.amount.at[tgt].set(0.0, mode="drop"),
-        fraud=ws.fraud.at[tgt].set(0.0, mode="drop"),
-    )
+    ws = ws.set_rows(tgt, -1, 0.0, 0.0, 0.0)
     return kd, ws, n, (keys, bd, cnt, amt, frd)
 
 
@@ -715,19 +706,13 @@ def promote_rows(
         kd, slot, adm = admit_slots(kd, keys, valid,
                                     n_probes=cfg.keydir_probes)
         slot_c = jnp.clip(slot, 0, ws.capacity - 1)
-        take = adm[:, None] & (bd > ws.bucket_day[slot_c])
-        new_bd = jnp.where(take, bd, ws.bucket_day[slot_c])
-        new_cnt = jnp.where(take, cnt, ws.count[slot_c])
-        new_amt = jnp.where(take, amt, ws.amount[slot_c])
-        new_frd = jnp.where(take, frd, ws.fraud[slot_c])
+        hot = ws.rows(slot_c)
+        take = adm[:, None] & (bd > hot[0])
         tgt = jnp.where(adm, slot, ws.capacity)
         out[dir_name] = kd
-        out[ws_name] = WindowState(
-            bucket_day=ws.bucket_day.at[tgt].set(new_bd, mode="drop"),
-            count=ws.count.at[tgt].set(new_cnt, mode="drop"),
-            amount=ws.amount.at[tgt].set(new_amt, mode="drop"),
-            fraud=ws.fraud.at[tgt].set(new_frd, mode="drop"),
-        )
+        out[ws_name] = ws.set_rows(tgt, *(
+            jnp.where(take, cold, row)
+            for cold, row in zip((bd, cnt, amt, frd), hot)))
         adm_n = jnp.sum(adm.astype(jnp.int32))
         drop_n = jnp.sum((valid & ~adm).astype(jnp.int32))
         stats.append(jnp.stack([adm_n, drop_n]))
@@ -828,11 +813,9 @@ def apply_feedback_at_slot(
     bucket = jnp.remainder(day, nb)
     flat = term_slot * nb + bucket
     # Only land the label if the bucket still holds that day (ring not wrapped).
-    live = valid & (state.terminal.bucket_day.reshape(-1)[flat] == day)
-    frd = state.terminal.fraud.reshape(-1).at[flat].add(
+    live = valid & (state.terminal.bucket_day[flat] == day)
+    frd = state.terminal.fraud.at[flat].add(
         label.astype(jnp.float32) * live.astype(jnp.float32)
     )
-    terminal = state.terminal._replace(
-        fraud=frd.reshape(state.terminal.fraud.shape)
-    )
-    return state._replace(terminal=terminal)
+    return state._replace(
+        terminal=dataclasses.replace(state.terminal, fraud=frd))

@@ -70,6 +70,7 @@ from typing import List, Optional, Tuple
 import jax
 import numpy as np
 
+from real_time_fraud_detection_system_tpu.ops.windows import WindowState
 from real_time_fraud_detection_system_tpu.utils.metrics import (
     active_recorder,
     get_registry,
@@ -135,15 +136,34 @@ def _observe_checkpoint(op: str, backend: str, t0: float, nbytes: int,
 # ---------------------------------------------------------------------------
 
 
+def _leaves_on_disk(tree):
+    """``(leaf, its on-disk shape)`` for every leaf of ``tree``, in pytree
+    order. A window column is stored flat (``ops/windows.py``) but
+    written as the ``[cap, NB]`` table it is — a free view of the host
+    copy — so checkpoints taken before the flat layout restore under it
+    and the reverse; every other leaf is written as it is."""
+    def is_windows(x):
+        return isinstance(x, WindowState)
+
+    out = []
+    for node in jax.tree_util.tree_leaves(tree, is_leaf=is_windows):
+        if is_windows(node):
+            out += [(c, (node.capacity, node.n_buckets))
+                    for c in node.columns()]
+        else:
+            out.append((node, tuple(np.shape(node))))
+    return out
+
+
 def _state_arrays(engine_state) -> Tuple[dict, dict]:
     """Flatten an EngineState into the npz array dict + meta dict — the
     ONE place the on-disk leaf naming (``fs_i``/``p_i``/``s_i``) lives."""
-    leaves_fs, _ = jax.tree_util.tree_flatten(engine_state.feature_state)
+    leaves_fs = _leaves_on_disk(engine_state.feature_state)
     leaves_p, _ = jax.tree_util.tree_flatten(engine_state.params)
     leaves_s, _ = jax.tree_util.tree_flatten(engine_state.scaler)
     arrays = {}
-    for i, leaf in enumerate(leaves_fs):
-        arrays[f"fs_{i}"] = np.asarray(leaf)
+    for i, (leaf, shape) in enumerate(leaves_fs):
+        arrays[f"fs_{i}"] = np.asarray(leaf).reshape(shape)
     for i, leaf in enumerate(leaves_p):
         arrays[f"p_{i}"] = np.asarray(leaf)
     for i, leaf in enumerate(leaves_s):
@@ -237,7 +257,15 @@ def _directory_occupancy(feature_state) -> dict:
 def _apply_arrays(engine_state, meta: dict, arrays: dict):
     """Rebuild an EngineState template from the (composed) array dict —
     the restore tail shared by v1 files and v2 full/delta chains."""
-    fs_leaves = [arrays[f"fs_{i}"] for i in range(meta["n_fs"])]
+    # a leaf written through a view (a window table) goes back to the
+    # shape the template holds it in; every other leaf keeps the
+    # checkpoint's shape (a directory's follows the writer's device
+    # count, and the engine's _ensure_layout re-homes it)
+    fs_leaves = [
+        a if np.shape(t) == on_disk else a.reshape(np.shape(t))
+        for a, (t, on_disk) in zip(
+            (arrays[f"fs_{i}"] for i in range(meta["n_fs"])),
+            _leaves_on_disk(engine_state.feature_state))]
     p_leaves = [arrays[f"p_{i}"] for i in range(meta["n_p"])]
     s_leaves = [arrays[f"s_{i}"] for i in range(meta["n_s"])]
     _, fs_def = jax.tree_util.tree_flatten(engine_state.feature_state)
@@ -343,11 +371,11 @@ def _template_spec(engine_state) -> dict:
     for prefix, tree in (("fs", engine_state.feature_state),
                          ("p", engine_state.params),
                          ("s", engine_state.scaler)):
-        for i, leaf in enumerate(jax.tree_util.tree_leaves(tree)):
+        for i, (leaf, shape) in enumerate(_leaves_on_disk(tree)):
             dt = getattr(leaf, "dtype", None)
             if dt is None:
                 dt = np.asarray(leaf).dtype
-            out[f"{prefix}_{i}"] = [list(np.shape(leaf)), str(dt)]
+            out[f"{prefix}_{i}"] = [list(shape), str(dt)]
     return dict(sorted(out.items()))
 
 
@@ -888,8 +916,8 @@ class _CheckpointerBase:
         def width_dependent(k: str) -> bool:
             # Only the per-shard planes legitimately change shape with
             # width: key directories (stacked [n, ...] leaves) and
-            # sketch replicas. Window tables are global [cap, NB] at
-            # EVERY width, so a capacity mismatch there must stay an
+            # sketch replicas. Window tables are global [cap, NB] on
+            # disk at EVERY width, so a capacity mismatch there must stay an
             # 'incompatible' quarantine-and-fallback, not leak through
             # to a hard reshard crash. Writers without leaf names
             # (pre-sharded-exact) never produced width-dependent

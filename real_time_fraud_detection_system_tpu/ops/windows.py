@@ -13,6 +13,20 @@ fraud-sum) for one absolute day, stamped with that day. A window query sums
 the buckets whose stamp falls inside the window; stale buckets (overwritten
 by the ring) simply don't match and contribute zero.
 
+Stored form: each of the four columns is ONE flat ``[capacity · n_buckets]``
+array, slot-major (``flat = slot · n_buckets + day % n_buckets``) — stored,
+carried through the step, donated and restored from a checkpoint that way.
+That is the layout the update works in: the TPU compiler's scatter wants a
+flat operand whatever the program writes, and it keeps a ``[cap, 40]``
+array with ``cap`` on the lanes (40 is no multiple of 128), so a column
+stored as a table was copied and reshaped to flat and back in every step —
+at 2^22 + 2^23 slots 206.7 of a 305.4 ms step moved tables between two
+layouts and computed nothing (PERF.md, PR 24 / PR 25). A flat column is
+also, byte for byte, its ``[n/128, 128]`` view, which is what the row
+gather of the query reads (:meth:`WindowState.rows`). Code that thinks in
+``[cap, NB]`` (compaction, promotion, reshard, the checkpoint's leaves)
+goes through :meth:`WindowState.tables` / ``rows`` / ``set_rows``.
+
 Canonical window semantics (documented deviation from the reference): windows
 are **trailing calendar days including the current day** — window w at day d
 covers days [d-w+1, d]; with ``delay`` (terminal risk label latency,
@@ -27,37 +41,122 @@ fully vectorized, jit/shard_map friendly, no data-dependent shapes.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Sequence, Tuple
+import dataclasses
+import math
+from functools import partial
+from typing import Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from real_time_fraud_detection_system_tpu.ops.numerics import sum_fixed_order
 from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
+COLUMNS = ("bucket_day", "count", "amount", "fraud")
 
-class WindowState(NamedTuple):
-    """Ring-buffer day aggregates for one key space (pytree of [cap, NB])."""
 
-    bucket_day: jnp.ndarray  # int32 [cap, NB]; -1 = empty
-    count: jnp.ndarray  # float32 [cap, NB]
-    amount: jnp.ndarray  # float32 [cap, NB] — sum of amounts that day
-    fraud: jnp.ndarray  # float32 [cap, NB] — sum of fraud labels that day
+@partial(jax.tree_util.register_dataclass, data_fields=list(COLUMNS),
+         meta_fields=["n_buckets"])
+@dataclasses.dataclass(frozen=True)
+class WindowState:
+    """Ring-buffer day aggregates for one key space: a pytree of four flat
+    ``[capacity · n_buckets]`` columns (see the module docstring), with
+    ``n_buckets`` as static metadata — it cannot be read from a flat
+    shape. Everything that wants ``[cap, NB]`` goes through
+    :meth:`tables` / :meth:`rows` / :meth:`set_rows`."""
+
+    bucket_day: jnp.ndarray  # int32 [cap · NB]; -1 = empty
+    count: jnp.ndarray  # float32 [cap · NB]
+    amount: jnp.ndarray  # float32 [cap · NB] — sum of amounts that day
+    fraud: jnp.ndarray  # float32 [cap · NB] — sum of fraud labels that day
+    n_buckets: int
 
     @property
     def capacity(self) -> int:
-        return int(self.bucket_day.shape[0])
+        return int(self.bucket_day.shape[0]) // self.n_buckets
 
-    @property
-    def n_buckets(self) -> int:
-        return int(self.bucket_day.shape[1])
+    def columns(self) -> Tuple[jnp.ndarray, ...]:
+        return tuple(getattr(self, c) for c in COLUMNS)
+
+    def tables(self) -> Tuple[jnp.ndarray, ...]:
+        """The four columns as ``[cap, NB]``. Free on host arrays (a flat
+        slot-major array reshapes to rows without a copy); on the chip it
+        is a pass over the table, so nothing on the per-batch path takes
+        it."""
+        return tuple(c.reshape(self.capacity, self.n_buckets)
+                     for c in self.columns())
+
+    @classmethod
+    def from_tables(cls, bucket_day, count, amount, fraud) -> "WindowState":
+        """The inverse of :meth:`tables`."""
+        return cls(bucket_day.reshape(-1), count.reshape(-1),
+                   amount.reshape(-1), fraud.reshape(-1),
+                   n_buckets=int(np.shape(bucket_day)[1]))
+
+    def rows(self, slot: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
+        """``(bucket_day, count, amount, fraud)`` of ``slot`` [B] in
+        ``[0, capacity)``, each ``[B, NB]``.
+
+        A flat column is read through its ``[n/128, 128]`` view — on the
+        chip the same bytes as the flat array, so the view is free — with
+        plain row takes, the one gather the chip's compiler emits well:
+        a slot's NB entries start at lane ``slot·NB mod 128``, a multiple
+        of ``gcd(NB, 128)``, inside the row that holds the start plus the
+        next one(s); the rows are taken whole and the NB lanes picked by
+        one select per possible start lane. On a v5e, 65,536 rows of a
+        2^23-slot column: 2.9 ms, against 3.6 for ``table[slot]`` on a
+        stored ``[cap, 40]`` table, 47 for an element gather at
+        ``slot·NB + arange(NB)`` and 76 for a ``lax.gather`` with
+        ``slice_sizes=(NB,)``, which the compiler turns into a loop over
+        the batch (my chip run, PR 25). A column whose length is no
+        multiple of 128 (toy sizes) takes the widest view that divides
+        it; the arithmetic is the same."""
+        nb = self.n_buckets
+        n = int(self.bucket_day.shape[0])
+        lanes = math.gcd(n, 128)
+        step = math.gcd(nb, lanes)  # a slot starts at a multiple of this
+        n_take = -(-(lanes - step + nb) // lanes)
+        start = slot.astype(jnp.int32) * nb
+        row = start // lanes
+        lane = start - row * lanes
+        last = n // lanes - 1
+
+        def take(col):
+            view = col.reshape(-1, lanes)
+            win = jnp.concatenate(
+                [view[jnp.minimum(row + i, last)] for i in range(n_take)],
+                axis=1)
+            out = win[:, :nb]
+            for k in range(step, lanes, step):
+                out = jnp.where((lane == k)[:, None], win[:, k:k + nb], out)
+            return out
+
+        return tuple(take(c) for c in self.columns())
+
+    def set_rows(self, slot: jnp.ndarray, bucket_day, count, amount,
+                 fraud) -> "WindowState":
+        """Overwrite whole rows: ``slot`` [K], a value ``[K, NB]`` (or a
+        scalar) per column. A slot of ``capacity`` or more is dropped, which
+        is how callers mask lanes out."""
+        nb = self.n_buckets
+        idx = (slot.astype(jnp.int32) * nb)[:, None] + jnp.arange(
+            nb, dtype=jnp.int32)
+        return WindowState(
+            *(c.at[idx].set(v, mode="drop")
+              for c, v in zip(self.columns(),
+                              (bucket_day, count, amount, fraud))),
+            n_buckets=nb)
 
 
 def init_window_state(capacity: int, n_buckets: int) -> WindowState:
+    n = capacity * n_buckets
     return WindowState(
-        bucket_day=jnp.full((capacity, n_buckets), -1, dtype=jnp.int32),
-        count=jnp.zeros((capacity, n_buckets), dtype=jnp.float32),
-        amount=jnp.zeros((capacity, n_buckets), dtype=jnp.float32),
-        fraud=jnp.zeros((capacity, n_buckets), dtype=jnp.float32),
+        bucket_day=jnp.full((n,), -1, dtype=jnp.int32),
+        count=jnp.zeros((n,), dtype=jnp.float32),
+        amount=jnp.zeros((n,), dtype=jnp.float32),
+        fraud=jnp.zeros((n,), dtype=jnp.float32),
+        n_buckets=n_buckets,
     )
 
 
@@ -79,30 +178,28 @@ def update_windows(
     Duplicate (slot, day) rows within the batch accumulate correctly
     (jnp scatter-add applies all duplicates).
 
-    ``track_amount`` / ``track_fraud``: scatters are the hot path's most
-    expensive op on TPU (~7 ms per 1M updates, serialized emitter;
-    reformulations — segment_sum, sorted/unique hints, one wide scatter —
-    all measured equal or worse). A table whose consumer never reads a
-    column may skip its scatter: the 15-feature spec reads customer
-    (count, amount) and terminal (count, fraud) only, so the engine drops
-    one scatter per keyspace (§``features/online._update_state``). A
-    skipped column still gets the (cheap, full-table) stale-bucket reset,
-    so its buckets never mix days: it simply misses this batch's
-    contributions — safe even if a later update re-enables tracking.
+    ``track_amount`` / ``track_fraud``: a scatter of 65,536 rows costs
+    ~6 ms a column on a v5e whatever the table's size (ledger, PR 24:
+    ``step_scatter_ms`` 25.9 for four; reformulations — segment_sum,
+    sorted/unique hints, one wide scatter — all measured equal or worse).
+    A table whose consumer never reads a column may skip its scatter: the
+    15-feature spec reads customer (count, amount) and terminal (count,
+    fraud) only, so the engine drops one scatter per keyspace
+    (§``features/online._update_state``). A skipped column still gets the
+    stale-bucket reset, so its buckets never mix days: it simply misses
+    this batch's contributions — safe even if a later update re-enables
+    tracking. That reset is a pass over the whole column and is not
+    cheap at a deployment's size: 8.6 ms a column of 2^23 slots, 25.2 ms
+    a step for the six columns of the benchmark's two tables (ledger,
+    PR 24: ``step_reset_ms``).
     """
     nb = state.n_buckets
-    cap = state.capacity
     with step_scope("update"):
         bucket = jnp.remainder(day, nb)
         flat = (slot * nb + bucket).astype(jnp.int32)
         # invalid rows stamp -1 which never wins
         day_in = jnp.where(valid, day, -1).astype(jnp.int32)
-
-        with step_scope("relayout"):
-            bd = state.bucket_day.reshape(-1)
-            count = state.count.reshape(-1)
-            amt = state.amount.reshape(-1)
-            frd = state.fraud.reshape(-1)
+        bd, count, amt, frd = state.columns()
 
         # Day stamp each touched bucket with max(existing, incoming).
         with step_scope("stamp"):
@@ -127,13 +224,7 @@ def update_windows(
             if track_fraud:
                 frd = frd.at[flat].add(fraud * w)
 
-        with step_scope("relayout"):
-            return WindowState(
-                bucket_day=new_bd.reshape(cap, nb),
-                count=count.reshape(cap, nb),
-                amount=amt.reshape(cap, nb),
-                fraud=frd.reshape(cap, nb),
-            )
+        return WindowState(new_bd, count, amt, frd, n_buckets=nb)
 
 
 def gather_state_rows(
@@ -142,12 +233,7 @@ def gather_state_rows(
     """One row-gather per table: (bucket_day, count, amount, fraud)[slot],
     each [B, NB]. The single embedding-style gather the query needs."""
     with step_scope("query"), step_scope("gather"):
-        return (
-            state.bucket_day[slot],
-            state.count[slot],
-            state.amount[slot],
-            state.fraud[slot],
-        )
+        return state.rows(slot)
 
 
 def query_gathered(

@@ -29,6 +29,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from real_time_fraud_detection_system_tpu.features.online import FeatureState
+from real_time_fraud_detection_system_tpu.ops.windows import COLUMNS
 
 
 def compat_shard_map(f, mesh: Mesh, in_specs, out_specs):
@@ -137,12 +138,12 @@ def shard_feature_state(
 
     ``axis`` may be one mesh axis name or a tuple (hybrid DCN×ICI meshes,
     see :mod:`.distributed`)."""
-    row_sharded = NamedSharding(mesh, P(axis, None))
     dev_sharded = NamedSharding(mesh, P(axis))
     n_dev = int(mesh.devices.size)
 
     def place_windows(ws):
-        return jax.tree.map(lambda a: jax.device_put(a, row_sharded), ws)
+        # flat slot-major columns: a shard is cap/n · NB contiguous entries
+        return jax.tree.map(lambda a: jax.device_put(a, dev_sharded), ws)
 
     def place_sketch(cms):
         if cms is None:
@@ -194,6 +195,12 @@ def shard_feature_state(
         terminal_dir=place_dir(state.terminal_dir, "terminal_dir"),
         terminal_cms=place_sketch(state.terminal_cms),
     )
+
+
+def _host_tables(ws):
+    """A window state's four columns as host ``[cap, NB]`` arrays (a flat
+    slot-major column reshapes to rows for free in NumPy)."""
+    return jax.tree.map(np.asarray, ws).tables()
 
 
 def _layout_perm(cap: int, n_dev: int) -> np.ndarray:
@@ -265,8 +272,8 @@ def reshard_feature_state(
         p_old = _layout_perm(cap, n_old)
         p_new = _layout_perm(cap, n_new)
 
-        def re(leaf):
-            a = np.asarray(leaf)
+        def re(table):
+            a = np.asarray(table)
             if a.shape[0] != cap:
                 raise ValueError(
                     f"state table has {a.shape[0]} rows, config says "
@@ -276,7 +283,7 @@ def reshard_feature_state(
             out[p_new] = a[p_old]
             return out
 
-        return jax.tree.map(re, ws)
+        return type(ws).from_tables(*map(re, _host_tables(ws)))
 
     cms = _merge_sketch(state.cms, n_old)
 
@@ -398,7 +405,7 @@ def _rebuild_exact_table(name: str, ctx: str, ws_type, kd_type,
         fresh[new_rows] = src
         return fresh
 
-    ws_new = ws_type(**{k: rehome(k) for k in fills})
+    ws_new = ws_type.from_tables(**{k: rehome(k) for k in fills})
     # ---- rebuild the per-shard directories ------------------------------
     dir_cap_new = 2 * cap_local_new
     nkeys = np.full((n_new, dir_cap_new), EMPTY_KEY, np.uint32)
@@ -454,10 +461,10 @@ def _extract_exact_table(name: str, ws, kd, n_old: int, cap: int):
         raise ValueError(
             f"{name}_dir is laid out for {keys.shape[0]} shard(s), "
             f"caller says n_old={n_old}")
-    bd = np.asarray(ws.bucket_day)
-    if bd.shape[0] != cap:
+    tables = _host_tables(ws)
+    if tables[0].shape[0] != cap:
         raise ValueError(
-            f"state table has {bd.shape[0]} rows, config says "
+            f"state table has {tables[0].shape[0]} rows, config says "
             f"{cap} — re-sharding a checkpoint taken under a "
             "different capacity would merge or drop keys")
     cap_local_old = cap // n_old
@@ -465,9 +472,7 @@ def _extract_exact_table(name: str, ws, kd, n_old: int, cap: int):
     lkeys = keys[shard_idx, entry_idx]
     old_rows = (shard_idx * cap_local_old
                 + slots[shard_idx, entry_idx].astype(np.int64))
-    vals = {k: np.asarray(getattr(ws, k))[old_rows]
-            for k in ("bucket_day", "count", "amount", "fraud")}
-    return lkeys, vals
+    return lkeys, {k: t[old_rows] for k, t in zip(COLUMNS, tables)}
 
 
 def _reshard_exact(state: FeatureState, fcfg, n_old: int, n_new: int,
@@ -676,17 +681,15 @@ def merge_process_states(states, cfg, n_locals) -> FeatureState:
         owner = (np.arange(cap) % n_total) // n_local
         ws0 = getattr(singles[0], name)
 
-        def one(leaf_name):
-            leaves = [np.asarray(getattr(getattr(s, name), leaf_name))
-                      for s in singles]
-            merged = np.empty_like(leaves[0])
+        def one(*tables):  # one column's [cap, NB] table per process
+            merged = np.empty_like(tables[0])
             for p in range(n_proc):
                 m = owner == p
-                merged[m] = leaves[p][m]
+                merged[m] = tables[p][m]
             return merged
 
-        return type(ws0)(**{k: one(k) for k in
-                            ("bucket_day", "count", "amount", "fraud")})
+        return type(ws0).from_tables(*map(
+            one, *(_host_tables(getattr(s, name)) for s in singles)))
 
     return states[0]._replace(
         customer=combine("customer", fcfg.customer_capacity),

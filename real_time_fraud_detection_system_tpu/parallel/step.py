@@ -612,8 +612,10 @@ def make_sharded_step(
 
         in_specs = (
             FeatureState(
-                customer=spec_like(fstate_template.customer, P(axis, None)),
-                terminal=spec_like(fstate_template.terminal, P(axis, None)),
+                # flat slot-major columns: a shard is cap/n · NB
+                # contiguous entries
+                customer=spec_like(fstate_template.customer, P(axis)),
+                terminal=spec_like(fstate_template.terminal, P(axis)),
                 # Owner-sharded sketch: leading device axis (mesh.py).
                 cms=dev_stacked(fstate_template.cms),
                 customer_dir=dev_stacked(fstate_template.customer_dir),
@@ -748,16 +750,15 @@ def make_sharded_compact(
                 parts += (jax.tree.map(lambda x: x[None], payload),)
             return parts
 
-        row = P(axis, None)
         dev = P(axis)
         in_specs = (
-            spec_like(fstate.customer, row),
-            spec_like(fstate.terminal, row),
+            spec_like(fstate.customer, dev),
+            spec_like(fstate.terminal, dev),
             spec_like(fstate.customer_dir, dev) if has_cdir else None,
             spec_like(fstate.terminal_dir, dev),
             P(),
         )
-        out_specs = in_specs[:4] + (row,)
+        out_specs = in_specs[:4] + (P(axis, None),)
         if demote_slots > 0:
             out_specs += (_payload_spec(),)
         fn = compat_shard_map(local, mesh, in_specs, out_specs)
@@ -837,11 +838,10 @@ def make_sharded_promote(
                 stats[None],  # [1, 2, 2] → [n_dev, 2, 2]
             )
 
-        row = P(axis, None)
         dev = P(axis)
         in_specs = (
-            spec_like(fstate.customer, row),
-            spec_like(fstate.terminal, row),
+            spec_like(fstate.customer, dev),
+            spec_like(fstate.terminal, dev),
             spec_like(fstate.customer_dir, dev) if has_cdir else None,
             spec_like(fstate.terminal_dir, dev),
             _payload_spec(),

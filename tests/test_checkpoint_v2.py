@@ -528,3 +528,84 @@ def test_make_checkpointer_forwards_knobs(tmp_path):
 def test_corrupt_error_reasons_closed_set():
     with pytest.raises(AssertionError):
         CorruptCheckpointError("bogus")
+
+
+# -- window tables on disk: [cap, NB], whatever the state holds in memory ----
+
+
+def _window_engine_state(batches=2):
+    """A small engine's state after a few batches (flat window columns)."""
+    from real_time_fraud_detection_system_tpu.config import (
+        Config,
+        FeatureConfig,
+        RuntimeConfig,
+    )
+    from real_time_fraud_detection_system_tpu.runtime import ScoringEngine
+
+    cfg = Config(
+        features=FeatureConfig(customer_capacity=64, terminal_capacity=128),
+        runtime=RuntimeConfig(batch_buckets=(64,), max_batch_rows=64))
+    eng = ScoringEngine(cfg, kind="logreg", params=init_logreg(15),
+                        scaler=Scaler(mean=np.zeros(15, np.float32),
+                                      scale=np.ones(15, np.float32)))
+    rng = np.random.default_rng(7)
+    for b in range(batches):
+        us = ((20_000 + b) * 86400 + np.arange(50) * 60).astype(
+            np.int64) * 1_000_000
+        eng.process_batch({
+            "tx_id": np.arange(50, dtype=np.int64) + 1000 * b,
+            "tx_datetime_us": us,
+            "customer_id": rng.integers(0, 60, 50).astype(np.int64),
+            "terminal_id": rng.integers(0, 120, 50).astype(np.int64),
+            "tx_amount_cents": rng.integers(100, 90_000, 50).astype(
+                np.int64),
+            "kafka_ts_ms": us // 1000,
+        })
+    return eng.state
+
+
+def _as_parent_held_it(feature_state):
+    """The feature state as the commits before the flat layout held it —
+    and so wrote it: each window table a NamedTuple of four ``[cap, NB]``
+    arrays under the same field names (same pytree paths, same order)."""
+    from collections import namedtuple
+
+    tables = namedtuple("WindowState",
+                        ["bucket_day", "count", "amount", "fraud"])
+    return feature_state._replace(
+        customer=tables(*feature_state.customer.tables()),
+        terminal=tables(*feature_state.terminal.tables()))
+
+
+@pytest.mark.parametrize("written_by", ["parent", "this_commit"])
+def test_window_tables_are_cap_by_nb_on_disk(tmp_path, written_by):
+    """A checkpoint whose window leaves are ``[cap, NB]``, as the parent
+    commit writes them, restores into the flat state to the bit; this
+    commit's own file has the same leaf names, shapes, dtypes and CRCs, so
+    the parent restores it too."""
+    import dataclasses as dc
+
+    state = _window_engine_state()
+    as_parent = dc.replace(
+        state, feature_state=_as_parent_held_it(state.feature_state))
+    ck = Checkpointer(str(tmp_path / written_by))
+    man = ck.manifest(ck.save(
+        as_parent if written_by == "parent" else state))
+    nb = state.feature_state.customer.n_buckets
+    assert [man["spec"][f"fs_{i}"][0] for i in range(8)] == (
+        [[64, nb]] * 4 + [[128, nb]] * 4)
+    other = Checkpointer(str(tmp_path / "other"))
+    man_other = other.manifest(other.save(
+        state if written_by == "parent" else as_parent))
+    for key in ("spec", "crcs", "fingerprint", "stored"):
+        assert man[key] == man_other[key], key
+
+    template = _window_engine_state(batches=0)
+    assert all(c.ndim == 1 for c in template.feature_state.terminal.columns())
+    out = ck.restore(template)
+    assert out is not None and out.batches_done == state.batches_done
+    leaves_equal(out, state)
+    assert out.feature_state.terminal.n_buckets == nb
+    assert not np.array_equal(  # the batches did land in the tables
+        np.asarray(out.feature_state.terminal.count),
+        np.zeros(128 * nb, np.float32))

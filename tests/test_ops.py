@@ -139,6 +139,119 @@ def test_windows_invalid_rows_ignored():
     assert abs(float(a[0, 0]) - 2.0) < 1e-6
 
 
+class _TablesOracle:
+    """The window semantics on plain NumPy ``[cap, NB]`` tables, one batch
+    at a time: stamp = max(existing, the batch's valid days), a bucket
+    whose stamp advanced starts from zero, a row counts iff its day is the
+    bucket's stamp after the batch. Whole-dollar amounts in the cases
+    below, so every sum is exact in any order and the comparison is to
+    the bit."""
+
+    def __init__(self, cap, nb):
+        self.nb = nb
+        self.bd = np.full((cap, nb), -1, np.int32)
+        self.cnt, self.amt, self.frd = (
+            np.zeros((cap, nb), np.float32) for _ in range(3))
+
+    def update(self, slot, day, amount, fraud, valid,
+               track_amount=True, track_fraud=True):
+        old = self.bd.copy()
+        for s, d, v in zip(slot, day, valid):
+            if v:
+                self.bd[s, d % self.nb] = max(self.bd[s, d % self.nb], d)
+        for t in (self.cnt, self.amt, self.frd):
+            t[self.bd > old] = 0.0
+        for s, d, a, f, v in zip(slot, day, amount, fraud, valid):
+            b = d % self.nb
+            if v and self.bd[s, b] == d:
+                self.cnt[s, b] += 1.0
+                if track_amount:
+                    self.amt[s, b] += a
+                if track_fraud:
+                    self.frd[s, b] += f
+
+    def query(self, slot, day, windows, delay=0):
+        age = day[:, None] - delay - self.bd[slot]
+        live = (self.bd[slot] >= 0) & (age >= 0)
+        return tuple(
+            np.stack([(t[slot] * (live & (age < w))).sum(axis=1)
+                      for w in windows], axis=1).astype(np.float32)
+            for t in (self.cnt, self.amt, self.frd))
+
+    def tables(self):
+        return self.bd, self.cnt, self.amt, self.frd
+
+
+def _rows(*rows):
+    """(slot, day, amount, fraud, valid) rows → one batch of columns."""
+    slot, day, amt, frd, valid = zip(*rows)
+    return (np.asarray(slot, np.int32), np.asarray(day, np.int32),
+            np.asarray(amt, np.float32), np.asarray(frd, np.float32),
+            np.asarray(valid, bool))
+
+
+_NB = 8  # the cases' ring: day d and day d + 8 share a bucket
+# case → (batches, update_windows keywords)
+_FLAT_STATE_CASES = {
+    "duplicate_slot_day_rows": ([
+        _rows((3, 100, 5, 1, True), (3, 100, 7, 0, True),
+              (3, 100, 2, 1, True), (9, 100, 4, 0, True),
+              (3, 101, 1, 0, True)),
+        _rows((3, 100, 3, 0, True), (3, 100, 3, 1, True))], {}),
+    "ring_wrap_in_one_batch": ([
+        _rows((2, 100, 5, 0, True), (2, 108, 7, 1, True),
+              (5, 100, 2, 0, True), (2, 108, 1, 0, True))], {}),
+    "ring_wrap_across_batches": ([
+        _rows((2, 100, 5, 1, True), (5, 103, 2, 0, True)),
+        _rows((2, 108, 7, 0, True), (5, 104, 3, 1, True)),
+        _rows((2, 116, 1, 1, True), (2, 109, 6, 0, True))], {}),
+    "late_rows_are_dropped": ([
+        _rows((4, 108, 5, 0, True), (6, 107, 2, 1, True)),
+        _rows((4, 100, 9, 1, True), (6, 99, 9, 1, True),
+              (4, 108, 1, 1, True), (6, 106, 4, 0, True))], {}),
+    "invalid_rows": ([
+        _rows((1, 100, 5, 1, True), (1, 100, 9, 1, False),
+              (1, 108, 9, 1, False), (7, 101, 9, 0, False)),
+        _rows((1, 116, 9, 1, False), (1, 100, 2, 0, True))], {}),
+    "amount_not_tracked": ([
+        _rows((2, 100, 5, 1, True), (2, 100, 7, 0, True)),
+        _rows((2, 108, 3, 1, True), (3, 100, 4, 1, True))],
+        {"track_amount": False}),
+    "fraud_not_tracked": ([
+        _rows((2, 100, 5, 1, True), (2, 100, 7, 0, True)),
+        _rows((2, 108, 3, 1, True), (3, 100, 4, 1, True))],
+        {"track_fraud": False}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FLAT_STATE_CASES))
+def test_flat_state_matches_tables_oracle(case):
+    """``update_windows`` + ``query_windows`` on the flat slot-major state
+    against the ``[cap, NB]`` oracle: the state after every batch, and
+    every window of every touched slot at several days."""
+    import jax
+
+    batches, kw = _FLAT_STATE_CASES[case]
+    cap, windows = 16, (1, 3, _NB)
+    state = init_window_state(cap, _NB)
+    assert state.capacity == cap and state.n_buckets == _NB
+    assert all(c.shape == (cap * _NB,) for c in state.columns())
+    oracle = _TablesOracle(cap, _NB)
+    update = jax.jit(lambda st, *cols: update_windows(st, *cols, **kw))
+    for cols in batches:
+        state = update(state, *map(jnp.asarray, cols))
+        oracle.update(*cols, **kw)
+        for got, want in zip(state.tables(), oracle.tables()):
+            np.testing.assert_array_equal(np.asarray(got), want)
+    slot = np.repeat(np.arange(cap, dtype=np.int32), 4)
+    day = np.tile(np.asarray([100, 108, 110, 117], np.int32), cap)
+    for delay in (0, 2):
+        got = query_windows(state, jnp.asarray(slot), jnp.asarray(day),
+                            windows, delay=delay)
+        for g, w in zip(got, oracle.query(slot, day, windows, delay)):
+            np.testing.assert_array_equal(np.asarray(g), w)
+
+
 def test_cms_overestimates_and_windows(rng):
     sk = cms_init(depth=4, width=1 << 10, n_days=8)
     keys = rng.integers(0, 50, 400).astype(np.uint32)

@@ -849,8 +849,8 @@ def test_state_feedback_after_cross_width_restore(small_dataset, tmp_path):
 
     cap = cfg.features.terminal_capacity
     p8 = _layout_perm(cap, N_DEV)
-    a = np.asarray(eng1.state.feature_state.terminal.fraud)
-    b = np.asarray(eng8.state.feature_state.terminal.fraud)
+    a, b = (np.asarray(e.state.feature_state.terminal.tables()[3])
+            for e in (eng1, eng8))  # the [cap, NB] fraud tables
     np.testing.assert_array_equal(a, b[p8])  # single[k] == mesh[perm[k]]
 
 

@@ -506,9 +506,10 @@ def test_reshard_exact_roundtrip_preserves_admitted_state():
     slot_a, hit_a = lookup_slots(st.terminal_dir, keys, valid)
     slot_b, hit_b = lookup_slots(s1.terminal_dir, keys, valid)
     np.testing.assert_array_equal(np.asarray(hit_a), np.asarray(hit_b))
-    for leaf in ("bucket_day", "count", "fraud"):
-        a = np.asarray(getattr(st.terminal, leaf))[np.asarray(slot_a)]
-        b = np.asarray(getattr(s1.terminal, leaf))[np.asarray(slot_b)]
+    for leaf, ta, tb in zip(("bucket_day", "count", "amount", "fraud"),
+                            st.terminal.tables(), s1.terminal.tables()):
+        a = ta[np.asarray(slot_a)]
+        b = tb[np.asarray(slot_b)]
         np.testing.assert_array_equal(
             a[np.asarray(hit_a)], b[np.asarray(hit_b)], err_msg=leaf)
     assert int(np.asarray(st.terminal_dir.free_top)) == int(

@@ -52,7 +52,10 @@ from real_time_fraud_detection_system_tpu.utils.trace import (
 
 PKG = "real_time_fraud_detection_system_tpu"
 TABLE = {"customer", "terminal"}
-UPDATE = {"update", "relayout", "stamp", "reset", "scatter"}
+# `relayout` is in the vocabulary and in no variant: the window columns
+# are stored flat, the layout `update_windows` works in, so the step has
+# nothing to open it around (benchmark/metrics/step_relayout_ms.* read 0)
+UPDATE = {"update", "stamp", "reset", "scatter"}
 QUERY = {"query", "gather", "sum"}
 COMMON = {"unpack", "assemble", "scale", "classify"} | TABLE | UPDATE | QUERY
 
@@ -122,10 +125,9 @@ def test_step_hlo_carries_the_variants_scopes(variant):
         assert want <= said, sorted(want - said)
         compiled = _op_names(low.compile().as_text())
         kept = {s for n in compiled for s in _scopes(n)}
-        # the compiler keeps the names on what it keeps of the ops (on the
-        # CPU a flat reshape is a bitcast, so `relayout` may be gone)
-        assert (want - {"relayout", "unpack"}) <= kept, \
-            sorted(want - kept)
+        assert "relayout" in STEP_SCOPES and "relayout" not in said
+        # the compiler keeps the names on what it keeps of the ops
+        assert (want - {"unpack"}) <= kept, sorted(want - kept)
         updates = [_scopes(n) for n in compiled if "rtfds.update" in n]
         assert updates
         for path in updates:

@@ -19,6 +19,7 @@ described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -106,8 +107,14 @@ def _packed(rows, sharding):
     return jax.ShapeDtypeStruct((7, rows), jnp.int32, sharding=sharding)
 
 
-@pytest.mark.parametrize("bucket", [256, 4096, 65536])
-def test_xla_forest_step_compiles(topo, one_chip, as_on_chip, bucket):
+@pytest.fixture(scope="module")
+def compiled_steps():
+    """(kind, bucket) → the engine's compiled step: two tests read the
+    forest's, and a compile at this size takes a quarter of a minute."""
+    return {}
+
+
+def _compiled_step(cache, one_chip, kind, bucket):
     """The DEFAULT served step (`rtfds score`, no flags) of the engine
     itself, at 2^20 + 2^21 state slots and the on-chip z_mode."""
     from real_time_fraud_detection_system_tpu.config import (
@@ -117,29 +124,83 @@ def test_xla_forest_step_compiles(topo, one_chip, as_on_chip, bucket):
     from real_time_fraud_detection_system_tpu.models.forest import (
         resolve_z_mode,
     )
+    from real_time_fraud_detection_system_tpu.models.logreg import (
+        init_logreg,
+    )
     from real_time_fraud_detection_system_tpu.models.scaler import Scaler
     from real_time_fraud_detection_system_tpu.runtime import ScoringEngine
 
+    if (kind, bucket) in cache:
+        return cache[kind, bucket]
     assert resolve_z_mode("auto") == "int8"  # what the chip resolves
     fcfg = _fcfg()
     cfg = Config(features=fcfg, runtime=RuntimeConfig(
         z_mode="int8", batch_buckets=(bucket,), max_batch_rows=bucket))
     eng = ScoringEngine(
-        cfg, kind="forest", params=_forest(),
+        cfg, kind=kind,
+        params=_forest() if kind == "forest" else init_logreg(N_FEAT),
         scaler=Scaler(mean=np.zeros(N_FEAT, np.float32),
                       scale=np.ones(N_FEAT, np.float32)),
         # shapes only: nothing is allocated
         feature_state=_on(one_chip, _state_shapes(fcfg)))
     (sig,) = eng.dispatch_inventory()
-    assert sig.bucket == bucket and sig.z_mode == "int8"
-    compiled = eng.signature_step(sig).lower(
+    assert sig.bucket == bucket
+    cache[kind, bucket] = eng.signature_step(sig).lower(
         *_on(one_chip, eng.signature_templates(sig))).compile()
+    return cache[kind, bucket]
+
+
+@pytest.mark.parametrize("bucket", [256, 4096, 65536])
+def test_xla_forest_step_compiles(topo, one_chip, as_on_chip,
+                                  compiled_steps, bucket):
+    compiled = _compiled_step(compiled_steps, one_chip, "forest", bucket)
     mem = compiled.memory_analysis()
     # the state alone is ~1.9 GB of arguments; all of it fits one chip
     assert mem.argument_size_in_bytes > 1.8e9
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < 16e9
     assert "tpu_custom_call" not in compiled.as_text()  # pure XLA
+
+
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
+_LAYOUT_MOVES = {"copy", "reshape", "transpose", "dynamic-update-slice"}
+
+
+def whole_column_moves(hlo_text, column_sizes):
+    """``(op, dtype, dims)`` of every instruction, fused ones included,
+    that moves a table column as a whole: a ``copy`` / ``reshape`` /
+    ``transpose`` / ``dynamic-update-slice`` whose result has as many
+    elements as a column of either table."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m or m.group(3) not in _LAYOUT_MOVES:
+            continue
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        if int(np.prod(dims)) in column_sizes:
+            out.append((m.group(3), m.group(1), tuple(dims)))
+    return out
+
+
+@pytest.mark.parametrize("bucket", [4096, 65536])
+@pytest.mark.parametrize("kind", ["forest", "logreg"])
+def test_step_moves_no_table_between_layouts(topo, one_chip, as_on_chip,
+                                             compiled_steps, kind, bucket):
+    """The window columns are stored in the layout their update works in
+    (flat, slot-major), so the chip's compiler has no column to re-lay
+    out: before PR 25 each stored [cap, 40] column went copy → reshape →
+    update → reshape → copy, 206.7 of a 305.4 ms step at the benchmark's
+    size (PERF.md). What may remain, a table: two flat int32 copies of
+    the day stamps around the scatter-max (`advanced = new > old` needs
+    the old ones)."""
+    fcfg = _fcfg()
+    nb = fcfg.n_day_buckets
+    text = _compiled_step(compiled_steps, one_chip, kind, bucket).as_text()
+    for cap in (fcfg.customer_capacity, fcfg.terminal_capacity):
+        moves = whole_column_moves(text, {cap * nb})
+        assert all(m == ("copy", "s32", (cap * nb,)) for m in moves), moves
+        assert len(moves) <= 2, moves
 
 
 @pytest.mark.parametrize("z_mode", ["f32", "bf16", "int8"])
@@ -227,10 +288,10 @@ def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
     cfg = Config(features=fcfg)
     rows_per_shard = 2 * (cfg.runtime.max_batch_rows // n_dev)
     fstate = _state_shapes(fcfg)
-    rows = NamedSharding(mesh, P("data", None))
+    slots = NamedSharding(mesh, P("data"))  # flat slot-major columns
     rep = NamedSharding(mesh, P())
-    fstate = fstate._replace(customer=_on(rows, fstate.customer),
-                             terminal=_on(rows, fstate.terminal))
+    fstate = fstate._replace(customer=_on(slots, fstate.customer),
+                             terminal=_on(slots, fstate.terminal))
     params, scaler = _on(rep, _forest()), Scaler(mean=_vec(rep),
                                                  scale=_vec(rep))
     packed = _packed(n_dev * rows_per_shard,

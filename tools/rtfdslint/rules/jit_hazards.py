@@ -441,7 +441,8 @@ def _identity_test(test: ast.AST) -> bool:
 def _static_property_names(project: Project) -> Set[str]:
     """Names of @property methods whose body derives only from shapes.
 
-    ``WindowState.capacity`` → ``self.bucket_day.shape[0]`` is static
+    ``WindowState.capacity`` → ``self.bucket_day.shape[0] //
+    self.n_buckets`` (a shape over static pytree metadata) is static
     under trace; accessing ``.capacity`` on a traced state launders
     taint. Name-based across the package (documented approximation):
     a name qualifies only if EVERY property of that name in the
