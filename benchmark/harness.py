@@ -301,6 +301,29 @@ def sink_numbers(batch_ids: Dict[int, np.ndarray], rows_acked: int,
     ]
 
 
+def steadiness_of(ack_t: List[float], write_s: List[float],
+                  poll_s: List[float]) -> dict:
+    """Where a stall would show, in milliseconds: the waits between the
+    window's acknowledgements (median, 95th percentile, longest and its
+    place, how many exceed 1.5 medians), its sink writes and its polls.
+    Empty under two acknowledgements."""
+    if len(ack_t) < 2:
+        return {}
+    gaps = np.diff(ack_t) * 1e3
+    p50 = percentile(gaps, 50)
+    out = {"ack_gap_p50_ms": p50,
+           "ack_gap_p95_ms": percentile(gaps, 95),
+           "ack_gap_max_ms": float(gaps.max()),
+           "ack_gap_max_at": int(gaps.argmax()) + 1,
+           "ack_gaps_over_1p5_p50": int((gaps > 1.5 * p50).sum())}
+    for name, secs in (("sink_write", write_s), ("poll", poll_s)):
+        ms = np.asarray(secs) * 1e3
+        out.update({f"{name}_p50_ms": percentile(ms, 50),
+                    f"{name}_p95_ms": percentile(ms, 95),
+                    f"{name}_max_ms": float(ms.max())})
+    return out
+
+
 # -- one run -----------------------------------------------------------------
 
 
@@ -441,15 +464,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             drain_s=round(time.perf_counter() - t_close, 3),
             queue=json.dumps(queue_stats))
         say("run_stats", **{k: v for k, v in run_stats.items()})
-        if len(acks) >= 2:
-            # where a stall would show: the longest wait between two
-            # acknowledgements, the longest sink write, the longest poll
-            gaps = np.diff([t for t, _ in acks])
-            say("steadiness", ack_gap_p50_ms=float(np.median(gaps) * 1e3),
-                ack_gap_max_ms=float(gaps.max() * 1e3),
-                ack_gap_max_at=int(gaps.argmax()) + 1,
-                sink_write_max_ms=max(sink.write_s[n_fill_done:]) * 1e3,
-                poll_max_ms=max(window.poll_s) * 1e3)
+        steadiness = steadiness_of([t for t, _ in acks],
+                                   sink.write_s[n_fill_done:], window.poll_s)
+        if steadiness:
+            say("steadiness", **steadiness)
 
         # -- correct -----------------------------------------------------
         t5 = time.perf_counter()
@@ -501,7 +519,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 cell, own_trace_dir, traced, done, device, {
                     "run_stats": run_stats, "registry_before": before,
                     "registry_after": after,
-                    "queue_stats": queue_stats,
+                    "queue_stats": queue_stats, "steadiness": steadiness,
                     "device_memory": {"peak_bytes_in_use": peak}}))
         result["device"] = device
         result["checks"] = numbers

@@ -1,6 +1,9 @@
 """The reference's LogisticRegression baseline (model_training.ipynb cell
-50) on the same 15 features: seeded weights on standardized features. The
-plain reference is the logistic of a float64 dot product."""
+50) on the same 15 features: weights on standardized features with the
+configuration's fixed signs and seeded sizes (the sink's cost follows the
+signs: a probability column that saturates at 1.0 has few distinct values,
+one that falls toward 0 has one per row). The plain reference is the
+logistic of a float64 dot product."""
 
 from __future__ import annotations
 
@@ -20,8 +23,11 @@ def build(config: dict, seed: int) -> dict:
     mp = config["model_params"]
     _, _, mean, scale = synthetic_rows(config, seed)
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0x106]))
-    w = rng.normal(0.0, float(mp["weight_std"]),
-                   int(mp["n_features"])).astype(np.float32)
+    lo, hi = mp["weight_abs_range"]
+    signs = np.asarray(mp["weight_signs"], np.float64)
+    if signs.shape != (int(mp["n_features"]),) or set(signs) - {1.0, -1.0}:
+        raise ValueError("weight_signs must be n_features values of +-1")
+    w = (signs * rng.uniform(lo, hi, len(signs))).astype(np.float32)
     b = np.float32(mp["bias"])
 
     def reference_proba(features: np.ndarray,

@@ -1,6 +1,5 @@
 """The traffic generator: seeded, real envelopes, open-loop polls."""
 
-import json
 import os
 
 import numpy as np
@@ -106,8 +105,73 @@ def test_late_rows_are_refused_not_ignored():
         gen.build(dict(traffic, late_share=0.02), config, 1, 1.0, _decode)
 
 
-def test_steady_rate_is_a_number_in_its_file():
-    traffic = json.load(open(os.path.join(
-        ROOT, "benchmark", "traffic", "steady-0.8.json")))
-    assert traffic["rate_rows_per_s"] > 0
-    assert traffic["rate_rows_per_s"] % 10_000 == 0
+def _poisson_traffic_files():
+    import glob
+
+    files = sorted(glob.glob(os.path.join(ROOT, "benchmark", "traffic",
+                                          "*.json")))
+    return [os.path.basename(f) for f in files
+            if harness.load_json(f).get("arrivals") == "poisson"]
+
+
+def _rate_faults(rate: float, pass_ms_by_bucket: dict, buckets) -> list:
+    """What is wrong with an open-loop rate, given the measured time of one
+    pass of the loop in each bucket of the ladder the rate can reach: the
+    rows a pass collects must run a listed bucket, lie at least 20 % of a
+    rung away from every rung (so that a pass a few ms longer or shorter
+    runs the same program), and exactly one bucket may feed itself."""
+    faults, own = [], []
+    passes = {int(b): ms for b, ms in pass_ms_by_bucket.items()}
+    for bucket, ms in sorted(passes.items()):
+        rows = rate * ms / 1e3
+        for rung in buckets:
+            if abs(rows - rung) < 0.2 * rung:
+                faults.append(f"a {ms} ms pass of the {bucket} bucket "
+                              f"collects {rows:.0f} rows, within 20 % of "
+                              f"the {rung} rung")
+        runs = next((b for b in buckets if rows <= b), None)
+        if runs not in passes:
+            faults.append(f"{rows:.0f} rows run the {runs} bucket, which "
+                          "has no measured pass time")
+        if runs == bucket:
+            own.append(bucket)
+    if len(own) != 1:
+        faults.append(f"buckets that feed themselves: {own}, not one")
+    return faults
+
+
+@pytest.mark.parametrize("name", _poisson_traffic_files())
+def test_open_loop_rate_is_derived_and_has_one_state(name):
+    """An open-loop mix states where its rate comes from (`derived_from`:
+    knee, commit, date, share, the ladder and the measured pass of every
+    bucket the rate can reach), the rate is share x knee rounded down to
+    10,000, and the loop has one self-sustaining batch size at it. PR 23's
+    170,000 rows/s fails this with the pass times read since PR 25 (see
+    the next test): it sat 1.7 % above the 16,384 rung."""
+    from real_time_fraud_detection_system_tpu.config import RuntimeConfig
+
+    traffic = harness.load_json(os.path.join(ROOT, "benchmark", "traffic",
+                                             name))
+    d = traffic["derived_from"]
+    for key in ("knee_rows_per_s", "knee_cell", "commit", "date", "share",
+                "batch_buckets", "pass_ms_by_bucket"):
+        assert key in d, key
+    rate = traffic["rate_rows_per_s"]
+    assert rate == int(d["share"] * d["knee_rows_per_s"] // 10_000) * 10_000
+    assert 0.0 < d["share"] < 1.0
+    buckets = RuntimeConfig().batch_buckets
+    assert tuple(d["batch_buckets"]) == tuple(buckets)
+    assert _rate_faults(rate, d["pass_ms_by_bucket"], buckets) == []
+
+
+def test_the_rule_refuses_pr23s_rate_at_todays_pass_times():
+    """170,000 rows/s with the passes PR 25 read (53.9 ms in the 16,384
+    bucket, 98 ms in the 65,536 one): two buckets feed themselves and one
+    sits on a rung. 0.8 of PR 23's knee at PR 23's ~301 ms pass was sound."""
+    from real_time_fraud_detection_system_tpu.config import RuntimeConfig
+
+    buckets = RuntimeConfig().batch_buckets
+    faults = _rate_faults(170_000, {"16384": 53.9, "65536": 98.0}, buckets)
+    assert any("16384 rung" in f for f in faults), faults
+    assert any("[16384, 65536]" in f for f in faults), faults
+    assert _rate_faults(170_000, {"65536": 301.0}, buckets) == []

@@ -112,14 +112,17 @@ def test_a_cell_a_metric_and_a_reader_added_as_new_files_only(tmp_path):
     m["per_layer"].append({"name": "toy_batches.sat", "unit": "count",
                            "better": "higher", "source": "program_counter",
                            "layer": "entry queue", "moves": "rows_per_s"})
-    next(x for x in m["end_to_end"] if x["name"] == "rows_per_s")[
-        "workloads"].append("toy.cell")
+    # every metric lists its cells: the new cell joins the ones it reports
+    for x in m["end_to_end"] + m["per_layer"]:
+        if x["name"] in ("rows_per_s", "source_poll_ms.sat"):
+            x["workloads"].append("toy.cell")
     json.dump(m, open(os.path.join(root, "BENCHMARK.json"), "w"))
 
     cell = harness.Cell(root, harness.load_manifest(root), "toy.cell")
     assert [x["name"] for x in cell.end_to_end()] == ["rows_per_s", "setup_s"]
     layer = {x["name"]: x for x in cell.per_layer()}
     assert "toy_batches.sat" in layer and "source_poll_ms.sat" in layer
+    assert "sink_write_ms.sat" not in layer  # lists its cells, not this one
     spec = layer["toy_batches.sat"]
     assert cell.plugin("readers", spec["reader"]).read(
         {"run_stats": {"batches": 21}}, **spec["args"]) == 42
@@ -132,6 +135,44 @@ def test_a_cell_a_metric_and_a_reader_added_as_new_files_only(tmp_path):
              for dp, _, fs in os.walk(os.path.join(root, "benchmark"))
              for p in fs if "__pycache__" not in dp and ".cache" not in dp}
     assert all(after[p] == before[p] for p in before if p in after)
+
+
+def test_every_per_layer_metric_lists_the_cells_that_report_it():
+    """A cell added later (the four-chip one) is then not held to a metric
+    it has no reader for; and every cell reports the host loop's gaps."""
+    m = harness.load_manifest()
+    cells = {w["name"] for w in m["workloads"]}
+    for x in m["per_layer"]:
+        assert x.get("workloads") and set(x["workloads"]) <= cells, x["name"]
+    for name in cells:
+        cell = harness.Cell(ROOT, m, name)
+        mine = {x["name"]: x for x in cell.per_layer()}
+        gaps = [n for n in mine if n.startswith("ack_gap_")]
+        assert sorted(g.split(".")[0] for g in gaps) == [
+            "ack_gap_max_ms", "ack_gap_p50_ms"], name
+        assert all(mine[g]["layer"] == "host loop"
+                   and mine[g]["source"] == "host_clock" for g in gaps)
+
+
+def test_steadiness_of_a_window_and_its_reader():
+    """Five acknowledgements 100 ms apart but for one 400 ms stall: the
+    median gap ignores the stall, the longest gap is it, and the reader
+    hands a metric file's key over (nothing where nothing was read)."""
+    acks = [10.0, 10.1, 10.2, 10.6, 10.7]
+    st = harness.steadiness_of(acks, [0.05, 0.05, 0.25, 0.05],
+                               [0.03, 0.03, 0.03, 0.04])
+    assert st["ack_gap_p50_ms"] == pytest.approx(100.0)
+    assert st["ack_gap_max_ms"] == pytest.approx(400.0)
+    assert st["ack_gap_max_at"] == 3 and st["ack_gaps_over_1p5_p50"] == 1
+    assert st["sink_write_max_ms"] == pytest.approx(250.0)
+    assert st["poll_p50_ms"] == pytest.approx(30.0)
+    assert harness.steadiness_of([10.0], [0.05], [0.03]) == {}
+    m = harness.load_manifest()
+    cell = harness.Cell(ROOT, m, "forest.steady")
+    spec = {x["name"]: x for x in cell.per_layer()}["ack_gap_max_ms.steady"]
+    read = cell.plugin("readers", spec["reader"]).read
+    assert read({"steadiness": st}, **spec["args"]) == pytest.approx(400.0)
+    assert read({"steadiness": {}}, **spec["args"]) is None
 
 
 def test_no_workload_no_run():
