@@ -66,9 +66,14 @@ class FeatureState(NamedTuple):
 
 def init_feature_state(
     cfg: FeatureConfig, with_cms: Optional[bool] = None,
-    n_shards: int = 1,
+    n_shards: int = 1, window_sharding=None,
 ) -> FeatureState:
-    """``n_shards > 1`` builds the SHARDED exact layout: the window
+    """``window_sharding`` (a mesh's slot-axis ``NamedSharding``) creates
+    the window tables — all but a few MB of the state — already spread
+    over that mesh, each device allocating only its share
+    (:func:`~..parallel.mesh.init_sharded_feature_state` is the caller).
+
+    ``n_shards > 1`` builds the SHARDED exact layout: the window
     tables stay global ``[capacity · NB]`` columns (placed ``P(axis)``, so
     shard s owns slots ``[s*cap/n, (s+1)*cap/n)``), but each shard gets
     its OWN key directory over its local slot range — stacked
@@ -106,8 +111,10 @@ def init_feature_state(
         terminal_cms = cms_init(cfg.cms_depth, cfg.cms_width,
                                 cfg.n_day_buckets, track_fraud=True)
     return FeatureState(
-        customer=init_window_state(cfg.customer_capacity, cfg.n_day_buckets),
-        terminal=init_window_state(cfg.terminal_capacity, cfg.n_day_buckets),
+        customer=init_window_state(cfg.customer_capacity, cfg.n_day_buckets,
+                                   window_sharding),
+        terminal=init_window_state(cfg.terminal_capacity, cfg.n_day_buckets,
+                                   window_sharding),
         cms=cms_init(cfg.cms_depth, cfg.cms_width, cfg.n_day_buckets)
         if with_cms
         else None,

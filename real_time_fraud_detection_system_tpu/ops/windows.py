@@ -149,15 +149,27 @@ class WindowState:
             n_buckets=nb)
 
 
-def init_window_state(capacity: int, n_buckets: int) -> WindowState:
+def init_window_state(capacity: int, n_buckets: int,
+                      sharding=None) -> WindowState:
+    """An empty table. With ``sharding`` (the mesh's slot-axis
+    ``NamedSharding``) every device allocates only its own
+    ``capacity / n_dev · n_buckets`` entries of each column: the fill is
+    compiled with that output sharding, so no device ever holds a whole
+    column (a four-chip table may be larger than one chip)."""
     n = capacity * n_buckets
-    return WindowState(
-        bucket_day=jnp.full((n,), -1, dtype=jnp.int32),
-        count=jnp.zeros((n,), dtype=jnp.float32),
-        amount=jnp.zeros((n,), dtype=jnp.float32),
-        fraud=jnp.zeros((n,), dtype=jnp.float32),
-        n_buckets=n_buckets,
-    )
+
+    def empty() -> WindowState:
+        return WindowState(
+            bucket_day=jnp.full((n,), -1, dtype=jnp.int32),
+            count=jnp.zeros((n,), dtype=jnp.float32),
+            amount=jnp.zeros((n,), dtype=jnp.float32),
+            fraud=jnp.zeros((n,), dtype=jnp.float32),
+            n_buckets=n_buckets,
+        )
+
+    if sharding is None:
+        return empty()
+    return jax.jit(empty, out_shardings=sharding)()
 
 
 def update_windows(

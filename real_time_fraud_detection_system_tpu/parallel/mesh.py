@@ -28,7 +28,10 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from real_time_fraud_detection_system_tpu.features.online import FeatureState
+from real_time_fraud_detection_system_tpu.features.online import (
+    FeatureState,
+    init_feature_state,
+)
 from real_time_fraud_detection_system_tpu.ops.windows import COLUMNS
 
 
@@ -121,11 +124,37 @@ def cross_process_collectives_supported(mesh: Mesh) -> Optional[str]:
         raise
 
 
+def init_sharded_feature_state(
+    fcfg, mesh: Mesh, axis: "str | tuple[str, ...]" = "data"
+) -> FeatureState:
+    """A fresh state in this mesh's layout, built where it will live.
+
+    The window tables are created under the mesh's slot-axis sharding:
+    each device allocates its own ``capacity / n_dev · n_buckets`` slice
+    of every column and no device ever holds a whole one, so a mesh holds
+    as many slots as its chips have memory for together (2^24 + 2^25
+    slots, 32.2 GB, on four 16 GB chips). Sketches and key directories
+    (MBs) are built in the mesh-width layout and placed by
+    :func:`shard_feature_state`, which finds the tables already in place.
+    One path for every width and key mode, ``n_dev == 1`` included.
+    Bit-equal to ``shard_feature_state(init_feature_state(fcfg,
+    n_shards=n_dev), mesh)``."""
+    state = init_feature_state(
+        fcfg, n_shards=int(mesh.devices.size),
+        window_sharding=NamedSharding(mesh, P(axis)))
+    return shard_feature_state(state, mesh, axis=axis)
+
+
 def shard_feature_state(
     state: FeatureState, mesh: Mesh, axis: "str | tuple[str, ...]" = "data"
 ) -> FeatureState:
-    """Place window tables sharded along the slot axis; CMS sharded by
-    customer owner.
+    """Place a state over the mesh: window tables sharded along the slot
+    axis; CMS sharded by customer owner. A leaf that already has the
+    mesh's sharding (a state from :func:`init_sharded_feature_state`, or
+    one placed before) stays where it is — ``device_put`` to the sharding
+    an array has copies nothing — so this is also the cheap "make sure"
+    after a restore; a *provided* state (elastic recovery, a checkpoint)
+    is spread from wherever it was built.
 
     The sketch gets a leading device axis ([n_dev, ND, depth, width]):
     rows are partitioned by ``customer_id % n_dev``, so each device keeps

@@ -32,6 +32,7 @@ pass per shard under the same ``shard_map``.
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Callable, Optional, Tuple
 
@@ -169,6 +170,16 @@ def _route(
     return dest * b + rank, rank
 
 
+@contextlib.contextmanager
+def _exchange_scope(part: str):
+    """``rtfds.exchange/rtfds.<part>``: what the exchange makes a device
+    do around its two all_to_alls (``route``: ranking rows by owner,
+    ``pack``: filling the send buffer, ``unpack``: reading the receive
+    buffer and the back-gather), so a trace prices the exchange whole."""
+    with step_scope("exchange"), step_scope(part):
+        yield
+
+
 def _make_xchg(axis, n_dev: int, cap: int):
     """The bucketed all_to_all: [n_dev·cap, ...] laid out owner-major →
     same shape with bucket b holding what every peer sent to owner b.
@@ -224,7 +235,12 @@ def make_sharded_step(
     """Build the jitted multi-chip step.
 
     step(feature_state, params, scaler, batch) -> (feature_state, params,
-    probs, features); batch leaves are [n_dev*B_local] sharded on axis 0.
+    probs, features[, tier rows in exact mode], overflows); batch leaves
+    are [n_dev*B_local] sharded on axis 0. ``overflows``, always last, is
+    one int32 scalar: how many of this step's exchanges took the
+    full-capacity branch (0 when every (sender, owner) pair fitted its
+    bucket). It is the psum'd flag the ``lax.cond`` already branches on;
+    the serving engine counts it (``rtfds_exchange_overflow_total``).
 
     ``packed=True`` makes the built step take ONE ``[7, n_dev*B_local]``
     int32 array (:func:`~..core.batch.pack_batch` layout) instead of a
@@ -292,7 +308,8 @@ def make_sharded_step(
             """Route (key, day, amount, fraud, valid) to the key's owner
             device, run ``fn(state, key, day, amount, fraud, valid) ->
             (state', mat)`` there, and route ``mat``'s per-row aggregates
-            back to the sending rows: → (state', local_mat [bl, K]).
+            back to the sending rows: → (state', local_mat [bl, K],
+            overflowed int32 scalar: 1 when the full-capacity branch ran).
 
             Wire format: ONE all_to_all carries the 5 forward fields as
             a packed [*, 5] uint32 matrix (32-bit fields travel as bit
@@ -316,32 +333,36 @@ def make_sharded_step(
             exchange takes the same branch everywhere and the collectives
             inside stay matched. Exactness is never capacity-dependent.
             """
+            no_overflow = jnp.zeros((), jnp.int32)
             if n_dev == 1:
                 # Width-1 mesh: every key is owner-local already; the
                 # exchange machinery is pure overhead (measured as most
                 # of the round-4 29% single-device tax).
                 return fn(state, key, batch.day, batch.amount, fraud,
-                          batch.valid)
-            dest = (key % jnp.uint32(n_dev)).astype(jnp.int32)
-            # Rank VALID rows only (invalid rows sort into a trailing
-            # pseudo-bucket): padding never inflates a valid row's rank
-            # into a spurious overflow fallback, never occupies receive
-            # slots, and the compact branch's efficiency stops depending
-            # on partition_batch_spill's valid-rows-first layout.
-            _, rank = _route(
-                jnp.where(batch.valid, dest, n_dev).astype(jnp.int32),
-                batch.valid, n_dev)
-            pk = jnp.stack(
-                [
-                    key,
-                    jax.lax.bitcast_convert_type(batch.day, jnp.uint32),
-                    jax.lax.bitcast_convert_type(
-                        batch.amount, jnp.uint32),
-                    jax.lax.bitcast_convert_type(fraud, jnp.uint32),
-                    batch.valid.astype(jnp.uint32),
-                ],
-                axis=1,
-            )
+                          batch.valid) + (no_overflow,)
+            with _exchange_scope("route"):
+                dest = (key % jnp.uint32(n_dev)).astype(jnp.int32)
+                # Rank VALID rows only (invalid rows sort into a trailing
+                # pseudo-bucket): padding never inflates a valid row's
+                # rank into a spurious overflow fallback, never occupies
+                # receive slots, and the compact branch's efficiency stops
+                # depending on partition_batch_spill's valid-rows-first
+                # layout.
+                _, rank = _route(
+                    jnp.where(batch.valid, dest, n_dev).astype(jnp.int32),
+                    batch.valid, n_dev)
+            with _exchange_scope("pack"):
+                pk = jnp.stack(
+                    [
+                        key,
+                        jax.lax.bitcast_convert_type(batch.day, jnp.uint32),
+                        jax.lax.bitcast_convert_type(
+                            batch.amount, jnp.uint32),
+                        jax.lax.bitcast_convert_type(fraud, jnp.uint32),
+                        batch.valid.astype(jnp.uint32),
+                    ],
+                    axis=1,
+                )
 
             def run(b_pair):
                 def go(st):
@@ -351,32 +372,39 @@ def make_sharded_step(
                     # because the capacity branch is only taken when no
                     # VALID row overflows and invalid rows are masked
                     # downstream
-                    pos = jnp.where(
-                        batch.valid & (rank < b_pair),
-                        dest * b_pair + rank, n_dev * b_pair)
+                    with _exchange_scope("pack"):
+                        pos = jnp.where(
+                            batch.valid & (rank < b_pair),
+                            dest * b_pair + rank, n_dev * b_pair)
+                        send = jnp.zeros((n_dev * b_pair, 5),
+                                         jnp.uint32).at[pos].set(pk)
                     xchg = _make_xchg(axis, n_dev, b_pair)
-                    r = xchg(jnp.zeros((n_dev * b_pair, 5), jnp.uint32)
-                             .at[pos].set(pk))
-                    st, mat = fn(
-                        st,
-                        r[:, 0],
-                        jax.lax.bitcast_convert_type(r[:, 1], jnp.int32),
-                        jax.lax.bitcast_convert_type(r[:, 2],
-                                                     jnp.float32),
-                        jax.lax.bitcast_convert_type(r[:, 3],
-                                                     jnp.float32),
-                        r[:, 4].astype(bool),
-                    )
-                    return st, xchg(mat)[pos]
+                    r = xchg(send)
+                    with _exchange_scope("unpack"):
+                        got = (
+                            r[:, 0],
+                            jax.lax.bitcast_convert_type(r[:, 1], jnp.int32),
+                            jax.lax.bitcast_convert_type(r[:, 2],
+                                                         jnp.float32),
+                            jax.lax.bitcast_convert_type(r[:, 3],
+                                                         jnp.float32),
+                            r[:, 4].astype(bool),
+                        )
+                    st, mat = fn(st, *got)
+                    back = xchg(mat)
+                    with _exchange_scope("unpack"):
+                        return st, back[pos]
 
                 return go
 
             cap_pair = min(bl, 2 * -(-bl // n_dev))
             if cap_pair >= bl:
-                return run(bl)(state)
-            over = (batch.valid & (rank >= cap_pair)).any()
-            over = jax.lax.psum(over.astype(jnp.int32), axis) > 0
-            return jax.lax.cond(over, run(bl), run(cap_pair), state)
+                return run(bl)(state) + (no_overflow,)
+            with _exchange_scope("route"):
+                over = (batch.valid & (rank >= cap_pair)).any()
+                over = jax.lax.psum(over.astype(jnp.int32), axis) > 0
+            return jax.lax.cond(over, run(bl), run(cap_pair), state) + (
+                over.astype(jnp.int32),)
 
         # ---- customer velocity ------------------------------------------
         # Owner-local (chunk 0: rows placed by customer % n_dev) or routed
@@ -434,8 +462,10 @@ def make_sharded_step(
                         [cc, ca], axis=1)
 
             st0 = (c_kd, fstate.customer, local_cms, zero2)
+            c_over = jnp.zeros((), jnp.int32)
             if route_customers:
-                (c_kd, customer, local_cms, c_cnt), cb = exchanged_compute(
+                ((c_kd, customer, local_cms, c_cnt), cb,
+                 c_over) = exchanged_compute(
                     batch.customer_key, customer_fn_x, st0)
             else:
                 (c_kd, customer, local_cms, c_cnt), cb = customer_fn_x(
@@ -468,7 +498,7 @@ def make_sharded_step(
                     return (kd, terminal, tcms, cnt), jnp.concatenate(
                         [tc, tf], axis=1)
 
-            (t_kd, terminal, t_cms, t_cnt), tb = exchanged_compute(
+            (t_kd, terminal, t_cms, t_cnt), tb, t_over = exchanged_compute(
                 batch.terminal_key, terminal_fn_x,
                 (t_kd, fstate.terminal, t_cms, zero2))
             t_count_l, t_fraud_l = tb[:, :nw], tb[:, nw:]
@@ -478,7 +508,7 @@ def make_sharded_step(
                 c_count, c_amount, t_count_l, t_fraud_l,
                 customer_dir=_restack(c_kd), terminal_dir=_restack(t_kd),
                 terminal_cms=_restack(t_cms),
-                tier=(c_cnt + t_cnt)[None])
+                tier=(c_cnt + t_cnt)[None], overflows=c_over + t_over)
 
         def customer_fn(st, c_key, c_day, c_amt, c_fraud, c_valid):
             """Owner-side customer velocity: sketch/window update + query
@@ -507,8 +537,9 @@ def make_sharded_step(
                 return (local_cms, customer), jnp.concatenate([cc, ca],
                                                               axis=1)
 
+        c_over = jnp.zeros((), jnp.int32)
         if route_customers:
-            (local_cms, customer), cb = exchanged_compute(
+            (local_cms, customer), cb, c_over = exchanged_compute(
                 batch.customer_key, customer_fn,
                 (local_cms, fstate.customer))
         else:
@@ -535,19 +566,20 @@ def make_sharded_step(
                 return terminal, jnp.concatenate([t_count, t_fraud],
                                                  axis=1)
 
-        terminal, tb = exchanged_compute(
+        terminal, tb, t_over = exchanged_compute(
             batch.terminal_key, terminal_fn, fstate.terminal)
         t_count_l, t_fraud_l = tb[:, :nw], tb[:, nw:]
         return _assemble_and_score(
             fstate, params, scaler, batch, fraud,
             customer, terminal, cms,
-            c_count, c_amount, t_count_l, t_fraud_l)
+            c_count, c_amount, t_count_l, t_fraud_l,
+            overflows=c_over + t_over)
 
     def _assemble_and_score(fstate, params, scaler, batch, fraud,
                             customer, terminal, cms,
                             c_count, c_amount, t_count_l, t_fraud_l,
                             customer_dir=None, terminal_dir=None,
-                            terminal_cms=None, tier=None):
+                            terminal_cms=None, tier=None, *, overflows):
         """Shared tail of ``local_step``: 15-feature assembly (order =
         features/spec.py), classify, optional psum'd online SGD, and the
         new-state pytree — identical math for the direct/hash and exact
@@ -582,9 +614,11 @@ def make_sharded_step(
             # already consumed the f32 features (predictions unaffected)
             with step_scope("emit"):
                 feats = feats.astype(jnp.bfloat16)
+        out = (new_state, params, probs, feats)
         if tier is not None:
-            return new_state, params, probs, feats, tier
-        return new_state, params, probs, feats
+            out += (tier,)
+        # uniform over the mesh (psum'd): leaves replicated
+        return out + (overflows,)
 
     from real_time_fraud_detection_system_tpu.parallel.mesh import (
         compat_shard_map,
@@ -631,7 +665,8 @@ def make_sharded_step(
             in_specs[1],
             P(axis),
             P(axis, None),
-        ) + ((P(axis, None),) if exact else ())  # [n_dev, 2] tier rows
+        ) + ((P(axis, None),) if exact else ()  # [n_dev, 2] tier rows
+             ) + (P(),)  # exchange overflows
         fn = _shard_map(local_step, in_specs, out_specs)
         thresh = float(cfg.runtime.emit_threshold)
         selective = cfg.runtime.emit_features and thresh > 0.0
@@ -641,12 +676,11 @@ def make_sharded_step(
             with step_scope("unpack"):
                 batch = unpack_batch(batch_in) if packed else batch_in
             out = fn(fstate, params, scaler, batch)
-            tier = out[4] if exact else None
+            # after the four: the tier rows (exact), the overflow count
+            extra = out[4:]
             fstate, params, probs, feats = out[:4]
             if not selective:
-                if exact:
-                    return fstate, params, probs, feats, tier
-                return fstate, params, probs, feats
+                return (fstate, params, probs, feats) + extra
             # Selective emission over the mesh: the same packed-transfer
             # contract as the single-chip engine (engine.py step tail) —
             # probs for every row, feature vectors compacted to flagged
@@ -665,9 +699,7 @@ def make_sharded_step(
                     feats[idx].reshape(-1),
                 ])
             emit = {"packed": packed_out, "full": feats}
-            if exact:
-                return fstate, params, probs, emit, tier
-            return fstate, params, probs, emit
+            return (fstate, params, probs, emit) + extra
 
         return jax.jit(outer, donate_argnums=(0,))
 

@@ -49,7 +49,6 @@ from real_time_fraud_detection_system_tpu.core.batch import (
 from real_time_fraud_detection_system_tpu.core.batch import bucket_size
 from real_time_fraud_detection_system_tpu.features.online import (
     apply_feedback_at_slot,
-    init_feature_state,
 )
 from real_time_fraud_detection_system_tpu.features.spec import N_FEATURES
 from real_time_fraud_detection_system_tpu.models.scaler import Scaler
@@ -57,6 +56,7 @@ from real_time_fraud_detection_system_tpu.ops.dedup import (
     latest_wins_mask_host,
 )
 from real_time_fraud_detection_system_tpu.parallel.mesh import (
+    init_sharded_feature_state,
     make_mesh,
     shard_feature_state,
 )
@@ -237,17 +237,18 @@ class ShardedScoringEngine(ScoringEngine):
 
             pre_state = init_sharded_history_state(cfg, mesh, axis=axis)
         if kind != "sequence":
-            # hand any provided state straight to the base constructor —
-            # letting it build a throwaway full-size fresh state would
-            # transiently double the footprint (same reasoning as the
-            # sequence pre_state above)
+            # The base constructor never builds the state: its fresh one
+            # is the whole table on ONE device, which a mesh that holds
+            # more than a chip's worth cannot allocate
+            # (RESOURCE_EXHAUSTED at 2^24 + 2^25 slots on four 16 GB
+            # chips). A provided state is handed through as it is; a
+            # fresh one is created already spread over the mesh, in the
+            # mesh-width layout (exact mode's per-shard directories
+            # included) — every key mode, every width.
             pre_state = feature_state
-            if exact and pre_state is None:
-                # exact mode's directory shapes are width-dependent
-                # (per-shard key directories): build the SHARDED layout
-                # first, never the single-chip one
-                pre_state = init_feature_state(cfg.features,
-                                               n_shards=n_mesh)
+            if pre_state is None:
+                pre_state = init_sharded_feature_state(
+                    cfg.features, mesh, axis=axis)
         super().__init__(
             cfg, kind, params, scaler, feature_state=pre_state,
             online_lr=online_lr, feature_cache=feature_cache,
@@ -280,6 +281,46 @@ class ShardedScoringEngine(ScoringEngine):
         self._m_step_builds = self.metrics.counter(
             "rtfds_sharded_step_builds_total",
             "sharded step compilations (local + routed variants)")
+        # What the mesh adds to a batch, summed over a run (the gauges
+        # above show the last batch only): the two host phases inside
+        # host_prep / result_wait, the chunks a batch became, the slots
+        # the devices computed on against the rows in them (the rest is
+        # padding), the fullest shard's rows and the mean shard's
+        # (imbalance = max ÷ mean), and how often the step's exchange
+        # outgrew its buckets.
+        self._m_phase_mesh = {
+            ph: self.metrics.histogram(
+                "rtfds_phase_seconds",
+                "per-batch loop-time decomposition by phase", phase=ph)
+            for ph in ("partition", "assemble")
+        }
+        self._m_chunks = {
+            routed: self.metrics.counter(
+                "rtfds_shard_chunks_total",
+                "chunk steps dispatched (routed=1: a dense spill chunk "
+                "whose customers travel to their owner)",
+                routed=str(int(routed)))
+            for routed in (False, True)
+        }
+        self._m_slots = self.metrics.counter(
+            "rtfds_shard_slots_total",
+            "row slots dispatched to the mesh (n_devices x rows_per_shard "
+            "a chunk)")
+        self._m_valid_rows = self.metrics.counter(
+            "rtfds_shard_valid_rows_total",
+            "rows in those slots; the rest is padding every device "
+            "computes on")
+        self._m_rows_max = self.metrics.counter(
+            "rtfds_shard_rows_max_total",
+            "rows of each batch's fullest shard, summed")
+        self._m_rows_mean = self.metrics.counter(
+            "rtfds_shard_rows_mean_total",
+            "each batch's rows / n_devices, summed (max / mean = "
+            "imbalance, at whatever width each batch was served)")
+        self._m_xchg_overflow = self.metrics.counter(
+            "rtfds_exchange_overflow_total",
+            "exchanges that took the full-capacity branch: a (sender, "
+            "owner) pair held more rows than its bucket")
         # Commit replicated leaves (params, scaler) to the mesh NOW: the
         # step's out_specs return them mesh-committed, so leaving the
         # build-time copies on the default device makes the SECOND step
@@ -311,8 +352,8 @@ class ShardedScoringEngine(ScoringEngine):
             return
         if cfg.features.terminal_capacity % self.n_dev:
             raise ValueError("terminal_capacity must divide by n_devices")
-        # the base constructor holds either the provided state or a fresh
-        # one — place it over the mesh (no second allocation)
+        # a fresh state was created on the mesh (above) and stays where
+        # it is; a provided one is spread over the mesh here
         self.state.feature_state = shard_feature_state(
             self.state.feature_state, self.mesh, axis=self.axis,
         )
@@ -867,16 +908,23 @@ class ShardedScoringEngine(ScoringEngine):
                     minlength=self.n_dev)
                 for i, g in enumerate(self._m_shard_rows):
                     g.set(int(loads[i]))
+                self._m_rows_max.inc(int(loads.max()))
+                self._m_rows_mean.inc(n / self.n_dev)
 
-            chunks = partition_batch_spill(
-                cols, self.n_dev, self.rows_per_shard
-            ) if n else []
+            t_part = time.perf_counter()
+            with self.tracer.span("partition"):
+                chunks = partition_batch_spill(
+                    cols, self.n_dev, self.rows_per_shard
+                ) if n else []
+            self._m_phase_mesh["partition"].observe(
+                time.perf_counter() - t_part)
         # host prep ends here: the chunk loop below is dispatch (make_
         # batch + H2D + jit launches), split out so the sharded loop's
         # phase decomposition matches the single-chip engine's.
         t_prep = time.perf_counter()
         parts = []
         tier_parts = []  # exact mode: per-chunk [n_dev, 2] tier rows
+        overflow_parts = []  # per-chunk exchange-overflow scalars
         t_fetch = None  # last chunk's async-fetch issue time
         for part_cols, rows, pos in chunks:
             batch = make_batch(
@@ -903,6 +951,9 @@ class ShardedScoringEngine(ScoringEngine):
             else:
                 jbatch = jax.tree.map(jnp.asarray, batch)
             routed = bool(part_cols.get("__routed__", False))
+            self._m_chunks[routed].inc()
+            self._m_slots.inc(len(part_cols["__valid__"]))
+            self._m_valid_rows.inc(len(rows))
             if self.kind == "sequence":
                 step = self._seq_step_routed if routed else self._seq_step
                 # original batch row index per chunk slot — the
@@ -943,6 +994,9 @@ class ShardedScoringEngine(ScoringEngine):
                 # chunk — accumulated across chunks, materialized at
                 # finish (scalar-sized; no async fetch needed)
                 tier_parts.append(out[4])
+            # scalar beside probs, read at finish like the tier rows
+            out[-1].copy_to_host_async()
+            overflow_parts.append(out[-1])
             self.state.feature_state = fstate
             self.state.params = params
             # async D2H per chunk: each chunk's transfer starts the
@@ -961,6 +1015,8 @@ class ShardedScoringEngine(ScoringEngine):
                   "fetch_issue_t": t_fetch}
         if tier_parts:
             handle["tier_shard"] = tier_parts
+        if overflow_parts:
+            handle["exchange_overflow"] = overflow_parts
         # notify compaction's recency cutoff (the base engine does this
         # in its own _start_batch; the sharded path overrides it wholesale)
         self._note_batch_days(cols)
@@ -984,6 +1040,17 @@ class ShardedScoringEngine(ScoringEngine):
         else:
             feats_np = np.zeros((n, N_FEATURES), dtype=np.float32)
         overflowed = False  # per BATCH, however many chunks overflow
+        # Re-assembly = the host's permutation of each chunk's slots back
+        # into input order, timed apart from the wait for the device (the
+        # np.asarray of a chunk's results) that precedes it.
+        asm_s = 0.0
+
+        def assembled(t_from: float) -> float:
+            t_to = time.perf_counter()
+            self.tracer.add_span("assemble", t_from, t_to,
+                                 batch=handle.get("trace_id"))
+            return t_to - t_from
+
         for rows, pos, probs, feats in handle["parts"]:
             if isinstance(feats, dict):
                 # selective emission: one packed fetch per chunk carries
@@ -995,6 +1062,7 @@ class ShardedScoringEngine(ScoringEngine):
                 cap = ((feats["packed"].shape[0] - pad - 1)
                        // (1 + N_FEATURES))
                 flat = np.asarray(feats["packed"])
+                t_asm = time.perf_counter()
                 probs_np[rows] = flat[:pad][pos]
                 count = int(flat[pad])
                 if count > cap:
@@ -1010,12 +1078,22 @@ class ShardedScoringEngine(ScoringEngine):
                     # target is a real batch row
                     feats_np[slot_to_row[idx]] = sel.reshape(
                         count, N_FEATURES)
+                asm_s += assembled(t_asm)
                 continue
-            probs_np[rows] = np.asarray(probs)[pos]
-            if feats is not None and emit:
-                # alerts-only mode skips the per-shard feature D2H, same
-                # contract as the single-chip engine
-                feats_np[rows] = np.asarray(feats)[pos]
+            probs_host = np.asarray(probs)
+            # alerts-only mode skips the per-shard feature D2H, same
+            # contract as the single-chip engine
+            feats_host = (np.asarray(feats)
+                          if feats is not None and emit else None)
+            t_asm = time.perf_counter()
+            probs_np[rows] = probs_host[pos]
+            if feats_host is not None:
+                feats_np[rows] = feats_host[pos]
+            asm_s += assembled(t_asm)
+        if handle["parts"]:
+            self._m_phase_mesh["assemble"].observe(asm_s)
+        for x in handle.pop("exchange_overflow", ()):
+            self._m_xchg_overflow.inc(int(x))
         if overflowed:
             # once per batch, matching the single-chip counter semantics
             # (engine.py: "batches whose flagged-row count overflowed")

@@ -365,7 +365,7 @@ STEP_SCOPES = (
     "assemble",                                    # features/online
     "scale",                                       # models/scaler
     "classify", "fused_step", "learn", "emit",     # engine.step
-    "exchange",                                    # parallel/step
+    "exchange", "route", "pack",                   # parallel/step
 )
 
 

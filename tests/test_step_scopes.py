@@ -72,7 +72,10 @@ VARIANTS = {
                   COMMON | {"emit"}),
     "online_sgd": ("logreg", {}, {"online_lr": 0.01}, 0,
                    COMMON | {"learn"}),
-    "sharded": ("forest", {}, {}, 2, COMMON | {"exchange"}),
+    # the exchange names its parts: ranking rows by owner, packing the
+    # send buffer, and (under the engine's own `unpack`) the back-gather
+    "sharded": ("forest", {}, {}, 2,
+                COMMON | {"exchange", "route", "pack"}),
 }
 
 
