@@ -14,6 +14,7 @@ label updates never contaminate the queried window.
 from __future__ import annotations
 
 import dataclasses
+from types import MappingProxyType
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -62,6 +63,24 @@ class FeatureState(NamedTuple):
     customer_dir: Optional[KeyDirectory] = None
     terminal_dir: Optional[KeyDirectory] = None
     terminal_cms: Optional[CountMinSketch] = None
+
+
+# The aggregate columns each key space's window table maintains beside its
+# day stamps and counts, as ``update_windows``' keywords. Decided here and
+# nowhere else, from the 15-feature spec: customer features are count and
+# average amount, terminal features count and risk (the fraud share). The
+# set is fixed for a table's life: the update neither scatters into nor
+# resets an unmaintained column (a table-wide pass a step and a ~6 ms
+# scatter saved, each), so customer ``fraud`` and terminal ``amount`` stay
+# the zeros ``init_window_state`` made and no feature may read them. Every
+# update of either table, one chip (below) and sharded
+# (``parallel/step.py``), passes these; late labels
+# (:func:`apply_feedback_at_slot`) write terminal ``fraud``, a column its
+# table maintains.
+CUSTOMER_COLUMNS = MappingProxyType(
+    {"track_amount": True, "track_fraud": False})
+TERMINAL_COLUMNS = MappingProxyType(
+    {"track_amount": False, "track_fraud": True})
 
 
 def init_feature_state(
@@ -223,7 +242,7 @@ def _update_state_exact(
                 n_probes=probes)
             customer = update_windows(
                 state.customer, cust_slot, batch.day, batch.amount, fraud,
-                batch.valid & c_adm, track_fraud=False,
+                batch.valid & c_adm, **CUSTOMER_COLUMNS,
             )
     with step_scope("terminal"):
         terminal_dir, term_slot, t_adm = admit_slots(
@@ -231,7 +250,7 @@ def _update_state_exact(
             n_probes=probes)
         terminal = update_windows(
             state.terminal, term_slot, batch.day, batch.amount, fraud,
-            batch.valid & t_adm, track_amount=False,
+            batch.valid & t_adm, **TERMINAL_COLUMNS,
         )
     with step_scope("customer"):
         cms = cms_update(state.cms, batch.customer_key, batch.amount,
@@ -268,19 +287,15 @@ def _update_state(
     if cfg.customer_source == "cms":
         customer = state.customer  # unused in cms mode: skip the scatter
     else:
-        # track_fraud=False: no feature reads customer fraud sums (spec is
-        # count+avg for customers) — one fewer scatter (~6 ms a batch of
-        # 65,536 rows on a v5e; ledger, PR 24: step_scatter_ms).
         with step_scope("customer"):
             customer = update_windows(
                 state.customer, cust_slot, batch.day, batch.amount, fraud,
-                batch.valid, track_fraud=False,
+                batch.valid, **CUSTOMER_COLUMNS,
             )
-    # track_amount=False symmetrically: terminal features are count+risk.
     with step_scope("terminal"):
         terminal = update_windows(
             state.terminal, term_slot, batch.day, batch.amount, fraud,
-            batch.valid, track_amount=False,
+            batch.valid, **TERMINAL_COLUMNS,
         )
     cms = state.cms
     if cms is not None:
@@ -820,6 +835,7 @@ def apply_feedback_at_slot(
     bucket = jnp.remainder(day, nb)
     flat = term_slot * nb + bucket
     # Only land the label if the bucket still holds that day (ring not wrapped).
+    # ``fraud`` is a column the terminal table maintains (TERMINAL_COLUMNS).
     live = valid & (state.terminal.bucket_day[flat] == day)
     frd = state.terminal.fraud.at[flat].add(
         label.astype(jnp.float32) * live.astype(jnp.float32)

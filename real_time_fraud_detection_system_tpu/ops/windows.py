@@ -35,8 +35,32 @@ The reference's pandas ``rolling('Nd')`` is a trailing wall-clock window;
 day-granular buckets are the streaming-friendly approximation, and training
 uses the SAME kernel via replay, so there is zero train/serve skew.
 
-All updates are O(B) scatters and all queries O(B × max_window) gathers —
-fully vectorized, jit/shard_map friendly, no data-dependent shapes.
+Updates are O(B) scatters plus a lazy reset that is a pass over the table;
+queries are O(B × max_window) gathers — fully vectorized, jit/shard_map
+friendly, no data-dependent shapes.
+
+Cost of :func:`update_windows` on a v5e (PERF.md, PR 29). What follows the
+batch: ~6 ms a scatter of 65,536 rows, whatever the table's size (one for
+the stamps, one a maintained column). What follows the table: the reset,
+which is written table-wide — set the old stamps aside (4 bytes a bucket
+read + 4 written), reset the first maintained column where the stamp
+advanced (old stamps, new stamps and the column read, the column and a
+1-byte mask written: 12 + 5), reset the second from the mask (5 + 4):
+**34 bytes a bucket** for a table that maintains two aggregate columns,
+counted from the operand and result shapes of the step compiled for the
+chip (``tests/test_tpu_compile.py`` holds it there). The passes run at
+~675 of the chip's 819 GB/s (25.4 ms a step for 2^22 + 2^23 slots × 40
+buckets), so their time is their bytes: an unmaintained column is not
+touched at all, and the stamps make one round trip beside the reset, not
+two (until PR 29: 50 bytes, 37.3 ms). The chip's compiler keeps the two
+resets apart, with the mask between them, however the selects are
+written; one fused pass would be 32. A reset that follows the batch
+instead — gather the old stamps at the batch's buckets, scatter zeros
+into those that advance, a scatter a maintained column — costs ~12.5 ms
+a table at 65,536 rows whatever its size, against 34 bytes × buckets ÷
+675 GB/s table-wide: 8.5 ms at 2^22 slots, 16.9 ms at 2^23. By that
+arithmetic the two meet near 6 M slots × 40 buckets; the batch-following
+form has not been measured (ROADMAP A1).
 """
 
 from __future__ import annotations
@@ -190,20 +214,22 @@ def update_windows(
     Duplicate (slot, day) rows within the batch accumulate correctly
     (jnp scatter-add applies all duplicates).
 
-    ``track_amount`` / ``track_fraud``: a scatter of 65,536 rows costs
-    ~6 ms a column on a v5e whatever the table's size (ledger, PR 24:
-    ``step_scatter_ms`` 25.9 for four; reformulations — segment_sum,
-    sorted/unique hints, one wide scatter — all measured equal or worse).
-    A table whose consumer never reads a column may skip its scatter: the
+    ``track_amount`` / ``track_fraud`` say which aggregate columns the
+    table MAINTAINS (day stamps and counts always are). That set is fixed
+    for a table's life and decided in one place per key space
+    (``features/online.CUSTOMER_COLUMNS`` / ``TERMINAL_COLUMNS``: the
     15-feature spec reads customer (count, amount) and terminal (count,
-    fraud) only, so the engine drops one scatter per keyspace
-    (§``features/online._update_state``). A skipped column still gets the
-    stale-bucket reset, so its buckets never mix days: it simply misses
-    this batch's contributions — safe even if a later update re-enables
-    tracking. That reset is a pass over the whole column and is not
-    cheap at a deployment's size: 8.6 ms a column of 2^23 slots, 25.2 ms
-    a step for the six columns of the benchmark's two tables (ledger,
-    PR 24: ``step_reset_ms``).
+    fraud) only). An unmaintained column is not touched: no scatter, no
+    reset — it leaves the step as the donated buffer it came in as and
+    stays what :func:`init_window_state` made it, so no feature may read
+    it, and a table whose flags changed mid-life would mix days in it.
+
+    What the update costs follows two things (module docstring, "Cost").
+    The batch: a scatter of 65,536 rows is ~6.2 ms a column on a v5e
+    whatever the table's size (ledger, PR 28: ``step_scatter_ms`` 25.5
+    for four, ``step_stamp_ms`` 12.0 for two). The table: the reset is a
+    pass over every bucket, 34 bytes a bucket with two maintained
+    columns.
     """
     nb = state.n_buckets
     with step_scope("update"):
@@ -213,17 +239,30 @@ def update_windows(
         day_in = jnp.where(valid, day, -1).astype(jnp.int32)
         bd, count, amt, frd = state.columns()
 
-        # Day stamp each touched bucket with max(existing, incoming).
+        with step_scope("reset"):
+            # The stamps as they were, set aside in ONE pass before the
+            # scatter-max overwrites them: the reset compares old with
+            # new. Written as a clamp at the empty stamp (a no-op: stamps
+            # are >= -1) behind a barrier so that it is a pass of its
+            # own, ahead of the scatter. Left to itself the compiler
+            # reads the old stamps from the donated buffer, scatters in a
+            # copy of it and copies the result back: two table passes.
+            old_bd = jax.lax.optimization_barrier(jnp.maximum(bd, -1))
+
+        # Day stamp each touched bucket with max(existing, incoming), in
+        # the donated buffer.
         with step_scope("stamp"):
             new_bd = bd.at[flat].max(day_in)
 
         # Buckets whose stamp advanced hold a stale (older) day: reset
-        # aggregates.
+        # the aggregates the table maintains.
         with step_scope("reset"):
-            advanced = new_bd > bd
+            advanced = new_bd > old_bd
             count = jnp.where(advanced, 0.0, count)
-            amt = jnp.where(advanced, 0.0, amt)
-            frd = jnp.where(advanced, 0.0, frd)
+            if track_amount:
+                amt = jnp.where(advanced, 0.0, amt)
+            if track_fraud:
+                frd = jnp.where(advanced, 0.0, frd)
 
         with step_scope("scatter"):
             # A row contributes only if its day is the bucket's (possibly

@@ -44,6 +44,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from real_time_fraud_detection_system_tpu.config import Config
 from real_time_fraud_detection_system_tpu.core.batch import TxBatch
 from real_time_fraud_detection_system_tpu.features.online import (
+    CUSTOMER_COLUMNS,
+    TERMINAL_COLUMNS,
     FeatureState,
     _assemble,
 )
@@ -448,7 +450,7 @@ def make_sharded_step(
                                                     n_probes=probes)
                     customer = update_windows(
                         customer, c_slot, c_day, c_amt, c_fraud,
-                        c_valid & c_adm, track_fraud=False)
+                        c_valid & c_adm, **CUSTOMER_COLUMNS)
                     lcms = cms_update(lcms, c_key, c_amt, c_day, c_valid)
                     cc_t, ca_t, _ = query_windows(customer, c_slot, c_day,
                                                   windows)
@@ -482,7 +484,7 @@ def make_sharded_step(
                                                     n_probes=probes)
                     terminal = update_windows(
                         terminal, t_slot, t_day, t_amt, t_fraud_in,
-                        t_valid & t_adm, track_amount=False)
+                        t_valid & t_adm, **TERMINAL_COLUMNS)
                     tcms = cms_update(tcms, t_key, t_amt, t_day, t_valid,
                                       fraud=t_fraud_in)
                     tc_t, _, tf_t = query_windows(
@@ -530,8 +532,7 @@ def make_sharded_step(
                               ).astype(jnp.int32)
                     customer = update_windows(
                         customer, c_slot, c_day, c_amt, c_fraud, c_valid,
-                        track_fraud=False,  # customer features: count+avg
-                    )
+                        **CUSTOMER_COLUMNS)
                     cc, ca, _ = query_windows(customer, c_slot, c_day,
                                               windows)
                 return (local_cms, customer), jnp.concatenate([cc, ca],
@@ -558,8 +559,7 @@ def make_sharded_step(
                           & jnp.uint32(t_cap_local - 1)).astype(jnp.int32)
                 terminal = update_windows(
                     terminal, t_slot, t_day, t_amt, t_fraud_in, t_valid,
-                    track_amount=False,  # terminal features: count+risk
-                )
+                    **TERMINAL_COLUMNS)
                 t_count, _, t_fraud = query_windows(
                     terminal, t_slot, t_day, windows,
                     delay=fcfg.delay_days)

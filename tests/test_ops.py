@@ -100,31 +100,111 @@ def test_windows_ring_eviction():
     assert int(c[0, 0]) == 0
 
 
-def test_windows_untracked_column_never_mixes_days():
-    """track_amount=False still applies the stale-bucket reset: a later
-    tracked update onto the advanced bucket must see a clean base, not the
-    previous day's sum (mixed-flag safety)."""
-    nb = 8
-    state = init_window_state(16, nb)
-    one = jnp.ones(1, jnp.float32)
-    v = jnp.ones(1, bool)
-    s0 = jnp.zeros(1, jnp.int32)
-    d = lambda x: jnp.asarray([x], jnp.int32)
-    # day 100 tracked: amount sum 5.0
-    state = update_windows(state, s0, d(100), one * 5, one * 0, v)
-    # day 108 (same ring bucket) with tracking OFF: stamp advances, amount
-    # column must reset to 0 even though its scatter is skipped
-    state = update_windows(state, s0, d(108), one * 7, one * 0, v,
-                           track_amount=False)
-    _, a, _ = query_windows(state, s0, d(108), (1,))
-    assert float(a[0, 0]) == 0.0  # missing contribution, NOT stale 5.0
-    # tracking back ON same day: clean base, only the new value lands
-    state = update_windows(state, s0, d(108), one * 3, one * 0, v)
-    _, a, _ = query_windows(state, s0, d(108), (1,))
-    assert abs(float(a[0, 0]) - 3.0) < 1e-6
-    # count was tracked throughout: all three day-108 rows present
-    c, _, _ = query_windows(state, s0, d(108), (1,))
-    assert int(c[0, 0]) == 2
+@pytest.mark.parametrize(
+    "skipped", [("amount",), ("fraud",), ("amount", "fraud")],
+    ids="_and_".join)
+def test_windows_unmaintained_column_is_not_touched(skipped):
+    """Which aggregate columns a table maintains is fixed for its life.
+    The update leaves an unmaintained column byte for byte as it was,
+    also where the batch advances its buckets (no reset, no scatter); the
+    maintained columns, the stamps and every window read from them are
+    what an update that maintains all three gives."""
+    import dataclasses
+
+    import jax
+
+    kw = {f"track_{c}": False for c in skipped}
+    cap = 16
+    state = init_window_state(cap, _NB)
+    # day 100 and 101 everywhere it matters, all columns maintained ...
+    first = _rows((2, 100, 5, 1, True), (2, 100, 7, 0, True),
+                  (3, 101, 4, 1, True), (9, 100, 2, 1, True))
+    state = update_windows(state, *map(jnp.asarray, first))
+    # ... and a pattern no real table holds in the unmaintained columns,
+    # so that any write to them would show
+    mark = jnp.arange(cap * _NB, dtype=jnp.float32) + 0.5
+    state = dataclasses.replace(state, **{c: mark for c in skipped})
+    # slot 2 and 9 advance to day 108 (the ring bucket of day 100), slot 3
+    # gets a same-day row, a late row and an invalid one
+    second = _rows((2, 108, 3, 1, True), (9, 108, 6, 0, True),
+                   (3, 101, 1, 1, True), (3, 93, 9, 1, True),
+                   (5, 108, 9, 1, False))
+    cols = tuple(map(jnp.asarray, second))
+    got = jax.jit(lambda st: update_windows(st, *cols, **kw))(state)
+    want = update_windows(state, *cols)  # maintains all three
+    assert int(want.bucket_day[2 * _NB + 108 % _NB]) == 108  # advanced
+    for c in skipped:
+        assert (np.asarray(getattr(got, c)).tobytes()
+                == np.asarray(mark).tobytes())
+    maintained = [c for c in ("bucket_day", "count", "amount", "fraud")
+                  if c not in skipped]
+    for c in maintained:
+        np.testing.assert_array_equal(np.asarray(getattr(got, c)),
+                                      np.asarray(getattr(want, c)))
+    slot = jnp.asarray(np.repeat(np.arange(cap, dtype=np.int32), 3))
+    day = jnp.asarray(np.tile(np.asarray([101, 108, 110], np.int32), cap))
+    names = ("count", "amount", "fraud")
+    for delay in (0, 2):
+        for name, g, w in zip(
+                names, query_windows(got, slot, day, (1, 3, _NB), delay),
+                query_windows(want, slot, day, (1, 3, _NB), delay)):
+            if name in maintained:
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_maintained_columns_are_defined_once():
+    """The customer table maintains (count, amount), the terminal table
+    (count, fraud): one definition in ``features/online.py``, and every
+    ``update_windows`` call of the one-chip and the sharded step passes
+    it — no call site repeats a literal. Late labels write a column its
+    table maintains, and nothing else."""
+    import ast
+    import inspect
+
+    from real_time_fraud_detection_system_tpu.features import online
+    from real_time_fraud_detection_system_tpu.parallel import step
+
+    assert dict(online.CUSTOMER_COLUMNS) == {"track_amount": True,
+                                             "track_fraud": False}
+    assert dict(online.TERMINAL_COLUMNS) == {"track_amount": False,
+                                             "track_fraud": True}
+    assert step.CUSTOMER_COLUMNS is online.CUSTOMER_COLUMNS
+    assert step.TERMINAL_COLUMNS is online.TERMINAL_COLUMNS
+    for module in (online, step):
+        tree = ast.parse(inspect.getsource(module))
+        # the two names are bound once, at module level, in online.py only
+        bound = [t.id for node in ast.walk(tree)
+                 if isinstance(node, ast.Assign)
+                 for t in node.targets if isinstance(t, ast.Name)
+                 and t.id in ("CUSTOMER_COLUMNS", "TERMINAL_COLUMNS")]
+        assert len(bound) == (2 if module is online else 0), bound
+        calls = [node for node in ast.walk(tree)
+                 if isinstance(node, ast.Call)
+                 and getattr(node.func, "id", None) == "update_windows"]
+        assert len(calls) == 4, (module.__name__, len(calls))
+        for call in calls:
+            table = ast.unparse(call.args[0]).rsplit(".", 1)[-1]
+            assert table in ("customer", "terminal"), table
+            (kw,) = call.keywords  # one ``**`` and no literal flag
+            assert kw.arg is None
+            assert kw.value.id == f"{table.upper()}_COLUMNS"
+
+    state = online.FeatureState(
+        customer=init_window_state(8, _NB),
+        terminal=update_windows(
+            init_window_state(8, _NB), *map(jnp.asarray, _rows(
+                (2, 100, 5, 0, True))), **online.TERMINAL_COLUMNS),
+        cms=None)
+    after = online.apply_feedback_at_slot(
+        state, jnp.asarray([2], jnp.int32), jnp.asarray([100], jnp.int32),
+        jnp.asarray([1], jnp.int32), jnp.asarray([True]))
+    changed = [c for c, a, b in zip(
+        ("bucket_day", "count", "amount", "fraud"),
+        state.terminal.columns(), after.terminal.columns())
+        if not np.array_equal(np.asarray(a), np.asarray(b))]
+    assert changed == ["fraud"]
+    assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(
+        state.customer.columns(), after.customer.columns()))
 
 
 def test_windows_invalid_rows_ignored():
@@ -159,8 +239,11 @@ class _TablesOracle:
         for s, d, v in zip(slot, day, valid):
             if v:
                 self.bd[s, d % self.nb] = max(self.bd[s, d % self.nb], d)
-        for t in (self.cnt, self.amt, self.frd):
-            t[self.bd > old] = 0.0
+        # only the columns the table maintains are reset (or written)
+        for t, kept in ((self.cnt, True), (self.amt, track_amount),
+                        (self.frd, track_fraud)):
+            if kept:
+                t[self.bd > old] = 0.0
         for s, d, a, f, v in zip(slot, day, amount, fraud, valid):
             b = d % self.nb
             if v and self.bd[s, b] == d:

@@ -12,6 +12,11 @@ stage. For every variant of the step that compiles here at toy size:
   and no ``rtfds.`` component outside the vocabulary;
 - in the COMPILED HLO every ``rtfds.update`` op sits under exactly one of
   ``rtfds.customer`` / ``rtfds.terminal``;
+- every pass over a whole window column that the program wrote (the old
+  stamps set aside, the compare, the resets) sits under
+  ``rtfds.update/rtfds.reset``; the scatters, which follow the batch, keep
+  ``stamp`` / ``scatter``. What the chip then runs over a table with no
+  ``rtfds.`` name is the compiler's own (``step_unscoped_pct``);
 - scopes are metadata: the step's outputs are bit-equal to a build with
   the scopes patched to no-ops.
 """
@@ -110,6 +115,25 @@ def _scopes(op_name):
             if c.startswith("rtfds.")]
 
 
+_COLUMN_OP = re.compile(
+    r"^\s*(?:ROOT )?[\w.\-]+ = \w+\[(\d+)\]\S* ([\w\-]+)\(.*"
+    r'op_name="([^"]*rtfds\.update[^"]*)"')
+
+
+def _column_passes_outside_reset(hlo_text, column_sizes):
+    """``(op, op_name)`` of every instruction under ``rtfds.update`` whose
+    result is a whole window column, the scatters aside, that is not
+    under ``rtfds.update/rtfds.reset``."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _COLUMN_OP.match(line)
+        if (m and int(m.group(1)) in column_sizes
+                and m.group(2) != "scatter"
+                and "rtfds.update/rtfds.reset/" not in m.group(3)):
+            out.append((m.group(2), m.group(3)))
+    return out
+
+
 def _lowered_steps(eng):
     return [eng.signature_step(sig).lower(*eng.signature_templates(sig))
             for sig in eng.dispatch_inventory()
@@ -118,14 +142,20 @@ def _lowered_steps(eng):
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
 def test_step_hlo_carries_the_variants_scopes(variant):
-    lowered = _lowered_steps(_engine(variant))
+    eng = _engine(variant)
+    lowered = _lowered_steps(eng)
     assert lowered
     want = VARIANTS[variant][4]
+    fcfg, n_dev = eng.cfg.features, max(1, VARIANTS[variant][3])
+    column_sizes = {cap * fcfg.n_day_buckets // n_dev for cap in (
+        fcfg.customer_capacity, fcfg.terminal_capacity)}
     for low in lowered:
-        said = {s for n in _op_names(
-            low.as_text(dialect="hlo", debug_info=True)) for s in _scopes(n)}
+        said_text = low.as_text(dialect="hlo", debug_info=True)
+        said = {s for n in _op_names(said_text) for s in _scopes(n)}
         assert said <= set(STEP_SCOPES)
         assert want <= said, sorted(want - said)
+        assert f"[{max(column_sizes)}]" in said_text  # the sizes are right
+        assert not _column_passes_outside_reset(said_text, column_sizes)
         compiled = _op_names(low.compile().as_text())
         kept = {s for n in compiled for s in _scopes(n)}
         assert "relayout" in STEP_SCOPES and "relayout" not in said

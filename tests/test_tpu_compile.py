@@ -191,16 +191,100 @@ def test_step_moves_no_table_between_layouts(topo, one_chip, as_on_chip,
     (flat, slot-major), so the chip's compiler has no column to re-lay
     out: before PR 25 each stored [cap, 40] column went copy → reshape →
     update → reshape → copy, 206.7 of a 305.4 ms step at the benchmark's
-    size (PERF.md). What may remain, a table: two flat int32 copies of
-    the day stamps around the scatter-max (`advanced = new > old` needs
-    the old ones)."""
+    size (PERF.md). What may remain, a table: one flat int32 copy of the
+    day stamps (until PR 29 the compiler made two around the scatter-max;
+    the update now sets the old stamps aside itself, in a pass it
+    names)."""
     fcfg = _fcfg()
     nb = fcfg.n_day_buckets
     text = _compiled_step(compiled_steps, one_chip, kind, bucket).as_text()
     for cap in (fcfg.customer_capacity, fcfg.terminal_capacity):
         moves = whole_column_moves(text, {cap * nb})
         assert all(m == ("copy", "s32", (cap * nb,)) for m in moves), moves
-        assert len(moves) <= 2, moves
+        assert len(moves) <= 1, moves
+
+
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([\w\-]+)\((.*)$")
+_HLO_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+_ELEM_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s32": 4, "u32": 4, "f32": 4}
+# instructions that hand buffers on without streaming them
+_NO_PASS = {"parameter", "tuple", "get-tuple-element", "bitcast",
+            "conditional", "while", "call", "optimization-barrier"}
+
+
+def _computations(hlo_text):
+    """name → body of every computation; the entry's is ``"ENTRY"``."""
+    return {("ENTRY" if m.group(1) else m.group(2)): m.group(3)
+            for m in re.finditer(
+                r"^(ENTRY )?%([\w.\-]+) [^\n]*\{\n(.*?)^\}", hlo_text,
+                re.S | re.M)}
+
+
+def column_passes(hlo_text, n):
+    """``(name, op, bytes_read, bytes_written, operands)``, bytes per
+    element, of every instruction of the entry computation that streams
+    an ``n``-element array: counted from the instruction's operand and
+    result shapes, so a fusion counts once whatever it holds. A fusion
+    around a scatter or a gather updates or reads its table in place at
+    the batch's buckets only and is no pass."""
+    bodies = _computations(hlo_text)
+    shapes, out = {}, []
+    for line in bodies["ENTRY"].splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        name, result, op, rest = m.groups()
+        shapes[name] = [_ELEM_BYTES[d] for d, dims in
+                        _HLO_ARRAY.findall(result) if dims == str(n)]
+        if op in _NO_PASS:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", rest)
+        body = bodies.get(called.group(1), "") if called else ""
+        if " scatter(" in body or " gather(" in body:
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])
+        read = sum(sum(shapes.get(o, ())) for o in operands)
+        if read or shapes[name]:
+            out.append((name, op, read, sum(shapes[name]), operands))
+    return out
+
+
+def _root_operands(hlo_text):
+    (root,) = [ln for ln in _computations(hlo_text)["ENTRY"].splitlines()
+               if ln.lstrip().startswith("ROOT ")]
+    return re.findall(r"%([\w.\-]+)", root.split(" tuple(", 1)[1])
+
+
+@pytest.mark.parametrize("bucket", [4096, 65536])
+@pytest.mark.parametrize("kind", ["forest", "logreg"])
+def test_update_moves_at_most_34_bytes_a_bucket(topo, one_chip, as_on_chip,
+                                                compiled_steps, kind,
+                                                bucket):
+    """What of the step follows the tables' size and not the batch: the
+    passes over whole window columns. Until PR 29 they moved 50 bytes a
+    bucket and table (two copies of the stamps, 8 + 8; the three resets
+    in two fusions with a materialized mask between them, 17 + 17) — 37
+    of a 100 ms step at 2^22 + 2^23 slots, at 83 % of the chip's HBM
+    bandwidth, so only fewer bytes shorten it. Now: the old stamps set
+    aside once (4 + 4), the reset of the first maintained column with the
+    compare (12 read, the column and the mask written: 17), the reset of
+    the second from the mask (5 + 4); the column a table does not
+    maintain goes from the step's input to its output as the same
+    buffer. (At most one table-sized copy a table:
+    test_step_moves_no_table_between_layouts.)"""
+    fcfg = _fcfg()
+    nb = fcfg.n_day_buckets
+    text = _compiled_step(compiled_steps, one_chip, kind, bucket).as_text()
+    root = _root_operands(text)
+    for cap, table, unmaintained in (
+            (fcfg.customer_capacity, "customer", "fraud"),
+            (fcfg.terminal_capacity, "terminal", "amount")):
+        passes = column_passes(text, cap * nb)
+        assert passes, table
+        assert sum(p[2] + p[3] for p in passes) <= 34, passes
+        (param,) = {o for o in root
+                    if o.startswith(f"fstate_{table}_{unmaintained}")}
+        assert not [p for p in passes if param in p[4]], (param, passes)
 
 
 @pytest.mark.parametrize("z_mode", ["f32", "bf16", "int8"])
