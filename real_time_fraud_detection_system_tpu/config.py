@@ -149,7 +149,7 @@ class FeatureConfig:
                 f"key_mode must be 'direct', 'hash' or 'exact', "
                 f"got {self.key_mode!r}"
             )
-        # direct mode masks with (capacity - 1) (features/online._slot) and
+        # direct mode masks with (capacity - 1) (ops/hashing.key_slot) and
         # the hash/exact placements assume pow2 tables — a non-pow2
         # capacity would silently ALIAS keys today, so refuse it loudly.
         for name in ("customer_capacity", "terminal_capacity"):

@@ -128,6 +128,9 @@ def encode_transaction_envelope(
     return json.dumps(env, separators=(",", ":")).encode("utf-8")
 
 
+_I64_MIN, _I64_MAX = -(2 ** 63), 2 ** 63 - 1
+
+
 def encode_transaction_envelopes(
     tx_id: np.ndarray,
     tx_datetime_us: np.ndarray,
@@ -189,17 +192,27 @@ def decode_transaction_envelopes(
             raw_amounts.append(b"\x00")
             continue
         try:
-            tx_id[i] = row["tx_id"]
-            t_us[i] = row["tx_datetime"]
-            cust[i] = row["customer_id"]
-            term[i] = row["terminal_id"]
+            ids = [row[k] for k in ("tx_id", "tx_datetime", "customer_id",
+                                    "terminal_id")]
+            if not all(type(v) is int and _I64_MIN <= v <= _I64_MAX
+                       for v in ids):
+                # 1e999 parses to inf, 2**70 to a Python int: neither is
+                # an int64 id or timestamp, and assigning one would raise
+                # OverflowError out of the whole poll
+                raise ValueError("id or timestamp is not an int64")
             amt = row.get("tx_amount")
-            raw = base64.b64decode(amt) if amt is not None else b"\x00"
+            # validate=True: without it non-alphabet bytes are silently
+            # DROPPED and a mangled amount decodes to a garbage value
+            # where native/envelope.cc rejects the row
+            raw = (base64.b64decode(amt, validate=True)
+                   if amt is not None else b"\x00")
         except (KeyError, TypeError, ValueError):
-            # incomplete/mistyped row image: mask, don't crash the batch
-            # (matches the native decoder's behavior)
+            # incomplete/mistyped row image (binascii.Error is a
+            # ValueError): mask, don't crash the batch (matches the
+            # native decoder's behavior)
             raw_amounts.append(b"\x00")
             continue
+        tx_id[i], t_us[i], cust[i], term[i] = ids
         op[i] = op_codes.get(payload.get("op", "c"), 0)
         raw_amounts.append(raw)
         valid[i] = True

@@ -29,7 +29,7 @@ Row ``C`` of every array is a write sink: padding rows route their
 scatters there, keeping scatter indices unique without host-side
 filtering.
 
-Key→slot follows the window state's contract (``features/online._slot``):
+Key→slot is the window state's rule (``ops/hashing.key_slot``):
 ``direct`` mode is collision-free while ids < capacity; past capacity
 (or in ``hash`` mode) colliding customers MERGE into one interleaved
 history — same degradation mode as the window tables, size capacity
@@ -48,7 +48,7 @@ import numpy as np
 
 from real_time_fraud_detection_system_tpu.config import FeatureConfig
 from real_time_fraud_detection_system_tpu.core.batch import TxBatch
-from real_time_fraud_detection_system_tpu.features.online import _slot
+from real_time_fraud_detection_system_tpu.ops.hashing import key_slot
 from real_time_fraud_detection_system_tpu.models.sequence import (
     N_EVENT_FEATURES,
     transformer_last_logit,
@@ -146,7 +146,7 @@ def update_and_score(
 
     ``slot_fn(customer_key) -> slot`` overrides the key→slot mapping
     (the sharded layout addresses a device-local block: owner shard
-    already selected, local slot = key // n_dev).
+    already selected, ``key_slot`` at the mesh's width).
 
     ``order_key`` [B] int32 breaks same-second timestamp ties (default:
     the row index). The routed sharded path passes each row's ORIGINAL
@@ -158,7 +158,7 @@ def update_and_score(
     b = batch.size
     valid = batch.valid
     if slot_fn is None:
-        slot = _slot(batch.customer_key, c, cfg.key_mode).astype(jnp.int32)
+        slot = key_slot(batch.customer_key, c, cfg.key_mode)
     else:
         slot = slot_fn(batch.customer_key).astype(jnp.int32)
     slot = jnp.where(valid, slot, c)  # padding → sink row

@@ -26,10 +26,11 @@ from real_time_fraud_detection_system_tpu.ops.cms import (
     CountMinSketch,
     cms_add_fraud,
     cms_init,
+    cms_query,
     cms_query_fraud,
     cms_update,
 )
-from real_time_fraud_detection_system_tpu.ops.hashing import slot_of
+from real_time_fraud_detection_system_tpu.ops.hashing import key_slot
 from real_time_fraud_detection_system_tpu.ops.numerics import div_ieee
 from real_time_fraud_detection_system_tpu.ops.keydir import (
     EMPTY_KEY,
@@ -41,6 +42,7 @@ from real_time_fraud_detection_system_tpu.ops.keydir import (
 )
 from real_time_fraud_detection_system_tpu.ops.windows import (
     WindowState,
+    gather_state_rows,
     init_window_state,
     query_windows,
     update_windows,
@@ -143,19 +145,6 @@ def init_feature_state(
     )
 
 
-def _slot(key: jnp.ndarray, capacity: int, mode: str) -> jnp.ndarray:
-    """Key → table slot. 'direct' is exact for dense serial ids (< capacity);
-    'hash' mixes for sparse key universes. 'exact' never comes through
-    here — it routes through the key directory (admit_slots)."""
-    if mode == "exact":
-        raise ValueError(
-            "key_mode='exact' routes through the key directory "
-            "(ops/keydir.admit_slots), not the static slot map")
-    if mode == "direct":
-        return (key & jnp.uint32(capacity - 1)).astype(jnp.int32)
-    return slot_of(key, capacity)
-
-
 def state_bytes(cfg: FeatureConfig, n_shards: int = 1) -> dict:
     """Static per-tier HBM accounting for the feature state a config
     would build (init_feature_state shapes × dtype bytes; no device
@@ -215,94 +204,149 @@ def _flags(batch: TxBatch, cfg: FeatureConfig) -> Tuple[jnp.ndarray, jnp.ndarray
     return is_weekend, is_night
 
 
-def _update_state_exact(
-    state: FeatureState, batch: TxBatch, cfg: FeatureConfig
-) -> Tuple[FeatureState, jnp.ndarray, jnp.ndarray, jnp.ndarray,
-           jnp.ndarray]:
-    """Tiered scatter-update half (``key_mode="exact"``).
+class TableState(NamedTuple):
+    """One table's share of the :class:`FeatureState` on its owner, the
+    state a :class:`TablePlane` call carries (a pytree: it crosses the
+    sharded exchange's ``lax.cond``)."""
 
-    Returns (new_state, cust_slot, c_adm, term_slot, t_adm): slots route
-    through the exact key directories; rows that miss admission carry
-    ``*_adm=False``, stay OUT of the dense scatters, and are served from
-    the sketch tier by the caller. The sketches are updated with EVERY
-    row (they shadow the full stream), so a key's sketch estimate stays
-    a valid overestimate whether or not it currently holds a hot slot.
+    windows: WindowState
+    directory: Optional[KeyDirectory] = None  # key_mode="exact"
+    sketch: Optional[CountMinSketch] = None
+    tier: Optional[jnp.ndarray] = None  # exact: [dense, cms] rows served
+
+
+@dataclasses.dataclass(frozen=True)
+class TablePlane:
+    """THE table plane: update-then-query of one key space's windows, as
+    its owner runs it. ``plane(tstate, key, day, amount, fraud, valid) ->
+    (tstate', [rows, 2·NW])`` — counts beside amount sums (customer) or
+    fraud sums (terminal). The one-chip step calls it where it stands; the
+    sharded step runs the same body behind ``exchanged_compute``
+    (``parallel/step.py``), with ``n_shards`` the mesh's width.
+
+    Per key mode: ``direct`` / ``hash`` take the slot from
+    :func:`~..ops.hashing.key_slot`; ``exact`` admits the key through the
+    owner's directory, keeps rows that miss admission OUT of the dense
+    scatter and serves them from the sketch tier (overestimate-only
+    counts/amounts; terminal risk a ratio of two overestimates — an
+    estimate, not a bound), counting both in ``tier``. A sketch the state
+    carries is updated with EVERY row, so its estimate stays a valid
+    overestimate whether or not the key holds a hot slot;
+    ``customer_source="cms"`` serves the customer side from it alone."""
+
+    table: str  # "customer" | "terminal": the scope, the column set
+    cfg: FeatureConfig
+    n_shards: int = 1
+
+    @property
+    def _customer(self) -> bool:
+        return self.table == "customer"
+
+    @property
+    def sketch_only(self) -> bool:
+        return self._customer and self.cfg.customer_source == "cms"
+
+    def of(self, state: FeatureState) -> TableState:
+        win, kd, sk = (
+            (state.customer, state.customer_dir, state.cms)
+            if self._customer else
+            (state.terminal, state.terminal_dir, state.terminal_cms))
+        return TableState(win, kd, sk, jnp.zeros(2, jnp.float32)
+                          if self.cfg.key_mode == "exact" else None)
+
+    def update(self, ts: TableState, key, day, amount, fraud, valid):
+        """The scatter half → (tstate', slot, admitted | None)."""
+        cfg = self.cfg
+        win, kd, sketch, tier = ts
+        slot = adm = None
+        if self.sketch_only and sketch is None:
+            raise ValueError(
+                "customer_source='cms' but the feature state has no "
+                "sketch (init_feature_state must be built from the "
+                "same config)")
+        if not self.sketch_only:
+            if cfg.key_mode == "exact":
+                kd, slot, adm = admit_slots(kd, key, valid,
+                                            n_probes=cfg.keydir_probes)
+                valid_hot = valid & adm
+            else:
+                capacity = (cfg.customer_capacity if self._customer
+                            else cfg.terminal_capacity)
+                slot = key_slot(key, capacity, cfg.key_mode, self.n_shards)
+                valid_hot = valid
+            win = update_windows(
+                win, slot, day, amount, fraud, valid_hot,
+                **(CUSTOMER_COLUMNS if self._customer
+                   else TERMINAL_COLUMNS))
+        if sketch is not None:
+            sketch = cms_update(sketch, key, amount, day, valid,
+                                fraud=None if self._customer else fraud)
+        return TableState(win, kd, sketch, tier), slot, adm
+
+    def query(self, ts: TableState, slot, adm, key, day, valid):
+        """The gather half → (tstate' (tier counted), [rows, 2·NW])."""
+        windows = tuple(self.cfg.windows)
+        delay = 0 if self._customer else self.cfg.delay_days
+        pick = (0, 1) if self._customer else (0, 2)  # count, amount|fraud
+        hot = cold = None
+        if slot is not None:
+            got = query_windows(ts.windows, slot, day, windows, delay=delay)
+            hot = [got[i] for i in pick]
+        if slot is None or adm is not None:  # the sketch tier is read
+            got = (cms_query(ts.sketch, key, day, windows) if self._customer
+                   else cms_query_fraud(ts.sketch, key, day, windows,
+                                        delay=delay))
+            cold = [got[i] for i in pick]
+        if adm is not None:
+            hot = [jnp.where(adm[:, None], h, c) for h, c in zip(hot, cold)]
+            ts = ts._replace(tier=ts.tier + jnp.stack([
+                jnp.sum((valid & adm).astype(jnp.float32)),
+                jnp.sum((valid & ~adm).astype(jnp.float32))]))
+        return ts, jnp.concatenate(hot or cold, axis=1)
+
+    def __call__(self, ts: TableState, key, day, amount, fraud, valid):
+        with step_scope(self.table):
+            ts, slot, adm = self.update(ts, key, day, amount, fraud, valid)
+            return self.query(ts, slot, adm, key, day, valid)
+
+
+def fraud_of(batch: TxBatch) -> jnp.ndarray:
+    """Labeled rows (``label >= 0``) carry their fraud flag into the
+    terminal table (the feedback path); unlabeled rows contribute 0."""
+    with step_scope("terminal"):
+        return jnp.maximum(batch.label, 0).astype(jnp.float32)
+
+
+def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
+               n_shards: int = 1, reach_customer=None, reach_terminal=None):
+    """unpacked batch → reach(customer plane) → reach(terminal plane):
+    the state half of THE device step, written once.
+
+    ``reach(plane, tstate, key, fraud) -> (tstate', mat, overflowed)`` is
+    how a table's owner is reached: ``None`` calls the plane where the
+    rows stand (one chip; a mesh's owner-placed customers), the sharded
+    step passes its exchange. ``state`` is one owner's view (a mesh
+    unstacks its per-device leaves first). Returns ``(state', customer
+    [B, 2·NW], terminal [B, 2·NW], tier rows [2] | None, overflows)``.
     """
-    with step_scope("terminal"):
-        fraud = jnp.maximum(batch.label, 0).astype(jnp.float32)
-    probes = cfg.keydir_probes
-    if cfg.customer_source == "cms":
-        customer, customer_dir = state.customer, None
-        cust_slot = jnp.zeros_like(batch.day)
-        c_adm = jnp.zeros_like(batch.valid)
-    else:
-        with step_scope("customer"):
-            customer_dir, cust_slot, c_adm = admit_slots(
-                state.customer_dir, batch.customer_key, batch.valid,
-                n_probes=probes)
-            customer = update_windows(
-                state.customer, cust_slot, batch.day, batch.amount, fraud,
-                batch.valid & c_adm, **CUSTOMER_COLUMNS,
-            )
-    with step_scope("terminal"):
-        terminal_dir, term_slot, t_adm = admit_slots(
-            state.terminal_dir, batch.terminal_key, batch.valid,
-            n_probes=probes)
-        terminal = update_windows(
-            state.terminal, term_slot, batch.day, batch.amount, fraud,
-            batch.valid & t_adm, **TERMINAL_COLUMNS,
-        )
-    with step_scope("customer"):
-        cms = cms_update(state.cms, batch.customer_key, batch.amount,
-                         batch.day, batch.valid)
-    with step_scope("terminal"):
-        terminal_cms = cms_update(state.terminal_cms, batch.terminal_key,
-                                  batch.amount, batch.day, batch.valid,
-                                  fraud=fraud)
-    new_state = FeatureState(
-        customer=customer, terminal=terminal, cms=cms,
-        customer_dir=customer_dir, terminal_dir=terminal_dir,
-        terminal_cms=terminal_cms,
-    )
-    return new_state, cust_slot, c_adm, term_slot, t_adm
+    fraud = fraud_of(batch)
 
+    def local(plane, ts, key, fraud):
+        return plane(ts, key, batch.day, batch.amount, fraud,
+                     batch.valid) + (jnp.zeros((), jnp.int32),)
 
-def _update_state(
-    state: FeatureState, batch: TxBatch, cfg: FeatureConfig
-) -> Tuple[FeatureState, jnp.ndarray, jnp.ndarray]:
-    """Shared scatter-update half of both scoring paths.
-
-    Returns (new_state, cust_slot, term_slot). Labeled rows
-    (``batch.label >= 0``) also scatter fraud counts into the terminal state
-    (the feedback path); unlabeled rows contribute 0.
-    """
-    with step_scope("customer"):
-        cust_slot = _slot(batch.customer_key, cfg.customer_capacity,
-                          cfg.key_mode)
-    with step_scope("terminal"):
-        term_slot = _slot(batch.terminal_key, cfg.terminal_capacity,
-                          cfg.key_mode)
-        # only the terminal table tracks fraud sums
-        fraud = jnp.maximum(batch.label, 0).astype(jnp.float32)
-    if cfg.customer_source == "cms":
-        customer = state.customer  # unused in cms mode: skip the scatter
-    else:
-        with step_scope("customer"):
-            customer = update_windows(
-                state.customer, cust_slot, batch.day, batch.amount, fraud,
-                batch.valid, **CUSTOMER_COLUMNS,
-            )
-    with step_scope("terminal"):
-        terminal = update_windows(
-            state.terminal, term_slot, batch.day, batch.amount, fraud,
-            batch.valid, **TERMINAL_COLUMNS,
-        )
-    cms = state.cms
-    if cms is not None:
-        with step_scope("customer"):
-            cms = cms_update(cms, batch.customer_key, batch.amount,
-                             batch.day, batch.valid)
-    return FeatureState(customer=customer, terminal=terminal, cms=cms), cust_slot, term_slot
+    c_plane = TablePlane("customer", cfg, n_shards)
+    t_plane = TablePlane("terminal", cfg, n_shards)
+    c, c_mat, c_over = (reach_customer or local)(
+        c_plane, c_plane.of(state), batch.customer_key, fraud)
+    t, t_mat, t_over = (reach_terminal or local)(
+        t_plane, t_plane.of(state), batch.terminal_key, fraud)
+    tier = None if t.tier is None else c.tier + t.tier
+    state = FeatureState(
+        customer=c.windows, terminal=t.windows, cms=c.sketch,
+        customer_dir=c.directory, terminal_dir=t.directory,
+        terminal_cms=t.sketch)
+    return state, c_mat, t_mat, tier, c_over + t_over
 
 
 def update_and_featurize(
@@ -310,46 +354,24 @@ def update_and_featurize(
     batch: TxBatch,
     cfg: FeatureConfig,
 ) -> Tuple[FeatureState, jnp.ndarray]:
-    """Returns (new_state, features [B, 15]).
+    """Returns (new_state, features [B, 15]): the planes run locally.
 
     Update-then-query: a row's windows include the current transaction and
     its batch-mates of the same key/day — matching the offline pandas
     ``rolling(...).count()`` which includes the current row
     (``feature_transformation.ipynb · cell 17``), at micro-batch granularity.
     """
-    windows = tuple(cfg.windows)
-    state, cust_slot, term_slot = _update_state(state, batch, cfg)
-    customer, terminal = state.customer, state.terminal
-
-    if cfg.customer_source == "cms":
-        if state.cms is None:
-            raise ValueError(
-                "customer_source='cms' but the feature state has no sketch "
-                "(init_feature_state must be built from the same config)"
-            )
-        from real_time_fraud_detection_system_tpu.ops.cms import cms_query
-
-        with step_scope("customer"):
-            c_count, c_amount = cms_query(
-                state.cms, batch.customer_key, batch.day, windows
-            )
-    else:
-        with step_scope("customer"):
-            c_count, c_amount, _ = query_windows(
-                customer, cust_slot, batch.day, windows
-            )
-    with step_scope("terminal"):
-        t_count, _, t_fraud = query_windows(
-            terminal, term_slot, batch.day, windows, delay=cfg.delay_days
-        )
-    features = _assemble(batch, cfg, c_count, c_amount, t_count, t_fraud)
-    return state, features
+    state, c_mat, t_mat, _, _ = run_planes(state, batch, cfg)
+    return state, assemble(batch, cfg, c_mat, t_mat)
 
 
-def _assemble(batch, cfg, c_count, c_amount, t_count, t_fraud) -> jnp.ndarray:
-    """Window sums → the [B, 15] matrix: the two averages, the calendar
+def assemble(batch, cfg, c_mat, t_mat) -> jnp.ndarray:
+    """The planes' window sums (``[B, 2·NW]`` each: counts, then amount |
+    fraud sums) → the [B, 15] matrix: the two averages, the calendar
     flags, the column stack."""
-    windows = tuple(cfg.windows)
+    nw = len(cfg.windows)
+    c_count, c_amount = c_mat[:, :nw], c_mat[:, nw:]
+    t_count, t_fraud = t_mat[:, :nw], t_mat[:, nw:]
     with step_scope("assemble"):
         # div_ieee: averages bit-equal to NumPy's and to the fused kernels'
         c_avg = jnp.where(
@@ -359,10 +381,10 @@ def _assemble(batch, cfg, c_count, c_amount, t_count, t_fraud) -> jnp.ndarray:
         is_weekend, is_night = _flags(batch, cfg)
         # Feature order must match features/spec.py::FEATURE_NAMES.
         cols = [batch.amount, is_weekend, is_night]
-        for i in range(len(windows)):
+        for i in range(nw):
             cols.append(c_count[:, i])
             cols.append(c_avg[:, i])
-        for i in range(len(windows)):
+        for i in range(nw):
             cols.append(t_count[:, i])
             cols.append(t_risk[:, i])
         return jnp.stack(cols, axis=1)
@@ -373,60 +395,36 @@ def update_and_featurize_exact(
     batch: TxBatch,
     cfg: FeatureConfig,
 ) -> Tuple[FeatureState, jnp.ndarray, jnp.ndarray]:
-    """Tiered twin of :func:`update_and_featurize` (``key_mode="exact"``).
+    """:func:`update_and_featurize` under ``key_mode="exact"``.
 
     Returns (new_state, features [B, 15], tier_rows [2] float32) where
     ``tier_rows = [dense, cms]`` counts (row × keyspace) admissions this
     batch — the device-side source of
-    ``rtfds_feature_tier_rows_total{tier=…}``.
-
-    Per row and keyspace: an admitted key reads its private hot-tier
-    window row (collision-exact — with the hot tier sized to hold every
-    key this path is bit-identical to ``direct`` mode); a row that
-    missed admission reads the count-min sketch instead
-    (overestimate-only counts/amounts; terminal risk becomes a ratio of
-    two overestimates — an estimate, not a bound).
+    ``rtfds_feature_tier_rows_total{tier=…}``. With the hot tier sized to
+    hold every key this path is bit-identical to ``direct`` mode.
     """
-    windows = tuple(cfg.windows)
-    state, cust_slot, c_adm, term_slot, t_adm = _update_state_exact(
-        state, batch, cfg)
+    state, c_mat, t_mat, tier, _ = run_planes(state, batch, cfg)
+    return state, assemble(batch, cfg, c_mat, t_mat), tier
 
-    if cfg.customer_source == "cms":
-        from real_time_fraud_detection_system_tpu.ops.cms import cms_query
 
-        with step_scope("customer"):
-            c_count, c_amount = cms_query(
-                state.cms, batch.customer_key, batch.day, windows)
-        c_tier_rows = jnp.zeros((), jnp.float32)  # no dense customer tier
-        c_miss_rows = jnp.zeros((), jnp.float32)
-    else:
-        from real_time_fraud_detection_system_tpu.ops.cms import cms_query
-
-        with step_scope("customer"):
-            cc_t, ca_t, _ = query_windows(
-                state.customer, cust_slot, batch.day, windows)
-            cc_s, ca_s = cms_query(
-                state.cms, batch.customer_key, batch.day, windows)
-            c_count = jnp.where(c_adm[:, None], cc_t, cc_s)
-            c_amount = jnp.where(c_adm[:, None], ca_t, ca_s)
-        c_tier_rows = jnp.sum((batch.valid & c_adm).astype(jnp.float32))
-        c_miss_rows = jnp.sum((batch.valid & ~c_adm).astype(jnp.float32))
-
-    with step_scope("terminal"):
-        tc_t, _, tf_t = query_windows(
-            state.terminal, term_slot, batch.day, windows,
-            delay=cfg.delay_days)
-        tc_s, _, tf_s = cms_query_fraud(
-            state.terminal_cms, batch.terminal_key, batch.day, windows,
-            delay=cfg.delay_days)
-        t_count = jnp.where(t_adm[:, None], tc_t, tc_s)
-        t_fraud = jnp.where(t_adm[:, None], tf_t, tf_s)
-
-    features = _assemble(batch, cfg, c_count, c_amount, t_count, t_fraud)
-    dense = c_tier_rows + jnp.sum((batch.valid & t_adm).astype(jnp.float32))
-    cms_rows = c_miss_rows + jnp.sum(
-        (batch.valid & ~t_adm).astype(jnp.float32))
-    return state, features, jnp.stack([dense, cms_rows])
+def _update_and_gather(state: FeatureState, batch: TxBatch,
+                       cfg: FeatureConfig):
+    """The fused kernels' state half: the planes' update, then the raw
+    rows the kernels window themselves → (state', customer (bucket_day,
+    count, amount), terminal (bucket_day, count, fraud))."""
+    fraud = fraud_of(batch)
+    rows = []
+    for plane, key in ((TablePlane("customer", cfg), batch.customer_key),
+                       (TablePlane("terminal", cfg), batch.terminal_key)):
+        with step_scope(plane.table):
+            ts, slot, _ = plane.update(
+                plane.of(state), key, batch.day, batch.amount, fraud,
+                batch.valid)
+            rows.append((ts, gather_state_rows(ts.windows, slot)))
+    (c, (c_bd, c_cnt, c_amt, _)), (t, (t_bd, t_cnt, _, t_frd)) = rows
+    state = state._replace(customer=c.windows, terminal=t.windows,
+                           cms=c.sketch)
+    return state, (c_bd, c_cnt, c_amt), (t_bd, t_cnt, t_frd)
 
 
 def update_and_score_pallas(
@@ -449,19 +447,12 @@ def update_and_score_pallas(
     from real_time_fraud_detection_system_tpu.ops.pallas_kernels import (
         fused_featurize_score,
     )
-    from real_time_fraud_detection_system_tpu.ops.windows import (
-        gather_state_rows,
-    )
 
-    state, cust_slot, term_slot = _update_state(state, batch, cfg)
-    with step_scope("customer"):
-        c_bd, c_cnt, c_amt, _ = gather_state_rows(state.customer, cust_slot)
-    with step_scope("terminal"):
-        t_bd, t_cnt, _, t_frd = gather_state_rows(state.terminal, term_slot)
+    state, c_rows, t_rows = _update_and_gather(state, batch, cfg)
     with step_scope("fused_step"):
         probs, feats = fused_featurize_score(
-            (c_bd, c_cnt, c_amt),
-            (t_bd, t_cnt, t_frd),
+            c_rows,
+            t_rows,
             batch.day,
             batch.tod_s,
             batch.amount,
@@ -499,20 +490,13 @@ def update_and_score_pallas_forest(
     from real_time_fraud_detection_system_tpu.ops.pallas_forest import (
         fused_forest_leaf_sum,
     )
-    from real_time_fraud_detection_system_tpu.ops.windows import (
-        gather_state_rows,
-    )
 
-    state, cust_slot, term_slot = _update_state(state, batch, cfg)
-    with step_scope("customer"):
-        c_bd, c_cnt, c_amt, _ = gather_state_rows(state.customer, cust_slot)
-    with step_scope("terminal"):
-        t_bd, t_cnt, _, t_frd = gather_state_rows(state.terminal, term_slot)
+    state, c_rows, t_rows = _update_and_gather(state, batch, cfg)
     with step_scope("fused_step"):
         leaf_sum, feats = fused_forest_leaf_sum(
             pf,
-            (c_bd, c_cnt, c_amt),
-            (t_bd, t_cnt, t_frd),
+            c_rows,
+            t_rows,
             batch.day,
             batch.tod_s,
             batch.amount,
@@ -777,7 +761,7 @@ def apply_feedback(
                                        valid & hit)
         return state._replace(terminal_cms=cms_add_fraud(
             state.terminal_cms, terminal_key, day, label, valid & ~hit))
-    term_slot = _slot(terminal_key, cfg.terminal_capacity, cfg.key_mode)
+    term_slot = key_slot(terminal_key, cfg.terminal_capacity, cfg.key_mode)
     return apply_feedback_at_slot(state, term_slot, day, label, valid)
 
 

@@ -32,6 +32,40 @@ def slot_of(key: jnp.ndarray, capacity: int, seed: int = 0) -> jnp.ndarray:
     return (hash_u32(key, seed) & jnp.uint32(capacity - 1)).astype(jnp.int32)
 
 
+def key_slot(key, capacity: int, key_mode: str = "direct",
+             n_shards: int = 1):
+    """Where a key's row lives: its slot within its owner's block of a
+    keyed table (window tables, history state), int32 [B].
+
+    THE layout rule, one chip or a mesh: shard ``key % n_shards`` owns the
+    key and keeps it at slot ``(key // n_shards) & (capacity/n_shards - 1)``
+    of its contiguous ``capacity / n_shards`` rows (:func:`key_row` is the
+    global row). On one shard that is 'direct' — exact for dense serial
+    ids below ``capacity``; 'hash' mixes sparse key universes first and
+    exists on one shard only: a mesh's layout is owner-modulo and has
+    never hashed. 'exact' never comes through here — it routes through
+    the key directory (``ops/keydir.admit_slots``). Plain operators, so
+    NumPy callers (the state's birth, late labels, reshards) and the
+    jitted step share the one rule; the mask is a modulo only for a
+    power-of-two local capacity, which the engines validate."""
+    if key_mode == "exact":
+        raise ValueError(
+            "key_mode='exact' routes through the key directory "
+            "(ops/keydir.admit_slots), not the static slot map")
+    if n_shards == 1 and key_mode == "hash":
+        return slot_of(key, capacity)
+    local = key if n_shards == 1 else key // n_shards
+    return (local & (capacity // n_shards - 1)).astype("int32")
+
+
+def key_row(key, capacity: int, key_mode: str = "direct",
+            n_shards: int = 1):
+    """Global table row of ``key``: owner block × local :func:`key_slot`."""
+    owner = (key % n_shards).astype("int32")
+    return owner * (capacity // n_shards) + key_slot(
+        key, capacity, key_mode, n_shards)
+
+
 def multi_hash(key: jnp.ndarray, depth: int, width: int) -> jnp.ndarray:
     """[B] keys → [depth, B] independent column indices in [0, width)."""
     assert width & (width - 1) == 0, "width must be a power of 2"

@@ -32,6 +32,7 @@ from real_time_fraud_detection_system_tpu.features.online import (
     FeatureState,
     init_feature_state,
 )
+from real_time_fraud_detection_system_tpu.ops.hashing import key_row
 from real_time_fraud_detection_system_tpu.ops.windows import COLUMNS
 
 
@@ -235,15 +236,11 @@ def _host_tables(ws):
 def _layout_perm(cap: int, n_dev: int) -> np.ndarray:
     """Global table row of key k under the n-device owner layout.
 
-    Single-chip (n=1): row = k. Sharded: device ``k % n`` owns contiguous
-    rows ``[owner * cap/n, (owner+1) * cap/n)`` and places k at local slot
-    ``k // n`` (``parallel/step.py``'s ``(key // n) & (cap_local - 1)``,
-    a no-op mask for k < cap) — so row = (k % n) * (cap/n) + k // n.
-    A bijection for pow2 cap/n, which the sharded step validates."""
-    k = np.arange(cap)
-    if n_dev == 1:
-        return k
-    return (k % n_dev) * (cap // n_dev) + k // n_dev
+    ``ops/hashing.key_row``, the one layout rule: row = k on one chip;
+    on a mesh device ``k % n`` owns contiguous rows and places k at local
+    slot ``k // n``. A bijection for pow2 cap/n, which the sharded step
+    validates."""
+    return key_row(np.arange(cap), cap, "direct", n_dev)
 
 
 def reshard_feature_state(

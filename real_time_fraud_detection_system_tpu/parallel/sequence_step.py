@@ -24,9 +24,9 @@ Sharded layout: every :class:`~..features.history.HistoryState` leaf
 gains a leading device axis ([n_dev, cap_local+1, ...], sharded on it);
 each device block is a self-contained local HistoryState (with its own
 padding-sink row), so the single-chip kernel runs unchanged inside
-``shard_map``. Local slot for key k on its owner: ``(k // n_dev) &
-(cap_local - 1)`` — mirroring the window layout (``step.py``), and like
-it requiring ``key_mode="direct"``.
+``shard_map``. Local slot for key k on its owner:
+``ops/hashing.key_slot`` at the mesh's width — the window layout's rule,
+requiring ``key_mode="direct"`` here.
 """
 
 from __future__ import annotations
@@ -39,6 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from real_time_fraud_detection_system_tpu.config import Config
 from real_time_fraud_detection_system_tpu.core.batch import TxBatch
+from real_time_fraud_detection_system_tpu.ops.hashing import key_slot
 from real_time_fraud_detection_system_tpu.parallel.mesh import (
     compat_shard_map,
 )
@@ -69,7 +70,7 @@ def _stacked_blank(fcfg, n_dev: int, as_jnp: bool):
 
 
 def _require_pow2_local(cap_local: int) -> None:
-    """Local slot math is ``(key // n) & (cap_local - 1)`` — a modulo only
+    """``key_slot`` masks with ``cap_local - 1`` — a modulo only
     when cap_local is a power of two. A non-pow2 local capacity would pass
     the divisibility check yet silently merge distinct customers' history
     (breaking the EXACT elastic-reshard contract), so reject it here."""
@@ -154,7 +155,7 @@ def reshard_history_state(state, cfg: Config, n_dev_new: int):
             np.asarray, init_history_state(fcfg))
         out = [np.array(a) for a in single]
         keys = np.arange(cap)
-        owner, local = keys % n_old, (keys // n_old) & (cap_local - 1)
+        owner, local = keys % n_old, key_slot(keys, cap, "direct", n_old)
         for i, a in enumerate(leaves):
             out[i][keys] = a[owner, local]
         return HistoryState(*out)
@@ -168,7 +169,8 @@ def reshard_history_state(state, cfg: Config, n_dev_new: int):
     _require_pow2_local(cap_local)
     out = list(_stacked_blank(fcfg, n_dev_new, as_jnp=False))
     keys = np.arange(cap)
-    owner, local = keys % n_dev_new, (keys // n_dev_new) & (cap_local - 1)
+    owner, local = keys % n_dev_new, key_slot(keys, cap, "direct",
+                                              n_dev_new)
     for i, a in enumerate(single):
         out[i][owner, local] = np.asarray(a)[keys]
     return HistoryState(*[jnp.asarray(a) for a in out])
@@ -202,8 +204,7 @@ def make_sharded_sequence_step(
 
 
     def slot_fn(key):
-        return ((key // jnp.uint32(n_dev))
-                & jnp.uint32(cap_local - 1)).astype(jnp.int32)
+        return key_slot(key, fcfg.customer_capacity, "direct", n_dev)
 
     def local_step(hstate, params, batch: TxBatch, order_key):
         from real_time_fraud_detection_system_tpu.parallel.step import (

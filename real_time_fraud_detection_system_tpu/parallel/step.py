@@ -15,14 +15,18 @@ Distribution contract (SURVEY §2.3 mapping):
   so every device applies the identical update (data-parallel training,
   BASELINE.json config 4).
 
+The body is the one-chip step's (``features/step.py``): the same
+``run_planes`` and tail run inside ``shard_map``, with the exchange
+below as the way a table's owner is reached.
+
 Everything is static-shape: the exchange buffer is [n_dev × B_local] per
 field (worst case: every local row targets one owner).
 
 ``key_mode="exact"`` (the tiered feature store) keeps this exact wire
 contract — ownership is still ``key % n_dev``, so the host partitioner
 and the owner exchange route identically — but the slot WITHIN a shard
-comes from that shard's private key directory instead of the
-``(key // n_dev) & (cap_local - 1)`` modulo math: each owner resolves
+comes from that shard's private key directory instead of
+``ops/hashing.key_slot``'s modulo math: each owner resolves
 its received (key, row) records through ``admit_slots`` locally,
 admission misses are served from the owner's per-device sketch replica,
 and per-shard [dense, cms] tier counts leave the step stacked
@@ -33,6 +37,7 @@ pass per shard under the same ``shard_map``.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 from functools import partial
 from typing import Callable, Optional, Tuple
 
@@ -44,17 +49,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from real_time_fraud_detection_system_tpu.config import Config
 from real_time_fraud_detection_system_tpu.core.batch import TxBatch
 from real_time_fraud_detection_system_tpu.features.online import (
-    CUSTOMER_COLUMNS,
-    TERMINAL_COLUMNS,
     FeatureState,
-    _assemble,
+    run_planes,
 )
-from real_time_fraud_detection_system_tpu.features.spec import N_FEATURES
-from real_time_fraud_detection_system_tpu.models.scaler import Scaler, transform
-from real_time_fraud_detection_system_tpu.ops.windows import (
-    query_windows,
-    update_windows,
+from real_time_fraud_detection_system_tpu.features.step import (
+    make_tail,
+    pack_selective,
+    selective,
 )
+from real_time_fraud_detection_system_tpu.models.scaler import Scaler
 from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
 
@@ -267,46 +270,42 @@ def make_sharded_step(
     assert mesh is not None
     n_dev = mesh.devices.size
     fcfg = cfg.features
-    use_cms = fcfg.customer_source == "cms"
     exact = fcfg.key_mode == "exact"
-    probes = fcfg.keydir_probes
-    windows = tuple(fcfg.windows)
-    nw = len(windows)
-    c_cap_local = fcfg.customer_capacity // n_dev
-    t_cap_local = fcfg.terminal_capacity // n_dev
-    for nm, cl in (("customer", c_cap_local), ("terminal", t_cap_local)):
-        # Local slot placement masks with `& (cap_local - 1)`, which is a
-        # modulo only for powers of two; a non-pow2 local capacity would
-        # silently alias distinct keys' window state.
+    if fcfg.key_mode == "hash":
+        # the mesh's layout is owner-modulo at every width, 1 included
+        # (ops/hashing.key_slot): it has never hashed
+        fcfg = dataclasses.replace(fcfg, key_mode="direct")
+    for nm, cap in (("customer", fcfg.customer_capacity),
+                    ("terminal", fcfg.terminal_capacity)):
+        # key_slot masks with `& (cap_local - 1)`, which is a modulo only
+        # for powers of two; a non-pow2 local capacity would silently
+        # alias distinct keys' window state.
+        cl = cap // n_dev
         if cl <= 0 or (cl & (cl - 1)):
             raise ValueError(
                 f"{nm}_capacity / n_devices must be a power of two, "
                 f"got {cl}")
 
-    def _unstack(t):
-        """Shard-stacked leaves ([1, ...] local blocks under P(axis)) →
-        the per-device view the single-shard ops consume."""
-        return (jax.tree.map(lambda x: jnp.squeeze(x, 0), t)
-                if t is not None else None)
+    def reduce_grads(g, labeled):
+        """Data-parallel SGD: psum'd gradients, so every device applies
+        the identical update."""
+        return (jax.tree.map(lambda gi: jax.lax.psum(gi, axis) / n_dev, g),
+                jnp.any(jax.lax.psum(labeled.astype(jnp.int32), axis) > 0))
 
-    def _restack(t):
-        return (jax.tree.map(lambda x: x[None], t)
-                if t is not None else None)
+    tail = make_tail(cfg, predict_fn, loss_fn, online_lr, reduce_grads)
+    def per_device(fstate: FeatureState, f):
+        """``f`` over the leaves that carry a leading shard axis ([1, ...]
+        local blocks under P(axis): directories, sketch replicas); the
+        window columns are flat and shard as they are."""
+        return fstate._replace(**{
+            name: jax.tree.map(f, getattr(fstate, name))
+            for name in ("cms", "customer_dir", "terminal_dir",
+                         "terminal_cms")})
 
     def local_step(fstate: FeatureState, params, scaler: Scaler, batch: TxBatch):
-        from real_time_fraud_detection_system_tpu.ops.cms import (
-            cms_query,
-            cms_query_fraud,
-            cms_update,
-        )
-        from real_time_fraud_detection_system_tpu.ops.keydir import (
-            admit_slots,
-        )
-
         bl = batch.customer_key.shape[0]
-        fraud = jnp.maximum(batch.label, 0).astype(jnp.float32)
 
-        def exchanged_compute(key, fn, state):
+        def exchanged_compute(fn, state, key, fraud):
             """Route (key, day, amount, fraud, valid) to the key's owner
             device, run ``fn(state, key, day, amount, fraud, valid) ->
             (state', mat)`` there, and route ``mat``'s per-row aggregates
@@ -408,217 +407,25 @@ def make_sharded_step(
             return jax.lax.cond(over, run(bl), run(cap_pair), state) + (
                 over.astype(jnp.int32),)
 
-        # ---- customer velocity ------------------------------------------
-        # Owner-local (chunk 0: rows placed by customer % n_dev) or routed
-        # (dense spill chunks: rows anywhere, owner reached over ICI).
-        cms = fstate.cms
-        local_cms = (
-            jax.tree.map(lambda x: jnp.squeeze(x, 0), cms)
-            if cms is not None
-            else None
-        )
-        if exact:
-            # Tiered exact store over the mesh: ownership stays the cheap
-            # stable modulo (key % n_dev — what the host partitioner and
-            # the owner exchange already route by), but the slot WITHIN a
-            # shard comes from that shard's private key directory. The
-            # capacity-bounded exchange ships the same (key, row) wire
-            # records as direct mode; each owner resolves slots locally
-            # via admit_slots, and admission misses are served from the
-            # owner's sketch replica — exactly the single-chip tiering,
-            # one instance per shard. Tier counts accumulate OWNER-side
-            # (skew is a per-shard property) and leave the step as a
-            # [n_dev, 2] stack.
-            c_kd = _unstack(fstate.customer_dir)
-            t_kd = _unstack(fstate.terminal_dir)
-            t_cms = _unstack(fstate.terminal_cms)
-            zero2 = jnp.zeros(2, jnp.float32)  # [dense, cms] rows served
-
-            def customer_fn_x(st, c_key, c_day, c_amt, c_fraud, c_valid):
-                kd, customer, lcms, cnt = st
-                with step_scope("customer"):
-                    if kd is None:
-                        # customer_source="cms": sketch-only velocity (no
-                        # dense customer tier, no tier accounting —
-                        # matching the single-chip exact engine)
-                        lcms = cms_update(lcms, c_key, c_amt, c_day,
-                                          c_valid)
-                        cc, ca = cms_query(lcms, c_key, c_day, windows)
-                        return (kd, customer, lcms, cnt), jnp.concatenate(
-                            [cc, ca], axis=1)
-                    kd, c_slot, c_adm = admit_slots(kd, c_key, c_valid,
-                                                    n_probes=probes)
-                    customer = update_windows(
-                        customer, c_slot, c_day, c_amt, c_fraud,
-                        c_valid & c_adm, **CUSTOMER_COLUMNS)
-                    lcms = cms_update(lcms, c_key, c_amt, c_day, c_valid)
-                    cc_t, ca_t, _ = query_windows(customer, c_slot, c_day,
-                                                  windows)
-                    cc_s, ca_s = cms_query(lcms, c_key, c_day, windows)
-                    cc = jnp.where(c_adm[:, None], cc_t, cc_s)
-                    ca = jnp.where(c_adm[:, None], ca_t, ca_s)
-                    cnt = cnt + jnp.stack([
-                        jnp.sum((c_valid & c_adm).astype(jnp.float32)),
-                        jnp.sum((c_valid & ~c_adm).astype(jnp.float32))])
-                    return (kd, customer, lcms, cnt), jnp.concatenate(
-                        [cc, ca], axis=1)
-
-            st0 = (c_kd, fstate.customer, local_cms, zero2)
-            c_over = jnp.zeros((), jnp.int32)
-            if route_customers:
-                ((c_kd, customer, local_cms, c_cnt), cb,
-                 c_over) = exchanged_compute(
-                    batch.customer_key, customer_fn_x, st0)
-            else:
-                (c_kd, customer, local_cms, c_cnt), cb = customer_fn_x(
-                    st0, batch.customer_key, batch.day, batch.amount,
-                    fraud, batch.valid)
-            c_count, c_amount = cb[:, :nw], cb[:, nw:]
-            cms = jax.tree.map(lambda x: x[None], local_cms)
-
-            def terminal_fn_x(st, t_key, t_day, t_amt, t_fraud_in,
-                              t_valid):
-                kd, terminal, tcms, cnt = st
-                with step_scope("terminal"):
-                    kd, t_slot, t_adm = admit_slots(kd, t_key, t_valid,
-                                                    n_probes=probes)
-                    terminal = update_windows(
-                        terminal, t_slot, t_day, t_amt, t_fraud_in,
-                        t_valid & t_adm, **TERMINAL_COLUMNS)
-                    tcms = cms_update(tcms, t_key, t_amt, t_day, t_valid,
-                                      fraud=t_fraud_in)
-                    tc_t, _, tf_t = query_windows(
-                        terminal, t_slot, t_day, windows,
-                        delay=fcfg.delay_days)
-                    tc_s, _, tf_s = cms_query_fraud(
-                        tcms, t_key, t_day, windows, delay=fcfg.delay_days)
-                    tc = jnp.where(t_adm[:, None], tc_t, tc_s)
-                    tf = jnp.where(t_adm[:, None], tf_t, tf_s)
-                    cnt = cnt + jnp.stack([
-                        jnp.sum((t_valid & t_adm).astype(jnp.float32)),
-                        jnp.sum((t_valid & ~t_adm).astype(jnp.float32))])
-                    return (kd, terminal, tcms, cnt), jnp.concatenate(
-                        [tc, tf], axis=1)
-
-            (t_kd, terminal, t_cms, t_cnt), tb, t_over = exchanged_compute(
-                batch.terminal_key, terminal_fn_x,
-                (t_kd, fstate.terminal, t_cms, zero2))
-            t_count_l, t_fraud_l = tb[:, :nw], tb[:, nw:]
-            return _assemble_and_score(
-                fstate, params, scaler, batch, fraud,
-                customer, terminal, cms,
-                c_count, c_amount, t_count_l, t_fraud_l,
-                customer_dir=_restack(c_kd), terminal_dir=_restack(t_kd),
-                terminal_cms=_restack(t_cms),
-                tier=(c_cnt + t_cnt)[None], overflows=c_over + t_over)
-
-        def customer_fn(st, c_key, c_day, c_amt, c_fraud, c_valid):
-            """Owner-side customer velocity: sketch/window update + query
-            on the rows this device owns; returns [*, 2·NW] aggregates."""
-            local_cms, customer = st
-            with step_scope("customer"):
-                if local_cms is not None:
-                    local_cms = cms_update(local_cms, c_key, c_amt, c_day,
-                                           c_valid)
-                if use_cms:
-                    # BASELINE config 3 × config 5: unbounded-key velocity
-                    # from the per-device sketch (each sketch holds only
-                    # this device's customers — fewer collisions than one
-                    # global sketch).
-                    cc, ca = cms_query(local_cms, c_key, c_day, windows)
-                else:
-                    c_slot = ((c_key // jnp.uint32(n_dev))
-                              & jnp.uint32(c_cap_local - 1)
-                              ).astype(jnp.int32)
-                    customer = update_windows(
-                        customer, c_slot, c_day, c_amt, c_fraud, c_valid,
-                        **CUSTOMER_COLUMNS)
-                    cc, ca, _ = query_windows(customer, c_slot, c_day,
-                                              windows)
-                return (local_cms, customer), jnp.concatenate([cc, ca],
-                                                              axis=1)
-
-        c_over = jnp.zeros((), jnp.int32)
-        if route_customers:
-            (local_cms, customer), cb, c_over = exchanged_compute(
-                batch.customer_key, customer_fn,
-                (local_cms, fstate.customer))
-        else:
-            (local_cms, customer), cb = customer_fn(
-                (local_cms, fstate.customer), batch.customer_key,
-                batch.day, batch.amount, fraud, batch.valid)
-        c_count, c_amount = cb[:, :nw], cb[:, nw:]
-        if cms is not None:
-            cms = jax.tree.map(lambda x: x[None], local_cms)
-
-        # ---- terminal windows: always routed to owner over ICI ----------
-        def terminal_fn(terminal, t_key, t_day, t_amt, t_fraud_in,
-                        t_valid):
-            with step_scope("terminal"):
-                t_slot = ((t_key // jnp.uint32(n_dev))
-                          & jnp.uint32(t_cap_local - 1)).astype(jnp.int32)
-                terminal = update_windows(
-                    terminal, t_slot, t_day, t_amt, t_fraud_in, t_valid,
-                    **TERMINAL_COLUMNS)
-                t_count, _, t_fraud = query_windows(
-                    terminal, t_slot, t_day, windows,
-                    delay=fcfg.delay_days)
-                return terminal, jnp.concatenate([t_count, t_fraud],
-                                                 axis=1)
-
-        terminal, tb, t_over = exchanged_compute(
-            batch.terminal_key, terminal_fn, fstate.terminal)
-        t_count_l, t_fraud_l = tb[:, :nw], tb[:, nw:]
-        return _assemble_and_score(
-            fstate, params, scaler, batch, fraud,
-            customer, terminal, cms,
-            c_count, c_amount, t_count_l, t_fraud_l,
-            overflows=c_over + t_over)
-
-    def _assemble_and_score(fstate, params, scaler, batch, fraud,
-                            customer, terminal, cms,
-                            c_count, c_amount, t_count_l, t_fraud_l,
-                            customer_dir=None, terminal_dir=None,
-                            terminal_cms=None, tier=None, *, overflows):
-        """Shared tail of ``local_step``: 15-feature assembly (order =
-        features/spec.py), classify, optional psum'd online SGD, and the
-        new-state pytree — identical math for the direct/hash and exact
-        state planes, so the tiered store cannot drift the scoring
-        arithmetic."""
-        feats = _assemble(batch, fcfg, c_count, c_amount, t_count_l,
-                          t_fraud_l)
-
-        # ---- score (+ optional online SGD with psum'd grads)
-        x = transform(scaler, feats)
-        with step_scope("classify"):
-            probs = jnp.where(batch.valid, predict_fn(params, x), 0.0)
-        if online_lr > 0.0 and loss_fn is not None:
-            with step_scope("learn"):
-                labeled = batch.valid & (batch.label >= 0)
-                y = jnp.maximum(batch.label, 0)
-                g = jax.grad(loss_fn)(params, x, y, labeled)
-                g = jax.tree.map(
-                    lambda gi: jax.lax.psum(gi, axis) / n_dev, g)
-                has = jnp.any(
-                    jax.lax.psum(labeled.astype(jnp.int32), axis) > 0
-                ).astype(jnp.float32)
-                params = jax.tree.map(
-                    lambda p, gi: p - online_lr * has * gi, params, g)
-
-        new_state = FeatureState(customer=customer, terminal=terminal,
-                                 cms=cms, customer_dir=customer_dir,
-                                 terminal_dir=terminal_dir,
-                                 terminal_cms=terminal_cms)
-        if cfg.runtime.emit_dtype == "bfloat16":
-            # halve the emitted matrix's D2H bytes; the classifier above
-            # already consumed the f32 features (predictions unaffected)
-            with step_scope("emit"):
-                feats = feats.astype(jnp.bfloat16)
-        out = (new_state, params, probs, feats)
-        if tier is not None:
-            out += (tier,)
-        # uniform over the mesh (psum'd): leaves replicated
-        return out + (overflows,)
+        # unstack → reach(customer plane) → reach(terminal plane) → tail
+        # → restack. Customers are owner-local (chunk 0: rows placed by
+        # customer % n_dev) or routed like terminals (dense spill chunks:
+        # rows anywhere); terminals always travel to their owner over ICI.
+        # Exact mode ships the same (key, row) wire records: each owner
+        # resolves slots through ITS directory and serves admission
+        # misses from ITS sketch replica, and the tier counts accumulate
+        # OWNER-side (skew is a per-shard property), leaving as a
+        # [n_dev, 2] stack.
+        fstate, c_mat, t_mat, tier, overflows = run_planes(
+            per_device(fstate, lambda x: jnp.squeeze(x, 0)),
+            batch, fcfg, n_dev,
+            reach_customer=exchanged_compute if route_customers else None,
+            reach_terminal=exchanged_compute)
+        params, probs, feats = tail(params, scaler, batch, c_mat, t_mat)
+        fstate = per_device(fstate, lambda x: x[None])
+        # overflows: uniform over the mesh (psum'd), leaves replicated
+        return (fstate, params, probs, feats) + (
+            () if tier is None else (tier[None],)) + (overflows,)
 
     from real_time_fraud_detection_system_tpu.parallel.mesh import (
         compat_shard_map,
@@ -668,38 +475,18 @@ def make_sharded_step(
         ) + ((P(axis, None),) if exact else ()  # [n_dev, 2] tier rows
              ) + (P(),)  # exchange overflows
         fn = _shard_map(local_step, in_specs, out_specs)
-        thresh = float(cfg.runtime.emit_threshold)
-        selective = cfg.runtime.emit_features and thresh > 0.0
-        cap_frac = cfg.runtime.emit_cap_fraction
 
         def outer(fstate, params, scaler, batch_in):
             with step_scope("unpack"):
                 batch = unpack_batch(batch_in) if packed else batch_in
-            out = fn(fstate, params, scaler, batch)
             # after the four: the tier rows (exact), the overflow count
-            extra = out[4:]
-            fstate, params, probs, feats = out[:4]
-            if not selective:
-                return (fstate, params, probs, feats) + extra
-            # Selective emission over the mesh: the same packed-transfer
-            # contract as the single-chip engine (engine.py step tail) —
-            # probs for every row, feature vectors compacted to flagged
-            # rows, one flat f32 array per chunk. The compaction runs on
-            # the GLOBAL arrays outside shard_map (XLA inserts the gather
-            # collectives); indices are global chunk slots, exact in f32
-            # for any chunk ≤ 2^24 slots.
-            pad = batch.valid.shape[0]
-            cap = max(8, int(pad * cap_frac))
-            with step_scope("emit"):
-                flagged = batch.valid & (probs >= thresh)
-                idx = jnp.nonzero(flagged, size=cap, fill_value=0)[0]
-                count = jnp.sum(flagged).astype(jnp.float32)
-                packed_out = jnp.concatenate([
-                    probs, count[None], idx.astype(jnp.float32),
-                    feats[idx].reshape(-1),
-                ])
-            emit = {"packed": packed_out, "full": feats}
-            return (fstate, params, probs, emit) + extra
+            fstate, params, probs, feats, *extra = fn(
+                fstate, params, scaler, batch)
+            if selective(cfg):
+                # on the GLOBAL arrays outside shard_map (XLA inserts the
+                # gather collectives)
+                feats = pack_selective(cfg, batch.valid, probs, feats)
+            return (fstate, params, probs, feats, *extra)
 
         return jax.jit(outer, donate_argnums=(0,))
 

@@ -36,11 +36,8 @@ from real_time_fraud_detection_system_tpu.features.online import (
     FeatureState,
     init_feature_state,
     state_bytes,
-    update_and_featurize,
-    update_and_featurize_exact,
-    update_and_score_pallas,
-    update_and_score_pallas_forest,
 )
+from real_time_fraud_detection_system_tpu.features.step import make_step
 from real_time_fraud_detection_system_tpu.features.spec import N_FEATURES
 from real_time_fraud_detection_system_tpu.models.forest import (
     TreeEnsemble,
@@ -479,118 +476,15 @@ class ScoringEngine:
         self._predict = predict_fn_for(kind, z_mode=self.z_mode)
         self._loss = loss_fn_for(kind)
         fcfg = cfg.features
-        z_mode = self.z_mode
-
         # What --use-pallas serves is decided in ONE place,
-        # _pallas_choice(params). The step below and the predict swap
+        # _pallas_choice(params). The step and the predict swap
         # (_maybe_use_pallas_forest) take its answer through
         # _announce_pallas at TRACE time, so the gauge / WARNING are
         # written by the code that makes the choice — here at build, on
         # every params swap, and whenever the step retraces.
         self._maybe_use_pallas_forest(kind)
         self._announce_pallas(params)
-
-        exact = self._exact
-
-        def _featurize(fstate, batch):
-            # one shared featurize for the non-fused branches: the tiered
-            # exact path additionally returns [dense, cms] row counts
-            if exact:
-                return update_and_featurize_exact(fstate, batch, fcfg)
-            fstate, feats = update_and_featurize(fstate, batch, fcfg)
-            return fstate, feats, None
-
-        def step(fstate: FeatureState, params, scaler: Scaler, packed):
-            # One packed H2D array per batch (see core.batch.pack_batch):
-            # the unpack is free bitcasts inside the fused program.
-            with step_scope("unpack"):
-                batch = unpack_batch(packed)
-            tier = None
-            # `kernel` is a trace-time fact: _pallas_choice reads the
-            # config and params' pytree FORM and static shapes, never a
-            # traced value. A retrace when a reload or an in-place
-            # restore changes the form is intended, and the gate reports
-            # its own choice as it makes it.
-            kernel = self._announce_pallas(params)
-            # rtfdslint: disable=jit-recompile-hazard (kernel is a str computed from static facts only — config, isinstance on the params pytree, admit_block over static .shape; no traced VALUE is branched on)
-            if kernel == "fused_logreg":
-                fstate, probs, feats = update_and_score_pallas(
-                    fstate, batch, fcfg, scaler.mean, scaler.scale,
-                    params.w, params.b,
-                )
-                x = transform(scaler, feats)
-            # rtfdslint: disable=jit-recompile-hazard (same static str as the branch above)
-            elif kernel == "fused_forest":
-                from real_time_fraud_detection_system_tpu.ops.pallas_forest \
-                    import to_pallas
-
-                pf = to_pallas(params, z_mode)
-                fstate, leaf, feats = update_and_score_pallas_forest(
-                    fstate, batch, fcfg, scaler.mean, scaler.scale, pf,
-                )
-                x = transform(scaler, feats)
-                with step_scope("fused_step"):
-                    probs = jnp.where(batch.valid, leaf / pf.n_trees, 0.0)
-            elif self.scorer == "cpu":
-                # Oracle serving: the classifier runs host-side on the
-                # returned features (process_batch), so don't burn device
-                # time on a predict whose output is discarded.
-                fstate, feats, tier = _featurize(fstate, batch)
-                x = transform(scaler, feats)
-                probs = jnp.zeros(batch.valid.shape, jnp.float32)
-            else:
-                fstate, feats, tier = _featurize(fstate, batch)
-                x = transform(scaler, feats)
-                with step_scope("classify"):
-                    probs = self._predict(params, x)
-                    probs = jnp.where(batch.valid, probs, 0.0)
-            if self.online_lr > 0.0 and self._loss is not None:
-                with step_scope("learn"):
-                    labeled = batch.valid & (batch.label >= 0)
-                    y = jnp.maximum(batch.label, 0)
-                    g = jax.grad(self._loss)(params, x, y, labeled)
-                    has = jnp.any(labeled).astype(jnp.float32)
-                    params = jax.tree.map(
-                        lambda p, gi: p - self.online_lr * has * gi,
-                        params, g)
-            if cfg.runtime.emit_dtype == "bfloat16":
-                # halve the emitted matrix's D2H bytes; the classifier
-                # above consumed the f32 features (predictions unaffected)
-                with step_scope("emit"):
-                    feats = feats.astype(jnp.bfloat16)
-            if self._selective:
-                # On-device compaction: gather the flagged rows' feature
-                # vectors into a fixed-capacity buffer, then pack
-                # probs + count + indices + features into ONE flat f32
-                # array — a batch costs a single D2H transfer (the same
-                # round-trip count as alerts-only serving) instead of a
-                # full [B, 15] matrix. Indices ride as f32, exact for any
-                # batch ≤ 2^24 rows (max_batch_rows is 2^20). The full
-                # matrix is ALSO returned (it already exists; untouched
-                # HBM until fetched) as the overflow fallback.
-                pad = batch.valid.shape[0]
-                cap = max(8, int(pad * cfg.runtime.emit_cap_fraction))
-                with step_scope("emit"):
-                    flagged = batch.valid & (probs >= thresh)
-                    idx = jnp.nonzero(flagged, size=cap, fill_value=0)[0]
-                    count = jnp.sum(flagged).astype(jnp.float32)
-                    packed_out = jnp.concatenate([
-                        probs,
-                        count[None],
-                        idx.astype(jnp.float32),
-                        feats[idx].reshape(-1),
-                    ])
-                emit = {"packed": packed_out, "full": feats}
-            else:
-                emit = feats
-            if exact:
-                # 5th output only in the tiered mode: every engine config
-                # has ONE static step arity, so the dispatch signatures
-                # stay enumerable (dispatch_inventory) and AOT-coverable.
-                return fstate, params, probs, emit, tier
-            return fstate, params, probs, emit
-
-        self._step = jax.jit(step, donate_argnums=self._donate)
+        self._build_step()
         if self._exact:
             from real_time_fraud_detection_system_tpu.features.online \
                 import compact_feature_state
@@ -610,6 +504,20 @@ class ScoringEngine:
             self._compact = jax.jit(compact, donate_argnums=self._donate)
             if demote:
                 self._init_cold(fcfg)
+
+    def _build_step(self) -> None:
+        """The jitted one-chip step (``features/step.py::make_step``).
+        ``self._predict`` is read when the step traces, not now: the
+        verifier's fixtures and a params swap replace it on a built
+        engine."""
+        self._step = jax.jit(
+            make_step(
+                self.cfg,
+                None if self.scorer == "cpu"
+                else (lambda p, x: self._predict(p, x)),
+                self._loss, self.online_lr,
+                kernel_of=self._announce_pallas, z_mode=self.z_mode),
+            donate_argnums=self._donate)
 
     def _init_telemetry(self, metrics) -> None:
         """Resolve the registry series ONCE at build time: the hot loop

@@ -55,6 +55,7 @@ from real_time_fraud_detection_system_tpu.models.scaler import Scaler
 from real_time_fraud_detection_system_tpu.ops.dedup import (
     latest_wins_mask_host,
 )
+from real_time_fraud_detection_system_tpu.ops.hashing import key_row
 from real_time_fraud_detection_system_tpu.parallel.mesh import (
     init_sharded_feature_state,
     make_mesh,
@@ -67,7 +68,6 @@ from real_time_fraud_detection_system_tpu.parallel.step import (
 from real_time_fraud_detection_system_tpu.runtime.engine import (
     BatchResult,
     ScoringEngine,
-    loss_fn_for,
 )
 from real_time_fraud_detection_system_tpu.utils.xla_telemetry import (
     step_signature,
@@ -249,14 +249,13 @@ class ShardedScoringEngine(ScoringEngine):
             if pre_state is None:
                 pre_state = init_sharded_feature_state(
                     cfg.features, mesh, axis=axis)
+        self.mesh = mesh  # _build_step, inside the base constructor
+        self.axis = axis
         super().__init__(
             cfg, kind, params, scaler, feature_state=pre_state,
             online_lr=online_lr, feature_cache=feature_cache,
             metrics=metrics, dead_letter=dead_letter,
         )
-        self.mesh = mesh
-        self.axis = axis
-        self.n_dev = int(self.mesh.devices.size)
         self.state.layout_devices = self.n_dev
         if self.topology is not None:
             # the writer's topology travels WITH the state: a per-process
@@ -357,32 +356,6 @@ class ShardedScoringEngine(ScoringEngine):
         self.state.feature_state = shard_feature_state(
             self.state.feature_state, self.mesh, axis=self.axis,
         )
-        # self._predict, not a fresh predict_fn_for(kind): the base
-        # constructor may have swapped in the fused Pallas tree scorer
-        # (use_pallas) — the mesh engine must serve the same kernel.
-        self._sharded_build = make_sharded_step(
-            cfg,
-            self._predict,
-            loss_fn=loss_fn_for(kind),
-            online_lr=online_lr,
-            mesh=self.mesh,
-            axis=self.axis,
-            packed=True,  # one H2D copy per chunk (see _start_batch)
-        )
-        # Dense-spill variant (customers routed to owner like terminals);
-        # compiled lazily on the first hot-key overflow.
-        self._sharded_build_routed = make_sharded_step(
-            cfg,
-            self._predict,
-            loss_fn=loss_fn_for(kind),
-            online_lr=online_lr,
-            mesh=self.mesh,
-            axis=self.axis,
-            route_customers=True,
-            packed=True,
-        )
-        self._sharded_step = None  # built on first batch (needs templates)
-        self._sharded_step_routed = None
         self._sharded_sf = None
         self._sharded_sf_exact = None
         if self._exact:
@@ -402,6 +375,24 @@ class ShardedScoringEngine(ScoringEngine):
                 # payload blocks, purely shard-local admission
                 self._promote = make_sharded_promote(cfg, self.mesh,
                                                      axis=self.axis)
+
+    def _build_step(self) -> None:
+        """The mesh's two step builders in the one-chip step's place:
+        owner-placed rows, and the dense-spill variant (customers routed
+        to their owner like terminals; compiled lazily on the first
+        hot-key overflow). Both are built on their first batch (they need
+        templates). ``self._predict``, not a fresh ``predict_fn_for``:
+        the base constructor may have swapped in the Pallas tree scorer
+        (use_pallas) and the mesh must serve the same kernel."""
+        self._sharded_build, self._sharded_build_routed = (
+            make_sharded_step(
+                self.cfg, self._predict, loss_fn=self._loss,
+                online_lr=self.online_lr, mesh=self.mesh, axis=self.axis,
+                route_customers=routed,
+                packed=True,  # one H2D copy per chunk (see _start_batch)
+            ) for routed in (False, True))
+        self._sharded_step = None
+        self._sharded_step_routed = None
 
     # -- per-shard feature-state telemetry ---------------------------------
 
@@ -1125,10 +1116,9 @@ class ShardedScoringEngine(ScoringEngine):
         """Land delayed fraud labels in the sharded terminal risk windows.
 
         The sharded layout places terminal key k at global row
-        ``(k % n_dev) * cap_local + ((k // n_dev) & (cap_local - 1))``
-        (owner shard × local slot, mirroring ``parallel/step.py``). The
-        scatter runs as a plain jitted global-array op — GSPMD inserts the
-        (off-hot-path) collectives."""
+        ``ops/hashing.key_row`` (owner shard × local slot, the step's own
+        rule). The scatter runs as a plain jitted global-array op — GSPMD
+        inserts the (off-hot-path) collectives."""
         # cross-width restored state must convert before any slot scatter
         self._ensure_layout()
         if self.kind == "sequence":
@@ -1140,8 +1130,6 @@ class ShardedScoringEngine(ScoringEngine):
         if not mask.any():
             return
         self._ensure_sharded()
-        n_dev = self.n_dev
-        cap_local = self.cfg.features.terminal_capacity // n_dev
         key = fold_key(np.asarray(terminal_ids)[mask]).astype(np.uint32)
         if self._exact:
             # Directory-routed feedback: ownership is key % n_dev (the
@@ -1166,10 +1154,8 @@ class ShardedScoringEngine(ScoringEngine):
                 apply_feedback_at_slot, donate_argnums=(0,)
             )
         if not self._exact:
-            gslot = (
-                (key % np.uint32(n_dev)).astype(np.int64) * cap_local
-                + ((key // np.uint32(n_dev)) & np.uint32(cap_local - 1))
-            ).astype(np.int32)
+            gslot = key_row(key, self.cfg.features.terminal_capacity,
+                            "direct", self.n_dev)
         d = np.asarray(days)[mask].astype(np.int32)
         y = labels[mask].astype(np.int32)
         # Bucket-pad like the single-chip path (engine.py) so a stream of

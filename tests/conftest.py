@@ -39,6 +39,8 @@ def small_dataset():
     return cfg, customers, terminals, txs
 
 
-@pytest.fixture(scope="session")
+@pytest.fixture
 def rng():
+    """A fresh generator per test: what a test draws never depends on
+    which tests its worker ran before it."""
     return np.random.default_rng(0)

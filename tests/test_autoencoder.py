@@ -22,7 +22,8 @@ from real_time_fraud_detection_system_tpu.models.train import train_model
 
 
 @pytest.fixture(scope="module")
-def blob_data(rng):
+def blob_data():
+    rng = np.random.default_rng(0)
     # Legit: tight gaussian blob; anomalies: far-out shell.
     n, f = 3000, 15
     x_legit = rng.normal(0, 1.0, (n, f)).astype(np.float32)

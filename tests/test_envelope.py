@@ -3,6 +3,7 @@
 import base64
 
 import numpy as np
+import pytest
 
 from real_time_fraud_detection_system_tpu.core.envelope import (
     decode_decimal_batch,
@@ -44,8 +45,6 @@ def test_decimal_batch_vectorized_edge_cases():
     """The packed-scatter decode is bit-identical to the scalar reference
     over every byte width 1..8, full-width int64 extremes, sign-bit
     boundaries, and degenerate inputs (empty batch / empty value)."""
-    # local rng, NOT the session fixture: consuming shared draws would
-    # shift every later rng-using test's data
     local = np.random.default_rng(1234)
     vals = [0, 1, -1, 127, 128, -128, -129, 255, -256,
             2**31 - 1, -(2**31), 2**62, -(2**62), 2**63 - 1, -(2**63)]
@@ -92,3 +91,28 @@ def test_envelope_delete_and_tombstone():
     cols, invalid = decode_transaction_envelopes([m_del, tomb, junk])
     assert invalid.tolist() == [False, True, True]
     assert cols["tx_id"][0] == 7 and cols["op"][0] == 2
+
+
+@pytest.mark.parametrize("field, text", [
+    # non-alphabet bytes: a lenient base64 decode drops them and yields a
+    # garbage amount where native/envelope.cc rejects the row
+    ("tx_amount", b'"A1oT{"'),
+    # parses to inf: assigning it to an int64 column raised OverflowError
+    # out of the whole poll
+    ("tx_datetime", b"1e999"),
+    ("tx_id", b"1" + b"0" * 30),  # an integer no int64 holds
+    # a float is not an id: the strict parser took 19000000, the scanner 19
+    ("customer_id", b"19E6"),
+    ("terminal_id", b"true"),
+])
+def test_malformed_field_masks_the_row_not_the_poll(field, text):
+    """One malformed envelope in a poll is masked; its neighbours decode."""
+    good = encode_transaction_envelope(7, 1_000_000, 1, 2, 500)
+    bad = encode_transaction_envelope(8, 2_000_000, 3, 4, 600)
+    head, sep, tail = bad.partition(b'"%s":' % field.encode())
+    end = min(i for i in (tail.find(b","), tail.find(b"}")) if i >= 0)
+    bad = head + sep + text + tail[end:]
+    cols, invalid = decode_transaction_envelopes([good, bad, good])
+    assert invalid.tolist() == [False, True, False]
+    assert cols["tx_id"].tolist() == [7, 0, 7]
+    assert cols["tx_amount_cents"].tolist() == [500, 0, 500]

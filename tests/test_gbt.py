@@ -12,7 +12,8 @@ from real_time_fraud_detection_system_tpu.models.metrics import roc_auc
 
 
 @pytest.fixture(scope="module")
-def xy(rng):
+def xy():
+    rng = np.random.default_rng(0)
     n, f = 8000, 15
     x = rng.normal(0, 1, (n, f))
     logits = np.sin(x[:, 0] * 2) + x[:, 1] * x[:, 2] + 0.5 * x[:, 3] - 1

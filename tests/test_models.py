@@ -20,7 +20,8 @@ from real_time_fraud_detection_system_tpu.models.logreg import (
 
 
 @pytest.fixture(scope="module")
-def xy(rng):
+def xy():
+    rng = np.random.default_rng(0)
     n, f = 3000, 15
     x = rng.normal(0, 1, (n, f))
     w = rng.normal(0, 1, f)

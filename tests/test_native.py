@@ -152,12 +152,21 @@ def test_native_schema_section_does_not_confuse_scanner():
     assert c["tx_amount_cents"][0] == 0x01C8B4
 
 
-def test_native_parity_differential_fuzz(rng):
+@pytest.mark.parametrize("seed", [0, 1, 23, 35, 50, 108])
+def test_native_parity_differential_fuzz(seed):
     """Mutation fuzz pinning the decoders' validity contract (see
     core/native.py docstring): the scanner is strictly more lenient — its
     invalid set is a SUBSET of the strict parser's — and wherever both
     accept a message the decoded columns are bit-identical. Inputs:
-    truncations, byte flips, garbage splices, whitespace injection."""
+    truncations, byte flips, garbage splices, whitespace injection.
+
+    Its own generator on fixed seeds, so what it draws does not depend on
+    which tests ran before it. Of seeds 0..199 the strict parser once
+    failed 54: a lenient base64 decode accepted a mangled amount the
+    scanner rejects (1, 23), an id of ``1e999`` raised OverflowError out
+    of the whole poll (35, 50), and a float id (``19E6``) decoded to
+    another number than the scanner's (108)."""
+    rng = np.random.default_rng(seed)
     base = encode_transaction_envelopes(
         np.arange(64, dtype=np.int64),
         rng.integers(1_700_000_000, 1_800_000_000, 64) * 1_000_000,

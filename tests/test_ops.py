@@ -154,40 +154,47 @@ def test_windows_unmaintained_column_is_not_touched(skipped):
 
 def test_maintained_columns_are_defined_once():
     """The customer table maintains (count, amount), the terminal table
-    (count, fraud): one definition in ``features/online.py``, and every
-    ``update_windows`` call of the one-chip and the sharded step passes
-    it — no call site repeats a literal. Late labels write a column its
-    table maintains, and nothing else."""
+    (count, fraud): one definition in ``features/online.py``, and ONE
+    ``update_windows`` / ``query_windows`` call outside ``ops/``, the
+    table plane's, passes it — the one-chip step, the sharded step and the
+    engine reach the tables through the plane and repeat neither the call
+    nor a literal. Late labels write a column its table maintains, and
+    nothing else."""
     import ast
     import inspect
 
     from real_time_fraud_detection_system_tpu.features import online
+    from real_time_fraud_detection_system_tpu.features import step as fstep
     from real_time_fraud_detection_system_tpu.parallel import step
+    from real_time_fraud_detection_system_tpu.runtime import (
+        engine,
+        sharded_engine,
+    )
 
+    names = ("CUSTOMER_COLUMNS", "TERMINAL_COLUMNS")
     assert dict(online.CUSTOMER_COLUMNS) == {"track_amount": True,
                                              "track_fraud": False}
     assert dict(online.TERMINAL_COLUMNS) == {"track_amount": False,
                                              "track_fraud": True}
-    assert step.CUSTOMER_COLUMNS is online.CUSTOMER_COLUMNS
-    assert step.TERMINAL_COLUMNS is online.TERMINAL_COLUMNS
-    for module in (online, step):
+    for module in (online, fstep, step, engine, sharded_engine):
         tree = ast.parse(inspect.getsource(module))
         # the two names are bound once, at module level, in online.py only
         bound = [t.id for node in ast.walk(tree)
                  if isinstance(node, ast.Assign)
                  for t in node.targets if isinstance(t, ast.Name)
-                 and t.id in ("CUSTOMER_COLUMNS", "TERMINAL_COLUMNS")]
+                 and t.id in names]
         assert len(bound) == (2 if module is online else 0), bound
-        calls = [node for node in ast.walk(tree)
-                 if isinstance(node, ast.Call)
-                 and getattr(node.func, "id", None) == "update_windows"]
-        assert len(calls) == 4, (module.__name__, len(calls))
-        for call in calls:
-            table = ast.unparse(call.args[0]).rsplit(".", 1)[-1]
-            assert table in ("customer", "terminal"), table
+        calls = {fn: [node for node in ast.walk(tree)
+                      if isinstance(node, ast.Call)
+                      and getattr(node.func, "id", None) == fn]
+                 for fn in ("update_windows", "query_windows")}
+        for fn, found in calls.items():
+            assert len(found) == (module is online), (module.__name__, fn)
+        for call in calls["update_windows"]:
             (kw,) = call.keywords  # one ``**`` and no literal flag
             assert kw.arg is None
-            assert kw.value.id == f"{table.upper()}_COLUMNS"
+            assert {n.id for n in ast.walk(kw.value)
+                    if isinstance(n, ast.Name)} >= set(names)
 
     state = online.FeatureState(
         customer=init_window_state(8, _NB),
@@ -497,3 +504,57 @@ def test_sum_fixed_order_is_the_written_tree(n):
     assert np.array_equal(
         np.asarray(sum_fixed_order(jnp.asarray(counts), 1)),
         counts.sum(axis=1))
+
+
+@pytest.mark.parametrize("key_mode", ["direct", "hash"])
+@pytest.mark.parametrize("n_shards", [1, 2, 4])
+def test_key_slot_is_the_rule_every_layout_used(n_shards, key_mode):
+    """``ops/hashing.key_slot`` / ``key_row`` equal the expressions they
+    replaced, kept here as literals: ``features/online._slot`` (one
+    chip), the sharded step's inlined local slot, the sharded engine's
+    NumPy global row for late labels, and ``mesh._layout_perm``."""
+    from real_time_fraud_detection_system_tpu.ops.hashing import (
+        key_row,
+        key_slot,
+        slot_of,
+    )
+    from real_time_fraud_detection_system_tpu.parallel.mesh import (
+        _layout_perm,
+    )
+
+    cap, n_dev = 1 << 10, n_shards
+    cap_local = cap // n_dev
+    key = np.random.default_rng(n_shards).integers(
+        0, 1 << 32, 4096, dtype=np.uint32)
+    jkey = jnp.asarray(key)
+    if n_shards == 1 and key_mode == "hash":
+        want = slot_of(jkey, cap)
+    elif n_shards == 1:
+        want = (jkey & jnp.uint32(cap - 1)).astype(jnp.int32)
+    else:  # a mesh's layout is owner-modulo under either mode
+        want = ((jkey // jnp.uint32(n_dev))
+                & jnp.uint32(cap_local - 1)).astype(jnp.int32)
+    got = key_slot(jkey, cap, key_mode, n_shards)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="key directory"):
+        key_slot(jkey, cap, "exact", n_shards)
+    if key_mode == "hash":
+        return
+    # NumPy callers share the rule: late labels' global row ...
+    np.testing.assert_array_equal(key_slot(key, cap, "direct", n_shards),
+                                  np.asarray(want))
+    gslot = (
+        (key % np.uint32(n_dev)).astype(np.int64) * cap_local
+        + ((key // np.uint32(n_dev)) & np.uint32(cap_local - 1))
+    ).astype(np.int32)
+    np.testing.assert_array_equal(
+        key_row(key, cap, "direct", n_shards), gslot)
+    # ... and the layout permutation of a reshard: owner × cap_local +
+    # local slot
+    k = np.arange(cap)
+    perm = _layout_perm(cap, n_dev)
+    np.testing.assert_array_equal(
+        perm, k if n_dev == 1 else (k % n_dev) * (cap // n_dev) + k // n_dev)
+    np.testing.assert_array_equal(
+        perm, (k % n_dev) * cap_local + key_slot(k, cap, "direct", n_dev))

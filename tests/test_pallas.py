@@ -101,11 +101,12 @@ def test_fused_kernel_jit_and_padding(rng):
     """Padded rows (valid=False) and a multi-tile grid must match the jnp
     path: 100 real rows padded to 256, scored with block_rows=128 → grid=(2,)
     where the second tile is mostly padding."""
-    from real_time_fraud_detection_system_tpu.features.online import _update_state
+    from real_time_fraud_detection_system_tpu.features.online import (
+        _update_and_gather,
+    )
     from real_time_fraud_detection_system_tpu.ops.pallas_kernels import (
         fused_featurize_score,
     )
-    from real_time_fraud_detection_system_tpu.ops.windows import gather_state_rows
 
     cfg = FeatureConfig(customer_capacity=256, terminal_capacity=512)
     params = init_logreg(15)
@@ -136,13 +137,11 @@ def test_fused_kernel_jit_and_padding(rng):
     )
 
     # kernel with a 2-tile grid (256 / 128)
-    state, cust_slot, term_slot = _update_state(
+    state, c_rows, t_rows = _update_and_gather(
         init_feature_state(cfg), batch, cfg
     )
-    c_bd, c_cnt, c_amt, _ = gather_state_rows(state.customer, cust_slot)
-    t_bd, t_cnt, _, t_frd = gather_state_rows(state.terminal, term_slot)
     probs, feats = fused_featurize_score(
-        (c_bd, c_cnt, c_amt), (t_bd, t_cnt, t_frd),
+        c_rows, t_rows,
         batch.day, batch.tod_s, batch.amount, batch.valid,
         scaler.mean, scaler.scale, params.w, params.b,
         windows=tuple(cfg.windows), delay=cfg.delay_days,

@@ -22,7 +22,8 @@ from real_time_fraud_detection_system_tpu.models.plots import (
 
 
 @pytest.fixture(scope="module")
-def scored(rng):
+def scored():
+    rng = np.random.default_rng(0)
     n = 2000
     y = (rng.random(n) < 0.1).astype(np.float64)
     s = np.clip(0.3 * y + 0.2 * rng.random(n), 0, 1)

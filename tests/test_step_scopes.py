@@ -81,6 +81,9 @@ VARIANTS = {
     # send buffer, and (under the engine's own `unpack`) the back-gather
     "sharded": ("forest", {}, {}, 2,
                 COMMON | {"exchange", "route", "pack"}),
+    # a mesh of one reaches both tables by a local call: the one-chip
+    # step's body inside shard_map, scope path for scope path
+    "sharded_1dev": ("forest", {}, {}, 1, COMMON),
 }
 
 
@@ -167,6 +170,17 @@ def test_step_hlo_carries_the_variants_scopes(variant):
             assert len(TABLE & set(path)) == 1, path
             assert path.index("update") > min(
                 path.index(t) for t in TABLE & set(path)), path
+    if variant == "sharded_1dev":
+        # one body, two engines: a per-layer metric file that names a
+        # scope path reads both for as long as they share it
+        # (the routed variant too: at one device every reach is local)
+        (chip_step,) = _lowered_steps(_engine("forest"))
+        chip, *mesh = [{tuple(_scopes(n)) for n in _op_names(
+            low.as_text(dialect="hlo", debug_info=True))}
+            for low in [chip_step] + lowered]
+        assert len(mesh) == 2
+        for paths in mesh:
+            assert paths == chip, sorted(paths ^ chip)
 
 
 def test_unknown_scope_is_refused():
