@@ -483,7 +483,7 @@ def _measure(args) -> dict:
         )
 
         # ---- registry-backed before/after evidence (ROADMAP PR-1 note):
-        # per-phase p50s for sync vs async sink and precompile off/on,
+        # per-phase p50s with a parquet sink and with precompile off/on,
         # straight from the run-stats trackers + the engine's registry.
         _progress("engine loop phase p50 before/after")
 
@@ -493,7 +493,6 @@ def _measure(args) -> dict:
             import tempfile
 
             from real_time_fraud_detection_system_tpu.io.sink import (
-                AsyncSink,
                 ParquetSink,
             )
             from real_time_fraud_detection_system_tpu.utils.metrics import (
@@ -504,27 +503,24 @@ def _measure(args) -> dict:
                 return {
                     k: round(s[f"{k}_p50_ms"], 4)
                     for k in ("host_prep", "dispatch", "result_wait",
-                              "sink_write")
+                              "sink_wait", "sink_write")
                 }
 
             out = {}
-            # sink_write: inline parquet write vs bounded-queue enqueue
-            for label, asynk in (("sink_sync", False), ("sink_async", True)):
-                d = tempfile.mkdtemp(prefix=f"rtfds_bench_{label}_")
-                sink = ParquetSink(d)
-                if asynk:
-                    sink = AsyncSink(sink, max_queue=8)
-                e = ScoringEngine(ecfg, kind="forest", params=params,
-                                  scaler=scaler)
-                e.run(_RandSource(1, engine_rows, seed=3), sink=sink,
+            # sink: the parquet write on the loop's writer thread
+            # (sink_write) and what the loop thread waited for it
+            # (sink_wait)
+            d = tempfile.mkdtemp(prefix="rtfds_bench_sink_")
+            sink = ParquetSink(d)
+            e = ScoringEngine(ecfg, kind="forest", params=params,
+                              scaler=scaler)
+            e.run(_RandSource(1, engine_rows, seed=3), sink=sink,
+                  trigger_seconds=0.0)
+            s = e.run(_RandSource(n_eng, engine_rows), sink=sink,
                       trigger_seconds=0.0)
-                s = e.run(_RandSource(n_eng, engine_rows), sink=sink,
-                          trigger_seconds=0.0)
-                if asynk:
-                    sink.close()
-                shutil.rmtree(d, ignore_errors=True)
-                out[label] = {"rows_per_s": round(s["rows_per_s"], 1),
-                              **_phases(s)}
+            shutil.rmtree(d, ignore_errors=True)
+            out["sink"] = {"rows_per_s": round(s["rows_per_s"], 1),
+                           **_phases(s)}
 
             # precompile: the second bucket size first lands MID-STREAM
             # (after the recompile detector's warmup) — precompile off

@@ -653,7 +653,6 @@ def cmd_score(args) -> int:
         # an SLO implies the controller: the knob is the intent
         autobatch=args.autobatch or args.latency_slo_ms > 0,
         latency_slo_ms=args.latency_slo_ms,
-        async_sink=args.async_sink,
         sink_queue_batches=args.sink_queue_batches,
         decode_workers=args.decode_workers,
         prefetch_batches=args.prefetch_batches,
@@ -1164,15 +1163,6 @@ def cmd_score(args) -> int:
         raw_table = RawTransactionsTable(raw_path,
                                          flush_every_batches=64)
         sink = FanoutSink(sink, raw_table)
-    if cfg.runtime.async_sink and sink is not None:
-        # Wrap OUTSIDE the fanout so one writer thread serves every
-        # destination in order; the engine drains it before checkpoint
-        # saves (offsets keep trailing durable output) and at run end.
-        from real_time_fraud_detection_system_tpu.io.sink import AsyncSink
-
-        sink = AsyncSink(sink, max_queue=cfg.runtime.sink_queue_batches)
-        log.info("async sink offload on (queue depth %d)",
-                 cfg.runtime.sink_queue_batches)
     if args.max_restarts > 0 and ckpt is None:
         log.error("--max-restarts requires --checkpoint-dir "
                   "(there is nothing to recover from without checkpoints)")
@@ -1314,15 +1304,6 @@ def cmd_score(args) -> int:
         close = getattr(source, "close", None)
         if close is not None:
             close()
-        if cfg.runtime.async_sink and sink is not None:
-            # stop the writer thread; never mask the run's own error
-            # with a drain-time one (it was already warn-logged)
-            try:
-                sink.close()
-            # rtfdslint: disable=broad-exception-catch (drain-time close error was already warn-logged by the writer; re-raising here would mask the run's own error)
-            except Exception as e:
-                log.warning("async sink close: %s: %s",
-                            type(e).__name__, e)
         if fb is not None:
             fb.close()
         if learning is not None:
@@ -2571,12 +2552,6 @@ def main(argv=None) -> int:
                    help="p50 micro-batch latency target for the "
                         "adaptive batch controller (implies --autobatch;"
                         " 0 = no SLO, maximize throughput)")
-    p.add_argument("--async-sink", action="store_true",
-                   help="offload sink appends to a background writer "
-                        "thread behind a bounded queue; the loop's "
-                        "sink_write phase becomes an enqueue, and "
-                        "checkpoints drain the queue first (exactly-"
-                        "once output is preserved)")
     p.add_argument("--decode-workers", type=int, default=0,
                    help="ingest-decode worker threads: each envelope "
                         "byte-batch is sharded into contiguous slabs "
@@ -2592,9 +2567,9 @@ def main(argv=None) -> int:
                    help="disable overlapped result fetch (async D2H "
                         "copies issued at dispatch time); on by default")
     p.add_argument("--sink-queue-batches", type=int, default=8,
-                   help="bounded queue depth (batch results) for "
-                        "--async-sink; a full queue backpressures the "
-                        "loop thread")
+                   help="bounded queue depth (batch results) of the "
+                        "loop's sink writer thread; a full queue "
+                        "backpressures the loop thread")
     p.add_argument("--use-pallas", action="store_true",
                    help="serve with the fused Pallas kernels where "
                         "available (tree/forest fused featurize+score, "

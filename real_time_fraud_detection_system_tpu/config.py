@@ -443,12 +443,6 @@ class RuntimeConfig:
     # p50 micro-batch latency target in ms for the autobatch controller
     # (0 = no SLO: maximize throughput instead).
     latency_slo_ms: float = 0.0
-    # Async sink offload (io/sink.py::AsyncSink): sink appends run on a
-    # background writer thread behind a bounded FIFO queue; the loop
-    # thread's sink_write phase collapses to an enqueue. Checkpoint
-    # saves drain the queue first, so offsets keep trailing durable sink
-    # output (the exactly-once invariant).
-    async_sink: bool = False
     # Ingest-decode worker threads (core/native.py): each polled
     # envelope byte-batch is sharded into contiguous offset slabs decoded
     # concurrently by a thread pool (the ctypes scanner releases the
@@ -471,7 +465,8 @@ class RuntimeConfig:
     # batches instead of serializing into result_wait. Free on CPU; the
     # head start is metered as rtfds_fetch_overlap_seconds_total.
     fetch_overlap: bool = True
-    # Bounded queue depth (batch results) for the async sink; a full
+    # Bounded queue depth (batch results) of the loop's sink writer
+    # (engine.run() owns one io/sink.py::AsyncSink thread per run); a full
     # queue backpressures the loop thread
     # (rtfds_sink_backpressure_seconds_total counts the blocked time).
     sink_queue_batches: int = 8

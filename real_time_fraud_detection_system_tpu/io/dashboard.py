@@ -445,7 +445,7 @@ def render_dashboard_html(
 # runtime.engine.PHASES; duplicated here so the io layer renders flight
 # records from any producer without importing the runtime).
 _OPS_PHASES = ("source_poll", "host_prep", "dispatch", "result_wait",
-               "sink_write")
+               "sink_wait", "sink_write")
 
 _EVENT_CLASS = {"fault": "serious", "restart": "serious",
                 "poison": "serious", "dead_letter": "serious",

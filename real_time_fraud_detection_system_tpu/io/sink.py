@@ -124,13 +124,13 @@ class _SinkError:
 
 
 class AsyncSink:
-    """Offload ``append`` to a background writer thread — the engine
-    loop's ``sink_write`` phase collapses to one bounded-queue enqueue.
+    """One ordered writer thread for ``append`` — the engine loop's own
+    (``runtime/engine.py::ScoringEngine.run`` wraps the sink it is given
+    in one for the length of the run; nothing else does).
 
-    The serving loop previously paid every sink write (parquet encode +
-    fsync-ish rename, an object-store PUT, an Iceberg commit) inline on
-    the loop thread between device steps — the largest remaining
-    synchronous I/O in the hot path. This wrapper keeps the device hot:
+    A sink write (parquet encode + rename, an object-store PUT, an
+    Iceberg commit) needs neither the chip nor the loop's state, so it
+    runs here while the loop thread polls, preps and dispatches:
 
     - **Ordered**: one writer thread drains a FIFO queue, so the inner
       sink sees appends in exactly the loop's order (part-file naming,
@@ -153,18 +153,35 @@ class AsyncSink:
     - **Drain contract**: ``drain()`` blocks until every queued append
       has landed in the inner sink. ``flush``/``truncate_after``/
       ``read_all``/``concat`` drain first, and the engine drains before
-      every checkpoint save — so checkpointed offsets keep TRAILING
-      durable sink output (the exactly-once invariant in
-      ``runtime/engine.py``'s checkpoint block: a crash replays rows,
-      never skips them, and replayed ``batch_index`` parts overwrite).
+      every checkpoint save and before ``run()`` returns — so
+      checkpointed offsets keep TRAILING durable sink output (the
+      exactly-once invariant in ``runtime/engine.py``'s checkpoint
+      block: a crash replays rows, never skips them, and replayed
+      ``batch_index`` parts overwrite).
+
+    ``write(inner, res, ctx)``, where given, runs on the writer thread in
+    place of ``inner.append(res)``: the engine's hook for what belongs
+    around the write where it happens (its span, its duration). ``ctx``
+    is whatever ``append`` was handed beside the result.
     """
 
     _STOP = object()
 
-    def __init__(self, inner, max_queue: int = 8, registry=None):
+    def __init__(self, inner, max_queue: int = 8, registry=None,
+                 write=None):
         if inner is None:
             raise ValueError("AsyncSink needs an inner sink")
         self.inner = inner
+        self._write = write
+        try:
+            # The sinks import pyarrow lazily, inside append — which runs
+            # on a thread that ends with the run. pyarrow (25.0) first
+            # imported by a thread that has since exited segfaults in a
+            # later pyarrow.dataset read (tools/parquet_sql_check.py did):
+            # import it on the thread that starts the writer.
+            import pyarrow  # noqa: F401
+        except ImportError:
+            pass
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, int(max_queue)))
         self._error: Optional[_SinkError] = None
         # injectable like the engine's registry, so per-run before/after
@@ -191,13 +208,17 @@ class AsyncSink:
                 if item is self._STOP:
                     return
                 if self._error is None:
+                    res, ctx = item
                     try:
-                        # rtfdslint: disable=cross-thread-race (drain() is the guard: every loop-side inner access — flush/truncate_after/read_all/concat — calls drain() first, and q.join() orders every writer append strictly before it; crash/replay lineage tests pin the contract)
-                        self.inner.append(item)
+                        if self._write is None:
+                            # rtfdslint: disable=cross-thread-race (drain() is the guard: every loop-side inner access — flush/truncate_after/read_all/concat — calls drain() first, and q.join() orders every writer append strictly before it; crash/replay lineage tests pin the contract)
+                            self.inner.append(res)
+                        else:
+                            self._write(self.inner, res, ctx)
                     # rtfdslint: disable=broad-exception-catch (thread-boundary transport: the writer parks the ORIGINAL exception; append/drain re-raise it typed on the loop thread for the supervisor's recover_on policy)
                     except BaseException as e:  # propagate to loop thread
                         self._error = _SinkError(
-                            e, int(getattr(item, "batch_index", -1)))
+                            e, int(getattr(res, "batch_index", -1)))
                         from real_time_fraud_detection_system_tpu.utils \
                             import get_logger
 
@@ -226,10 +247,11 @@ class AsyncSink:
 
     # -- sink API (loop thread) --------------------------------------------
 
-    def append(self, res) -> None:
+    def append(self, res, ctx=None) -> None:
         self._raise_pending()
         t0 = time.perf_counter()
-        self._q.put(res)  # blocks when full: bounded-memory backpressure
+        # blocks when full: bounded-memory backpressure
+        self._q.put((res, ctx))
         waited = time.perf_counter() - t0
         if waited > 1e-4:  # an uncontended put is ~µs; only count blocks
             self._m_backpressure.inc(waited)
@@ -263,12 +285,18 @@ class AsyncSink:
         self.drain()
         return self.inner.concat()
 
-    def close(self) -> None:
-        """Drain, stop the writer thread, and surface any pending error."""
+    def stop(self) -> None:
+        """Let every queued append land, then end the writer thread. Raises
+        nothing: for a ``finally`` in which the loop's own exception, if
+        there is one, must stay the one that propagates."""
         if self._thread.is_alive():
             self._q.join()
             self._q.put(self._STOP)
             self._thread.join(timeout=30.0)
+
+    def close(self) -> None:
+        """Drain, stop the writer thread, and surface any pending error."""
+        self.stop()
         self._raise_pending()
 
 

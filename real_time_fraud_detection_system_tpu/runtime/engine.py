@@ -57,6 +57,7 @@ from real_time_fraud_detection_system_tpu.models.mlp import (
 )
 from real_time_fraud_detection_system_tpu.models.scaler import Scaler, transform
 from real_time_fraud_detection_system_tpu.core import native
+from real_time_fraud_detection_system_tpu.io.sink import AsyncSink
 from real_time_fraud_detection_system_tpu.ops.dedup import (
     latest_wins_mask_host,
 )
@@ -82,9 +83,60 @@ from real_time_fraud_detection_system_tpu.utils.xla_telemetry import (
 # The per-batch loop-time decomposition every layer reports under
 # (rtfds_phase_seconds{phase=...} and the flight record's "phases" dict):
 # source poll → host prep (dedup+pack) → dispatch (H2D + jit call) →
-# result wait (device compute minus overlap + unpack) → sink write.
+# result wait (device compute minus overlap + unpack) → sink wait (the
+# loop thread blocked on its writer: the join before a poll, a full
+# queue, a drain). Those five are serial on the loop thread and sum to
+# its wall time; ``sink_write`` is the write itself, timed on the writer
+# thread where it runs, beside them.
 PHASES = ("source_poll", "host_prep", "dispatch", "result_wait",
-          "sink_write")
+          "sink_wait", "sink_write")
+
+
+class PollAhead:
+    """The loop's join rule: may the next poll go ahead of the sink write
+    before it, or does the loop first wait for its writer to go idle?
+
+    A poll freezes a batch. Polling ahead of the write is free only when
+    the rows it freezes were waiting anyway — the source holds a backlog —
+    and then the write overlaps the poll, the prep, the dispatch and the
+    chip. Without a backlog an early poll only freezes the next batch a
+    write's time sooner than the chip can take it, and every row in it
+    waits that much longer: there the loop joins first (write, then poll:
+    the inline order).
+
+    The sign of a backlog is a launched batch that filled the largest
+    bucket (or left a carry). One such batch is believed at first
+    (``need`` = 1). But the sign has an echo: the join that ends a spell of
+    polling ahead makes one interval between polls a write's time longer,
+    that interval's rows can fill the bucket on their own, and believing
+    it starts the next spell — full, short, full, short, at 0.7 of the
+    chip-paced rate and above (PERF.md §6, PR 31: ``forest.steady`` read
+    +12 % at p50 that way). A spell whose FIRST early poll already comes
+    back short was such an echo, and raises ``need`` by one: it takes that
+    many full batches in a row from then on. A real backlog fills every
+    poll and pays ``need`` - 1 joined writes once; under saturation
+    ``need`` stays 1.
+    """
+
+    def __init__(self, cap: int):
+        self.cap = int(cap)
+        self.need = 1
+        self.streak = 0  # full batches launched in a row
+        self.spell = 0  # batches launched from polls made ahead, in a row
+        self.ahead = False  # read before each poll; False: join first
+
+    def launched(self, rows: int, carry: bool) -> None:
+        """A batch of ``rows`` polled rows was launched (``carry``: a
+        further poll is already waiting for the next one)."""
+        self.spell = self.spell + 1 if self.ahead else 0
+        if carry or rows >= self.cap:
+            self.streak += 1
+        else:
+            if self.spell == 1:
+                self.need += 1
+            self.streak = 0
+        self.ahead = self.streak >= self.need
+
 
 # Budget for ONE double-buffered Pallas tree block — the ensemble-
 # dependent VMEM term (ops/pallas_forest.admit_block). It bounds the
@@ -539,6 +591,15 @@ class ScoringEngine:
                 "per-batch loop-time decomposition by phase", phase=ph)
             for ph in PHASES
         }
+        # How often the loop's join rule let a write overlap the next poll
+        # (two names: a ratio reader sums every series of a name).
+        self._m_sink_batches = reg.counter(
+            "rtfds_sink_batches_total",
+            "batches handed to the loop's sink writer")
+        self._m_sink_overlapped = reg.counter(
+            "rtfds_sink_overlapped_batches_total",
+            "batches whose write the next poll did not wait for (the "
+            "source showed a backlog)")
         self._m_last = reg.gauge(
             "rtfds_last_batch_unix_seconds",
             "wall-clock time the last batch finished (healthz input)")
@@ -2081,6 +2142,21 @@ class ScoringEngine:
         (offsets, state) pair never includes an in-flight batch's effects
         (a replay after restore would double-apply them otherwise).
 
+        ``sink.append`` runs on ONE ordered writer thread that this call
+        owns (:class:`~..io.sink.AsyncSink` around the sink it was given,
+        started below and ended in the ``finally``; a sink that already
+        is one is unwrapped, not wrapped twice). A batch is acknowledged
+        when the inner ``append`` returned, there. Before a poll the loop
+        waits for the writer to go idle unless the source showed a
+        backlog (:class:`PollAhead`: the batches last launched filled the
+        largest bucket): without one, polling earlier would only freeze
+        the next batch's rows sooner (write, then poll: the inline
+        order); with one the loop polls at once and the write overlaps
+        the poll, the prep, the dispatch and the chip. The writer is
+        drained before every checkpoint save and before this returns;
+        ``state.offsets`` may lead durable output only between two
+        drains.
+
         ``heartbeat`` (a :class:`~.faults.Heartbeat`) is beaten once per
         loop pass — including idle polls — so a watchdog can tell a quiet
         stream from a silently hung source or device step.
@@ -2124,6 +2200,7 @@ class ScoringEngine:
             "host_prep": LatencyTracker(),
             "dispatch": LatencyTracker(),
             "result_wait": LatencyTracker(),
+            "sink_wait": LatencyTracker(),
             "sink_write": LatencyTracker(),
         }
         auto = None
@@ -2185,7 +2262,9 @@ class ScoringEngine:
         # Source-poll time since the last finished batch — attributed to
         # the NEXT batch's flight record so per-batch phases sum to the
         # loop's wall time (minus trigger pacing, reported separately).
-        pending = {"poll_s": 0.0}
+        # Likewise the time the loop thread was blocked on its writer.
+        # "handed": batches given to the writer since the last poll.
+        pending = {"poll_s": 0.0, "sink_wait_s": 0.0, "handed": 0}
         t_start = time.perf_counter()
         # CPU time of the serving loop proper (precompile excluded —
         # the AOT block above ran before this line). rows / cpu_s is the
@@ -2208,6 +2287,38 @@ class ScoringEngine:
             # broker commits to the checkpoint cadence.
             feedback.auto_commit = False
 
+        def _write(inner, res, ctx) -> None:
+            # WRITER THREAD. The acknowledgement is this append's return;
+            # the span and the duration are taken here, where the write
+            # runs, so sink_write reads the write and never an enqueue.
+            trace_id, record = ctx
+            t_sink = time.perf_counter()
+            with self.tracer.span("sink_write", batch=trace_id):
+                inner.append(res)
+            sink_s = time.perf_counter() - t_sink
+            phase_hist["sink_write"].observe(sink_s)
+            trackers["sink_write"].record(sink_s)
+            if record is not None:
+                record(sink_s)
+
+        writer = None
+        if sink is not None:
+            inner = sink
+            if isinstance(sink, AsyncSink):
+                sink.drain()
+                inner = sink.inner
+            writer = AsyncSink(
+                inner, max_queue=self.cfg.runtime.sink_queue_batches,
+                registry=self.metrics, write=_write)
+        rule = PollAhead(max(self.cfg.runtime.batch_buckets))
+
+        def _sink_blocked(fn, *args) -> None:
+            t_wait = time.perf_counter()
+            try:
+                fn(*args)
+            finally:
+                pending["sink_wait_s"] += time.perf_counter() - t_wait
+
         def _finish(handle: dict) -> None:
             t_block = time.perf_counter()
             # explicit batch= : with pipeline_depth > 1 this handle's
@@ -2229,19 +2340,47 @@ class ScoringEngine:
             phase_hist["dispatch"].observe(dispatch_s)
             phase_hist["result_wait"].observe(wait_s)
             self.state.offsets = handle["source_offsets"]
-            sink_s = 0.0
-            if sink is not None:
-                # With an AsyncSink this measures the ENQUEUE (plus any
-                # backpressure block) — the loop thread's actual cost;
-                # the write itself runs on the sink's writer thread and
-                # reports through rtfds_sink_write_seconds.
-                t_sink = time.perf_counter()
-                with self.tracer.span("sink_write",
-                                      batch=handle.get("trace_id")):
-                    sink.append(res)
-                sink_s = time.perf_counter() - t_sink
-                phase_hist["sink_write"].observe(sink_s)
-                trackers["sink_write"].record(sink_s)
+            record = None
+            if recorder is not None:
+                extra = {}
+                if handle.get("trace_id"):
+                    # cross-reference: a slow batch in the flight record
+                    # names its span waterfall in the exported trace
+                    extra["trace_id"] = handle["trace_id"]
+                phases = {"source_poll": pending["poll_s"],
+                          "host_prep": prep_s, "dispatch": dispatch_s,
+                          "result_wait": wait_s}
+                pending["poll_s"] = 0.0
+                depth_now = len(q)
+
+                def record(sink_s: Optional[float] = None) -> None:
+                    # with a sink: on the writer thread, once the write
+                    # has a duration (the loop thread's phases were
+                    # fixed before the enqueue)
+                    if sink_s is not None:
+                        phases["sink_write"] = sink_s
+                    recorder.record_batch(
+                        res.batch_index, len(res.tx_id), phases,
+                        queue_depth=depth_now, latency_s=res.latency_s,
+                        **extra)
+            if writer is not None:
+                # What the loop thread was blocked on its writer since
+                # the last batch went to it — the join before a poll, a
+                # checkpoint's drain, that enqueue on a full queue — is
+                # this batch's sink_wait (the next-batch attribution of
+                # poll_s above).
+                sink_wait_s = pending["sink_wait_s"]
+                pending["sink_wait_s"] = 0.0
+                phase_hist["sink_wait"].observe(sink_wait_s)
+                trackers["sink_wait"].record(sink_wait_s)
+                if record is not None:
+                    phases["sink_wait"] = sink_wait_s
+                _sink_blocked(writer.append, res,
+                              (handle.get("trace_id"), record))
+                self._m_sink_batches.inc()
+                pending["handed"] += 1
+            elif record is not None:
+                record()
             if auto is not None:
                 auto.observe(len(res.tx_id), res.latency_s)
             if overload is not None:
@@ -2251,20 +2390,6 @@ class ScoringEngine:
                     # state updates that landed, not dispatches
                     overload.note_replayed(rr)
                 overload.observe_batch(len(res.tx_id), res.latency_s)
-            if recorder is not None:
-                extra = {}
-                if handle.get("trace_id"):
-                    # cross-reference: a slow batch in the flight record
-                    # names its span waterfall in the exported trace
-                    extra["trace_id"] = handle["trace_id"]
-                recorder.record_batch(
-                    res.batch_index, len(res.tx_id),
-                    {"source_poll": pending["poll_s"],
-                     "host_prep": prep_s, "dispatch": dispatch_s,
-                     "result_wait": wait_s, "sink_write": sink_s},
-                    queue_depth=len(q), latency_s=res.latency_s, **extra,
-                )
-                pending["poll_s"] = 0.0
             if feedback is not None:
                 # Between-batch label application (before the checkpoint,
                 # so saved state includes the landed labels).
@@ -2312,14 +2437,13 @@ class ScoringEngine:
                 # the batch cadence, between device steps
                 learning.on_batch(self)
             if checkpointer is not None and self.state.batches_done % every == 0:
-                # Drain an async sink BEFORE the state save: checkpointed
+                # Drain the writer BEFORE the state save: checkpointed
                 # offsets must TRAIL durable sink output (a crash then
                 # replays rows into parts that already landed — the
                 # exactly-once overwrite — never records progress for
                 # writes still sitting in a queue).
-                drain = getattr(sink, "drain", None)
-                if drain is not None:
-                    drain()
+                if writer is not None:
+                    _sink_blocked(writer.drain)
                 if self._cold is not None:
                     # Buffered demotions become durable segments NOW so
                     # the lineage the checkpoint records is on disk, and
@@ -2362,6 +2486,14 @@ class ScoringEngine:
                 _finish(q.popleft())
 
         def _poll():
+            if writer is not None:
+                # The join rule (PollAhead): no backlog → the inline
+                # order, write then poll; a backlog → poll now.
+                if not rule.ahead:
+                    _sink_blocked(writer.drain)
+                elif pending["handed"]:
+                    self._m_sink_overlapped.inc(pending["handed"])
+                pending["handed"] = 0
             t_poll = time.perf_counter()
             # Attribute the poll to the batch that will CONSUME it (the
             # same next-batch attribution the flight record uses via
@@ -2392,6 +2524,8 @@ class ScoringEngine:
                 _drain()
             idx = self.state.batches_done + len(q) + 1
             tid = self.tracer.begin_batch(idx)
+            rule.launched(len(next(iter(cols.values()), ())),
+                          carry is not None)
             handle = self._start_batch(cols)
             t_last_start = time.perf_counter()
             handle["index"] = idx
@@ -2410,114 +2544,114 @@ class ScoringEngine:
         carry = None  # (cols, offsets): a poll beyond the coalesce cap
         cap = max(self.cfg.runtime.batch_buckets)
         t_last_start = None  # previous batch's dispatch time (pacing)
-        while not exhausted:
-            if heartbeat is not None:
-                heartbeat.beat()
-            started = self.state.batches_done + len(q)
-            if max_batches and started >= max_batches:
-                capped = True
-                break
-            if self.stop_event is not None and self.stop_event.is_set():
-                # Coordinated drain (fleet resize / graceful SIGTERM):
-                # stop at a batch boundary with the capped-run tail —
-                # deferred/shed batches stay behind the checkpointed
-                # offsets by the defer() contract, so the caller's final
-                # checkpoint resumes them exactly-once under the next
-                # topology instead of force-draining them here.
-                capped = True
-                break
-            if trigger > 0 and t_last_start is not None:
-                # Trigger pacing, once per loop pass on the POLL side:
-                # batch starts stay >= trigger apart while already-
-                # dispatched batches keep computing through the sleep.
-                # (Pacing used to run inside _finish, stacking one sleep
-                # per queued handle on every drain.) The slept time is
-                # credited as wait so in-flight latencies measure the
-                # pipeline, not the pacing.
-                dt = trigger - (time.perf_counter() - t_last_start)
-                if dt > 0:
-                    # rtfdslint: disable=blocking-call-on-loop-thread (sanctioned pacing wait point: --trigger-interval spacing on the poll side, slept time credited as wait; regression-pinned in test_runtime trigger-pacing tests)
-                    time.sleep(dt)
-                    _add_wait(dt)
-            if overload is not None and overload.want_replay():
-                # Descending from rung 3 (or the spill hit its memory
-                # cap): the deferred FIFO's head replays through the
-                # normal scoring path BEFORE any live poll — rows reach
-                # the feature state in exactly the order a
-                # never-overloaded run would have seen them.
-                item = overload.next_replay()
-                if item is not None:
-                    _launch(item.cols, item.offsets,
-                            replay_rows=item.rows)
-                    continue
-            if carry is not None:
-                cols, offs = carry
-                carry = None
-            else:
-                cols = _poll()
-                if cols is None:
-                    break
-                if len(next(iter(cols.values()), ())) == 0:
-                    # Idle live source (e.g. KafkaSource on a quiet
-                    # topic): not a batch — no sink append, no step, no
-                    # checkpoint cadence, no max_batches consumption.
-                    # Flush the in-flight batches (their results must not
-                    # wait for future traffic), then wait a trigger.
-                    _drain()
-                    if overload is not None:
-                        # the quiet period is the ladder's recovery
-                        # window: tick the controller so descend dwell
-                        # accumulates and deferred batches replay even
-                        # if live traffic never returns
-                        overload.idle_tick()
-                    if trigger > 0:
-                        # rtfdslint: disable=blocking-call-on-loop-thread (sanctioned wait point: idle live source with nothing in flight — sleeping one trigger IS the correct behavior, there is no work to stall)
-                        time.sleep(trigger)
-                    continue
-                offs = list(source.offsets)
-            # The adaptive controller overrides the static coalesce
-            # target while active (it only MERGES small polls upward —
-            # an oversized poll still bucket-pads as before).
-            assemble = auto.target_rows() if auto is not None else coalesce
-            if assemble > 0:
-                # Never assemble past the largest jit bucket: a poll that
-                # would overflow is carried into the NEXT batch, and its
-                # rows stay excluded from this batch's checkpoint offsets
-                # (a crash must replay them, not skip them).
-                target = min(assemble, cap)
-                parts = [cols]
-                total = len(next(iter(cols.values())))
-                while total < target:
-                    more = _poll()
-                    if more is None:
-                        exhausted = True  # serve the tail, then stop
-                        break
-                    m = len(next(iter(more.values()), ()))
-                    if m == 0:
-                        break  # idle: serve what we have now
-                    if total + m > cap:
-                        carry = (more, list(source.offsets))
-                        break
-                    parts.append(more)
-                    total += m
-                    offs = list(source.offsets)
-                if len(parts) > 1:
-                    cols = {k: np.concatenate([p[k] for p in parts])
-                            for k in parts[0]}
-            if overload is not None and overload.should_defer():
-                # Rung 3 admission control: the whole assembled batch
-                # defers to the durable spill instead of dispatching. It
-                # consumes no batch_index (sink lineage stays gap-free)
-                # and state.offsets stays at the last SCORED batch, so a
-                # crash replays deferred rows from the checkpoint.
-                # Batches dispatched BEFORE the climb finish first —
-                # rung 3 holds nothing in flight, so their results land
-                # instead of idling in the pipeline behind the deferral.
-                _drain()
-                overload.defer(cols, offs)
-                continue
-            _launch(cols, offs)
         try:
+            while not exhausted:
+                if heartbeat is not None:
+                    heartbeat.beat()
+                started = self.state.batches_done + len(q)
+                if max_batches and started >= max_batches:
+                    capped = True
+                    break
+                if self.stop_event is not None and self.stop_event.is_set():
+                    # Coordinated drain (fleet resize / graceful SIGTERM):
+                    # stop at a batch boundary with the capped-run tail —
+                    # deferred/shed batches stay behind the checkpointed
+                    # offsets by the defer() contract, so the caller's final
+                    # checkpoint resumes them exactly-once under the next
+                    # topology instead of force-draining them here.
+                    capped = True
+                    break
+                if trigger > 0 and t_last_start is not None:
+                    # Trigger pacing, once per loop pass on the POLL side:
+                    # batch starts stay >= trigger apart while already-
+                    # dispatched batches keep computing through the sleep.
+                    # (Pacing used to run inside _finish, stacking one sleep
+                    # per queued handle on every drain.) The slept time is
+                    # credited as wait so in-flight latencies measure the
+                    # pipeline, not the pacing.
+                    dt = trigger - (time.perf_counter() - t_last_start)
+                    if dt > 0:
+                        # rtfdslint: disable=blocking-call-on-loop-thread (sanctioned pacing wait point: --trigger-interval spacing on the poll side, slept time credited as wait; regression-pinned in test_runtime trigger-pacing tests)
+                        time.sleep(dt)
+                        _add_wait(dt)
+                if overload is not None and overload.want_replay():
+                    # Descending from rung 3 (or the spill hit its memory
+                    # cap): the deferred FIFO's head replays through the
+                    # normal scoring path BEFORE any live poll — rows reach
+                    # the feature state in exactly the order a
+                    # never-overloaded run would have seen them.
+                    item = overload.next_replay()
+                    if item is not None:
+                        _launch(item.cols, item.offsets,
+                                replay_rows=item.rows)
+                        continue
+                if carry is not None:
+                    cols, offs = carry
+                    carry = None
+                else:
+                    cols = _poll()
+                    if cols is None:
+                        break
+                    if len(next(iter(cols.values()), ())) == 0:
+                        # Idle live source (e.g. KafkaSource on a quiet
+                        # topic): not a batch — no sink append, no step, no
+                        # checkpoint cadence, no max_batches consumption.
+                        # Flush the in-flight batches (their results must not
+                        # wait for future traffic), then wait a trigger.
+                        _drain()
+                        if overload is not None:
+                            # the quiet period is the ladder's recovery
+                            # window: tick the controller so descend dwell
+                            # accumulates and deferred batches replay even
+                            # if live traffic never returns
+                            overload.idle_tick()
+                        if trigger > 0:
+                            # rtfdslint: disable=blocking-call-on-loop-thread (sanctioned wait point: idle live source with nothing in flight — sleeping one trigger IS the correct behavior, there is no work to stall)
+                            time.sleep(trigger)
+                        continue
+                    offs = list(source.offsets)
+                # The adaptive controller overrides the static coalesce
+                # target while active (it only MERGES small polls upward —
+                # an oversized poll still bucket-pads as before).
+                assemble = auto.target_rows() if auto is not None else coalesce
+                if assemble > 0:
+                    # Never assemble past the largest jit bucket: a poll that
+                    # would overflow is carried into the NEXT batch, and its
+                    # rows stay excluded from this batch's checkpoint offsets
+                    # (a crash must replay them, not skip them).
+                    target = min(assemble, cap)
+                    parts = [cols]
+                    total = len(next(iter(cols.values())))
+                    while total < target:
+                        more = _poll()
+                        if more is None:
+                            exhausted = True  # serve the tail, then stop
+                            break
+                        m = len(next(iter(more.values()), ()))
+                        if m == 0:
+                            break  # idle: serve what we have now
+                        if total + m > cap:
+                            carry = (more, list(source.offsets))
+                            break
+                        parts.append(more)
+                        total += m
+                        offs = list(source.offsets)
+                    if len(parts) > 1:
+                        cols = {k: np.concatenate([p[k] for p in parts])
+                                for k in parts[0]}
+                if overload is not None and overload.should_defer():
+                    # Rung 3 admission control: the whole assembled batch
+                    # defers to the durable spill instead of dispatching. It
+                    # consumes no batch_index (sink lineage stays gap-free)
+                    # and state.offsets stays at the last SCORED batch, so a
+                    # crash replays deferred rows from the checkpoint.
+                    # Batches dispatched BEFORE the climb finish first —
+                    # rung 3 holds nothing in flight, so their results land
+                    # instead of idling in the pipeline behind the deferral.
+                    _drain()
+                    overload.defer(cols, offs)
+                    continue
+                _launch(cols, offs)
             if overload is not None and not capped:
                 # Source exhausted with batches still deferred: the
                 # stream must not end owing rows — force-drain the FIFO
@@ -2538,19 +2672,24 @@ class ScoringEngine:
                     _launch(item.cols, item.offsets,
                             replay_rows=item.rows)
             _drain()
+            # The writer drains before run() returns: the caller's
+            # follow-up (final checkpoint save, offset commits, reading
+            # the output) must see fully-landed writes, and a deferred
+            # writer error must surface in THIS run, with its own type.
+            if writer is not None:
+                _sink_blocked(writer.drain)
         finally:
             if overload is not None:
                 # revert every engine-side degrade so a later run() on
                 # this engine starts clean (rung metrics stay honest)
                 overload.deactivate()
+            if writer is not None:
+                # no thread outlives a run; on the way out of an
+                # exception the queued writes still land (the restore
+                # fence or the replay's overwrite deals with them) and
+                # the loop's exception stays the one that propagates
+                writer.stop()
         self._m_qdepth.set(0)
-        # Async sinks drain before run() returns: the caller's follow-up
-        # (final checkpoint save, offset commits, reading the output)
-        # must see fully-landed writes, and a deferred writer error must
-        # surface in THIS run, not on some later call.
-        sink_drain = getattr(sink, "drain", None)
-        if sink_drain is not None:
-            sink_drain()
         if self._cold is not None:
             # Land in-flight promotions and persist buffered demotions so
             # the caller's follow-up save records fresh segment lineage.
@@ -2576,6 +2715,9 @@ class ScoringEngine:
             "host_prep_p50_ms": snaps["host_prep"].get("p50_ms", 0.0),
             "dispatch_p50_ms": snaps["dispatch"].get("p50_ms", 0.0),
             "result_wait_p50_ms": snaps["result_wait"].get("p50_ms", 0.0),
+            # the loop thread blocked on its writer, and the write itself
+            # (timed on the writer thread): per batch
+            "sink_wait_p50_ms": snaps["sink_wait"].get("p50_ms", 0.0),
             "sink_write_p50_ms": snaps["sink_write"].get("p50_ms", 0.0),
             "pipeline_depth": depth,
             # the z-contraction mode the serving step compiled with —

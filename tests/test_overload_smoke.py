@@ -96,8 +96,12 @@ def _cfg(dcfg, tmp, enabled: bool, **overload_kw) -> Config:
         data=dcfg,
         features=FeatureConfig(customer_capacity=256,
                                terminal_capacity=512, cms_width=1 << 10),
+        # sink_queue_batches: the toy loop outruns its Parquet writer, and
+        # a writer queue of the default 8 would fill and hold the ladder
+        # up by its own pressure signal; the burst under test is the lag
         runtime=RuntimeConfig(batch_buckets=(256,), max_batch_rows=256,
                               precompile=True, autobatch=True,
+                              sink_queue_batches=64,
                               overload=OverloadConfig(**ok)),
     )
 
