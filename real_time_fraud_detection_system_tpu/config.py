@@ -76,10 +76,22 @@ class FeatureConfig:
     # count-min sketch tier (overestimate-only degradation, observable via
     # rtfds_feature_tier_rows_total).
     key_mode: str = "direct"
-    # key_mode="exact" knobs: fixed probe depth of the directory's double
-    # hashing (the directory is 2x the slot capacity, load factor <= 0.5,
-    # so 8 probes make admission misses vanishingly rare until the free
-    # list itself runs dry), and the recency-compaction cadence — every
+    # key_mode="exact" knobs: fixed probe depth P of the directory's double
+    # hashing (the directory has D = 2x the slot capacity entries), and the
+    # recency-compaction cadence. A key misses admission FOR GOOD — it is
+    # served from the sketch tier until a compaction frees one of its
+    # positions — when all P of its probe positions are taken, so filling
+    # the directory to load alpha loses D * alpha^(P+1) / (P+1) keys in
+    # expectation (tests/test_keydir.py holds the count to that): occupancy
+    # and P are chosen TOGETHER. Worked, D = 2^23 (2^22 slots): three
+    # quarters of the slots live (alpha 0.375) at P = 8 -> 137 keys on the
+    # sketch; half the slots (alpha 0.25) at P = 8 -> 3.6; alpha 0.375 at
+    # P = 16 -> 0.03; alpha 0.25 at P = 16 -> 3e-5 (what the benchmark's
+    # forest-rf100-d8-exact states, since its limit on wrong answers is 0).
+    # The default 8 is for a deployment that tolerates a few keys on the
+    # sketch. Whether or not the sketch tier serves any row, EVERY row
+    # updates both sketches and reads them (README, "Feature-state
+    # playbook": what that costs on the chip). Compaction — every
     # N batches a full-table vector pass reclaims slots whose newest
     # bucket_day is older than delay_days + max(windows) (dead history:
     # no query can ever see it). 0 = compaction off.

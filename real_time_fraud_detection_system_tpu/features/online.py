@@ -540,54 +540,55 @@ def compact_feature_state(
     ``EMPTY_KEY``/empty rows. The host appends the payload to
     ``io/coldstore.py`` — demote, don't discard.
     """
-    horizon = jnp.int32(cfg.delay_days + max(cfg.windows))
-    cutoff = now_day.astype(jnp.int32) - horizon
-    now = now_day.astype(jnp.int32)
-    demote = int(demote_slots)
-    out = {}
-    counts = []
-    payload = {}
-    for dir_name, ws_name in (("customer_dir", "customer"),
-                              ("terminal_dir", "terminal")):
-        kd = getattr(state, dir_name)
-        ws = getattr(state, ws_name)
-        if kd is None:
-            out[dir_name], out[ws_name] = kd, ws
-            counts.append(jnp.int32(0))
-            payload[ws_name] = None
-            continue
-        newest = jnp.max(ws.tables()[0], axis=1)  # [slot_cap]
-        slot_idx = jnp.clip(kd.slots, 0, ws.capacity - 1)
-        live = kd.slots >= 0
-        newest_e = newest[slot_idx]
-        dead_entry = live & (newest_e < cutoff)
+    with step_scope("compact"):
+        horizon = jnp.int32(cfg.delay_days + max(cfg.windows))
+        cutoff = now_day.astype(jnp.int32) - horizon
+        now = now_day.astype(jnp.int32)
+        demote = int(demote_slots)
+        out = {}
+        counts = []
+        payload = {}
+        for dir_name, ws_name in (("customer_dir", "customer"),
+                                  ("terminal_dir", "terminal")):
+            kd = getattr(state, dir_name)
+            ws = getattr(state, ws_name)
+            if kd is None:
+                out[dir_name], out[ws_name] = kd, ws
+                counts.append(jnp.int32(0))
+                payload[ws_name] = None
+                continue
+            newest = jnp.max(ws.tables()[0], axis=1)  # [slot_cap]
+            slot_idx = jnp.clip(kd.slots, 0, ws.capacity - 1)
+            live = kd.slots >= 0
+            newest_e = newest[slot_idx]
+            dead_entry = live & (newest_e < cutoff)
+            if demote > 0:
+                # Pressure eviction EXTENDS the dead mask (payload gathered
+                # before any vacate), so the demote variant pays ONE
+                # combined reclaim + window-table sweep — not a second
+                # full-table pass on top of the dead reclaim.
+                kd, ws, n, pay = _demote_oldest(
+                    kd, ws, dead_entry, newest_e, live, now,
+                    int(cfg.delay_days + max(cfg.windows)), demote,
+                    cfg.cold_highwater)
+                payload[ws_name] = pay
+            else:
+                old_slots = kd.slots  # pre-clear ids (reclaim vacates them)
+                kd, dead, n = reclaim_entries(kd, dead_entry)
+                tgt = jnp.where(dead, old_slots, ws.capacity)
+                ws = ws.clear_slots(tgt)
+                payload[ws_name] = None
+            out[dir_name] = kd
+            out[ws_name] = ws
+            counts.append(n)
+        new_state = state._replace(
+            customer=out["customer"], terminal=out["terminal"],
+            customer_dir=out["customer_dir"],
+            terminal_dir=out["terminal_dir"],
+        )
         if demote > 0:
-            # Pressure eviction EXTENDS the dead mask (payload gathered
-            # before any vacate), so the demote variant pays ONE
-            # combined reclaim + window-table sweep — not a second
-            # full-table pass on top of the dead reclaim.
-            kd, ws, n, pay = _demote_oldest(
-                kd, ws, dead_entry, newest_e, live, now,
-                int(cfg.delay_days + max(cfg.windows)), demote,
-                cfg.cold_highwater)
-            payload[ws_name] = pay
-        else:
-            old_slots = kd.slots  # pre-clear ids (reclaim vacates them)
-            kd, dead, n = reclaim_entries(kd, dead_entry)
-            tgt = jnp.where(dead, old_slots, ws.capacity)
-            ws = ws.set_rows(tgt, -1, 0.0, 0.0, 0.0)
-            payload[ws_name] = None
-        out[dir_name] = kd
-        out[ws_name] = ws
-        counts.append(n)
-    new_state = state._replace(
-        customer=out["customer"], terminal=out["terminal"],
-        customer_dir=out["customer_dir"],
-        terminal_dir=out["terminal_dir"],
-    )
-    if demote > 0:
-        return new_state, jnp.stack(counts), payload
-    return new_state, jnp.stack(counts)
+            return new_state, jnp.stack(counts), payload
+        return new_state, jnp.stack(counts)
 
 
 def _demote_oldest(
@@ -670,7 +671,7 @@ def _demote_oldest(
     old_slots = kd.slots
     kd, dead, n = reclaim_entries(kd, dead_entry | sel)
     tgt = jnp.where(dead, old_slots, slot_cap)
-    ws = ws.set_rows(tgt, -1, 0.0, 0.0, 0.0)
+    ws = ws.clear_slots(tgt)
     return kd, ws, n, (keys, bd, cnt, amt, frd)
 
 

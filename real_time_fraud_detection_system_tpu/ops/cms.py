@@ -87,12 +87,22 @@ def cms_update(
         fresh = valid & (day_in == new_slice_day[sl])
         w = fresh.astype(jnp.float32)  # [B]
         cols = multi_hash(key, depth, width)  # [depth, B]
-        rows = jnp.broadcast_to(
-            jnp.arange(depth, dtype=jnp.int32)[:, None], cols.shape)
-        slc = jnp.broadcast_to(sl[None, :], cols.shape)
+        # The cell of (slice, depth row, column) in the table laid flat,
+        # and the scatter-add written on that flat view: given the three
+        # indices the chip's compiler flattens them itself and the fusion
+        # it makes carries no op_name, so 11.5 ms a step of sketch updates
+        # read as unscoped (PERF.md, PR 32). Same cells, same order.
+        flat = ((sl[None, :] * depth
+                 + jnp.arange(depth, dtype=jnp.int32)[:, None]) * width
+                + cols).reshape(-1)
         wb = jnp.broadcast_to(w[None, :], cols.shape)
-        count = count.at[slc, rows, cols].add(wb)
-        amt = amt.at[slc, rows, cols].add(wb * amount[None, :])
+
+        def add(table, values):  # values [depth, B]
+            return table.reshape(-1).at[flat].add(
+                values.reshape(-1)).reshape(table.shape)
+
+        count = add(count, wb)
+        amt = add(amt, wb * amount[None, :])
         frd = sk.fraud
         if frd is not None:
             # Same slice-reset + fresh-mask discipline as count/amount; a
@@ -101,7 +111,7 @@ def cms_update(
             frd = jnp.where(advanced, 0.0, frd)
             f_in = (jnp.zeros_like(w) if fraud is None
                     else fraud.astype(jnp.float32))
-            frd = frd.at[slc, rows, cols].add(wb * f_in[None, :])
+            frd = add(frd, wb * f_in[None, :])
         return CountMinSketch(slice_day=new_slice_day, count=count,
                               amount=amt, fraud=frd)
 

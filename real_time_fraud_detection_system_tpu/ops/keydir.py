@@ -41,6 +41,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -94,15 +95,20 @@ def _canon(key: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(key == EMPTY_KEY, jnp.uint32(0xFFFFFFFE), key)
 
 
-def _probe_positions(key: jnp.ndarray, dir_cap: int,
-                     n_probes: int) -> jnp.ndarray:
-    """[B] keys → [B, P] probe positions (double hashing, odd stride)."""
+def _probe_position(key: jnp.ndarray, j, dir_cap: int) -> jnp.ndarray:
+    """Position of probe ``j`` (broadcast against ``key``): double hashing
+    over a power-of-two table, an odd stride walks the whole of it."""
     h1 = hash_u32(key, seed=0)
     h2 = hash_u32(key, seed=1) | jnp.uint32(1)
-    j = jnp.arange(n_probes, dtype=jnp.uint32)
-    pos = (h1[:, None] + j[None, :] * h2[:, None]) \
-        & jnp.uint32(dir_cap - 1)
-    return pos.astype(jnp.int32)
+    return ((h1 + j * h2) & jnp.uint32(dir_cap - 1)).astype(jnp.int32)
+
+
+def _probe_positions(key: jnp.ndarray, dir_cap: int,
+                     n_probes: int) -> jnp.ndarray:
+    """[B] keys → [B, P] probe positions."""
+    return _probe_position(
+        key[:, None], jnp.arange(n_probes, dtype=jnp.uint32)[None, :],
+        dir_cap)
 
 
 def lookup_slots(
@@ -182,68 +188,86 @@ def admit_slots(
     with step_scope("keydir"):
         dir_cap = kd.dir_capacity
         slot_cap = kd.slot_capacity
-        key = _canon(key)
         B = int(key.shape[0])
-        pos = _probe_positions(key, dir_cap, n_probes)  # [B, P]
         keys = kd.keys
-        # FULL-depth lookup FIRST, claims only for keys with no existing
-        # entry: reclaim_entries can vacate a position on a live key's
-        # probe-path PREFIX, and a claim-as-you-probe insert would grab that
-        # vacancy before ever reaching the key's real entry — duplicating
-        # the key, resetting its window history, and leaking its old slot.
-        # (lookup_slots scans all P positions for the same reason; this is
-        # the insert-side half of the no-tombstones argument.)
-        found = keys[pos] == key[:, None]  # [B, P]
-        pidx = jnp.argmax(found, axis=1)
-        hit0 = found.any(axis=1) & valid
-        entry = jnp.where(
-            hit0, jnp.take_along_axis(pos, pidx[:, None], axis=1)[:, 0], 0)
-        placed = ~valid | hit0
-        claimed = jnp.zeros(B, dtype=bool)  # matched via a claim made NOW
-        for j in range(n_probes):
-            p = pos[:, j]
-            cur = keys[p]
-            # batch duplicates of a key claimed in an EARLIER round match
-            # here (pre-call lookup could not see that claim)
-            hit = (~placed) & (cur == key)
-            entry = jnp.where(hit, p, entry)
-            placed = placed | hit
-            # Claim attempt: scatter-min our key into still-empty positions;
-            # among racing writers the smallest key wins, losers re-probe.
-            want = (~placed) & (cur == EMPTY_KEY)
-            cand = jnp.where(want, key, EMPTY_KEY)
-            keys = keys.at[p].min(cand)
-            won = want & (keys[p] == key)
-            entry = jnp.where(won, p, entry)
-            claimed = claimed | won
-            placed = placed | won
-        # One owner per newly claimed entry (batch duplicates of one new key
-        # all carry claimed=True on the same entry; exactly one pops a slot).
-        rows = jnp.arange(B, dtype=jnp.int32)
-        owner = jnp.full((dir_cap,), B, jnp.int32).at[
-            jnp.where(claimed, entry, dir_cap)].min(rows, mode="drop")
-        new = claimed & (owner[entry] == rows)
-        # Grant free slots to owners in row order; owners past the stack
-        # height roll their claim back (their duplicates then miss too).
-        rank = jnp.cumsum(new.astype(jnp.int32)) - 1  # [B]
-        avail = kd.free_top
-        has = new & (rank < avail)
-        slot_new = kd.free[jnp.clip(avail - 1 - rank, 0, slot_cap - 1)]
-        slots = kd.slots.at[jnp.where(has, entry, dir_cap)].set(
-            slot_new, mode="drop")
-        revert = new & ~(rank < avail)
-        keys = keys.at[jnp.where(revert, entry, dir_cap)].set(
-            EMPTY_KEY, mode="drop")
-        free_top = avail - jnp.sum(has.astype(jnp.int32))
-        # Final resolution covers every case at once: hits, fresh grants,
-        # batch duplicates of grants, rolled-back claims (keys[entry] no
-        # longer matches), and rows that never placed (probe overflow).
-        slot = slots[entry]
-        admitted = placed & valid & (keys[entry] == key) & (slot >= 0)
+        with step_scope("lookup"):
+            key = _canon(key)
+            pos = _probe_positions(key, dir_cap, n_probes)  # [B, P]
+            # FULL-depth lookup FIRST, claims only for keys with no
+            # existing entry: reclaim_entries can vacate a position on a
+            # live key's probe-path PREFIX, and a claim-as-you-probe insert
+            # would grab that vacancy before ever reaching the key's real
+            # entry — duplicating the key, resetting its window history,
+            # and leaking its old slot. (lookup_slots scans all P positions
+            # for the same reason; this is the insert-side half of the
+            # no-tombstones argument.)
+            found = keys[pos] == key[:, None]  # [B, P]
+            pidx = jnp.argmax(found, axis=1)
+            hit0 = found.any(axis=1) & valid
+            entry = jnp.where(
+                hit0,
+                jnp.take_along_axis(pos, pidx[:, None], axis=1)[:, 0], 0)
+        with step_scope("claim"):
+            # The P rounds as ONE loop body: unrolled, 2 x 16 rounds of a
+            # scatter and two gathers made each of the five bucket programs
+            # compile ~16 s on the chip (PERF.md, PR 32). Same values, round
+            # for round (tests/test_keydir.py pins it bit for bit).
+            def claim_round(j, carry):
+                keys, entry, placed, claimed = carry
+                p = _probe_position(  # pos[:, j]
+                    key, jnp.asarray(j, jnp.uint32), dir_cap)
+                cur = keys[p]
+                # batch duplicates of a key claimed in an EARLIER round
+                # match here (pre-call lookup could not see that claim)
+                hit = (~placed) & (cur == key)
+                entry = jnp.where(hit, p, entry)
+                placed = placed | hit
+                # Claim attempt: scatter-min our key into still-empty
+                # positions; among racing writers the smallest key wins,
+                # losers re-probe.
+                want = (~placed) & (cur == EMPTY_KEY)
+                cand = jnp.where(want, key, EMPTY_KEY)
+                keys = keys.at[p].min(cand)
+                won = want & (keys[p] == key)
+                entry = jnp.where(won, p, entry)
+                return keys, entry, placed | won, claimed | won
+
+            # claimed: matched via a claim made NOW
+            keys, entry, placed, claimed = jax.lax.fori_loop(
+                0, n_probes, claim_round,
+                (keys, entry, ~valid | hit0, jnp.zeros(B, dtype=bool)))
+        with step_scope("grant"):
+            # One owner per newly claimed entry (batch duplicates of one
+            # new key all carry claimed=True on the same entry; exactly one
+            # pops a slot).
+            rows = jnp.arange(B, dtype=jnp.int32)
+            owner = jnp.full((dir_cap,), B, jnp.int32).at[
+                jnp.where(claimed, entry, dir_cap)].min(rows, mode="drop")
+            new = claimed & (owner[entry] == rows)
+            # Grant free slots to owners in row order; owners past the
+            # stack height roll their claim back (their duplicates then
+            # miss too).
+            rank = jnp.cumsum(new.astype(jnp.int32)) - 1  # [B]
+            avail = kd.free_top
+            has = new & (rank < avail)
+            slot_new = kd.free[jnp.clip(avail - 1 - rank, 0, slot_cap - 1)]
+            slots = kd.slots.at[jnp.where(has, entry, dir_cap)].set(
+                slot_new, mode="drop")
+            revert = new & ~(rank < avail)
+            keys = keys.at[jnp.where(revert, entry, dir_cap)].set(
+                EMPTY_KEY, mode="drop")
+            free_top = avail - jnp.sum(has.astype(jnp.int32))
+            # Final resolution covers every case at once: hits, fresh
+            # grants, batch duplicates of grants, rolled-back claims
+            # (keys[entry] no longer matches), and rows that never placed
+            # (probe overflow).
+            slot = slots[entry]
+            admitted = placed & valid & (keys[entry] == key) & (slot >= 0)
+            slot = jnp.where(admitted, slot, 0)
         return (
             KeyDirectory(keys=keys, slots=slots, free=kd.free,
                          free_top=free_top),
-            jnp.where(admitted, slot, 0),
+            slot,
             admitted,
         )
 

@@ -726,7 +726,17 @@ class ScoringEngine:
         self._m_tier = None
         self._m_slots_occ = None
         self._m_slots_rec = None
+        self._m_compactions = None
+        self._m_compact_s = None
         if self._exact:
+            self._m_compactions = reg.counter(
+                "rtfds_state_compactions_total",
+                "recency-compaction passes run (features.compact_every)")
+            self._m_compact_s = reg.histogram(
+                "rtfds_state_compact_seconds",
+                "loop-thread seconds in one compaction pass: the dispatch "
+                "and the wait for its reclaimed counts, which drains the "
+                "steps in flight")
             self._m_tier = {
                 t: reg.counter(
                     "rtfds_feature_tier_rows_total",
@@ -1106,6 +1116,7 @@ class ScoringEngine:
         if (not self._compact_every
                 or self.state.batches_done % self._compact_every != 0):
             return
+        t0 = time.perf_counter()
         day = jnp.asarray(np.int32(self._max_day))
         with self.tracer.span("state_compact", day=self._max_day):
             with self._recompile.step(step_signature(
@@ -1120,6 +1131,8 @@ class ScoringEngine:
             fstate, reclaimed = out
         self.state.feature_state = fstate
         self._record_compaction(fstate, reclaimed)
+        self._m_compactions.inc()
+        self._m_compact_s.observe(time.perf_counter() - t0)
 
     def _record_compaction(self, fstate, reclaimed) -> None:
         """Meter one compaction pass (counters, gauges, flight event) —

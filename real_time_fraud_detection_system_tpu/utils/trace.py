@@ -362,6 +362,8 @@ STEP_SCOPES = (
     "relayout",
     "query", "gather", "sum",                      # ops/windows
     "keydir", "cms",                               # ops/keydir, ops/cms
+    "lookup", "claim", "grant",                    # the parts of keydir
+    "compact",                                     # features/online
     "assemble",                                    # features/online
     "scale",                                       # models/scaler
     "classify", "fused_step", "learn", "emit",     # engine.step

@@ -192,3 +192,115 @@ def test_admission_exactness_property(n_keys, slot_cap):
     slots = list(seen.values())
     assert len(set(slots)) == len(slots) <= slot_cap
     assert int(occupied_slots(kd)) == len(seen)
+
+
+@pytest.mark.parametrize("n_probes", [2, 4, 8])
+@pytest.mark.parametrize("load", [0.25, 0.375])
+def test_keys_that_miss_admission_follow_load_and_probe_depth(load,
+                                                              n_probes):
+    """The rule README's "Feature-state playbook" states: a key misses
+    admission FOR GOOD when all P of its probe positions are taken, so
+    filling a directory of D entries to load alpha (alpha·D distinct
+    keys, each admitted at the load it finds) loses
+    ``D · alpha^(P+1) / (P+1)`` keys in expectation: 137 of 3.1 M at
+    alpha 0.375 and P = 8 in a 2^23-entry directory, 3e-5 at alpha 0.25
+    and P = 16 (``benchmark/configs/forest-rf100-d8-exact.json``). Held
+    here on a small directory, within sampling error."""
+    dir_cap, batch = 1 << 16, 1024
+    n_keys = int(load * dir_cap)
+    rng = np.random.default_rng([dir_cap, n_probes, int(load * 1000)])
+    keys = rng.choice(1 << 31, size=n_keys, replace=False).astype(np.uint32)
+    admit = jax.jit(admit_slots, static_argnames="n_probes")
+    kd = init_keydir(dir_cap, dir_cap // 2)  # the free stack never runs dry
+    missed = 0
+    for i in range(0, n_keys, batch):
+        kd, _, adm = admit(kd, jnp.asarray(keys[i:i + batch]),
+                           jnp.ones(batch, bool), n_probes=n_probes)
+        missed += batch - int(np.asarray(adm).sum())
+    assert int(occupied_slots(kd)) == n_keys - missed
+    expected = dir_cap * load ** (n_probes + 1) / (n_probes + 1)
+    # the count is a sum of rare independent events: Poisson-wide
+    assert abs(missed - expected) <= 4.0 * np.sqrt(expected) + 2.0, (
+        missed, expected)
+
+
+def _admit_slots_unrolled(kd, key, valid, n_probes):
+    """``admit_slots`` as it was written before PR 32: the P claim rounds
+    unrolled in Python over the ``[B, P]`` probe positions. Kept here as
+    the pin for the loop the program now runs."""
+    from real_time_fraud_detection_system_tpu.ops.keydir import (
+        KeyDirectory,
+        _canon,
+        _probe_positions,
+    )
+
+    dir_cap, slot_cap = kd.dir_capacity, kd.slot_capacity
+    key = _canon(key)
+    B = int(key.shape[0])
+    pos = _probe_positions(key, dir_cap, n_probes)
+    keys = kd.keys
+    found = keys[pos] == key[:, None]
+    pidx = jnp.argmax(found, axis=1)
+    hit0 = found.any(axis=1) & valid
+    entry = jnp.where(
+        hit0, jnp.take_along_axis(pos, pidx[:, None], axis=1)[:, 0], 0)
+    placed = ~valid | hit0
+    claimed = jnp.zeros(B, dtype=bool)
+    for j in range(n_probes):
+        p = pos[:, j]
+        cur = keys[p]
+        hit = (~placed) & (cur == key)
+        entry = jnp.where(hit, p, entry)
+        placed = placed | hit
+        want = (~placed) & (cur == EMPTY_KEY)
+        keys = keys.at[p].min(jnp.where(want, key, EMPTY_KEY))
+        won = want & (keys[p] == key)
+        entry = jnp.where(won, p, entry)
+        claimed = claimed | won
+        placed = placed | won
+    rows = jnp.arange(B, dtype=jnp.int32)
+    owner = jnp.full((dir_cap,), B, jnp.int32).at[
+        jnp.where(claimed, entry, dir_cap)].min(rows, mode="drop")
+    new = claimed & (owner[entry] == rows)
+    rank = jnp.cumsum(new.astype(jnp.int32)) - 1
+    avail = kd.free_top
+    has = new & (rank < avail)
+    slot_new = kd.free[jnp.clip(avail - 1 - rank, 0, slot_cap - 1)]
+    slots = kd.slots.at[jnp.where(has, entry, dir_cap)].set(
+        slot_new, mode="drop")
+    revert = new & ~(rank < avail)
+    keys = keys.at[jnp.where(revert, entry, dir_cap)].set(
+        EMPTY_KEY, mode="drop")
+    slot = slots[entry]
+    admitted = placed & valid & (keys[entry] == key) & (slot >= 0)
+    return (KeyDirectory(keys=keys, slots=slots, free=kd.free,
+                         free_top=avail - jnp.sum(has.astype(jnp.int32))),
+            jnp.where(admitted, slot, 0), admitted)
+
+
+@pytest.mark.parametrize("n_probes", [1, 3, 16])
+def test_claim_rounds_as_a_loop_equal_the_unrolled_rounds_bit_for_bit(
+        n_probes):
+    """Racing new keys, batch duplicates of a new key, returning keys,
+    invalid rows, reclaimed vacancies on a probe path and a free stack
+    that runs dry mid-batch: the directory and the answers of the loop
+    are the unrolled rounds' to the bit, batch after batch."""
+    rng = np.random.default_rng(n_probes)
+    loop = jax.jit(admit_slots, static_argnames="n_probes")
+    plain = jax.jit(_admit_slots_unrolled, static_argnames="n_probes")
+    kd_a = kd_b = init_keydir(256, 96)
+    ran_dry = False
+    for step in range(12):
+        keys = jnp.asarray(rng.integers(0, 400, 128).astype(np.uint32))
+        valid = jnp.asarray(rng.random(128) < 0.9)
+        kd_a, slot_a, adm_a = loop(kd_a, keys, valid, n_probes=n_probes)
+        kd_b, slot_b, adm_b = plain(kd_b, keys, valid, n_probes=n_probes)
+        ran_dry = ran_dry or int(kd_a.free_top) == 0
+        if step % 4 == 3:  # vacate a third of the live entries
+            dead = jnp.asarray(rng.random(256) < 0.33)
+            kd_a, kd_b = (reclaim_entries(kd, dead)[0]
+                          for kd in (kd_a, kd_b))
+        for a, b in zip(jax.tree.leaves((kd_a, slot_a, adm_a)),
+                        jax.tree.leaves((kd_b, slot_b, adm_b))):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert ran_dry or n_probes == 1  # one probe loses keys first

@@ -63,14 +63,17 @@ TABLE = {"customer", "terminal"}
 UPDATE = {"update", "stamp", "reset", "scatter"}
 QUERY = {"query", "gather", "sum"}
 COMMON = {"unpack", "assemble", "scale", "classify"} | TABLE | UPDATE | QUERY
+# admit_slots names its three parts: the full-depth probe, the P claim
+# rounds, and the owner / free-stack / roll-back / final resolution
+KEYDIR_PARTS = {"lookup", "claim", "grant"}
 
 # variant → (kind, FeatureConfig overrides, RuntimeConfig overrides,
 #            sharded over n virtual devices, scopes it must carry)
 VARIANTS = {
     "forest": ("forest", {}, {}, 0, COMMON),
     "logreg": ("logreg", {}, {}, 0, COMMON),
-    "exact": ("logreg", {"key_mode": "exact"}, {}, 0,
-              COMMON | {"keydir", "cms"}),
+    "exact": ("logreg", {"key_mode": "exact", "compact_every": 4}, {}, 0,
+              COMMON | {"keydir", "cms"} | KEYDIR_PARTS),
     "cms": ("logreg", {"customer_source": "cms"}, {}, 0,
             COMMON | {"cms"}),
     "selective": ("forest", {}, {"emit_threshold": 0.4}, 0,
@@ -181,6 +184,44 @@ def test_step_hlo_carries_the_variants_scopes(variant):
         assert len(mesh) == 2
         for paths in mesh:
             assert paths == chip, sorted(paths ^ chip)
+
+
+def test_exact_key_path_names_its_parts_under_their_table():
+    """``key_mode="exact"``: every op of ``admit_slots`` sits under
+    ``<table>/rtfds.keydir/<part>`` with one of the three parts (the
+    benchmark's ``step_keydir_{lookup,claim,grant}_ms`` add up to
+    ``step_keydir_ms``), the sketch's update AND its query sit under
+    ``<table>/rtfds.cms``, and the compaction — a program of its own —
+    carries ``rtfds.compact`` on everything it names."""
+    eng = _engine("exact")
+    (low,) = _lowered_steps(eng)
+    paths = [_scopes(n) for n in _op_names(low.compile().as_text())]
+    keydir = [p for p in paths if "keydir" in p]
+    assert keydir
+    for p in keydir:
+        i = p.index("keydir")
+        assert i >= 1 and p[i - 1] in TABLE, p
+        assert len(p) > i + 1 and p[i + 1] in KEYDIR_PARTS, p
+    assert {p[p.index("keydir") + 1] for p in keydir} == KEYDIR_PARTS
+    assert not [p for p in paths if KEYDIR_PARTS & set(p)
+                and "keydir" not in p]
+    said = [(_scopes(n), n) for n in _op_names(
+        low.as_text(dialect="hlo", debug_info=True))]
+    cms = [(p, n) for p, n in said if "cms" in p]
+    for p, _ in cms:
+        assert p[p.index("cms") - 1] in TABLE, p
+    # the query's gathers as well as the update's scatter-adds
+    assert any(n.endswith("gather") for _, n in cms)
+    assert any("scatter" in n.rsplit("/", 1)[-1] for _, n in cms)
+    (sig,) = [s for s in eng.dispatch_inventory() if s.variant == "compact"]
+    compact = eng.signature_step(sig).lower(*eng.signature_templates(sig))
+    named = [_scopes(n) for n in _op_names(
+        compact.as_text(dialect="hlo", debug_info=True))
+        if n.startswith("jit(")]  # the rest: parameters, reducers' bodies
+    assert named and all(p[:1] == ["compact"] for p in named), [
+        p for p in named if p[:1] != ["compact"]][:3]
+    kept = [_scopes(n) for n in _op_names(compact.compile().as_text())]
+    assert any(p[:1] == ["compact"] for p in kept)
 
 
 def test_unknown_scope_is_refused():

@@ -388,3 +388,44 @@ def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
     assert text.count("all-to-all") >= 2
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert 0.2 * 1.9e9 < per_device < 0.3 * 2.1e9  # ~a quarter of the state
+
+
+@pytest.mark.parametrize("variant", ["step", "compact"])
+def test_exact_key_programs_fit_the_chip_at_the_benchmarks_size(
+        topo, one_chip, as_on_chip, variant):
+    """``key_mode="exact"`` at the size of the benchmark's
+    ``forest-rf100-d8-exact`` (2^22 + 2^23 slots, directories of twice
+    that, 16 probes): the 65,536-row step and the ``("compact",)`` program
+    compile for one v5e with their state donated and room to spare. The
+    compaction is the one the CPU cannot vouch for: written with a lane
+    per directory entry into ``set_rows``, its ``[2^24, 40]`` element
+    indices alone made the chip's compiler refuse it — 18.41 GB of 15.75
+    (PERF.md, PR 32) — while every CPU test passed."""
+    from real_time_fraud_detection_system_tpu.config import (
+        Config,
+        FeatureConfig,
+        RuntimeConfig,
+    )
+    from real_time_fraud_detection_system_tpu.models.scaler import Scaler
+    from real_time_fraud_detection_system_tpu.runtime import ScoringEngine
+
+    fcfg = FeatureConfig(customer_capacity=1 << 22,
+                         terminal_capacity=1 << 23, key_mode="exact",
+                         keydir_probes=16, compact_every=64)
+    eng = ScoringEngine(
+        Config(features=fcfg, runtime=RuntimeConfig(
+            z_mode="int8", batch_buckets=(65536,), max_batch_rows=65536)),
+        kind="forest", params=_forest(),
+        scaler=Scaler(mean=np.zeros(N_FEAT, np.float32),
+                      scale=np.ones(N_FEAT, np.float32)),
+        feature_state=_on(one_chip, _state_shapes(fcfg)))
+    (sig,) = [s for s in eng.dispatch_inventory() if s.variant == variant]
+    mem = eng.signature_step(sig).lower(
+        *_on(one_chip, eng.signature_templates(sig))).compile(
+        ).memory_analysis()
+    state = 8_409_579_848  # features/online.state_bytes
+    assert mem.argument_size_in_bytes >= state
+    assert mem.alias_size_in_bytes >= state  # donated, updated in place
+    # the chip has 15.75 GB for a program; the direct step's temporaries
+    # are 1.7 GB, the compaction's one padded [2^23, 40] view 4.4
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
