@@ -27,7 +27,7 @@ from real_time_fraud_detection_system_tpu.ops.cms import (
     cms_add_fraud,
     cms_init,
     cms_query,
-    cms_query_fraud,
+    cms_query_where,
     cms_update,
 )
 from real_time_fraud_detection_system_tpu.ops.hashing import key_slot
@@ -284,25 +284,31 @@ class TablePlane:
         return TableState(win, kd, sketch, tier), slot, adm
 
     def query(self, ts: TableState, slot, adm, key, day, valid):
-        """The gather half → (tstate' (tier counted), [rows, 2·NW])."""
+        """The gather half → (tstate' (tier counted), [rows, 2·NW]).
+
+        Under ``exact`` the sketch is read for the rows it serves —
+        delivered rows that missed admission, ``valid & ~adm`` — and for
+        no others (:func:`~..ops.cms.cms_query_where`: nothing missed,
+        no sketch table touched). A padding row (``~valid``) is no miss:
+        its features hold the sketch read's fill, 0.0."""
         windows = tuple(self.cfg.windows)
         delay = 0 if self._customer else self.cfg.delay_days
-        pick = (0, 1) if self._customer else (0, 2)  # count, amount|fraud
-        hot = cold = None
-        if slot is not None:
-            got = query_windows(ts.windows, slot, day, windows, delay=delay)
-            hot = [got[i] for i in pick]
-        if slot is None or adm is not None:  # the sketch tier is read
-            got = (cms_query(ts.sketch, key, day, windows) if self._customer
-                   else cms_query_fraud(ts.sketch, key, day, windows,
-                                        delay=delay))
-            cold = [got[i] for i in pick]
+        if slot is None:  # customer_source="cms": the sketch serves all
+            return ts, jnp.concatenate(
+                cms_query(ts.sketch, key, day, windows), axis=1)
+        got = query_windows(ts.windows, slot, day, windows, delay=delay)
+        served = [got[i] for i in ((0, 1) if self._customer else (0, 2))]
         if adm is not None:
-            hot = [jnp.where(adm[:, None], h, c) for h, c in zip(hot, cold)]
+            miss = valid & ~adm
+            cold, _ = cms_query_where(
+                ts.sketch, ("count", "amount" if self._customer else "fraud"),
+                key, day, miss, windows, delay)
+            served = [jnp.where(adm[:, None], h, c)
+                      for h, c in zip(served, cold)]
             ts = ts._replace(tier=ts.tier + jnp.stack([
                 jnp.sum((valid & adm).astype(jnp.float32)),
-                jnp.sum((valid & ~adm).astype(jnp.float32))]))
-        return ts, jnp.concatenate(hot or cold, axis=1)
+                jnp.sum(miss.astype(jnp.float32))]))
+        return ts, jnp.concatenate(served, axis=1)
 
     def __call__(self, ts: TableState, key, day, amount, fraud, valid):
         with step_scope(self.table):

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from real_time_fraud_detection_system_tpu.ops.hashing import multi_hash
@@ -187,6 +188,74 @@ def _cms_query_tables(
                   cols[:, :, None]]
             out.append((jnp.min(g, axis=0) * live) @ sel.T)
         return tuple(out)
+
+
+def chunk_rows(n_rows: int) -> int:
+    """K: the rows one trip of :func:`cms_query_where` reads — 256, or
+    half the batch bucket where that is smaller. Chosen on a v5e at the
+    65,536 bucket from 256 / 512 / 1,024 / 2,048 / 4,096 / 8,192
+    (PERF.md, PR 33): a trip's two table-gathers cost 0.74 / 1.65 / 3.8 /
+    7.3 / 14.5 / 28.7 ms, and a batch in which every row missed 214 /
+    215 / 244 / 235 / 233 / 230 ms where the whole-batch read costs 200:
+    the smallest chunk is the cheapest a row at both ends, and a trip's
+    fixed cost (~0.1 ms) forbids going much lower (128: 0.43 and 222).
+    Inside the whole step, every row missed, 256 read 435 ms against
+    4,096's 422 and the whole-batch read's 412: the price of a point no
+    deployment should stand at, paid for a miss that costs a twentieth
+    where deployments do. Never the whole of a
+    bucket, half of a small one: a loop whose one chunk is the batch
+    reads nothing that depends on the trip, the compiler hoists the read
+    out of it, and every batch pays the read again."""
+    return min(256, -(-n_rows // 2))
+
+
+def cms_query_where(
+    sk: CountMinSketch,
+    columns: Sequence[str],  # of "count" | "amount" | "fraud"
+    key: jnp.ndarray,  # uint32 [B]
+    day: jnp.ndarray,  # int32 [B]
+    rows: jnp.ndarray,  # bool [B]: the rows the sketch serves
+    windows: Sequence[int],
+    delay: int = 0,
+) -> Tuple[Tuple[jnp.ndarray, ...], jnp.ndarray]:
+    """The windowed estimates of ``columns`` for the rows where ``rows``
+    is true and for no others, at a cost that follows their count →
+    ``(one [B, NW] a column, trips)``.
+
+    The served rows are ranked (a cumulative sum, one scatter of row
+    indices by rank) and read ``chunk_rows(B)`` at a time by
+    :func:`_cms_query_tables` — the whole-batch read's arithmetic, so a
+    served row gets the bits :func:`cms_query` gives it — in a
+    ``lax.while_loop`` of ``trips = ⌈served ÷ K⌉`` turns: none served,
+    no sketch table is touched. A row that is not served reads 0.0 in
+    every column."""
+    with step_scope("cms"):
+        (b,) = key.shape
+        k = chunk_rows(b)
+        span = -(-b // k) * k
+        upto = jnp.cumsum(rows.astype(jnp.int32))  # served rows ≤ here
+        served = upto[-1]
+        # order[r] = the r-th served row; past the last one, b: out of
+        # range, so the write-back below drops it
+        order = jnp.full((span,), b, jnp.int32).at[
+            jnp.where(rows, upto - 1, span)].set(
+                jnp.arange(b, dtype=jnp.int32), mode="drop")
+        tables = tuple(getattr(sk, c) for c in columns)
+
+        def read_chunk(carry):
+            trip, out = carry
+            at = jax.lax.dynamic_slice(order, (trip * k,), (k,))
+            src = jnp.minimum(at, b - 1)
+            got = _cms_query_tables(sk, tables, key[src], day[src],
+                                    windows, delay)
+            return trip + 1, tuple(
+                o.at[at].set(g, mode="drop") for o, g in zip(out, got))
+
+        zeros = jnp.zeros((b, len(windows)), jnp.float32)
+        trips, out = jax.lax.while_loop(
+            lambda carry: carry[0] * k < served, read_chunk,
+            (jnp.int32(0), (zeros,) * len(tables)))
+        return out, trips
 
 
 def cms_query(

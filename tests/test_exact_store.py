@@ -3,6 +3,7 @@ semantics, exactness vs direct mode, overflow to the CMS tier, recency
 compaction, feedback routing, and the config-level guard rails."""
 
 import dataclasses as dc
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,10 +15,16 @@ from real_time_fraud_detection_system_tpu.config import (
     FeatureConfig,
     RuntimeConfig,
 )
-from real_time_fraud_detection_system_tpu.core.batch import make_batch
+from real_time_fraud_detection_system_tpu.core.batch import (
+    make_batch,
+    pad_batch,
+)
 from real_time_fraud_detection_system_tpu.features.online import (
+    TablePlane,
     apply_feedback,
+    assemble,
     compact_feature_state,
+    fraud_of,
     init_feature_state,
     state_bytes,
     update_and_featurize,
@@ -25,7 +32,14 @@ from real_time_fraud_detection_system_tpu.features.online import (
 )
 from real_time_fraud_detection_system_tpu.models.logreg import init_logreg
 from real_time_fraud_detection_system_tpu.models.scaler import Scaler
+from real_time_fraud_detection_system_tpu.ops.cms import (
+    chunk_rows,
+    cms_query,
+    cms_query_fraud,
+    cms_query_where,
+)
 from real_time_fraud_detection_system_tpu.ops.hashing import slot_of
+from real_time_fraud_detection_system_tpu.ops.windows import query_windows
 from real_time_fraud_detection_system_tpu.runtime.engine import (
     ScoringEngine,
 )
@@ -203,6 +217,136 @@ def test_overflow_serves_cms_tier_and_counts_it():
     # CMS-tier counts keep the overestimate-only contract: the 30-day
     # customer count can never undercount the key's true row count
     assert dense > 0
+
+
+# ---------------------------------------------------------------------------
+# the sketch tier's read rule: only the rows it serves, in chunks (PR 33)
+# ---------------------------------------------------------------------------
+
+READ_ROWS, READ_VALID = 640, 600  # 40 padding rows, scattered
+READ_K = 256  # chunk_rows(640)
+
+
+@functools.lru_cache(maxsize=None)  # immutable arrays, twelve cases
+def _updated_plane(table):
+    """One table's plane under ``exact`` after two batches, every key
+    admitted: ``(plane, tstate, slot, adm, key, day, valid)`` of the
+    second — 640 rows of which 40, scattered, are padding, the keys drawn
+    from 100 (so most are duplicated), days on both sides of the
+    terminal delay."""
+    cfg = _fcfg(key_mode="exact")
+    plane = TablePlane(table, cfg)
+    ts = plane.of(init_feature_state(cfg))
+    rng = np.random.default_rng(33)
+    for step in range(2):
+        key = jnp.asarray(rng.integers(0, 100, READ_ROWS).astype(np.uint32))
+        day = jnp.asarray(
+            (DAY0 + 9 * step + rng.integers(0, 9, READ_ROWS)).astype(
+                np.int32))
+        amount = jnp.asarray(
+            rng.integers(100, 50000, READ_ROWS).astype(np.float32) / 100)
+        fraud = jnp.asarray(
+            (rng.random(READ_ROWS) < 0.3).astype(np.float32))
+        valid = np.zeros(READ_ROWS, bool)
+        valid[rng.permutation(READ_ROWS)[:READ_VALID]] = True
+        valid = jnp.asarray(valid)
+        ts, slot, adm = plane.update(ts, key, day, amount, fraud, valid)
+    assert bool((adm == valid).all())  # admit_slots: padding is not admitted
+    return plane, ts, slot, adm, key, day, valid
+
+
+def _whole_batch_read(plane, ts, slot, adm, key, day, valid):
+    """The read as it stood before PR 33, the plain reference: the
+    sketch read for EVERY row of the batch, then picked per row."""
+    windows = tuple(plane.cfg.windows)
+    customer = plane.table == "customer"
+    delay = 0 if customer else plane.cfg.delay_days
+    pick = (0, 1) if customer else (0, 2)
+    got = query_windows(ts.windows, slot, day, windows, delay=delay)
+    hot = [got[i] for i in pick]
+    got = (cms_query(ts.sketch, key, day, windows) if customer
+           else cms_query_fraud(ts.sketch, key, day, windows, delay=delay))
+    cold = [got[i] for i in pick]
+    mat = jnp.concatenate(
+        [jnp.where(adm[:, None], h, c) for h, c in zip(hot, cold)], axis=1)
+    tier = ts.tier + jnp.stack([
+        jnp.sum((valid & adm).astype(jnp.float32)),
+        jnp.sum((valid & ~adm).astype(jnp.float32))])
+    return mat, tier
+
+
+@pytest.mark.parametrize("table", ["customer", "terminal"])
+@pytest.mark.parametrize(
+    "missed", [0, 1, READ_K - 1, READ_K, READ_K + 1, READ_VALID],
+    ids=["none", "one", "K-1", "K", "K+1", "all"])
+def test_sketch_is_read_for_the_rows_that_missed_and_no_others(table,
+                                                               missed):
+    """``TablePlane.query`` under ``exact`` with ``missed`` of the 600
+    delivered rows forced off the hot tier: on every delivered row the
+    window sums, and the ``tier`` counts, are the whole-batch read's bit
+    for bit; the sketch was read in ⌈missed ÷ K⌉ chunks of K rows; the
+    40 padding rows are not misses (they cost no trip) and read 0.0."""
+    assert chunk_rows(READ_ROWS) == READ_K
+    plane, ts, slot, adm, key, day, valid = _updated_plane(table)
+    rows = np.flatnonzero(np.asarray(valid))
+    off = np.random.default_rng(missed).permutation(rows)[:missed]
+    adm = adm.at[jnp.asarray(off, jnp.int32)].set(False)
+    assert int((valid & ~adm).sum()) == missed
+    assert int((~adm).sum()) == missed + READ_ROWS - READ_VALID
+
+    want, want_tier = _whole_batch_read(plane, ts, slot, adm, key, day,
+                                        valid)
+    got_ts, got = jax.jit(plane.query)(ts, slot, adm, key, day, valid)
+    v = np.asarray(valid)
+    np.testing.assert_array_equal(np.asarray(got)[v], np.asarray(want)[v])
+    np.testing.assert_array_equal(np.asarray(got)[~v], 0.0)
+    np.testing.assert_array_equal(np.asarray(got_ts.tier),
+                                  np.asarray(want_tier))
+    assert float(got_ts.tier[1]) == missed
+    # a missed row's counts are the sketch's: never under the hot tier's
+    nw = len(plane.cfg.windows)
+    hot = _whole_batch_read(plane, ts, slot, valid, key, day, valid)[0]
+    assert (np.asarray(got)[off, :nw] >= np.asarray(hot)[off, :nw]).all()
+
+    columns = ("count", "amount" if table == "customer" else "fraud")
+    _, trips = jax.jit(
+        lambda sk, k, d, m: cms_query_where(
+            sk, columns, k, d, m, tuple(plane.cfg.windows),
+            0 if table == "customer" else plane.cfg.delay_days))(
+        ts.sketch, key, day, valid & ~adm)
+    assert int(trips) == -(-missed // READ_K)
+
+
+def test_a_dry_free_stack_is_served_in_chunks_like_the_whole_batch():
+    """The same rule through the directory itself: a hot tier of 16
+    slots under 500 keys misses most rows of every batch, and the
+    features of every delivered row are the whole-batch read's."""
+    cfg = _fcfg(customer_capacity=16, terminal_capacity=16,
+                key_mode="exact")
+    st = init_feature_state(cfg)
+    rng = np.random.default_rng(9)
+    served = 0
+    for _ in range(3):
+        b = _batch(rng, n=200, n_cust=500, n_term=500)
+        b = jax.tree.map(jnp.asarray, pad_batch(
+            jax.tree.map(np.asarray, b), 256))
+        want = []
+        for plane, key in ((TablePlane("customer", cfg), b.customer_key),
+                           (TablePlane("terminal", cfg), b.terminal_key)):
+            ts, slot, adm = plane.update(
+                plane.of(st), key, b.day, b.amount, fraud_of(b), b.valid)
+            want.append(_whole_batch_read(plane, ts, slot, adm, key, b.day,
+                                          b.valid))
+        st, feats, tier = jax.jit(
+            lambda s, x: update_and_featurize_exact(s, x, cfg))(st, b)
+        ref = assemble(b, cfg, want[0][0], want[1][0])
+        np.testing.assert_array_equal(np.asarray(feats)[:200],
+                                      np.asarray(ref)[:200])
+        assert np.isfinite(np.asarray(feats)).all()
+        np.testing.assert_array_equal(
+            np.asarray(tier), np.asarray(want[0][1] + want[1][1]))
+        served += float(tier[1])
+    assert served > 3 * 200  # most of both key spaces missed
 
 
 def test_compaction_reclaims_dead_slots_and_preserves_live():

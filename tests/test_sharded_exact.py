@@ -312,6 +312,43 @@ def test_sharded_exact_overflow_counts_per_shard_and_healthz():
     assert fs["tier_rows_per_shard"]["0"]["dense"] >= 0
 
 
+def test_sharded_exact_misses_are_read_in_chunks_like_the_whole_batch(
+        monkeypatch):
+    """Behind the exchange the plane reads the sketch only for the rows
+    that missed admission on their owner, a chunk at a time
+    (``ops/cms.cms_query_where``); what every delivered row gets is what
+    the whole-batch read, selected per row, gave it — the read as it
+    stood before PR 33, put in the loop's place for the comparison."""
+    from real_time_fraud_detection_system_tpu.features import online
+    from real_time_fraud_detection_system_tpu.ops import cms
+
+    def whole_batch(sk, columns, key, day, rows, windows, delay=0):
+        return cms._cms_query_tables(
+            sk, tuple(getattr(sk, c) for c in columns), key, day, windows,
+            delay), 0
+
+    params, scaler = _model()
+    rows, n_b = 256, 3
+    outs, tiers = [], []
+    for stand_in in (None, whole_batch):
+        if stand_in is not None:
+            monkeypatch.setattr(online, "cms_query_where", stand_in)
+        reg = MetricsRegistry()
+        eng = ShardedScoringEngine(
+            _cfg(cust_cap=64, term_cap=64, rows=rows), "logreg", params,
+            scaler, n_devices=N_DEV, metrics=reg)
+        res = [eng.process_batch(b) for b in _batches(
+            n_b, rows=rows, n_cust=5000, n_term=5000)]
+        outs.append((np.concatenate([r.probs for r in res]),
+                     np.concatenate([r.features for r in res])))
+        tiers.append([reg.get("rtfds_feature_tier_rows_total",
+                              tier=t).value for t in ("dense", "cms")])
+    assert tiers[0] == tiers[1] and tiers[0][1] > rows  # most rows missed
+    np.testing.assert_array_equal(outs[0][0], outs[1][0])
+    np.testing.assert_array_equal(outs[0][1], outs[1][1])
+    assert np.isfinite(outs[0][1]).all()
+
+
 def test_sharded_exact_compaction_reclaims_on_every_shard():
     """A DRIFTING working set (disjoint key range per batch) with the
     day marching 10/batch past the 37-day horizon: the per-shard

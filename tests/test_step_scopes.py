@@ -55,6 +55,11 @@ from real_time_fraud_detection_system_tpu.utils.trace import (
     step_scope,
 )
 
+from test_tpu_compile import (  # noqa: E402 (pytest adds tests/ to path)
+    sketch_read_loops,
+    sketch_table_gathers,
+)
+
 PKG = "real_time_fraud_detection_system_tpu"
 TABLE = {"customer", "terminal"}
 # `relayout` is in the vocabulary and in no variant: the window columns
@@ -222,6 +227,46 @@ def test_exact_key_path_names_its_parts_under_their_table():
         p for p in named if p[:1] != ["compact"]][:3]
     kept = [_scopes(n) for n in _op_names(compact.compile().as_text())]
     assert any(p[:1] == ["compact"] for p in kept)
+
+
+def test_sketch_read_loop_is_all_under_cms_and_only_exact_has_one():
+    """``key_mode="exact"`` reads the sketch in a ``while`` of chunks, a
+    table: in the COMPILED step the loop, its condition, and every named
+    op of its body — the chunk's gathers, the per-row arithmetic, the
+    write-back — sit under ``<table>/rtfds.cms``, so ``step_cms_ms`` holds
+    the whole cost of the tier, and no gather from a sketch table stands
+    outside a loop. The steps that bypass the rule have no such loop:
+    ``customer_source="cms"`` reads the sketch for the whole batch, where
+    every row IS served from it, and the ``direct`` steps name no
+    ``rtfds.cms`` op at all."""
+    def compiled(variant):
+        eng = _engine(variant)
+        low, *_ = _lowered_steps(eng)  # sharded: the local chunk's step
+        text = low.compile().as_text()
+        f = eng.cfg.features
+        return text, sketch_read_loops(text), sketch_table_gathers(
+            text, (f.n_day_buckets, f.cms_depth, f.cms_width))
+
+    text, loops, gathers = compiled("exact")
+    assert sorted(_scopes(op)[0] for op, _ in loops) == sorted(TABLE)
+    for op, inside in loops:
+        assert _scopes(op)[-1] == "cms", op
+        named = [n for c in inside for n in _op_names(c)
+                 if n.startswith("jit(")]  # the rest: reducers' bodies
+        assert any(n.endswith("/gather") for n in named)
+        assert any(n.endswith("/scatter") for n in named)
+        off = [n for n in named if f"rtfds.{_scopes(op)[0]}/rtfds.cms/"
+               not in n]
+        assert not off, off[:3]
+    # count + amount, count + fraud: [depth 4, a chunk's rows (half of
+    # this toy bucket's 64), 30 days] elements each
+    assert gathers == [(True, 4 * 32 * 30)] * 4, gathers
+    text, loops, gathers = compiled("cms")
+    assert not loops and "rtfds.cms" in text
+    assert gathers == [(False, 4 * 64 * 30)] * 2, gathers
+    for variant in ("forest", "logreg", "sharded"):
+        text, loops, gathers = compiled(variant)
+        assert not loops and not gathers and "rtfds.cms" not in text
 
 
 def test_unknown_scope_is_refused():

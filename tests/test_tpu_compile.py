@@ -220,6 +220,60 @@ def _computations(hlo_text):
                 re.S | re.M)}
 
 
+def _reached_from(bodies, roots):
+    """The bodies of the computations ``roots`` call, theirs too
+    (fusions, reducers, nested loops)."""
+    seen, todo = {}, list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in bodies:
+            continue
+        seen[name] = bodies[name]
+        todo += re.findall(
+            r"(?:calls|to_apply|condition|body)=%([\w.\-]+)", bodies[name])
+    return seen
+
+
+_WHILE = re.compile(r" while\(.*condition=%([\w.\-]+), body=%([\w.\-]+)"
+                    r'.*op_name="([^"]*rtfds\.cms[^"]*)"')
+
+
+def _sketch_read_loops(hlo_text, bodies):
+    """``(op_name, {computation it runs: body})`` of every ``while`` named
+    under ``rtfds.cms``."""
+    return [(m.group(3), _reached_from(bodies, m.group(1, 2)))
+            for m in map(_WHILE.search, hlo_text.splitlines()) if m]
+
+
+def sketch_read_loops(hlo_text):
+    """``(op_name, [bodies of the computations it runs])`` of every
+    ``while`` of a compiled step named under ``rtfds.cms``: the sketch
+    tier's chunked read, one a table."""
+    return [(op, list(inside.values())) for op, inside in
+            _sketch_read_loops(hlo_text, _computations(hlo_text))]
+
+
+def sketch_table_gathers(hlo_text, table_dims):
+    """``(inside a sketch read loop, elements read)`` of every ``gather``
+    of a compiled step whose operand is a whole sketch table
+    (``[days, depth, width]``, whatever type the compiler reads it
+    as)."""
+    bodies = _computations(hlo_text)
+    in_loops = {name for _, inside in _sketch_read_loops(hlo_text, bodies)
+                for name in inside}
+    dims = ",".join(map(str, table_dims))
+    out = []
+    for name, body in bodies.items():
+        shape = {m.group(1): m.group(2) for m in re.finditer(
+            r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]", body, re.M)}
+        for m in re.finditer(
+                r"= \w+\[([\d,]*)\]\S* gather\(%([\w.\-]+),", body):
+            if shape.get(m.group(2)) == dims:
+                out.append((name in in_loops, int(np.prod(
+                    [int(d) for d in m.group(1).split(",")]))))
+    return out
+
+
 def column_passes(hlo_text, n):
     """``(name, op, bytes_read, bytes_written, operands)``, bytes per
     element, of every instruction of the entry computation that streams
@@ -390,17 +444,15 @@ def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
     assert 0.2 * 1.9e9 < per_device < 0.3 * 2.1e9  # ~a quarter of the state
 
 
-@pytest.mark.parametrize("variant", ["step", "compact"])
-def test_exact_key_programs_fit_the_chip_at_the_benchmarks_size(
-        topo, one_chip, as_on_chip, variant):
+EXACT_ROWS = 65536
+
+
+def _compiled_exact(cache, one_chip, variant, rows=EXACT_ROWS):
     """``key_mode="exact"`` at the size of the benchmark's
     ``forest-rf100-d8-exact`` (2^22 + 2^23 slots, directories of twice
-    that, 16 probes): the 65,536-row step and the ``("compact",)`` program
-    compile for one v5e with their state donated and room to spare. The
-    compaction is the one the CPU cannot vouch for: written with a lane
-    per directory entry into ``set_rows``, its ``[2^24, 40]`` element
-    indices alone made the chip's compiler refuse it — 18.41 GB of 15.75
-    (PERF.md, PR 32) — while every CPU test passed."""
+    that, 16 probes, sketches at their defaults): the ``rows``-row step
+    or the ``("compact",)`` program of the engine itself, compiled for
+    one v5e → (features config, compiled)."""
     from real_time_fraud_detection_system_tpu.config import (
         Config,
         FeatureConfig,
@@ -412,20 +464,64 @@ def test_exact_key_programs_fit_the_chip_at_the_benchmarks_size(
     fcfg = FeatureConfig(customer_capacity=1 << 22,
                          terminal_capacity=1 << 23, key_mode="exact",
                          keydir_probes=16, compact_every=64)
-    eng = ScoringEngine(
-        Config(features=fcfg, runtime=RuntimeConfig(
-            z_mode="int8", batch_buckets=(65536,), max_batch_rows=65536)),
-        kind="forest", params=_forest(),
-        scaler=Scaler(mean=np.zeros(N_FEAT, np.float32),
-                      scale=np.ones(N_FEAT, np.float32)),
-        feature_state=_on(one_chip, _state_shapes(fcfg)))
-    (sig,) = [s for s in eng.dispatch_inventory() if s.variant == variant]
-    mem = eng.signature_step(sig).lower(
-        *_on(one_chip, eng.signature_templates(sig))).compile(
-        ).memory_analysis()
+    if ("exact", variant, rows) not in cache:
+        eng = ScoringEngine(
+            Config(features=fcfg, runtime=RuntimeConfig(
+                z_mode="int8", batch_buckets=(rows,), max_batch_rows=rows)),
+            kind="forest", params=_forest(),
+            scaler=Scaler(mean=np.zeros(N_FEAT, np.float32),
+                          scale=np.ones(N_FEAT, np.float32)),
+            feature_state=_on(one_chip, _state_shapes(fcfg)))
+        (sig,) = [s for s in eng.dispatch_inventory()
+                  if s.variant == variant]
+        cache["exact", variant, rows] = eng.signature_step(sig).lower(
+            *_on(one_chip, eng.signature_templates(sig))).compile()
+    return fcfg, cache["exact", variant, rows]
+
+
+@pytest.mark.parametrize("variant", ["step", "compact"])
+def test_exact_key_programs_fit_the_chip_at_the_benchmarks_size(
+        topo, one_chip, as_on_chip, compiled_steps, variant):
+    """The exact step and the compaction compile for one v5e with their
+    state donated and room to spare. The compaction is the one the CPU
+    cannot vouch for: written with a lane per directory entry into
+    ``set_rows``, its ``[2^24, 40]`` element indices alone made the
+    chip's compiler refuse it — 18.41 GB of 15.75 (PERF.md, PR 32) —
+    while every CPU test passed."""
+    _, compiled = _compiled_exact(compiled_steps, one_chip, variant)
+    mem = compiled.memory_analysis()
     state = 8_409_579_848  # features/online.state_bytes
     assert mem.argument_size_in_bytes >= state
     assert mem.alias_size_in_bytes >= state  # donated, updated in place
     # the chip has 15.75 GB for a program; the direct step's temporaries
     # are 1.7 GB, the compaction's one padded [2^23, 40] view 4.4
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+
+
+@pytest.mark.parametrize("rows", [256, EXACT_ROWS])
+def test_exact_step_reads_the_sketch_a_chunk_at_a_time_inside_its_loops(
+        topo, one_chip, as_on_chip, compiled_steps, rows):
+    """What the chip's compiler made of the sketch tier's read rule
+    (``ops/cms.cms_query_where``): no gather from a sketch table stands
+    outside a ``while`` named under ``rtfds.cms`` — a batch in which no
+    row missed admission runs no trip and reads no sketch — and the
+    gathers inside read ``[depth, K, 30 days]`` elements, a chunk's, not
+    the batch's ``[depth, 65,536, 30]`` that made four 99 ms operations
+    of a 567 ms step (PERF.md, PR 32). One loop a table, two columns
+    each: the terminal side asks for count and fraud and gets no amount
+    gather for the compiler to find dead. At the smallest bucket too: a
+    chunk that were the whole batch would be a loop-invariant read,
+    which the compiler hoists out of the loop for every batch to pay."""
+    from real_time_fraud_detection_system_tpu.ops.cms import chunk_rows
+
+    fcfg, compiled = _compiled_exact(compiled_steps, one_chip, "step", rows)
+    text = compiled.as_text()
+    loops = sketch_read_loops(text)
+    assert sorted(op.split("/")[1] for op, _ in loops) == [
+        "rtfds.customer", "rtfds.terminal"], [op for op, _ in loops]
+    gathers = sketch_table_gathers(
+        text, (fcfg.n_day_buckets, fcfg.cms_depth, fcfg.cms_width))
+    k = chunk_rows(rows)
+    assert k == {256: 128, EXACT_ROWS: 256}[rows]
+    assert gathers == [(True, fcfg.cms_depth * k * max(fcfg.windows))] * 4, \
+        gathers
