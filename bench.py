@@ -1312,7 +1312,7 @@ def _measure(args) -> dict:
     # ---- tiered feature-store scale curve (detail.state_scale) ----------
     # ROADMAP item 2's proof shape, extended to the host cold tier: key
     # universe 64k → 10M two-tier, then 100M with features.cold_store
-    # (demote-don't-discard + async promote) × Zipf skew
+    # (demote-don't-discard + promote-before-score) × Zipf skew
     # with a BOUNDED hot tier (key_mode="exact") — loop rows/s must stay
     # flat (the state never grows past the working set), per-tier state
     # bytes must hold under --state-hbm-budget-mb (validated at engine
@@ -1567,20 +1567,26 @@ def _state_scale_block(args, on_cpu: bool) -> dict:
         }
         last_engine = eng
     # ---- 100M-key cold-tier cell ------------------------------------
-    # The third tier's proof: same bounded 2×32k-slot hot tier, 10× the
-    # 10M directory sweep — compaction DEMOTES evicted keys' exact rows
-    # to host segments (features.cold_store) instead of discarding them,
-    # and returning keys promote back asynchronously. rows/s must stay
-    # within 15% of the 64k baseline, HBM stays the same static
-    # state_bytes() (the cold tier is host memory/disk), and the
-    # demotion/promotion counters + exactness_degraded_keys scope the
-    # bit-identity claim honestly.
+    # The third tier: a bounded hot tier, 10× the 10M directory sweep —
+    # compaction DEMOTES evicted keys' exact rows to host segments
+    # (features.cold_store) instead of discarding them, and a returning
+    # key is promoted back BEFORE the step that scores its row. The tier
+    # is sized by its rule (README, Cold tier): the slots hold eight
+    # batches' keys, half of them are kept free (cold_highwater 0.5) and
+    # a pass can demote what the four batches between two passes admit —
+    # so every key is exact (dense_hit_rate 1.0, exactness_degraded_keys
+    # 0) and a promote lane always finds a slot. The hot tier is the
+    # sweep's 2×32k slots at the quick size; HBM stays the static
+    # state_bytes() (the cold tier is host memory/disk). A CPU figure.
     n_cold = 100_000_000
     _progress(f"state scale universe {n_cold} (cold tier)")
     with tempfile.TemporaryDirectory() as td_cold:
-        cold_fcfg = _dc.replace(fcfg, cold_store=td_cold,
-                                cold_demote_slots=1024,
-                                cold_promote_queue=256)
+        cold_slots = max(fcfg.customer_capacity, 8 * rows)
+        cold_fcfg = _dc.replace(
+            fcfg, cold_store=td_cold, keydir_probes=16,
+            customer_capacity=cold_slots, terminal_capacity=cold_slots,
+            cold_highwater=0.5, cold_demote_slots=4 * rows,
+            state_hbm_budget_mb=0.0)
         cold_cfg = cfg.replace(features=cold_fcfg)
         sampler = ZipfKeySampler(n_cold, skew)
         reg = MetricsRegistry()
@@ -1590,7 +1596,6 @@ def _state_scale_block(args, on_cpu: bool) -> dict:
         eng.run(_ZipfSource(2, rows, sampler, day_every=1, seed=7))
         stats = eng.run(_ZipfSource(n_batches, rows, sampler,
                                     day_every=max(n_batches // 6, 1)))
-        eng.drain_promotions()
         dense = reg.get("rtfds_feature_tier_rows_total", tier="dense")
         cms = reg.get("rtfds_feature_tier_rows_total", tier="cms")
         d = dense.value if dense is not None else 0.0
@@ -1617,8 +1622,6 @@ def _state_scale_block(args, on_cpu: bool) -> dict:
                     _mval("rtfds_feature_cold_demotions_total")),
                 "promotions": int(
                     _mval("rtfds_feature_cold_promotions_total")),
-                "promote_wait_s": round(_mval(
-                    "rtfds_feature_cold_promote_wait_seconds_total"), 3),
             },
         }
         out["flat_100m_within_15pct"] = (

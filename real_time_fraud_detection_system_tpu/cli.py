@@ -679,7 +679,6 @@ def cmd_score(args) -> int:
             compact_every=args.state_compact_every,
             state_hbm_budget_mb=args.state_hbm_budget_mb,
             cold_store=args.cold_store,
-            cold_promote_queue=args.cold_promote_queue,
             cold_segment_mb=args.cold_segment_mb,
         ))
     except ValueError as e:
@@ -722,11 +721,10 @@ def cmd_score(args) -> int:
             if args.state_hbm_budget_mb > 0 else "")
         if cfg.features.cold_store:
             log.info(
-                "host cold tier: %s (segment %.1f MB, promote queue %d) "
-                "— evicted keys demote with exact rows and promote back "
-                "asynchronously on return",
-                cfg.features.cold_store, cfg.features.cold_segment_mb,
-                cfg.features.cold_promote_queue)
+                "host cold tier: %s (segment %.1f MB) — evicted keys "
+                "demote with exact rows and are promoted back before "
+                "the step that scores their next row",
+                cfg.features.cold_store, cfg.features.cold_segment_mb)
     cfg = cfg.replace(learn=_dc.replace(
         cfg.learn,
         registry_path=args.learn_registry,
@@ -2512,17 +2510,13 @@ def main(argv=None) -> int:
                    help="host cold tier for --key-mode exact: directory "
                         "or s3:// url where compaction demotes evicted "
                         "keys' exact window rows instead of discarding "
-                        "them; returning keys promote back "
-                        "asynchronously (README 'Feature-state playbook' "
-                        "§ Cold tier). Requires --state-compact-every. "
+                        "them; a returning key is promoted back before "
+                        "the step that scores its row (README "
+                        "'Feature-state playbook' § Cold tier). "
+                        "tmp://[name] = a fresh store under the system's "
+                        "temporary directory, gone with the process (no "
+                        "checkpoints). Requires --state-compact-every. "
                         "Empty = off (evictions degrade to the sketch)")
-    p.add_argument("--cold-promote-queue", type=int, default=64,
-                   help="bounded depth of the async promoter's request "
-                        "queue; a full queue drops the request and the "
-                        "key re-enqueues on its next touch "
-                        "(rtfds_feature_cold_promote_backlog vs the "
-                        "_queue_limit gauge is the overload ladder's "
-                        "cold_promote pressure input)")
     p.add_argument("--cold-segment-mb", type=float, default=4.0,
                    help="cold-store flush threshold: buffered demotions "
                         "become one durable segment (blob + CRC'd "

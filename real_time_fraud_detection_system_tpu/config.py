@@ -104,26 +104,42 @@ class FeatureConfig:
     state_hbm_budget_mb: float = 0.0
     # Host cold tier for key_mode="exact": compaction DEMOTES pressure-
     # evicted keys' exact window rows to an append+compact keyed store on
-    # the host (io/coldstore.py) instead of discarding them; a returning
-    # key is detected host-side against the cold index and its rows are
-    # PROMOTED back into the hot tier asynchronously between device steps
-    # (a ("promote",) dispatch signature — zero mid-stream recompiles).
+    # the host (io/coldstore.py) instead of discarding them. EXACT PER
+    # ROW: a returning key is detected in host prep against the store's
+    # index (the host wrote it) and its rows are PROMOTED back into the
+    # hot tier by a ("promote", table, width) program dispatched before
+    # the step that scores its row — width from a ladder of precompiled
+    # lane counts (engine.promote_widths: the largest batch bucket, at
+    # most 16,384, and its quarters down to 256; more keys than that go
+    # in several payloads), so zero mid-stream recompiles. A pass's
+    # payload is landed by the loop thread before _maybe_compact
+    # returns (readable at once; the segment write follows on the
+    # store's writer thread). Resident on the host, with no eviction:
+    # the rows of every key in the store, 4 + 16 x n_day_buckets bytes
+    # each — the tier reaches as far as host memory does.
     # Empty string disables the tier (evictions discard, PR 13 behavior).
-    # Accepts a local directory or an s3:// URL (flaky-store retries and
-    # CRC verification inherited from the checkpoint backends).
+    # Accepts a local directory, an s3:// URL (flaky-store retries and
+    # CRC verification inherited from the checkpoint backends), or
+    # tmp://[name]: a fresh directory under the system's temporary
+    # directory, private to this process and removed when it ends (runs
+    # without checkpoints: a benchmark, a replay).
     cold_store: str = ""
-    # Bounded promoter request queue (keys awaiting a host cold-store
-    # read); a full queue drops the request and the key is re-enqueued
-    # on its next touch — backpressure, never unbounded growth.
-    cold_promote_queue: int = 64
     # Cold segment flush threshold (MB of buffered demoted rows before a
     # segment blob + manifest is written). Checkpoints always flush.
     cold_segment_mb: float = 4.0
-    # Max keys demoted per table per compaction pass (the static top-k
-    # width of the eviction scan — one compiled shape).
+    # Max keys demoted per table per compaction pass (the width of the
+    # demotion payload — one compiled shape). Sizing: cold_demote_slots
+    # / compact_every is the demotion capacity a batch, and it has to
+    # exceed the keys a batch admits (new keys + promotions) or the hot
+    # tier fills and a promote lane finds no slot (ColdPromoteError).
     cold_demote_slots: int = 1024
     # Hot-tier occupancy target: compaction demotes oldest-first down to
-    # ceil(highwater * slot_capacity) occupied slots per table.
+    # ceil(highwater * slot_capacity) occupied slots per table. Tied to
+    # keydir_probes: the directory has 2 x slots entries, so its load is
+    # half the occupancy, and a key whose P probe positions are all
+    # taken is served from the sketch for good — expected keys lost over
+    # a fill to load a of D entries: D a^(P+1)/(P+1). 0.5 with P = 16 is
+    # exact in practice (3e-5 keys at 2^23 entries); 0.75 needs P >= 16.
     cold_highwater: float = 0.75
     # Count-min sketch for unbounded key cardinality (velocity features).
     cms_depth: int = 4
@@ -182,10 +198,6 @@ class FeatureConfig:
             raise ValueError(
                 f"state_hbm_budget_mb must be >= 0 (0 = unchecked), "
                 f"got {self.state_hbm_budget_mb}")
-        if self.cold_promote_queue < 1:
-            raise ValueError(
-                f"cold_promote_queue must be >= 1 (the promoter queue is "
-                f"bounded), got {self.cold_promote_queue}")
         if self.cold_segment_mb <= 0:
             raise ValueError(
                 f"cold_segment_mb must be > 0, got {self.cold_segment_mb}")

@@ -546,55 +546,63 @@ def compact_feature_state(
     ``EMPTY_KEY``/empty rows. The host appends the payload to
     ``io/coldstore.py`` — demote, don't discard.
     """
+    # Every op below sits under rtfds.compact — but for the demote pass's
+    # selection and payload gather (rtfds.demote, in _demote_oldest), a
+    # SIBLING scope and not a part: the stage metrics that read the two
+    # then add up to the program, and no op counts twice.
+    demote = int(demote_slots)
     with step_scope("compact"):
         horizon = jnp.int32(cfg.delay_days + max(cfg.windows))
         cutoff = now_day.astype(jnp.int32) - horizon
         now = now_day.astype(jnp.int32)
-        demote = int(demote_slots)
-        out = {}
-        counts = []
-        payload = {}
-        for dir_name, ws_name in (("customer_dir", "customer"),
-                                  ("terminal_dir", "terminal")):
-            kd = getattr(state, dir_name)
-            ws = getattr(state, ws_name)
-            if kd is None:
-                out[dir_name], out[ws_name] = kd, ws
-                counts.append(jnp.int32(0))
-                payload[ws_name] = None
-                continue
+    out = {}
+    counts = []
+    payload = {}
+    for dir_name, ws_name in (("customer_dir", "customer"),
+                              ("terminal_dir", "terminal")):
+        kd = getattr(state, dir_name)
+        ws = getattr(state, ws_name)
+        if kd is None:
+            out[dir_name], out[ws_name] = kd, ws
+            counts.append(jnp.int32(0))
+            payload[ws_name] = None
+            continue
+        with step_scope("compact"):
             newest = jnp.max(ws.tables()[0], axis=1)  # [slot_cap]
             slot_idx = jnp.clip(kd.slots, 0, ws.capacity - 1)
             live = kd.slots >= 0
             newest_e = newest[slot_idx]
             dead_entry = live & (newest_e < cutoff)
-            if demote > 0:
-                # Pressure eviction EXTENDS the dead mask (payload gathered
-                # before any vacate), so the demote variant pays ONE
-                # combined reclaim + window-table sweep — not a second
-                # full-table pass on top of the dead reclaim.
-                kd, ws, n, pay = _demote_oldest(
-                    kd, ws, dead_entry, newest_e, live, now,
-                    int(cfg.delay_days + max(cfg.windows)), demote,
-                    cfg.cold_highwater)
-                payload[ws_name] = pay
-            else:
+        if demote > 0:
+            # Pressure eviction EXTENDS the dead mask (payload gathered
+            # before any vacate), so the demote variant pays ONE
+            # combined reclaim + window-table sweep — not a second
+            # full-table pass on top of the dead reclaim.
+            kd, ws, n, pay = _demote_oldest(
+                kd, ws, dead_entry, newest_e, live, now,
+                int(cfg.delay_days + max(cfg.windows)), demote,
+                cfg.cold_highwater)
+            payload[ws_name] = pay
+        else:
+            with step_scope("compact"):
                 old_slots = kd.slots  # pre-clear ids (reclaim vacates them)
                 kd, dead, n = reclaim_entries(kd, dead_entry)
                 tgt = jnp.where(dead, old_slots, ws.capacity)
                 ws = ws.clear_slots(tgt)
-                payload[ws_name] = None
-            out[dir_name] = kd
-            out[ws_name] = ws
-            counts.append(n)
-        new_state = state._replace(
-            customer=out["customer"], terminal=out["terminal"],
-            customer_dir=out["customer_dir"],
-            terminal_dir=out["terminal_dir"],
-        )
-        if demote > 0:
-            return new_state, jnp.stack(counts), payload
-        return new_state, jnp.stack(counts)
+            payload[ws_name] = None
+        out[dir_name] = kd
+        out[ws_name] = ws
+        counts.append(n)
+    new_state = state._replace(
+        customer=out["customer"], terminal=out["terminal"],
+        customer_dir=out["customer_dir"],
+        terminal_dir=out["terminal_dir"],
+    )
+    with step_scope("compact"):
+        reclaimed = jnp.stack(counts)
+    if demote > 0:
+        return new_state, reclaimed, payload
+    return new_state, reclaimed
 
 
 def _demote_oldest(
@@ -627,57 +635,74 @@ def _demote_oldest(
     threshold age and a cumsum rank breaks the tie at the threshold by
     lowest index — the exact set ``lax.top_k`` would pick (its ties
     also resolve to the lowest index), at O(n) scatter cost instead of
-    an O(n log k) sort over the whole directory. Returns
+    an O(n log k) sort over the whole directory. Selection and payload
+    gather run under ``rtfds.demote``, the vacate and the table sweep
+    under ``rtfds.compact``: siblings, so what reads the one never
+    counts the other. Returns
     ``(kd, ws, n_reclaimed_total, (keys, bd, cnt, amt, frd))``.
     """
-    slot_cap = int(ws.capacity)
-    dir_cap = int(kd.keys.shape[0])
-    k = min(int(demote_slots), dir_cap)
-    hzn = max(int(horizon), 1)
-    n_dead = jnp.sum((dead_entry & live).astype(jnp.int32))
-    occ = (jnp.int32(kd.free.shape[0]) - kd.free_top.astype(jnp.int32)
-           - n_dead)
-    target = jnp.int32(int(highwater * slot_cap))
-    n_evict = jnp.clip(occ - target, 0, k)
-    eligible = live & ~dead_entry & (newest_e < now_day)
-    # Age histogram over [1, hzn] (bucket 0 holds the ineligible mass
-    # and is never selectable; eligible entries have age >= 1 because
-    # newest_e < now_day, and age <= hzn because older is dead).
-    age = jnp.clip(jnp.where(eligible, now_day - newest_e, 0),
-                   0, hzn).astype(jnp.int32)
-    hist = jnp.zeros((hzn + 3,), jnp.int32).at[age].add(1)
-    incl = jnp.cumsum(hist[::-1])[::-1]  # incl[a] = #entries age >= a
-    # Threshold t* = max age with incl >= n_evict (monotone, so a count
-    # of satisfied ages IS the argmax); floor 1 covers the
-    # n_evict > #eligible case, where every eligible entry is taken.
-    thresh = jnp.maximum(
-        jnp.sum((incl >= n_evict)[1:hzn + 2].astype(jnp.int32)),
-        jnp.int32(1))
-    quota = n_evict - incl[thresh + 1]  # lanes left for age == t*
-    at_t = age == thresh
-    rank_t = jnp.cumsum(at_t.astype(jnp.int32)) - 1
-    sel = (age > thresh) | (at_t & (rank_t < quota))
-    # Pack selected entry indices into the fixed k payload lanes in
-    # index order (payload lane order is semantically irrelevant — the
-    # cold store treats rows independently).
-    lane = jnp.where(sel, jnp.cumsum(sel.astype(jnp.int32)) - 1, k)
-    eidx = jnp.full((k,), dir_cap, jnp.int32).at[lane].set(
-        jnp.arange(dir_cap, dtype=jnp.int32), mode="drop")
-    lane_live = (jnp.arange(k, dtype=jnp.int32)
-                 < jnp.sum(sel.astype(jnp.int32)))
-    eidx_c = jnp.clip(eidx, 0, dir_cap - 1)
-    # Gather the payload BEFORE vacating: keys + full window rows.
-    keys = jnp.where(lane_live, kd.keys[eidx_c], jnp.uint32(EMPTY_KEY))
-    slot_g = jnp.clip(kd.slots[eidx_c], 0, slot_cap - 1)
-    m = lane_live[:, None]
-    bd, cnt, amt, frd = (
-        jnp.where(m, row, fill)
-        for row, fill in zip(ws.rows(slot_g), (jnp.int32(-1), 0.0, 0.0, 0.0)))
+    with step_scope("demote"):
+        slot_cap = int(ws.capacity)
+        dir_cap = int(kd.keys.shape[0])
+        k = min(int(demote_slots), dir_cap)
+        hzn = max(int(horizon), 1)
+        n_dead = jnp.sum((dead_entry & live).astype(jnp.int32))
+        occ = (jnp.int32(kd.free.shape[0]) - kd.free_top.astype(jnp.int32)
+               - n_dead)
+        target = jnp.int32(int(highwater * slot_cap))
+        n_evict = jnp.clip(occ - target, 0, k)
+        eligible = live & ~dead_entry & (newest_e < now_day)
+        # Age histogram over [1, hzn] (bucket 0 holds the ineligible mass
+        # and is never selectable; eligible entries have age >= 1 because
+        # newest_e < now_day, and age <= hzn because older is dead).
+        age = jnp.clip(jnp.where(eligible, now_day - newest_e, 0),
+                       0, hzn).astype(jnp.int32)
+        # one compare-and-count an age, not a scatter-add of every
+        # directory entry into ~40 bins (the chip serialises those)
+        hist = jnp.sum(
+            age[None, :] == jnp.arange(hzn + 3, dtype=jnp.int32)[:, None],
+            axis=1, dtype=jnp.int32)
+        incl = jnp.cumsum(hist[::-1])[::-1]  # incl[a] = #entries age >= a
+        # Threshold t* = max age with incl >= n_evict (monotone, so a count
+        # of satisfied ages IS the argmax); floor 1 covers the
+        # n_evict > #eligible case, where every eligible entry is taken.
+        thresh = jnp.maximum(
+            jnp.sum((incl >= n_evict)[1:hzn + 2].astype(jnp.int32)),
+            jnp.int32(1))
+        quota = n_evict - incl[thresh + 1]  # lanes left for age == t*
+        at_t = age == thresh
+        rank_t = jnp.cumsum(at_t.astype(jnp.int32)) - 1
+        sel = (age > thresh) | (at_t & (rank_t < quota))
+        # Pack selected entry indices into the fixed k payload lanes in
+        # index order (payload lane order is semantically irrelevant — the
+        # cold store treats rows independently): lane j holds the
+        # (j+1)-th selected entry, found by k binary searches in the
+        # running count — not by a scatter of every directory entry.
+        taken = jnp.cumsum(sel.astype(jnp.int32))
+        eidx = jnp.searchsorted(
+            taken, jnp.arange(1, k + 1, dtype=jnp.int32),
+            side="left").astype(jnp.int32)  # dir_cap past the last one
+        lane_live = jnp.arange(k, dtype=jnp.int32) < taken[-1]
+        eidx_c = jnp.clip(eidx, 0, dir_cap - 1)
+        # Gather the payload BEFORE vacating: keys + full window rows.
+        # Lanes go out in KEY order (one sort of k keys; EMPTY_KEY, the
+        # largest u32, keeps the padding last): the store's index is
+        # sorted by key, so the host lands such a payload as it stands,
+        # without gathering every row into order once more.
+        keys = jnp.where(lane_live, kd.keys[eidx_c], jnp.uint32(EMPTY_KEY))
+        by_key = jnp.argsort(keys)
+        keys, eidx_c = keys[by_key], eidx_c[by_key]
+        slot_g = jnp.clip(kd.slots[eidx_c], 0, slot_cap - 1)
+        m = lane_live[:, None]
+        bd, cnt, amt, frd = (
+            jnp.where(m, row, fill)
+            for row, fill in zip(ws.rows(slot_g), (jnp.int32(-1), 0.0, 0.0, 0.0)))
     # One combined vacate: dead history + demoted entries.
-    old_slots = kd.slots
-    kd, dead, n = reclaim_entries(kd, dead_entry | sel)
-    tgt = jnp.where(dead, old_slots, slot_cap)
-    ws = ws.clear_slots(tgt)
+    with step_scope("compact"):
+        old_slots = kd.slots
+        kd, dead, n = reclaim_entries(kd, dead_entry | sel)
+        tgt = jnp.where(dead, old_slots, slot_cap)
+        ws = ws.clear_slots(tgt)
     return kd, ws, n, (keys, bd, cnt, amt, frd)
 
 
@@ -686,8 +711,8 @@ def promote_rows(
     payload: dict,  # {"customer": (keys, bd, cnt, amt, frd)|None, ...}
     cfg: FeatureConfig,
 ) -> Tuple[FeatureState, jnp.ndarray]:
-    """Async promotion landing: merge cold-store rows back into the hot
-    tier between device steps.
+    """Promotion: merge cold-store rows back into the hot tier, ahead of
+    the step that scores the rows of the keys they belong to.
 
     Per table: ``admit_slots`` grants (or finds) a slot for every
     non-``EMPTY_KEY`` payload lane, then a per-bucket DAY-DOMINANCE
@@ -700,8 +725,9 @@ def promote_rows(
     post-return writes land on days >= the return day, so cold and hot
     buckets never contend for the same day. Returns ``(state,
     stats [2, 2] int32)`` = per-table ``[admitted, dropped]`` (dropped:
-    the free list ran dry — the host re-enqueues on the key's next
-    touch). The caller guarantees unique keys per dispatch.
+    the free list ran dry or every probe position was taken — the engine
+    stops the run before that batch is delivered). The caller guarantees
+    unique keys per dispatch.
     """
     out = {}
     stats = []
@@ -715,27 +741,33 @@ def promote_rows(
             stats.append(jnp.zeros((2,), jnp.int32))
             continue
         keys, bd, cnt, amt, frd = pay
-        valid = keys != jnp.uint32(EMPTY_KEY)
+        # the admit carries rtfds.keydir and its parts, as in the step;
+        # everything else of the program carries rtfds.promote — siblings
+        with step_scope("promote"):
+            valid = keys != jnp.uint32(EMPTY_KEY)
         kd, slot, adm = admit_slots(kd, keys, valid,
                                     n_probes=cfg.keydir_probes)
-        slot_c = jnp.clip(slot, 0, ws.capacity - 1)
-        hot = ws.rows(slot_c)
-        take = adm[:, None] & (bd > hot[0])
-        tgt = jnp.where(adm, slot, ws.capacity)
-        out[dir_name] = kd
-        out[ws_name] = ws.set_rows(tgt, *(
-            jnp.where(take, cold, row)
-            for cold, row in zip((bd, cnt, amt, frd), hot)))
-        adm_n = jnp.sum(adm.astype(jnp.int32))
-        drop_n = jnp.sum((valid & ~adm).astype(jnp.int32))
-        stats.append(jnp.stack([adm_n, drop_n]))
+        with step_scope("promote"):
+            slot_c = jnp.clip(slot, 0, ws.capacity - 1)
+            hot = ws.rows(slot_c)
+            take = adm[:, None] & (bd > hot[0])
+            tgt = jnp.where(adm, slot, ws.capacity)
+            out[dir_name] = kd
+            out[ws_name] = ws.set_rows(tgt, *(
+                jnp.where(take, cold, row)
+                for cold, row in zip((bd, cnt, amt, frd), hot)))
+            adm_n = jnp.sum(adm.astype(jnp.int32))
+            drop_n = jnp.sum((valid & ~adm).astype(jnp.int32))
+            stats.append(jnp.stack([adm_n, drop_n]))
+    with step_scope("promote"):
+        stats = jnp.stack(stats)
     return (
         state._replace(
             customer=out["customer"], terminal=out["terminal"],
             customer_dir=out["customer_dir"],
             terminal_dir=out["terminal_dir"],
         ),
-        jnp.stack(stats),
+        stats,
     )
 
 

@@ -2,10 +2,15 @@
 
 PR 13/14's recency compaction reclaimed hot-tier slots by *discarding*
 the evicted keys' window rows — a returning key degraded to the CMS
-sketch forever. This module is the third tier ROADMAP item 3 names: an
-append+compact keyed store on the host (local directory or object
-store), fed by the compaction pass's demotion payload and drained by an
-asynchronous promoter thread, so the device never stalls on a cold key.
+sketch forever. This module is the third tier: an append+compact keyed
+store on the host (local directory or object store), fed by the
+compaction pass's demotion payload and read by the engine BEFORE the
+step that scores a returning key's row (``runtime/engine.py::
+_promote_returning``): exact per row, not eventually.
+
+Everything follows the batch, not the key: the index is a pair of sorted
+arrays per table, an append lands a payload with fancy-indexed copies,
+a read gathers a batch's rows segment by segment. No per-key Python.
 
 Layout: demoted rows buffer in memory and flush as immutable
 **segments** — a blob ``seg-<seq>.npz`` (per-table ``{t}_keys`` u32 [n],
@@ -17,27 +22,31 @@ Because the manifest embeds the key list, :meth:`ColdStore.open` (and
 no blob reads. A crash between blob and manifest leaves an orphan blob
 (deleted on open); a torn manifest is quarantined, never trusted.
 
+**Resident on the host:** every segment this process wrote or has read
+stays in memory, as the arrays it was written from, until its last live
+key is promoted (then the arrays go; the file waits for :meth:`gc`). At
+most ``cold keys x (4 + 16 x n_day_buckets)`` bytes plus the rows
+promoted since their segment's last live key left. A segment found on
+disk at open is read — and CRC-checked — on its first use.
+
 Contracts, in the order people get them wrong:
 
-- **Newest wins.** A key demoted twice resolves to its newest rows: the
-  in-memory index maps ``(table, key)`` to the latest location (a
-  buffered row or a ``(seq, row)`` in a flushed segment).
-- **Reads verify.** ``get_rows`` CRC-checks a segment blob before
-  serving from it (once per load; a small LRU keeps verified arrays
-  hot). A mismatch quarantines the segment, drops its index entries and
-  raises :class:`ColdStoreCorruptError` — the promoter counts it and
-  the affected keys degrade to CMS *honestly* instead of promoting
-  garbage into the exact tier.
+- **Newest wins.** A key demoted twice resolves to its newest rows.
+- **Reads verify.** A segment blob is CRC-checked when it is loaded from
+  the backend. A mismatch quarantines the segment, drops its index
+  entries and raises :class:`ColdStoreCorruptError` — the engine counts
+  the lost keys (``exactness_degraded_keys``) and they are served from
+  the sketch *honestly* instead of promoting garbage into the exact tier.
 - **Checkpoint lineage = live segments.** :meth:`lineage` lists only
   segments that still hold un-promoted keys; :meth:`sync_to` (restore)
   deletes everything else — segments written after the checkpoint are
   regenerated by replay (exactly-once), fully-promoted ones are covered
   by the checkpointed hot state.
-- **The promoter is fenced and bounded** (mirror of
-  ``runtime/prefetch.py``): bounded request queue (a full queue drops
-  the request; the key re-enqueues on its next touch), original-typed
-  error propagation to the loop thread, generation fencing across
-  restore, and per-key poison isolation for corrupt segments.
+- **A fresh store per process**: ``tmp://[name]`` (see
+  :func:`resolve_location`) is a directory made under the system's
+  temporary directory at construction and removed at :meth:`close` or at
+  interpreter exit. It cannot carry a checkpoint's lineage across a
+  restart, and ``engine.run`` refuses a checkpointer with it.
 """
 
 from __future__ import annotations
@@ -46,16 +55,20 @@ import io as _io
 import json
 import os
 import queue
+import shutil
+import tempfile
 import threading
-import time
+import weakref
 import zlib
-from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 SEG_FORMAT = 1
 _EMPTY_KEY = 0xFFFFFFFF
+TMP_SCHEME = "tmp://"
+_BUF = -1  # segment id of rows still in the flush buffer
+_ROW_DTYPES = (np.int32, np.float32, np.float32, np.float32)
 
 
 class ColdStoreCorruptError(Exception):
@@ -70,6 +83,76 @@ def _seg_manifest(seq: int) -> str:
     return f"seg-{seq:08d}.json"
 
 
+def resolve_location(path_or_url: str) -> Tuple[str, bool]:
+    """``--cold-store`` as given → ``(where the store lives, ephemeral)``.
+
+    ``tmp://[name]`` is the fresh-store form: a new directory
+    ``<tempfile.gettempdir()>/<name or rtfds-cold>-<random>`` (so under
+    ``TMPDIR``, beside a local sink's own temporary files), empty by
+    construction and private to this process. Every other value is a
+    durable location, used as it stands (a directory or ``s3://``)."""
+    if not path_or_url.startswith(TMP_SCHEME):
+        return path_or_url, False
+    name = path_or_url[len(TMP_SCHEME):].strip("/") or "rtfds-cold"
+    if os.sep in name or name in (".", ".."):
+        raise ValueError(
+            f"cold_store {path_or_url!r}: tmp:// takes a bare name, the "
+            "directory is made under the system's temporary directory")
+    return tempfile.mkdtemp(prefix=name + "-"), True
+
+
+def _canon_keys(keys) -> np.ndarray:
+    return np.asarray(keys, dtype=np.uint32).reshape(-1)
+
+
+class _TableIndex:
+    """One table's membership: ``keys`` sorted, and beside each key the
+    segment (``_BUF`` = the flush buffer) and the row that hold its
+    newest demoted rows."""
+
+    __slots__ = ("keys", "seg", "row")
+
+    def __init__(self):
+        self.keys = np.empty(0, np.uint32)
+        self.seg = np.empty(0, np.int64)
+        self.row = np.empty(0, np.int64)
+
+    def find(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """→ (position of each key, whether it is there)."""
+        if not self.keys.size:
+            return (np.zeros(keys.shape, np.int64),
+                    np.zeros(keys.shape, bool))
+        pos = np.searchsorted(self.keys, keys)
+        pos_c = np.minimum(pos, self.keys.size - 1)
+        return pos_c, self.keys[pos_c] == keys
+
+    def put(self, keys: np.ndarray, seg: int,
+            rows: np.ndarray) -> np.ndarray:
+        """``keys`` (unique) now live at ``(seg, rows)``: newest wins.
+        → the segments that the keys already stored were in before."""
+        pos, hit = self.find(keys)
+        left = self.seg[pos[hit]]
+        self.seg[pos[hit]] = seg
+        self.row[pos[hit]] = rows[hit]
+        if hit.all():
+            return left
+        new_k = keys[~hit]
+        order = np.argsort(new_k, kind="stable")
+        new_k, new_r = new_k[order], rows[~hit][order]
+        at = np.searchsorted(self.keys, new_k)
+        self.keys = np.insert(self.keys, at, new_k)
+        self.seg = np.insert(self.seg, at, seg)
+        self.row = np.insert(self.row, at, new_r)
+        return left
+
+    def keep(self, mask: np.ndarray) -> np.ndarray:
+        """Keep ``mask``'s entries → the segments of those dropped."""
+        gone = self.seg[~mask]
+        self.keys, self.seg, self.row = (
+            self.keys[mask], self.seg[mask], self.row[mask])
+        return gone
+
+
 class ColdStore:
     """Append+compact keyed store over the checkpoint storage backends
     (``_LocalBackend``/``_StoreBackend`` — flaky-store retries and the
@@ -81,30 +164,36 @@ class ColdStore:
             _StoreBackend,
         )
 
-        self.path = path_or_url
-        if path_or_url.startswith("s3://"):
+        self.url = path_or_url
+        self.path, self.ephemeral = resolve_location(path_or_url)
+        self._cleanup = None
+        if self.ephemeral:
+            # gone with the store, or with the process if nobody closes it
+            self._cleanup = weakref.finalize(
+                self, shutil.rmtree, self.path, ignore_errors=True)
+        if self.path.startswith("s3://"):
             from real_time_fraud_detection_system_tpu.io.store import (
                 make_store,
             )
 
-            self._backend = _StoreBackend(make_store(path_or_url),
-                                          prefix="")
+            self._backend = _StoreBackend(make_store(self.path), prefix="")
         else:
-            self._backend = _LocalBackend(path_or_url)
+            self._backend = _LocalBackend(self.path)
         self._segment_bytes = max(1, int(segment_mb * (1 << 20)))
+        # _lock guards the index and the resident arrays; _io_lock
+        # serialises segment writes, which run outside _lock so that a
+        # large flush never holds up the loop thread's detection.
         self._lock = threading.RLock()
-        # (table, key) -> (seq, row) for flushed rows; (None, key) for
-        # rows still sitting in the flush buffer. Newest location wins.
-        self._index: Dict[Tuple[str, int], Tuple[Optional[int], int]] = {}
-        self._seg_meta: Dict[int, dict] = {}  # seq -> manifest dict
-        self._buf: Dict[str, "OrderedDict"] = {}  # table -> key -> row
+        self._io_lock = threading.Lock()
+        self._tables: Dict[str, _TableIndex] = {}
+        self._seg_meta: Dict[int, dict] = {}  # seq -> committed manifest
+        self._data: Dict[int, dict] = {}  # seq/_BUF -> {table: 4 arrays}
+        self._live: Dict[int, int] = {}  # seq/_BUF -> keys it is newest for
         self._buf_bytes = 0
         self._next_seq = 0
-        self._cache: "OrderedDict" = OrderedDict()  # seq -> arrays (LRU)
-        self._cache_depth = 4
         self._open()
 
-    # -- open / crash hygiene -------------------------------------------
+    # -- open / close / crash hygiene -----------------------------------
 
     def _log(self):
         from real_time_fraud_detection_system_tpu.utils import get_logger
@@ -130,7 +219,7 @@ class ColdStore:
                 seq = int(man["seq"])
                 blob = str(man["blob"])
                 int(man["crc"])
-                keys = {t: [int(k) for k in ks]
+                keys = {t: np.asarray(ks, dtype=np.uint32)
                         for t, ks in dict(man["keys"]).items()}
             # rtfdslint: disable=broad-exception-catch (crash-safe re-open: ANY unparsable manifest is quarantined evidence, never trusted state)
             except Exception:
@@ -143,13 +232,21 @@ class ColdStore:
                 continue
             self._seg_meta[seq] = man
             for table, ks in keys.items():
-                for row, k in enumerate(ks):
-                    self._index[(table, k)] = (seq, row)
+                # a key listed twice in one manifest: the later row wins
+                uk, first_rev = np.unique(ks[::-1], return_index=True)
+                self._put(table, uk, seq,
+                          (ks.size - 1 - first_rev).astype(np.int64))
             self._next_seq = max(self._next_seq, seq + 1)
         committed = {m["blob"] for m in self._seg_meta.values()}
         for b in blobs:
             if b not in committed:
                 self._backend.delete(b)  # orphan blob: never committed
+
+    def close(self) -> None:
+        """An ephemeral (``tmp://``) store removes its directory; a
+        durable one has nothing to release."""
+        if self._cleanup is not None:
+            self._cleanup()
 
     def _quarantine_name(self, name: str) -> None:
         try:
@@ -157,94 +254,151 @@ class ColdStore:
         except (OSError, KeyError):
             self._backend.delete(name)
 
-    # -- append (loop thread: compaction demotions) ---------------------
+    def _table(self, table: str) -> _TableIndex:
+        t = self._tables.get(table)
+        if t is None:
+            t = self._tables[table] = _TableIndex()
+        return t
 
-    def append(self, table: str, keys, bd, cnt, amt, frd) -> int:
-        """Buffer demoted rows (``EMPTY_KEY`` lanes skipped); flush a
-        segment once the buffer crosses the segment threshold. Returns
-        the number of rows actually appended."""
-        keys = np.asarray(keys, dtype=np.uint32)
-        bd = np.asarray(bd, dtype=np.int32)
-        cnt = np.asarray(cnt, dtype=np.float32)
-        amt = np.asarray(amt, dtype=np.float32)
-        frd = np.asarray(frd, dtype=np.float32)
-        nb = bd.shape[1] if bd.ndim == 2 else 0
-        row_bytes = 4 + 16 * nb
-        n = 0
+    def _left(self, segs: np.ndarray) -> None:
+        """Index entries left ``segs`` (one element an entry): a segment
+        no key points at any more gives up its resident arrays (the file
+        waits for :meth:`gc`)."""
+        for seq, n in zip(*np.unique(segs, return_counts=True)):
+            seq = int(seq)
+            self._live[seq] = self._live.get(seq, 0) - int(n)
+            if self._live[seq] <= 0:
+                del self._live[seq]
+                if seq != _BUF:
+                    self._data.pop(seq, None)
+
+    def _put(self, table: str, keys: np.ndarray, seg: int,
+             rows: np.ndarray) -> None:
+        self._live[seg] = self._live.get(seg, 0) + int(keys.size)
+        self._left(self._table(table).put(keys, seg, rows))
+
+    def _keep(self, table: str, mask: np.ndarray) -> int:
+        if mask.all():
+            return 0
+        gone = self._table(table).keep(mask)
+        self._left(gone)
+        return int(gone.size)
+
+    # -- append (compaction demotions) ----------------------------------
+
+    def append(self, table: str, keys, bd, cnt, amt, frd,
+               flush: bool = True) -> int:
+        """Land demoted rows (``EMPTY_KEY`` lanes skipped; a key given
+        twice keeps its last row): readable at once. A segment is
+        flushed once the buffer crosses the segment threshold — here, or
+        (``flush=False``) when the caller says :meth:`flush_if_full`.
+        Returns the rows appended."""
+        keys = _canon_keys(keys)
+        live = np.flatnonzero(keys != np.uint32(_EMPTY_KEY))
+        if not live.size:
+            return 0
+        rows = tuple(np.asarray(a, dtype=dt).reshape(keys.size, -1)
+                     for a, dt in zip((bd, cnt, amt, frd), _ROW_DTYPES))
+        uk = keys[:live.size]
+        if live[-1] == live.size - 1 and bool((uk[1:] > uk[:-1]).all()):
+            # the compaction pass's own payload: live lanes first, keys
+            # ascending — landed as it stands; a view where most lanes
+            # are live, a copy where it would pin mostly padding
+            dense = 2 * live.size >= keys.size
+            rows = tuple(a[:live.size] if dense else a[:live.size].copy()
+                         for a in rows)
+        else:
+            uk, first_rev = np.unique(keys[live][::-1], return_index=True)
+            take = live[live.size - 1 - first_rev]  # last lane of each key
+            rows = tuple(a[take] for a in rows)
         with self._lock:
-            buf = self._buf.setdefault(table, OrderedDict())
-            for i in range(keys.shape[0]):
-                k = int(keys[i])
-                if k == _EMPTY_KEY:
-                    continue
-                if k not in buf:
-                    self._buf_bytes += row_bytes
-                buf[k] = (bd[i], cnt[i], amt[i], frd[i])
-                self._index[(table, k)] = (None, k)
-                n += 1
-            if self._buf_bytes >= self._segment_bytes:
-                self._flush_locked()
-        return n
+            buf = self._data.setdefault(_BUF, {})
+            old = buf.get(table)
+            base = 0 if old is None else old[0].shape[0]
+            buf[table] = rows if old is None else tuple(
+                np.concatenate([o, r]) for o, r in zip(old, rows))
+            self._put(table, uk, _BUF,
+                      base + np.arange(uk.size, dtype=np.int64))
+            self._buf_bytes += uk.size * (4 + 16 * rows[0].shape[1])
+        if flush:
+            self.flush_if_full()
+        return int(uk.size)
+
+    def flush_if_full(self) -> Optional[int]:
+        with self._lock:
+            full = self._buf_bytes >= self._segment_bytes
+        return self.flush() if full else None
 
     def flush(self) -> Optional[int]:
         """Flush buffered rows as a committed segment (checkpoint save
         calls this so the lineage it records is durable). Returns the
         new segment's seq, or None when the buffer was empty."""
-        with self._lock:
-            return self._flush_locked()
+        with self._io_lock:
+            with self._lock:
+                buf = self._data.pop(_BUF, None)
+                self._buf_bytes = 0
+                arrays, man_keys, resident, where = {}, {}, {}, {}
+                for table, rows in sorted((buf or {}).items()):
+                    t = self._table(table)
+                    at = np.flatnonzero(t.seg == _BUF)
+                    if not at.size:
+                        continue  # every buffered row superseded/promoted
+                    src = t.row[at]
+                    # one payload, nothing of it gone since: as it stands
+                    whole = (src.size == rows[0].shape[0]
+                             and bool((np.diff(src) == 1).all()))
+                    resident[table] = rows if whole else tuple(
+                        a[src] for a in rows)
+                    man_keys[table] = t.keys[at]
+                    where[table] = at
+                    arrays[f"{table}_keys"] = man_keys[table]
+                    for name, a in zip(("bd", "cnt", "amt", "frd"),
+                                       resident[table]):
+                        arrays[f"{table}_{name}"] = a
+                if not man_keys:
+                    self._live.pop(_BUF, None)
+                    return None
+                seq = self._next_seq
+                self._next_seq = seq + 1
+                # readable from memory at once; durable once the manifest
+                # below is committed (lineage lists committed segments)
+                self._data[seq] = resident
+                self._live[seq] = self._live.pop(_BUF)
+                for table, at in where.items():
+                    t = self._table(table)
+                    t.seg[at] = seq
+                    t.row[at] = np.arange(at.size, dtype=np.int64)
+            bio = _io.BytesIO()
+            np.savez(bio, **arrays)
+            data = bio.getvalue()
+            man = {
+                "format": SEG_FORMAT,
+                "seq": seq,
+                "blob": _seg_blob(seq),
+                "crc": zlib.crc32(data) & 0xFFFFFFFF,
+                "bytes": len(data),
+                "keys": {t: ks.tolist() for t, ks in man_keys.items()},
+            }
+            # Blob FIRST, manifest as the commit point: a crash between
+            # the two leaves an orphan blob that open() deletes.
+            self._backend.write(_seg_blob(seq), data)
+            self._backend.write(
+                _seg_manifest(seq),
+                json.dumps(man, sort_keys=True).encode("utf-8"))
+            with self._lock:
+                self._seg_meta[seq] = man
+            return seq
 
-    def _flush_locked(self) -> Optional[int]:
-        if not any(self._buf.values()):
-            return None
-        seq = self._next_seq
-        arrays = {}
-        man_keys: Dict[str, List[int]] = {}
-        for table, rows in self._buf.items():
-            if not rows:
-                continue
-            ks = list(rows.keys())
-            man_keys[table] = ks
-            arrays[f"{table}_keys"] = np.array(ks, dtype=np.uint32)
-            arrays[f"{table}_bd"] = np.stack([rows[k][0] for k in ks])
-            arrays[f"{table}_cnt"] = np.stack([rows[k][1] for k in ks])
-            arrays[f"{table}_amt"] = np.stack([rows[k][2] for k in ks])
-            arrays[f"{table}_frd"] = np.stack([rows[k][3] for k in ks])
-        bio = _io.BytesIO()
-        np.savez(bio, **arrays)
-        data = bio.getvalue()
-        man = {
-            "format": SEG_FORMAT,
-            "seq": seq,
-            "blob": _seg_blob(seq),
-            "crc": zlib.crc32(data) & 0xFFFFFFFF,
-            "bytes": len(data),
-            "keys": man_keys,
-        }
-        # Blob FIRST, manifest as the commit point: a crash between the
-        # two leaves an orphan blob that open() deletes.
-        self._backend.write(_seg_blob(seq), data)
-        self._backend.write(
-            _seg_manifest(seq),
-            json.dumps(man, sort_keys=True).encode("utf-8"))
-        self._next_seq = seq + 1
-        self._seg_meta[seq] = man
-        for table, ks in man_keys.items():
-            for row, k in enumerate(ks):
-                loc = self._index.get((table, k))
-                if loc is not None and loc[0] is None:
-                    self._index[(table, k)] = (seq, row)
-        self._buf = {}
-        self._buf_bytes = 0
-        return seq
-
-    # -- reads (promoter thread) ----------------------------------------
+    # -- reads -----------------------------------------------------------
 
     def _load_segment(self, seq: int) -> dict:
+        """The segment's arrays, from memory or — CRC-checked — from the
+        backend."""
         with self._lock:
-            if seq in self._cache:
-                self._cache.move_to_end(seq)
-                return self._cache[seq]
+            got = self._data.get(seq)
             man = self._seg_meta.get(seq)
+        if got is not None:
+            return got
         if man is None:
             raise ColdStoreCorruptError(f"segment {seq} unknown")
         try:
@@ -259,73 +413,85 @@ class ColdStore:
                 f"segment {seq} CRC mismatch (byte corruption)")
         try:
             with np.load(_io.BytesIO(data)) as z:
-                arrays = {k: z[k] for k in z.files}
+                got = {
+                    t: tuple(z[f"{t}_{n}"]
+                             for n in ("bd", "cnt", "amt", "frd"))
+                    for t in man["keys"]}
         # rtfdslint: disable=broad-exception-catch (a CRC-valid but unparsable npz is still poison: quarantine + typed raise, never propagate a decode internals error)
         except Exception:
             self._drop_segment(seq, quarantine=True)
             raise ColdStoreCorruptError(
                 f"segment {seq} blob unparsable") from None
         with self._lock:
-            self._cache[seq] = arrays
-            while len(self._cache) > self._cache_depth:
-                self._cache.popitem(last=False)
-        return arrays
+            self._data[seq] = got
+        return got
+
+    def read_rows(self, table: str, keys) -> tuple:
+        """The batch read: ``(found [n] bool, bd, cnt, amt, frd)`` —
+        which of ``keys`` the store holds, and one row for each of THOSE,
+        in ``keys``' order (``keys[found]`` names them). One gather per
+        segment touched. A corrupt segment raises
+        :class:`ColdStoreCorruptError` after quarantining itself."""
+        keys = _canon_keys(keys)
+        with self._lock:
+            t = self._table(table)
+            pos, found = t.find(keys)
+            pos = pos[found]
+            seg, row = t.seg[pos], t.row[pos]
+            nb = 0
+            parts = []
+            for s in np.unique(seg).tolist():
+                arrays = self._load_segment(int(s))[table]
+                nb = arrays[0].shape[1]
+                at = np.flatnonzero(seg == s)
+                parts.append((at, tuple(a[row[at]] for a in arrays)))
+        out = tuple(np.empty((pos.size, nb), dt) for dt in _ROW_DTYPES)
+        for at, rows in parts:
+            for o, r in zip(out, rows):
+                o[at] = r
+        return (found,) + out
 
     def get_rows(self, table: str, keys: Sequence[int]) -> dict:
-        """Resolve ``keys`` to their newest cold rows. Missing keys are
-        simply absent from the result; a corrupt segment raises
-        :class:`ColdStoreCorruptError` after quarantining itself."""
+        """The per-key view of :meth:`read_rows`: ``{key: (bd, cnt, amt,
+        frd)}``, missing keys simply absent."""
+        keys = _canon_keys(keys)
+        found, bd, cnt, amt, frd = self.read_rows(table, keys)
+        return {int(k): (bd[j], cnt[j], amt[j], frd[j])
+                for j, k in enumerate(keys[found])}
+
+    def cold_mask(self, table: str, keys) -> np.ndarray:
+        """Which of ``keys`` (any order, repeats allowed) the store
+        holds: the engine's returning-key detection, one sorted-index
+        lookup a batch."""
+        keys = _canon_keys(keys)
         with self._lock:
-            locs = {int(k): self._index.get((table, int(k)))
-                    for k in keys}
-        out = {}
-        for k, loc in locs.items():
-            if loc is None:
-                continue
-            seq, row = loc
-            if seq is None:
-                with self._lock:
-                    r = self._buf.get(table, {}).get(k)
-                if r is not None:
-                    out[k] = r
-                continue
-            arrays = self._load_segment(seq)
-            out[k] = (
-                arrays[f"{table}_bd"][row],
-                arrays[f"{table}_cnt"][row],
-                arrays[f"{table}_amt"][row],
-                arrays[f"{table}_frd"][row],
-            )
-        return out
+            return self._table(table).find(keys)[1]
 
     def contains(self, table: str, key: int) -> bool:
-        with self._lock:
-            return (table, int(key)) in self._index
+        return bool(self.cold_mask(table, [key])[0])
 
     def index_snapshot(self, table: str) -> np.ndarray:
-        """Sorted u32 array of this table's cold keys — the host-side
-        membership set the engine intersects batches against
-        (``np.isin``). Rebuilt by the caller when :meth:`version`
-        changes (append/promotion/quarantine), not per batch."""
+        """Sorted u32 array of this table's cold keys (a copy)."""
         with self._lock:
-            ks = [k for (t, k) in self._index if t == table]
-        return np.sort(np.asarray(ks, dtype=np.uint32))
-
-    def version(self) -> int:
-        """Monotonic-ish index mutation stamp for snapshot caching."""
-        with self._lock:
-            return len(self._index) + (self._next_seq << 32)
+            return self._table(table).keys.copy()
 
     # -- promotion / retention ------------------------------------------
 
+    def _live_segs(self) -> set:
+        return {s for s in self._live if s != _BUF}
+
+    def _forget(self, table: str, keys: np.ndarray) -> int:
+        t = self._table(table)
+        pos, hit = t.find(keys)
+        mask = np.ones(t.keys.size, bool)
+        mask[pos[hit]] = False
+        return self._keep(table, mask)
+
     def mark_promoted(self, table: str, keys: Sequence[int]) -> None:
-        """A key's rows landed back in the hot tier: drop its index
-        entry (stops re-detection; segment bytes stay until gc)."""
+        """The keys' rows went back to the hot tier: drop their index
+        entries (stops re-detection; segment bytes stay until gc)."""
         with self._lock:
-            for k in keys:
-                loc = self._index.pop((table, int(k)), None)
-                if loc is not None and loc[0] is None:
-                    self._buf.get(table, {}).pop(int(k), None)
+            self._forget(table, _canon_keys(keys))
 
     def gc(self) -> List[int]:
         """Delete segments with zero live index entries. Call AFTER a
@@ -334,8 +500,7 @@ class ColdStore:
         (an *older* checkpoint restored over a gc'd store degrades those
         keys to CMS honestly, with a warning from :meth:`sync_to`)."""
         with self._lock:
-            live = {loc[0] for loc in self._index.values()
-                    if loc[0] is not None}
+            live = self._live_segs()
             dead = [s for s in self._seg_meta if s not in live]
             for seq in dead:
                 self._drop_segment(seq, quarantine=False)
@@ -344,10 +509,10 @@ class ColdStore:
     def _drop_segment(self, seq: int, quarantine: bool) -> None:
         with self._lock:
             man = self._seg_meta.pop(seq, None)
-            self._cache.pop(seq, None)
-            for key in [key for key, loc in self._index.items()
-                        if loc[0] == seq]:
-                del self._index[key]
+            for table, t in self._tables.items():
+                self._keep(table, t.seg != seq)
+            self._data.pop(seq, None)
+            self._live.pop(seq, None)
         if man is None:
             return
         if quarantine:
@@ -364,8 +529,7 @@ class ColdStore:
         :meth:`flush`). Per segment: seq/blob/crc/bytes + per-table key
         COUNTS (the full key lists live in the on-disk manifests)."""
         with self._lock:
-            live = {loc[0] for loc in self._index.values()
-                    if loc[0] is not None}
+            live = self._live_segs()
             segs = []
             for seq in sorted(self._seg_meta):
                 if seq not in live:
@@ -382,7 +546,7 @@ class ColdStore:
             return {
                 "cold_store": self.path,
                 "segments": segs,
-                "total_keys": len(self._index),
+                "total_keys": self.keys_count,
                 "total_bytes": sum(s["bytes"] for s in segs),
             }
 
@@ -395,13 +559,12 @@ class ColdStore:
         flush buffer is dropped (its rows post-date the checkpoint)."""
         listed = {int(s["seq"]): s
                   for s in (lineage or {}).get("segments", [])}
-        with self._lock:
+        with self._io_lock, self._lock:
             for seq in [s for s in self._seg_meta if s not in listed]:
                 self._drop_segment(seq, quarantine=False)
-            for key in [key for key, loc in self._index.items()
-                        if loc[0] is None]:
-                del self._index[key]
-            self._buf = {}
+            for table, t in self._tables.items():
+                self._keep(table, t.seg != _BUF)
+            self._data.pop(_BUF, None)
             self._buf_bytes = 0
             missing = [s for s in listed if s not in self._seg_meta]
             top = max(list(listed) + list(self._seg_meta), default=-1)
@@ -418,24 +581,16 @@ class ColdStore:
         a topology change (fleet resize restore). ``owned_fn(table,
         keys_u32) -> bool mask`` is the NEW topology's ownership
         predicate; non-owned keys are unindexed here (their segment
-        bytes stay until :meth:`gc`) and buffered rows are discarded —
-        the owning process promotes them from ITS store. Returns the
-        number of keys dropped."""
+        bytes stay until :meth:`gc`) — the owning process promotes them
+        from ITS store. Returns the number of keys dropped."""
         dropped = 0
         with self._lock:
-            tables = sorted({t for (t, _k) in self._index})
-            for table in tables:
-                ks = np.asarray(
-                    [k for (t, k) in self._index if t == table],
-                    dtype=np.uint32)
+            for table in sorted(self._tables):
+                ks = self._table(table).keys
                 if not ks.size:
                     continue
                 mine = np.asarray(owned_fn(table, ks), dtype=bool)
-                for k in ks[~mine]:
-                    loc = self._index.pop((table, int(k)), None)
-                    if loc is not None and loc[0] is None:
-                        self._buf.get(table, {}).pop(int(k), None)
-                dropped += int((~mine).sum())
+                dropped += self._forget(table, ks[~mine])
         return dropped
 
     # -- gauges ----------------------------------------------------------
@@ -443,13 +598,12 @@ class ColdStore:
     @property
     def keys_count(self) -> int:
         with self._lock:
-            return len(self._index)
+            return sum(int(t.keys.size) for t in self._tables.values())
 
     @property
     def bytes(self) -> int:
         with self._lock:
-            live = {loc[0] for loc in self._index.values()
-                    if loc[0] is not None}
+            live = self._live_segs()
             seg = sum(int(m["bytes"]) for s, m in self._seg_meta.items()
                       if s in live)
             return seg + self._buf_bytes
@@ -472,169 +626,83 @@ def consolidate_cold_stores(src_paths: Sequence[str], dest_path: str,
                 f"cold consolidation destination {dest_path} is also a "
                 "source — merge into a fresh generation directory")
         src = ColdStore(src_path, segment_mb=segment_mb)
-        with src._lock:
-            tables = sorted({t for (t, _k) in src._index})
-        for table in tables:
-            ks = src.index_snapshot(table)
-            rows = None
+        for table in sorted(src._tables):
+            got = None
             # each corrupt segment quarantines itself on first touch, so
             # one extra attempt per segment always terminates
             for _ in range(len(src._seg_meta) + 1):
+                ks = src.index_snapshot(table)
                 try:
-                    rows = src.get_rows(table, ks)
+                    got = src.read_rows(table, ks)
                     break
                 except ColdStoreCorruptError:
                     continue
-            if not rows:
+            if got is None or not ks.size:
                 continue
-            kept = np.asarray(sorted(rows), dtype=np.uint32)
-            dest.append(
-                table, kept,
-                np.stack([rows[int(k)][0] for k in kept]),
-                np.stack([rows[int(k)][1] for k in kept]),
-                np.stack([rows[int(k)][2] for k in kept]),
-                np.stack([rows[int(k)][3] for k in kept]))
+            dest.append(table, ks[got[0]], *got[1:])
     dest.flush()
     return dest
 
 
-class _Err:
-    __slots__ = ("exc",)
+class SegmentWriter:
+    """The cold tier's one writer thread: the durable copy, off the loop
+    thread.
 
-    def __init__(self, exc: BaseException):
-        self.exc = exc
-
-
-class ColdPromoter:
-    """Background cold→hot promotion reader (the input-side mirror of
-    ``PrefetchSource``'s producer): the loop thread enqueues returning
-    keys, the worker resolves their rows from the :class:`ColdStore`
-    off-thread, and the loop drains ready rows between device steps.
-
-    - the request queue is BOUNDED (``maxsize=depth``): a full queue
-      drops the request (``request()`` → False) and the key re-enqueues
-      on its next touch — backpressure, never unbounded host growth;
-    - worker errors ship to the loop thread and re-raise with their
-      ORIGINAL type from :meth:`poll_ready`;
-    - :meth:`reset` fences the generation across checkpoint restore
-      (fresh stop-event + queues; a worker wedged in a hung store read
-      is abandoned with its orphaned queues);
-    - a corrupt segment is POISON-ISOLATED per key: the store
-      quarantines it, the worker counts the skip and the key surfaces
-      with ``rows=None`` so the engine clears it from pending (served
-      from CMS honestly) instead of wedging the promotion pipeline.
+    A compaction pass's payload is landed by the loop thread itself,
+    inside ``engine._maybe_compact`` (:meth:`ColdStore.append`: readable
+    from memory at once, so a key that comes back in the very next batch
+    is found where every other cold key is). What follows here, and what
+    nobody waits for, is the segment write — the ``.npz`` blob, its CRC
+    and the manifest of a full flush buffer. :meth:`kick` asks for one
+    :meth:`ColdStore.flush_if_full`; :meth:`wait` blocks until every
+    request is served (a checkpoint save, the end of a run); a
+    worker-side failure re-raises on the loop thread, with its own type,
+    from the next call.
     """
 
-    def __init__(self, store: ColdStore, depth: int = 64):
+    def __init__(self, store: ColdStore):
         self.store = store
-        self.depth = max(1, int(depth))
-        self.corrupt_skipped = 0
-        self._gen = 0
-        self._stop = threading.Event()
-        self._req: "queue.Queue" = queue.Queue(maxsize=self.depth)
-        self._ready: "queue.Queue" = queue.Queue(maxsize=self.depth)
-        self._thread: Optional[threading.Thread] = None
-        self._start_worker()
-
-    def _start_worker(self) -> None:
-        self._stop = threading.Event()
-        self._req = queue.Queue(maxsize=self.depth)
-        self._ready = queue.Queue(maxsize=self.depth)
+        self._lock = threading.Lock()
+        self._idle = threading.Condition(self._lock)
+        self._inflight = 0
+        self._error: Optional[BaseException] = None
+        # rtfdslint: disable=unbounded-queue (one request a compaction pass, each a no-op unless the flush buffer is full: bounded by the passes a segment write outlasts)
+        self._q: "queue.Queue" = queue.Queue()
         self._thread = threading.Thread(
-            target=self._work,
-            args=(self._stop, self._gen, self._req, self._ready),
-            daemon=True, name="rtfds-cold-promoter")
+            target=self._work, daemon=True, name="rtfds-cold-writer")
         self._thread.start()
 
-    def _work(self, stop: threading.Event, gen: int, req: "queue.Queue",
-              ready: "queue.Queue") -> None:
-        def put(item) -> bool:
-            # bounded put that a generation fence can interrupt
-            while not stop.is_set():
-                try:
-                    ready.put(item, timeout=0.1)
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        while not stop.is_set():
+    def _work(self) -> None:
+        while self._q.get() is not None:
             try:
-                g, table, key, t_enq = req.get(timeout=0.1)
-            except queue.Empty:
-                continue
-            if g != gen:
-                continue  # stale generation: fenced request
-            try:
-                rows = self.store.get_rows(table, [key]).get(int(key))
-            except ColdStoreCorruptError:
-                # poison isolation: the store quarantined the segment;
-                # surface the key with no rows so pending clears and it
-                # degrades to CMS honestly
-                self.corrupt_skipped += 1
-                rows = None
-            # rtfdslint: disable=broad-exception-catch (thread-boundary transport: the worker ships the ORIGINAL exception to the loop thread, which re-raises it typed from poll_ready)
+                self.store.flush_if_full()
+            # rtfdslint: disable=broad-exception-catch (thread-boundary transport: the worker ships the ORIGINAL exception to the loop thread, which re-raises it typed from the next call)
             except BaseException as e:
-                put(_Err(e))
-                return
-            if not put((table, int(key), rows, t_enq)):
-                return
+                with self._lock:
+                    self._error = self._error or e
+            with self._lock:
+                self._inflight -= 1
+                self._idle.notify_all()
 
-    # -- loop thread ------------------------------------------------------
+    def _raise(self) -> None:
+        if self._error is not None:
+            e, self._error = self._error, None
+            raise e
 
-    def request(self, table: str, key: int) -> bool:
-        """Enqueue a returning key for promotion. Non-blocking: returns
-        False on a full queue (the key re-enqueues on its next touch)."""
-        try:
-            self._req.put_nowait(
-                (self._gen, table, int(key), time.perf_counter()))
-            return True
-        except queue.Full:
-            return False
+    def kick(self) -> None:
+        """Rows were appended: write a segment if the buffer is full."""
+        with self._lock:
+            self._raise()
+            self._inflight += 1
+        self._q.put(True)
 
-    def poll_ready(self, max_items: int = 64) -> list:
-        """Drain up to ``max_items`` resolved promotions without
-        blocking: ``[(table, key, rows|None, t_enq), ...]``. Re-raises a
-        worker-side failure with its original type."""
-        out = []
-        while len(out) < max_items:
-            try:
-                item = self._ready.get_nowait()
-            except queue.Empty:
-                break
-            if isinstance(item, _Err):
-                raise item.exc
-            out.append(item)
-        return out
-
-    def backlog(self) -> int:
-        """Keys enqueued or resolved but not yet landed on device."""
-        return self._req.qsize() + self._ready.qsize()
-
-    def reset(self) -> None:
-        """Generation fence (checkpoint restore): stop the worker,
-        orphan its queues, start a fresh generation. In-flight requests
-        and un-landed ready rows are discarded — the restored engine
-        re-detects returning keys against the synced cold index."""
-        self._stop.set()
-        t = self._thread
-        if t is not None:
-            t.join(timeout=5.0)
-            if t.is_alive():
-                from real_time_fraud_detection_system_tpu.utils import (
-                    get_logger,
-                )
-
-                get_logger("coldstore").warning(
-                    "cold promoter did not exit within 5s (store read "
-                    "wedged); abandoning it — its late results land in "
-                    "an orphaned queue nothing reads")
-        self._gen += 1
-        self._start_worker()
+    def wait(self) -> None:
+        """Block until every requested write is done."""
+        with self._lock:
+            while self._inflight:
+                self._idle.wait()
+            self._raise()
 
     def close(self) -> None:
-        self._stop.set()
-        t = self._thread
-        if t is not None:
-            t.join(timeout=5.0)
-        self._thread = None
+        self._q.put(None)
+        self._thread.join(timeout=10.0)

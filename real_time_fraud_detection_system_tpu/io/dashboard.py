@@ -769,15 +769,10 @@ def render_ops_html(
                 f"{_compact(int(per_shard[worst]))}/"
                 f"{_compact(cap_shard)}"))
         if last.get("cold_keys") is not None:
-            # host cold tier armed: depth of the demoted key set and the
-            # promotion backlog at the last compaction — a growing
-            # backlog means returning keys are being served from the
-            # sketch longer than the promoter can land them
-            cold_bits = f"cold {_compact(int(last['cold_keys']))} key(s)"
-            backlog = int(last.get("promote_backlog", 0))
-            if backlog:
-                cold_bits += f", {_compact(backlog)} promoting"
-            sub_bits.append(cold_bits)
+            # host cold tier armed: depth of the demoted key set at the
+            # last compaction
+            sub_bits.append(
+                f"cold {_compact(int(last['cold_keys']))} key(s)")
         tiles.append((
             "Feature store",
             f"{_compact(occ)}/{_compact(cap)} slots" if cap

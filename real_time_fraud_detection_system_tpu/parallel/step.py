@@ -608,7 +608,9 @@ def make_sharded_promote(
     ``promote(fstate, payload) -> (fstate', stats [n_dev, 2, 2])``: the
     engine groups promoted keys host-side by owner shard (the same
     ``key % n_shards`` modulo the ingest router uses) and pads each
-    shard's block to the fixed ``K`` with ``EMPTY_KEY``, so every device
+    shard's block to a width of its lane ladder with ``EMPTY_KEY`` (the
+    jit retraces a table and a width; ``precompile`` compiles them all),
+    so every device
     runs :func:`~..features.online.promote_rows` over ITS block and ITS
     directory — purely local, zero collectives, one fixed shape. Stats
     come back stacked per shard ([admitted, dropped] per table) for the
@@ -627,12 +629,12 @@ def make_sharded_promote(
     def spec_like(tree, spec):
         return jax.tree.map(lambda _: spec, tree)
 
-    def _payload_spec():
+    def _payload_spec(payload):
+        # a payload carries ONE table's lanes (the other is None): the
+        # engine dispatches a promote a table and a width of its ladder
         leaf = (P(axis, None),) + (P(axis, None, None),) * 4
-        return {
-            "customer": leaf if has_cdir else None,
-            "terminal": leaf,
-        }
+        return {t: (leaf if payload.get(t) is not None else None)
+                for t in ("customer", "terminal")}
 
     def outer(fstate: FeatureState, payload):
         def local(customer, terminal, c_kd, t_kd, pay):
@@ -663,7 +665,7 @@ def make_sharded_promote(
             spec_like(fstate.terminal, dev),
             spec_like(fstate.customer_dir, dev) if has_cdir else None,
             spec_like(fstate.terminal_dir, dev),
-            _payload_spec(),
+            _payload_spec(payload),
         )
         out_specs = in_specs[:4] + (P(axis, None, None),)
         fn = compat_shard_map(local, mesh, in_specs, out_specs)

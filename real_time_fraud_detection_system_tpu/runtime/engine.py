@@ -90,6 +90,52 @@ from real_time_fraud_detection_system_tpu.utils.xla_telemetry import (
 # thread where it runs, beside them.
 PHASES = ("source_poll", "host_prep", "dispatch", "result_wait",
           "sink_wait", "sink_write")
+# The cold tier's own phases of the same histogram, registered only when
+# the tier is armed: cold_detect (inside host_prep), state_promote (row
+# read, payload build and promote dispatch, between host_prep and
+# dispatch), cold_append (one pass's payload fetch and landing, inside
+# state_compact).
+COLD_PHASES = ("cold_detect", "state_promote", "cold_append")
+
+
+class ColdPromoteError(RuntimeError):
+    """A returning key's exact rows could not be put back into the hot
+    tier before the step that scored its row."""
+
+
+PROMOTE_LANES_MAX = 16384
+
+
+def blank_lanes(shape: tuple, rows: tuple) -> list:
+    """An all-padding promote payload of lane shape ``shape`` (``(W,)``,
+    or ``(n_dev, W)`` on the mesh): ``EMPTY_KEY`` keys and empty rows as
+    wide as ``rows``' (bd, cnt, amt, frd), for the caller to fill."""
+    return [np.full(shape, 0xFFFFFFFF, np.uint32)] + [
+        np.full(shape + r.shape[1:], fill, r.dtype)
+        for r, fill in zip(rows, (-1, 0.0, 0.0, 0.0))]
+
+
+def one_table_payload(table: str, lanes) -> dict:
+    """The promote programs take one table's lanes a dispatch."""
+    return {t: (tuple(lanes) if t == table else None)
+            for t in ("customer", "terminal")}
+
+
+def promote_widths(max_rows: int) -> tuple:
+    """The lane ladder of the ``("promote", table, width)`` programs:
+    the largest batch's rows (no batch holds more distinct keys than
+    rows) or ``PROMOTE_LANES_MAX``, whichever is less, and every quarter
+    of it down to 256 lanes — what ``batch_buckets`` is to the step. A
+    promote costs by its lanes (the directory's admit), so a few thousand
+    returning keys must not pay for a whole batch's; and a batch that
+    brings back more keys than the widest program holds dispatches
+    several (a 65,536-lane program is a quarter of a minute of compile a
+    table that a run may never use)."""
+    top = max(1, min(int(max_rows), PROMOTE_LANES_MAX))
+    widths = [top]
+    while widths[-1] // 4 >= 256:
+        widths.append(widths[-1] // 4)
+    return tuple(sorted(widths))
 
 
 class PollAhead:
@@ -399,15 +445,13 @@ class ScoringEngine:
         self._m_slots_rec = None
         # Host cold tier (features.cold_store, key_mode="exact"): armed
         # by _init_cold below; the defaults keep every shared-path
-        # getattr/None-check cheap for sequence/direct/hash engines.
+        # None-check cheap for sequence/direct/hash engines.
         self._cold = None  # io.coldstore.ColdStore
-        self._promoter = None  # io.coldstore.ColdPromoter
+        self._cold_writer = None  # io.coldstore.SegmentWriter
         self._promote = None  # jitted features.online.promote_rows
         self._demote_slots = 0
-        self._cold_pending = set()  # (table, key) enqueued, not landed
-        self._degraded_keys = set()  # served from CMS while cold/in-flight
-        self._cold_index = {}  # table -> sorted uint32 key snapshot
-        self._cold_index_version = -1
+        self._promote_widths = ()  # lane ladder of the promote programs
+        self._degraded_keys = set()  # cold rows lost to a corrupt segment
         self._cold_synced = False
         # Elastic-fleet seams (armed by the CLI, None everywhere else):
         # a threading.Event the launcher's coordinated drain sets via
@@ -785,26 +829,34 @@ class ScoringEngine:
         return ("customer", "terminal")
 
     def _init_cold(self, fcfg) -> None:
-        """Arm the host cold tier: the keyed store, the async promoter
-        thread, the jitted promote-merge step and its telemetry."""
+        """Arm the host cold tier: the keyed store, its segment-writer
+        thread, the jitted promote-merge step, the lane ladder and the
+        telemetry."""
         from real_time_fraud_detection_system_tpu.features.online import (
             promote_rows,
         )
         from real_time_fraud_detection_system_tpu.io.coldstore import (
-            ColdPromoter,
             ColdStore,
+            SegmentWriter,
         )
 
         self._cold = ColdStore(fcfg.cold_store,
                                segment_mb=fcfg.cold_segment_mb)
-        self._promoter = ColdPromoter(self._cold,
-                                      depth=fcfg.cold_promote_queue)
+        self._cold_writer = SegmentWriter(self._cold)
+        self._promote_widths = promote_widths(
+            max(self.cfg.runtime.batch_buckets))
 
         def promote(fstate, payload):
             return promote_rows(fstate, payload, fcfg)
 
         self._promote = jax.jit(promote, donate_argnums=self._donate)
         reg = self.metrics
+        self._m_phase_cold = {
+            ph: reg.histogram(
+                "rtfds_phase_seconds",
+                "per-batch loop-time decomposition by phase", phase=ph)
+            for ph in COLD_PHASES
+        }
         self._m_cold_keys = reg.gauge(
             "rtfds_feature_cold_keys",
             "keys resident in the host cold tier (demoted, not yet "
@@ -819,199 +871,208 @@ class ScoringEngine:
             "rtfds_feature_cold_demotions_total",
             "hot-tier keys demoted to the cold tier by compaction "
             "pressure eviction")
-        self._m_cold_wait = reg.counter(
-            "rtfds_feature_cold_promote_wait_seconds_total",
-            "seconds between a returning key's promotion request and "
-            "its rows landing in the hot tier")
-        self._m_cold_backlog = reg.gauge(
-            "rtfds_feature_cold_promote_backlog",
-            "promotion requests enqueued or resolved but not yet "
-            "landed on device (overload-ladder pressure input)")
-        reg.gauge(
-            "rtfds_feature_cold_promote_queue_limit",
-            "bounded capacity of the cold promoter request queue "
-            "(features.cold_promote_queue)").set(
-            float(fcfg.cold_promote_queue))
+        self._m_cold_rows = reg.counter(
+            "rtfds_feature_cold_rows_total",
+            "rows (not keys) whose customer or terminal was in the cold "
+            "tier when their batch was prepared, so promoted before the "
+            "step that scored them")
+        self._m_cold_lanes = reg.counter(
+            "rtfds_feature_cold_promote_lanes_total",
+            "lanes of the promote programs dispatched, padding included "
+            "(live lanes = rtfds_feature_cold_promotions_total)")
 
-    def _note_cold_touches(self, cols: dict) -> None:
-        """Host-side returning-key detection: the host WROTE the cold
-        store, so it knows exactly which keys are cold — intersect the
-        batch's folded keys with a cached sorted snapshot of the cold
-        index (rebuilt only when the index mutates) and enqueue hits to
-        the promoter. No extra device output, no step-arity change, no
-        stall: the rows are served from CMS this batch (counted in
-        ``exactness_degraded_keys``) and converge to exact state when
-        the promotion lands."""
-        if self._cold is None:
+    def _land_demotions(self, payload: dict) -> None:
+        """Land one compaction pass's demotion payload in the store, here
+        on the loop thread: when ``_maybe_compact`` returns, every
+        demoted key is in the store's index and its rows are readable —
+        before the host prep of any batch dispatched after this pass.
+        The keys' fetch is the wait for the pass itself; the rows' copies
+        off the device (up to ``cold_demote_slots x 16 NB`` bytes a
+        table) are started together and only for a table that demoted
+        something. The segment write follows on the writer thread. The
+        sharded engine's stacked ``[n_dev, K, ...]`` leaves need nothing
+        special: the store flattens lanes."""
+        parts = []
+        for table in self._cold_tables():
+            pay = payload.get(table)
+            if pay is None:
+                continue
+            keys = np.asarray(pay[0]).reshape(-1)
+            if not (keys != np.uint32(0xFFFFFFFF)).any():
+                continue  # nothing demoted: the rows are not fetched
+            for leaf in pay[1:]:
+                leaf.copy_to_host_async()
+            parts.append((table, keys, pay[1:]))
+        if not parts:
             return
-        ver = self._cold.version()
-        if ver != self._cold_index_version:
-            self._cold_index = {
-                t: self._cold.index_snapshot(t)
-                for t in self._cold_tables()}
-            self._cold_index_version = ver
+        t0 = time.perf_counter()
+        with self.tracer.span("cold_append"):
+            total = sum(
+                self._cold.append(table, keys,
+                                  *(np.asarray(r) for r in rows),
+                                  flush=False)
+                for table, keys, rows in parts)
+        self._m_phase_cold["cold_append"].observe(time.perf_counter() - t0)
+        self._cold_writer.kick()
+        self._m_cold_dem.inc(total)
+        self._m_cold_keys.set(float(self._cold.keys_count))
+        self._m_cold_bytes.set(float(self._cold.bytes))
+
+    def _promote_lanes(self, table: str, keys: np.ndarray,
+                       rows: tuple) -> list:
+        """Resolved cold rows of one table → the payloads to dispatch,
+        each ``(width, {"customer": lanes|None, "terminal": ...})`` with
+        ``lanes = (keys [W], bd, cnt, amt, frd [W, NB])``,
+        ``EMPTY_KEY``-padded to the smallest precompiled width that
+        holds them (more keys than the widest: several payloads). The
+        sharded engine overrides with owner-grouped ``[n_dev, W, ...]``
+        leaves."""
+        top = self._promote_widths[-1]
+        out = []
+        for lo in range(0, keys.size, top):
+            n = min(top, keys.size - lo)
+            w = next(w for w in self._promote_widths if w >= n)
+            lanes = blank_lanes((w,), rows)
+            for lane, src in zip(lanes, (keys,) + rows):
+                lane[:n] = src[lo:lo + n]
+            out.append((w, one_table_payload(table, lanes)))
+        return out
+
+    def _returning_keys(self, cols: dict):
+        """Host prep's cold-tier part: which of this batch's keys sit in
+        the cold store. The host WROTE the store, so it knows: one
+        sorted-index lookup a table. → ``{table: unique keys}``, or
+        None: the tier is not armed, or (the quiet batch) nothing
+        returns and nothing more is done."""
+        if self._cold is None:
+            return None
         from real_time_fraud_detection_system_tpu.core.batch import (
             fold_key,
         )
 
+        t0 = time.perf_counter()
+        hits, cold_row = {}, None
         for table, col in (("customer", "customer_id"),
                            ("terminal", "terminal_id")):
-            snap = self._cold_index.get(table)
-            if snap is None or not snap.size:
-                continue
             ids = cols.get(col)
-            if ids is None or not len(ids):
+            if (table not in self._cold_tables() or ids is None
+                    or not len(ids)):
                 continue
             keys = fold_key(np.asarray(ids))
             # the directory canonicalizes EMPTY_KEY collisions the same
             # way (ops/keydir._canon) — mirror it or miss those keys
             keys = np.where(keys == np.uint32(0xFFFFFFFF),
                             np.uint32(0xFFFFFFFE), keys)
-            for k in np.unique(keys[np.isin(keys, snap)]):
-                ki = int(k)
-                self._degraded_keys.add((table, ki))
-                if (table, ki) in self._cold_pending:
-                    continue  # already in flight
-                if self._promoter.request(table, ki):
-                    self._cold_pending.add((table, ki))
-                # full queue: dropped — the key re-enqueues on its
-                # next touch (bounded backpressure, never unbounded)
-        self._m_cold_backlog.set(float(self._promoter.backlog()))
+            mask = self._cold.cold_mask(table, keys)
+            if mask.any():
+                hits[table] = np.unique(keys[mask])
+                cold_row = mask if cold_row is None else cold_row | mask
+        t1 = time.perf_counter()
+        self.tracer.add_span("cold_detect", t0, t1)
+        self._m_phase_cold["cold_detect"].observe(t1 - t0)
+        if not hits:
+            return None
+        self._m_cold_rows.inc(int(cold_row.sum()))
+        return hits
 
-    def _append_demotions(self, payload: dict) -> None:
-        """Land one compaction pass's demotion payload in the cold
-        store. Normalizes the sharded stacked ``[n_dev, K, ...]`` leaves
-        to flat rows; ``EMPTY_KEY`` lanes are skipped by the store. A
-        demoted key with a promotion in flight has that promotion
-        CANCELLED (its resolved rows pre-date this demotion): the next
-        touch re-detects and promotes the fresh rows."""
-        if self._cold is None:
-            return
-        total = 0
-        for table in ("customer", "terminal"):
-            pay = payload.get(table)
-            if pay is None:
-                continue
-            keys, bd, cnt, amt, frd = (np.asarray(x) for x in pay)
-            if keys.ndim > 1:  # sharded stacked payload
-                keys = keys.reshape(-1)
-                bd = bd.reshape(-1, bd.shape[-1])
-                cnt = cnt.reshape(-1, cnt.shape[-1])
-                amt = amt.reshape(-1, amt.shape[-1])
-                frd = frd.reshape(-1, frd.shape[-1])
-            total += self._cold.append(table, keys, bd, cnt, amt, frd)
-            for k in keys[keys != np.uint32(0xFFFFFFFF)]:
-                self._cold_pending.discard((table, int(k)))
-        if total:
-            self._m_cold_dem.inc(total)
-        self._m_cold_keys.set(float(self._cold.keys_count))
-        self._m_cold_bytes.set(float(self._cold.bytes))
+    def _promote_returning(self, hits: dict) -> list:
+        """Promote before score: the exact window rows of every returning
+        key of the batch about to be dispatched go back into the hot tier
+        through a precompiled ``("promote", table, width)`` program,
+        dispatched BEFORE that batch's step — device order is dispatch
+        order, so the rows are resident when the step reads them. A batch
+        with no returning key never gets here. → ``[(table, stats)]`` of
+        the promotes dispatched, for the batch's handle:
+        ``_check_promotes`` reads them when the batch is finished.
 
-    def _build_promote_payload(self, rows_by_table: dict) -> dict:
-        """Resolved cold rows → the ONE fixed-shape promote payload the
-        compiled ``("promote",)`` signature accepts (``EMPTY_KEY``-padded
-        ``[K, ...]`` per present table). The sharded engine overrides
-        with owner-modulo-grouped ``[n_dev, K, ...]`` leaves."""
-        k = self._demote_slots
-        nb = self.cfg.features.n_day_buckets
-        tables = self._cold_tables()
-        payload = {}
-        for table in ("customer", "terminal"):
-            if table not in tables:
-                payload[table] = None
-                continue
-            keys = np.full((k,), 0xFFFFFFFF, np.uint32)
-            bd = np.full((k, nb), -1, np.int32)
-            cnt = np.zeros((k, nb), np.float32)
-            amt = np.zeros((k, nb), np.float32)
-            frd = np.zeros((k, nb), np.float32)
-            for i, (key, r) in enumerate(
-                    (rows_by_table.get(table) or {}).items()):
-                keys[i] = key
-                bd[i], cnt[i], amt[i], frd[i] = r
-            payload[table] = (keys, bd, cnt, amt, frd)
-        return payload
+        The one way a detected key is NOT promoted is a corrupt segment
+        (quarantined by the store): its keys are counted in
+        ``exactness_degraded_keys`` and served from the sketch, as a key
+        never demoted but unadmitted would be."""
+        from real_time_fraud_detection_system_tpu.io.coldstore import (
+            ColdStoreCorruptError,
+        )
 
-    def _maybe_promote(self) -> None:
-        """Land resolved promotions between device steps (called once
-        per finished batch right after ``_maybe_compact`` — the same
-        single-threaded contract). Drains the promoter's ready queue up
-        to the payload width, dispatches the compiled ``("promote",)``
-        signature, and retires landed keys from the cold index."""
-        if self._promoter is None:
-            return
-        k = self._demote_slots
-        ready = self._promoter.poll_ready(max_items=k)
-        self._m_cold_backlog.set(float(self._promoter.backlog()))
-        if not ready:
-            return
-        rows_by_table: dict = {"customer": {}, "terminal": {}}
-        wait = 0.0
-        now = time.perf_counter()
-        for table, key, rows, t_enq in ready:
-            if (table, key) not in self._cold_pending:
-                continue  # cancelled (re-demoted mid-flight) or fenced
-            self._cold_pending.discard((table, key))
-            wait += now - t_enq
-            if rows is None:
-                continue  # corrupt/missing segment: stays on CMS, counted
-            rows_by_table[table][key] = rows
-        if wait > 0.0:
-            self._m_cold_wait.inc(wait)
-        if not any(rows_by_table.values()):
-            return
-        payload = self._build_promote_payload(rows_by_table)
-        with self.tracer.span("state_promote"):
-            with self._recompile.step(step_signature(
-                    static=(self.kind, "promote"))):
-                fstate, stats = self._dispatch_step(
-                    ("promote",), self._promote,
-                    self.state.feature_state, payload)
-        self.state.feature_state = fstate
-        st = np.asarray(stats).reshape(-1, 2, 2).sum(axis=0)
-        self._m_cold_prom.inc(int(st[:, 0].sum()))
-        for i, table in enumerate(("customer", "terminal")):
-            landed = list(rows_by_table[table])
-            if not landed:
-                continue
-            if int(st[i, 1]) == 0:
-                # every lane admitted: retire the keys from the index
-                # (stops re-detection; segment bytes stay until gc)
-                self._cold.mark_promoted(table, landed)
-            # else: the free list ran dry for some lane — keys stay
-            # cold and re-promote on their next touch (the merge is
-            # idempotent, so the already-admitted ones are harmless)
-        self._m_cold_keys.set(float(self._cold.keys_count))
-        self._m_cold_bytes.set(float(self._cold.bytes))
-
-    def drain_promotions(self, timeout_s: float = 10.0) -> bool:
-        """Block until every pending cold promotion has landed (test &
-        shutdown helper — never called from the serving loop). Returns
-        True when pending drained within the timeout."""
-        if self._promoter is None:
-            return True
         t0 = time.perf_counter()
-        while self._cold_pending:
-            self._maybe_promote()
-            if not self._cold_pending:
-                break
-            if time.perf_counter() - t0 > timeout_s:
-                return False
-            # rtfdslint: disable=blocking-call-on-loop-thread (drain helper blocks BY CONTRACT; tests/shutdown only, never reachable from the serving loop)
-            time.sleep(0.005)
-        return True
+        checks = []
+        with self.tracer.span("state_promote"):
+            for table, keys in hits.items():
+                while True:
+                    # a corrupt segment quarantines itself on its first
+                    # touch, so the retry terminates: one per segment
+                    try:
+                        found, *rows = self._cold.read_rows(table, keys)
+                        break
+                    except ColdStoreCorruptError as e:
+                        from real_time_fraud_detection_system_tpu.utils \
+                            import get_logger
+
+                        get_logger("engine").error(
+                            "cold tier: %s — its keys are served from the "
+                            "sketch (exactness_degraded_keys)", e)
+                lost = keys[~found]
+                self._degraded_keys.update(
+                    (table, int(k)) for k in lost)  # corruption only
+                keys = keys[found]
+                if not keys.size:
+                    continue
+                for width, payload in self._promote_lanes(
+                        table, keys, tuple(rows)):
+                    with self._recompile.step(step_signature(
+                            static=(self.kind, "promote", table, width))):
+                        fstate, stats = self._dispatch_step(
+                            ("promote", table, width), self._promote,
+                            self.state.feature_state, payload)
+                    self.state.feature_state = fstate
+                    checks.append((table, stats))
+                    self._m_cold_lanes.inc(
+                        int(np.prod(payload[table][0].shape)))
+                self._cold.mark_promoted(table, keys)
+                self._m_cold_prom.inc(int(keys.size))
+        self._m_phase_cold["state_promote"].observe(
+            time.perf_counter() - t0)
+        self._m_cold_keys.set(float(self._cold.keys_count))
+        self._m_cold_bytes.set(float(self._cold.bytes))
+        return checks
+
+    def _check_promotes(self, handle: dict) -> None:
+        """Before a batch's result is built: every lane of the promotes
+        dispatched ahead of its step was admitted. A lane the directory
+        could not admit (the free stack ran dry, or all its probe
+        positions were taken) means the row was scored from the sketch:
+        the run stops here, before that batch is delivered, rather than
+        hand on an inexact row as exact."""
+        for table, stats in handle.pop("promote_checks", ()):
+            dropped = int(np.asarray(stats).reshape(-1, 2, 2)[:, :, 1].sum())
+            if dropped:
+                raise ColdPromoteError(
+                    f"cold tier: {dropped} returning {table} key(s) could "
+                    "not be admitted to the hot tier before their rows "
+                    "were scored (free slots ran out between compaction "
+                    "passes). Size the tier so that cold_demote_slots x "
+                    "passes keeps occupancy under cold_highwater: raise "
+                    "cold_demote_slots, lower compact_every or "
+                    "cold_highwater, or add slots (README, Cold tier)")
+
+    def _settle_cold(self) -> None:
+        """Everything demoted so far is in the store and durable, and the
+        state carries the lineage a checkpoint save will record."""
+        self._cold_writer.wait()
+        self._cold.flush()  # what the buffer still holds, full or not
+        self.state.cold_lineage = self._cold.lineage()
 
     def _sync_cold_after_restore(self) -> None:
         """Adopt a restored checkpoint's cold lineage exactly once:
         prune post-checkpoint segments (replay regenerates them —
-        exactly-once across the tier boundary), fence the promoter
-        generation, and drop in-flight pending state."""
+        exactly-once across the tier boundary) and drop what was on its
+        way to the store."""
         if self._cold is None or self._cold_synced:
             return
         lineage = getattr(self.state, "cold_lineage", None)
         if lineage is None:
             return
         self._cold_synced = True
+        self._cold_writer.wait()
         self._cold.sync_to(lineage)
         topo = getattr(self, "topology", None)
         if topo is not None and topo.n_processes > 1:
@@ -1032,12 +1093,8 @@ class ScoringEngine:
                     "cold tier re-homed for process %d/%d: dropped %d "
                     "foreign key(s)", topo.process_id,
                     topo.n_processes, dropped)
-        self._promoter.reset()
-        self._cold_pending.clear()
-        self._cold_index_version = -1
         self._m_cold_keys.set(float(self._cold.keys_count))
         self._m_cold_bytes.set(float(self._cold.bytes))
-        self._m_cold_backlog.set(0.0)
 
     def checkpoint_state(self) -> EngineState:
         """The state a checkpoint save should persist. With a terminal-
@@ -1124,11 +1181,11 @@ class ScoringEngine:
                 out = self._dispatch_step(
                     ("compact",), self._compact,
                     self.state.feature_state, day)
-        if self._demote_slots:
-            fstate, reclaimed, payload = out
-            self._append_demotions(payload)
-        else:
-            fstate, reclaimed = out
+            if self._demote_slots:
+                fstate, reclaimed, payload = out
+                self._land_demotions(payload)
+            else:
+                fstate, reclaimed = out
         self.state.feature_state = fstate
         self._record_compaction(fstate, reclaimed)
         self._m_compactions.inc()
@@ -1161,7 +1218,6 @@ class ScoringEngine:
                 extra = {
                     "cold_keys": int(self._cold.keys_count),
                     "cold_bytes": int(self._cold.bytes),
-                    "promote_backlog": int(self._promoter.backlog()),
                 }
             recorder.record_event(
                 "feature_state", reclaimed=rec_now,
@@ -1246,42 +1302,38 @@ class ScoringEngine:
                 emit_dtype=self.cfg.runtime.emit_dtype,
                 use_pallas=False,
             ))
-        if self._demote_slots:
-            # Cold-tier promotion landing is a compiled family member
-            # too: ONE shape (the full state + the EMPTY_KEY-padded
-            # [K, NB] payload per table), so an async promotion can land
-            # mid-stream without a recompile or a device stall.
-            sigs.append(DispatchSignature(
-                key=("promote",),
+        sigs.extend(self._promote_signatures(tuple(self._donate)))
+        return sigs
+
+    def _promote_signatures(self, donate: tuple) -> list:
+        """The cold tier's promote programs as inventory entries: one a
+        table with a directory and a width of the lane ladder, keyed as
+        ``_promote_returning`` dispatches them, so a returning key never
+        pays a mid-stream compile whatever the batch brings back."""
+        return [
+            DispatchSignature(
+                key=("promote", table, int(w)),
                 variant="promote",
                 kind=self.kind,
                 z_mode=None,
-                bucket=0,
-                donate=tuple(self._donate),
+                bucket=int(w),
+                donate=donate,
                 selective=False,
                 emit_dtype=self.cfg.runtime.emit_dtype,
                 use_pallas=False,
-            ))
-        return sigs
-
-    def _promote_payload_sds(self) -> dict:
-        """Shape-only template of the promote payload (the sharded
-        engine overrides with its stacked per-shard layout)."""
-        k = self._demote_slots
-        nb = self.cfg.features.n_day_buckets
-        tables = self._cold_tables()
-
-        def tbl():
-            return (
-                jax.ShapeDtypeStruct((k,), jnp.uint32),
-                jax.ShapeDtypeStruct((k, nb), jnp.int32),
-                jax.ShapeDtypeStruct((k, nb), jnp.float32),
-                jax.ShapeDtypeStruct((k, nb), jnp.float32),
-                jax.ShapeDtypeStruct((k, nb), jnp.float32),
             )
+            for table in (self._cold_tables() if self._demote_slots else ())
+            for w in self._promote_widths
+        ]
 
-        return {t: (tbl() if t in tables else None)
-                for t in ("customer", "terminal")}
+    def _promote_payload_sds(self, table: str, width: int) -> dict:
+        """Shape-only template of one promote payload (the sharded
+        engine overrides with its stacked per-shard layout)."""
+        nb = self.cfg.features.n_day_buckets
+        lanes = (jax.ShapeDtypeStruct((width,), jnp.uint32),
+                 jax.ShapeDtypeStruct((width, nb), jnp.int32)) + (
+            jax.ShapeDtypeStruct((width, nb), jnp.float32),) * 3
+        return one_table_payload(table, lanes)
 
     def signature_templates(self, sig: DispatchSignature) -> tuple:
         """Shape-only argument templates for ``sig`` — what
@@ -1298,7 +1350,7 @@ class ScoringEngine:
         if sig.variant == "promote":
             return (
                 self._sds(self.state.feature_state),
-                self._promote_payload_sds(),
+                self._promote_payload_sds(sig.key[1], sig.key[2]),
             )
         return (
             self._sds(self.state.feature_state),
@@ -1706,6 +1758,7 @@ class ScoringEngine:
             keep = latest_wins_mask_host(cols["tx_id"], cols["kafka_ts_ms"])
             cols = {k: v[keep] for k, v in cols.items()}
             validate_ingest_rows(cols)
+            returning = self._returning_keys(cols)
             n = len(cols["tx_id"])
             pad = bucket_size(n, self.cfg.runtime.batch_buckets)
             if use_native:
@@ -1726,6 +1779,9 @@ class ScoringEngine:
             # t1 sits after ALL host packing on both paths, so
             # prep_s/dispatch_s attribute the same stages either way
             t1 = time.perf_counter()
+        promoted = (self._promote_returning(returning)
+                    if returning is not None else ())
+        t1b = time.perf_counter()
         pre_state = None
         if self._nan_guard:
             # Donation is off under the guard, so these references stay
@@ -1753,7 +1809,6 @@ class ScoringEngine:
             self.state.feature_state = fstate
             self.state.params = params
             self._note_batch_days(cols)
-            self._note_cold_touches(cols)
             # Start the D2H copies NOW (they queue behind the step's
             # compute): by the time _finish_batch blocks, the transfer
             # has been running since compute finished.
@@ -1761,13 +1816,15 @@ class ScoringEngine:
             t2 = time.perf_counter()
         return {"cols": cols, "n": n, "probs": probs, "feats": feats,
                 "tier": tier, "t0": t0, "prep_s": t1 - t0,
-                "dispatch_s": t2 - t1, "pre_state": pre_state,
-                "fetch_issue_t": t_fetch}
+                "dispatch_s": t2 - t1b, "pre_state": pre_state,
+                "fetch_issue_t": t_fetch,
+                "promote_checks": promoted}
 
     def _finish_batch(self, handle: dict) -> BatchResult:
         """Block on the handle's device futures; build the BatchResult."""
         n = handle["n"]
         self._meter_fetch_overlap(handle)
+        self._check_promotes(handle)
         if self._selective:
             probs_np, feats_np = self._unpack_selective(handle)
             return self._finish_result(handle, probs_np, feats_np)
@@ -1938,7 +1995,6 @@ class ScoringEngine:
         self._m_rows.inc(n)
         self._m_last.set(time.time())
         self._maybe_compact()
-        self._maybe_promote()
         # Device-memory gauges ride the batch cadence; on backends
         # without memory stats (CPU) this is a single boolean check.
         self._devmem.sample()
@@ -2181,6 +2237,13 @@ class ScoringEngine:
         # host store to it (prune post-checkpoint segments, fence the
         # promoter) BEFORE any batch can touch a demoted key.
         self._sync_cold_after_restore()
+        if (self._cold is not None and self._cold.ephemeral
+                and checkpointer is not None):
+            raise ValueError(
+                f"cold_store={self._cold.url!r} is a fresh store that is "
+                "gone with this process: a checkpoint's cold lineage "
+                "could not be restored from it. Give --cold-store a "
+                "directory or an s3:// location to run with checkpoints")
         if self.cfg.runtime.precompile and not self._aot:
             # AOT bucket precompilation: every bucket size compiles NOW,
             # before the first poll — no first-touch compile ever lands
@@ -2462,8 +2525,7 @@ class ScoringEngine:
                     # the lineage the checkpoint records is on disk, and
                     # restore can rebuild the exact cold index from
                     # manifests alone.
-                    self._cold.flush()
-                    self.state.cold_lineage = self._cold.lineage()
+                    self._settle_cold()
                 self._maybe_exchange_cms()
                 checkpointer.save(self.checkpoint_state())
                 # Broker-side offsets (sources that have them, e.g. Kafka)
@@ -2704,11 +2766,9 @@ class ScoringEngine:
                 writer.stop()
         self._m_qdepth.set(0)
         if self._cold is not None:
-            # Land in-flight promotions and persist buffered demotions so
-            # the caller's follow-up save records fresh segment lineage.
-            self.drain_promotions()
-            self._cold.flush()
-            self.state.cold_lineage = self._cold.lineage()
+            # Persist buffered demotions so the caller's follow-up save
+            # records fresh segment lineage.
+            self._settle_cold()
         wall = time.perf_counter() - t_start
         cpu_s = time.process_time() - t_cpu0
         # LatencyTracker-backed snapshots: exact percentiles over the
@@ -2747,10 +2807,10 @@ class ScoringEngine:
             # emit_threshold or raise emit_cap_fraction)
             stats["selective_overflows"] = self.selective_overflows - ovf0
         if self._cold is not None:
-            # Keys scored from the CMS sketch while their promotion was
-            # still in flight — the honest scope of the bit-identity
-            # claim. 0 means every returning key converged before it was
-            # touched again (or was never demoted).
+            # Returning keys scored from the CMS sketch because their
+            # cold rows were lost to a corrupt segment — the honest scope
+            # of the exactness claim. A sound store reads 0: every
+            # returning key is promoted before its row is scored.
             stats["exactness_degraded_keys"] = (
                 len(self._degraded_keys) - degraded0)
         return stats

@@ -230,16 +230,6 @@ class OverloadController:
             depth_total = self.reg.family_total("rtfds_sink_queue_depth")
             if depth_total is not None:
                 comps["sink_fill"] = depth_total / sink_cap
-        # Cold-promotion storm (features.cold_store): a promoter backlog
-        # pinned at its bounded queue depth means returning keys are
-        # arriving faster than promotions can land — the sketch serves
-        # them degraded meanwhile, and the host is doing segment reads at
-        # full tilt. Same normalized fill shape as the queue signals.
-        q_limit = self.reg.get("rtfds_feature_cold_promote_queue_limit")
-        if q_limit is not None and q_limit.value > 0:
-            backlog = self.reg.get("rtfds_feature_cold_promote_backlog")
-            if backlog is not None:
-                comps["cold_promote"] = backlog.value / q_limit.value
         return (max(comps.values()) if comps else 0.0), comps
 
     def _note_lag(self, lag: float) -> None:

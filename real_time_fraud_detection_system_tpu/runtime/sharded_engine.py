@@ -68,6 +68,8 @@ from real_time_fraud_detection_system_tpu.parallel.step import (
 from real_time_fraud_detection_system_tpu.runtime.engine import (
     BatchResult,
     ScoringEngine,
+    blank_lanes,
+    one_table_payload,
 )
 from real_time_fraud_detection_system_tpu.utils.xla_telemetry import (
     step_signature,
@@ -506,7 +508,6 @@ class ShardedScoringEngine(ScoringEngine):
                 extra = {
                     "cold_keys": int(self._cold.keys_count),
                     "cold_bytes": int(self._cold.bytes),
-                    "promote_backlog": int(self._promoter.backlog()),
                 }
             recorder.record_event(
                 "feature_state", reclaimed=int(rec.sum()),
@@ -519,57 +520,43 @@ class ShardedScoringEngine(ScoringEngine):
 
     # -- cold tier over the mesh -------------------------------------------
 
-    def _promote_payload_sds(self) -> dict:
-        """Stacked per-shard promote-payload template: ``[n_dev, K]``
-        keys / ``[n_dev, K, NB]`` rows per present table (the shard_map
+    def _promote_payload_sds(self, table: str, width: int) -> dict:
+        """Stacked per-shard promote-payload template: ``[n_dev, W]``
+        keys / ``[n_dev, W, NB]`` rows for the one table (the shard_map
         splits the leading device axis)."""
-        k = self._demote_slots
         nb = self.cfg.features.n_day_buckets
         n = self.n_dev
-        tables = self._cold_tables()
+        lanes = (jax.ShapeDtypeStruct((n, width), jnp.uint32),
+                 jax.ShapeDtypeStruct((n, width, nb), jnp.int32)) + (
+            jax.ShapeDtypeStruct((n, width, nb), jnp.float32),) * 3
+        return one_table_payload(table, lanes)
 
-        def tbl():
-            return (
-                jax.ShapeDtypeStruct((n, k), jnp.uint32),
-                jax.ShapeDtypeStruct((n, k, nb), jnp.int32),
-                jax.ShapeDtypeStruct((n, k, nb), jnp.float32),
-                jax.ShapeDtypeStruct((n, k, nb), jnp.float32),
-                jax.ShapeDtypeStruct((n, k, nb), jnp.float32),
-            )
-
-        return {t: (tbl() if t in tables else None)
-                for t in ("customer", "terminal")}
-
-    def _build_promote_payload(self, rows_by_table: dict) -> dict:
-        """Owner-modulo-grouped promote payload: key ``k`` lands in
+    def _promote_lanes(self, table: str, keys: np.ndarray,
+                       rows: tuple) -> list:
+        """Owner-modulo-grouped promote payloads: key ``k`` lands in
         shard ``k % n_dev``'s lane block — the same stable modulo the
         ingest partitioner and the owner exchange route by, so a key
         demoted by shard *i* promotes back into shard *i*'s directory.
-        ``poll_ready(max_items=K)`` bounds total keys at the per-shard
-        lane width, so even a fully-skewed ready set fits one block."""
-        k = self._demote_slots
-        nb = self.cfg.features.n_day_buckets
-        n = self.n_dev
-        tables = self._cold_tables()
-        payload = {}
-        for table in ("customer", "terminal"):
-            if table not in tables:
-                payload[table] = None
-                continue
-            keys = np.full((n, k), 0xFFFFFFFF, np.uint32)
-            bd = np.full((n, k, nb), -1, np.int32)
-            cnt = np.zeros((n, k, nb), np.float32)
-            amt = np.zeros((n, k, nb), np.float32)
-            frd = np.zeros((n, k, nb), np.float32)
-            fill = [0] * n
-            for key, r in (rows_by_table.get(table) or {}).items():
-                s = int(key) % n
-                i = fill[s]
-                fill[s] = i + 1
-                keys[s, i] = key
-                bd[s, i], cnt[s, i], amt[s, i], frd[s, i] = r
-            payload[table] = (keys, bd, cnt, amt, frd)
-        return payload
+        A payload is as wide as its fullest shard's block needs; a
+        shard with more keys than the widest program holds spreads them
+        over several payloads."""
+        n, top = self.n_dev, self._promote_widths[-1]
+        owner = (keys % np.uint32(n)).astype(np.int64)
+        order = np.argsort(owner, kind="stable")
+        counts = np.bincount(owner, minlength=n)
+        shard = owner[order]
+        lane = np.arange(keys.size) - (np.cumsum(counts) - counts)[shard]
+        out = []
+        for lo in range(0, int(counts.max()), top):
+            pick = (lane >= lo) & (lane < lo + top)
+            w = next(w for w in self._promote_widths
+                     if w >= min(int(counts.max()) - lo, top))
+            at, src = (shard[pick], lane[pick] - lo), order[pick]
+            lanes = blank_lanes((n, w), rows)
+            for block, rows_of in zip(lanes, (keys,) + rows):
+                block[at] = rows_of[src]
+            out.append((w, one_table_payload(table, lanes)))
+        return out
 
     # -- sharding upkeep ---------------------------------------------------
 
@@ -743,22 +730,7 @@ class ShardedScoringEngine(ScoringEngine):
                 emit_dtype=self.cfg.runtime.emit_dtype,
                 use_pallas=False,
             ))
-        if self._demote_slots:
-            # Cold-tier promotion over the mesh: ONE fixed shape (the
-            # sharded state + the owner-grouped [n_dev, K, ...] payload
-            # blocks) — enumerated so warmup compiles it and a returning
-            # key can never pay a mid-stream compile.
-            sigs.append(DispatchSignature(
-                key=("promote",),
-                variant="promote",
-                kind=self.kind,
-                z_mode=None,
-                bucket=0,
-                donate=(0,),
-                selective=False,
-                emit_dtype=self.cfg.runtime.emit_dtype,
-                use_pallas=False,
-            ))
+        sigs.extend(self._promote_signatures((0,)))
         return sigs
 
     def _ensure_step(self, routed: bool):
@@ -887,6 +859,7 @@ class ShardedScoringEngine(ScoringEngine):
             keep = latest_wins_mask_host(cols["tx_id"], cols["kafka_ts_ms"])
             cols = {k: v[keep] for k, v in cols.items()}
             self._validate_sharded(cols)
+            returning = self._returning_keys(cols)
             n = len(cols["tx_id"])
             self._ensure_sharded()
             if n:
@@ -909,6 +882,10 @@ class ShardedScoringEngine(ScoringEngine):
                 ) if n else []
             self._m_phase_mesh["partition"].observe(
                 time.perf_counter() - t_part)
+        t_prepped = time.perf_counter()
+        # promote before score, ahead of the first chunk's step
+        promoted = (self._promote_returning(returning)
+                    if returning is not None else ())
         # host prep ends here: the chunk loop below is dispatch (make_
         # batch + H2D + jit launches), split out so the sharded loop's
         # phase decomposition matches the single-chip engine's.
@@ -1002,7 +979,7 @@ class ShardedScoringEngine(ScoringEngine):
             self.tracer.add_span("dispatch", t_prep, t_disp,
                                  chunks=len(chunks))
         handle = {"cols": cols, "n": n, "parts": parts, "t0": t0,
-                  "prep_s": t_prep - t0, "dispatch_s": t_disp - t_prep,
+                  "prep_s": t_prepped - t0, "dispatch_s": t_disp - t_prep,
                   "fetch_issue_t": t_fetch}
         if tier_parts:
             handle["tier_shard"] = tier_parts
@@ -1011,12 +988,13 @@ class ShardedScoringEngine(ScoringEngine):
         # notify compaction's recency cutoff (the base engine does this
         # in its own _start_batch; the sharded path overrides it wholesale)
         self._note_batch_days(cols)
-        self._note_cold_touches(cols)
+        handle["promote_checks"] = promoted
         return handle
 
     def _finish_batch(self, handle: dict) -> BatchResult:
         n = handle["n"]
         self._meter_fetch_overlap(handle)
+        self._check_promotes(handle)
         # _emit_features_now, not the raw config flag: the overload
         # ladder's rung-2 degrade (inherited run() loop) switches the
         # mesh engine to alerts-only emission the same host-side way —

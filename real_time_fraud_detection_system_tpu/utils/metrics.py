@@ -803,8 +803,8 @@ class MetricsServer:
                     fstate["budget_bytes"] = budget.value
                     fstate["budget_used"] = round(
                         sb.value / budget.value, 4)
-            # Host cold tier (features.cold_store): depth, promotion
-            # traffic and the promoter backlog — present only once an
+            # Host cold tier (features.cold_store): depth and promotion
+            # traffic — present only once an
             # engine armed the cold store, so two-tier runs keep the
             # block absent rather than zero-filled.
             ck = self.registry.get("rtfds_feature_cold_keys")
@@ -816,12 +816,9 @@ class MetricsServer:
                          "promotions"),
                         ("rtfds_feature_cold_demotions_total",
                          "demotions"),
-                        ("rtfds_feature_cold_promote_wait_seconds_total",
-                         "promote_wait_seconds"),
-                        ("rtfds_feature_cold_promote_backlog",
-                         "promote_backlog"),
-                        ("rtfds_feature_cold_promote_queue_limit",
-                         "promote_queue_limit")):
+                        ("rtfds_feature_cold_rows_total", "rows"),
+                        ("rtfds_feature_cold_promote_lanes_total",
+                         "promote_lanes")):
                     m = self.registry.get(name)
                     if m is not None:
                         cold[key] = m.value

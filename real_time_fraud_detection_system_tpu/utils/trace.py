@@ -364,6 +364,9 @@ STEP_SCOPES = (
     "keydir", "cms",                               # ops/keydir, ops/cms
     "lookup", "claim", "grant",                    # the parts of keydir
     "compact",                                     # features/online
+    # the cold tier's two: the demote pass's selection and payload gather
+    # inside compact, and the whole ("promote", table, width) program
+    "demote", "promote",                           # features/online
     "assemble",                                    # features/online
     "scale",                                       # models/scaler
     "classify", "fused_step", "learn", "emit",     # engine.step

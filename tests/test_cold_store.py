@@ -6,13 +6,16 @@ Three layers of contract, each tested here:
   rebuilds the key index from segment manifests alone; newest-wins on
   re-demotion; byte-flipped blobs and torn manifests quarantine (typed
   `ColdStoreCorruptError`, never garbage served); promoted segments gc;
-  the promoter queue is bounded and poison-isolates corrupt segments.
+  the batch read equals the per-key read; the writer thread writes full
+  buffers as segments and ships worker errors typed.
 - **Engine round-trip bit-identity**: a key demoted by compaction
-  pressure, re-touched (served degraded from CMS, promotion enqueued
-  async), then promoted back is BIT-identical — features and probs — to
-  a never-evicted control, at both the AOT (`--precompile`) and plain
-  jit levels, with ZERO mid-stream recompiles (the `("promote",)`
-  dispatch signature is part of the precompiled inventory).
+  pressure and touched again is promoted BEFORE the step that scores
+  that row: every batch, the returning one included, is BIT-identical —
+  features and probs — to a never-evicted control, at both the AOT
+  (`--precompile`) and plain jit levels, with ZERO mid-stream recompiles
+  (the `("promote", table, width)` signatures are part of the
+  precompiled inventory). The wider matrix (pipeline depth, the mesh,
+  many keys a batch, the orderings) is `tests/test_cold_exact.py`.
 - **Sharded ≡ single**: the same flow through the mesh engine
   (per-shard demote, owner-modulo promote grouping) matches a
   single-chip never-evicted control bit-exactly.
@@ -34,7 +37,7 @@ from real_time_fraud_detection_system_tpu.config import (
     RuntimeConfig,
 )
 from real_time_fraud_detection_system_tpu.io.coldstore import (
-    ColdPromoter,
+    SegmentWriter,
     ColdStore,
     ColdStoreCorruptError,
     consolidate_cold_stores,
@@ -162,7 +165,8 @@ def test_consolidate_then_rehome_bit_identity(tmp_path):
                                 str(tmp_path / "merged"))
     # grow back out: process 1 of 2 adopts only odd keys
     merged.rehome(lambda _t, ks: ks % 2 == 1)
-    assert sorted(k for (_t, k) in merged._index) == [1, 3, 7]
+    assert sorted(merged.index_snapshot("customer").tolist()
+                  + merged.index_snapshot("terminal").tolist()) == [1, 3, 7]
     np.testing.assert_array_equal(
         merged.get_rows("customer", [3])[3][2], rows_b[2][1])
 
@@ -242,39 +246,75 @@ def test_store_torn_manifest_and_orphan_blob(tmp_path):
     assert 1 in cs2.get_rows("customer", [1])
 
 
-def test_promoter_poison_isolation_and_bounded_queue(tmp_path):
-    """The promoter surfaces a corrupt segment's key with rows=None
-    (pending clears, key degrades to CMS honestly) instead of wedging;
-    the request queue is bounded — a full queue drops the request."""
-    import time
+def test_writer_thread_durability_and_typed_errors(tmp_path):
+    """Appended rows are readable at once, from memory; the segment
+    write (the durable copy) happens on the writer thread, once a
+    request finds the buffer full, and ``wait`` blocks until it has; a
+    worker-side failure re-raises on the caller's thread with its own
+    type; and a corrupt segment poisons only its own keys (typed error
+    once, then misses)."""
+    import threading
 
     d = str(tmp_path / "cold")
-    cs = ColdStore(d)
-    cs.append("customer", [11], *_rows(8, 1))
+    cs = ColdStore(d, segment_mb=0.0001)
+    gate = threading.Event()
+    real_flush = cs.flush
+
+    def slow_flush():
+        gate.wait(10.0)
+        return real_flush()
+
+    cs.flush = slow_flush
+    writer = SegmentWriter(cs)
+    try:
+        r1, r2 = _rows(8, 3), _rows(9, 2)
+        k1 = np.array([11, 0xFFFFFFFF, 12], np.uint32)
+        assert cs.append("customer", k1, *r1, flush=False) == 2
+        writer.kick()
+        cs.append("customer", np.array([12, 13], np.uint32), *r2,
+                  flush=False)
+        writer.kick()
+        probe = np.array([11, 12, 13, 14], np.uint32)
+        assert cs.cold_mask("customer", probe).tolist() == [
+            True, True, True, False]  # the EMPTY_KEY lane never lands
+        # newest wins: key 12's rows are the second append's
+        np.testing.assert_array_equal(
+            cs.get_rows("customer", [12])[12][0], r2[0][0])
+        assert not [n for n in os.listdir(d) if n.endswith(".json")]
+        gate.set()
+        writer.wait()
+        assert [n for n in os.listdir(d) if n.endswith(".json")]
+        reopened = ColdStore(d)
+        assert reopened.cold_mask("customer", probe).tolist() == [
+            True, True, True, False]
+
+        def boom():
+            raise OSError("disk full")
+
+        cs.flush_if_full = boom
+        cs.append("customer", np.array([20], np.uint32), *_rows(1, 1),
+                  flush=False)
+        writer.kick()
+        with pytest.raises(OSError, match="disk full"):
+            writer.wait()
+        writer.wait()  # raised once, not sticky
+    finally:
+        writer.close()
+
+    # poison isolation: a corrupt segment takes only its own keys along
+    cs.flush, cs.flush_if_full = real_flush, lambda: None
+    cs.flush()
+    cs.append("customer", [30], *_rows(3, 1))
     cs.flush()
     blob = os.path.join(d, "seg-00000000.npz")
     with open(blob, "r+b") as fh:
         fh.seek(10)
         fh.write(b"\xff\xff\xff\xff")
-
-    p = ColdPromoter(ColdStore(d), depth=4)
-    try:
-        assert p.request("customer", 11)
-        ready = []
-        t0 = time.perf_counter()
-        while not ready and time.perf_counter() - t0 < 10.0:
-            ready = p.poll_ready()
-            time.sleep(0.01)
-        assert ready and ready[0][:3] == ("customer", 11, None)
-        assert p.corrupt_skipped == 1
-    finally:
-        p.close()
-
-    # boundedness: with the worker stopped, depth+1 requests overflow
-    p2 = ColdPromoter(ColdStore(d), depth=2)
-    p2.close()
-    assert p2.request("customer", 1) and p2.request("customer", 2)
-    assert not p2.request("customer", 3)  # full queue: dropped, not grown
+    cs2 = ColdStore(d)
+    with pytest.raises(ColdStoreCorruptError):
+        cs2.read_rows("customer", np.array([11, 30], np.uint32))
+    found = cs2.read_rows("customer", np.array([11, 30], np.uint32))[0]
+    assert found.tolist() == [False, True]
 
 
 def test_cold_config_validation():
@@ -284,8 +324,8 @@ def test_cold_config_validation():
         FeatureConfig(cold_store="/tmp/x", compact_every=4)
     with pytest.raises(ValueError, match="compact_every"):
         FeatureConfig(cold_store="/tmp/x", key_mode="exact")
-    with pytest.raises(ValueError, match="cold_promote_queue"):
-        FeatureConfig(cold_promote_queue=0, **ok)
+    with pytest.raises(TypeError):  # the bounded promoter queue is gone
+        FeatureConfig(cold_promote_queue=64, **ok)
     with pytest.raises(ValueError, match="cold_segment_mb"):
         FeatureConfig(cold_segment_mb=0, **ok)
     with pytest.raises(ValueError, match="cold_demote_slots"):
@@ -316,7 +356,7 @@ def _cold_fcfg(tmp_path):
     return dict(customer_capacity=128, terminal_capacity=128,
                 cms_width=1 << 12, key_mode="exact", compact_every=2,
                 cold_store=str(tmp_path / "cold"), cold_demote_slots=16,
-                cold_highwater=0.25, cold_promote_queue=64)
+                cold_highwater=0.25)
 
 
 def _engine(cfg, reg):
@@ -342,19 +382,41 @@ def _cold_batches():
     ]
 
 
+def _assert_promoted_before_scored(eng, reg, precompile=True):
+    """The contract's counters: something was demoted and promoted, no
+    row was served from the sketch, no key degraded, and (under AOT) the
+    promote programs were part of the precompiled inventory."""
+    assert reg.get("rtfds_feature_cold_demotions_total").value > 0
+    assert reg.get("rtfds_feature_cold_promotions_total").value > 0
+    assert reg.get("rtfds_feature_cold_rows_total").value > 0
+    assert not eng._degraded_keys
+    cms = reg.get("rtfds_feature_tier_rows_total", tier="cms")
+    assert cms is None or cms.value == 0
+    if precompile:
+        # zero mid-stream recompiles is the AOT guarantee (plain jit
+        # legitimately compiles a promote width on its first use)
+        rc = reg.get("rtfds_xla_recompiles_total")
+        assert (rc.value if rc else 0) == 0
+        fb = reg.get("rtfds_aot_fallbacks_total")
+        assert (fb.value if fb else 0) == 0
+
+
 @pytest.mark.parametrize("precompile", [True, False],
                          ids=["aot", "jit"])
 def test_engine_demote_miss_promote_bit_identity(tmp_path, precompile):
-    """Demote → miss (CMS-served, counted degraded) → async promote →
-    next touch BIT-identical to a never-evicted control. Under AOT the
-    promote step dispatches through the precompiled ("promote",)
+    """Demote → return → promoted BEFORE scored: the batch that touches
+    an evicted key is itself BIT-identical to a never-evicted control —
+    no batch is served from the sketch first. Under AOT the promote
+    dispatches through a precompiled ("promote", table, width)
     signature: zero recompiles, zero fallbacks."""
     fcfg = _cold_fcfg(tmp_path)
     rt = RuntimeConfig(batch_buckets=(64,), max_batch_rows=64,
                        precompile=precompile)
     reg = MetricsRegistry()
     eng = _engine(Config(features=FeatureConfig(**fcfg), runtime=rt), reg)
-    assert ("promote",) in [s.key for s in eng.dispatch_inventory()]
+    keys = [s.key for s in eng.dispatch_inventory()]
+    assert ("promote", "customer", 64) in keys
+    assert ("promote", "terminal", 64) in keys
     # control: hot tier big enough that nothing is ever evicted
     fc2 = dict(fcfg)
     fc2.update(customer_capacity=4096, terminal_capacity=4096,
@@ -366,40 +428,23 @@ def test_engine_demote_miss_promote_bit_identity(tmp_path, precompile):
         ctrl.precompile()
 
     a, batches = _cold_batches()
-    for cols in batches:
-        eng.process_batch({k: v.copy() for k, v in cols.items()})
-        ctrl.process_batch({k: v.copy() for k, v in cols.items()})
-
-    assert reg.get("rtfds_feature_cold_demotions_total").value > 0
+    batches.append(_cols(a[:32], a[:32] + 10000, DAY0 + 5))
+    for cols in batches:  # the pings included: every batch, every row
+        r_e = eng.process_batch({k: v.copy() for k, v in cols.items()})
+        r_c = ctrl.process_batch({k: v.copy() for k, v in cols.items()})
+        np.testing.assert_array_equal(np.asarray(r_e.features),
+                                      np.asarray(r_c.features))
+        np.testing.assert_array_equal(np.asarray(r_e.probs),
+                                      np.asarray(r_c.probs))
     assert reg.get("rtfds_feature_cold_keys").value > 0
-    # the ping itself was served degraded from CMS and enqueued async
-    assert len(eng._degraded_keys) > 0
-    assert eng.drain_promotions(timeout_s=30.0)
-    assert reg.get("rtfds_feature_cold_promotions_total").value > 0
-
-    # post-promotion touch: BIT-identical to the never-evicted control
-    cols = _cols(a[:16], a[:16] + 10000, DAY0 + 5)
-    r_e = eng.process_batch({k: v.copy() for k, v in cols.items()})
-    r_c = ctrl.process_batch({k: v.copy() for k, v in cols.items()})
-    np.testing.assert_array_equal(np.asarray(r_e.features),
-                                  np.asarray(r_c.features))
-    np.testing.assert_array_equal(np.asarray(r_e.probs),
-                                  np.asarray(r_c.probs))
-
-    if precompile:
-        # zero mid-stream recompiles is the AOT guarantee: the promote
-        # dispatch was part of the precompiled inventory (plain jit
-        # legitimately compiles it on first use)
-        rc = reg.get("rtfds_xla_recompiles_total")
-        assert (rc.value if rc else 0) == 0
-        fb = reg.get("rtfds_aot_fallbacks_total")
-        assert (fb.value if fb else 0) == 0
+    _assert_promoted_before_scored(eng, reg, precompile)
 
 
 def test_sharded_cold_matches_single(tmp_path):
-    """The same demote→miss→promote flow through the mesh engine
-    (per-shard demotions, owner-modulo promote grouping) lands
-    bit-identical probs to a single-chip never-evicted control."""
+    """The same demote → return → promote-before-score flow through the
+    mesh engine (per-shard demotions, owner-modulo promote grouping)
+    lands bit-identical probs to a single-chip never-evicted control on
+    every batch, the returning ones included."""
     from real_time_fraud_detection_system_tpu.runtime import (
         ShardedScoringEngine,
     )
@@ -415,7 +460,8 @@ def test_sharded_cold_matches_single(tmp_path):
         Config(features=FeatureConfig(**fcfg), runtime=rt),
         kind="logreg", params=params, scaler=scaler,
         n_devices=4, metrics=reg)
-    assert ("promote",) in [s.key for s in eng.dispatch_inventory()]
+    assert ("promote", "customer", 64) in [
+        s.key for s in eng.dispatch_inventory()]
     eng.precompile()
     fc2 = dict(fcfg)
     fc2.update(customer_capacity=4096, terminal_capacity=4096,
@@ -427,23 +473,13 @@ def test_sharded_cold_matches_single(tmp_path):
     ctrl.precompile()
 
     a, batches = _cold_batches()
+    batches.append(_cols(a[:32], a[:32] + 10000, DAY0 + 5))
     for cols in batches:
-        eng.process_batch({k: v.copy() for k, v in cols.items()})
-        ctrl.process_batch({k: v.copy() for k, v in cols.items()})
-
-    assert reg.get("rtfds_feature_cold_demotions_total").value > 0
-    assert eng.drain_promotions(timeout_s=30.0)
-    assert reg.get("rtfds_feature_cold_promotions_total").value > 0
-
-    cols = _cols(a[:16], a[:16] + 10000, DAY0 + 5)
-    r_e = eng.process_batch({k: v.copy() for k, v in cols.items()})
-    r_c = ctrl.process_batch({k: v.copy() for k, v in cols.items()})
-    np.testing.assert_array_equal(np.asarray(r_e.probs),
-                                  np.asarray(r_c.probs))
-    rc = reg.get("rtfds_xla_recompiles_total")
-    assert (rc.value if rc else 0) == 0
-    fb = reg.get("rtfds_aot_fallbacks_total")
-    assert (fb.value if fb else 0) == 0
+        r_e = eng.process_batch({k: v.copy() for k, v in cols.items()})
+        r_c = ctrl.process_batch({k: v.copy() for k, v in cols.items()})
+        np.testing.assert_array_equal(np.asarray(r_e.probs),
+                                      np.asarray(r_c.probs))
+    _assert_promoted_before_scored(eng, reg)
 
 
 # -- checkpoint lineage ------------------------------------------------------
@@ -468,10 +504,9 @@ def test_checkpoint_cold_lineage_inspect_and_restore(tmp_path):
         eng.process_batch({k: v.copy() for k, v in cols.items()})
     assert reg.get("rtfds_feature_cold_demotions_total").value > 0
 
-    eng._cold.flush()
-    lin = eng._cold.lineage()
+    eng._settle_cold()  # what run() does: landed, flushed, lineage set
+    lin = eng.state.cold_lineage
     assert lin["total_keys"] > 0 and lin["segments"]
-    eng.state.cold_lineage = lin
     ckpt = Checkpointer(str(tmp_path / "ck"))
     path = ckpt.save(eng.state)
 

@@ -357,18 +357,19 @@ def test_recovery_events_in_flight_record(tmp_path, small_dataset):
 
 
 def _mk_cold(small_dataset, rows: int, cold_dir: str):
-    """A cold-tier variant of :func:`_mk`: hot tier oversubscribed
-    (64 slots, 120 customers) so compaction demotes under pressure and
-    recurring customers force promotion traffic every batch."""
+    """A cold-tier variant of :func:`_mk`: the hot tier holds a batch's
+    keys but not the customers' history (256 slots kept at 64 occupied,
+    120 customers), so every compaction demotes and recurring customers
+    are promoted — before the step that scores them — every batch."""
     dcfg, _, _, txs = small_dataset
     part = txs.slice(slice(0, rows))
     cfg = Config(
         data=dcfg,
         features=FeatureConfig(
-            key_mode="exact", customer_capacity=64, terminal_capacity=128,
+            key_mode="exact", customer_capacity=256,
+            terminal_capacity=512, keydir_probes=16,
             cms_width=1 << 10, compact_every=1, cold_store=cold_dir,
-            cold_demote_slots=16, cold_highwater=0.5,
-            cold_promote_queue=64),
+            cold_demote_slots=128, cold_highwater=0.25),
         runtime=RuntimeConfig(checkpoint_every_batches=2,
                               batch_buckets=(256,), max_batch_rows=256),
     )
@@ -387,14 +388,15 @@ def _mk_cold(small_dataset, rows: int, cold_dir: str):
 
 def test_cold_crash_mid_promotion_resume_exactly_once(
         tmp_path, small_dataset):
-    """SIGKILL mid-promotion, emulated the way the kill-during-save
-    cells do: the dying incarnation leaves (a) a POST-checkpoint cold
-    segment (demotions flushed after the last fence) and (b) an
-    enqueued promotion that never lands. Resume must prune the
+    """SIGKILL between two checkpoints, emulated the way the
+    kill-during-save cells do: the dying incarnation leaves a
+    POST-checkpoint cold segment (demotions flushed after the last
+    fence; promotions are never in flight across a batch boundary now:
+    they land before the step they are for). Resume must prune the
     post-checkpoint segment from the cold store (replay regenerates
-    those demotions — exactly-once across the tier boundary), fence the
-    promoter, survive a second scripted crash mid-replay, and complete
-    with a gap/dup-free sink lineage and ZERO corruption counted."""
+    those demotions — exactly-once across the tier boundary), survive a
+    second scripted crash mid-replay, and complete with a gap/dup-free
+    sink lineage, ZERO corruption counted and no key degraded."""
     cold_dir = str(tmp_path / "cold")
     part, make_engine = _mk_cold(small_dataset, 1536, cold_dir)
     d = str(tmp_path / "ck")
@@ -406,8 +408,8 @@ def test_cold_crash_mid_promotion_resume_exactly_once(
     lineage = man["meta"]["cold_lineage"]
     assert lineage["segments"], "checkpoint must record cold lineage"
 
-    # the crash artifacts: a post-checkpoint segment + an in-flight
-    # promotion request on the promoter the "kill" abandons
+    assert not eng._degraded_keys  # promoted before scored, every one
+    # the crash artifact: a post-checkpoint segment
     nb = eng.cfg.features.n_day_buckets
     eng._cold.append(
         "customer", np.array([999_999], np.uint32),
@@ -416,7 +418,6 @@ def test_cold_crash_mid_promotion_resume_exactly_once(
         np.zeros((1, nb), np.float32))
     orphan_seq = eng._cold.flush()
     assert orphan_seq is not None
-    assert eng._promoter.request("customer", 999_999)  # never lands
 
     base = _counters()
     src = FlakySource(ReplaySource(part, EPOCH0, batch_rows=256),

@@ -280,11 +280,11 @@ def _cold_cols(cust, term, day):
 
 def test_state_smoke_cold(tmp_path):
     """The cold-tier cell: an oversubscribed hot tier demotes under
-    pressure, evicted keys are forcibly re-touched (served degraded,
-    promoted async), and the promotion traffic is EXACT — counters
-    equal the host-computed cold∩ping intersection, with the
-    ``("promote",)`` signature in the precompiled inventory and zero
-    mid-stream recompiles."""
+    pressure, evicted keys are forcibly re-touched (promoted before
+    the step that scores them), and the promotion traffic is EXACT —
+    counters equal the host-computed cold∩ping intersection, with the
+    ``("promote", table, width)`` signatures in the precompiled
+    inventory and zero mid-stream recompiles."""
     from real_time_fraud_detection_system_tpu.core.batch import fold_key
 
     cfg = Config(
@@ -297,7 +297,6 @@ def test_state_smoke_cold(tmp_path):
             cold_store=str(tmp_path / "cold"),
             cold_demote_slots=16,
             cold_highwater=0.25,
-            cold_promote_queue=64,
         ),
         runtime=RuntimeConfig(batch_buckets=(64,), max_batch_rows=64,
                               precompile=True),
@@ -311,7 +310,7 @@ def test_state_smoke_cold(tmp_path):
 
     # the promote variant joins compact in the precompiled inventory
     keys = [s.key for s in eng.dispatch_inventory()]
-    assert ("compact",) in keys and ("promote",) in keys
+    assert ("compact",) in keys and ("promote", "customer", 64) in keys
     a = np.arange(0, 48)
     b = np.arange(1000, 1032)
     demote_phase = [
@@ -330,27 +329,31 @@ def test_state_smoke_cold(tmp_path):
     # host-computed ground truth: which pinged keys are actually cold
     expected = 0
     ping_c, ping_t = a[:16], a[:16] + 10000
+    cold_row = np.zeros(16, bool)  # rows with a cold customer or terminal
     for table, ids in (("customer", ping_c), ("terminal", ping_t)):
         snap = eng._cold.index_snapshot(table)
         folded = fold_key(np.asarray(ids))
         expected += int(np.isin(folded, snap).sum())
+        cold_row |= np.isin(folded, snap)
     assert expected > 0, "the ping must hit demoted keys"
 
-    # ping: evicted keys return — run() drains promotions before exit
+    # ping: evicted keys return — promoted ahead of the ping's own step
     stats2 = eng.run(
         _ScriptedSource([_cold_cols(ping_c, ping_t, DAY0 + 5)]),
         sink=sink)
     assert stats2["batches"] == 1
 
-    # promotion traffic is EXACT: every cold∩ping key was served
-    # degraded once, promoted exactly once, and landed
+    # promotion traffic is EXACT: every cold∩ping key was promoted
+    # exactly once, before its row was scored, and none served degraded
     assert reg.get(
         "rtfds_feature_cold_promotions_total").value == expected
-    assert stats2["exactness_degraded_keys"] == expected
+    assert stats2["exactness_degraded_keys"] == 0
     assert reg.get(
-        "rtfds_feature_cold_promote_backlog").value == 0
-    wait = reg.get("rtfds_feature_cold_promote_wait_seconds_total")
-    assert wait is not None and wait.value >= 0.0
+        "rtfds_feature_cold_rows_total").value == cold_row.sum()
+    assert reg.get(
+        "rtfds_feature_cold_promote_lanes_total").value == 2 * 64
+    landed = reg.get("rtfds_phase_seconds", phase="cold_append")
+    assert landed is not None and landed.count > 0
 
     # zero mid-stream recompiles / AOT fallbacks across BOTH runs
     rc = reg.get("rtfds_xla_recompiles_total")
@@ -372,5 +375,5 @@ def test_state_smoke_cold(tmp_path):
     assert cold["promotions"] == expected
     assert cold["demotions"] == reg.get(
         "rtfds_feature_cold_demotions_total").value
-    assert cold["promote_queue_limit"] == 64
-    assert cold["promote_backlog"] == 0
+    assert cold["rows"] == cold_row.sum()
+    assert cold["promote_lanes"] == 2 * 64

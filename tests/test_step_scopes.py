@@ -229,6 +229,65 @@ def test_exact_key_path_names_its_parts_under_their_table():
     assert any(p[:1] == ["compact"] for p in kept)
 
 
+@pytest.mark.parametrize("n_dev", [0, 2], ids=["one-chip", "mesh"])
+def test_cold_tier_programs_name_their_stages_as_siblings(n_dev, tmp_path):
+    """With the cold store armed the compaction gains the demote pass
+    and the engine a family of promote programs. Every named op of the
+    compaction sits under ``rtfds.compact`` OR ``rtfds.demote`` — never
+    both — and every named op of a promote under ``rtfds.keydir/<part>``
+    (its admit, named as in the step) OR ``rtfds.promote``: siblings, so
+    the benchmark's ``step_compact_ms`` + ``step_demote_ms`` and
+    ``step_keydir_ms`` + ``step_promote_ms`` add up to their programs and
+    no device time is read twice. Without the store the compaction names
+    ``rtfds.compact`` alone, as before."""
+    feat = {"key_mode": "exact", "compact_every": 4, "keydir_probes": 16,
+            "cold_store": str(tmp_path / "cold"), "cold_demote_slots": 16}
+    VARIANTS["_cold"] = ("logreg", feat, {}, n_dev, set())
+    try:
+        eng = _engine("_cold")
+    finally:
+        del VARIANTS["_cold"]
+    by_variant = {}
+    for sig in eng.dispatch_inventory():
+        if sig.variant in ("compact", "promote"):
+            low = eng.signature_step(sig).lower(
+                *eng.signature_templates(sig))
+            names = _op_names(low.as_text(dialect="hlo", debug_info=True))
+            paths = [p for p in map(_scopes, names) if p]
+            assert paths and {s for p in paths for s in p} <= set(
+                STEP_SCOPES)
+            if not n_dev:  # (shard_map's own squeezes carry no stage)
+                assert not [n for n in names if n.startswith("jit(")
+                            and "/" in n and not _scopes(n)]
+            kept = [_scopes(n) for n in _op_names(low.compile().as_text())]
+            by_variant.setdefault(sig.variant, []).append((paths, kept))
+    ((paths, kept),) = by_variant["compact"]
+    for p in paths:
+        assert p[:1] in (["compact"], ["demote"]) and not (
+            {"compact", "demote"} <= set(p)), p
+    assert any(p[:1] == ["demote"] for p in kept)
+    assert any(p[:1] == ["compact"] for p in kept)
+    assert len(by_variant["promote"]) == 2 * len(eng._promote_widths)
+    for paths, kept in by_variant["promote"]:
+        for p in paths:
+            assert p[:1] in (["keydir"], ["promote"]) and not (
+                {"keydir", "promote"} <= set(p)), p
+            if p[:1] == ["keydir"]:
+                assert len(p) > 1 and p[1] in KEYDIR_PARTS, p
+        assert any(p[:1] == ["promote"] for p in kept)
+        assert {p[1] for p in kept if p[:1] == ["keydir"]} == KEYDIR_PARTS
+    # the store not armed: no demote, no promote, in name or in program
+    plain = _engine("exact")
+    assert not [s for s in plain.dispatch_inventory()
+                if s.variant == "promote"]
+    (sig,) = [s for s in plain.dispatch_inventory()
+              if s.variant == "compact"]
+    said = {s for n in _op_names(plain.signature_step(sig).lower(
+        *plain.signature_templates(sig)).as_text(
+            dialect="hlo", debug_info=True)) for s in _scopes(n)}
+    assert said == {"compact"}
+
+
 def test_sketch_read_loop_is_all_under_cms_and_only_exact_has_one():
     """``key_mode="exact"`` reads the sketch in a ``while`` of chunks, a
     table: in the COMPILED step the loop, its condition, and every named

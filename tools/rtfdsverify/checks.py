@@ -91,11 +91,21 @@ class AotCoverageCheck:
             # shard_map'd per-shard pass under the same key)
             expected.add(("compact",))
             if getattr(fcfg, "cold_store", ""):
-                # engine.py::_maybe_promote lands resolved cold-tier
-                # promotions under this key between device steps (same
-                # single-chip/sharded split as compact) — a returning
-                # key must never pay a mid-stream compile
-                expected.add(("promote",))
+                # engine.py::_promote_returning dispatches a returning
+                # key's rows ahead of the step that scores it, under a
+                # key a table with a directory and a width of the lane
+                # ladder (same single-chip/sharded split as compact) —
+                # a returning key must never pay a mid-stream compile
+                from real_time_fraud_detection_system_tpu.runtime.engine \
+                    import promote_widths
+
+                tables = (("terminal",)
+                          if fcfg.customer_source == "cms"
+                          else ("customer", "terminal"))
+                expected.update(
+                    ("promote", t, w) for t in tables
+                    for w in promote_widths(
+                        max(eng.cfg.runtime.batch_buckets)))
         for key in sorted(expected - set(keys), key=str):
             out.append(_f(
                 self.name, "P0", target,
