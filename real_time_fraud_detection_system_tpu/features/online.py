@@ -213,6 +213,7 @@ class TableState(NamedTuple):
     directory: Optional[KeyDirectory] = None  # key_mode="exact"
     sketch: Optional[CountMinSketch] = None
     tier: Optional[jnp.ndarray] = None  # exact: [dense, cms] rows served
+    rounds: Optional[jnp.ndarray] = None  # exact: claim rounds the admit ran
 
 
 @dataclasses.dataclass(frozen=True)
@@ -251,13 +252,15 @@ class TablePlane:
             (state.customer, state.customer_dir, state.cms)
             if self._customer else
             (state.terminal, state.terminal_dir, state.terminal_cms))
-        return TableState(win, kd, sk, jnp.zeros(2, jnp.float32)
-                          if self.cfg.key_mode == "exact" else None)
+        exact = self.cfg.key_mode == "exact"
+        return TableState(win, kd, sk,
+                          jnp.zeros(2, jnp.float32) if exact else None,
+                          jnp.zeros((), jnp.float32) if exact else None)
 
     def update(self, ts: TableState, key, day, amount, fraud, valid):
         """The scatter half → (tstate', slot, admitted | None)."""
         cfg = self.cfg
-        win, kd, sketch, tier = ts
+        win, kd, sketch, tier, rounds = ts
         slot = adm = None
         if self.sketch_only and sketch is None:
             raise ValueError(
@@ -266,8 +269,9 @@ class TablePlane:
                 "same config)")
         if not self.sketch_only:
             if cfg.key_mode == "exact":
-                kd, slot, adm = admit_slots(kd, key, valid,
-                                            n_probes=cfg.keydir_probes)
+                kd, slot, adm, ran = admit_slots(
+                    kd, key, valid, n_probes=cfg.keydir_probes)
+                rounds = rounds + ran.astype(jnp.float32)
                 valid_hot = valid & adm
             else:
                 capacity = (cfg.customer_capacity if self._customer
@@ -281,7 +285,7 @@ class TablePlane:
         if sketch is not None:
             sketch = cms_update(sketch, key, amount, day, valid,
                                 fraud=None if self._customer else fraud)
-        return TableState(win, kd, sketch, tier), slot, adm
+        return TableState(win, kd, sketch, tier, rounds), slot, adm
 
     def query(self, ts: TableState, slot, adm, key, day, valid):
         """The gather half → (tstate' (tier counted), [rows, 2·NW]).
@@ -333,7 +337,10 @@ def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
     rows stand (one chip; a mesh's owner-placed customers), the sharded
     step passes its exchange. ``state`` is one owner's view (a mesh
     unstacks its per-device leaves first). Returns ``(state', customer
-    [B, 2·NW], terminal [B, 2·NW], tier rows [2] | None, overflows)``.
+    [B, 2·NW], terminal [B, 2·NW], tier [4] | None, overflows)`` — under
+    ``exact`` ``tier`` is ``[dense rows, cms rows, customer claim rounds,
+    terminal claim rounds]``, the one small vector a batch's finish
+    fetches for the registry.
     """
     fraud = fraud_of(batch)
 
@@ -347,7 +354,8 @@ def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
         c_plane, c_plane.of(state), batch.customer_key, fraud)
     t, t_mat, t_over = (reach_terminal or local)(
         t_plane, t_plane.of(state), batch.terminal_key, fraud)
-    tier = None if t.tier is None else c.tier + t.tier
+    tier = None if t.tier is None else jnp.concatenate(
+        [c.tier + t.tier, jnp.stack([c.rounds, t.rounds])])
     state = FeatureState(
         customer=c.windows, terminal=t.windows, cms=c.sketch,
         customer_dir=c.directory, terminal_dir=t.directory,
@@ -403,11 +411,13 @@ def update_and_featurize_exact(
 ) -> Tuple[FeatureState, jnp.ndarray, jnp.ndarray]:
     """:func:`update_and_featurize` under ``key_mode="exact"``.
 
-    Returns (new_state, features [B, 15], tier_rows [2] float32) where
-    ``tier_rows = [dense, cms]`` counts (row × keyspace) admissions this
+    Returns (new_state, features [B, 15], tier [4] float32) where
+    ``tier[:2] = [dense, cms]`` counts (row × keyspace) admissions this
     batch — the device-side source of
-    ``rtfds_feature_tier_rows_total{tier=…}``. With the hot tier sized to
-    hold every key this path is bit-identical to ``direct`` mode.
+    ``rtfds_feature_tier_rows_total{tier=…}`` — and ``tier[2:]`` the claim
+    rounds the customer and the terminal admit ran
+    (``rtfds_keydir_claim_rounds_total{table=…}``). With the hot tier
+    sized to hold every key this path is bit-identical to ``direct`` mode.
     """
     state, c_mat, t_mat, tier, _ = run_planes(state, batch, cfg)
     return state, assemble(batch, cfg, c_mat, t_mat), tier
@@ -724,10 +734,12 @@ def promote_rows(
     required every cold bucket to be strictly pre-eviction-day, and
     post-return writes land on days >= the return day, so cold and hot
     buckets never contend for the same day. Returns ``(state,
-    stats [2, 2] int32)`` = per-table ``[admitted, dropped]`` (dropped:
-    the free list ran dry or every probe position was taken — the engine
-    stops the run before that batch is delivered). The caller guarantees
-    unique keys per dispatch.
+    stats [2, 3] int32)`` = per-table ``[admitted, dropped, claim
+    rounds]`` (dropped: the free list ran dry or every probe position was
+    taken — the engine stops the run before that batch is delivered;
+    rounds: what the admit ran, for
+    ``rtfds_keydir_claim_rounds_total``). The caller guarantees unique
+    keys per dispatch.
     """
     out = {}
     stats = []
@@ -738,15 +750,15 @@ def promote_rows(
         pay = payload.get(ws_name)
         if kd is None or pay is None:
             out[dir_name], out[ws_name] = kd, ws
-            stats.append(jnp.zeros((2,), jnp.int32))
+            stats.append(jnp.zeros((3,), jnp.int32))
             continue
         keys, bd, cnt, amt, frd = pay
         # the admit carries rtfds.keydir and its parts, as in the step;
         # everything else of the program carries rtfds.promote — siblings
         with step_scope("promote"):
             valid = keys != jnp.uint32(EMPTY_KEY)
-        kd, slot, adm = admit_slots(kd, keys, valid,
-                                    n_probes=cfg.keydir_probes)
+        kd, slot, adm, rounds = admit_slots(kd, keys, valid,
+                                            n_probes=cfg.keydir_probes)
         with step_scope("promote"):
             slot_c = jnp.clip(slot, 0, ws.capacity - 1)
             hot = ws.rows(slot_c)
@@ -758,7 +770,7 @@ def promote_rows(
                 for cold, row in zip((bd, cnt, amt, frd), hot)))
             adm_n = jnp.sum(adm.astype(jnp.int32))
             drop_n = jnp.sum((valid & ~adm).astype(jnp.int32))
-            stats.append(jnp.stack([adm_n, drop_n]))
+            stats.append(jnp.stack([adm_n, drop_n, rounds]))
     with step_scope("promote"):
         stats = jnp.stack(stats)
     return (

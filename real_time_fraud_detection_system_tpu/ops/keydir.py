@@ -16,13 +16,18 @@ Everything is vectorized, fixed-shape and jit/shard_map-friendly:
 - **probing** is double hashing over a power-of-two table
   (``h1 + j·(h2|1)``, an odd stride walks the whole table) with a FIXED
   probe depth — lookups scan all P candidate positions and pick the
-  match, so there is no early-exit data dependence and deleted entries
-  need no tombstones;
+  match, so the LOOKUP has no early-exit data dependence and deleted
+  entries need no tombstones;
 - **batched insert** resolves scatter races with claim rounds: round j's
   writers scatter-min their key into still-empty positions, re-read, and
   the losers continue to probe j+1. Batch duplicates of one new key all
   win the same entry; a scatter-min of the row index picks ONE owner to
-  pop the free-slot stack, so one key costs one slot;
+  pop the free-slot stack, so one key costs one slot. The rounds do end
+  early, and exactly: they follow the full-depth lookup, only rows whose
+  key it did not find take part, and a round with no unplaced row is an
+  identity — so the loop runs while a round is left and a row is
+  unplaced (P at most; none for a batch of known keys; ~log(new keys) ÷
+  log(1 ÷ load) while keys arrive) and its answers are the P rounds';
 - **the free-slot stack** (``free``/``free_top``) is the admission
   bound: when it runs dry the claimed entry is rolled back and the row
   reports ``admitted=False`` — a full hot tier degrades to the sketch
@@ -175,15 +180,17 @@ def admit_slots(
     key: jnp.ndarray,  # uint32 [B]
     valid: jnp.ndarray,  # bool [B]
     n_probes: int = 8,
-) -> Tuple[KeyDirectory, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[KeyDirectory, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Lookup-or-insert a batch of keys; the hot path's admission op.
 
-    Returns ``(kd', slot [B] int32, admitted [B] bool)``. A row is
-    admitted iff its key already owned a slot or could claim a directory
-    entry within ``n_probes`` probes AND a free slot remained; batch
-    duplicates of one key share a single slot. Non-admitted rows return
-    slot 0 and MUST be masked out of dense-tier scatters (the caller
-    serves them from the sketch tier).
+    Returns ``(kd', slot [B] int32, admitted [B] bool, rounds [] int32)``.
+    A row is admitted iff its key already owned a slot or could claim a
+    directory entry within ``n_probes`` probes AND a free slot remained;
+    batch duplicates of one key share a single slot. Non-admitted rows
+    return slot 0 and MUST be masked out of dense-tier scatters (the
+    caller serves them from the sketch tier). ``rounds`` is how many claim
+    rounds ran, 0..``n_probes``: none when the lookup found every key
+    (``rtfds_keydir_claim_rounds_total`` counts them).
     """
     with step_scope("keydir"):
         dir_cap = kd.dir_capacity
@@ -208,14 +215,20 @@ def admit_slots(
                 hit0,
                 jnp.take_along_axis(pos, pidx[:, None], axis=1)[:, 0], 0)
         with step_scope("claim"):
-            # The P rounds as ONE loop body: unrolled, 2 x 16 rounds of a
+            # The rounds as ONE loop body (unrolled, 2 x 16 rounds of a
             # scatter and two gathers made each of the five bucket programs
-            # compile ~16 s on the chip (PERF.md, PR 32). Same values, round
-            # for round (tests/test_keydir.py pins it bit for bit).
-            def claim_round(j, carry):
-                keys, entry, placed, claimed = carry
+            # compile ~16 s on the chip: PERF.md, PR 32), run WHILE a round
+            # is left and a row is still unplaced. With every row placed
+            # ``hit`` and ``want`` are all false and ``cand`` is all
+            # EMPTY_KEY, whose scatter-min changes nothing: the rounds not
+            # run are identities, so the answers are the fixed P rounds' to
+            # the bit (tests/test_keydir.py pins them) and a batch of known
+            # keys runs none. A key no round can place holds its batch to
+            # all P.
+            def claim_round(carry):
+                j, keys, entry, placed, claimed = carry
                 p = _probe_position(  # pos[:, j]
-                    key, jnp.asarray(j, jnp.uint32), dir_cap)
+                    key, j.astype(jnp.uint32), dir_cap)
                 cur = keys[p]
                 # batch duplicates of a key claimed in an EARLIER round
                 # match here (pre-call lookup could not see that claim)
@@ -230,12 +243,17 @@ def admit_slots(
                 keys = keys.at[p].min(cand)
                 won = want & (keys[p] == key)
                 entry = jnp.where(won, p, entry)
-                return keys, entry, placed | won, claimed | won
+                return j + 1, keys, entry, placed | won, claimed | won
+
+            def unplaced_with_a_round_left(carry):
+                j, _, _, placed, _ = carry
+                return (j < n_probes) & ~placed.all()
 
             # claimed: matched via a claim made NOW
-            keys, entry, placed, claimed = jax.lax.fori_loop(
-                0, n_probes, claim_round,
-                (keys, entry, ~valid | hit0, jnp.zeros(B, dtype=bool)))
+            rounds, keys, entry, placed, claimed = jax.lax.while_loop(
+                unplaced_with_a_round_left, claim_round,
+                (jnp.int32(0), keys, entry, ~valid | hit0,
+                 jnp.zeros(B, dtype=bool)))
         with step_scope("grant"):
             # One owner per newly claimed entry (batch duplicates of one
             # new key all carry claimed=True on the same entry; exactly one
@@ -269,6 +287,7 @@ def admit_slots(
                          free_top=free_top),
             slot,
             admitted,
+            rounds,
         )
 
 

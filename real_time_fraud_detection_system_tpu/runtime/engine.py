@@ -768,6 +768,7 @@ class ScoringEngine:
         reg = self.metrics
         fcfg = self.cfg.features
         self._m_tier = None
+        self._m_claim_rounds = None
         self._m_slots_occ = None
         self._m_slots_rec = None
         self._m_compactions = None
@@ -791,6 +792,15 @@ class ScoringEngine:
             }
             tables = (("customer", fcfg.customer_source != "cms"),
                       ("terminal", True))
+            self._m_claim_rounds = {
+                t: reg.counter(
+                    "rtfds_keydir_claim_rounds_total",
+                    "claim rounds the directory's admit ran (the step's "
+                    "and the cold tier's promotes'): none for a batch of "
+                    "known keys, keydir_probes for one that holds a key "
+                    "no round can place", table=t)
+                for t, present in tables if present
+            }
             self._m_slots_occ = {
                 t: reg.gauge(
                     "rtfds_feature_slots_occupied",
@@ -1043,7 +1053,10 @@ class ScoringEngine:
         the run stops here, before that batch is delivered, rather than
         hand on an inexact row as exact."""
         for table, stats in handle.pop("promote_checks", ()):
-            dropped = int(np.asarray(stats).reshape(-1, 2, 2)[:, :, 1].sum())
+            # [admitted, dropped, claim rounds] a table, summed over shards
+            stats = np.asarray(stats).reshape(-1, 2, 3).sum(axis=0)
+            self._count_claim_rounds(stats[:, 2])
+            dropped = int(stats[:, 1].sum())
             if dropped:
                 raise ColdPromoteError(
                     f"cold tier: {dropped} returning {table} key(s) could "
@@ -1053,6 +1066,12 @@ class ScoringEngine:
                     "passes keeps occupancy under cold_highwater: raise "
                     "cold_demote_slots, lower compact_every or "
                     "cold_highwater, or add slots (README, Cold tier)")
+
+    def _count_claim_rounds(self, rounds) -> None:
+        """``rounds`` = [customer, terminal] claim rounds one program ran."""
+        for table, n in zip(("customer", "terminal"), rounds):
+            if table in self._m_claim_rounds:
+                self._m_claim_rounds[table].inc(float(n))
 
     def _settle_cold(self) -> None:
         """Everything demoted so far is in the store and durable, and the
@@ -1984,11 +2003,13 @@ class ScoringEngine:
             self._online_dirty = True
         tier = handle.get("tier")
         if tier is not None and self._m_tier is not None:
-            # [dense, cms] row x keyspace admissions this batch; the
-            # step already materialized, so this tiny fetch is free
+            # [dense, cms] row x keyspace admissions this batch, then
+            # the two admits' claim rounds; the step already
+            # materialized, so this tiny fetch is free
             t = np.asarray(tier)
             self._m_tier["dense"].inc(float(t[0]))
             self._m_tier["cms"].inc(float(t[1]))
+            self._count_claim_rounds(t[2:])
         self.state.batches_done += 1
         self.state.rows_done += n
         self._m_batches.inc()

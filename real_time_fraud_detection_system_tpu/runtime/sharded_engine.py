@@ -891,7 +891,7 @@ class ShardedScoringEngine(ScoringEngine):
         # phase decomposition matches the single-chip engine's.
         t_prep = time.perf_counter()
         parts = []
-        tier_parts = []  # exact mode: per-chunk [n_dev, 2] tier rows
+        tier_parts = []  # exact mode: per-chunk [n_dev, 4] tier vectors
         overflow_parts = []  # per-chunk exchange-overflow scalars
         t_fetch = None  # last chunk's async-fetch issue time
         for part_cols, rows, pos in chunks:
@@ -958,9 +958,10 @@ class ShardedScoringEngine(ScoringEngine):
                 )
             fstate, params, probs, feats = out[:4]
             if self._exact:
-                # [n_dev, 2] per-shard [dense, cms] rows served this
-                # chunk — accumulated across chunks, materialized at
-                # finish (scalar-sized; no async fetch needed)
+                # [n_dev, 4] per-shard [dense, cms] rows served this
+                # chunk and the two admits' claim rounds — accumulated
+                # across chunks, materialized at finish (scalar-sized;
+                # no async fetch needed)
                 tier_parts.append(out[4])
             # scalar beside probs, read at finish like the tier rows
             out[-1].copy_to_host_async()
@@ -1069,18 +1070,19 @@ class ShardedScoringEngine(ScoringEngine):
             self.selective_overflows += 1
         tier_parts = handle.pop("tier_shard", None)
         if tier_parts is not None:
-            # per-shard tier accounting ([n_dev, 2] summed over chunks):
+            # per-shard tier accounting ([n_dev, 4] summed over chunks):
             # shard-labeled counters get their own rows, the base
             # table-level counters get the shard sums — so the global
-            # healthz/dashboard contract is identical on the mesh.
-            tier = np.zeros((self.n_dev, 2), np.float64)
+            # healthz/dashboard contract is identical on the mesh. The
+            # claim rounds (columns 2, 3) have table-level series only.
+            tier = np.zeros((self.n_dev, 4), np.float64)
             for t in tier_parts:
                 tier += np.asarray(t)
             if self._m_tier_shard is not None:
                 for s in range(self.n_dev):
                     self._m_tier_shard[("dense", s)].inc(float(tier[s, 0]))
                     self._m_tier_shard[("cms", s)].inc(float(tier[s, 1]))
-            handle["tier"] = tier.sum(axis=0)  # [dense, cms] global
+            handle["tier"] = tier.sum(axis=0)  # global, as one chip's
         return self._emit_result(handle, probs_np, feats_np)
 
     # -- feedback into the owner-partitioned terminal table ----------------

@@ -203,6 +203,43 @@ def test_cold_armed_equals_all_hot(tmp_path, devices, depth, precompile):
     assert back_to_back > 0
 
 
+@pytest.mark.parametrize("devices", [1, 4], ids=["one-device", "mesh"])
+def test_claim_rounds_of_steps_and_promotes_are_counted(tmp_path, devices):
+    """``rtfds_keydir_claim_rounds_total{table=…}`` is every round every
+    admit ran: the step's, carried beside the tier rows a batch's finish
+    fetches anyway, and each promote program's, the third column of the
+    stats ``_check_promotes`` reads — summed over the mesh's shards. A
+    promoted key is new to its directory, so the promotes run rounds."""
+    rt = RuntimeConfig(batch_buckets=(64,), max_batch_rows=64)
+    reg = MetricsRegistry()
+    eng = _build(_fcfg(str(tmp_path / "cold"), cap=256, demote=64), rt,
+                 reg, devices)
+    ran = {"step": np.zeros(2), "promote": np.zeros(2)}
+    programs = {"step": 0, "promote": 0}
+    dispatch = eng._dispatch_step
+
+    def spy(key, fn, *args):
+        out = dispatch(key, fn, *args)
+        if key[0] in ("step", "sharded"):  # [shards,] [dense, cms, c, t]
+            ran["step"] += np.asarray(out[4]).reshape(-1, 4).sum(0)[2:]
+            programs["step"] += 1
+        elif key[0] == "promote":  # [shards,] table x [adm, drop, rounds]
+            ran["promote"] += np.asarray(out[1]).reshape(
+                -1, 2, 3).sum(0)[:, 2]
+            programs["promote"] += 1
+        return out
+
+    eng._dispatch_step = spy
+    eng.run(_Source(_churn(7, 24, 64, 1024)), _Sink())
+    assert programs["step"] == 24 and programs["promote"] > 10
+    assert (ran["promote"] > 0).all() and (ran["step"] > 0).all()
+    for i, table in enumerate(("customer", "terminal")):
+        got = reg.get("rtfds_keydir_claim_rounds_total", table=table).value
+        assert got == ran["step"][i] + ran["promote"][i], table
+        # two admits a step and one a promote, 16 rounds each at most
+        assert got < 16 * devices * (programs["step"] + programs["promote"])
+
+
 @pytest.mark.parametrize("devices,ladder", [
     (1, (256, 1024)), (4, (256, 1024)), (1, (256,)), (4, (256,)),
 ], ids=["one", "mesh", "one-chunked", "mesh-chunked"])

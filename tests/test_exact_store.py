@@ -343,8 +343,8 @@ def test_a_dry_free_stack_is_served_in_chunks_like_the_whole_batch():
         np.testing.assert_array_equal(np.asarray(feats)[:200],
                                       np.asarray(ref)[:200])
         assert np.isfinite(np.asarray(feats)).all()
-        np.testing.assert_array_equal(
-            np.asarray(tier), np.asarray(want[0][1] + want[1][1]))
+        np.testing.assert_array_equal(  # [dense, cms]; [2:] the rounds
+            np.asarray(tier)[:2], np.asarray(want[0][1] + want[1][1]))
         served += float(tier[1])
     assert served > 3 * 200  # most of both key spaces missed
 

@@ -10,6 +10,8 @@ import pytest
 
 from real_time_fraud_detection_system_tpu.ops.keydir import (
     EMPTY_KEY,
+    KeyDirectory,
+    _probe_positions,
     admit_slots,
     init_keydir,
     lookup_slots,
@@ -21,7 +23,7 @@ from real_time_fraud_detection_system_tpu.ops.keydir import (
 def _admit(kd, keys, valid=None):
     k = jnp.asarray(np.asarray(keys, np.uint32))
     v = jnp.ones(k.shape, bool) if valid is None else jnp.asarray(valid)
-    return admit_slots(kd, k, v)
+    return admit_slots(kd, k, v)[:3]
 
 
 def test_admit_assigns_unique_slots_and_coalesces_duplicates():
@@ -163,8 +165,8 @@ def test_admit_under_jit_matches_eager():
     for _ in range(4):
         keys = rng.integers(0, 200, 64).astype(np.uint32)
         kd_e, s_e, a_e = _admit(kd_e, keys)
-        kd_j, s_j, a_j = jitted(kd_j, jnp.asarray(keys),
-                                jnp.ones(64, bool))
+        kd_j, s_j, a_j, _ = jitted(kd_j, jnp.asarray(keys),
+                                   jnp.ones(64, bool))
         np.testing.assert_array_equal(np.asarray(s_e), np.asarray(s_j))
         np.testing.assert_array_equal(np.asarray(a_e), np.asarray(a_j))
     np.testing.assert_array_equal(np.asarray(kd_e.keys),
@@ -214,8 +216,8 @@ def test_keys_that_miss_admission_follow_load_and_probe_depth(load,
     kd = init_keydir(dir_cap, dir_cap // 2)  # the free stack never runs dry
     missed = 0
     for i in range(0, n_keys, batch):
-        kd, _, adm = admit(kd, jnp.asarray(keys[i:i + batch]),
-                           jnp.ones(batch, bool), n_probes=n_probes)
+        kd, _, adm, _ = admit(kd, jnp.asarray(keys[i:i + batch]),
+                              jnp.ones(batch, bool), n_probes=n_probes)
         missed += batch - int(np.asarray(adm).sum())
     assert int(occupied_slots(kd)) == n_keys - missed
     expected = dir_cap * load ** (n_probes + 1) / (n_probes + 1)
@@ -293,7 +295,7 @@ def test_claim_rounds_as_a_loop_equal_the_unrolled_rounds_bit_for_bit(
     for step in range(12):
         keys = jnp.asarray(rng.integers(0, 400, 128).astype(np.uint32))
         valid = jnp.asarray(rng.random(128) < 0.9)
-        kd_a, slot_a, adm_a = loop(kd_a, keys, valid, n_probes=n_probes)
+        kd_a, slot_a, adm_a, _ = loop(kd_a, keys, valid, n_probes=n_probes)
         kd_b, slot_b, adm_b = plain(kd_b, keys, valid, n_probes=n_probes)
         ran_dry = ran_dry or int(kd_a.free_top) == 0
         if step % 4 == 3:  # vacate a third of the live entries
@@ -304,3 +306,165 @@ def test_claim_rounds_as_a_loop_equal_the_unrolled_rounds_bit_for_bit(
                         jax.tree.leaves((kd_b, slot_b, adm_b))):
             assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
     assert ran_dry or n_probes == 1  # one probe loses keys first
+
+
+# -- the claim rounds end when every row is placed (PR 35) -----------------
+
+P, DIR, SLOTS, ROWS = 16, 64, 32, 8
+FILLER = 1_000_000  # keys that only occupy positions; nobody looks them up
+
+
+def _probes(key):
+    """The P probe positions of ``key`` in a DIR-entry directory."""
+    return np.asarray(_probe_positions(
+        jnp.asarray([key], jnp.uint32), DIR, P))[0].tolist()
+
+
+def _built(taken, slot_cap=SLOTS):
+    """A directory with ``taken`` = {position: key} occupied, entry i
+    owning slot i, the rest of the slots on the free stack."""
+    keys = np.full(DIR, EMPTY_KEY, np.uint32)
+    slots = np.full(DIR, -1, np.int32)
+    for i, (pos, key) in enumerate(taken.items()):
+        keys[pos], slots[pos] = key, i
+    return KeyDirectory(
+        keys=jnp.asarray(keys), slots=jnp.asarray(slots),
+        free=jnp.arange(slot_cap - 1, -1, -1, dtype=jnp.int32),
+        free_top=jnp.int32(slot_cap - len(taken)))
+
+
+def _chain(key, r):
+    """``key``'s first r - 1 probe positions taken by other keys: its
+    claim lands in round r - 1, so r rounds run."""
+    return _built({pos: FILLER + i
+                   for i, pos in enumerate(_probes(key)[:r - 1])})
+
+
+def _off_path(key, n):
+    """n positions that are none of ``key``'s probes."""
+    return [p for p in range(DIR) if p not in _probes(key)][:n]
+
+
+def _racing_pair():
+    """Two keys whose FIRST probe position is the same entry."""
+    first = {}
+    for key in range(1, 500):
+        other = first.setdefault(_probes(key)[0], key)
+        if other != key:
+            return other, key
+    raise AssertionError("no two of 500 keys share a first position")
+
+
+def _case_known():
+    kd = _admit(init_keydir(DIR, SLOTS), [11, 12, 13])[0]
+    return kd, [13, 11, 12, 11], None, 0, lambda kd2, slot, adm: (
+        adm[:4].all() and slot[1] == slot[3])
+
+
+def _case_padding():
+    return init_keydir(DIR, SLOTS), [5, 6, 7], [False] * 3, 0, (
+        lambda kd2, slot, adm: not adm.any()
+        and (np.asarray(kd2.keys) == EMPTY_KEY).all())
+
+
+def _case_chain(r):
+    def case():
+        return _chain(777, r), [777], None, r, lambda kd2, slot, adm: (
+            adm[0] and np.asarray(kd2.keys)[_probes(777)[r - 1]] == 777)
+    return case
+
+
+def _case_unplaceable():
+    # all P positions taken: every round runs, and the key still misses
+    return _chain(777, P + 1), [777], None, P, (
+        lambda kd2, slot, adm: not adm[0])
+
+
+def _case_mixed():
+    # a known key, a new key placed in round 0 and one that needs three:
+    # the loop runs for the slowest row, not the first
+    kd = _chain(777, 3)
+    kd = _admit(kd, [42])[0]
+    return kd, [42, 777, 43], None, 3, lambda kd2, slot, adm: adm[:3].all()
+
+
+def _case_duplicates():
+    return init_keydir(DIR, SLOTS), [9, 9, 9], None, 1, (
+        lambda kd2, slot, adm: adm[:3].all() and len(set(slot[:3])) == 1
+        and int(occupied_slots(kd2)) == 1)
+
+
+def _case_race():
+    # the smaller key wins the shared entry in round 0, the loser claims
+    # its second position in round 1
+    a, b = _racing_pair()
+    return init_keydir(DIR, SLOTS), [b, a], None, 2, (
+        lambda kd2, slot, adm: adm[:2].all() and slot[0] != slot[1]
+        and np.asarray(kd2.keys)[_probes(a)[0]] == a
+        and np.asarray(kd2.keys)[_probes(b)[1]] == b)
+
+
+def _case_dry_stack():
+    # no slot is free: the claim of round 0 is rolled back by the grant,
+    # so the key comes again, and costs its one round, every batch
+    kd = _built({pos: FILLER + i
+                 for i, pos in enumerate(_off_path(777, 2))}, slot_cap=2)
+    return kd, [777], None, 1, lambda kd2, slot, adm: (
+        not adm[0] and np.array_equal(np.asarray(kd2.keys),
+                                      np.asarray(kd.keys)))
+
+
+def _case_vacated_prefix():
+    # a live key at its third position, its first vacated by a reclaim:
+    # the full-depth lookup finds it, no round runs, the vacancy stays
+    a, b, c = _probes(321)[:3]
+    kd = _built({a: FILLER, b: FILLER + 1, c: 321})
+    kd = reclaim_entries(kd, jnp.arange(DIR) == a)[0]
+    return kd, [321], None, 0, lambda kd2, slot, adm: (
+        adm[0] and slot[0] == 2
+        and np.asarray(kd2.keys)[a] == EMPTY_KEY)
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(_case_known, id="all-keys-known"),
+    pytest.param(_case_padding, id="padding-only"),
+    pytest.param(_case_chain(1), id="chain-1"),
+    pytest.param(_case_chain(3), id="chain-3"),
+    pytest.param(_case_chain(P), id="chain-P"),
+    pytest.param(_case_unplaceable, id="no-round-can-place"),
+    pytest.param(_case_mixed, id="slowest-row-decides"),
+    pytest.param(_case_duplicates, id="duplicates-of-a-new-key"),
+    pytest.param(_case_race, id="two-keys-race-for-one-position"),
+    pytest.param(_case_dry_stack, id="dry-free-stack"),
+    pytest.param(_case_vacated_prefix, id="vacated-probe-prefix"),
+])
+def test_claim_rounds_end_when_every_row_is_placed(case):
+    """``admit_slots`` runs its claim rounds while a row is unplaced, P
+    at most: ``(kd', slot, admitted)`` are the fixed P rounds' bit for
+    bit under jit, the round count is what the case's construction
+    needs, and a second admit of the same batch on the first's
+    directory agrees again (a rolled-back claim comes again; a placed
+    key runs no round)."""
+    kd, keys, valid, rounds, holds = case()
+    key = np.zeros(ROWS, np.uint32)
+    key[:len(keys)] = keys
+    ok = np.zeros(ROWS, bool)
+    ok[:len(keys)] = True if valid is None else valid
+    key, ok = jnp.asarray(key), jnp.asarray(ok)
+    loop = jax.jit(admit_slots, static_argnames="n_probes")
+    plain = jax.jit(_admit_slots_unrolled, static_argnames="n_probes")
+
+    *got, ran = loop(kd, key, ok, n_probes=P)
+    want = plain(kd, key, ok, n_probes=P)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert ran.dtype == jnp.int32 and ran.shape == ()
+    assert int(ran) == rounds
+    assert holds(got[0], np.asarray(got[1]), np.asarray(got[2]))
+
+    admitted = bool(np.asarray(got[2])[np.asarray(ok)].all())
+    *again, ran_again = loop(got[0], key, ok, n_probes=P)
+    want_again = plain(want[0], key, ok, n_probes=P)
+    for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(want_again)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert int(ran_again) == (0 if admitted else rounds)

@@ -693,3 +693,139 @@ def test_sharded_exact_indivisible_capacity_refused():
     cfg = _cfg(cust_cap=4, term_cap=512)  # pow2, but 4 / 8 devices
     with pytest.raises(ValueError, match="power of two"):
         ShardedScoringEngine(cfg, "logreg", params, scaler, n_devices=8)
+
+
+# ---------------------------------------------------------------------------
+# the claim rounds' counter (PR 35), one device and the mesh
+# ---------------------------------------------------------------------------
+
+def _rounds_needed(kd_before, kd_after, keys, n_probes):
+    """Claim rounds a batch of ``keys`` needs, read off the directory
+    the FIXED rounds left: a new key placed at its j-th probe position
+    was unplaced through round j, so the slowest new key decides — none
+    new, no round; a key in no position, all of them."""
+    import jax.numpy as jnp
+
+    from real_time_fraud_detection_system_tpu.ops.keydir import (
+        _probe_positions,
+    )
+
+    keys = np.unique(keys)
+    pos = np.asarray(_probe_positions(
+        jnp.asarray(keys), kd_before.dir_capacity, n_probes))
+    new = ~(np.asarray(kd_before.keys)[pos] == keys[:, None]).any(axis=1)
+    at = np.asarray(kd_after.keys)[pos] == keys[:, None]
+    placed_in = np.where(at.any(axis=1), at.argmax(axis=1) + 1, n_probes)
+    return int(placed_in[new].max(initial=0))
+
+
+def _engine_on(cfg, n_dev, reg):
+    """The mesh's engine over ``n_dev`` devices, or one chip's at 0."""
+    params, scaler = _model()
+    if n_dev:
+        return ShardedScoringEngine(cfg, "logreg", params, scaler,
+                                    n_devices=n_dev, metrics=reg)
+    return ScoringEngine(cfg, "logreg", params, scaler, metrics=reg)
+
+
+@pytest.mark.parametrize("n_dev", [0, N_DEV], ids=["one-device", "mesh"])
+def test_claim_rounds_counter_is_what_the_fixed_rounds_need(n_dev):
+    """``rtfds_keydir_claim_rounds_total{table=…}`` after a run over
+    known keys is the sum, batch by batch and shard by shard, of the
+    rounds the fixed-depth reference (``tests/test_keydir.py``'s
+    unrolled ``admit_slots``) needed to place that shard's new keys —
+    under 2 x 16 a batch, and NOTHING for a pass over keys the
+    directories already hold."""
+    import jax
+    import jax.numpy as jnp
+    from test_keydir import _admit_slots_unrolled
+
+    from real_time_fraud_detection_system_tpu.core.batch import fold_key
+    from real_time_fraud_detection_system_tpu.ops.keydir import init_keydir
+
+    cfg, reg, rows = _cfg(), MetricsRegistry(), 256
+    n, probes = max(1, n_dev), cfg.features.keydir_probes
+    eng = _engine_on(cfg, n_dev, reg)
+    fixed = jax.jit(_admit_slots_unrolled, static_argnames="n_probes")
+    cap = cfg.features.customer_capacity // n  # == terminal_capacity
+    dirs = {t: [init_keydir(2 * cap, cap) for _ in range(n)]
+            for t in ("customer", "terminal")}
+    want = dict.fromkeys(dirs, 0)
+    batches = _batches(4, rows=rows)
+    for b in batches:
+        eng.process_batch(b)
+        for table in dirs:
+            keys = fold_key(b[f"{table}_id"])
+            for s in range(n):
+                own = keys[keys % np.uint32(n) == s]
+                padded = np.zeros(rows, np.uint32)
+                padded[:own.size] = own
+                kd = dirs[table][s]
+                dirs[table][s] = fixed(
+                    kd, jnp.asarray(padded),
+                    jnp.arange(rows) < own.size, n_probes=probes)[0]
+                want[table] += _rounds_needed(kd, dirs[table][s], own,
+                                              probes)
+    got = {t: reg.get("rtfds_keydir_claim_rounds_total", table=t).value
+           for t in dirs}
+    assert got == want and all(0 < v < probes * n * len(batches)
+                               for v in got.values()), (got, want)
+    for b in batches:  # every key known now: not one round more
+        eng.process_batch(b)
+    assert got == {
+        t: reg.get("rtfds_keydir_claim_rounds_total", table=t).value
+        for t in dirs}
+    assert reg.get("rtfds_batches_total").value == 2 * len(batches)
+
+
+@pytest.mark.parametrize("key_mode,n_dev", [
+    ("direct", 0), ("hash", 0), ("direct", N_DEV)])
+def test_no_claim_rounds_series_without_a_directory(key_mode, n_dev):
+    """``direct`` / ``hash`` take the slot from ``key_slot``: no admit
+    runs and the registry holds no claim-rounds series for a dashboard
+    (or the benchmark's ``keydir_claim_rounds.sat``) to read as zero."""
+    reg = MetricsRegistry()
+    eng = _engine_on(_cfg(key_mode), n_dev, reg)
+    eng.process_batch(_batches(1)[0])
+    assert reg.get("rtfds_batches_total").value == 1
+    assert reg.family_total("rtfds_keydir_claim_rounds_total") is None
+
+
+def test_sharded_exact_claims_like_the_fixed_rounds_behind_the_exchange(
+        monkeypatch):
+    """Behind the exchange each owner's claim loop ends on its own rows;
+    what every delivered row gets — and the directories the shards are
+    left with — is what P fixed rounds gave, put in the loop's place for
+    the comparison (``tests/test_keydir.py``'s unrolled ``admit_slots``),
+    through an overflowing hot tier (a dry free stack's rolled-back
+    claims) as well as an ample one."""
+    import jax.numpy as jnp
+    from test_keydir import _admit_slots_unrolled
+
+    from real_time_fraud_detection_system_tpu.features import online
+
+    def fixed_rounds(kd, key, valid, n_probes):
+        return _admit_slots_unrolled(kd, key, valid, n_probes) + (
+            jnp.int32(n_probes),)
+
+    params, scaler = _model()
+    for caps in (dict(), dict(cust_cap=64, term_cap=64)):
+        outs = []
+        for stand_in in (None, fixed_rounds):
+            with monkeypatch.context() as m:
+                if stand_in is not None:
+                    m.setattr(online, "admit_slots", stand_in)
+                eng = ShardedScoringEngine(
+                    _cfg(**caps), "logreg", params, scaler,
+                    n_devices=N_DEV, metrics=MetricsRegistry())
+                res = [eng.process_batch(b) for b in _batches(
+                    3, n_cust=100 if not caps else 5000,
+                    n_term=200 if not caps else 5000)]
+            fs = eng.state.feature_state
+            outs.append([np.concatenate([r.probs for r in res]),
+                         np.concatenate([r.features for r in res])] + [
+                np.asarray(leaf) for kd in (fs.customer_dir,
+                                            fs.terminal_dir)
+                for leaf in kd])
+        for a, b in zip(*outs):
+            assert a.tobytes() == b.tobytes()

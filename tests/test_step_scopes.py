@@ -56,6 +56,8 @@ from real_time_fraud_detection_system_tpu.utils.trace import (
 )
 
 from test_tpu_compile import (  # noqa: E402 (pytest adds tests/ to path)
+    claim_loops,
+    reads_a_mask_of,
     sketch_read_loops,
     sketch_table_gathers,
 )
@@ -68,8 +70,9 @@ TABLE = {"customer", "terminal"}
 UPDATE = {"update", "stamp", "reset", "scatter"}
 QUERY = {"query", "gather", "sum"}
 COMMON = {"unpack", "assemble", "scale", "classify"} | TABLE | UPDATE | QUERY
-# admit_slots names its three parts: the full-depth probe, the P claim
-# rounds, and the owner / free-stack / roll-back / final resolution
+# admit_slots names its three parts: the full-depth probe, the claim
+# rounds (a loop that ends when every row is placed), and the owner /
+# free-stack / roll-back / final resolution
 KEYDIR_PARTS = {"lookup", "claim", "grant"}
 
 # variant → (kind, FeatureConfig overrides, RuntimeConfig overrides,
@@ -391,3 +394,32 @@ def test_profile_to_turns_the_tracer_on_for_the_capture(tmp_path):
             assert not tracer.enabled
     finally:
         tracer.configure(enabled=was)
+
+
+def test_claim_rounds_are_one_loop_a_table_that_ends_on_the_placed_mask():
+    """``key_mode="exact"``: the COMPILED step holds the claim rounds as
+    one ``while`` a table whose condition reduces the batch's placed mask
+    (not P unrolled rounds, not a fixed trip count: a batch of known keys
+    runs no round), and the loop, its condition and every named op of
+    its body sit under ``<table>/rtfds.keydir/rtfds.claim`` — the scope
+    ``step_keydir_claim_ms`` reads, which is in the vocabulary already.
+    The steps that take their slot from ``key_slot`` name no
+    ``rtfds.keydir`` op and hold no such loop."""
+    eng = _engine("exact")
+    (low,) = _lowered_steps(eng)
+    text = low.compile().as_text()
+    loops = claim_loops(text)
+    assert sorted(_scopes(op)[0] for op, _, _ in loops) == sorted(TABLE)
+    for op, condition, inside in loops:
+        assert _scopes(op)[1:] == ["keydir", "claim"], op
+        assert reads_a_mask_of(condition, 64), condition
+        named = [n for c in [condition] + inside for n in _op_names(c)
+                 if n.startswith("jit(")]  # the rest: reducers' bodies
+        assert any(n.endswith("/scatter-min") for n in named)
+        off = [n for n in named if _scopes(n)[:3] != _scopes(op)]
+        assert not off, off[:3]
+    assert "claim" in STEP_SCOPES
+    for variant in ("forest", "logreg", "cms", "sharded"):
+        low, *_ = _lowered_steps(_engine(variant))
+        text = low.compile().as_text()
+        assert not claim_loops(text) and "rtfds.keydir" not in text

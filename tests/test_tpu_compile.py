@@ -234,15 +234,21 @@ def _reached_from(bodies, roots):
     return seen
 
 
-_WHILE = re.compile(r" while\(.*condition=%([\w.\-]+), body=%([\w.\-]+)"
-                    r'.*op_name="([^"]*rtfds\.cms[^"]*)"')
+def _loops_under(hlo_text, scope):
+    """``(op_name, condition, body)`` — the computations' names — of every
+    ``while`` whose op_name holds ``scope``."""
+    loop = re.compile(
+        r" while\(.*condition=%([\w.\-]+), body=%([\w.\-]+)"
+        r'.*op_name="([^"]*' + re.escape(scope) + r'[^"]*)"')
+    return [(m.group(3), m.group(1), m.group(2))
+            for m in map(loop.search, hlo_text.splitlines()) if m]
 
 
 def _sketch_read_loops(hlo_text, bodies):
     """``(op_name, {computation it runs: body})`` of every ``while`` named
     under ``rtfds.cms``."""
-    return [(m.group(3), _reached_from(bodies, m.group(1, 2)))
-            for m in map(_WHILE.search, hlo_text.splitlines()) if m]
+    return [(op, _reached_from(bodies, (cond, body)))
+            for op, cond, body in _loops_under(hlo_text, "rtfds.cms")]
 
 
 def sketch_read_loops(hlo_text):
@@ -251,6 +257,30 @@ def sketch_read_loops(hlo_text):
     tier's chunked read, one a table."""
     return [(op, list(inside.values())) for op, inside in
             _sketch_read_loops(hlo_text, _computations(hlo_text))]
+
+
+def claim_loops(hlo_text):
+    """``(op_name, its condition's text, [bodies of the computations its
+    body runs])`` of every ``while`` of a compiled step named under
+    ``rtfds.claim``: the directory's claim rounds, one loop a table."""
+    bodies = _computations(hlo_text)
+    return [(op, bodies[cond], list(_reached_from(bodies, [body]).values()))
+            for op, cond, body in _loops_under(hlo_text, "rtfds.claim")]
+
+
+def reads_a_mask_of(condition, rows):
+    """Whether a ``while`` condition works on a ``pred[rows]`` of its
+    carry — the placed mask, reduced to "a row is unplaced" however the
+    compiler writes the reduction (a fixed trip count compares a counter
+    alone and never takes the mask out of the tuple)."""
+    masks = set(re.findall(
+        r"%([\w.\-]+) = pred\[" + str(rows) + r"\]", condition))
+    for line in condition.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m and m.group(3) not in _NO_PASS and masks & set(
+                re.findall(r"%([\w.\-]+)", m.group(4).split(")", 1)[0])):
+            return True
+    return False
 
 
 def sketch_table_gathers(hlo_text, table_dims):
@@ -525,3 +555,38 @@ def test_exact_step_reads_the_sketch_a_chunk_at_a_time_inside_its_loops(
     assert k == {256: 128, EXACT_ROWS: 256}[rows]
     assert gathers == [(True, fcfg.cms_depth * k * max(fcfg.windows))] * 4, \
         gathers
+
+
+@pytest.mark.parametrize("rows", [256, EXACT_ROWS])
+def test_exact_step_runs_its_claim_rounds_while_a_row_is_unplaced(
+        topo, one_chip, as_on_chip, compiled_steps, rows):
+    """What the chip's compiler made of ``admit_slots``' claim rounds:
+    one ``while`` a table — not 16 unrolled rounds, not a fixed trip
+    count: the condition reduces the ``[rows]`` placed mask beside its
+    ``j < 16`` — with every named op of its body under
+    ``<table>/rtfds.keydir/rtfds.claim`` (``step_keydir_claim_ms`` holds
+    the loop and nothing else), the scatter-min updating the directory's
+    keys in place: no copy of a directory-sized array a trip (PERF.md,
+    PR 35: the 48.6 ms the 2 x 16 fixed rounds cost a 168.9 ms step)."""
+    fcfg, compiled = _compiled_exact(compiled_steps, one_chip, "step", rows)
+    loops = claim_loops(compiled.as_text())
+    assert sorted(op.split("/")[1] for op, _, _ in loops) == [
+        "rtfds.customer", "rtfds.terminal"], [op for op, _, _ in loops]
+    for op, condition, inside in loops:
+        table = op.split("/")[1]
+        assert reads_a_mask_of(condition, rows), condition
+        assert f"constant({fcfg.keydir_probes})" in condition
+        named = [n for c in inside for n in re.findall(
+            r'op_name="([^"]*)"', c) if n.startswith("jit(")]
+        # one round a trip: ONE scatter in everything the body runs
+        assert sum(c.count(" scatter(") for c in inside) == 1
+        assert any(n.endswith("/scatter-min") for n in named)
+        off = [n for n in named
+               if f"{table}/rtfds.keydir/rtfds.claim/" not in n]
+        assert not off, off[:3]
+        dir_cap = 2 * (fcfg.customer_capacity if "customer" in table
+                       else fcfg.terminal_capacity)
+        copies = [ln for c in inside for ln in c.splitlines()
+                  if re.search(
+                      rf"= \w+\[{dir_cap}\]\S* copy(-start)?\(", ln)]
+        assert not copies, copies[:2]
