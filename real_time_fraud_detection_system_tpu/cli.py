@@ -2146,13 +2146,26 @@ def cmd_trace(args) -> int:
     if batches:
         worst = sorted(batches, key=lambda b: -b["total_ms"])[:args.top_k]
         print(f"\nslowest batches (top {len(worst)}), critical phase "
-              "per batch:")
+              "(largest self time) per batch, phases as self/total ms:")
         for b in worst:
-            phases = " ".join(f"{k}={v:.2f}" for k, v in
-                              b["phases_ms"].items())
+            # each phase as self/total: what it spent itself, and with
+            # the spans it opened
+            phases = " ".join(
+                f"{k}={b['self_ms'][k]:.2f}/{v:.2f}"
+                for k, v in b["phases_ms"].items())
             print(f"  {b['trace_id']}  total {b['total_ms']:9.3f} ms  "
                   f"critical {b['critical_phase']} "
-                  f"({b['critical_ms']:.3f} ms)  [{phases}]")
+                  f"({b['critical_ms']:.3f} ms self)  [{phases}]")
+    if summary["self_time"]:
+        rows = summary["self_time"][:max(args.top_k, 10)]
+        print(f"\nself time by span (top {len(rows)}): where each "
+              "thread's time went and was not handed further down")
+        print(f"  {'role':<7} {'span':<16} {'count':>6} "
+              f"{'self ms':>11} {'total ms':>11}")
+        for r in rows:
+            print(f"  {r['role'] or '-':<7} {r['name']:<16} "
+                  f"{r['count']:>6} {r['self_ms']:>11.3f} "
+                  f"{r['total_ms']:>11.3f}")
     if summary["slowest_spans"]:
         print(f"\nslowest spans (top {len(summary['slowest_spans'])}):")
         for s in summary["slowest_spans"]:

@@ -336,11 +336,17 @@ def decode_transaction_envelopes_fast(
     messages: Iterable[bytes],
     kafka_timestamps_ms: Optional[Sequence[int]] = None,
 ) -> Tuple[dict, np.ndarray]:
-    """Dispatcher: C++ scanner when buildable (≈6× faster), Python otherwise."""
+    """Dispatcher: C++ scanner when buildable (≈6× faster), Python
+    otherwise. The span ``decode`` is opened here, where the work is (a
+    child of the poll that asked for it); an empty poll leaves none."""
     from real_time_fraud_detection_system_tpu.core import native
+    from real_time_fraud_detection_system_tpu.utils.trace import get_tracer
 
-    if native.native_available():
-        return native.decode_transaction_envelopes_native(
-            messages, kafka_timestamps_ms
-        )
-    return decode_transaction_envelopes(messages, kafka_timestamps_ms)
+    with get_tracer().span("decode") as span:
+        if hasattr(messages, "__len__") and not len(messages):
+            span.cancel()
+        if native.native_available():
+            return native.decode_transaction_envelopes_native(
+                messages, kafka_timestamps_ms
+            )
+        return decode_transaction_envelopes(messages, kafka_timestamps_ms)

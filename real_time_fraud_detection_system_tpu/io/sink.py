@@ -50,20 +50,33 @@ class _SinkTelemetry:
         self._m_failures = reg.counter(
             "rtfds_sink_failures_total", "failed appends", sink=sink_kind)
 
-    def _observe_write(self, t0: float, rows: int, nbytes: int) -> None:
+    def _begin_write(self, res) -> tuple:
+        """→ ``(t0, span)``: the append's start and its open
+        ``sink/<kind>`` span, a child of whatever span the calling thread
+        has open (the engine's ``sink_write``), under ``res``'s batch."""
+        idx = getattr(res, "batch_index", -1)
+        span = self._tracer.span(
+            f"sink/{self._sink_kind}",
+            batch=f"b{idx:08d}" if idx >= 0 else "").open()
+        return time.perf_counter(), span
+
+    def _part(self, name: str):
+        """``with self._part("encode"):`` — one part of an append, as the
+        span ``sink/<name>``."""
+        return self._tracer.span(f"sink/{name}")
+
+    def _observe_write(self, t0: float, span, rows: int,
+                       nbytes: int) -> None:
         t1 = time.perf_counter()
         self._m_write.observe(t1 - t0)
         self._m_rows.inc(rows)
         if nbytes:
             self._m_bytes.inc(nbytes)
-        if self._tracer.enabled:
-            # Timeline-only (batch=""): the engine's sink_write span
-            # carries the batch attribution — with pipelining the
-            # tracer's CURRENT batch can be newer than the one whose
-            # rows are being written, so claiming it would lie. On the
-            # Perfetto timeline the span still nests under sink_write.
-            self._tracer.add_span(f"sink/{self._sink_kind}", t0, t1,
-                                  batch="", rows=rows, bytes=nbytes)
+        span.close(t0, t1, rows=rows, bytes=nbytes)
+
+    def _fail_write(self, t0: float, span) -> None:
+        self._m_failures.inc()
+        span.close(t0, time.perf_counter(), failed=True)
 
 
 def _result_to_columns(res) -> dict:
@@ -159,10 +172,13 @@ class AsyncSink:
       block: a crash replays rows, never skips them, and replayed
       ``batch_index`` parts overwrite).
 
-    ``write(inner, res, ctx)``, where given, runs on the writer thread in
-    place of ``inner.append(res)``: the engine's hook for what belongs
-    around the write where it happens (its span, its duration). ``ctx``
-    is whatever ``append`` was handed beside the result.
+    ``write(inner, res, ctx, t_queued)``, where given, runs on the writer
+    thread in place of ``inner.append(res)``: the engine's hook for what
+    belongs around the write where it happens (its span, its duration).
+    ``ctx`` is whatever ``append`` was handed beside the result;
+    ``t_queued`` the ``perf_counter`` reading as the enqueue returned
+    (None where the writer took the batch off before that), so the hook
+    can say how long the batch lay in the queue.
     """
 
     _STOP = object()
@@ -208,13 +224,13 @@ class AsyncSink:
                 if item is self._STOP:
                     return
                 if self._error is None:
-                    res, ctx = item
+                    res, ctx, t_queued = item
                     try:
                         if self._write is None:
                             # rtfdslint: disable=cross-thread-race (drain() is the guard: every loop-side inner access — flush/truncate_after/read_all/concat — calls drain() first, and q.join() orders every writer append strictly before it; crash/replay lineage tests pin the contract)
                             self.inner.append(res)
                         else:
-                            self._write(self.inner, res, ctx)
+                            self._write(self.inner, res, ctx, t_queued)
                     # rtfdslint: disable=broad-exception-catch (thread-boundary transport: the writer parks the ORIGINAL exception; append/drain re-raise it typed on the loop thread for the supervisor's recover_on policy)
                     except BaseException as e:  # propagate to loop thread
                         self._error = _SinkError(
@@ -250,12 +266,20 @@ class AsyncSink:
     def append(self, res, ctx=None) -> None:
         self._raise_pending()
         t0 = time.perf_counter()
+        item = [res, ctx, None]
         # blocks when full: bounded-memory backpressure
-        self._q.put((res, ctx))
-        waited = time.perf_counter() - t0
+        self._q.put(item)
+        item[2] = t1 = time.perf_counter()
+        waited = t1 - t0
         if waited > 1e-4:  # an uncontended put is ~µs; only count blocks
             self._m_backpressure.inc(waited)
         self._m_depth.set(self._q.qsize())
+
+    @property
+    def idle(self) -> bool:
+        """Nothing queued and nothing being written (read on the thread
+        that appends, nothing can arrive meanwhile)."""
+        return self._q.unfinished_tasks == 0
 
     def drain(self) -> None:
         """Block until every queued append has landed (or failed) in the
@@ -377,10 +401,11 @@ class ParquetSink(_SinkTelemetry):
         import pyarrow as pa
         import pyarrow.parquet as pq
 
-        t0 = time.perf_counter()
+        t0, span = self._begin_write(res)
         try:
-            cols = _result_to_columns(res)
-            table = pa.table({k: pa.array(v) for k, v in cols.items()})
+            with self._part("convert"):
+                cols = _result_to_columns(res)
+                table = pa.table({k: pa.array(v) for k, v in cols.items()})
             idx = getattr(res, "batch_index", -1)
             if idx >= 0:
                 name = f"part-{idx:08d}.parquet"
@@ -390,13 +415,15 @@ class ParquetSink(_SinkTelemetry):
                 self._seq += 1
             path = os.path.join(self.directory, name)
             tmp = path + ".tmp"
-            pq.write_table(table, tmp)
-            nbytes = os.path.getsize(tmp)
-            os.replace(tmp, path)
+            with self._part("encode"):
+                pq.write_table(table, tmp)
+            with self._part("commit"):
+                nbytes = os.path.getsize(tmp)
+                os.replace(tmp, path)
         except Exception:
-            self._m_failures.inc()
+            self._fail_write(t0, span)
             raise
-        self._observe_write(t0, len(res.tx_id), nbytes)
+        self._observe_write(t0, span, len(res.tx_id), nbytes)
 
     def truncate_after(self, batch_index: int) -> None:
         """Drop indexed parts beyond ``batch_index`` — the sink-side
@@ -450,10 +477,11 @@ class StoreParquetSink(_SinkTelemetry):
         import pyarrow as pa
         import pyarrow.parquet as pq
 
-        t0 = time.perf_counter()
+        t0, span = self._begin_write(res)
         try:
-            cols = _result_to_columns(res)
-            table = pa.table({k: pa.array(v) for k, v in cols.items()})
+            with self._part("convert"):
+                cols = _result_to_columns(res)
+                table = pa.table({k: pa.array(v) for k, v in cols.items()})
             idx = getattr(res, "batch_index", -1)
             if idx >= 0:
                 name = f"part-{idx:08d}.parquet"
@@ -461,14 +489,16 @@ class StoreParquetSink(_SinkTelemetry):
                 name = (f"part-{int(time.time() * 1e3)}-"
                         f"{self._seq:06d}.parquet")
                 self._seq += 1
-            buf = pa.BufferOutputStream()
-            pq.write_table(table, buf)
-            data = buf.getvalue().to_pybytes()
-            self.store.put(name, data)
+            with self._part("encode"):
+                buf = pa.BufferOutputStream()
+                pq.write_table(table, buf)
+                data = buf.getvalue().to_pybytes()
+            with self._part("commit"):
+                self.store.put(name, data)
         except Exception:
-            self._m_failures.inc()
+            self._fail_write(t0, span)
             raise
-        self._observe_write(t0, len(res.tx_id), len(data))
+        self._observe_write(t0, span, len(res.tx_id), len(data))
 
     def truncate_after(self, batch_index: int) -> None:
         for key in self.store.list(""):
@@ -887,14 +917,16 @@ class IcebergSink(_SinkTelemetry):
         return pa.table(dict(zip(names, arrays)))
 
     def append(self, res) -> None:
-        t0 = time.perf_counter()
+        t0, span = self._begin_write(res)
         try:
-            tbl = self._to_arrow(res)
-            self.table.append(tbl)
+            with self._part("convert"):
+                tbl = self._to_arrow(res)
+            with self._part("commit"):
+                self.table.append(tbl)
         except Exception:
-            self._m_failures.inc()
+            self._fail_write(t0, span)
             raise
-        self._observe_write(t0, len(res.tx_id), tbl.nbytes)
+        self._observe_write(t0, span, len(res.tx_id), tbl.nbytes)
 
 
 def make_iceberg_sink(

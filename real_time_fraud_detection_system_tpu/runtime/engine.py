@@ -96,6 +96,57 @@ PHASES = ("source_poll", "host_prep", "dispatch", "result_wait",
 # dispatch), cold_append (one pass's payload fetch and landing, inside
 # state_compact).
 COLD_PHASES = ("cold_detect", "state_promote", "cold_append")
+# What the loop thread's pass is made of besides (PR 37; README, Tracing:
+# the span tree), each a span and a series of the same histogram:
+# loop_pass (one pass of run()'s loop), sink_join and sink_enqueue (the
+# two waits sink_wait adds up, with a checkpoint's drain), device_wait
+# and fetch (the two halves of result_wait), hooks (feedback, model
+# reload, learner), checkpoint, pace (the trigger's sleep; a pass whose
+# poll came back empty), and on the writer thread writer_queue (enqueue
+# return to write start). compact_fetch joins them where compaction is
+# armed.
+LOOP_PHASES = ("loop_pass", "sink_join", "sink_enqueue", "device_wait",
+               "fetch", "hooks", "checkpoint", "pace", "writer_queue")
+
+
+class _Phase:
+    """One measurement of one phase: the clock is read twice, and those
+    two readings are the phase's histogram observation, its entry in the
+    run's percentile tracker and, when tracing is on, its span."""
+
+    __slots__ = ("_eng", "name", "span", "_hist", "t0", "t1")
+
+    def __init__(self, eng: "ScoringEngine", name: str, span, hist):
+        self._eng = eng
+        self.name = name
+        self.span = span
+        self._hist = hist
+
+    def __enter__(self) -> "_Phase":
+        self.span.open()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = t1 = time.perf_counter()
+        self.span.close(self.t0, t1)
+        if self._hist is not None:
+            self._hist.observe(t1 - self.t0)
+        tracker = self._eng._trackers.get(self.name)
+        if tracker is not None:
+            tracker.record(t1 - self.t0)
+        return False
+
+    def fold(self, name: str) -> None:
+        """Count and record this phase as ``name``, one span with the
+        ``name`` before it where neither had a child."""
+        self.name = name
+        self._hist = self._eng._m_phase.get(name)
+        self.span.fold(name)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
 
 
 class ColdPromoteError(RuntimeError):
@@ -633,8 +684,10 @@ class ScoringEngine:
             ph: reg.histogram(
                 "rtfds_phase_seconds",
                 "per-batch loop-time decomposition by phase", phase=ph)
-            for ph in PHASES
+            for ph in PHASES + LOOP_PHASES
         }
+        # run()'s percentile trackers by phase name, for its length
+        self._trackers: dict = {}
         # How often the loop's join rule let a write overlap the next poll
         # (two names: a ratio reader sums every series of a name).
         self._m_sink_batches = reg.counter(
@@ -733,6 +786,14 @@ class ScoringEngine:
             for o in ("clean", "clobbered_online_updates")
         }
 
+    def _phase(self, name: str, batch: Optional[str] = None, hist=None,
+               **args) -> _Phase:
+        """``with self._phase(name): ...`` — the one way a phase of the
+        loop is timed (:class:`_Phase`). ``hist``: a histogram other than
+        ``rtfds_phase_seconds{phase=name}``."""
+        return _Phase(self, name, self.tracer.span(name, batch=batch, **args),
+                      hist if hist is not None else self._m_phase.get(name))
+
     # -- tiered feature store (key_mode="exact") ---------------------------
 
     def _state_shards(self) -> int:
@@ -782,6 +843,10 @@ class ScoringEngine:
                 "loop-thread seconds in one compaction pass: the dispatch "
                 "and the wait for its reclaimed counts, which drains the "
                 "steps in flight")
+            self._m_phase["compact_fetch"] = reg.histogram(
+                "rtfds_phase_seconds",
+                "per-batch loop-time decomposition by phase",
+                phase="compact_fetch")
             self._m_tier = {
                 t: reg.counter(
                     "rtfds_feature_tier_rows_total",
@@ -861,12 +926,12 @@ class ScoringEngine:
 
         self._promote = jax.jit(promote, donate_argnums=self._donate)
         reg = self.metrics
-        self._m_phase_cold = {
+        self._m_phase.update({
             ph: reg.histogram(
                 "rtfds_phase_seconds",
                 "per-batch loop-time decomposition by phase", phase=ph)
             for ph in COLD_PHASES
-        }
+        })
         self._m_cold_keys = reg.gauge(
             "rtfds_feature_cold_keys",
             "keys resident in the host cold tier (demoted, not yet "
@@ -896,10 +961,11 @@ class ScoringEngine:
         on the loop thread: when ``_maybe_compact`` returns, every
         demoted key is in the store's index and its rows are readable —
         before the host prep of any batch dispatched after this pass.
-        The keys' fetch is the wait for the pass itself; the rows' copies
-        off the device (up to ``cold_demote_slots x 16 NB`` bytes a
-        table) are started together and only for a table that demoted
-        something. The segment write follows on the writer thread. The
+        The pass itself was waited out by ``_maybe_compact``'s
+        ``compact_fetch``; the rows' copies off the device (up to
+        ``cold_demote_slots x 16 NB`` bytes a table) are started
+        together and only for a table that demoted something. The
+        segment write follows on the writer thread. The
         sharded engine's stacked ``[n_dev, K, ...]`` leaves need nothing
         special: the store flattens lanes."""
         parts = []
@@ -915,14 +981,12 @@ class ScoringEngine:
             parts.append((table, keys, pay[1:]))
         if not parts:
             return
-        t0 = time.perf_counter()
-        with self.tracer.span("cold_append"):
+        with self._phase("cold_append"):
             total = sum(
                 self._cold.append(table, keys,
                                   *(np.asarray(r) for r in rows),
                                   flush=False)
                 for table, keys, rows in parts)
-        self._m_phase_cold["cold_append"].observe(time.perf_counter() - t0)
         self._cold_writer.kick()
         self._m_cold_dem.inc(total)
         self._m_cold_keys.set(float(self._cold.keys_count))
@@ -960,26 +1024,25 @@ class ScoringEngine:
             fold_key,
         )
 
-        t0 = time.perf_counter()
         hits, cold_row = {}, None
-        for table, col in (("customer", "customer_id"),
-                           ("terminal", "terminal_id")):
-            ids = cols.get(col)
-            if (table not in self._cold_tables() or ids is None
-                    or not len(ids)):
-                continue
-            keys = fold_key(np.asarray(ids))
-            # the directory canonicalizes EMPTY_KEY collisions the same
-            # way (ops/keydir._canon) — mirror it or miss those keys
-            keys = np.where(keys == np.uint32(0xFFFFFFFF),
-                            np.uint32(0xFFFFFFFE), keys)
-            mask = self._cold.cold_mask(table, keys)
-            if mask.any():
-                hits[table] = np.unique(keys[mask])
-                cold_row = mask if cold_row is None else cold_row | mask
-        t1 = time.perf_counter()
-        self.tracer.add_span("cold_detect", t0, t1)
-        self._m_phase_cold["cold_detect"].observe(t1 - t0)
+        with self._phase("cold_detect"):
+            for table, col in (("customer", "customer_id"),
+                               ("terminal", "terminal_id")):
+                ids = cols.get(col)
+                if (table not in self._cold_tables() or ids is None
+                        or not len(ids)):
+                    continue
+                keys = fold_key(np.asarray(ids))
+                # the directory canonicalizes EMPTY_KEY collisions the
+                # same way (ops/keydir._canon) — mirror it or miss those
+                # keys
+                keys = np.where(keys == np.uint32(0xFFFFFFFF),
+                                np.uint32(0xFFFFFFFE), keys)
+                mask = self._cold.cold_mask(table, keys)
+                if mask.any():
+                    hits[table] = np.unique(keys[mask])
+                    cold_row = mask if cold_row is None \
+                        else cold_row | mask
         if not hits:
             return None
         self._m_cold_rows.inc(int(cold_row.sum()))
@@ -1003,9 +1066,8 @@ class ScoringEngine:
             ColdStoreCorruptError,
         )
 
-        t0 = time.perf_counter()
         checks = []
-        with self.tracer.span("state_promote"):
+        with self._phase("state_promote"):
             for table, keys in hits.items():
                 while True:
                     # a corrupt segment quarantines itself on its first
@@ -1039,8 +1101,6 @@ class ScoringEngine:
                         int(np.prod(payload[table][0].shape)))
                 self._cold.mark_promoted(table, keys)
                 self._m_cold_prom.inc(int(keys.size))
-        self._m_phase_cold["state_promote"].observe(
-            time.perf_counter() - t0)
         self._m_cold_keys.set(float(self._cold.keys_count))
         self._m_cold_bytes.set(float(self._cold.bytes))
         return checks
@@ -1192,36 +1252,38 @@ class ScoringEngine:
         if (not self._compact_every
                 or self.state.batches_done % self._compact_every != 0):
             return
-        t0 = time.perf_counter()
-        day = jnp.asarray(np.int32(self._max_day))
-        with self.tracer.span("state_compact", day=self._max_day):
+        with self._phase("state_compact", hist=self._m_compact_s,
+                         day=self._max_day):
+            day = jnp.asarray(np.int32(self._max_day))
             with self._recompile.step(step_signature(
                     day, static=(self.kind, "compact"))):
                 out = self._dispatch_step(
                     ("compact",), self._compact,
                     self.state.feature_state, day)
+            fstate, reclaimed = out[:2]
+            with self._phase("compact_fetch"):
+                # the wait for the pass, and for the steps in flight
+                # ahead of it: the pass's other outputs are ready with
+                # this one
+                reclaimed = np.asarray(reclaimed)
             if self._demote_slots:
-                fstate, reclaimed, payload = out
-                self._land_demotions(payload)
-            else:
-                fstate, reclaimed = out
-        self.state.feature_state = fstate
-        self._record_compaction(fstate, reclaimed)
-        self._m_compactions.inc()
-        self._m_compact_s.observe(time.perf_counter() - t0)
+                self._land_demotions(out[2])
+            self.state.feature_state = fstate
+            self._record_compaction(fstate, reclaimed)
+            self._m_compactions.inc()
 
     def _record_compaction(self, fstate, reclaimed) -> None:
         """Meter one compaction pass (counters, gauges, flight event) —
         the sharded engine overrides with the per-shard breakdown."""
-        rec = np.asarray(reclaimed)  # [customer, terminal]
+        rec = np.asarray(reclaimed)  # [customer, terminal], on the host
         occupied = {}
         for i, table in enumerate(("customer", "terminal")):
             if table in self._m_slots_rec:
                 self._m_slots_rec[table].inc(int(rec[i]))
             kd = getattr(fstate, f"{table}_dir")
             if kd is not None and table in self._m_slots_occ:
-                # the reclaimed fetch above already synced the step, so
-                # this scalar read is free
+                # compact_fetch already waited the pass out, so this
+                # scalar read is free
                 occ = int(kd.slot_capacity) - int(np.asarray(kd.free_top))
                 self._m_slots_occ[table].set(occ)
                 occupied[table] = occ
@@ -1765,14 +1827,13 @@ class ScoringEngine:
         N+1's H2D transfer and dispatch while batch N still computes —
         the double-buffered overlap of SURVEY §2.3 item 3.
         """
-        t0 = time.perf_counter()
         # Latest-wins dedup by tx_id (reference ROW_NUMBER/MERGE semantics,
         # kafka_s3_sink_transactions.py:173-222) on host — tx_ids are
         # int64. The C++ path (native/hostprep.cc) is the same math in
         # one O(n) hash pass + one fused pack pass, bit-identical
         # (differential-pinned); it lifts the host ceiling past what a
         # locally attached chip can consume. NumPy is the fallback.
-        with self.tracer.span("host_prep"):
+        with self._phase("host_prep") as prep:
             use_native = native.hostprep_available()
             keep = latest_wins_mask_host(cols["tx_id"], cols["kafka_ts_ms"])
             cols = {k: v[keep] for k, v in cols.items()}
@@ -1795,12 +1856,10 @@ class ScoringEngine:
                     label=cols.get("label"),
                     pad_to=pad,
                 ))
-            # t1 sits after ALL host packing on both paths, so
+            # the phase closes after ALL host packing on both paths, so
             # prep_s/dispatch_s attribute the same stages either way
-            t1 = time.perf_counter()
         promoted = (self._promote_returning(returning)
                     if returning is not None else ())
-        t1b = time.perf_counter()
         pre_state = None
         if self._nan_guard:
             # Donation is off under the guard, so these references stay
@@ -1808,7 +1867,7 @@ class ScoringEngine:
             # without the non-finite rows.
             pre_state = (self.state.feature_state, self.state.params,
                          self.state.batches_done, self.state.rows_done)
-        with self.tracer.span("dispatch", rows=n, pad=pad):
+        with self._phase("dispatch", rows=n, pad=pad) as disp:
             jbatch = jnp.asarray(packed)
             # Steady-state recompile alarm: the signature keys on what
             # the jit cache keys on from the engine's side — the packed
@@ -1832,43 +1891,55 @@ class ScoringEngine:
             # compute): by the time _finish_batch blocks, the transfer
             # has been running since compute finished.
             t_fetch = self._issue_host_fetch(probs, feats)
-            t2 = time.perf_counter()
         return {"cols": cols, "n": n, "probs": probs, "feats": feats,
-                "tier": tier, "t0": t0, "prep_s": t1 - t0,
-                "dispatch_s": t2 - t1b, "pre_state": pre_state,
+                "tier": tier, "t0": prep.t0, "prep_s": prep.seconds,
+                "dispatch_s": disp.seconds, "pre_state": pre_state,
                 "fetch_issue_t": t_fetch,
                 "promote_checks": promoted}
 
     def _finish_batch(self, handle: dict) -> BatchResult:
-        """Block on the handle's device futures; build the BatchResult."""
+        """Block on the handle's device futures (``device_wait``), then
+        build the BatchResult on the host (``fetch``)."""
         n = handle["n"]
+        tid = handle.get("trace_id")
         self._meter_fetch_overlap(handle)
-        self._check_promotes(handle)
-        if self._selective:
-            probs_np, feats_np = self._unpack_selective(handle)
+        # alerts-only mode (configured, or the overload ladder's rung-2
+        # degrade): the feature matrix stays in HBM. The sequence
+        # scorer's matrix is definitionally zeros (raw event channels
+        # replace engineered features) — never worth a D2H, and the
+        # host-side filler is a shared read-only buffer.
+        emit = self._emit_features_now() and self.kind != "sequence"
+        with self._phase("device_wait", batch=tid):
+            # the blocking materialization of exactly the leaves
+            # _issue_host_fetch started copying
+            if self._selective:
+                packed = np.asarray(handle["feats"]["packed"])
+            else:
+                feats_host = np.asarray(handle["feats"]) if emit else None
+                probs_host = (np.asarray(handle["probs"])
+                              if self.scorer != "cpu" else None)
+        with self._phase("fetch", batch=tid):
+            self._check_promotes(handle)
+            if self._selective:
+                probs_np, feats_np = self._unpack_selective(handle, packed)
+                return self._finish_result(handle, probs_np, feats_np)
+            if emit:
+                # astype: under emit_dtype="bfloat16" the transfer was
+                # bf16 (half the bytes); widen back for sinks/consumers
+                feats_np = feats_host[:n].astype(np.float32, copy=False)
+            else:
+                feats_np = self._zero_features(n)
+            if self.scorer == "cpu":
+                # parity/baseline oracle: host-side pipeline on the same
+                # features (sklearn pipeline, or a TrainedModel's
+                # pure-NumPy path)
+                fn = getattr(self.cpu_model, "predict_proba_np", None) or (
+                    self.cpu_model.predict_proba
+                )
+                probs_np = fn(feats_np.astype(np.float64))
+            else:
+                probs_np = probs_host[:n]
             return self._finish_result(handle, probs_np, feats_np)
-        if not self._emit_features_now() or self.kind == "sequence":
-            # alerts-only mode (configured, or the overload ladder's
-            # rung-2 degrade): the feature matrix stays in HBM. The
-            # sequence scorer's matrix is definitionally zeros (raw event
-            # channels replace engineered features) — never worth a D2H,
-            # and the host-side filler is a shared read-only buffer.
-            feats_np = self._zero_features(n)
-        else:
-            # astype: under emit_dtype="bfloat16" the transfer was bf16
-            # (half the bytes); widen back for sinks/consumers
-            feats_np = np.asarray(handle["feats"])[:n].astype(
-                np.float32, copy=False)
-        if self.scorer == "cpu":
-            # parity/baseline oracle: host-side pipeline on the same features
-            # (sklearn pipeline, or a TrainedModel's pure-NumPy path)
-            fn = getattr(self.cpu_model, "predict_proba_np", None) or (
-                self.cpu_model.predict_proba
-            )
-            probs_np = fn(feats_np.astype(np.float64))
-        else:
-            probs_np = np.asarray(handle["probs"])[:n]
-        return self._finish_result(handle, probs_np, feats_np)
 
     def _finish_result(self, handle: dict, probs_np: np.ndarray,
                        feats_np: np.ndarray) -> BatchResult:
@@ -1936,8 +2007,8 @@ class ScoringEngine:
         # strictly shrinks the surviving row set
         return self._finish_batch(h2)
 
-    def _unpack_selective(self, handle: dict) -> tuple:
-        """Decode the packed selective-emission transfer.
+    def _unpack_selective(self, handle: dict, flat: np.ndarray) -> tuple:
+        """Decode the packed selective-emission transfer ``flat``.
 
         One flat f32 fetch carries [probs(pad) | count(1) | idx(cap) |
         feats(cap·15)]. Flagged rows' feature vectors land bit-identical
@@ -1950,7 +2021,6 @@ class ScoringEngine:
         em = handle["feats"]
         pad = em["full"].shape[0]
         cap = (em["packed"].shape[0] - pad - 1) // (1 + N_FEATURES)
-        flat = np.asarray(em["packed"])
         # copy: a view into the packed fetch would pin the whole
         # pad+1+(1+15)·cap f32 buffer (~MBs/batch at the 262k big-batch
         # cap) for as long as any sink retains BatchResult.probs
@@ -2015,11 +2085,7 @@ class ScoringEngine:
         self._m_batches.inc()
         self._m_rows.inc(n)
         self._m_last.set(time.time())
-        self._maybe_compact()
-        # Device-memory gauges ride the batch cadence; on backends
-        # without memory stats (CPU) this is a single boolean check.
-        self._devmem.sample()
-        res = BatchResult(
+        return BatchResult(
             tx_id=cols["tx_id"],
             tx_datetime_us=cols["tx_datetime_us"],
             customer_id=cols["customer_id"],
@@ -2027,12 +2093,24 @@ class ScoringEngine:
             amount_cents=cols["tx_amount_cents"],
             features=feats_np,
             probs=probs_np,
-            latency_s=(
-                time.perf_counter() - handle["t0"]
-                - handle.get("waited", 0.0)
-            ),
+            latency_s=0.0,  # _close_batch's to say
             batch_index=self.state.batches_done,
         )
+
+    def _close_batch(self, handle: dict) -> BatchResult:
+        """``result_wait`` — the handle's :meth:`_finish_batch` — and what
+        follows a finished batch between device steps: the compaction on
+        its cadence, the memory gauges, and the batch's latency, which
+        ends here."""
+        with self._phase("result_wait", batch=handle.get("trace_id")) as wait:
+            res = self._finish_batch(handle)
+        handle["wait_s"] = wait.seconds
+        self._maybe_compact()
+        # Device-memory gauges ride the batch cadence; on backends
+        # without memory stats (CPU) this is a single boolean check.
+        self._devmem.sample()
+        res.latency_s = (time.perf_counter() - handle["t0"]
+                         - handle.get("waited", 0.0))
         self._m_lat.observe(res.latency_s)
         return res
 
@@ -2060,8 +2138,8 @@ class ScoringEngine:
         self._ensure_layout()
         tid = self.tracer.begin_batch(self.state.batches_done + 1)
         handle = self._start_batch(cols)
-        with self.tracer.span("result_wait", batch=tid):
-            return self._finish_batch(handle)
+        handle["trace_id"] = tid
+        return self._close_batch(handle)
 
     @property
     def supports_online_sgd(self) -> bool:
@@ -2355,7 +2433,8 @@ class ScoringEngine:
                     degrade_emission=_act_degrade_emission,
                     force_max_batch=_act_force_max),
                 recorder_fn=lambda: recorder)
-        phase_hist = self._m_phase
+        self._trackers = trackers
+        tracer = self.tracer
         # Source-poll time since the last finished batch — attributed to
         # the NEXT batch's flight record so per-batch phases sum to the
         # loop's wall time (minus trigger pacing, reported separately).
@@ -2384,19 +2463,22 @@ class ScoringEngine:
             # broker commits to the checkpoint cadence.
             feedback.auto_commit = False
 
-        def _write(inner, res, ctx) -> None:
+        def _write(inner, res, ctx, t_queued) -> None:
             # WRITER THREAD. The acknowledgement is this append's return;
-            # the span and the duration are taken here, where the write
-            # runs, so sink_write reads the write and never an enqueue.
+            # the phase is timed here, where the write runs, so
+            # sink_write reads the write and never an enqueue — that wait
+            # is writer_queue, from the enqueue's return to this start.
             trace_id, record = ctx
-            t_sink = time.perf_counter()
-            with self.tracer.span("sink_write", batch=trace_id):
+            tracer.set_role("writer")
+            with self._phase("sink_write", batch=trace_id) as write:
+                if t_queued is None or t_queued > write.t0:
+                    t_queued = write.t0  # taken off the queue at once
+                tracer.add_span("writer_queue", t_queued, write.t0,
+                                batch=trace_id, parent=0)
+                self._m_phase["writer_queue"].observe(write.t0 - t_queued)
                 inner.append(res)
-            sink_s = time.perf_counter() - t_sink
-            phase_hist["sink_write"].observe(sink_s)
-            trackers["sink_write"].record(sink_s)
             if record is not None:
-                record(sink_s)
+                record(write.seconds)
 
         writer = None
         if sink is not None:
@@ -2409,84 +2491,25 @@ class ScoringEngine:
                 registry=self.metrics, write=_write)
         rule = PollAhead(max(self.cfg.runtime.batch_buckets))
 
-        def _sink_blocked(fn, *args) -> None:
-            t_wait = time.perf_counter()
-            try:
+        def _sink_blocked(name: str, fn, *args, batch=None) -> None:
+            # the loop thread waiting on its writer, under the span that
+            # says at which point of the pass; together: sink_wait
+            with self._phase(name, batch=batch) as blocked:
                 fn(*args)
-            finally:
-                pending["sink_wait_s"] += time.perf_counter() - t_wait
+            pending["sink_wait_s"] += blocked.seconds
 
-        def _finish(handle: dict) -> None:
-            t_block = time.perf_counter()
-            # explicit batch= : with pipeline_depth > 1 this handle's
-            # trace id is OLDER than the tracer's current batch
-            with self.tracer.span("result_wait",
-                                  batch=handle.get("trace_id")):
-                res = self._finish_batch(handle)
-            # Loop-time decomposition: host prep (dedup + pad) vs H2D +
-            # dispatch (the per-step overhead pipelining hides) vs the
-            # result wait (device compute minus overlap).
-            prep_s = handle.get("prep_s", 0.0)
-            dispatch_s = handle.get("dispatch_s", 0.0)
-            wait_s = time.perf_counter() - t_block
-            trackers["host_prep"].record(prep_s)
-            trackers["dispatch"].record(dispatch_s)
-            trackers["result_wait"].record(wait_s)
-            trackers["latency"].record(res.latency_s, rows=len(res.tx_id))
-            phase_hist["host_prep"].observe(prep_s)
-            phase_hist["dispatch"].observe(dispatch_s)
-            phase_hist["result_wait"].observe(wait_s)
-            self.state.offsets = handle["source_offsets"]
-            record = None
-            if recorder is not None:
-                extra = {}
-                if handle.get("trace_id"):
-                    # cross-reference: a slow batch in the flight record
-                    # names its span waterfall in the exported trace
-                    extra["trace_id"] = handle["trace_id"]
-                phases = {"source_poll": pending["poll_s"],
-                          "host_prep": prep_s, "dispatch": dispatch_s,
-                          "result_wait": wait_s}
-                pending["poll_s"] = 0.0
-                depth_now = len(q)
+        def _join_writer() -> None:
+            # an idle writer is not waited for (drain() then only
+            # re-raises a failure it parked): no span a quiet pass
+            if writer.idle:
+                writer.drain()
+            else:
+                _sink_blocked("sink_join", writer.drain)
 
-                def record(sink_s: Optional[float] = None) -> None:
-                    # with a sink: on the writer thread, once the write
-                    # has a duration (the loop thread's phases were
-                    # fixed before the enqueue)
-                    if sink_s is not None:
-                        phases["sink_write"] = sink_s
-                    recorder.record_batch(
-                        res.batch_index, len(res.tx_id), phases,
-                        queue_depth=depth_now, latency_s=res.latency_s,
-                        **extra)
-            if writer is not None:
-                # What the loop thread was blocked on its writer since
-                # the last batch went to it — the join before a poll, a
-                # checkpoint's drain, that enqueue on a full queue — is
-                # this batch's sink_wait (the next-batch attribution of
-                # poll_s above).
-                sink_wait_s = pending["sink_wait_s"]
-                pending["sink_wait_s"] = 0.0
-                phase_hist["sink_wait"].observe(sink_wait_s)
-                trackers["sink_wait"].record(sink_wait_s)
-                if record is not None:
-                    phases["sink_wait"] = sink_wait_s
-                _sink_blocked(writer.append, res,
-                              (handle.get("trace_id"), record))
-                self._m_sink_batches.inc()
-                pending["handed"] += 1
-            elif record is not None:
-                record()
-            if auto is not None:
-                auto.observe(len(res.tx_id), res.latency_s)
-            if overload is not None:
-                rr = handle.pop("overload_replay_rows", None)
-                if rr is not None:
-                    # counted at FINISH: replay accounting reflects
-                    # state updates that landed, not dispatches
-                    overload.note_replayed(rr)
-                overload.observe_batch(len(res.tx_id), res.latency_s)
+        hooks = (feedback is not None or model_reload is not None
+                 or learning is not None)
+
+        def _run_hooks(res) -> None:
             if feedback is not None:
                 # Between-batch label application (before the checkpoint,
                 # so saved state includes the landed labels).
@@ -2533,41 +2556,118 @@ class ScoringEngine:
                 # candidate install / promotion / rollback decisions ride
                 # the batch cadence, between device steps
                 learning.on_batch(self)
+
+        def _checkpoint() -> None:
+            # Drain the writer BEFORE the state save: checkpointed
+            # offsets must TRAIL durable sink output (a crash then
+            # replays rows into parts that already landed — the
+            # exactly-once overwrite — never records progress for
+            # writes still sitting in a queue).
+            if writer is not None:
+                _join_writer()
+            if self._cold is not None:
+                # Buffered demotions become durable segments NOW so
+                # the lineage the checkpoint records is on disk, and
+                # restore can rebuild the exact cold index from
+                # manifests alone.
+                self._settle_cold()
+            self._maybe_exchange_cms()
+            checkpointer.save(self.checkpoint_state())
+            # Broker-side offsets (sources that have them, e.g. Kafka)
+            # are committed only AFTER the framework checkpoint lands:
+            # they trail it, never lead, so a crash replays — never
+            # skips — rows. Same for consumed feedback labels.
+            commit = getattr(source, "commit", None)
+            if commit is not None:
+                commit()
+            if feedback is not None:
+                feedback.commit()
+            if self._cold is not None:
+                # Only after the checkpoint (and its offset commits)
+                # landed is it safe to delete fully-promoted
+                # segments: a crash before this point restores a
+                # lineage that still lists them.
+                self._cold.gc()
+
+        def _finish(handle: dict) -> None:
+            # the handle's own trace id: with pipeline_depth > 1 it is
+            # OLDER than the tracer's current batch
+            tid = handle.get("trace_id")
+            res = self._close_batch(handle)
+            trackers["latency"].record(res.latency_s, rows=len(res.tx_id))
+            self.state.offsets = handle["source_offsets"]
+            record = None
+            if recorder is not None:
+                extra = {}
+                if handle.get("trace_id"):
+                    # cross-reference: a slow batch in the flight record
+                    # names its span waterfall in the exported trace
+                    extra["trace_id"] = handle["trace_id"]
+                # Loop-time decomposition: host prep (dedup + pad) vs
+                # H2D + dispatch (the per-step overhead pipelining
+                # hides) vs the result wait (device compute minus
+                # overlap) — the phases' own readings.
+                phases = {"source_poll": pending["poll_s"],
+                          "host_prep": handle.get("prep_s", 0.0),
+                          "dispatch": handle.get("dispatch_s", 0.0),
+                          "result_wait": handle["wait_s"]}
+                pending["poll_s"] = 0.0
+                depth_now = len(q)
+
+                def record(sink_s: Optional[float] = None) -> None:
+                    # with a sink: on the writer thread, once the write
+                    # has a duration (the loop thread's phases were
+                    # fixed before the enqueue)
+                    if sink_s is not None:
+                        phases["sink_write"] = sink_s
+                    recorder.record_batch(
+                        res.batch_index, len(res.tx_id), phases,
+                        queue_depth=depth_now, latency_s=res.latency_s,
+                        **extra)
+            if writer is not None:
+                # What the loop thread was blocked on its writer since
+                # the last batch went to it — the join before a poll, a
+                # checkpoint's drain, that enqueue on a full queue — is
+                # this batch's sink_wait (the next-batch attribution of
+                # poll_s above).
+                sink_wait_s = pending["sink_wait_s"]
+                pending["sink_wait_s"] = 0.0
+                self._m_phase["sink_wait"].observe(sink_wait_s)
+                trackers["sink_wait"].record(sink_wait_s)
+                if record is not None:
+                    phases["sink_wait"] = sink_wait_s
+                _sink_blocked("sink_enqueue", writer.append, res,
+                              (tid, record), batch=tid)
+                self._m_sink_batches.inc()
+                pending["handed"] += 1
+            elif record is not None:
+                record()
+            if auto is not None:
+                auto.observe(len(res.tx_id), res.latency_s)
+            if overload is not None:
+                rr = handle.pop("overload_replay_rows", None)
+                if rr is not None:
+                    # counted at FINISH: replay accounting reflects
+                    # state updates that landed, not dispatches
+                    overload.note_replayed(rr)
+                overload.observe_batch(len(res.tx_id), res.latency_s)
+            if hooks:
+                # feedback, model reload and the learner ride the batch
+                # cadence between device steps: one span for the three
+                with self._phase("hooks", batch=tid):
+                    _run_hooks(res)
             if checkpointer is not None and self.state.batches_done % every == 0:
-                # Drain the writer BEFORE the state save: checkpointed
-                # offsets must TRAIL durable sink output (a crash then
-                # replays rows into parts that already landed — the
-                # exactly-once overwrite — never records progress for
-                # writes still sitting in a queue).
-                if writer is not None:
-                    _sink_blocked(writer.drain)
-                if self._cold is not None:
-                    # Buffered demotions become durable segments NOW so
-                    # the lineage the checkpoint records is on disk, and
-                    # restore can rebuild the exact cold index from
-                    # manifests alone.
-                    self._settle_cold()
-                self._maybe_exchange_cms()
-                checkpointer.save(self.checkpoint_state())
-                # Broker-side offsets (sources that have them, e.g. Kafka)
-                # are committed only AFTER the framework checkpoint lands:
-                # they trail it, never lead, so a crash replays — never
-                # skips — rows. Same for consumed feedback labels.
-                commit = getattr(source, "commit", None)
-                if commit is not None:
-                    commit()
-                if feedback is not None:
-                    feedback.commit()
-                if self._cold is not None:
-                    # Only after the checkpoint (and its offset commits)
-                    # landed is it safe to delete fully-promoted
-                    # segments: a crash before this point restores a
-                    # lineage that still lists them.
-                    self._cold.gc()
+                with self._phase("checkpoint", batch=tid):
+                    _checkpoint()
             # NOTE: trigger pacing used to sleep HERE, once per finished
             # handle — so _drain() stacked one sleep per queued batch
             # before every checkpoint/idle flush. Pacing now happens once
             # per loop pass on the poll side (see the main loop).
+
+        def _next_id() -> Optional[str]:
+            # the trace id of the batch the loop will launch next
+            return (f"b{self.state.batches_done + len(q) + 1:08d}"
+                    if tracer.enabled else None)
 
         def _add_wait(dt: float) -> None:
             # Waiting for the NEXT batch to arrive is not part of any
@@ -2586,24 +2686,24 @@ class ScoringEngine:
                 # The join rule (PollAhead): no backlog → the inline
                 # order, write then poll; a backlog → poll now.
                 if not rule.ahead:
-                    _sink_blocked(writer.drain)
+                    _join_writer()
                 elif pending["handed"]:
                     self._m_sink_overlapped.inc(pending["handed"])
                 pending["handed"] = 0
-            t_poll = time.perf_counter()
             # Attribute the poll to the batch that will CONSUME it (the
             # same next-batch attribution the flight record uses via
             # pending["poll_s"]): begin_batch(idx) only runs after the
             # poll returns, so the current trace id here is still the
             # PREVIOUS batch's.
-            nid = (f"b{self.state.batches_done + len(q) + 1:08d}"
-                   if self.tracer.enabled else None)
-            with self.tracer.span("source_poll", batch=nid):
+            with self._phase("source_poll", batch=_next_id()) as poll:
                 c = source.poll_batch()
-            dt = time.perf_counter() - t_poll
-            _add_wait(dt)
-            phase_hist["source_poll"].observe(dt)
-            pending["poll_s"] += dt
+                if c is not None and not len(next(iter(c.values()), ())):
+                    # nothing arrived: the pass it belongs to is a
+                    # `pace`, and a quiet source's polls leave no span
+                    # each (the histogram still counts them)
+                    poll.span.cancel()
+            _add_wait(poll.seconds)
+            pending["poll_s"] += poll.seconds
             return c
 
         def _launch(cols, offs, replay_rows=None) -> None:
@@ -2640,114 +2740,128 @@ class ScoringEngine:
         carry = None  # (cols, offsets): a poll beyond the coalesce cap
         cap = max(self.cfg.runtime.batch_buckets)
         t_last_start = None  # previous batch's dispatch time (pacing)
-        try:
-            while not exhausted:
-                if heartbeat is not None:
-                    heartbeat.beat()
-                started = self.state.batches_done + len(q)
-                if max_batches and started >= max_batches:
-                    capped = True
-                    break
-                if self.stop_event is not None and self.stop_event.is_set():
-                    # Coordinated drain (fleet resize / graceful SIGTERM):
-                    # stop at a batch boundary with the capped-run tail —
-                    # deferred/shed batches stay behind the checkpointed
-                    # offsets by the defer() contract, so the caller's final
-                    # checkpoint resumes them exactly-once under the next
-                    # topology instead of force-draining them here.
-                    capped = True
-                    break
-                if trigger > 0 and t_last_start is not None:
-                    # Trigger pacing, once per loop pass on the POLL side:
-                    # batch starts stay >= trigger apart while already-
-                    # dispatched batches keep computing through the sleep.
-                    # (Pacing used to run inside _finish, stacking one sleep
-                    # per queued handle on every drain.) The slept time is
-                    # credited as wait so in-flight latencies measure the
-                    # pipeline, not the pacing.
-                    dt = trigger - (time.perf_counter() - t_last_start)
-                    if dt > 0:
+
+        def _pass(lap: _Phase) -> bool:
+            """One pass of the loop; → False to leave it."""
+            nonlocal exhausted, capped, carry
+            if heartbeat is not None:
+                heartbeat.beat()
+            started = self.state.batches_done + len(q)
+            if max_batches and started >= max_batches:
+                capped = True
+                return False
+            if self.stop_event is not None and self.stop_event.is_set():
+                # Coordinated drain (fleet resize / graceful SIGTERM):
+                # stop at a batch boundary with the capped-run tail —
+                # deferred/shed batches stay behind the checkpointed
+                # offsets by the defer() contract, so the caller's final
+                # checkpoint resumes them exactly-once under the next
+                # topology instead of force-draining them here.
+                capped = True
+                return False
+            if trigger > 0 and t_last_start is not None:
+                # Trigger pacing, once per loop pass on the POLL side:
+                # batch starts stay >= trigger apart while already-
+                # dispatched batches keep computing through the sleep.
+                # (Pacing used to run inside _finish, stacking one sleep
+                # per queued handle on every drain.) The slept time is
+                # credited as wait so in-flight latencies measure the
+                # pipeline, not the pacing.
+                dt = trigger - (time.perf_counter() - t_last_start)
+                if dt > 0:
+                    with self._phase("pace"):
                         # rtfdslint: disable=blocking-call-on-loop-thread (sanctioned pacing wait point: --trigger-interval spacing on the poll side, slept time credited as wait; regression-pinned in test_runtime trigger-pacing tests)
                         time.sleep(dt)
-                        _add_wait(dt)
-                if overload is not None and overload.want_replay():
-                    # Descending from rung 3 (or the spill hit its memory
-                    # cap): the deferred FIFO's head replays through the
-                    # normal scoring path BEFORE any live poll — rows reach
-                    # the feature state in exactly the order a
-                    # never-overloaded run would have seen them.
-                    item = overload.next_replay()
-                    if item is not None:
-                        _launch(item.cols, item.offsets,
-                                replay_rows=item.rows)
-                        continue
-                if carry is not None:
-                    cols, offs = carry
-                    carry = None
-                else:
-                    cols = _poll()
-                    if cols is None:
-                        break
-                    if len(next(iter(cols.values()), ())) == 0:
-                        # Idle live source (e.g. KafkaSource on a quiet
-                        # topic): not a batch — no sink append, no step, no
-                        # checkpoint cadence, no max_batches consumption.
-                        # Flush the in-flight batches (their results must not
-                        # wait for future traffic), then wait a trigger.
-                        _drain()
-                        if overload is not None:
-                            # the quiet period is the ladder's recovery
-                            # window: tick the controller so descend dwell
-                            # accumulates and deferred batches replay even
-                            # if live traffic never returns
-                            overload.idle_tick()
-                        if trigger > 0:
-                            # rtfdslint: disable=blocking-call-on-loop-thread (sanctioned wait point: idle live source with nothing in flight — sleeping one trigger IS the correct behavior, there is no work to stall)
-                            time.sleep(trigger)
-                        continue
-                    offs = list(source.offsets)
-                # The adaptive controller overrides the static coalesce
-                # target while active (it only MERGES small polls upward —
-                # an oversized poll still bucket-pads as before).
-                assemble = auto.target_rows() if auto is not None else coalesce
-                if assemble > 0:
-                    # Never assemble past the largest jit bucket: a poll that
-                    # would overflow is carried into the NEXT batch, and its
-                    # rows stay excluded from this batch's checkpoint offsets
-                    # (a crash must replay them, not skip them).
-                    target = min(assemble, cap)
-                    parts = [cols]
-                    total = len(next(iter(cols.values())))
-                    while total < target:
-                        more = _poll()
-                        if more is None:
-                            exhausted = True  # serve the tail, then stop
-                            break
-                        m = len(next(iter(more.values()), ()))
-                        if m == 0:
-                            break  # idle: serve what we have now
-                        if total + m > cap:
-                            carry = (more, list(source.offsets))
-                            break
-                        parts.append(more)
-                        total += m
-                        offs = list(source.offsets)
-                    if len(parts) > 1:
-                        cols = {k: np.concatenate([p[k] for p in parts])
-                                for k in parts[0]}
-                if overload is not None and overload.should_defer():
-                    # Rung 3 admission control: the whole assembled batch
-                    # defers to the durable spill instead of dispatching. It
-                    # consumes no batch_index (sink lineage stays gap-free)
-                    # and state.offsets stays at the last SCORED batch, so a
-                    # crash replays deferred rows from the checkpoint.
-                    # Batches dispatched BEFORE the climb finish first —
-                    # rung 3 holds nothing in flight, so their results land
-                    # instead of idling in the pipeline behind the deferral.
+                    _add_wait(dt)
+            if overload is not None and overload.want_replay():
+                # Descending from rung 3 (or the spill hit its memory
+                # cap): the deferred FIFO's head replays through the
+                # normal scoring path BEFORE any live poll — rows reach
+                # the feature state in exactly the order a
+                # never-overloaded run would have seen them.
+                item = overload.next_replay()
+                if item is not None:
+                    _launch(item.cols, item.offsets,
+                            replay_rows=item.rows)
+                    return True
+            if carry is not None:
+                cols, offs = carry
+                carry = None
+            else:
+                cols = _poll()
+                if cols is None:
+                    return False
+                if len(next(iter(cols.values()), ())) == 0:
+                    # Idle live source (e.g. KafkaSource on a quiet
+                    # topic): not a batch — no sink append, no step, no
+                    # checkpoint cadence, no max_batches consumption.
+                    # Flush the in-flight batches (their results must not
+                    # wait for future traffic), then wait a trigger. The
+                    # pass is a `pace`, one span with its like.
+                    lap.fold("pace")
                     _drain()
-                    overload.defer(cols, offs)
-                    continue
-                _launch(cols, offs)
+                    if overload is not None:
+                        # the quiet period is the ladder's recovery
+                        # window: tick the controller so descend dwell
+                        # accumulates and deferred batches replay even
+                        # if live traffic never returns
+                        overload.idle_tick()
+                    if trigger > 0:
+                        # rtfdslint: disable=blocking-call-on-loop-thread (sanctioned wait point: idle live source with nothing in flight — sleeping one trigger IS the correct behavior, there is no work to stall)
+                        time.sleep(trigger)
+                    return True
+                offs = list(source.offsets)
+            # The adaptive controller overrides the static coalesce
+            # target while active (it only MERGES small polls upward —
+            # an oversized poll still bucket-pads as before).
+            assemble = auto.target_rows() if auto is not None else coalesce
+            if assemble > 0:
+                # Never assemble past the largest jit bucket: a poll that
+                # would overflow is carried into the NEXT batch, and its
+                # rows stay excluded from this batch's checkpoint offsets
+                # (a crash must replay them, not skip them).
+                target = min(assemble, cap)
+                parts = [cols]
+                total = len(next(iter(cols.values())))
+                while total < target:
+                    more = _poll()
+                    if more is None:
+                        exhausted = True  # serve the tail, then stop
+                        break
+                    m = len(next(iter(more.values()), ()))
+                    if m == 0:
+                        break  # idle: serve what we have now
+                    if total + m > cap:
+                        carry = (more, list(source.offsets))
+                        break
+                    parts.append(more)
+                    total += m
+                    offs = list(source.offsets)
+                if len(parts) > 1:
+                    cols = {k: np.concatenate([p[k] for p in parts])
+                            for k in parts[0]}
+            if overload is not None and overload.should_defer():
+                # Rung 3 admission control: the whole assembled batch
+                # defers to the durable spill instead of dispatching. It
+                # consumes no batch_index (sink lineage stays gap-free)
+                # and state.offsets stays at the last SCORED batch, so a
+                # crash replays deferred rows from the checkpoint.
+                # Batches dispatched BEFORE the climb finish first —
+                # rung 3 holds nothing in flight, so their results land
+                # instead of idling in the pipeline behind the deferral.
+                _drain()
+                overload.defer(cols, offs)
+                return True
+            _launch(cols, offs)
+            return True
+
+        def _stream() -> None:
+            while not exhausted:
+                # one pass under one span, so that every instant of
+                # run() has a parent
+                with self._phase("loop_pass", batch=_next_id()) as lap:
+                    if not _pass(lap):
+                        break
             if overload is not None and not capped:
                 # Source exhausted with batches still deferred: the
                 # stream must not end owing rows — force-drain the FIFO
@@ -2773,23 +2887,37 @@ class ScoringEngine:
             # the output) must see fully-landed writes, and a deferred
             # writer error must surface in THIS run, with its own type.
             if writer is not None:
-                _sink_blocked(writer.drain)
+                _join_writer()
+
+        # `run` is the root of this run's span tree; the loop thread's
+        # role is set here, the writer's in _write
+        role = tracer.set_role("loop")
+        try:
+            with self._phase("run"):
+                try:
+                    _stream()
+                finally:
+                    if overload is not None:
+                        # revert every engine-side degrade so a later
+                        # run() on this engine starts clean (rung metrics
+                        # stay honest)
+                        overload.deactivate()
+                    if writer is not None:
+                        # no thread outlives a run; on the way out of an
+                        # exception the queued writes still land (the
+                        # restore fence or the replay's overwrite deals
+                        # with them) and the loop's exception stays the
+                        # one that propagates
+                        writer.stop()
+                self._m_qdepth.set(0)
+                if self._cold is not None:
+                    # Persist buffered demotions so the caller's
+                    # follow-up save records fresh segment lineage.
+                    with self._phase("cold_settle"):
+                        self._settle_cold()
         finally:
-            if overload is not None:
-                # revert every engine-side degrade so a later run() on
-                # this engine starts clean (rung metrics stay honest)
-                overload.deactivate()
-            if writer is not None:
-                # no thread outlives a run; on the way out of an
-                # exception the queued writes still land (the restore
-                # fence or the replay's overwrite deals with them) and
-                # the loop's exception stays the one that propagates
-                writer.stop()
-        self._m_qdepth.set(0)
-        if self._cold is not None:
-            # Persist buffered demotions so the caller's follow-up save
-            # records fresh segment lineage.
-            self._settle_cold()
+            tracer.set_role(role)
+            self._trackers = {}
         wall = time.perf_counter() - t_start
         cpu_s = time.process_time() - t_cpu0
         # LatencyTracker-backed snapshots: exact percentiles over the

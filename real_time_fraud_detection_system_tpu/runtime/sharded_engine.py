@@ -289,12 +289,12 @@ class ShardedScoringEngine(ScoringEngine):
         # padding), the fullest shard's rows and the mean shard's
         # (imbalance = max ÷ mean), and how often the step's exchange
         # outgrew its buckets.
-        self._m_phase_mesh = {
+        self._m_phase.update({
             ph: self.metrics.histogram(
                 "rtfds_phase_seconds",
                 "per-batch loop-time decomposition by phase", phase=ph)
             for ph in ("partition", "assemble")
-        }
+        })
         self._m_chunks = {
             routed: self.metrics.counter(
                 "rtfds_shard_chunks_total",
@@ -854,8 +854,7 @@ class ShardedScoringEngine(ScoringEngine):
         :meth:`~.engine.ScoringEngine.run`'s double-buffering overlaps the
         next batch's partition + H2D with this batch's mesh compute.
         """
-        t0 = time.perf_counter()
-        with self.tracer.span("host_prep"):
+        with self._phase("host_prep") as prep:
             keep = latest_wins_mask_host(cols["tx_id"], cols["kafka_ts_ms"])
             cols = {k: v[keep] for k, v in cols.items()}
             self._validate_sharded(cols)
@@ -875,25 +874,42 @@ class ShardedScoringEngine(ScoringEngine):
                 self._m_rows_max.inc(int(loads.max()))
                 self._m_rows_mean.inc(n / self.n_dev)
 
-            t_part = time.perf_counter()
-            with self.tracer.span("partition"):
+            with self._phase("partition"):
                 chunks = partition_batch_spill(
                     cols, self.n_dev, self.rows_per_shard
                 ) if n else []
-            self._m_phase_mesh["partition"].observe(
-                time.perf_counter() - t_part)
-        t_prepped = time.perf_counter()
         # promote before score, ahead of the first chunk's step
         promoted = (self._promote_returning(returning)
                     if returning is not None else ())
-        # host prep ends here: the chunk loop below is dispatch (make_
+        # host prep ended above: the chunk loop below is dispatch (make_
         # batch + H2D + jit launches), split out so the sharded loop's
-        # phase decomposition matches the single-chip engine's.
-        t_prep = time.perf_counter()
+        # phase decomposition matches the single-chip engine's — one
+        # dispatch phase over all chunk launches (the per-chunk jit calls
+        # are its children on the profiler timeline).
         parts = []
         tier_parts = []  # exact mode: per-chunk [n_dev, 4] tier vectors
         overflow_parts = []  # per-chunk exchange-overflow scalars
-        t_fetch = None  # last chunk's async-fetch issue time
+        with self._phase("dispatch", chunks=len(chunks)) as disp:
+            t_fetch = self._dispatch_chunks(
+                chunks, parts, tier_parts, overflow_parts)
+        handle = {"cols": cols, "n": n, "parts": parts, "t0": prep.t0,
+                  "prep_s": prep.seconds, "dispatch_s": disp.seconds,
+                  "fetch_issue_t": t_fetch}
+        if tier_parts:
+            handle["tier_shard"] = tier_parts
+        if overflow_parts:
+            handle["exchange_overflow"] = overflow_parts
+        # notify compaction's recency cutoff (the base engine does this
+        # in its own _start_batch; the sharded path overrides it wholesale)
+        self._note_batch_days(cols)
+        handle["promote_checks"] = promoted
+        return handle
+
+    def _dispatch_chunks(self, chunks, parts: list, tier_parts: list,
+                         overflow_parts: list) -> Optional[float]:
+        """Launch one sharded step a chunk, filling the three lists the
+        batch's finish reads; → the last async fetch's issue time."""
+        t_fetch = None
         for part_cols, rows, pos in chunks:
             batch = make_batch(
                 customer_id=part_cols["customer_id"],
@@ -973,66 +989,92 @@ class ShardedScoringEngine(ScoringEngine):
             # dispatches and the next batch's host prep
             t_fetch = self._issue_host_fetch(probs, feats) or t_fetch
             parts.append((rows, pos, probs, feats))
-        t_disp = time.perf_counter()
-        if chunks:
-            # one dispatch span over all chunk launches (the per-chunk
-            # jit calls are its children on the profiler timeline)
-            self.tracer.add_span("dispatch", t_prep, t_disp,
-                                 chunks=len(chunks))
-        handle = {"cols": cols, "n": n, "parts": parts, "t0": t0,
-                  "prep_s": t_prepped - t0, "dispatch_s": t_disp - t_prep,
-                  "fetch_issue_t": t_fetch}
-        if tier_parts:
-            handle["tier_shard"] = tier_parts
-        if overflow_parts:
-            handle["exchange_overflow"] = overflow_parts
-        # notify compaction's recency cutoff (the base engine does this
-        # in its own _start_batch; the sharded path overrides it wholesale)
-        self._note_batch_days(cols)
-        handle["promote_checks"] = promoted
-        return handle
+        return t_fetch
 
     def _finish_batch(self, handle: dict) -> BatchResult:
         n = handle["n"]
+        tid = handle.get("trace_id")
         self._meter_fetch_overlap(handle)
-        self._check_promotes(handle)
         # _emit_features_now, not the raw config flag: the overload
         # ladder's rung-2 degrade (inherited run() loop) switches the
         # mesh engine to alerts-only emission the same host-side way —
         # the shard_map step and both AOT variants are untouched.
         emit = self._emit_features_now()
-        probs_np = np.zeros(n, dtype=np.float32)
-        if self.kind == "sequence" or not emit:
-            # nothing below writes the feature matrix on these paths
-            # (sequence parts carry feats=None; alerts-only skips the
-            # per-shard feats copy) — share the read-only staging buffer
-            feats_np = self._zero_features(n)
-        else:
-            feats_np = np.zeros((n, N_FEATURES), dtype=np.float32)
-        overflowed = False  # per BATCH, however many chunks overflow
-        # Re-assembly = the host's permutation of each chunk's slots back
-        # into input order, timed apart from the wait for the device (the
-        # np.asarray of a chunk's results) that precedes it.
-        asm_s = 0.0
+        with self._phase("device_wait", batch=tid):
+            # the wait for the device: every chunk's results on the host
+            # (selective emission: one packed fetch a chunk; alerts-only
+            # skips the per-shard feature D2H, same contract as the
+            # single-chip engine)
+            fetched = [
+                (np.asarray(feats["packed"]), None)
+                if isinstance(feats, dict) else
+                (np.asarray(probs),
+                 np.asarray(feats) if feats is not None and emit else None)
+                for _, _, probs, feats in handle["parts"]]
+        with self._phase("fetch", batch=tid):
+            self._check_promotes(handle)
+            probs_np = np.zeros(n, dtype=np.float32)
+            if self.kind == "sequence" or not emit:
+                # nothing below writes the feature matrix on these paths
+                # (sequence parts carry feats=None; alerts-only skips the
+                # per-shard feats copy) — share the read-only staging
+                # buffer
+                feats_np = self._zero_features(n)
+            else:
+                feats_np = np.zeros((n, N_FEATURES), dtype=np.float32)
+            if handle["parts"]:
+                # Re-assembly = the host's permutation of each chunk's
+                # slots back into input order, apart from the wait for
+                # the device that precedes it.
+                with self._phase("assemble", batch=tid):
+                    overflowed = self._assemble(
+                        handle["parts"], fetched, probs_np, feats_np)
+                if overflowed:
+                    # once per BATCH however many chunks overflow,
+                    # matching the single-chip counter semantics
+                    # (engine.py: "batches whose flagged-row count
+                    # overflowed")
+                    self.selective_overflows += 1
+            for x in handle.pop("exchange_overflow", ()):
+                self._m_xchg_overflow.inc(int(x))
+            tier_parts = handle.pop("tier_shard", None)
+            if tier_parts is not None:
+                # per-shard tier accounting ([n_dev, 4] summed over
+                # chunks): shard-labeled counters get their own rows, the
+                # base table-level counters get the shard sums — so the
+                # global healthz/dashboard contract is identical on the
+                # mesh. The claim rounds (columns 2, 3) have table-level
+                # series only.
+                tier = np.zeros((self.n_dev, 4), np.float64)
+                for t in tier_parts:
+                    tier += np.asarray(t)
+                if self._m_tier_shard is not None:
+                    for s in range(self.n_dev):
+                        self._m_tier_shard[("dense", s)].inc(
+                            float(tier[s, 0]))
+                        self._m_tier_shard[("cms", s)].inc(
+                            float(tier[s, 1]))
+                handle["tier"] = tier.sum(axis=0)  # global, as one chip's
+            return self._emit_result(handle, probs_np, feats_np)
 
-        def assembled(t_from: float) -> float:
-            t_to = time.perf_counter()
-            self.tracer.add_span("assemble", t_from, t_to,
-                                 batch=handle.get("trace_id"))
-            return t_to - t_from
-
-        for rows, pos, probs, feats in handle["parts"]:
+    @staticmethod
+    def _assemble(parts, fetched, probs_np, feats_np) -> bool:
+        """Permute every chunk's fetched results into ``probs_np`` /
+        ``feats_np`` (input order); → whether a chunk's flagged rows
+        overflowed the selective-emission cap."""
+        overflowed = False
+        for (rows, pos, _, feats), (head, feats_host) in zip(parts,
+                                                              fetched):
             if isinstance(feats, dict):
-                # selective emission: one packed fetch per chunk carries
+                # selective emission: the packed fetch carries
                 # [probs(pad) | count | idx(cap) | feats(cap·15)] — the
                 # same layout the single-chip engine unpacks; indices are
                 # chunk SLOTS, mapped back to original batch rows via the
                 # chunk's (pos → rows) placement.
+                flat = head
                 pad = feats["full"].shape[0]
                 cap = ((feats["packed"].shape[0] - pad - 1)
                        // (1 + N_FEATURES))
-                flat = np.asarray(feats["packed"])
-                t_asm = time.perf_counter()
                 probs_np[rows] = flat[:pad][pos]
                 count = int(flat[pad])
                 if count > cap:
@@ -1048,42 +1090,11 @@ class ShardedScoringEngine(ScoringEngine):
                     # target is a real batch row
                     feats_np[slot_to_row[idx]] = sel.reshape(
                         count, N_FEATURES)
-                asm_s += assembled(t_asm)
                 continue
-            probs_host = np.asarray(probs)
-            # alerts-only mode skips the per-shard feature D2H, same
-            # contract as the single-chip engine
-            feats_host = (np.asarray(feats)
-                          if feats is not None and emit else None)
-            t_asm = time.perf_counter()
-            probs_np[rows] = probs_host[pos]
+            probs_np[rows] = head[pos]
             if feats_host is not None:
                 feats_np[rows] = feats_host[pos]
-            asm_s += assembled(t_asm)
-        if handle["parts"]:
-            self._m_phase_mesh["assemble"].observe(asm_s)
-        for x in handle.pop("exchange_overflow", ()):
-            self._m_xchg_overflow.inc(int(x))
-        if overflowed:
-            # once per batch, matching the single-chip counter semantics
-            # (engine.py: "batches whose flagged-row count overflowed")
-            self.selective_overflows += 1
-        tier_parts = handle.pop("tier_shard", None)
-        if tier_parts is not None:
-            # per-shard tier accounting ([n_dev, 4] summed over chunks):
-            # shard-labeled counters get their own rows, the base
-            # table-level counters get the shard sums — so the global
-            # healthz/dashboard contract is identical on the mesh. The
-            # claim rounds (columns 2, 3) have table-level series only.
-            tier = np.zeros((self.n_dev, 4), np.float64)
-            for t in tier_parts:
-                tier += np.asarray(t)
-            if self._m_tier_shard is not None:
-                for s in range(self.n_dev):
-                    self._m_tier_shard[("dense", s)].inc(float(tier[s, 0]))
-                    self._m_tier_shard[("cms", s)].inc(float(tier[s, 1]))
-            handle["tier"] = tier.sum(axis=0)  # global, as one chip's
-        return self._emit_result(handle, probs_np, feats_np)
+        return overflowed
 
     # -- feedback into the owner-partitioned terminal table ----------------
 

@@ -68,7 +68,15 @@ class _SourceTelemetry:
         # implementation is serving.
         self._m_lag = None
 
-    def _observe_poll(self, t0: float, cols: Optional[dict],
+    def _begin_poll(self) -> tuple:
+        """→ ``(t0, span)``: the poll's start and its open
+        ``source/<kind>`` span, a child of whatever span the calling
+        thread has open (the engine's ``source_poll``, whose batch it
+        takes)."""
+        span = self._tracer.span(f"source/{self._source_kind}").open()
+        return time.perf_counter(), span
+
+    def _observe_poll(self, t0: float, span, cols: Optional[dict],
                       lag: Optional[int] = None) -> None:
         t1 = time.perf_counter()
         self._m_poll.observe(t1 - t0)
@@ -77,14 +85,9 @@ class _SourceTelemetry:
             n = len(next(iter(cols.values()), ()))
             if n:
                 self._m_ingested.inc(n)
-        if self._tracer.enabled:
-            # Timeline-only (batch=""): the engine's source_poll span
-            # carries the batch attribution; with pipelining this poll
-            # may serve a LATER batch than the tracer's current one, so
-            # claiming the current id would lie. On the Perfetto
-            # timeline the span still nests under source_poll by time.
-            self._tracer.add_span(f"source/{self._source_kind}", t0, t1,
-                                  batch="", rows=n)
+            else:
+                span.cancel()  # nothing arrived: no span a quiet poll
+        span.close(t0, t1, rows=n)
         if lag is not None:
             if self._m_lag is None:
                 self._m_lag = get_registry().gauge(
@@ -195,14 +198,14 @@ class ReplaySource(_SourceTelemetry):
 
     def poll_batch(self) -> Optional[dict]:
         """Next micro-batch as a column dict (None when exhausted)."""
-        t0 = time.perf_counter()
+        t0, span = self._begin_poll()
         cols = self._poll_inner()
         if self.mode == "columnar":
             lag = self.txs.n - self._pos
         else:
             lag = sum(self.broker.end_offsets(
                 "debezium.payment.transactions")) - sum(self._offsets)
-        self._observe_poll(t0, cols, lag=lag)
+        self._observe_poll(t0, span, cols, lag=lag)
         return cols
 
     def _poll_inner(self) -> Optional[dict]:
@@ -282,11 +285,11 @@ class SyntheticSource(_SourceTelemetry):
         self._init_source_metrics("synthetic")
 
     def poll_batch(self) -> Optional[dict]:
-        t0 = time.perf_counter()
+        t0, span = self._begin_poll()
         cols = self._replay._poll_inner()
         if cols is not None and self.rate_tps > 0:
             time.sleep(len(cols["tx_id"]) / self.rate_tps)
-        self._observe_poll(t0, cols,
+        self._observe_poll(t0, span, cols,
                            lag=self._replay.txs.n - self._replay._pos)
         return cols
 
@@ -396,16 +399,16 @@ class RawTableSource(_SourceTelemetry):
         return len(self._cols["tx_id"])
 
     def poll_batch(self) -> Optional[dict]:
-        t0 = time.perf_counter()
+        t0, span = self._begin_poll()
         if self._pos >= self.n:
-            self._observe_poll(t0, None, lag=0)
+            self._observe_poll(t0, span, None, lag=0)
             return None
         s, e = self._pos, min(self._pos + self.batch_rows, self.n)
         self._pos = e
         out = {k: v[s:e] for k, v in self._cols.items()}
         # replayed history: event time doubles as the transport timestamp
         out["kafka_ts_ms"] = out["tx_datetime_us"] // 1000
-        self._observe_poll(t0, out, lag=self.n - self._pos)
+        self._observe_poll(t0, span, out, lag=self.n - self._pos)
         return out
 
     @property
@@ -465,7 +468,7 @@ class PartitionAffineSource(_SourceTelemetry):
             "all)", process=str(topology.process_id))
 
     def poll_batch(self) -> Optional[dict]:
-        t0 = time.perf_counter()
+        t0, span = self._begin_poll()
         cols = self.inner.poll_batch()
         if cols is not None and len(next(iter(cols.values()), ())):
             mine = self.topology.owns(cols["customer_id"])
@@ -476,7 +479,7 @@ class PartitionAffineSource(_SourceTelemetry):
         # a fully-filtered batch surfaces as 0 rows, which the engine
         # treats as an idle poll and polls again — the inner cursor has
         # advanced, so the stream still terminates
-        self._observe_poll(t0, cols)
+        self._observe_poll(t0, span, cols)
         return cols
 
     @property
@@ -551,7 +554,7 @@ class OwnershipFloorSource(_SourceTelemetry):
             "after a fleet shrink merge)")
 
     def poll_batch(self) -> Optional[dict]:
-        t0 = time.perf_counter()
+        t0, span = self._begin_poll()
         pos = int(self.inner.offsets[0])  # global position of next row
         cols = self.inner.poll_batch()
         n = 0 if cols is None else len(next(iter(cols.values()), ()))
@@ -564,7 +567,7 @@ class OwnershipFloorSource(_SourceTelemetry):
             if n_skip:
                 self._m_floor_skipped.inc(n_skip)
                 cols = {k: v[keep] for k, v in cols.items()}
-        self._observe_poll(t0, cols)
+        self._observe_poll(t0, span, cols)
         return cols
 
     @property
@@ -724,12 +727,12 @@ class KafkaSource(_SourceTelemetry):
         (the default) returns an empty poll as a zero-row wait instead,
         by polling again on the next engine trigger.
         """
-        t0 = time.perf_counter()
+        t0, span = self._begin_poll()
         cols = self._poll_inner()
         # no lag gauge: a broker high-watermark query per poll is an
         # extra RPC on the hot path; scrape consumer-group lag from the
         # broker's own exporter instead
-        self._observe_poll(t0, cols)
+        self._observe_poll(t0, span, cols)
         return cols
 
     def _poll_inner(self) -> Optional[dict]:

@@ -1,6 +1,5 @@
 from real_time_fraud_detection_system_tpu.utils.timing import (  # noqa: F401
     LatencyTracker,
-    Timer,
     date_to_epoch_s,
 )
 from real_time_fraud_detection_system_tpu.utils.logging import (  # noqa: F401

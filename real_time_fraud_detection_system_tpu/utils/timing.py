@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import datetime as _dt
 import time
-from typing import Dict, Optional
+from typing import Dict
 
 import numpy as np
 
@@ -20,18 +20,6 @@ def date_to_epoch_s(date: str) -> int:
     """ISO date string → seconds since the unix epoch (UTC midnight)."""
     d = _dt.date.fromisoformat(date)
     return int((d - _dt.date(1970, 1, 1)).days) * 86400
-
-
-class Timer:
-    """Context-manager wall timer: ``with Timer() as t: ...; t.seconds``."""
-
-    def __enter__(self) -> "Timer":
-        self._t0 = time.perf_counter()
-        self.seconds = 0.0
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.seconds = time.perf_counter() - self._t0
 
 
 class LatencyTracker:

@@ -565,7 +565,7 @@ def test_kafka_style_source_never_sets_lag_gauge():
     try:
         src = _SourceTelemetry()
         src._init_source_metrics("kafka")
-        src._observe_poll(0.0, {"tx_id": [1, 2]})  # no lag known
+        src._observe_poll(*src._begin_poll(), {"tx_id": [1, 2]})  # no lag
         assert reg.get("rtfds_source_lag_rows") is None
         server = MetricsServer(port=0, registry=reg,
                                max_source_lag_rows=10).start()
@@ -575,7 +575,7 @@ def test_kafka_style_source_never_sets_lag_gauge():
             assert "source_lag_rows" not in body["checks"]
         finally:
             server.stop()
-        src._observe_poll(0.0, None, lag=50)  # a source that CAN: sets
+        src._observe_poll(*src._begin_poll(), None, lag=50)  # one that CAN
         assert reg.get("rtfds_source_lag_rows").value == 50
     finally:
         reg.clear()

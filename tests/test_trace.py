@@ -125,6 +125,177 @@ def test_disabled_tracer_is_inert_and_returns_empty_ids():
     assert len(tr) == 0
 
 
+# ---------------------------------------------------------------------------
+# the span tree: ids, parents by the thread's own stack, roles, self time
+# ---------------------------------------------------------------------------
+
+def test_parent_is_the_innermost_open_span_of_the_same_thread():
+    import threading
+
+    tr = Tracer().configure(enabled=True, annotate=False)
+    tr.set_role("loop")
+    other = {}
+
+    def writer():
+        tr.set_role("writer")
+        with tr.span("sink_write") as w:
+            with tr.span("sink/encode"):
+                pass
+        other["write"] = w.id
+
+    with tr.span("run") as run:
+        with tr.span("loop_pass") as lap:
+            with tr.span("host_prep") as prep:
+                # a thread started under an open span owes it nothing:
+                # the stack is the thread's own, not containment in time
+                t = threading.Thread(target=writer)
+                t.start()
+                t.join(timeout=10)
+                assert not t.is_alive()
+                with tr.span("cold_detect"):
+                    pass
+            tr.instant("marker")
+    by = {s.name: s for s in tr.snapshot()}
+    assert by["run"].parent == 0 and by["run"].id == run.id
+    assert by["loop_pass"].parent == run.id
+    assert by["host_prep"].parent == lap.id
+    assert by["cold_detect"].parent == prep.id
+    assert by["marker"].parent == lap.id
+    assert by["sink_write"].parent == 0
+    assert by["sink/encode"].parent == other["write"]
+    assert {s.role for s in tr.snapshot()
+            if s.name.startswith("sink")} == {"writer"}
+    assert by["run"].role == by["cold_detect"].role == "loop"
+    assert len({s.id for s in tr.snapshot()}) == len(tr.snapshot())
+    # a thread that never named its role
+    t = threading.Thread(target=lambda: tr.instant("elsewhere"))
+    t.start()
+    t.join(timeout=10)
+    assert tr.snapshot()[-1].role == "other"
+    tr.set_role("other")
+
+
+def test_self_time_is_duration_minus_what_the_children_cover():
+    tr = Tracer().configure(enabled=True, annotate=False)
+    with tr.span("result_wait"):
+        with tr.span("device_wait"):
+            time.sleep(0.02)
+        with tr.span("fetch"):
+            time.sleep(0.005)
+        time.sleep(0.01)  # result_wait's own
+    by = {s.name: s for s in tr.snapshot()}
+    rw = by["result_wait"]
+    assert rw.child_s == pytest.approx(
+        by["device_wait"].dur_s + by["fetch"].dur_s)
+    assert rw.self_s == pytest.approx(rw.dur_s - rw.child_s)
+    assert 0.009 < rw.self_s < rw.dur_s - 0.024
+    assert by["fetch"].self_s == by["fetch"].dur_s  # a leaf
+
+
+def test_add_span_takes_an_explicit_parent_or_the_stack():
+    tr = Tracer().configure(enabled=True, annotate=False)
+    t = time.perf_counter()
+    with tr.span("sink_write", batch="b00000007") as w:
+        # a wait measured across threads hangs where it is told to
+        tr.add_span("writer_queue", t - 0.5, t, batch="b00000007", parent=0)
+        # work already timed inside an open span is its child, and takes
+        # its batch
+        tr.add_span("xla_compile", t, t + 0.001)
+    tr.add_span("late", t, t + 0.001, parent=w.id)
+    by = {s.name: s for s in tr.snapshot()}
+    assert by["writer_queue"].parent == 0
+    assert by["writer_queue"].dur_s == pytest.approx(0.5)
+    assert by["xla_compile"].parent == w.id
+    assert by["xla_compile"].trace_id == "b00000007"
+    assert by["late"].parent == w.id
+    assert by["sink_write"].child_s == pytest.approx(0.001)
+
+
+def test_a_span_without_a_batch_takes_its_parents():
+    tr = Tracer().configure(enabled=True, annotate=False)
+    tr.begin_batch(9)  # the batch the loop is on now
+    with tr.span("source_poll", batch="b00000010"):
+        with tr.span("decode"):
+            pass
+    with tr.span("host_prep"):
+        pass
+    by = {s.name: s for s in tr.snapshot()}
+    assert by["decode"].batch == 10 and by["decode"].trace_id == "b00000010"
+    assert by["host_prep"].batch == 9
+
+
+def test_cancel_leaves_no_record_and_fold_lengthens_the_last():
+    tr = Tracer().configure(enabled=True, annotate=False)
+    with tr.span("run") as run:
+        for _ in range(50):  # a quiet source's passes
+            with tr.span("loop_pass") as lap:
+                with tr.span("source_poll") as poll:
+                    poll.cancel()
+                lap.fold("pace")
+        with tr.span("loop_pass") as lap:  # a pass with work in it
+            with tr.span("source_poll"):
+                pass
+        for _ in range(3):
+            with tr.span("loop_pass") as lap:
+                lap.fold("pace")
+    spans = tr.snapshot()
+    assert [s.name for s in spans] == [
+        "pace", "source_poll", "loop_pass", "pace", "run"]
+    assert spans[0].args["folded"] == 50 and spans[3].args["folded"] == 3
+    assert spans[0].parent == spans[3].parent == run.id
+    assert spans[0].t1 <= spans[1].t0
+    assert spans[-1].child_s == pytest.approx(
+        sum(s.dur_s for s in spans if s.parent == run.id), abs=1e-4)
+    assert tr.dropped == 0
+    # a cancelled span's children still close cleanly under its parent
+    with tr.span("outer") as outer:
+        with tr.span("gone") as gone:
+            gone.cancel()
+        with tr.span("kept"):
+            pass
+    assert tr.snapshot()[-2].name == "kept"
+    assert tr.snapshot()[-2].parent == outer.id
+
+
+def test_a_span_left_open_by_an_exception_does_not_adopt_the_rest():
+    tr = Tracer().configure(enabled=True, annotate=False)
+    with tr.span("run") as run:
+        with pytest.raises(ValueError):
+            with tr.span("source_poll"):
+                tr.span("source/kafka").open()  # never closed: it raised
+                raise ValueError("poll failed")
+        with tr.span("host_prep"):
+            pass
+    by = {s.name: s for s in tr.snapshot()}
+    assert by["host_prep"].parent == run.id
+    assert "source/kafka" not in by
+
+
+def test_chrome_export_carries_the_tree():
+    tr = Tracer().configure(enabled=True, annotate=False)
+    tr.set_role("loop")
+    with tr.span("run"):
+        with tr.span("loop_pass"):
+            pass
+    tr.set_role("other")
+    events = {e["name"]: e for e in tr.export_chrome()["traceEvents"]
+              if e["ph"] == "X"}
+    assert events["run"]["args"]["parent"] == 0
+    assert events["loop_pass"]["args"]["parent"] == \
+        events["run"]["args"]["id"] > 0
+    assert events["loop_pass"]["args"]["role"] == "loop"
+
+
+def test_disabled_span_takes_every_call_of_a_live_one():
+    span = Tracer().span("x")
+    assert span.open() is span
+    span.cancel()
+    span.fold("pace")
+    span.close(0.0, 1.0, rows=3)
+    with span:
+        pass
+
+
 def _batch_of_spans(tr):
     """One serving batch's worth of tracer traffic: 5 live phase spans
     + 2 retroactive source/sink spans."""
@@ -177,6 +348,54 @@ def test_summarize_chrome_critical_path_and_topk():
     assert b1["critical_phase"] == "dispatch"
     assert b1["phases_ms"]["dispatch"] == pytest.approx(10.0, abs=0.1)
     assert s["slowest_spans"][0]["name"] == "dispatch"
+
+
+def test_summarize_chrome_on_a_nested_trace():
+    """With parents, a batch's total is its outermost spans' duration —
+    not every span's, which counts a child twice — and its critical
+    phase the largest SELF time, not the longest span (the pass itself)."""
+    tr = Tracer().configure(enabled=True, annotate=False)
+    tr.set_role("loop")
+    t = 100.0
+    tr.begin_batch(3)
+    with tr.span("loop_pass") as lap:
+        pass
+    root = tr.snapshot()[-1]
+    tr.clear()
+    # hand-timed: loop_pass 100 ms = poll 30 (decode 25) + prep 10
+    # (cold_detect 8) + wait 50 (device_wait 49) + 10 of its own
+    tr.add_span("loop_pass", t, t + 0.100, parent=0)
+    lap_id = tr.snapshot()[-1].id
+    tr.add_span("source_poll", t, t + 0.030, parent=lap_id)
+    tr.add_span("decode", t + 0.002, t + 0.027,
+                parent=tr.snapshot()[-1].id)
+    tr.add_span("host_prep", t + 0.030, t + 0.040, parent=lap_id)
+    tr.add_span("cold_detect", t + 0.031, t + 0.039,
+                parent=tr.snapshot()[-1].id)
+    tr.add_span("result_wait", t + 0.040, t + 0.090, parent=lap_id)
+    tr.add_span("device_wait", t + 0.040, t + 0.089,
+                parent=tr.snapshot()[-1].id)
+    tr.set_role("writer")
+    tr.add_span("sink_write", t + 0.050, t + 0.120, parent=0)
+    tr.add_span("sink/encode", t + 0.060, t + 0.110,
+                parent=tr.snapshot()[-1].id)
+    tr.set_role("other")
+    s = summarize_chrome(tr.export_chrome(), top_k=3)
+    (b,) = s["batches"]
+    assert b["total_ms"] == pytest.approx(100.0 + 70.0, abs=0.01)
+    assert sum(b["phases_ms"].values()) == pytest.approx(392.0, abs=0.05)
+    assert b["critical_phase"] == "sink/encode"
+    assert b["critical_ms"] == pytest.approx(50.0, abs=0.01)
+    assert b["self_ms"]["loop_pass"] == pytest.approx(10.0, abs=0.01)
+    assert b["self_ms"]["source_poll"] == pytest.approx(5.0, abs=0.01)
+    assert b["self_ms"]["device_wait"] == pytest.approx(49.0, abs=0.01)
+    assert sum(b["self_ms"].values()) == pytest.approx(170.0, abs=0.05)
+    top = s["self_time"][0]
+    assert (top["role"], top["name"]) == ("writer", "sink/encode")
+    loop = [r for r in s["self_time"] if r["role"] == "loop"]
+    assert loop[0]["name"] == "device_wait"
+    assert sum(r["self_ms"] for r in loop) == pytest.approx(100.0, abs=0.05)
+    assert root.name == "loop_pass" and lap.id == root.id
 
 
 def test_ascii_waterfall_render():
@@ -601,6 +820,7 @@ def test_cli_trace_subcommand(tmp_path, capsys):
     assert cli.main(["trace", "--trace", path]) == 0
     out = capsys.readouterr().out
     assert "slowest batches" in out
+    assert "self time by span" in out and "dispatch=8.00/8.00" in out
     assert "trace b00000001" in out  # the ASCII waterfall rendered
 
     rc = cli.main(["trace", "--trace", str(tmp_path / "missing.json")])
