@@ -92,9 +92,10 @@ class FeatureConfig:
     # sketch. Whether or not the sketch tier serves any row, EVERY row
     # updates both sketches and reads them (README, "Feature-state
     # playbook": what that costs on the chip). Compaction — every
-    # N batches a full-table vector pass reclaims slots whose newest
-    # bucket_day is older than delay_days + max(windows) (dead history:
-    # no query can ever see it). 0 = compaction off.
+    # N batches a pass reclaims slots whose newest bucket_day is older
+    # than delay_days + max(windows) (dead history: no query can ever
+    # see it), at a cost that follows what it vacates. 0 = compaction
+    # off.
     keydir_probes: int = 8
     compact_every: int = 0
     # HBM budget for the whole feature state (dense tier + directory +

@@ -38,6 +38,8 @@ from real_time_fraud_detection_system_tpu.ops.keydir import (
     admit_slots,
     init_keydir,
     lookup_slots,
+    pack_lanes,
+    packed_entries,
     reclaim_entries,
 )
 from real_time_fraud_detection_system_tpu.ops.windows import (
@@ -532,8 +534,9 @@ def compact_feature_state(
     cfg: FeatureConfig,
     demote_slots: int = 0,
 ):
-    """Recency compaction (``key_mode="exact"``): one full-table vector
-    pass reclaiming hot-tier slots that hold only dead history.
+    """Recency compaction (``key_mode="exact"``): reclaim the hot-tier
+    slots that hold only dead history, at a cost that follows what the
+    pass vacates.
 
     A slot whose NEWEST ``bucket_day`` is older than
     ``now_day - (delay_days + max(windows))`` can never contribute to
@@ -543,6 +546,16 @@ def compact_feature_state(
     clean. Returns (new_state, reclaimed [2] int32 = [customer,
     terminal]). Fixed shapes throughout: this is a ``DispatchSignature``
     variant of the compiled step family, not a recompile.
+
+    What a table gives up is known from dense counts before any indexed
+    work (:func:`_table_gives`): a table that gives nothing up runs no
+    entry-wide gather, no selection and no trip of the vacate — its
+    ``reclaimed`` is 0, which is how the host counts the sweeps
+    (``rtfds_state_compact_sweeps_total``); one that does pays one
+    gather a directory entry to find the entries, and packs what it
+    vacates K lanes a trip (``ops/keydir.reclaim_entries``). Either way
+    the pass ends in one dense select a window column and two over the
+    directory, which do not follow the input.
 
     ``demote_slots > 0`` adds the cold tier's PRESSURE eviction behind
     the dead reclaim: when a table still sits above
@@ -557,13 +570,11 @@ def compact_feature_state(
     ``io/coldstore.py`` — demote, don't discard.
     """
     # Every op below sits under rtfds.compact — but for the demote pass's
-    # selection and payload gather (rtfds.demote, in _demote_oldest), a
-    # SIBLING scope and not a part: the stage metrics that read the two
-    # then add up to the program, and no op counts twice.
+    # selection and payload gather (rtfds.demote, in _select_oldest and
+    # _demoted_rows), a SIBLING scope and not a part: the stage metrics
+    # that read the two then add up to the program, and no op counts twice.
     demote = int(demote_slots)
     with step_scope("compact"):
-        horizon = jnp.int32(cfg.delay_days + max(cfg.windows))
-        cutoff = now_day.astype(jnp.int32) - horizon
         now = now_day.astype(jnp.int32)
     out = {}
     counts = []
@@ -577,31 +588,8 @@ def compact_feature_state(
             counts.append(jnp.int32(0))
             payload[ws_name] = None
             continue
-        with step_scope("compact"):
-            newest = jnp.max(ws.tables()[0], axis=1)  # [slot_cap]
-            slot_idx = jnp.clip(kd.slots, 0, ws.capacity - 1)
-            live = kd.slots >= 0
-            newest_e = newest[slot_idx]
-            dead_entry = live & (newest_e < cutoff)
-        if demote > 0:
-            # Pressure eviction EXTENDS the dead mask (payload gathered
-            # before any vacate), so the demote variant pays ONE
-            # combined reclaim + window-table sweep — not a second
-            # full-table pass on top of the dead reclaim.
-            kd, ws, n, pay = _demote_oldest(
-                kd, ws, dead_entry, newest_e, live, now,
-                int(cfg.delay_days + max(cfg.windows)), demote,
-                cfg.cold_highwater)
-            payload[ws_name] = pay
-        else:
-            with step_scope("compact"):
-                old_slots = kd.slots  # pre-clear ids (reclaim vacates them)
-                kd, dead, n = reclaim_entries(kd, dead_entry)
-                tgt = jnp.where(dead, old_slots, ws.capacity)
-                ws = ws.clear_slots(tgt)
-            payload[ws_name] = None
-        out[dir_name] = kd
-        out[ws_name] = ws
+        out[dir_name], out[ws_name], n, payload[ws_name] = _compact_table(
+            kd, ws, now, cfg, min(demote, kd.dir_capacity))
         counts.append(n)
     new_state = state._replace(
         customer=out["customer"], terminal=out["terminal"],
@@ -615,105 +603,201 @@ def compact_feature_state(
     return new_state, reclaimed
 
 
-def _demote_oldest(
+def _compact_table(
     kd: KeyDirectory,
     ws: WindowState,
-    dead_entry: jnp.ndarray,  # bool [dir_cap] — the dead-history mask
-    newest_e: jnp.ndarray,  # int32 [dir_cap] — newest bucket per entry
-    live: jnp.ndarray,  # bool [dir_cap]
     now_day: jnp.ndarray,  # int32 []
-    horizon: int,  # days — dead-history cutoff distance (static)
-    demote_slots: int,
-    highwater: float,
+    cfg: FeatureConfig,
+    demote_slots: int,  # the payload's lanes a table; 0 = no cold tier
 ):
-    """Pressure eviction for one table, FUSED with the dead-history
-    reclaim: pick the ``demote_slots`` oldest live directory entries
-    (strictly pre-``now_day`` newest bucket; an entry touched today is
-    never evicted under the feet of the batch that just wrote it), but
-    only as many as POST-dead-reclaim occupancy sits above the
-    ``highwater`` target. The evicted rows are gathered into a
-    fixed-shape payload first, then the dead mask and the demote
-    selection vacate in ONE ``reclaim_entries`` + window sweep (the
-    fused pass costs one table rewrite, not two — the selection and the
-    resulting state are identical to running the passes sequentially;
-    only the internal free-stack push order differs, which no feature
-    value depends on).
+    """One table's share of :func:`compact_feature_state`. Returns
+    ``(kd, ws, n_reclaimed, payload | None)``.
 
-    Oldest-``n_evict`` selection runs WITHOUT a ``top_k`` sort:
-    eligible ages live in ``[1, horizon]`` (anything older is already
-    in the dead mask), so an age histogram + suffix sum finds the
-    threshold age and a cumsum rank breaks the tie at the threshold by
-    lowest index — the exact set ``lax.top_k`` would pick (its ties
-    also resolve to the lowest index), at O(n) scatter cost instead of
-    an O(n log k) sort over the whole directory. Selection and payload
-    gather run under ``rtfds.demote``, the vacate and the table sweep
-    under ``rtfds.compact``: siblings, so what reads the one never
-    counts the other. Returns
-    ``(kd, ws, n_reclaimed_total, (keys, bd, cnt, amt, frd))``.
-    """
-    with step_scope("demote"):
-        slot_cap = int(ws.capacity)
-        dir_cap = int(kd.keys.shape[0])
-        k = min(int(demote_slots), dir_cap)
-        hzn = max(int(horizon), 1)
-        n_dead = jnp.sum((dead_entry & live).astype(jnp.int32))
-        occ = (jnp.int32(kd.free.shape[0]) - kd.free_top.astype(jnp.int32)
-               - n_dead)
-        target = jnp.int32(int(highwater * slot_cap))
-        n_evict = jnp.clip(occ - target, 0, k)
-        eligible = live & ~dead_entry & (newest_e < now_day)
-        # Age histogram over [1, hzn] (bucket 0 holds the ineligible mass
-        # and is never selectable; eligible entries have age >= 1 because
-        # newest_e < now_day, and age <= hzn because older is dead).
-        age = jnp.clip(jnp.where(eligible, now_day - newest_e, 0),
-                       0, hzn).astype(jnp.int32)
-        # one compare-and-count an age, not a scatter-add of every
-        # directory entry into ~40 bins (the chip serialises those)
-        hist = jnp.sum(
-            age[None, :] == jnp.arange(hzn + 3, dtype=jnp.int32)[:, None],
-            axis=1, dtype=jnp.int32)
-        incl = jnp.cumsum(hist[::-1])[::-1]  # incl[a] = #entries age >= a
-        # Threshold t* = max age with incl >= n_evict (monotone, so a count
-        # of satisfied ages IS the argmax); floor 1 covers the
-        # n_evict > #eligible case, where every eligible entry is taken.
-        thresh = jnp.maximum(
-            jnp.sum((incl >= n_evict)[1:hzn + 2].astype(jnp.int32)),
-            jnp.int32(1))
-        quota = n_evict - incl[thresh + 1]  # lanes left for age == t*
-        at_t = age == thresh
-        rank_t = jnp.cumsum(at_t.astype(jnp.int32)) - 1
-        sel = (age > thresh) | (at_t & (rank_t < quota))
-        # Pack selected entry indices into the fixed k payload lanes in
-        # index order (payload lane order is semantically irrelevant — the
-        # cold store treats rows independently): lane j holds the
-        # (j+1)-th selected entry, found by k binary searches in the
-        # running count — not by a scatter of every directory entry.
-        taken = jnp.cumsum(sel.astype(jnp.int32))
-        eidx = jnp.searchsorted(
-            taken, jnp.arange(1, k + 1, dtype=jnp.int32),
-            side="left").astype(jnp.int32)  # dir_cap past the last one
-        lane_live = jnp.arange(k, dtype=jnp.int32) < taken[-1]
-        eidx_c = jnp.clip(eidx, 0, dir_cap - 1)
-        # Gather the payload BEFORE vacating: keys + full window rows.
-        # Lanes go out in KEY order (one sort of k keys; EMPTY_KEY, the
-        # largest u32, keeps the padding last): the store's index is
-        # sorted by key, so the host lands such a payload as it stands,
-        # without gathering every row into order once more.
-        keys = jnp.where(lane_live, kd.keys[eidx_c], jnp.uint32(EMPTY_KEY))
-        by_key = jnp.argsort(keys)
-        keys, eidx_c = keys[by_key], eidx_c[by_key]
-        slot_g = jnp.clip(kd.slots[eidx_c], 0, slot_cap - 1)
-        m = lane_live[:, None]
-        bd, cnt, amt, frd = (
-            jnp.where(m, row, fill)
-            for row, fill in zip(ws.rows(slot_g), (jnp.int32(-1), 0.0, 0.0, 0.0)))
-    # One combined vacate: dead history + demoted entries.
+    The two conditionals yield a ``[dir_cap]`` vector each and touch
+    neither the window columns nor the directory: state a conditional
+    returned, it might copy."""
+    horizon = int(cfg.delay_days + max(cfg.windows))
     with step_scope("compact"):
-        old_slots = kd.slots
-        kd, dead, n = reclaim_entries(kd, dead_entry | sel)
-        tgt = jnp.where(dead, old_slots, slot_cap)
-        ws = ws.clear_slots(tgt)
-    return kd, ws, n, (keys, bd, cnt, amt, frd)
+        cutoff = now_day - jnp.int32(horizon)
+        newest = jnp.max(ws.tables()[0], axis=1)  # [slot_cap]
+        live = kd.slots >= 0
+        gives, demotes, n_evict = _table_gives(
+            kd, newest, cutoff, now_day,
+            jnp.int32(int(cfg.cold_highwater * ws.capacity)), demote_slots)
+        # one lane a directory entry — for a table that gives something
+        # up; the fill makes no entry dead and none eligible
+        newest_e = jax.lax.cond(
+            gives,
+            lambda: newest[jnp.clip(kd.slots, 0, ws.capacity - 1)],
+            lambda: jnp.full_like(kd.slots, now_day))
+        dead_entry = live & (newest_e < cutoff)
+    payload = None
+    if demote_slots:
+        # payload gathered BEFORE the vacate; pressure eviction EXTENDS
+        # the dead mask, so the demote variant pays ONE combined reclaim
+        # + window sweep, not a second on top
+        with step_scope("demote"):
+            sel = jax.lax.cond(
+                demotes,
+                lambda: _select_oldest(
+                    live & ~dead_entry & (newest_e < now_day),
+                    now_day - newest_e, n_evict, horizon),
+                lambda: jnp.zeros_like(live))
+            payload = _demoted_rows(kd, ws, sel, demote_slots)
+    with step_scope("compact"):
+        if demote_slots:
+            dead_entry = dead_entry | sel
+        kd, vacated, n = reclaim_entries(kd, dead_entry)
+        ws = ws.clear_slots(vacated)
+    return kd, ws, n, payload
+
+
+def _table_gives(
+    kd: KeyDirectory,
+    newest: jnp.ndarray,  # int32 [slot_cap] — newest bucket_day per slot
+    cutoff: jnp.ndarray,  # int32 [] — history older than this is dead
+    now_day: jnp.ndarray,  # int32 []
+    target: jnp.ndarray,  # int32 [] — occupied slots the tier may keep
+    demote_slots: int,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """What a pass will take from one table, from dense per-slot counts
+    and the free stack's height — before any gather through the
+    directory. Returns ``(gives [] bool, demotes [] bool, n_evict []
+    int32)``: whether anything is vacated at all, whether the pressure
+    eviction selects anything, and its quota.
+
+    A free slot's row is empty (``bucket_day`` −1: the initial fill, a
+    rolled-back grant and ``clear_slots`` all leave it so), so with
+    ``cutoff`` ≥ 0 every slot whose newest day reaches it is occupied
+    and ``occupied − count(newest ≥ cutoff)`` is the number of dead
+    entries exactly — an occupied slot whose row is still empty counts
+    as dead here as it does entry by entry. With ``cutoff`` ≤ −1 every
+    slot passes the compare, the free ones too: they are taken off, and
+    nothing is dead. The demote quota and the count of slots it may take
+    (live, last touched before ``now_day``) are scalars the same way."""
+    free_top = kd.free_top.astype(jnp.int32)
+    occupied = jnp.int32(kd.slot_capacity) - free_top
+    n_recent = (jnp.sum((newest >= cutoff).astype(jnp.int32))
+                - jnp.where(cutoff < 0, free_top, 0))
+    n_dead = occupied - n_recent
+    if not demote_slots:
+        return n_dead > 0, jnp.bool_(False), jnp.int32(0)
+    n_evict = jnp.clip(occupied - n_dead - target, 0, demote_slots)
+    n_today = jnp.sum((newest >= now_day).astype(jnp.int32))
+    demotes = jnp.minimum(n_evict, n_recent - n_today) > 0
+    return (n_dead > 0) | demotes, demotes, n_evict
+
+
+def _select_oldest(
+    eligible: jnp.ndarray,  # bool [dir_cap] — live, not dead, pre-now_day
+    age_days: jnp.ndarray,  # int32 [dir_cap] — now_day - newest bucket
+    n_evict: jnp.ndarray,  # int32 [] — the quota (post-dead-reclaim
+    #                        occupancy over the highwater target, ≤ K)
+    horizon: int,  # days — dead-history cutoff distance (static)
+) -> jnp.ndarray:
+    """Pressure eviction's selection for one table: the ``n_evict``
+    oldest eligible directory entries (strictly pre-``now_day`` newest
+    bucket; an entry touched today is never evicted under the feet of
+    the batch that just wrote it), all of them when fewer are eligible.
+    Returns the ``[dir_cap]`` bool selection.
+
+    It runs WITHOUT a ``top_k`` sort: eligible ages live in
+    ``[1, horizon]`` (anything older is already in the dead mask), so an
+    age histogram + suffix sum finds the threshold age and a cumsum rank
+    breaks the tie at the threshold by lowest index — the exact set
+    ``lax.top_k`` would pick (its ties also resolve to the lowest
+    index), at O(n) cost instead of an O(n log k) sort over the whole
+    directory. Named by its caller's ``rtfds.demote``."""
+    hzn = max(int(horizon), 1)
+    # Age histogram over [1, hzn] (bucket 0 holds the ineligible mass
+    # and is never selectable; eligible entries have age >= 1 because
+    # newest_e < now_day, and age <= hzn because older is dead).
+    age = jnp.clip(jnp.where(eligible, age_days, 0),
+                   0, hzn).astype(jnp.int32)
+    # one compare-and-count an age, not a scatter-add of every
+    # directory entry into ~40 bins (the chip serialises those)
+    hist = jnp.sum(
+        age[None, :] == jnp.arange(hzn + 3, dtype=jnp.int32)[:, None],
+        axis=1, dtype=jnp.int32)
+    incl = jnp.cumsum(hist[::-1])[::-1]  # incl[a] = #entries age >= a
+    # Threshold t* = max age with incl >= n_evict (monotone, so a count
+    # of satisfied ages IS the argmax); floor 1 covers the
+    # n_evict > #eligible case, where every eligible entry is taken.
+    thresh = jnp.maximum(
+        jnp.sum((incl >= n_evict)[1:hzn + 2].astype(jnp.int32)),
+        jnp.int32(1))
+    quota = n_evict - incl[thresh + 1]  # lanes left for age == t*
+    at_t = age == thresh
+    rank_t = jnp.cumsum(at_t.astype(jnp.int32)) - 1
+    return (age > thresh) | (at_t & (rank_t < quota))
+
+
+def _demoted_rows(
+    kd: KeyDirectory,
+    ws: WindowState,
+    sel: jnp.ndarray,  # bool [dir_cap] — _select_oldest's selection
+    demote_slots: int,  # the payload's lanes (≥ the selection's size)
+):
+    """The demote payload of one table: keys and exact window rows of
+    the selected entries in ``demote_slots`` fixed lanes, gathered
+    BEFORE the vacate — ``(keys u32 [k], bucket_day i32 [k, NB],
+    count/amount/fraud f32 [k, NB])``, unselected lanes ``EMPTY_KEY`` /
+    empty rows. Named by its caller's ``rtfds.demote``.
+
+    Lanes go out in KEY order (one sort of k keys; EMPTY_KEY, the
+    largest u32, keeps the padding last): the store's index is sorted by
+    key, so the host lands such a payload as it stands, without
+    gathering every row into order once more. The indexed work follows
+    the selection: entries are packed into lanes by rank (lane j holds
+    the (j+1)-th selected entry, ``ops/keydir.packed_entries``) and the
+    rows gathered K lanes a trip of two ``lax.while_loop``s of
+    ⌈selected ÷ K⌉ trips each — nothing selected, no trip, and the
+    payload is the empty one it was born as."""
+    slot_cap, dir_cap = int(ws.capacity), kd.dir_capacity
+    nb = int(ws.bucket_day.shape[0]) // slot_cap  # = ws.n_buckets
+    k = int(demote_slots)
+    lanes = pack_lanes(k)
+    trips = -(-k // lanes)
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    taken = jnp.cumsum(sel.astype(jnp.int32))
+    n_sel = taken[-1]
+
+    def more(carry):
+        return carry[0] * lanes < n_sel
+
+    def pack(carry):
+        trip, keys, eidx = carry
+        entry = jnp.minimum(
+            packed_entries(taken, trip * lanes, lanes), dir_cap - 1)
+        key = kd.keys[entry]
+        live = trip * lanes + lane < n_sel
+        return (trip + 1,
+                keys.at[trip].set(jax.lax.select(
+                    live, key, jnp.full_like(key, EMPTY_KEY))),
+                eidx.at[trip].set(entry))
+
+    _, keys, eidx = jax.lax.while_loop(more, pack, (
+        jnp.int32(0),
+        jnp.full((trips, lanes), EMPTY_KEY, jnp.uint32),
+        jnp.zeros((trips, lanes), jnp.int32)))
+    keys, eidx = keys.reshape(-1), eidx.reshape(-1)
+    by_key = jnp.argsort(keys)  # the selected lanes first, by key
+    keys, eidx = keys[by_key], eidx[by_key].reshape(trips, lanes)
+    fills = (jnp.int32(-1), 0.0, 0.0, 0.0)
+
+    def fetch(carry):
+        trip, rows = carry
+        slot = jnp.clip(kd.slots[eidx[trip]], 0, slot_cap - 1)
+        ok = (trip * lanes + lane < n_sel)[:, None]
+        return trip + 1, tuple(
+            buf.at[trip].set(jnp.where(ok, row, fill))
+            for buf, row, fill in zip(rows, ws.rows(slot), fills))
+
+    _, rows = jax.lax.while_loop(more, fetch, (jnp.int32(0), tuple(
+        jnp.full((trips, lanes, nb), fill, col.dtype)
+        for fill, col in zip(fills, ws.columns()))))
+    return (keys[:k],) + tuple(
+        r.reshape(trips * lanes, nb)[:k] for r in rows)
 
 
 def promote_rows(

@@ -34,7 +34,8 @@ Everything is vectorized, fixed-shape and jit/shard_map-friendly:
   tier instead of clobbering a live slot;
 - **reclaim** pushes dead slots back on the stack and vacates their
   directory entries (no tombstones needed — see probing above), which is
-  what the engine's recency compaction pass calls.
+  what the engine's recency compaction pass calls; its indexed work is
+  lane-packed, as wide as what it vacates.
 
 Sentinel note: ``EMPTY_KEY`` (0xFFFFFFFF) is reserved; a real key equal
 to it is remapped to 0xFFFFFFFE (``fold_key`` output collides with that
@@ -291,20 +292,85 @@ def admit_slots(
         )
 
 
+# K: the lanes one trip of a lane-packed pass works on (reclaim_entries'
+# vacate, the demote pass's packing and payload gather in
+# features/online.py). A trip's cost follows K, not what it packs, so K is
+# one static width chosen on the chip (PERF.md, PR 38) and no option.
+PACK_LANES = 16384
+
+
+def pack_lanes(n: int) -> int:
+    """K for an ``n``-lane input: :data:`PACK_LANES`, or half of a
+    smaller input — never the whole of it (``ops/cms.chunk_rows``' rule:
+    a loop whose one chunk is the input reads nothing that depends on the
+    trip, and the compiler hoists the read out for every pass to pay)."""
+    return max(1, min(PACK_LANES, n // 2))
+
+
+def packed_entries(running: jnp.ndarray, first, lanes: int) -> jnp.ndarray:
+    """Lane-packing by rank: ``running`` is the cumulative count of a
+    ``[dir_cap]`` selection, lane ``j`` of the ``lanes`` returned holds
+    the index of the selected entry of rank ``first + j`` (in entry
+    order), or ``dir_cap`` past the last one. ``lanes`` binary searches
+    in the running count (the first index whose count reaches the
+    lane's rank + 1: ``jnp.searchsorted``'s ``side="left"``, written in
+    ``lax`` so every op carries the caller's scope) — not a scatter of
+    every directory entry."""
+    (n,) = running.shape
+    want = first + jnp.arange(1, lanes + 1, dtype=jnp.int32)
+
+    def halve(_, bounds):
+        lo, hi = bounds  # the answer lies in [lo, hi]
+        mid = (lo + hi) >> 1
+        right = (lo < hi) & (running[jnp.minimum(mid, n - 1)] < want)
+        return (jax.lax.select(right, mid + 1, lo),
+                jax.lax.select(right | (lo >= hi), hi, mid))
+
+    lo, _ = jax.lax.fori_loop(
+        0, n.bit_length(), halve,
+        (jnp.zeros((lanes,), jnp.int32), jnp.full((lanes,), n, jnp.int32)))
+    return lo
+
+
 def reclaim_entries(
     kd: KeyDirectory,
     dead_entry: jnp.ndarray,  # bool [dir_cap] — entries to vacate
 ) -> Tuple[KeyDirectory, jnp.ndarray, jnp.ndarray]:
     """Vacate ``dead_entry`` positions and push their slots back on the
-    free stack. Returns ``(kd', dead [dir_cap] bool, n_reclaimed [])``
-    — ``dead`` is the mask restricted to live entries, which the caller
-    uses to reset the reclaimed slots' window rows."""
-    slot_cap = kd.slot_capacity
+    free stack, in entry order. Returns ``(kd', vacated [slot_cap] bool,
+    n_reclaimed [])`` — ``vacated`` flags the slots given up, which the
+    caller uses to reset their window rows
+    (``WindowState.clear_slots``).
+
+    The indexed work is as wide as what is vacated, not as wide as the
+    directory: the dead entries are ranked by one cumulative sum and
+    packed ``K = pack_lanes(dir_cap)`` lanes a trip of a
+    ``lax.while_loop`` of ⌈n ÷ K⌉ trips — a K-lane gather of their
+    slots, a K-lane scatter onto the free stack at ``free_top + rank``, a
+    K-lane scatter of flags into ``vacated``; nothing dead, no trip. The
+    directory itself is vacated by two dense selects."""
+    slot_cap, dir_cap = kd.slot_capacity, kd.dir_capacity
+    lanes = pack_lanes(dir_cap)
     dead = dead_entry & (kd.slots >= 0)
-    rank = jnp.cumsum(dead.astype(jnp.int32)) - 1
-    push = jnp.where(dead, kd.free_top + rank, slot_cap)
-    free = kd.free.at[push].set(kd.slots, mode="drop")
-    n = jnp.sum(dead.astype(jnp.int32))
+    running = jnp.cumsum(dead.astype(jnp.int32))
+    n = running[-1]
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+
+    def vacate(carry):
+        trip, free, vacated = carry
+        rank = trip * lanes + lane
+        entry = packed_entries(running, trip * lanes, lanes)
+        slot = kd.slots[jnp.minimum(entry, dir_cap - 1)]
+        ok, drop = rank < n, jnp.full_like(rank, slot_cap)
+        free = free.at[jax.lax.select(ok, kd.free_top + rank, drop)].set(
+            slot, mode="drop")
+        vacated = vacated.at[jax.lax.select(ok, slot, drop)].set(
+            True, mode="drop")
+        return trip + 1, free, vacated
+
+    _, free, vacated = jax.lax.while_loop(
+        lambda carry: carry[0] * lanes < n, vacate,
+        (jnp.int32(0), kd.free, jnp.zeros((slot_cap,), bool)))
     return (
         KeyDirectory(
             keys=jnp.where(dead, EMPTY_KEY, kd.keys),
@@ -312,7 +378,7 @@ def reclaim_entries(
             free=free,
             free_top=kd.free_top + n,
         ),
-        dead,
+        vacated,
         n,
     )
 

@@ -173,19 +173,19 @@ class WindowState:
             n_buckets=nb)
 
 
-    def clear_slots(self, slot: jnp.ndarray) -> "WindowState":
-        """Empty the rows of ``slot`` [K] (a slot of ``capacity`` or more
-        is dropped): one scatter of K flags into a per-slot mask, then one
-        pass over each column. For K of the order of the table (the
-        compaction hands a lane per directory entry) :meth:`set_rows` is
-        the wrong tool: its ``[K, NB]`` element indices alone are 2.7 GB at
-        2^24 entries, and the chip's compiler refuses the program (18.4 GB
-        of 15.75; compiled for a described v5e, PR 32)."""
+    def clear_slots(self, vacated: jnp.ndarray) -> "WindowState":
+        """Empty the rows of the slots flagged in ``vacated`` (bool
+        ``[capacity]``): one dense select a column, no indexed work — the
+        compaction flags the slots it gives up a packed chunk at a time
+        (``ops/keydir.reclaim_entries``) and sweeps once. For slots of
+        the order of the table :meth:`set_rows` is the wrong tool: its
+        ``[K, NB]`` element indices alone are 2.7 GB at 2^24 lanes, and
+        the chip's compiler refuses the program (18.4 GB of 15.75;
+        compiled for a described v5e, PR 32)."""
         cap, nb = self.capacity, self.n_buckets
-        hit = jnp.zeros((cap,), bool).at[slot].set(True, mode="drop")
 
         def clear(col, fill):
-            return jnp.where(hit[:, None], fill,
+            return jnp.where(vacated[:, None], fill,
                              col.reshape(cap, nb)).reshape(-1)
 
         return WindowState(

@@ -834,6 +834,7 @@ class ScoringEngine:
         self._m_slots_rec = None
         self._m_compactions = None
         self._m_compact_s = None
+        self._m_sweeps = None
         if self._exact:
             self._m_compactions = reg.counter(
                 "rtfds_state_compactions_total",
@@ -881,6 +882,17 @@ class ScoringEngine:
                     "delay + max(window))", table=t)
                 for t, present in tables if present
             }
+            if self._compact_every:
+                self._m_sweeps = {
+                    t: reg.counter(
+                        "rtfds_state_compact_sweeps_total",
+                        "table sweeps of the compaction passes that ran "
+                        "their entry-wide part: the table gave something "
+                        "up (a pass whose dense counts find nothing dead "
+                        "and nothing to demote in a table skips it)",
+                        table=t)
+                    for t, present in tables if present
+                }
         if self._exact or fcfg.state_hbm_budget_mb > 0:
             sb = state_bytes(fcfg, n_shards=self._state_shards())
             for tier in ("dense", "directory", "cms", "total"):
@@ -1280,6 +1292,10 @@ class ScoringEngine:
         for i, table in enumerate(("customer", "terminal")):
             if table in self._m_slots_rec:
                 self._m_slots_rec[table].inc(int(rec[i]))
+            if table in (self._m_sweeps or {}):
+                # a table swept <=> it gave something up: the pass's
+                # dense pre-check is exact
+                self._m_sweeps[table].inc(int(rec[i] > 0))
             kd = getattr(fstate, f"{table}_dir")
             if kd is not None and table in self._m_slots_occ:
                 # compact_fetch already waited the pass out, so this

@@ -474,6 +474,9 @@ class ShardedScoringEngine(ScoringEngine):
         for i, table in enumerate(("customer", "terminal")):
             if table in (self._m_slots_rec or {}):
                 self._m_slots_rec[table].inc(int(rec[:, i].sum()))
+            if table in (self._m_sweeps or {}):
+                # each device sweeps its own directory
+                self._m_sweeps[table].inc(int((rec[:, i] > 0).sum()))
             kd = getattr(fstate, f"{table}_dir")
             if kd is None:
                 continue

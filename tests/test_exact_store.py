@@ -38,7 +38,12 @@ from real_time_fraud_detection_system_tpu.ops.cms import (
     cms_query_fraud,
     cms_query_where,
 )
+from real_time_fraud_detection_system_tpu.ops import keydir
 from real_time_fraud_detection_system_tpu.ops.hashing import slot_of
+from real_time_fraud_detection_system_tpu.ops.keydir import (
+    admit_slots,
+    lookup_slots,
+)
 from real_time_fraud_detection_system_tpu.ops.windows import query_windows
 from real_time_fraud_detection_system_tpu.runtime.engine import (
     ScoringEngine,
@@ -371,6 +376,369 @@ def test_compaction_reclaims_dead_slots_and_preserves_live():
     assert int(np.asarray(st2.terminal_dir.free_top)) \
         == cfg.terminal_capacity
     assert (np.asarray(st2.customer.bucket_day) == -1).all()
+
+
+# -- the pass follows what it vacates (PR 38) --------------------------------
+#
+# The entry-wide form the pass had — one lane a directory entry for the
+# ``newest[slot]`` gather, the free-stack scatter and the flag scatter —
+# kept as a plain NumPy oracle: the lane-packed pass has to leave its
+# state, its counts and its demote payload bit for bit.
+
+NB = 40
+EMPTY = np.uint32(0xFFFFFFFF)
+FILLS = (np.int32(-1), np.float32(0), np.float32(0), np.float32(0))
+
+
+def _entry_wide_compaction(state, now_day, cfg, demote_slots=0):
+    """→ (state as numpy leaves, reclaimed [2], payload | None)."""
+    horizon = cfg.delay_days + max(cfg.windows)
+    cutoff = now_day - horizon
+    out, counts, payload = {}, [], {}
+    for table in ("customer", "terminal"):
+        kd = getattr(state, f"{table}_dir")
+        keys, slots, free = (np.array(x) for x in (kd.keys, kd.slots,
+                                                   kd.free))
+        top = int(kd.free_top)
+        cols = [np.array(t) for t in getattr(state, table).tables()]
+        cap = cols[0].shape[0]
+        live = slots >= 0
+        newest_e = cols[0].max(axis=1)[np.clip(slots, 0, cap - 1)]
+        dead = live & (newest_e < cutoff)
+        sel = np.zeros_like(dead)
+        if demote_slots:
+            k = min(demote_slots, len(keys))
+            occupied = cap - top - int(dead.sum())
+            n_evict = int(np.clip(
+                occupied - int(cfg.cold_highwater * cap), 0, k))
+            cand = np.flatnonzero(live & ~dead & (newest_e < now_day))
+            # the oldest first, a tie to the lowest entry index
+            cand = cand[np.lexsort((cand, newest_e[cand]))][:n_evict]
+            sel[cand] = True
+            cand = cand[np.argsort(keys[cand], kind="stable")]
+            pay = [np.full((k,), EMPTY, np.uint32)] + [
+                np.full((k, NB), f, f.dtype) for f in FILLS]
+            pay[0][:len(cand)] = keys[cand]
+            for lane_rows, col in zip(pay[1:], cols):
+                lane_rows[:len(cand)] = col[slots[cand]]
+            payload[table] = tuple(pay)
+        gone = np.flatnonzero(dead | sel)  # in entry order
+        free[top:top + len(gone)] = slots[gone]
+        for col, fill in zip(cols, FILLS):
+            col[slots[gone]] = fill
+        keys[gone], slots[gone] = EMPTY, -1
+        out[table] = (keys, slots, free, np.int32(top + len(gone)),
+                      *(c.reshape(-1) for c in cols))
+        counts.append(len(gone))
+    return out, np.asarray(counts, np.int32), payload or None
+
+
+def _assert_pass_equals_oracle(state, now_day, cfg, demote_slots=0):
+    """Run the pass (jitted, as the engine does) and the oracle on
+    ``state``; every leaf the pass writes, the counts and the payload
+    equal to the bit. Returns the pass's (state, reclaimed)."""
+    before = jax.tree.map(np.array, state)
+    out = jax.jit(lambda st, day: compact_feature_state(
+        st, day, cfg, demote_slots=demote_slots))(state, jnp.int32(now_day))
+    assert_recorded_pass_equals_oracle(
+        before, now_day, jax.tree.map(np.asarray, out), cfg, demote_slots)
+    # untouched by the pass: the sketches
+    for a, b in zip(jax.tree.leaves((out[0].cms, out[0].terminal_cms)),
+                    jax.tree.leaves((before.cms, before.terminal_cms))):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    return out[0], np.asarray(out[1])
+
+
+def _built_state(cfg, customers, terminals, seed=0):
+    """A state whose tables hold ``{key: newest day | None}``: each key
+    admitted through the directory, its row stamped with two days up to
+    its newest (``None``: granted a slot, the row still empty) and seeded
+    values in the other columns."""
+    rng = np.random.default_rng(seed)
+    st = init_feature_state(cfg)
+    placed = {}
+    for table, held in (("customer", customers), ("terminal", terminals)):
+        keys = jnp.asarray(np.fromiter(held, np.uint32, len(held)))
+        kd, slot, adm, _ = admit_slots(
+            getattr(st, f"{table}_dir"), keys, jnp.ones(len(held), bool),
+            n_probes=cfg.keydir_probes)
+        assert np.asarray(adm).all()
+        bd = np.full((len(held), NB), -1, np.int32)
+        for i, day in enumerate(held.values()):
+            for d in ((day - 1, day) if day is not None else ()):
+                bd[i, d % NB] = d
+        vals = [np.where(bd >= 0, rng.random(bd.shape), 0).astype(
+            np.float32) for _ in range(3)]
+        ws = getattr(st, table).set_rows(slot, jnp.asarray(bd), *map(
+            jnp.asarray, vals))
+        placed[f"{table}_dir"], placed[table] = kd, ws
+    return st._replace(**placed)
+
+
+NOW = DAY0 + 100
+HORIZON = 37  # delay 7 + the 30-day window: older than NOW - 37 is dead
+DEAD, OLD, MID, TODAY = NOW - 50, NOW - 30, NOW - 10, NOW
+
+
+def _ages(n, day, first=0):
+    return {first + i: day for i in range(n)}
+
+
+def _case_nothing_goes():
+    # live history everywhere, the tier under its target: no sweep
+    return (_ages(20, MID), _ages(30, TODAY, 100)), 0, {}, (0, 0)
+
+
+def _case_dead_only():
+    return ({**_ages(9, DEAD), **_ages(11, MID, 50)},
+            {**_ages(5, DEAD, 100), **_ages(25, TODAY, 200)}), 0, {}, (9, 5)
+
+
+def _case_dead_over_k():
+    # 41 + 23 dead entries, 4 lanes a trip: 11 and 6 trips, the last
+    # ones part-filled
+    return ({**_ages(41, DEAD), **_ages(7, MID, 50)},
+            {**_ages(23, DEAD, 100), **_ages(9, MID, 200)}), 0, {
+                "pack_lanes": 4}, (41, 23)
+
+
+def _case_tie_at_the_threshold():
+    # target 16 of 64 slots, 40 occupied after 4 dead go: the quota is 8
+    # of k = 8; 3 are older, the 10 of age OLD tie for the other 5 and
+    # the lowest entry indices win; today's are never taken
+    return ({**_ages(4, DEAD), **_ages(3, OLD - 2, 50),
+             **_ages(10, OLD, 100), **_ages(12, MID, 200),
+             **_ages(15, TODAY, 300)},
+            _ages(6, TODAY, 400)), 8, {"cold_highwater": 0.25}, (12, 0)
+
+
+def _case_quota_over_the_eligible():
+    # 50 occupied over a target of 16, a quota of 32 — and only 6 keys
+    # that were not touched today: all 6 go, nothing else
+    return ({**_ages(6, MID), **_ages(44, TODAY, 100)},
+            _ages(6, TODAY, 400)), 32, {"cold_highwater": 0.25}, (6, 0)
+
+
+def _case_one_table_gives():
+    # the customers demote and hold dead history, the terminals are
+    # under their target with live history: one sweep of two
+    return ({**_ages(5, DEAD), **_ages(30, OLD, 50),
+             **_ages(10, TODAY, 300)},
+            {**_ages(8, MID, 400), **_ages(6, TODAY, 500)}), 16, {
+                "cold_highwater": 0.25, "pack_lanes": 4}, (21, 0)
+
+
+def _case_empty_rows_are_dead():
+    # a granted slot whose row was never written has no history to keep:
+    # dead entry by entry and by the dense count alike
+    return ({0: None, 1: None, **_ages(5, MID, 50)},
+            {7: None, **_ages(3, TODAY, 100)}), 0, {}, (2, 1)
+
+
+def _case_before_the_first_horizon():
+    # now_day under the horizon: the cutoff is negative, every slot
+    # passes the dense compare — the free ones too — and nothing is dead,
+    # not even a granted slot with an empty row; the quota still takes
+    # what is eligible (the empty rows and yesterday's)
+    return ({0: None, **_ages(20, 4, 50), **_ages(8, 5, 100)},
+            {7: None, **_ages(3, 5, 100)}), 16, {
+                "cold_highwater": 0.25, "now": 5}, (13, 0)
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(_case_nothing_goes, id="nothing-dead-no-quota"),
+    pytest.param(_case_dead_only, id="dead-only"),
+    pytest.param(_case_dead_over_k, id="dead-over-K-several-trips"),
+    pytest.param(_case_tie_at_the_threshold, id="quota-tie-at-threshold"),
+    pytest.param(_case_quota_over_the_eligible, id="quota-over-eligible"),
+    pytest.param(_case_one_table_gives, id="one-table-gives"),
+    pytest.param(_case_empty_rows_are_dead, id="empty-rows-are-dead"),
+    pytest.param(_case_before_the_first_horizon, id="negative-cutoff"),
+])
+def test_compaction_equals_the_entry_wide_oracle(case, monkeypatch):
+    """Directory, free stack (push order included), ``free_top``, the
+    four window columns of both tables, ``reclaimed`` and the demote
+    payload lane for lane: the entry-wide oracle's, in every shape of
+    input the pass tells apart — and ``reclaimed`` is 0 exactly for a
+    table whose sweep the dense counts skipped."""
+    (customers, terminals), demote, knobs, want_n = case()
+    if "pack_lanes" in knobs:
+        monkeypatch.setattr(keydir, "PACK_LANES", knobs["pack_lanes"])
+    cfg = _fcfg(customer_capacity=64, terminal_capacity=64,
+                key_mode="exact", keydir_probes=16,
+                cold_highwater=knobs.get("cold_highwater", 0.75))
+    now = knobs.get("now", NOW)
+    st = _built_state(cfg, customers, terminals)
+    st2, rec = _assert_pass_equals_oracle(st, now, cfg, demote)
+    assert tuple(rec) == want_n
+    # a second pass on what the first left finds nothing dead; with a
+    # quota it goes on demoting, as the oracle does
+    _assert_pass_equals_oracle(st2, now, cfg, demote)
+
+
+def spy_on_passes(eng):
+    """Record every compaction ``eng`` dispatches from here on:
+    ``[(state before, now_day, outputs)]``, all on the host (the pass
+    donates its input)."""
+    seen, inner = [], eng._compact
+
+    def spy(fstate, day):
+        before = jax.tree.map(np.array, fstate)
+        out = inner(fstate, day)
+        seen.append((before, int(day), jax.tree.map(np.asarray, out)))
+        return out
+
+    eng._compact = spy
+    return seen
+
+
+def assert_recorded_pass_equals_oracle(before, day, out, fcfg, demote,
+                                       n_dev=0):
+    """One recorded pass against the oracle — a device of the mesh at a
+    time when ``n_dev``: each runs the pass on its own directory and its
+    own block of the window columns. Returns the tables swept,
+    ``{table: count}``: those that gave something up."""
+    swept = {"customer": 0, "terminal": 0}
+    for s in range(max(n_dev, 1)):
+        def mine(x, flat=False):
+            if not n_dev:
+                return x
+            return x.reshape(n_dev, -1)[s] if flat else x[s]
+
+        def shard_of(st):
+            return st._replace(**{
+                t: dc.replace(getattr(st, t), **{
+                    c: mine(getattr(getattr(st, t), c), flat=True)
+                    for c in ("bucket_day", "count", "amount", "fraud")})
+                for t in ("customer", "terminal")}, **{
+                f"{t}_dir": jax.tree.map(mine, getattr(st, f"{t}_dir"))
+                for t in ("customer", "terminal")})
+
+        want, want_n, want_pay = _entry_wide_compaction(
+            shard_of(before), day, fcfg, demote)
+        got = shard_of(out[0])
+        np.testing.assert_array_equal(mine(out[1]), want_n)
+        for table in ("customer", "terminal"):
+            kd, ws = getattr(got, f"{table}_dir"), getattr(got, table)
+            for a, b in zip((kd.keys, kd.slots, kd.free, kd.free_top,
+                             *ws.columns()), want[table]):
+                assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+            if demote:
+                for a, b in zip(out[2][table], want_pay[table]):
+                    assert mine(a).tobytes() == b.tobytes(), table
+            swept[table] += int(want_n[("customer", "terminal").index(
+                table)] > 0)
+    return swept
+
+
+def _drifting(n_batches, rows=256, step=10):
+    """A working set that drifts (batch i touches keys [64 i, 64 i + 64)
+    only) while the day marches ``step`` a batch: earlier batches' slots
+    go dead past the 37-day horizon."""
+    rng = np.random.default_rng(3)
+    out = []
+    for i in range(n_batches):
+        n = rows
+        out.append({
+            "tx_id": np.arange(i * n, (i + 1) * n, dtype=np.int64),
+            "tx_datetime_us": ((DAY0 + i * step) * 86400 + rng.integers(
+                0, 86400, n)).astype(np.int64) * 1_000_000,
+            "customer_id": (i * 64 + rng.integers(0, 64, n)).astype(
+                np.int64),
+            "terminal_id": (i * 64 + rng.integers(0, 64, n)).astype(
+                np.int64),
+            "tx_amount_cents": (rng.integers(1, 500, n) * 100).astype(
+                np.int64),
+            "kafka_ts_ms": np.zeros(n, dtype=np.int64),
+        })
+    return out
+
+
+def assert_sweeps_counted(reg, passes, swept):
+    """``rtfds_state_compact_sweeps_total{table}`` = the tables (a
+    device's, on the mesh) that gave something up, summed over the
+    recorded passes; registered at 0 from the build."""
+    assert reg.get("rtfds_state_compactions_total").value == passes
+    for table, n in swept.items():
+        assert reg.get("rtfds_state_compact_sweeps_total",
+                       table=table).value == n, table
+
+
+def test_engine_passes_equal_the_oracle_and_the_sweeps_are_counted():
+    """Through ``engine.run()``: every pass the loop dispatches leaves
+    the oracle's state, and the host's sweep counter — read off the
+    ``reclaimed`` it already fetched — counts exactly the tables that
+    gave something up: the first passes find nothing dead (0 sweeps),
+    the later ones reclaim from both tables."""
+    from test_sharded_exact import _Src
+
+    cfg = Config(
+        features=_fcfg(customer_capacity=512, terminal_capacity=512,
+                       cms_width=1 << 10, key_mode="exact",
+                       compact_every=2),
+        runtime=RuntimeConfig(batch_buckets=(256,), max_batch_rows=256,
+                              trigger_seconds=0.0, precompile=False))
+    reg = MetricsRegistry()
+    eng = _engine(cfg, reg)
+    for table in ("customer", "terminal"):
+        assert reg.get("rtfds_state_compact_sweeps_total",
+                       table=table).value == 0
+    seen = spy_on_passes(eng)
+    eng.run(_Src(_drifting(10)))
+    assert len(seen) == 5
+    swept = {"customer": 0, "terminal": 0}
+    for before, day, out in seen:
+        for t, n in assert_recorded_pass_equals_oracle(
+                before, day, out, cfg.features, 0).items():
+            swept[t] += n
+    assert 0 < swept["customer"] < len(seen)  # some passes swept nothing
+    assert_sweeps_counted(reg, len(seen), swept)
+    # no compaction configured, no series
+    plain = MetricsRegistry()
+    _engine(Config(features=_fcfg(key_mode="exact")), plain)
+    assert plain.get("rtfds_state_compact_sweeps_total",
+                     table="customer") is None
+
+
+def test_a_vacated_probe_prefix_still_resolves_after_the_pass():
+    """The pass vacates entries that sit on live keys' probe paths (half
+    the keys of a directory at load 0.5 are dead): every survivor is
+    found again in its old slot, with its row, and a dead key that
+    returns is admitted afresh into an empty row."""
+    cfg = _fcfg(customer_capacity=64, terminal_capacity=64,
+                key_mode="exact", keydir_probes=16)
+    held = {**_ages(30, DEAD), **_ages(30, MID, 1000)}
+    st = _built_state(cfg, held, held)
+    slot_of_key = {}
+    for k in held:
+        s, hit = lookup_slots(st.customer_dir, jnp.asarray([k], jnp.uint32),
+                              jnp.ones(1, bool), n_probes=16)
+        assert bool(hit[0])
+        slot_of_key[k] = int(s[0])
+    st2, rec = _assert_pass_equals_oracle(st, NOW, cfg)
+    assert tuple(rec) == (30, 30)
+    keys = jnp.asarray(np.fromiter(held, np.uint32, len(held)))
+    kd, slot, adm, _ = admit_slots(st2.customer_dir, keys,
+                                   jnp.ones(len(held), bool), n_probes=16)
+    assert np.asarray(adm).all()
+    before = st.customer.tables()
+    after = st2.customer.tables()
+    for k, s in zip(held, np.asarray(slot).tolist()):
+        if held[k] == MID:  # a survivor: its slot, its row
+            assert s == slot_of_key[k]
+            for a, b in zip(after, before):
+                np.testing.assert_array_equal(a[s], b[s])
+        else:  # came back: a fresh grant of a cleared row
+            assert (np.asarray(after[0][s]) == -1).all()
+    assert int(kd.free_top) == 64 - 60
+
+
+def test_the_pass_chunks_never_hold_the_whole_input():
+    """K is half of a small input at most (``ops/cms.chunk_rows``' rule:
+    a loop whose one chunk is the input gets hoisted) and 16,384 — the
+    width chosen on the chip — at the benchmark's sizes."""
+    assert keydir.pack_lanes(1 << 24) == keydir.pack_lanes(131072) == 16384
+    assert keydir.pack_lanes(64) == 32 and keydir.pack_lanes(1) == 1
 
 
 def test_exact_feedback_routes_hits_to_table_misses_to_sketch():

@@ -387,6 +387,55 @@ def test_sharded_exact_compaction_reclaims_on_every_shard():
                 shard=str(s)).value for s in range(N_DEV))
 
 
+@pytest.mark.parametrize("cold", [False, True],
+                         ids=["dead-only", "cold-tier-demotes"])
+def test_each_devices_pass_equals_the_entry_wide_oracle(cold, tmp_path):
+    """The pass inside ``shard_map``: every device runs it on its own
+    directory and its own block of the window columns — no collective in
+    its loops, each device's trips follow its own counts — and leaves,
+    device by device, the entry-wide oracle's directory, free stack,
+    columns, counts and demote payload to the bit
+    (``tests/test_exact_store.py`` keeps the oracle). The sweep counter
+    is the mesh's sum: one a device and table that gave something up."""
+    from test_exact_store import (
+        _drifting,
+        assert_recorded_pass_equals_oracle,
+        assert_sweeps_counted,
+        spy_on_passes,
+    )
+
+    params, scaler = _model()
+    reg = MetricsRegistry()
+    if cold:
+        # test_cold_exact.py's sizing: keys of a universe four times the
+        # hot tier, a new day and a pass every batch, 16 probes
+        from test_cold_exact import _churn
+
+        cfg = _cfg(cust_cap=256, term_cap=256, rows=64, keydir_probes=16,
+                   compact_every=1, cold_store=str(tmp_path / "cold"),
+                   cold_demote_slots=64, cold_highwater=0.5)
+        batches, demote = _churn(7, 12, 64, 1024), 64
+    else:
+        cfg = _cfg(compact_every=3)
+        batches, demote = _drifting(12), 0
+    cfg = dc.replace(cfg, runtime=dc.replace(cfg.runtime,
+                                             precompile=False))
+    eng = ShardedScoringEngine(cfg, "logreg", params, scaler,
+                               n_devices=N_DEV, metrics=reg)
+    seen = spy_on_passes(eng)
+    eng.run(_Src(batches))
+    assert len(seen) == (12 if cold else 4)
+    swept = {"customer": 0, "terminal": 0}
+    for before, day, out in seen:
+        for t, n in assert_recorded_pass_equals_oracle(
+                before, day, out, cfg.features, demote,
+                n_dev=N_DEV).items():
+            swept[t] += n
+    # devices that gave nothing up in a pass, and devices that did
+    assert 0 < swept["customer"] < len(seen) * N_DEV
+    assert_sweeps_counted(reg, len(seen), swept)
+
+
 # ---------------------------------------------------------------------------
 # feedback: directory-routed labels
 # ---------------------------------------------------------------------------

@@ -129,6 +129,12 @@ def _scopes(op_name):
             if c.startswith("rtfds.")]
 
 
+def _control_flow(hlo_text):
+    """``(while | conditional, op_name)`` of every one in a program."""
+    return re.findall(
+        r' (while|conditional)\(.*op_name="([^"]*)"', hlo_text)
+
+
 _COLUMN_OP = re.compile(
     r"^\s*(?:ROOT )?[\w.\-]+ = \w+\[(\d+)\]\S* ([\w\-]+)\(.*"
     r'op_name="([^"]*rtfds\.update[^"]*)"')
@@ -228,8 +234,14 @@ def test_exact_key_path_names_its_parts_under_their_table():
         if n.startswith("jit(")]  # the rest: parameters, reducers' bodies
     assert named and all(p[:1] == ["compact"] for p in named), [
         p for p in named if p[:1] != ["compact"]][:3]
-    kept = [_scopes(n) for n in _op_names(compact.compile().as_text())]
+    text = compact.compile().as_text()
+    kept = [_scopes(n) for n in _op_names(text)]
     assert any(p[:1] == ["compact"] for p in kept)
+    # the pass's control flow — a conditional around the entry-wide
+    # gather, a loop of packed trips, a table — is the stage's too
+    flow = _control_flow(text)
+    assert sorted(kind for kind, _ in flow).count("conditional") == 2
+    assert all(_scopes(n)[:1] == ["compact"] for _, n in flow), flow
 
 
 @pytest.mark.parametrize("n_dev", [0, 2], ids=["one-chip", "mesh"])
@@ -250,7 +262,7 @@ def test_cold_tier_programs_name_their_stages_as_siblings(n_dev, tmp_path):
         eng = _engine("_cold")
     finally:
         del VARIANTS["_cold"]
-    by_variant = {}
+    by_variant, flows = {}, {}
     for sig in eng.dispatch_inventory():
         if sig.variant in ("compact", "promote"):
             low = eng.signature_step(sig).lower(
@@ -262,14 +274,21 @@ def test_cold_tier_programs_name_their_stages_as_siblings(n_dev, tmp_path):
             if not n_dev:  # (shard_map's own squeezes carry no stage)
                 assert not [n for n in names if n.startswith("jit(")
                             and "/" in n and not _scopes(n)]
-            kept = [_scopes(n) for n in _op_names(low.compile().as_text())]
+            text = low.compile().as_text()
+            kept = [_scopes(n) for n in _op_names(text)]
             by_variant.setdefault(sig.variant, []).append((paths, kept))
+            flows[sig.variant] = _control_flow(text)
     ((paths, kept),) = by_variant["compact"]
     for p in paths:
         assert p[:1] in (["compact"], ["demote"]) and not (
             {"compact", "demote"} <= set(p)), p
     assert any(p[:1] == ["demote"] for p in kept)
     assert any(p[:1] == ["compact"] for p in kept)
+    # the conditionals and loops of the pass, whatever the compiler kept
+    # of them, belong to one of the two stages: none is unscoped time
+    assert flows["compact"] and all(
+        _scopes(n)[:1] in (["compact"], ["demote"])
+        for _, n in flows["compact"]), flows["compact"]
     assert len(by_variant["promote"]) == 2 * len(eng._promote_widths)
     for paths, kept in by_variant["promote"]:
         for p in paths:

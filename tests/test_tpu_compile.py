@@ -477,12 +477,14 @@ def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
 EXACT_ROWS = 65536
 
 
-def _compiled_exact(cache, one_chip, variant, rows=EXACT_ROWS):
+def _compiled_exact(cache, one_chip, variant, rows=EXACT_ROWS, cold=False):
     """``key_mode="exact"`` at the size of the benchmark's
     ``forest-rf100-d8-exact`` (2^22 + 2^23 slots, directories of twice
     that, 16 probes, sketches at their defaults): the ``rows``-row step
     or the ``("compact",)`` program of the engine itself, compiled for
-    one v5e → (features config, compiled)."""
+    one v5e → (features config, compiled). ``cold``: with
+    ``forest-rf100-d8-cold``'s tier armed (a pass every 4 batches that
+    demotes up to 131,072 keys a table over a fifth of the slots)."""
     from real_time_fraud_detection_system_tpu.config import (
         Config,
         FeatureConfig,
@@ -491,10 +493,13 @@ def _compiled_exact(cache, one_chip, variant, rows=EXACT_ROWS):
     from real_time_fraud_detection_system_tpu.models.scaler import Scaler
     from real_time_fraud_detection_system_tpu.runtime import ScoringEngine
 
+    tier = dict(compact_every=4, cold_store="tmp://rtfds-compile",
+                cold_demote_slots=131072, cold_highwater=0.2) if cold \
+        else dict(compact_every=64)
     fcfg = FeatureConfig(customer_capacity=1 << 22,
                          terminal_capacity=1 << 23, key_mode="exact",
-                         keydir_probes=16, compact_every=64)
-    if ("exact", variant, rows) not in cache:
+                         keydir_probes=16, **tier)
+    if ("exact", variant, rows, cold) not in cache:
         eng = ScoringEngine(
             Config(features=fcfg, runtime=RuntimeConfig(
                 z_mode="int8", batch_buckets=(rows,), max_batch_rows=rows)),
@@ -504,9 +509,9 @@ def _compiled_exact(cache, one_chip, variant, rows=EXACT_ROWS):
             feature_state=_on(one_chip, _state_shapes(fcfg)))
         (sig,) = [s for s in eng.dispatch_inventory()
                   if s.variant == variant]
-        cache["exact", variant, rows] = eng.signature_step(sig).lower(
+        cache["exact", variant, rows, cold] = eng.signature_step(sig).lower(
             *_on(one_chip, eng.signature_templates(sig))).compile()
-    return fcfg, cache["exact", variant, rows]
+    return fcfg, cache["exact", variant, rows, cold]
 
 
 @pytest.mark.parametrize("variant", ["step", "compact"])
@@ -526,6 +531,82 @@ def test_exact_key_programs_fit_the_chip_at_the_benchmarks_size(
     # the chip has 15.75 GB for a program; the direct step's temporaries
     # are 1.7 GB, the compaction's one padded [2^23, 40] view 4.4
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+    if variant == "compact":
+        # not above the entry-wide pass's (PERF.md, PR 32 and PR 38)
+        assert mem.temp_size_in_bytes <= 4_363_527_680
+
+
+def indexed_ops(hlo_text):
+    """``(gather | scatter, lanes, inside a conditional's branch)`` of
+    every one in a compiled program — lanes: how many indices it works
+    on, the index operand's longest side."""
+    bodies = _computations(hlo_text)
+    branches = _reached_from(bodies, [
+        name for group in re.findall(
+            r"branch_computations=\{([^}]*)\}", hlo_text)
+        for name in re.findall(r"%([\w.\-]+)", group)] + re.findall(
+            r"(?:true|false)_computation=%([\w.\-]+)", hlo_text))
+    out = []
+    for name, body in bodies.items():
+        shape = {m.group(1): [int(d) for d in m.group(2).split(",") if d]
+                 for m in re.finditer(
+                     r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]", body,
+                     re.M)}
+        for m in re.finditer(
+                r" (gather|scatter)\(%[\w.\-]+, %([\w.\-]+)", body):
+            out.append((m.group(1), max(shape.get(m.group(2)) or [1]),
+                        name in branches))
+    return out
+
+
+@pytest.mark.parametrize("cold", [False, True],
+                         ids=["forest-exact", "forest-cold"])
+def test_compaction_is_as_wide_as_what_it_vacates(
+        topo, one_chip, as_on_chip, compiled_steps, cold):
+    """What the chip's compiler made of the pass at the two exact cells'
+    size: the one operation with a lane a directory entry — the
+    ``newest[slot]`` gather, 2^23 and 2^24 lanes — sits inside a
+    conditional's branch, a table, so a table that gives nothing up
+    never runs it; no ``scatter`` is that wide anywhere (the parent's
+    two, a table, were 333 ms of a 0.71 s pass: PERF.md, PR 35); every
+    other gather and scatter works on a packed chunk of 16,384 lanes, or
+    on the demote payload's 131,072. The conditionals yield
+    ``[dir_cap]`` vectors alone: no window column and no directory comes
+    out of one, or is copied anywhere in the program."""
+    from real_time_fraud_detection_system_tpu.ops.keydir import pack_lanes
+
+    fcfg, compiled = _compiled_exact(compiled_steps, one_chip, "compact",
+                                     cold=cold)
+    text = compiled.as_text()
+    dirs = (2 * fcfg.customer_capacity, 2 * fcfg.terminal_capacity)
+    ops = indexed_ops(text)
+    wide = [op for op in ops if op[1] >= min(dirs)]
+    assert sorted(wide) == [("gather", d, True) for d in dirs], wide
+    lanes = pack_lanes(dirs[0])
+    assert lanes == 16384
+    narrow = {n for _, n, _ in ops if n < min(dirs)}
+    assert narrow <= {lanes, fcfg.cold_demote_slots}, narrow
+    assert sum(kind == "scatter" for kind, _, _ in ops) == 4
+    for m in re.finditer(r"= \((.*?)\) conditional\(", text):
+        sizes = {int(n) for n in re.findall(r"\w+\[(\d+)\]", m.group(1))}
+        assert sizes <= set(dirs), m.group(0)[:200]
+    nb = fcfg.n_day_buckets
+    columns = {cap * nb for cap in (fcfg.customer_capacity,
+                                    fcfg.terminal_capacity)}
+    # (the dense sweep's padded [cap, 40] view and its broadcast mask
+    # are reshapes, left as they were: ROADMAP A9)
+    assert not [m for m in whole_column_moves(text, columns)
+                if m[0] != "reshape"]
+    copied = [ln.strip()[:120] for ln in text.splitlines() if re.search(
+        rf"= \w+\[({dirs[0]}|{dirs[1]})\]\S* copy\(", ln)]
+    # the running counts' layout changes apart (s32 [n/128, 128] views),
+    # the compiler copies one directory-sized vector a table: the vacated
+    # ``slots`` on their way out — never a window column
+    assert len(copied) <= 2, copied
+    if cold:
+        # state + payload out; the payload's loop-carried buffers are
+        # what this variant holds beyond the parent's 4.37 GB
+        assert compiled.memory_analysis().temp_size_in_bytes < 5.0e9
 
 
 @pytest.mark.parametrize("rows", [256, EXACT_ROWS])
