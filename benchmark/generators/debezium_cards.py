@@ -350,6 +350,14 @@ class Traffic:
             return len(self.due_s)
         return self.window.rows_polled
 
+    def draw_stats(self) -> dict:
+        """How far the window went into its draw: the rows it polled, the
+        draw's length, and how many times the draw was begun again
+        (``draw_wraps``: 0 while every window row is a draw of its own)."""
+        polled = self.window.rows_polled
+        return {"rows_polled": polled, "draw_rows": self.draw_rows,
+                "draw_wraps": max(polled - 1, 0) // self.draw_rows}
+
     def queue_stats(self) -> dict:
         """Entry-queue readings of the window, for the ``traffic`` reader."""
         w = self.window

@@ -331,7 +331,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              t_start: float, root: str = ROOT, allow_cpu: bool = False,
              overrides: Optional[dict] = None, trace_dir: str = "",
              control: bool = False,
-             sabotage: Optional[Callable] = None) -> dict:
+             sabotage: Optional[Callable] = None,
+             oracle=None) -> dict:
     """→ the result object of the contract's last line (and, under
     ``checks``, the numbers compared).
 
@@ -339,7 +340,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     ``control`` also puts the lower-precision reference in the program's
     place after the check and reports what the comparison says of it under
     ``control``. ``sabotage(engine, sink)`` is for the benchmark's own
-    tests: it breaks the timed path underneath before the fill starts."""
+    tests: it breaks the timed path underneath before the fill starts.
+    ``oracle`` (a class with ``WindowReference``'s interface; the builder's
+    ``--oracle 1`` passes the tests' dense one) repeats the comparison with
+    that class in the reference's place and reports, under ``oracle``,
+    whether every number came out the same."""
     manifest = load_manifest(root)
     cell = Cell(root, manifest, workload, overrides)
     devices = claim_device(root, cell.chips, allow_cpu)
@@ -455,8 +460,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "setup_s": setup_s,
         }
         queue_stats = traffic.queue_stats()
+        draw = traffic.draw_stats()
         say("window", seconds=seconds, batches=run_stats["batches"],
-            rows=run_stats["rows"], rows_acked_in_window=rows_in_window,
+            rows=run_stats["rows"], rows_polled=draw["rows_polled"],
+            draw_rows=draw["draw_rows"], rows_acked_in_window=rows_in_window,
             acks_in_window=len(acks),
             rows_in_window_per_s=rows_in_window / seconds,
             rows_per_s=rows_per_s, decision_p50_ms=p50,
@@ -483,19 +490,28 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "delta")
         numbers.append(reference.number("recompiles_in_window", recompiles,
                                         limits))
+        # a traffic file that states limits of its own (the draw rule's
+        # draw_wraps) is held to them by what its generator counted
+        for name in cell.traffic.get("limits", {}):
+            numbers.append(reference.number(name, draw[name],
+                                            cell.traffic["limits"]))
         sample = sample_batches(batch_ids, fill_stats["batches"],
                                 cell.traffic, seed)
         parts = read_parts(out_dir, sample)
-        numbers += reference.check_rows(
+        t5b = time.perf_counter()
+        compared = reference.check_rows(
             parts, batch_ids, sample, traffic, cell.config,
             model["reference_proba"])
+        numbers += compared
         for n in numbers:
             say("check", **n)
         correct = all(n["ok"] for n in numbers)
         flagged = sum(int((p["prediction"] >= 0.5).sum())
                       for p in parts.values())
         say("check", correct=correct, batches_compared=len(sample),
-            flagged=flagged, check_s=round(time.perf_counter() - t5, 2))
+            flagged=flagged, check_s=round(time.perf_counter() - t5, 2),
+            sink_read_s=round(t5b - t5, 2),
+            reference_s=round(time.perf_counter() - t5b, 2))
         controlled = None
         if control:
             controlled = reference.check_rows(
@@ -503,6 +519,22 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 model["reference_proba"], lower_precision=True)
             for n in controlled:
                 say("control", **n)
+        by_oracle = None
+        if oracle is not None:
+            t6 = time.perf_counter()
+            by_oracle = {"checks": reference.check_rows(
+                parts, batch_ids, sample, traffic, cell.config,
+                model["reference_proba"], window_reference=oracle)}
+            same = by_oracle["checks"] == compared
+            if control:
+                by_oracle["control"] = reference.check_rows(
+                    {}, batch_ids, sample, traffic, cell.config,
+                    model["reference_proba"], lower_precision=True,
+                    window_reference=oracle)
+                same = same and by_oracle["control"] == controlled
+            by_oracle["equal"] = bool(same)
+            say("oracle", equal=same, numbers=json.dumps(by_oracle),
+                oracle_s=round(time.perf_counter() - t6, 2))
 
         # -- the line ----------------------------------------------------
         device = {"platform": devices[0].platform,
@@ -525,6 +557,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         result["checks"] = numbers
         if controlled is not None:
             result["control"] = controlled
+        if by_oracle is not None:
+            result["oracle"] = by_oracle
         return result
     finally:
         shutil.rmtree(out_dir, ignore_errors=True)
@@ -546,6 +580,8 @@ def traced_metrics(cell: Cell, trace_dir: str, traced: dict, done: list,
         window_s, batches)
     if summary is None:
         raise RuntimeError("no operation ran on the device in the trace")
+    if not summary["steps"]:
+        raise RuntimeError("the trace holds no whole step of the program")
     ctx["trace_summary"] = summary
     metrics = {}
     for m in cell.per_layer():
@@ -554,7 +590,8 @@ def traced_metrics(cell: Cell, trace_dir: str, traced: dict, done: list,
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     device["busy_s"] = summary["busy_s"]
     device["window_s"] = summary["window_s"]
-    say("trace", batches_in_trace=batches, window_s=window_s,
+    say("trace", acks_in_trace=batches, steps_in_trace=summary["steps"],
+        step_span_s=summary["span_s"], window_s=window_s,
         busy_s=summary["busy_s"])
     return {"metrics": metrics,
             "breakdown": {"device_ops": summary["device_ops"],

@@ -2,14 +2,21 @@
 split by the ``rtfds.<stage>`` scopes the program puts on its step
 (``utils/trace.STEP_SCOPES``, opened with ``jax.named_scope``).
 
-``read(ctx, scopes=[...], stat="ms")`` is the share of the busy time that
-ran under any of ``scopes`` times ``trace_summary["device_step_ms"]``, so
-the stage metrics and ``device_step_ms`` have one denominator and the stages
-plus the unscoped rest add up to the step. ``stat="unscoped_pct"`` is the
-share, in per cent, under no ``rtfds.`` component at all. A scope is given
-as ``"rtfds.update/rtfds.relayout"`` and matches as consecutive components
+``read(ctx, scopes=[...], stat="ms")`` is the device time that ran under
+any of ``scopes`` inside the trace's span of whole steps
+(``device_trace.whole_steps``: first whole execution's start → last one's
+end) ÷ the number of those steps, in milliseconds. ``device_step_ms`` is the
+same steps' mean duration, so the stage metrics and ``device_step_ms`` have
+one denominator — the steps the trace holds, never the acknowledgements —
+and the stages whose scopes live inside the step plus the unscoped rest
+never exceed it; a scope opened in a program of its own (``rtfds.compact``,
+``rtfds.demote``, ``rtfds.promote``) reads that program's device time
+inside the span ÷ the same count. ``stat="unscoped_pct"`` is the share, in
+per cent, of the whole steps' own busy time under no ``rtfds.`` component
+at all (what a compaction leaves unnamed is not in it). A scope is given
+as ``"rtfds.update/rtfds.reset"`` and matches as consecutive components
 of an operation's HLO ``op_name``
-(``jit(step)/rtfds.terminal/rtfds.update/rtfds.relayout/reshape``), so
+(``jit(step)/rtfds.terminal/rtfds.update/rtfds.reset/select_n``), so
 ``"rtfds.query"`` takes both tables' and a later
 ``"rtfds.terminal/rtfds.update/rtfds.reset"`` one table's.
 
@@ -45,6 +52,7 @@ left out of the line.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -53,28 +61,34 @@ import tempfile
 from collections import defaultdict
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
+from benchmark.readers import device_trace
+
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)")
 OP_LINE = "XLA Ops"
 OP_NAME_STAT = "tf_op"
 PREFIX = "rtfds."
 CTX_KEY = "device_scopes"
+PS_PER_MS = 1e9
+PS_PER_US = 1_000_000
 
 
 def read(ctx: dict, scopes: Sequence[str] = (), stat: str = "ms"):
     if CTX_KEY not in ctx:
         path = find_trace()
-        ctx[CTX_KEY] = table(load_events(path)) if path else None
+        ctx[CTX_KEY] = per_step(*load_lines(path)) if path else None
     t = ctx[CTX_KEY]
     if t is None:
         return None
+    counted = (ctx.get("trace_summary") or {}).get("steps", t["n_steps"])
+    if counted != t["n_steps"]:
+        raise RuntimeError(
+            f"the trace's whole steps: {counted} by device_trace, "
+            f"{t['n_steps']} by device_scopes")
     if stat == "unscoped_pct":
-        return 100.0 * under(t, None) / t["busy"]
+        return 100.0 * under(t["steps"], None) / t["steps"]["busy"]
     if stat != "ms":
         raise ValueError(f"unknown stat {stat!r}")
-    step_ms = (ctx.get("trace_summary") or {}).get("device_step_ms")
-    if step_ms is None:
-        return None
-    return under(t, scopes) / t["busy"] * step_ms
+    return under(t["span"], scopes) / t["n_steps"] / PS_PER_MS
 
 
 # -- the arithmetic ----------------------------------------------------------
@@ -127,6 +141,37 @@ def table(events: List[list]) -> Optional[dict]:
     return {"busy": sum(by_op.values()), "by_op_name": dict(by_op)}
 
 
+def clip(events: List[list], spans: List[Tuple[int, int]]) -> List[list]:
+    """The parts of ``events`` (``[name, start, duration, ...]``) that lie
+    inside the disjoint, sorted ``spans``."""
+    ends = [b for _, b in spans]
+    out = []
+    for ev in events:
+        s, e = ev[1], ev[1] + ev[2]
+        for a, b in spans[bisect.bisect_right(ends, s):]:
+            if a >= e:
+                break
+            out.append([ev[0], max(s, a), min(e, b) - max(s, a)] + ev[3:])
+    return out
+
+
+def per_step(ops: List[list], modules: List[list]) -> Optional[dict]:
+    """One device's op line (``[[name, start_ps, duration_ps, op_name]]``)
+    and module line (``[[name, start_ps, duration_ps]]``) → ``{"n_steps",
+    "span": table of what ran from the first whole step's start to the
+    last one's end, "steps": table of what ran inside the whole steps}``.
+    ``None`` when the step carries no ``rtfds.`` scope or the trace holds
+    no whole step."""
+    steps = device_trace.whole_steps(modules, ops, per_us=PS_PER_US)
+    if not steps:
+        return None
+    span = table(clip(ops, [(steps[0][0], steps[-1][1])]))
+    inside = table(clip(ops, steps))
+    if span is None or inside is None:
+        return None
+    return {"n_steps": len(steps), "span": span, "steps": inside}
+
+
 # -- the file ----------------------------------------------------------------
 
 
@@ -145,6 +190,10 @@ def find_trace() -> Optional[str]:
         if not dirs:
             return None
         root = max(dirs, key=os.path.getmtime)
+    return find_trace_under(root)
+
+
+def find_trace_under(root: str) -> Optional[str]:
     files = glob.glob(os.path.join(root, "**", "*.xplane.pb"),
                       recursive=True)
     return max(files, key=os.path.getmtime) if files else None
@@ -191,10 +240,12 @@ def _map_entry(v) -> Tuple[int, object]:
     return d.get(1, 0), d.get(2, b"")
 
 
-def load_events(path: str) -> List[list]:
+def load_lines(path: str) -> Tuple[List[list], List[list]]:
     """The first device's ``XLA Ops`` line as ``[[name, start_ps,
-    duration_ps, op_name], ...]``; ``op_name`` is "" where the event's
-    metadata has no ``tf_op`` stat. Field numbers are those of
+    duration_ps, op_name], ...]`` (``op_name`` is "" where the event's
+    metadata has no ``tf_op`` stat) and its ``XLA Modules`` line as
+    ``[[name, start_ps, duration_ps], ...]``, both on one clock (a line's
+    ``timestamp_ns`` plus the event's offset). Field numbers are those of
     ``tsl/profiler/protobuf/xplane.proto``."""
     with open(path, "rb") as f:
         space = memoryview(f.read())
@@ -207,7 +258,7 @@ def load_events(path: str) -> List[list]:
         if m and (best is None or int(m.group(1)) < best[0]):
             best = (int(m.group(1)), plane)
     if best is None:
-        return []
+        return [], []
     lines, event_meta, stat_names = [], {}, {}
     for num, v in fields(best[1]):
         if num == 3:  # XPlane.lines
@@ -234,18 +285,24 @@ def load_events(path: str) -> List[list]:
                     op_name = stat_names.get(s[7], "")
         # "<op_name>:<op_type>"
         names[k] = (name, op_name.rsplit(":", 1)[0])
-    out = []
+    ops, modules = [], []
     for line in lines:
-        events, line_name = [], ""
+        events, line_name, t0_ps = [], "", 0
         for num, v in fields(line):
             if num == 4:  # XLine.events
                 events.append(v)
             elif num == 2:  # XLine.name
                 line_name = _text(v)
-        if line_name != OP_LINE:
+            elif num == 3:  # XLine.timestamp_ns
+                t0_ps = int(v) * 1000
+        if line_name not in (OP_LINE, device_trace.MODULE_LINE):
             continue
         for ev in events:
             e = dict(fields(ev))  # metadata_id 1, offset_ps 2, duration_ps 3
             name, op_name = names.get(e.get(1, 0), ("", ""))
-            out.append([name, int(e.get(2, 0)), int(e.get(3, 0)), op_name])
-    return out
+            start, dur = t0_ps + int(e.get(2, 0)), int(e.get(3, 0))
+            if line_name == OP_LINE:
+                ops.append([name, start, dur, op_name])
+            else:
+                modules.append([name, start, dur])
+    return ops, modules

@@ -51,7 +51,7 @@ from benchmark.readers import device_scopes, device_trace, tracer_spans
 
 CTX_KEY = "loop_idle"
 ANNOTATION = re.compile(r"^rtfds\.(.+)#(\d+)$")
-MODULE_LINE = "XLA Modules"
+MODULE_LINE = device_trace.MODULE_LINE
 MIN_GAP_S = 1e-3
 UNSPANNED = ("run", "loop_pass")
 

@@ -43,8 +43,100 @@ def bf16_round(x: np.ndarray) -> np.ndarray:
     return u.astype(np.uint32).view(np.float32)
 
 
+class KeyRows:
+    """key → row of a table that grows by one row a new key, rows in order
+    of first sight. Nothing here is sized by the id universe: two sorted
+    key arrays (a large one and the recent arrivals, merged into it every
+    ``MERGE`` keys) and two searches a lookup."""
+
+    MERGE = 1 << 18
+
+    def __init__(self):
+        empty = np.empty(0, np.int64)
+        self._levels = [(empty, empty), (empty, empty)]  # (keys, rows)
+        self.count = 0
+
+    def find(self, keys: np.ndarray) -> np.ndarray:
+        """Row of every key; -1 for a key never admitted."""
+        uniq, inverse = np.unique(keys, return_inverse=True)
+        return self._find_sorted(uniq)[inverse]
+
+    def _find_sorted(self, uniq: np.ndarray) -> np.ndarray:
+        # sorted needles: the search walks each level once, front to back
+        out = np.full(len(uniq), -1, np.int64)
+        for ks, rs in self._levels:
+            if len(ks):
+                pos = np.minimum(np.searchsorted(ks, uniq), len(ks) - 1)
+                hit = ks[pos] == uniq
+                out[hit] = rs[pos[hit]]
+        return out
+
+    def admit(self, keys: np.ndarray) -> np.ndarray:
+        """Row of every key, new keys given the next rows in sorted
+        order."""
+        uniq, inverse = np.unique(keys, return_inverse=True)
+        rows = self._find_sorted(uniq)
+        miss = rows < 0
+        if miss.any():
+            new = uniq[miss]
+            ks, rs = self._levels[1]
+            at = np.searchsorted(ks, new)
+            fresh = self.count + np.arange(len(new), dtype=np.int64)
+            self._levels[1] = (np.insert(ks, at, new),
+                               np.insert(rs, at, fresh))
+            self.count += len(new)
+            rows[miss] = fresh
+            if len(self._levels[1][0]) > self.MERGE:
+                (mk, mr), (ks, rs) = self._levels
+                at = np.searchsorted(mk, ks)
+                self._levels = [(np.insert(mk, at, ks),
+                                 np.insert(mr, at, rs)),
+                                (ks[:0], rs[:0])]
+        return rows[inverse]
+
+
+def grown(table: np.ndarray, rows: int) -> np.ndarray:
+    """``table`` with room for at least ``rows`` rows, the new ones zero.
+    Grown in place (``realloc``: a large block is remapped, not copied)
+    an eighth at a time; no view of the table may be alive."""
+    if rows > len(table):
+        table.resize((max(rows, len(table) + len(table) // 8, 1 << 12),)
+                     + table.shape[1:], refcheck=False)
+    return table
+
+
+def add_in_row_order(counts_flat: np.ndarray, sums_flat: np.ndarray,
+                     cells: np.ndarray, values: np.ndarray) -> None:
+    """``np.add.at(counts_flat, cells, 1)`` and ``np.add.at(sums_flat,
+    cells, values)`` to the bit — every cell takes its values one by one
+    in row order — in one sort and a few vectorised passes: the k-th pass
+    adds, to every cell, the k-th of its rows."""
+    order = np.argsort(cells, kind="stable")
+    cs, vs = cells[order], values[order]
+    first = np.flatnonzero(np.r_[True, cs[1:] != cs[:-1]])
+    counts = np.diff(np.r_[first, len(cs)])
+    counts_flat[cs[first]] += counts.astype(counts_flat.dtype)
+    sums_flat[cs[first]] += vs[first]
+    if counts.max() > 1:
+        rank = np.arange(len(cs)) - np.repeat(first, counts)
+        for k in range(1, int(counts.max())):
+            again = np.flatnonzero(rank == k)
+            sums_flat[cs[again]] += vs[again]
+
+
+def count_in(flat: np.ndarray, cells: np.ndarray) -> None:
+    """``np.add.at(flat, cells, 1)``: integers, so the order is nothing."""
+    uniq, counts = np.unique(cells, return_counts=True)
+    flat[uniq] += counts.astype(flat.dtype)
+
+
 class WindowReference:
-    """Daily aggregates per key over the days a run touches.
+    """Daily aggregates per key over the days a run touches, one table
+    row a key the run has shown it (``KeyRows``): memory and time follow
+    the touched keys, not the id universe. The arithmetic a key sees is
+    that of a dense ``[universe, days]`` table, to the bit
+    (``benchmark/tests/dense_reference.py`` keeps that one as the tests'
+    oracle).
 
     ``lower_precision=True`` is the CONTROL, not a reference: the same
     arithmetic with every stored sum and every emitted feature rounded to
@@ -59,9 +151,10 @@ class WindowReference:
         self.weekend_start = int(features["weekend_start_weekday"])
         self.first_day, self.n_days = int(first_day), int(n_days)
         self.low = bool(lower_precision)
-        self.c_cnt = np.zeros((n_customers, n_days), np.int32)
-        self.c_amt = np.zeros((n_customers, n_days), np.float64)
-        self.t_cnt = np.zeros((n_terminals, n_days), np.int32)
+        self.c_rows, self.t_rows = KeyRows(), KeyRows()
+        self.c_cnt = np.zeros((0, self.n_days), np.int32)
+        self.c_amt = np.zeros((0, self.n_days), np.float64)
+        self.t_cnt = np.zeros((0, self.n_days), np.int32)
 
     def _day_index(self, t_us: np.ndarray) -> np.ndarray:
         d = t_us // US_PER_DAY - self.first_day
@@ -71,14 +164,16 @@ class WindowReference:
 
     def update(self, cols: dict) -> None:
         """One batch's rows enter the aggregates."""
-        c, t = cols["customer_id"], cols["terminal_id"]
+        c = self.c_rows.admit(np.asarray(cols["customer_id"], np.int64))
+        t = self.t_rows.admit(np.asarray(cols["terminal_id"], np.int64))
+        self.c_cnt = grown(self.c_cnt, self.c_rows.count)
+        self.c_amt = grown(self.c_amt, self.c_rows.count)
+        self.t_cnt = grown(self.t_cnt, self.t_rows.count)
         d = self._day_index(cols["tx_datetime_us"])
         amount = (cols["tx_amount_cents"] / 100.0).astype(np.float32)
-        # flat views: ufunc.at is fast on one-dimensional indices
-        np.add.at(self.c_cnt.reshape(-1), c * self.n_days + d, 1)
-        np.add.at(self.c_amt.reshape(-1), c * self.n_days + d,
-                  amount.astype(np.float64))
-        np.add.at(self.t_cnt.reshape(-1), t * self.n_days + d, 1)
+        add_in_row_order(self.c_cnt.reshape(-1), self.c_amt.reshape(-1),
+                         c * self.n_days + d, amount.astype(np.float64))
+        count_in(self.t_cnt.reshape(-1), t * self.n_days + d)
         if self.low:
             self.c_amt[c, d] = bf16_round(self.c_amt[c, d])
         for back in range(self.ring, self.n_days, self.ring):
@@ -87,9 +182,12 @@ class WindowReference:
             self.c_amt[c[old], d[old] - back] = 0.0
             self.t_cnt[t[old], d[old] - back] = 0
 
-    def _window_sums(self, table, key, last_day) -> np.ndarray:
-        """[n, len(windows)]: table[key, last_day-w+1 .. last_day]."""
-        rows = table[key].astype(np.float64)
+    def _window_sums(self, table, row, last_day) -> np.ndarray:
+        """[n, len(windows)]: table[row, last_day-w+1 .. last_day]; a key
+        never seen (row -1) has nothing on any day."""
+        rows = np.zeros((len(row), self.n_days))
+        seen = row >= 0
+        rows[seen] = table[row[seen]]
         pre = np.concatenate(
             [np.zeros((len(rows), 1)), np.cumsum(rows, axis=1)], axis=1)
         ok = last_day >= 0
@@ -102,7 +200,8 @@ class WindowReference:
 
     def features(self, cols: dict) -> np.ndarray:
         """The 15 features of a batch whose rows have already entered."""
-        c, t = cols["customer_id"], cols["terminal_id"]
+        c = self.c_rows.find(np.asarray(cols["customer_id"], np.int64))
+        t = self.t_rows.find(np.asarray(cols["terminal_id"], np.int64))
         us = cols["tx_datetime_us"]
         d = self._day_index(us)
         day, tod = us // US_PER_DAY, (us % US_PER_DAY) // 1_000_000
@@ -160,7 +259,8 @@ def number(name: str, value, limits: dict) -> dict:
 def check_rows(parts: Dict[int, dict], batch_ids: Dict[int, np.ndarray],
                sample: List[int], traffic, config: dict,
                reference_proba: Callable,
-               lower_precision: bool = False) -> List[dict]:
+               lower_precision: bool = False,
+               window_reference=None) -> List[dict]:
     """Run the reference over every batch in sink order and compare the
     sampled batches.
 
@@ -168,7 +268,10 @@ def check_rows(parts: Dict[int, dict], batch_ids: Dict[int, np.ndarray],
     in order: each one's rows enter the aggregates). ``parts``: batch_index
     → the full columns of the sampled part files. With ``lower_precision``
     the CONTROL takes the program's place: what is compared is the bf16
-    reference's features and the classifier behind a bf16 scaler."""
+    reference's features and the classifier behind a bf16 scaler.
+    ``window_reference`` puts another class in ``WindowReference``'s place
+    (the builder's comparison with the dense oracle of the tests)."""
+    make_reference = window_reference or WindowReference
     order = sorted(batch_ids)
     first = int(traffic.lookup(batch_ids[order[0]])
                 ["tx_datetime_us"].min() // US_PER_DAY)
@@ -177,7 +280,7 @@ def check_rows(parts: Dict[int, dict], batch_ids: Dict[int, np.ndarray],
     uni = config["key_universe"]
 
     def make(low: bool) -> WindowReference:
-        return WindowReference(
+        return make_reference(
             config["features"], int(uni["customers"]), int(uni["terminals"]),
             first, last - first + 1, lower_precision=low)
 

@@ -14,9 +14,9 @@ TOY = harness.load_json(os.path.join(
     harness.ROOT, "benchmark", "tests", "data", "toy_overrides.json"))
 
 
-def run(workload, seed, **kw):
+def run(workload, seed, overrides=TOY, **kw):
     return harness.run_cell(workload, seed, 1.0, False, time.perf_counter(),
-                            allow_cpu=True, overrides=TOY, **kw)
+                            allow_cpu=True, overrides=overrides, **kw)
 
 
 def failed(numbers):
@@ -97,3 +97,24 @@ def test_a_broken_timed_path_is_not_correct(sabotage, caught_by):
     assert result["correct"] is False
     assert caught_by <= set(failed(result["checks"])), failed(
         result["checks"])
+
+
+def test_a_window_that_outruns_its_draw_is_not_correct():
+    """A traffic file that carries the draw rule states ``draw_wraps`` 0:
+    a window that polls more rows than the draw holds begins it again, no
+    key is new from there on, and the run fails its check by that number
+    alone — every answer is still right."""
+    short = harness.merge(TOY, {"traffic": {"draw_rows": 4096,
+                                            "limits": {"draw_wraps": 0}}})
+    result = run("forest.saturate", 4_100_000_019, overrides=short)
+    assert result["correct"] is False
+    assert failed(result["checks"]) == ["draw_wraps"]
+    by = {n["name"]: n for n in result["checks"]}
+    assert by["draw_wraps"]["value"] >= 1 and by["draw_wraps"]["limit"] == 0
+    # the same window on a draw that outlasts it
+    long = harness.merge(TOY, {"traffic": {"draw_rows": 1 << 20,
+                                           "limits": {"draw_wraps": 0}}})
+    result = run("forest.saturate", 4_100_000_019, overrides=long)
+    assert result["correct"] is True, failed(result["checks"])
+    assert {n["name"]: n["value"] for n in result["checks"]}[
+        "draw_wraps"] == 0

@@ -112,23 +112,88 @@ def test_hand_made_events():
         t, ["rtfds.classify", "rtfds.classify/while"]) == 47
 
 
-def test_read_shares_the_step_with_device_step_ms():
-    ctx = {device_scopes.CTX_KEY: device_scopes.table(HAND),
-           "trace_summary": {"device_step_ms": 300.0}}
+def _steps(n, ops_in_a_step, between=()):
+    """``n`` executions of a step of ``ops_in_a_step`` (each ``(length,
+    op_name)``) with ``between`` after the first, a lead op before and a
+    tail op after: → (ops, modules)."""
+    ops, modules, t = [_ev("lead", 0, 7 * US)], [], 10 * US
+    for i in range(n):
+        start = t
+        for j, (length, op_name) in enumerate(ops_in_a_step):
+            ops.append(_ev(f"fusion.{j}", t, length * US, op_name))
+            t += length * US
+        modules.append(["jit_step(42)", start, t - start])
+        if i == 0:
+            for name, length, op_name in between:
+                ops.append(_ev("pass", t, length * US, op_name))
+                modules.append([name, t, length * US])
+                t += length * US
+        t += 2 * US  # the hand-over between two programs
+    ops.append(_ev("tail", t, 5 * US))
+    return ops, modules
+
+
+US = device_scopes.PS_PER_US  # the hand-made steps count microseconds
+STEP = [(30, "jit(step)/rtfds.terminal/rtfds.update/rtfds.reset/select_n"),
+        (47, "jit(step)/rtfds.classify/while/body/dot"),
+        (3, ""), (20, "jit(step)/rtfds.customer/rtfds.query/x")]
+
+
+def test_read_divides_by_the_steps_the_trace_holds():
+    ops, modules = _steps(4, STEP)
+    t = device_scopes.per_step(ops, modules)
+    assert t["n_steps"] == 4
+    ctx = {device_scopes.CTX_KEY: t}
+    ps = device_scopes.PS_PER_MS / US
     got = device_scopes.read(ctx, scopes=["rtfds.classify"], stat="ms")
-    assert got == pytest.approx(300.0 * 47 / 99)
-    pct = device_scopes.read(ctx, stat="unscoped_pct")
-    assert pct == pytest.approx(100.0 * 18 / 99)
+    assert got == pytest.approx(47 / ps)  # 4 x 47 under the scope, 4 steps
+    assert device_scopes.read(
+        ctx, scopes=["rtfds.update/rtfds.reset"]) == pytest.approx(30 / ps)
+    assert device_scopes.read(ctx, stat="unscoped_pct") == pytest.approx(3.0)
     with pytest.raises(ValueError):
         device_scopes.read(ctx, scopes=[], stat="share")
+    # an execution the trace's head cut (it begins with the trace's first
+    # operation) and one in flight at its end are no steps: their time
+    # under a scope is outside the span and divides into nothing
+    cut_ops = [e for e in ops if e[0] != "%lead = f32[] x()"]
+    assert device_scopes.per_step(cut_ops, modules)["n_steps"] == 3
+    no_tail = [e for e in ops if e[0] != "%tail = f32[] x()"]
+    t3 = device_scopes.per_step(no_tail, modules)
+    assert t3["n_steps"] == 3
+    assert device_scopes.read({device_scopes.CTX_KEY: t3},
+                              scopes=["rtfds.classify"]) == pytest.approx(
+        47 / ps)
+
+
+def test_a_program_of_its_own_is_read_per_step_beside_the_step():
+    """A compaction between two steps: its scope reads its device time ÷
+    the steps, the step's own stages and the unscoped share do not hold
+    it."""
+    between = [("jit_compact(7)", 400, "jit(compact)/rtfds.compact/gather"),
+               ("jit_compact(7)", 100, "")]  # what it leaves unnamed
+    ops, modules = _steps(4, STEP, between)
+    ctx = {device_scopes.CTX_KEY: device_scopes.per_step(ops, modules)}
+    ps = device_scopes.PS_PER_MS / US
+    assert device_scopes.read(
+        ctx, scopes=["rtfds.compact"]) == pytest.approx(400 / 4 / ps)
+    assert device_scopes.read(
+        ctx, scopes=["rtfds.classify"]) == pytest.approx(47 / ps)
+    assert device_scopes.read(ctx, stat="unscoped_pct") == pytest.approx(3.0)
+    # a pass after the last whole step is outside the span
+    ops, modules = _steps(1, STEP, between)
+    ctx = {device_scopes.CTX_KEY: device_scopes.per_step(ops, modules)}
+    assert device_scopes.read(ctx, scopes=["rtfds.compact"]) == 0.0
 
 
 def test_a_step_without_scopes_reads_as_nothing():
     bare = [_ev("fusion.1", 0, 10, "jit(step)/scatter-add"),
             _ev("copy.1", 10, 5)]
     assert device_scopes.table(bare) is None
-    ctx = {device_scopes.CTX_KEY: None,
-           "trace_summary": {"device_step_ms": 300.0}}
+    ops, modules = _steps(3, [(10, "jit(step)/scatter-add"), (5, "")])
+    assert device_scopes.per_step(ops, modules) is None
+    ops, modules = _steps(3, STEP)
+    assert device_scopes.per_step(ops, []) is None  # no whole step
+    ctx = {device_scopes.CTX_KEY: None}
     assert device_scopes.read(ctx, scopes=["rtfds.query"]) is None
     assert device_scopes.read(ctx, stat="unscoped_pct") is None
 
@@ -138,7 +203,7 @@ def test_no_trace_reads_as_nothing(tmp_path, monkeypatch):
                         lambda: str(tmp_path))
     monkeypatch.setattr(sys, "argv", ["run.py", "--trace", "1"])
     assert device_scopes.find_trace() is None
-    ctx = {"trace_summary": {"device_step_ms": 300.0}}
+    ctx = {}
     assert device_scopes.read(ctx, scopes=["rtfds.query"]) is None
     assert ctx[device_scopes.CTX_KEY] is None  # looked for once
     # an empty directory named on the command line: still nothing
@@ -165,5 +230,5 @@ def test_the_trace_file_is_found_and_parsed(tmp_path, monkeypatch):
     assert found and found.endswith(".xplane.pb")
     monkeypatch.setattr(sys, "argv", ["run.py", f"--trace-dir={d}"])
     assert device_scopes.find_trace() == found
-    assert device_scopes.load_events(found) == []
+    assert device_scopes.load_lines(found) == ([], [])
     assert device_scopes.read({}, stat="unscoped_pct") is None

@@ -8,7 +8,6 @@ reclaims keys which then come back. With one probe instead of sixteen
 the same rehearsal serves rows from the sketch tier and is not
 ``correct``: the check catches it."""
 
-import json
 import os
 import re
 import time
@@ -20,7 +19,6 @@ from benchmark import harness
 from benchmark.readers import device_scopes
 
 ROOT = harness.ROOT
-DATA = os.path.join(os.path.dirname(__file__), "data")
 CELL = "forest-exact.saturate"
 US_PER_DAY = 86_400_000_000
 NEW = ["step_keydir_ms.sat", "step_keydir_lookup_ms.sat",
@@ -46,7 +44,9 @@ TOY = {
     },
     "traffic": {
         "fill_batches": 40, "fill_batch_rows": 512, "pool_envelopes": 4096,
-        "draw_rows": 16384, "max_poll_rows": 512,
+        # a draw no rehearsal's window outlasts: the file's draw_wraps 0
+        # holds here too
+        "draw_rows": 131072, "max_poll_rows": 512,
         "check_window_rows": 1 << 20,
     },
 }
@@ -60,9 +60,16 @@ def test_the_cell_resolves_to_its_files_and_only_the_key_path_differs():
     assert cell.chips == 1 and cell.regime == "sat"
     assert [m["name"] for m in cell.end_to_end()] == ["rows_per_s",
                                                       "setup_s"]
+    assert cell.entry["traffic"] == "saturate-arriving"
     assert cell.traffic.pop("generator") == "debezium_cards_active"
     assert one.traffic.pop("generator") == "debezium_cards"
-    assert cell.traffic == one.traffic  # saturate, key for key
+    # saturate key for key, but for a draw that outlasts the window and
+    # the rule that keeps it so (test_generator.py holds the arithmetic)
+    assert cell.traffic.pop("draw_rows") == 4 * one.traffic.pop("draw_rows")
+    assert cell.traffic.pop("limits") == {"draw_wraps": 0}
+    assert cell.traffic.pop("derived_from")["cell"] == CELL
+    assert cell.traffic.pop("why") != one.traffic.pop("why")
+    assert cell.traffic == one.traffic
     ex, d8 = cell.config, one.config
     for key in ("limits", "guarantees", "runtime", "ingest", "model",
                 "model_params", "reduced", "chips"):
@@ -91,7 +98,7 @@ def test_the_cell_resolves_to_its_files_and_only_the_key_path_differs():
     assert set(NEW) <= set(mine) and not set(NEW) & theirs
     assert {mine[n]["layer"] for n in NEW} == {
         "key directory and sketch tier"}
-    assert all(mine[n]["workloads"] == [CELL] for n in NEW)
+    assert all(CELL in mine[n]["workloads"] for n in NEW)
     assert theirs <= set(mine)  # every .sat metric of forest.saturate
     assert not SHARDED & set(mine)
 
@@ -152,23 +159,45 @@ def test_ids_are_a_seeded_sample_of_the_universe_under_the_same_laws():
             np.random.default_rng(0), 10, 11)
 
 
-def _program_events(engine):
-    """One event of unit length for every named op of the engine's largest
-    step and of its compaction, as the chip's trace would carry them: the
-    CPU's trace has no device plane, so the stage metrics are read from
-    the program's own compiled ``op_name``s — a metric file whose scope
-    the program does not open reads 0 here as it would on the chip."""
+US = 1_000_000  # picoseconds: every fabricated op runs a microsecond
+
+
+def _op_names(engine, sig):
+    text = engine.signature_step(sig).lower(
+        *engine.signature_templates(sig)).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+def program_trace(engine, between=("compact",)):
+    """A device plane as the chip's trace would carry it, made from the
+    program's own compiled ``op_name``s (the CPU's trace has no device
+    plane): the engine's largest step, the programs named in ``between``
+    (the compaction; the cold cell adds a promote), the step again — one
+    op of a microsecond for every named op, one ``XLA Modules`` event a
+    program — between two ops that make both steps whole. A metric file
+    whose scope the program does not open reads 0 here as it would on the
+    chip. → ``(trace for device_trace.summarize, ops, modules)``, the
+    last two on ``device_scopes.load_lines``'s picosecond clock."""
     sigs = engine.dispatch_inventory()
-    biggest = max((s for s in sigs if s.variant == "step"),
-                  key=lambda s: s.bucket)
-    events, t = [], 0
-    for sig in [biggest] + [s for s in sigs if s.variant == "compact"]:
-        text = engine.signature_step(sig).lower(
-            *engine.signature_templates(sig)).compile().as_text()
-        for name in re.findall(r'op_name="([^"]*)"', text):
-            events.append(["%op", t, 1, name])
-            t += 1
-    return events
+    step = max((s for s in sigs if s.variant == "step"),
+               key=lambda s: s.bucket)
+    programs = [("jit_step", _op_names(engine, step))]
+    for variant in between:
+        sig = next(s for s in sigs if s.variant == variant)
+        programs.append((f"jit_{variant}", _op_names(engine, sig)))
+    programs.append(programs[0])
+    ops, modules, t = [["%lead", 0, US, ""]], [], 2 * US
+    for name, op_names in programs:
+        modules.append([f"{name}(1)", t, len(op_names) * US])
+        for op_name in op_names:
+            ops.append(["%op", t, US, op_name])
+            t += US
+    ops.append(["%tail", t + US, US, ""])  # a hand-over later
+    ns = [{"name": device_scopes.device_trace.MODULE_LINE, "events": [
+        [n, s // 1000, d // 1000] for n, s, d in modules]},
+        {"name": device_scopes.OP_LINE, "events": [
+            [n, s // 1000, d // 1000] for n, s, d, _ in ops]}]
+    return {"planes": [{"name": "/device:TPU:0", "lines": ns}]}, ops, modules
 
 
 def rehearse(monkeypatch, trace, probes=None, seconds=3.0):
@@ -176,25 +205,35 @@ def rehearse(monkeypatch, trace, probes=None, seconds=3.0):
     over = TOY if probes is None else harness.merge(
         TOY, {"config": {"features": {"keydir_probes": probes}}})
     if trace:
-        with open(os.path.join(DATA, "trace_forest_saturate.json")) as f:
-            canned = json.load(f)
-        monkeypatch.setattr(harness.device_trace, "load_xplane",
-                            lambda path: canned)
-        inner = harness.traced_metrics
-
-        def with_the_programs_scopes(cell, trace_dir, traced, done, device,
-                                     ctx):
-            ctx[device_scopes.CTX_KEY] = device_scopes.table(
-                _program_events(seen["engine"]))
-            return inner(cell, trace_dir, traced, done, device, ctx)
-
-        monkeypatch.setattr(harness, "traced_metrics",
-                            with_the_programs_scopes)
+        install_program_trace(monkeypatch, seen)
     result = harness.run_cell(
         CELL, SEED, seconds, trace, time.perf_counter(), allow_cpu=True,
         overrides=over,
         sabotage=lambda engine, sink: seen.update(engine=engine, sink=sink))
     return result, seen
+
+
+def install_program_trace(monkeypatch, seen, between=("compact",)):
+    """The reduction reads ``program_trace`` of the engine the run built
+    (``seen["engine"]``) in place of the profiler's file."""
+    made = {}
+
+    def fabricated():
+        if not made:
+            made["t"] = program_trace(seen["engine"], between)
+        return made["t"]
+
+    monkeypatch.setattr(harness.device_trace, "load_xplane",
+                        lambda path: fabricated()[0])
+    inner = harness.traced_metrics
+
+    def with_the_programs_scopes(cell, trace_dir, traced, done, device,
+                                 ctx):
+        _, ops, modules = fabricated()
+        ctx[device_scopes.CTX_KEY] = device_scopes.per_step(ops, modules)
+        return inner(cell, trace_dir, traced, done, device, ctx)
+
+    monkeypatch.setattr(harness, "traced_metrics", with_the_programs_scopes)
 
 
 def reclaimed_and_back(t, done, n_before=4):

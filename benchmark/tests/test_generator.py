@@ -175,3 +175,69 @@ def test_the_rule_refuses_pr23s_rate_at_todays_pass_times():
     assert any("16384 rung" in f for f in faults), faults
     assert any("[16384, 65536]" in f for f in faults), faults
     assert _rate_faults(170_000, {"65536": 301.0}, buckets) == []
+
+
+def _draw_rule_traffic_files():
+    import glob
+
+    files = sorted(glob.glob(os.path.join(ROOT, "benchmark", "traffic",
+                                          "*.json")))
+    return [os.path.basename(f) for f in files
+            if "draw_wraps" in harness.load_json(f).get("limits", {})]
+
+
+def _draw_faults(traffic: dict, run_seconds: int) -> list:
+    """What is wrong with a backlogged mix's draw under the rule its
+    ``derived_from`` states: ``draw_rows`` = headroom x the ledger's
+    highest ``rows_per_s`` of a cell on the file x ``run_seconds``,
+    rounded up to a power of two, and a window that begins the draw again
+    fails its run (``limits.draw_wraps`` 0)."""
+    faults = []
+    d = traffic.get("derived_from", {})
+    for key in ("rule", "cell", "rows_per_s", "rows_per_s_source",
+                "run_seconds", "headroom", "commit", "date"):
+        if key not in d:
+            faults.append(f"derived_from lacks {key}")
+    if faults:
+        return faults
+    if traffic.get("limits", {}).get("draw_wraps") != 0:
+        faults.append("limits.draw_wraps is not 0")
+    if d["run_seconds"] != run_seconds:
+        faults.append(f"derived for {d['run_seconds']} s, the benchmark "
+                      f"runs {run_seconds}")
+    if d["headroom"] < 1.5:
+        faults.append(f"headroom {d['headroom']} under 1.5")
+    need = d["headroom"] * d["rows_per_s"] * d["run_seconds"]
+    want = 1 << int(np.ceil(np.log2(need)))
+    if traffic["draw_rows"] != want:
+        faults.append(f"draw_rows {traffic['draw_rows']}, the rule gives "
+                      f"{want} for {need:.0f} rows")
+    return faults
+
+
+@pytest.mark.parametrize("name", _draw_rule_traffic_files())
+def test_a_draw_that_carries_the_rule_outlasts_its_window(name):
+    manifest = harness.load_manifest()
+    traffic = harness.load_json(os.path.join(ROOT, "benchmark", "traffic",
+                                             name))
+    assert _draw_faults(traffic, manifest["run_seconds"]) == []
+    users = [w["name"] for w in manifest["workloads"]
+             if w["traffic"] + ".json" == name]
+    assert traffic["derived_from"]["cell"] in users
+    assert traffic["arrivals"] == "backlogged"
+
+
+def test_the_rule_refuses_the_draw_the_exact_cell_had():
+    """``saturate-active.json``'s 4,194,304 rows given the rule's block:
+    64 batches where a window at the ledger's 474,270 rows/s polls ~145."""
+    new = harness.load_json(os.path.join(ROOT, "benchmark", "traffic",
+                                         "saturate-arriving.json"))
+    old = harness.load_json(os.path.join(ROOT, "benchmark", "traffic",
+                                         "saturate-active.json"))
+    assert "derived_from" not in old and "limits" not in old
+    given = dict(old, derived_from=new["derived_from"], limits=new["limits"])
+    faults = _draw_faults(given, 20)
+    assert len(faults) == 1 and "draw_rows 4194304" in faults[0], faults
+    assert _draw_faults(dict(new, limits={}), 20) == [
+        "limits.draw_wraps is not 0"]
+    assert _draw_rule_traffic_files() == ["saturate-arriving.json"]

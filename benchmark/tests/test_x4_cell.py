@@ -106,10 +106,18 @@ def test_exchange_metric_reads_the_exchange_and_its_parts():
                                    "rtfds.unpack/gather"],
         ["%copy", 90, 10, ""],
     ]
-    ctx = {device_scopes.CTX_KEY: device_scopes.table(events),
-           "trace_summary": {"device_step_ms": 50.0}}
+    # the mesh's step is ``jit_outer`` on the module line; two whole
+    # executions between a lead and a tail operation
+    us = device_scopes.PS_PER_US
+    ops, modules = [["%lead", 0, 5 * us, ""]], []
+    for start in (10, 120):
+        ops += [[n, (start + s) * us, d * us, op] for n, s, d, op in events]
+        modules.append(["jit_outer(99)", start * us, 100 * us])
+    ops.append(["%tail", 230 * us, 5 * us, ""])
+    ctx = {device_scopes.CTX_KEY: device_scopes.per_step(ops, modules)}
     got = device_scopes.read(ctx, **spec["args"])
-    assert got == pytest.approx(50.0 * (10 + 20 + 5 + 15) / 100)
+    assert got == pytest.approx(
+        (10 + 20 + 5 + 15) * us / device_scopes.PS_PER_MS)
     assert device_scopes.read(ctx, stat="unscoped_pct") == pytest.approx(10)
 
 
@@ -120,8 +128,9 @@ def test_exchange_metric_reads_the_exchange_and_its_parts():
 REHEARSAL = """
 import json, sys, time
 from benchmark import harness
+from benchmark.tests.record_trace import trace_of
 with open(sys.argv[1]) as f:
-    canned = json.load(f)
+    canned = trace_of(json.load(f))
 harness.device_trace.load_xplane = lambda path: canned
 result = harness.run_cell(
     sys.argv[3], 2_800_000_123, 2.0, True, time.perf_counter(),
@@ -135,7 +144,7 @@ def test_rehearsal_on_four_devices_ends_with_the_cells_metrics():
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     run = subprocess.run(
         [sys.executable, "-c", REHEARSAL,
-         os.path.join(DATA, "trace_forest_saturate.json"),
+         os.path.join(DATA, "steps_forest_saturate.json"),
          os.path.join(DATA, "toy_overrides.json"), CELL],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
     assert run.returncode == 0, run.stderr[-2000:]
