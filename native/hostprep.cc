@@ -19,6 +19,10 @@
 //     or -1, valid flags; zeros in the padding tail. Output layout is
 //     core/batch.pack_batch's [7, pad] int32.
 //
+//   pack_rows_wide — the same at key_bits=64: an id is split into its two
+//     words instead of folded (low words in rows 0-1, high words in rows
+//     7-8 of core/batch.pack_batch's [9, pad] int32).
+//
 // Build: g++ -O3 -shared -fPIC -o libhostprep.so hostprep.cc
 
 #include <cstdint>
@@ -34,6 +38,51 @@ inline uint64_t mix64(uint64_t x) {
   x *= 0xc4ceb9fe1a85ec53ULL;
   x ^= x >> 33;
   return x;
+}
+
+// packed: int32 [7, pad] C-order, or [9, pad] with the keys' high words
+// in rows 7-8 (Wide). label may be NULL (=> -1 everywhere).
+template <bool Wide>
+void pack_rows_impl(const int64_t* dt_us, const int64_t* cust,
+                    const int64_t* term, const int64_t* amount,
+                    const int64_t* label, int64_t n, int64_t pad,
+                    int32_t* packed) {
+  const int64_t kUsPerDay = 86400000000LL;
+  int32_t* ck = packed;
+  int32_t* tk = packed + pad;
+  int32_t* day = packed + 2 * pad;
+  int32_t* tod = packed + 3 * pad;
+  int32_t* amt = packed + 4 * pad;
+  int32_t* lab = packed + 5 * pad;
+  int32_t* val = packed + 6 * pad;
+  int32_t* ch = packed + 7 * pad;  // Wide only
+  int32_t* th = packed + 8 * pad;
+  std::memset(packed, 0, sizeof(int32_t) * (Wide ? 9 : 7) * (size_t)pad);
+  for (int64_t i = 0; i < n; ++i) {
+    uint64_t c = (uint64_t)cust[i];
+    uint64_t t = (uint64_t)term[i];
+    if (Wide) {
+      ck[i] = (int32_t)(uint32_t)(c & 0xFFFFFFFFULL);
+      ch[i] = (int32_t)(uint32_t)(c >> 32);
+      tk[i] = (int32_t)(uint32_t)(t & 0xFFFFFFFFULL);
+      th[i] = (int32_t)(uint32_t)(t >> 32);
+    } else {
+      ck[i] = (int32_t)(uint32_t)((c ^ (c >> 32)) & 0xFFFFFFFFULL);
+      tk[i] = (int32_t)(uint32_t)((t ^ (t >> 32)) & 0xFFFFFFFFULL);
+    }
+    int64_t d = dt_us[i] / kUsPerDay;
+    int64_t r = dt_us[i] % kUsPerDay;
+    if (r < 0) {  // match NumPy floor-division semantics
+      d -= 1;
+      r += kUsPerDay;
+    }
+    day[i] = (int32_t)d;
+    tod[i] = (int32_t)(r / 1000000LL);
+    float a = (float)((double)amount[i] / 100.0);
+    std::memcpy(&amt[i], &a, 4);
+    lab[i] = label ? (int32_t)label[i] : -1;
+    val[i] = 1;
+  }
 }
 
 }  // namespace
@@ -80,38 +129,18 @@ int64_t latest_wins_keep(const int64_t* key, const int64_t* ts, int64_t n,
   return kept;
 }
 
-// packed: int32 [7, pad] C-order. label may be NULL (=> -1 everywhere).
 void pack_rows(const int64_t* dt_us, const int64_t* cust,
                const int64_t* term, const int64_t* amount,
                const int64_t* label, int64_t n, int64_t pad,
                int32_t* packed) {
-  const int64_t kUsPerDay = 86400000000LL;
-  int32_t* ck = packed;
-  int32_t* tk = packed + pad;
-  int32_t* day = packed + 2 * pad;
-  int32_t* tod = packed + 3 * pad;
-  int32_t* amt = packed + 4 * pad;
-  int32_t* lab = packed + 5 * pad;
-  int32_t* val = packed + 6 * pad;
-  std::memset(packed, 0, sizeof(int32_t) * 7 * (size_t)pad);
-  for (int64_t i = 0; i < n; ++i) {
-    uint64_t c = (uint64_t)cust[i];
-    ck[i] = (int32_t)(uint32_t)((c ^ (c >> 32)) & 0xFFFFFFFFULL);
-    uint64_t t = (uint64_t)term[i];
-    tk[i] = (int32_t)(uint32_t)((t ^ (t >> 32)) & 0xFFFFFFFFULL);
-    int64_t d = dt_us[i] / kUsPerDay;
-    int64_t r = dt_us[i] % kUsPerDay;
-    if (r < 0) {  // match NumPy floor-division semantics
-      d -= 1;
-      r += kUsPerDay;
-    }
-    day[i] = (int32_t)d;
-    tod[i] = (int32_t)(r / 1000000LL);
-    float a = (float)((double)amount[i] / 100.0);
-    std::memcpy(&amt[i], &a, 4);
-    lab[i] = label ? (int32_t)label[i] : -1;
-    val[i] = 1;
-  }
+  pack_rows_impl<false>(dt_us, cust, term, amount, label, n, pad, packed);
+}
+
+void pack_rows_wide(const int64_t* dt_us, const int64_t* cust,
+                    const int64_t* term, const int64_t* amount,
+                    const int64_t* label, int64_t n, int64_t pad,
+                    int32_t* packed) {
+  pack_rows_impl<true>(dt_us, cust, term, amount, label, n, pad, packed);
 }
 
 }  // extern "C"

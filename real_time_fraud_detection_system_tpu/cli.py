@@ -676,6 +676,7 @@ def cmd_score(args) -> int:
         cfg = cfg.replace(features=_dc.replace(
             cfg.features,
             key_mode=args.key_mode,
+            key_bits=args.key_bits,
             compact_every=args.state_compact_every,
             state_hbm_budget_mb=args.state_hbm_budget_mb,
             cold_store=args.cold_store,
@@ -2506,6 +2507,16 @@ def main(argv=None) -> int:
                         "directory, hot tier sized to the working set, "
                         "admission misses served from the count-min "
                         "sketch — README 'Feature-state playbook')")
+    p.add_argument("--key-bits", type=int, default=32, choices=[32, 64],
+                   help="width of an id on the device: 32 xor-folds "
+                        "every int64 id to one word (exact for ids under "
+                        "2^32; wider ids that fold alike MERGE, counted "
+                        "in rtfds_wide_id_rows_total), 64 carries both "
+                        "words through the batch, the directory, the "
+                        "sketches, the cold store and checkpoints — two "
+                        "ids share state only if all 64 bits agree. 64 "
+                        "needs --key-mode exact on one chip "
+                        "(--devices > 1 and multi-host refuse it)")
     p.add_argument("--state-compact-every", type=int, default=0,
                    help="recency compaction cadence for --key-mode "
                         "exact: every N batches a full-table vector "

@@ -76,6 +76,22 @@ class FeatureConfig:
     # count-min sketch tier (overestimate-only degradation, observable via
     # rtfds_feature_tier_rows_total).
     key_mode: str = "direct"
+    # The width of an id as the device sees it, a stated, static property
+    # of a deployment (the programs are compiled before a row is seen).
+    # 32: the host xor-folds every int64 id to one uint32 word
+    # (core/batch.fold_key) — exact for ids under 2^32 (SERIAL keys); two
+    # wider ids whose words xor alike are ONE key from there on (counted:
+    # rtfds_wide_id_rows_total, and warned about once). 64: the id travels
+    # as its two uint32 words — the batch, the directory, the sketches,
+    # the cold store and a checkpoint carry the whole key, and two ids
+    # share state only if all 64 bits agree (16-digit card numbers, a
+    # 64-bit merchant hash, BIGSERIAL). Needs key_mode="exact": a direct
+    # table would need capacity above the highest id, and a hashed one
+    # merges keys by design. One bit pattern is reserved at 64,
+    # 0xFFFFFFFF_FFFFFFFF (int64 -1, the cold tier's padding lane): a row
+    # that carries it is never admitted to the hot tier and is served
+    # from — and counted on — the sketch tier.
+    key_bits: int = 32
     # key_mode="exact" knobs: fixed probe depth P of the directory's double
     # hashing (the directory has D = 2x the slot capacity entries), and the
     # recency-compaction cadence. A key misses admission FOR GOOD — it is
@@ -178,6 +194,15 @@ class FeatureConfig:
                 f"key_mode must be 'direct', 'hash' or 'exact', "
                 f"got {self.key_mode!r}"
             )
+        if self.key_bits not in (32, 64):
+            raise ValueError(
+                f"key_bits must be 32 or 64, got {self.key_bits!r}")
+        if self.key_bits == 64 and self.key_mode != "exact":
+            raise ValueError(
+                "key_bits=64 requires key_mode='exact': 'direct' needs a "
+                "table above the highest id and 'hash' merges keys by "
+                "design — only the key directory holds a 64-bit id whole, "
+                f"got key_mode={self.key_mode!r}")
         # direct mode masks with (capacity - 1) (ops/hashing.key_slot) and
         # the hash/exact placements assume pow2 tables — a non-pow2
         # capacity would silently ALIAS keys today, so refuse it loudly.

@@ -10,6 +10,14 @@ Device arrays are 32-bit on purpose (TPU-friendly, no jax x64 flag):
 timestamps are carried as (day, second-of-day) pairs instead of µs epochs;
 64-bit identifiers stay host-side and rows are re-joined by position after
 scoring. Weekday/night flags derive in-kernel from (day, tod_s).
+
+A key is as wide as the deployment states (``FeatureConfig.key_bits``).
+At 32 an id is xor-folded to one uint32 word (:func:`fold_key`) and a key
+column is ``uint32 [B]``. At 64 the id is split into its two words
+(:func:`split_key`) and a key column is ``uint32 [2, B]`` — row 0 the low
+word, row 1 the high: words first, so each is a plain ``[B]`` vector on
+the chip. On the host a key is ``np.uint32`` or ``np.uint64``
+(:func:`host_keys`).
 """
 
 from __future__ import annotations
@@ -29,8 +37,8 @@ class TxBatch(NamedTuple):
     padding; padded rows never touch state or sinks.
     """
 
-    customer_key: jnp.ndarray  # uint32 [B] — hashed/truncated customer id
-    terminal_key: jnp.ndarray  # uint32 [B]
+    customer_key: jnp.ndarray  # uint32 [B] — folded id; [2, B] at 64 bits
+    terminal_key: jnp.ndarray  # uint32 [B] | [2, B]
     day: jnp.ndarray  # int32 [B] — days since unix epoch
     tod_s: jnp.ndarray  # int32 [B] — second within day
     amount: jnp.ndarray  # float32 [B] — dollars (display/features)
@@ -39,7 +47,7 @@ class TxBatch(NamedTuple):
 
     @property
     def size(self) -> int:
-        return int(self.customer_key.shape[0])
+        return int(self.valid.shape[0])
 
 
 def bucket_size(n: int, buckets: Sequence[int]) -> int:
@@ -56,6 +64,50 @@ def fold_key(ids: np.ndarray) -> np.ndarray:
     return ((v ^ (v >> np.uint64(32))) & np.uint64(0xFFFFFFFF)).astype(np.uint32)
 
 
+def split_key(ids: np.ndarray) -> np.ndarray:
+    """int64 ids → uint32 [2, n]: the low and the high word of each id's
+    bit pattern (a negative id is its two's complement, read as uint64)."""
+    v = np.ascontiguousarray(ids, dtype=np.int64).view(np.uint64)
+    return np.stack([(v & np.uint64(0xFFFFFFFF)).astype(np.uint32),
+                     (v >> np.uint64(32)).astype(np.uint32)])
+
+
+def join_key(words: np.ndarray) -> np.ndarray:
+    """Inverse of :func:`split_key`: uint32 [2, ...] → uint64 [...]."""
+    words = np.asarray(words, dtype=np.uint32)
+    return (words[0].astype(np.uint64)
+            | (words[1].astype(np.uint64) << np.uint64(32)))
+
+
+def host_keys(ids: np.ndarray, key_bits: int = 32) -> np.ndarray:
+    """The host's name of each id's key at the stated width: the uint32
+    fold at 32 bits, the uint64 bit pattern at 64 — what the cold store
+    indexes and what :func:`device_keys` hands the device."""
+    if key_bits == 64:
+        return np.ascontiguousarray(ids, dtype=np.int64).view(np.uint64)
+    return fold_key(np.asarray(ids))
+
+
+def device_keys(keys: np.ndarray) -> np.ndarray:
+    """:func:`host_keys` output → the device's key column: uint32 ``[n]``
+    as it stands, uint64 split words-first into uint32 ``[2, n]``."""
+    keys = np.asarray(keys)
+    if keys.dtype == np.uint64:
+        return split_key(keys.view(np.int64))
+    return keys.astype(np.uint32)
+
+
+def wide_id_rows(customer_id: np.ndarray, terminal_id: np.ndarray) -> int:
+    """Rows whose customer or terminal id does not fit 32 bits (negative
+    ids included): what a 32-bit deployment folds, and may merge."""
+    c = np.asarray(customer_id, np.int64).view(np.uint64)
+    t = np.asarray(terminal_id, np.int64).view(np.uint64)
+    top = np.uint64(0xFFFFFFFF)
+    if not c.size or max(c.max(), t.max()) <= top:
+        return 0  # the serial-id deployment: two max passes a batch
+    return int(np.count_nonzero((c > top) | (t > top)))
+
+
 def make_batch(
     customer_id: np.ndarray,
     terminal_id: np.ndarray,
@@ -63,6 +115,7 @@ def make_batch(
     amount_cents: np.ndarray,
     label: Optional[np.ndarray] = None,
     pad_to: Optional[int] = None,
+    key_bits: int = 32,
 ) -> TxBatch:
     """Build a (host-side numpy) TxBatch from columnar int64 inputs."""
     n = len(customer_id)
@@ -71,9 +124,11 @@ def make_batch(
         raise ValueError(f"pad_to={m} < batch rows {n}")
 
     def _pad(a: np.ndarray) -> np.ndarray:
-        out = np.zeros(m, dtype=a.dtype)
-        out[:n] = a
+        out = np.zeros(a.shape[:-1] + (m,), dtype=a.dtype)
+        out[..., :n] = a
         return out
+
+    key_of = split_key if key_bits == 64 else fold_key
 
     day = (tx_datetime_us // US_PER_DAY).astype(np.int32)
     tod = ((tx_datetime_us % US_PER_DAY) // 1_000_000).astype(np.int32)
@@ -81,8 +136,8 @@ def make_batch(
     valid = np.zeros(m, dtype=bool)
     valid[:n] = True
     return TxBatch(
-        customer_key=_pad(fold_key(customer_id)),
-        terminal_key=_pad(fold_key(terminal_id)),
+        customer_key=_pad(key_of(customer_id)),
+        terminal_key=_pad(key_of(terminal_id)),
         day=_pad(day),
         tod_s=_pad(tod),
         amount=_pad((amount_cents.astype(np.float64) / 100.0).astype(np.float32)),
@@ -99,10 +154,10 @@ def pad_batch(batch: TxBatch, pad_to: int) -> TxBatch:
     if pad_to < n:
         raise ValueError(f"pad_to={pad_to} < batch rows {n}")
 
-    def _pad(a):
+    def _pad(a):  # rows are the last axis: [n], or [2, n] for a wide key
         a = np.asarray(a)
-        out = np.zeros((pad_to,) + a.shape[1:], dtype=a.dtype)
-        out[:n] = a
+        out = np.zeros(a.shape[:-1] + (pad_to,), dtype=a.dtype)
+        out[..., :n] = a
         return out
 
     return TxBatch(*[_pad(x) for x in batch])
@@ -115,17 +170,27 @@ def pack_batch(batch: TxBatch) -> np.ndarray:
     a batch as 7 separate leaves costs 7× the fixed overhead of moving it
     as one array. uint32 keys and float32 amounts travel as their int32
     bit patterns; :func:`unpack_batch` bitcasts them back inside jit, so
-    the round trip is exact.
+    the round trip is exact. A wide batch (``key_bits=64``) is ``[9, B]``:
+    the keys' low words where the folded keys ride, their high words in
+    two rows behind ``valid`` — rows 2-6 mean what they always meant.
     """
+    ck = np.asarray(batch.customer_key).view(np.int32)
+    tk = np.asarray(batch.terminal_key).view(np.int32)
+    wide = ck.ndim == 2
     return np.stack([
-        np.asarray(batch.customer_key).view(np.int32),
-        np.asarray(batch.terminal_key).view(np.int32),
+        ck[0] if wide else ck,
+        tk[0] if wide else tk,
         np.asarray(batch.day),
         np.asarray(batch.tod_s),
         np.asarray(batch.amount).view(np.int32),
         np.asarray(batch.label),
         np.asarray(batch.valid).astype(np.int32),
-    ])
+    ] + ([ck[1], tk[1]] if wide else []))
+
+
+def packed_rows(key_bits: int = 32) -> int:
+    """Rows of the packed batch at a key width: 7, or 9 at 64 bits."""
+    return 9 if key_bits == 64 else 7
 
 
 def unpack_batch(packed: jnp.ndarray) -> TxBatch:
@@ -134,9 +199,15 @@ def unpack_batch(packed: jnp.ndarray) -> TxBatch:
     import jax
 
     bitcast = jax.lax.bitcast_convert_type
+    if packed.shape[0] == 9:  # wide keys: [lo, hi] words first
+        c_key = bitcast(jnp.stack([packed[0], packed[7]]), jnp.uint32)
+        t_key = bitcast(jnp.stack([packed[1], packed[8]]), jnp.uint32)
+    else:
+        c_key = bitcast(packed[0], jnp.uint32)
+        t_key = bitcast(packed[1], jnp.uint32)
     return TxBatch(
-        customer_key=bitcast(packed[0], jnp.uint32),
-        terminal_key=bitcast(packed[1], jnp.uint32),
+        customer_key=c_key,
+        terminal_key=t_key,
         day=packed[2],
         tod_s=packed[3],
         amount=bitcast(packed[4], jnp.float32),

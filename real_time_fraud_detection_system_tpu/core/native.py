@@ -293,13 +293,14 @@ def _configure_hostprep(lib) -> None:
         i64p, i64p, ctypes.c_int64,
         np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS"),
     ]
-    lib.pack_rows.restype = None
-    lib.pack_rows.argtypes = [
-        i64p, i64p, i64p, i64p,
-        ctypes.c_void_p,  # label, nullable
-        ctypes.c_int64, ctypes.c_int64,
-        np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-    ]
+    for fn in (lib.pack_rows, lib.pack_rows_wide):
+        fn.restype = None
+        fn.argtypes = [
+            i64p, i64p, i64p, i64p,
+            ctypes.c_void_p,  # label, nullable
+            ctypes.c_int64, ctypes.c_int64,
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+        ]
 
 
 def _load_hostprep() -> Optional[ctypes.CDLL]:
@@ -338,8 +339,10 @@ def pack_rows(
     amount_cents: np.ndarray,
     label: Optional[np.ndarray],
     pad: int,
+    key_bits: int = 32,
 ) -> np.ndarray:
-    """Fused make_batch + pack_batch: → int32 [7, pad] (zeros-padded),
+    """Fused make_batch + pack_batch: → int32 [7, pad] (zeros-padded;
+    [9, pad] at ``key_bits=64``, the ids split and not folded),
     bit-identical to the NumPy composition (tests/test_native.py)."""
     lib = _load_hostprep()
     if lib is None:
@@ -348,10 +351,11 @@ def pack_rows(
     n = len(tx_datetime_us)
     if pad < n:
         raise ValueError(f"pad={pad} < batch rows {n}")
-    packed = np.empty((7, pad), dtype=np.int32)
+    wide = key_bits == 64
+    packed = np.empty((9 if wide else 7, pad), dtype=np.int32)
     lab = (np.ascontiguousarray(label, np.int64)
            if label is not None else None)
-    lib.pack_rows(
+    (lib.pack_rows_wide if wide else lib.pack_rows)(
         np.ascontiguousarray(tx_datetime_us, np.int64),
         np.ascontiguousarray(customer_id, np.int64),
         np.ascontiguousarray(terminal_id, np.int64),

@@ -41,6 +41,7 @@ from real_time_fraud_detection_system_tpu.ops.keydir import (
     pack_lanes,
     packed_entries,
     reclaim_entries,
+    reserved,
 )
 from real_time_fraud_detection_system_tpu.ops.windows import (
     WindowState,
@@ -117,6 +118,11 @@ def init_feature_state(
         # list itself runs dry (THE admission bound).
         def _dir(cap: int):
             if n_shards > 1:
+                if cfg.key_bits == 64:
+                    raise ValueError(
+                        "key_bits=64 has no sharded layout: the mesh's "
+                        "owner exchange and its stacked directories "
+                        "carry one-word keys (ROADMAP B14)")
                 if cap % n_shards:
                     raise ValueError(
                         f"capacity {cap} must divide by n_shards "
@@ -126,7 +132,7 @@ def init_feature_state(
 
                 local = cap // n_shards
                 return init_stacked_keydir(2 * local, local, n_shards)
-            return init_keydir(2 * cap, cap)
+            return init_keydir(2 * cap, cap, cfg.key_bits)
 
         if cfg.customer_source != "cms":
             customer_dir = _dir(cfg.customer_capacity)
@@ -177,12 +183,15 @@ def state_bytes(cfg: FeatureConfig, n_shards: int = 1) -> dict:
         # ...whose fraud column is a third table on the terminal sketch
         cms += nb * cfg.cms_depth * cfg.cms_width * 4
         # KeyDirectory: keys u32 + slots i32 over 2x slots, free i32 +
-        # free_top i32 per table (one free_top per shard).
+        # free_top i32 per table (one free_top per shard); at key_bits=64
+        # the entry's two key words beside its fingerprint, 8 B more.
+        entry_bytes = 16 if cfg.key_bits == 64 else 8
         for cap, present in ((cfg.customer_capacity,
                               cfg.customer_source != "cms"),
                              (cfg.terminal_capacity, True)):
             if present:
-                directory += 2 * cap * 8 + cap * 4 + 4 * max(n_shards, 1)
+                directory += (2 * cap * entry_bytes + cap * 4
+                              + 4 * max(n_shards, 1))
     # per-device sketch replicas over the mesh (disjoint key partitions:
     # each device sketches only its owners' traffic)
     cms *= max(n_shards, 1)
@@ -216,6 +225,9 @@ class TableState(NamedTuple):
     sketch: Optional[CountMinSketch] = None
     tier: Optional[jnp.ndarray] = None  # exact: [dense, cms] rows served
     rounds: Optional[jnp.ndarray] = None  # exact: claim rounds the admit ran
+    # key_bits=64: [rows that met another key under their fingerprint,
+    # verify trips] of the admit's lookup
+    alias: Optional[jnp.ndarray] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -235,7 +247,10 @@ class TablePlane:
     estimate, not a bound), counting both in ``tier``. A sketch the state
     carries is updated with EVERY row, so its estimate stays a valid
     overestimate whether or not the key holds a hot slot;
-    ``customer_source="cms"`` serves the customer side from it alone."""
+    ``customer_source="cms"`` serves the customer side from it alone.
+
+    ``key`` is ``uint32 [rows]``, or ``[2, rows]`` at ``key_bits=64``
+    (``exact`` only): the directory and the sketches take it whole."""
 
     table: str  # "customer" | "terminal": the scope, the column set
     cfg: FeatureConfig
@@ -257,12 +272,14 @@ class TablePlane:
         exact = self.cfg.key_mode == "exact"
         return TableState(win, kd, sk,
                           jnp.zeros(2, jnp.float32) if exact else None,
-                          jnp.zeros((), jnp.float32) if exact else None)
+                          jnp.zeros((), jnp.float32) if exact else None,
+                          jnp.zeros(2, jnp.float32)
+                          if self.cfg.key_bits == 64 else None)
 
     def update(self, ts: TableState, key, day, amount, fraud, valid):
         """The scatter half → (tstate', slot, admitted | None)."""
         cfg = self.cfg
-        win, kd, sketch, tier, rounds = ts
+        win, kd, sketch, tier, rounds, alias = ts
         slot = adm = None
         if self.sketch_only and sketch is None:
             raise ValueError(
@@ -271,9 +288,11 @@ class TablePlane:
                 "same config)")
         if not self.sketch_only:
             if cfg.key_mode == "exact":
-                kd, slot, adm, ran = admit_slots(
+                kd, slot, adm, ran, met = admit_slots(
                     kd, key, valid, n_probes=cfg.keydir_probes)
                 rounds = rounds + ran.astype(jnp.float32)
+                if met is not None:  # a wide directory counts its aliases
+                    alias = alias + met.astype(jnp.float32)
                 valid_hot = valid & adm
             else:
                 capacity = (cfg.customer_capacity if self._customer
@@ -287,7 +306,7 @@ class TablePlane:
         if sketch is not None:
             sketch = cms_update(sketch, key, amount, day, valid,
                                 fraud=None if self._customer else fraud)
-        return TableState(win, kd, sketch, tier, rounds), slot, adm
+        return TableState(win, kd, sketch, tier, rounds, alias), slot, adm
 
     def query(self, ts: TableState, slot, adm, key, day, valid):
         """The gather half → (tstate' (tier counted), [rows, 2·NW]).
@@ -342,7 +361,8 @@ def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
     [B, 2·NW], terminal [B, 2·NW], tier [4] | None, overflows)`` — under
     ``exact`` ``tier`` is ``[dense rows, cms rows, customer claim rounds,
     terminal claim rounds]``, the one small vector a batch's finish
-    fetches for the registry.
+    fetches for the registry; at ``key_bits=64`` two more ride behind,
+    ``[…, alias rows, alias verify trips]``, both tables summed.
     """
     fraud = fraud_of(batch)
 
@@ -357,7 +377,9 @@ def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
     t, t_mat, t_over = (reach_terminal or local)(
         t_plane, t_plane.of(state), batch.terminal_key, fraud)
     tier = None if t.tier is None else jnp.concatenate(
-        [c.tier + t.tier, jnp.stack([c.rounds, t.rounds])])
+        [c.tier + t.tier, jnp.stack([c.rounds, t.rounds])]
+        + ([] if t.alias is None else
+           [t.alias if c.alias is None else c.alias + t.alias]))
     state = FeatureState(
         customer=c.windows, terminal=t.windows, cms=c.sketch,
         customer_dir=c.directory, terminal_dir=t.directory,
@@ -742,12 +764,15 @@ def _demoted_rows(
     the selected entries in ``demote_slots`` fixed lanes, gathered
     BEFORE the vacate — ``(keys u32 [k], bucket_day i32 [k, NB],
     count/amount/fraud f32 [k, NB])``, unselected lanes ``EMPTY_KEY`` /
-    empty rows. Named by its caller's ``rtfds.demote``.
+    empty rows. Named by its caller's ``rtfds.demote``. A wide directory
+    hands out ``keys u32 [2, k]``, both words of every entry, padding
+    lanes ``EMPTY_KEY`` in both (the reserved pattern).
 
     Lanes go out in KEY order (one sort of k keys; EMPTY_KEY, the
-    largest u32, keeps the padding last): the store's index is sorted by
-    key, so the host lands such a payload as it stands, without
-    gathering every row into order once more. The indexed work follows
+    largest u32, keeps the padding last; a wide key sorts by its high
+    word, then its low: the order of the uint64 it is): the store's index
+    is sorted by key, so the host lands such a payload as it stands,
+    without gathering every row into order once more. The indexed work follows
     the selection: entries are packed into lanes by rank (lane j holds
     the (j+1)-th selected entry, ``ops/keydir.packed_entries``) and the
     rows gathered K lanes a trip of two ``lax.while_loop``s of
@@ -765,24 +790,35 @@ def _demoted_rows(
     def more(carry):
         return carry[0] * lanes < n_sel
 
+    # the words of an entry's key: the key itself, or (low, high)
+    words = (kd.keys_lo, kd.keys_hi) if kd.wide else (kd.keys,)
+
     def pack(carry):
         trip, keys, eidx = carry
         entry = jnp.minimum(
             packed_entries(taken, trip * lanes, lanes), dir_cap - 1)
-        key = kd.keys[entry]
+        key = tuple(w[entry] for w in words)
         live = trip * lanes + lane < n_sel
         return (trip + 1,
-                keys.at[trip].set(jax.lax.select(
-                    live, key, jnp.full_like(key, EMPTY_KEY))),
+                tuple(k.at[trip].set(jax.lax.select(
+                    live, w, jnp.full_like(w, EMPTY_KEY)))
+                    for k, w in zip(keys, key)),
                 eidx.at[trip].set(entry))
 
     _, keys, eidx = jax.lax.while_loop(more, pack, (
         jnp.int32(0),
-        jnp.full((trips, lanes), EMPTY_KEY, jnp.uint32),
+        tuple(jnp.full((trips, lanes), EMPTY_KEY, jnp.uint32)
+              for _ in words),
         jnp.zeros((trips, lanes), jnp.int32)))
-    keys, eidx = keys.reshape(-1), eidx.reshape(-1)
-    by_key = jnp.argsort(keys)  # the selected lanes first, by key
-    keys, eidx = keys[by_key], eidx[by_key].reshape(trips, lanes)
+    keys, eidx = tuple(k.reshape(-1) for k in keys), eidx.reshape(-1)
+    if kd.wide:
+        # the selected lanes first, by (high, low): the uint64's order
+        hi, lo, eidx = jax.lax.sort((keys[1], keys[0], eidx), num_keys=2)
+        keys, eidx = jnp.stack([lo, hi]), eidx.reshape(trips, lanes)
+    else:
+        keys, = keys
+        by_key = jnp.argsort(keys)  # the selected lanes first, by key
+        keys, eidx = keys[by_key], eidx[by_key].reshape(trips, lanes)
     fills = (jnp.int32(-1), 0.0, 0.0, 0.0)
 
     def fetch(carry):
@@ -796,7 +832,7 @@ def _demoted_rows(
     _, rows = jax.lax.while_loop(more, fetch, (jnp.int32(0), tuple(
         jnp.full((trips, lanes, nb), fill, col.dtype)
         for fill, col in zip(fills, ws.columns()))))
-    return (keys[:k],) + tuple(
+    return (keys[..., :k],) + tuple(
         r.reshape(trips * lanes, nb)[:k] for r in rows)
 
 
@@ -840,8 +876,9 @@ def promote_rows(
         # the admit carries rtfds.keydir and its parts, as in the step;
         # everything else of the program carries rtfds.promote — siblings
         with step_scope("promote"):
-            valid = keys != jnp.uint32(EMPTY_KEY)
-        kd, slot, adm, rounds = admit_slots(kd, keys, valid,
+            valid = (~reserved(keys) if kd.wide
+                     else keys != jnp.uint32(EMPTY_KEY))
+        kd, slot, adm, rounds, _ = admit_slots(kd, keys, valid,
                                             n_probes=cfg.keydir_probes)
         with step_scope("promote"):
             slot_c = jnp.clip(slot, 0, ws.capacity - 1)

@@ -96,6 +96,22 @@ class CorruptCheckpointError(Exception):
         super().__init__(f"{reason}: {detail}" if detail else reason)
 
 
+class CheckpointKeyWidthError(ValueError):
+    """The checkpoint is HEALTHY but was written at another
+    ``features.key_bits`` than the restoring engine states: its key
+    directories hold folded one-word keys where the template holds whole
+    64-bit ones, or the reverse. Like a topology mismatch this is a
+    refusal, never a quarantine — the whole lineage shares the width."""
+
+
+def state_key_bits(feature_state) -> int:
+    """The key width a feature state was built at: 64 where a key
+    directory carries the entry's two key words, else 32."""
+    dirs = (getattr(feature_state, name, None)
+            for name in ("customer_dir", "terminal_dir"))
+    return 64 if any(kd is not None and kd.wide for kd in dirs) else 32
+
+
 class CheckpointTopologyError(ValueError):
     """The checkpoint is HEALTHY but was written under a different
     multi-host process topology than the restoring engine serves.
@@ -196,6 +212,10 @@ def _state_arrays(engine_state) -> Tuple[dict, dict]:
         # loop can tell restored params from the current champion
         "model_version": getattr(engine_state, "model_version", None),
     }
+    if state_key_bits(engine_state.feature_state) == 64:
+        # the width the directories were written at; a 32-bit
+        # checkpoint's meta is what it always was (absent = 32)
+        meta["key_bits"] = 64
     occ = _directory_occupancy(engine_state.feature_state)
     if occ:
         # per-shard hot-tier occupancy at save time (tiered exact
@@ -897,6 +917,15 @@ class _CheckpointerBase:
         via the elastic reshard — which itself hard-fails on a genuine
         capacity mismatch, loudly, instead of this path quarantining a
         healthy cross-width checkpoint."""
+        ck_bits = int(meta.get("key_bits") or 32)
+        bits = state_key_bits(getattr(template, "feature_state", None))
+        if ck_bits != bits:
+            raise CheckpointKeyWidthError(
+                f"{name} was written at key_bits={ck_bits}; this engine "
+                f"states key_bits={bits}. A folded key and a whole one do "
+                "not name the same state: restore at the width the "
+                "checkpoint was written at, or start the wider "
+                "deployment from a fresh state")
         spec = _template_spec(template)
         n_fs = sum(1 for k in spec if k.startswith("fs_"))
         n_p = sum(1 for k in spec if k.startswith("p_"))

@@ -42,6 +42,13 @@ Contracts, in the order people get them wrong:
   deletes everything else — segments written after the checkpoint are
   regenerated by replay (exactly-once), fully-promoted ones are covered
   by the checkpointed hot state.
+- **A store has one key width.** ``key_bits=32`` (the default) is the
+  layout above, as it always was. ``key_bits=64`` holds ``uint64`` keys —
+  the index, the ``{t}_keys`` arrays and the manifests' key lists carry
+  the whole id, padding lanes are ``0xFFFFFFFF_FFFFFFFF`` — and stamps
+  ``"key_bits": 64`` into every manifest; a manifest of the other width
+  is refused at open by name (:class:`ColdStoreKeyWidthError`), never
+  read as if it were this one's.
 - **A fresh store per process**: ``tmp://[name]`` (see
   :func:`resolve_location`) is a directory made under the system's
   temporary directory at construction and removed at :meth:`close` or at
@@ -65,7 +72,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 SEG_FORMAT = 1
-_EMPTY_KEY = 0xFFFFFFFF
 TMP_SCHEME = "tmp://"
 _BUF = -1  # segment id of rows still in the flush buffer
 _ROW_DTYPES = (np.int32, np.float32, np.float32, np.float32)
@@ -73,6 +79,10 @@ _ROW_DTYPES = (np.int32, np.float32, np.float32, np.float32)
 
 class ColdStoreCorruptError(Exception):
     """A cold segment failed CRC/parse verification (quarantined)."""
+
+
+class ColdStoreKeyWidthError(ValueError):
+    """The store on disk was written at another ``key_bits``."""
 
 
 def _seg_blob(seq: int) -> str:
@@ -101,10 +111,6 @@ def resolve_location(path_or_url: str) -> Tuple[str, bool]:
     return tempfile.mkdtemp(prefix=name + "-"), True
 
 
-def _canon_keys(keys) -> np.ndarray:
-    return np.asarray(keys, dtype=np.uint32).reshape(-1)
-
-
 class _TableIndex:
     """One table's membership: ``keys`` sorted, and beside each key the
     segment (``_BUF`` = the flush buffer) and the row that hold its
@@ -112,8 +118,8 @@ class _TableIndex:
 
     __slots__ = ("keys", "seg", "row")
 
-    def __init__(self):
-        self.keys = np.empty(0, np.uint32)
+    def __init__(self, key_dtype=np.uint32):
+        self.keys = np.empty(0, key_dtype)
         self.seg = np.empty(0, np.int64)
         self.row = np.empty(0, np.int64)
 
@@ -158,12 +164,18 @@ class ColdStore:
     (``_LocalBackend``/``_StoreBackend`` — flaky-store retries and the
     atomic tmp+rename / atomic-PUT write discipline are inherited)."""
 
-    def __init__(self, path_or_url: str, segment_mb: float = 4.0):
+    def __init__(self, path_or_url: str, segment_mb: float = 4.0,
+                 key_bits: int = 32):
         from real_time_fraud_detection_system_tpu.io.checkpoint import (
             _LocalBackend,
             _StoreBackend,
         )
 
+        if key_bits not in (32, 64):
+            raise ValueError(f"key_bits must be 32 or 64, got {key_bits!r}")
+        self.key_bits = int(key_bits)
+        self._key_dtype = np.uint64 if key_bits == 64 else np.uint32
+        self._empty_key = np.iinfo(self._key_dtype).max  # a padding lane
         self.url = path_or_url
         self.path, self.ephemeral = resolve_location(path_or_url)
         self._cleanup = None
@@ -195,6 +207,9 @@ class ColdStore:
 
     # -- open / close / crash hygiene -----------------------------------
 
+    def _canon_keys(self, keys) -> np.ndarray:
+        return np.asarray(keys, dtype=self._key_dtype).reshape(-1)
+
     def _log(self):
         from real_time_fraud_detection_system_tpu.utils import get_logger
 
@@ -219,12 +234,25 @@ class ColdStore:
                 seq = int(man["seq"])
                 blob = str(man["blob"])
                 int(man["crc"])
-                keys = {t: np.asarray(ks, dtype=np.uint32)
-                        for t, ks in dict(man["keys"]).items()}
+                bits = int(man.get("key_bits", 32))
+                keys = {t: np.asarray(ks, dtype=self._key_dtype)
+                        for t, ks in dict(man["keys"]).items()
+                        } if bits == self.key_bits else None
             # rtfdslint: disable=broad-exception-catch (crash-safe re-open: ANY unparsable manifest is quarantined evidence, never trusted state)
             except Exception:
                 self._quarantine_name(n)
                 continue
+            if keys is None:
+                # a healthy segment of the other width: not evidence of
+                # a crash, so nothing is quarantined — the store is not
+                # this deployment's
+                raise ColdStoreKeyWidthError(
+                    f"cold store {self.path}: segment {n} was written at "
+                    f"key_bits={bits}, this deployment states "
+                    f"key_bits={self.key_bits} — a folded key and a "
+                    "whole one do not name the same rows; point "
+                    "--cold-store at a fresh location or restore the "
+                    "width the store was written at")
             if blob not in blobs:
                 # manifest without its blob: a deleted/torn blob —
                 # quarantine the manifest, keys degrade to CMS honestly
@@ -257,7 +285,7 @@ class ColdStore:
     def _table(self, table: str) -> _TableIndex:
         t = self._tables.get(table)
         if t is None:
-            t = self._tables[table] = _TableIndex()
+            t = self._tables[table] = _TableIndex(self._key_dtype)
         return t
 
     def _left(self, segs: np.ndarray) -> None:
@@ -293,8 +321,8 @@ class ColdStore:
         flushed once the buffer crosses the segment threshold — here, or
         (``flush=False``) when the caller says :meth:`flush_if_full`.
         Returns the rows appended."""
-        keys = _canon_keys(keys)
-        live = np.flatnonzero(keys != np.uint32(_EMPTY_KEY))
+        keys = self._canon_keys(keys)
+        live = np.flatnonzero(keys != self._empty_key)
         if not live.size:
             return 0
         rows = tuple(np.asarray(a, dtype=dt).reshape(keys.size, -1)
@@ -319,7 +347,8 @@ class ColdStore:
                 np.concatenate([o, r]) for o, r in zip(old, rows))
             self._put(table, uk, _BUF,
                       base + np.arange(uk.size, dtype=np.int64))
-            self._buf_bytes += uk.size * (4 + 16 * rows[0].shape[1])
+            self._buf_bytes += uk.size * (
+                keys.itemsize + 16 * rows[0].shape[1])
         if flush:
             self.flush_if_full()
         return int(uk.size)
@@ -379,6 +408,8 @@ class ColdStore:
                 "bytes": len(data),
                 "keys": {t: ks.tolist() for t, ks in man_keys.items()},
             }
+            if self.key_bits == 64:
+                man["key_bits"] = 64  # a 32-bit manifest stays as it was
             # Blob FIRST, manifest as the commit point: a crash between
             # the two leaves an orphan blob that open() deletes.
             self._backend.write(_seg_blob(seq), data)
@@ -432,7 +463,7 @@ class ColdStore:
         in ``keys``' order (``keys[found]`` names them). One gather per
         segment touched. A corrupt segment raises
         :class:`ColdStoreCorruptError` after quarantining itself."""
-        keys = _canon_keys(keys)
+        keys = self._canon_keys(keys)
         with self._lock:
             t = self._table(table)
             pos, found = t.find(keys)
@@ -454,7 +485,7 @@ class ColdStore:
     def get_rows(self, table: str, keys: Sequence[int]) -> dict:
         """The per-key view of :meth:`read_rows`: ``{key: (bd, cnt, amt,
         frd)}``, missing keys simply absent."""
-        keys = _canon_keys(keys)
+        keys = self._canon_keys(keys)
         found, bd, cnt, amt, frd = self.read_rows(table, keys)
         return {int(k): (bd[j], cnt[j], amt[j], frd[j])
                 for j, k in enumerate(keys[found])}
@@ -463,7 +494,7 @@ class ColdStore:
         """Which of ``keys`` (any order, repeats allowed) the store
         holds: the engine's returning-key detection, one sorted-index
         lookup a batch."""
-        keys = _canon_keys(keys)
+        keys = self._canon_keys(keys)
         with self._lock:
             return self._table(table).find(keys)[1]
 
@@ -471,7 +502,8 @@ class ColdStore:
         return bool(self.cold_mask(table, [key])[0])
 
     def index_snapshot(self, table: str) -> np.ndarray:
-        """Sorted u32 array of this table's cold keys (a copy)."""
+        """Sorted array of this table's cold keys (a copy): ``uint32``,
+        or ``uint64`` in a ``key_bits=64`` store."""
         with self._lock:
             return self._table(table).keys.copy()
 
@@ -491,7 +523,7 @@ class ColdStore:
         """The keys' rows went back to the hot tier: drop their index
         entries (stops re-detection; segment bytes stay until gc)."""
         with self._lock:
-            self._forget(table, _canon_keys(keys))
+            self._forget(table, self._canon_keys(keys))
 
     def gc(self) -> List[int]:
         """Delete segments with zero live index entries. Call AFTER a
@@ -618,7 +650,8 @@ def consolidate_cold_stores(src_paths: Sequence[str], dest_path: str,
     flushed; fleet ownership makes source key sets disjoint, and a
     corrupt source segment degrades exactly as it does at serve time
     (quarantined, its keys fall back to the CMS sketch) instead of
-    failing the resize. Returns the opened destination store."""
+    failing the resize. Returns the opened destination store. (Fleets
+    serve one-word keys: the stores are ``key_bits=32``.)"""
     dest = ColdStore(dest_path, segment_mb=segment_mb)
     for src_path in src_paths:
         if os.path.abspath(src_path) == os.path.abspath(dest_path):

@@ -11,6 +11,10 @@ reset when its ring position is claimed by a newer day. Query = per-day
 min-over-depth estimate, summed over the window — matching the window
 semantics of :mod:`.windows` (trailing calendar days, inclusive).
 
+A key is ``uint32 [B]``, or ``[2, B]`` at ``key_bits=64``: the column
+hashes (``ops/hashing.multi_hash``) then mix both words, so two ids that
+fold alike share a cell only as often as any two keys do.
+
 This is BASELINE.json config 3 ("HBM-resident count-min sketch per-card /
 per-merchant velocity features"); the reference has no equivalent (its
 features are precomputed static joins, ``fraud_detection.py:100-123``).
@@ -230,7 +234,7 @@ def cms_query_where(
     no sketch table is touched. A row that is not served reads 0.0 in
     every column."""
     with step_scope("cms"):
-        (b,) = key.shape
+        b = key.shape[-1]  # a wide key is [2, B]
         k = chunk_rows(b)
         span = -(-b // k) * k
         upto = jnp.cumsum(rows.astype(jnp.int32))  # served rows ≤ here
@@ -246,7 +250,7 @@ def cms_query_where(
             trip, out = carry
             at = jax.lax.dynamic_slice(order, (trip * k,), (k,))
             src = jnp.minimum(at, b - 1)
-            got = _cms_query_tables(sk, tables, key[src], day[src],
+            got = _cms_query_tables(sk, tables, key[..., src], day[src],
                                     windows, delay)
             return trip + 1, tuple(
                 o.at[at].set(g, mode="drop") for o, g in zip(out, got))

@@ -26,6 +26,19 @@ def hash_u32(x: jnp.ndarray, seed: int = 0) -> jnp.ndarray:
     return h
 
 
+def hash_key(key: jnp.ndarray, seed: int = 0) -> jnp.ndarray:
+    """:func:`hash_u32` of a key column at either width → uint32 [B]. A
+    one-word key (``[B]``) is mixed as it always was. A wide key (``[2,
+    B]``: low words, high words — ``core/batch.split_key``) mixes BOTH:
+    the high word goes through the mixer first and is xored into the low
+    one, so two ids that differ in either word differ before the final
+    mix (the mixer is a bijection of 32 bits) and collide only as two
+    random 32-bit values do — ids whose words xor alike share nothing."""
+    if key.ndim == 1:
+        return hash_u32(key, seed)
+    return hash_u32(key[0] ^ hash_u32(key[1], seed + 0x51), seed)
+
+
 def slot_of(key: jnp.ndarray, capacity: int, seed: int = 0) -> jnp.ndarray:
     """Key → table slot in [0, capacity). capacity must be a power of two."""
     assert capacity & (capacity - 1) == 0, "capacity must be a power of 2"
@@ -67,10 +80,11 @@ def key_row(key, capacity: int, key_mode: str = "direct",
 
 
 def multi_hash(key: jnp.ndarray, depth: int, width: int) -> jnp.ndarray:
-    """[B] keys → [depth, B] independent column indices in [0, width)."""
+    """[B] keys (``[2, B]`` at 64 bits) → [depth, B] independent column
+    indices in [0, width)."""
     assert width & (width - 1) == 0, "width must be a power of 2"
     cols = [
-        (hash_u32(key, seed=d) & jnp.uint32(width - 1)).astype(jnp.int32)
+        (hash_key(key, seed=d) & jnp.uint32(width - 1)).astype(jnp.int32)
         for d in range(depth)
     ]
     return jnp.stack(cols, axis=0)

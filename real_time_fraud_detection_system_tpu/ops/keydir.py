@@ -41,11 +41,40 @@ Sentinel note: ``EMPTY_KEY`` (0xFFFFFFFF) is reserved; a real key equal
 to it is remapped to 0xFFFFFFFE (``fold_key`` output collides with that
 one value in 2^32 — the same order of aliasing the 32-bit fold already
 accepts).
+
+**Wide keys** (``FeatureConfig.key_bits=64``; a key is ``uint32 [2, B]``,
+low words then high words). The TPU has no 64-bit integer lane worth
+using, so the probe table stays what it is — ``keys`` holds a 32-bit
+FINGERPRINT of the entry's key, the xor-fold of its two words (what the
+32-bit deployment stores as the key itself), probed by the same double
+hashing — and the key itself sits beside it in two more ``[dir_cap]``
+leaves, ``keys_lo`` / ``keys_hi``, read once a row at the entry whose
+fingerprint matched. Two ids that fold alike share a fingerprint and a
+probe path, never an entry:
+
+- the lookup verifies both words at the first fingerprint match; a row
+  whose match holds ANOTHER key (an alias) strikes that position and
+  looks at its next match, in a ``lax.while_loop`` that runs while such a
+  row is left — one trip for a batch without aliases, and
+  ``rtfds_keydir_alias_rows_total`` counts the rows that took more;
+- the claim rounds elect by fingerprint as they always did; the grant's
+  owner (lowest row of an entry) writes both words, every claimant
+  re-reads them, and claimants that find another key there — two NEW ids
+  of one fingerprint raced for one position in one round — go through
+  the rounds and the grant again (a pass), in a loop that runs while
+  such a row is left: ~(new keys)^2 / 2^33 of a second pass a batch;
+- vacancy is the fingerprint's (``EMPTY_KEY``, never a fingerprint:
+  ``_canon``), so NO id is unrepresentable in the directory. One pattern
+  is reserved all the same, ``0xFFFFFFFF_FFFFFFFF`` (int64 -1): it marks
+  a padding lane of the demote and promote payloads, and a row that
+  carries it is never admitted (the sketch tier serves and counts it).
+  ``keys_lo`` / ``keys_hi`` of a vacant entry are stale, never read.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+import contextlib
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +101,14 @@ class KeyDirectory(NamedTuple):
     slots: jnp.ndarray  # int32 [dir_cap]; slot owned by the entry, -1 vacant
     free: jnp.ndarray  # int32 [slot_cap]; free[:free_top] = free slot ids
     free_top: jnp.ndarray  # int32 [] — live height of the free stack
+    # key_bits=64 only (None keeps the 32-bit pytree what it always was):
+    # the entry's key, word by word — ``keys`` then holds its fingerprint
+    keys_lo: Optional[jnp.ndarray] = None  # uint32 [dir_cap]
+    keys_hi: Optional[jnp.ndarray] = None  # uint32 [dir_cap]
+
+    @property
+    def wide(self) -> bool:
+        return self.keys_lo is not None
 
     @property
     def dir_capacity(self) -> int:
@@ -82,17 +119,24 @@ class KeyDirectory(NamedTuple):
         return int(self.free.shape[0])
 
 
-def init_keydir(dir_capacity: int, slot_capacity: int) -> KeyDirectory:
+def init_keydir(dir_capacity: int, slot_capacity: int,
+                key_bits: int = 32) -> KeyDirectory:
     assert dir_capacity & (dir_capacity - 1) == 0, \
         "dir_capacity must be a power of 2"
     assert slot_capacity <= dir_capacity, \
         "more slots than directory entries can never all be reachable"
+
+    def vacant():
+        return jnp.full((dir_capacity,), EMPTY_KEY, dtype=jnp.uint32)
+
     return KeyDirectory(
-        keys=jnp.full((dir_capacity,), EMPTY_KEY, dtype=jnp.uint32),
+        keys=vacant(),
         slots=jnp.full((dir_capacity,), -1, dtype=jnp.int32),
         # low slot ids pop first (free[top-1] is the next grant)
         free=jnp.arange(slot_capacity - 1, -1, -1, dtype=jnp.int32),
         free_top=jnp.int32(slot_capacity),
+        keys_lo=vacant() if key_bits == 64 else None,
+        keys_hi=vacant() if key_bits == 64 else None,
     )
 
 
@@ -117,14 +161,74 @@ def _probe_positions(key: jnp.ndarray, dir_cap: int,
         dir_cap)
 
 
+def fingerprint(key: jnp.ndarray) -> jnp.ndarray:
+    """Wide key ``[2, B]`` → its uint32 ``[B]`` fingerprint: the xor-fold
+    of the two words (``core/batch.fold_key``), never ``EMPTY_KEY``."""
+    return _canon(key[0] ^ key[1])
+
+
+def reserved(key: jnp.ndarray) -> jnp.ndarray:
+    """bool [B]: wide keys that carry the one reserved pattern."""
+    return (key[0] == EMPTY_KEY) & (key[1] == EMPTY_KEY)
+
+
+def _find_wide(kd: KeyDirectory, key: jnp.ndarray, fp: jnp.ndarray,
+               valid: jnp.ndarray, n_probes: int):
+    """The wide lookup → ``(entry [B], hit [B], alias [2])``: the entry
+    that holds each valid row's key, both words verified, and ``[rows
+    that met another key under their fingerprint, trips the loop ran]``.
+
+    One ``[B, P]`` gather of fingerprints, as at 32 bits; then a loop
+    that, while a row has an unverified match, reads both words at each
+    such row's FIRST match — its entry if they are its own, a position to
+    strike if they are another key's. A batch without aliases runs one
+    trip; one in which nothing matched, none."""
+    dir_cap = kd.dir_capacity
+    lo, hi = key[0], key[1]
+    pos = _probe_positions(fp, dir_cap, n_probes)  # [B, P]
+    cand = (kd.keys[pos] == fp[:, None]) & valid[:, None]  # [B, P]
+    probe = jax.lax.broadcasted_iota(jnp.int32, cand.shape, 1)
+
+    def check(carry):
+        trips, cand, entry, hit, aliased = carry
+        pidx = jnp.argmax(cand, axis=1).astype(jnp.int32)
+        first = probe == pidx[:, None]
+        e = jnp.sum(jax.lax.select(first, pos, jnp.zeros_like(pos)),
+                    axis=1)
+        has = cand.any(axis=1) & ~hit
+        same = (kd.keys_lo[e] == lo) & (kd.keys_hi[e] == hi)
+        ok = has & same
+        other = has & ~same
+        return (trips + 1, cand & ~(first & other[:, None]),
+                jax.lax.select(ok, e, entry), hit | ok, aliased | other)
+
+    def unverified(carry):
+        _, cand, _, hit, _ = carry
+        return (cand.any(axis=1) & ~hit).any()
+
+    zeros = jnp.zeros(valid.shape, bool)
+    trips, _, entry, hit, aliased = jax.lax.while_loop(
+        unverified, check,
+        (jnp.int32(0), cand, jnp.zeros(valid.shape, jnp.int32), zeros,
+         zeros))
+    alias = jnp.stack([jnp.sum(aliased.astype(jnp.int32)), trips])
+    return entry, hit, alias
+
+
 def lookup_slots(
     kd: KeyDirectory,
-    key: jnp.ndarray,  # uint32 [B]
+    key: jnp.ndarray,  # uint32 [B] ([2, B] for a wide directory)
     valid: jnp.ndarray,  # bool [B]
     n_probes: int = 8,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Read-only probe: (slot [B] int32, hit [B] bool). Missing/invalid
     rows return slot 0 with ``hit=False`` — mask before scattering."""
+    if kd.wide:
+        entry, found, _ = _find_wide(
+            kd, key, fingerprint(key), valid & ~reserved(key), n_probes)
+        slot = kd.slots[entry]
+        hit = found & (slot >= 0)
+        return jnp.where(hit, slot, 0), hit
     key = _canon(key)
     pos = _probe_positions(key, kd.dir_capacity, n_probes)  # [B, P]
     found = kd.keys[pos] == key[:, None]  # [B, P]
@@ -142,7 +246,8 @@ def init_stacked_keydir(dir_capacity: int, slot_capacity: int,
     [n, dir_cap], ``free`` [n, slot_cap], ``free_top`` [n]) — the
     layout the sharded engine places over the mesh (one directory per
     device, sharded on axis 0). Inside ``shard_map`` each device
-    squeezes the axis off and runs the plain single-shard ops."""
+    squeezes the axis off and runs the plain single-shard ops. One-word
+    keys only: the mesh refuses ``key_bits=64`` at construction."""
     kd = init_keydir(dir_capacity, slot_capacity)
     return KeyDirectory(
         keys=jnp.broadcast_to(kd.keys[None], (n_shards,) + kd.keys.shape),
@@ -178,21 +283,28 @@ def lookup_slots_stacked(
 
 def admit_slots(
     kd: KeyDirectory,
-    key: jnp.ndarray,  # uint32 [B]
+    key: jnp.ndarray,  # uint32 [B] ([2, B] for a wide directory)
     valid: jnp.ndarray,  # bool [B]
     n_probes: int = 8,
-) -> Tuple[KeyDirectory, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[KeyDirectory, jnp.ndarray, jnp.ndarray, jnp.ndarray,
+           Optional[jnp.ndarray]]:
     """Lookup-or-insert a batch of keys; the hot path's admission op.
 
-    Returns ``(kd', slot [B] int32, admitted [B] bool, rounds [] int32)``.
+    Returns ``(kd', slot [B] int32, admitted [B] bool, rounds [] int32,
+    alias)``.
     A row is admitted iff its key already owned a slot or could claim a
     directory entry within ``n_probes`` probes AND a free slot remained;
     batch duplicates of one key share a single slot. Non-admitted rows
     return slot 0 and MUST be masked out of dense-tier scatters (the
     caller serves them from the sketch tier). ``rounds`` is how many claim
     rounds ran, 0..``n_probes``: none when the lookup found every key
-    (``rtfds_keydir_claim_rounds_total`` counts them).
+    (``rtfds_keydir_claim_rounds_total`` counts them). A wide directory
+    (``key`` is ``[2, B]``) goes through :func:`admit_wide`, whose
+    ``alias`` counts are the fifth value; a one-word directory has none
+    to count and returns ``None`` there.
     """
+    if kd.wide:
+        return admit_wide(kd, key, valid, n_probes)
     with step_scope("keydir"):
         dir_cap = kd.dir_capacity
         slot_cap = kd.slot_capacity
@@ -284,12 +396,143 @@ def admit_slots(
             admitted = placed & valid & (keys[entry] == key) & (slot >= 0)
             slot = jnp.where(admitted, slot, 0)
         return (
-            KeyDirectory(keys=keys, slots=slots, free=kd.free,
-                         free_top=free_top),
+            kd._replace(keys=keys, slots=slots, free_top=free_top),
             slot,
             admitted,
             rounds,
+            None,
         )
+
+
+@contextlib.contextmanager
+def _part(name: str):
+    """``rtfds.keydir/rtfds.<name>``: one part of the wide admission."""
+    with step_scope("keydir"), step_scope(name):
+        yield
+
+
+def admit_wide(
+    kd: KeyDirectory,  # wide: keys_lo / keys_hi present
+    key: jnp.ndarray,  # uint32 [2, B]
+    valid: jnp.ndarray,  # bool [B]
+    n_probes: int = 8,
+) -> Tuple[KeyDirectory, jnp.ndarray, jnp.ndarray, jnp.ndarray,
+           jnp.ndarray]:
+    """:func:`admit_slots` for 64-bit keys → ``(kd', slot, admitted,
+    rounds, alias [2] int32)``: two ids are one key only if both words
+    agree. ``alias`` is the lookup's ``[rows that met another key under
+    their fingerprint, verify trips]``
+    (``rtfds_keydir_alias_rows_total`` / ``…_trips_total``).
+
+    The rounds elect by fingerprint — the parent's scatter-min, its
+    cost a round unchanged — and what a fingerprint cannot tell is
+    settled at the grant: the owner of a claimed entry writes its two
+    words there, every claimant reads them back, and a claimant that
+    reads another key (a second new id of the same fingerprint won the
+    same position in the same round) is unplaced again. Rounds and grant
+    are one PASS of a loop that runs while such a row is left, at most
+    ``n_probes`` passes: one for nearly every batch. Ids of one
+    fingerprint share a probe path, so at most ``n_probes`` of them are
+    resident at a time; the others miss admission like any key whose
+    positions are taken.
+
+    No in-round match is looked for (the 32-bit rounds' ``hit``): every
+    row of one key is unplaced or placed together — the lookup found the
+    key or it did not, duplicates win or lose a position together — so a
+    key is never written while a row of it is still probing."""
+    dir_cap = kd.dir_capacity
+    slot_cap = kd.slot_capacity
+    B = int(valid.shape[0])
+    with _part("lookup"):
+        lo, hi = key[0], key[1]
+        valid = valid & ~reserved(key)
+        fp = fingerprint(key)
+        entry, hit0, alias = _find_wide(kd, key, fp, valid, n_probes)
+        unplaced = valid & ~hit0
+
+    def claim_round(carry):
+        j, keys, entry, placed, claimed = carry
+        p = _probe_position(fp, j.astype(jnp.uint32), dir_cap)
+        want = ~placed & (keys[p] == EMPTY_KEY)
+        keys = keys.at[p].min(
+            jax.lax.select(want, fp, jnp.full_like(fp, EMPTY_KEY)))
+        won = want & (keys[p] == fp)
+        return (j + 1, keys, jax.lax.select(won, p, entry),
+                placed | won, claimed | won)
+
+    def unplaced_with_a_round_left(carry):
+        j, _, _, placed, _ = carry
+        return (j < n_probes) & ~placed.all()
+
+    def one_pass(carry):
+        (n, rounds, keys, klo, khi, slots, free_top, entry, todo,
+         got) = carry
+        with _part("claim"):
+            ran, keys, entry, _, claimed = jax.lax.while_loop(
+                unplaced_with_a_round_left, claim_round,
+                (jnp.int32(0), keys, entry, ~todo,
+                 jnp.zeros(B, dtype=bool)))
+        with _part("grant"):
+            rows = jnp.arange(B, dtype=jnp.int32)
+            drop = jnp.full_like(entry, dir_cap)
+            owner = jnp.full((dir_cap,), B, jnp.int32).at[
+                jax.lax.select(claimed, entry, drop)].min(
+                    rows, mode="drop")
+            new = claimed & (owner[entry] == rows)
+            # the owner names the entry; its batch duplicates read
+            # their own key back, a fingerprint twin reads another's
+            mine_at = jax.lax.select(new, entry, drop)
+            klo = klo.at[mine_at].set(lo, mode="drop")
+            khi = khi.at[mine_at].set(hi, mode="drop")
+            mine = claimed & (klo[entry] == lo) & (khi[entry] == hi)
+            rank = jax.lax.cumsum(new.astype(jnp.int32)) - 1
+            has = new & (rank < free_top)
+            slot_new = kd.free[
+                jnp.clip(free_top - 1 - rank, 0, slot_cap - 1)]
+            slots = slots.at[jax.lax.select(has, entry, drop)].set(
+                slot_new, mode="drop")
+            revert = new & ~has
+            keys = keys.at[jax.lax.select(revert, entry, drop)].set(
+                EMPTY_KEY, mode="drop")
+            free_top = free_top - jnp.sum(has.astype(jnp.int32))
+            return (n + 1, rounds + ran, keys, klo, khi, slots, free_top,
+                    entry, claimed & ~mine, got | mine)
+
+    def a_twin_is_unplaced(carry):
+        n, todo = carry[0], carry[8]
+        with _part("claim"):
+            return (n < n_probes) & todo.any()
+
+    # The first pass is every batch's; further passes are a loop that runs
+    # while a fingerprint twin is unplaced (~0.1 of a pass a batch at
+    # 26,000 new keys). The loop stands BESIDE rtfds.keydir and its
+    # condition and each part of its body open keydir/<part> anew: a later
+    # pass's rounds book under rtfds.keydir/rtfds.claim and its grant
+    # under rtfds.keydir/rtfds.grant, as the first pass's do (a scope
+    # opened around the loop would put "while/body" between the two names,
+    # and a reader of "rtfds.keydir/rtfds.grant" would miss the grant).
+    (_, rounds, keys, klo, khi, slots, free_top, entry, _,
+     got) = jax.lax.while_loop(
+        a_twin_is_unplaced, one_pass,
+        one_pass((jnp.int32(0), jnp.int32(0), kd.keys, kd.keys_lo,
+                  kd.keys_hi, kd.slots, kd.free_top, entry, unplaced,
+                  hit0)))
+    with _part("grant"):
+        # An entry a row got holds that row's key for good, or was
+        # rolled back (the free stack ran dry: its fingerprint is
+        # gone, and whoever claims it next finds no slot either) —
+        # so the fingerprint and the slot decide, as at 32 bits.
+        slot = slots[entry]
+        admitted = got & (keys[entry] == fp) & (slot >= 0)
+        slot = jnp.where(admitted, slot, 0)
+    return (
+        kd._replace(keys=keys, slots=slots, free_top=free_top,
+                    keys_lo=klo, keys_hi=khi),
+        slot,
+        admitted,
+        rounds,
+        alias,
+    )
 
 
 # K: the lanes one trip of a lane-packed pass works on (reclaim_entries'
@@ -372,7 +615,7 @@ def reclaim_entries(
         lambda carry: carry[0] * lanes < n, vacate,
         (jnp.int32(0), kd.free, jnp.zeros((slot_cap,), bool)))
     return (
-        KeyDirectory(
+        kd._replace(
             keys=jnp.where(dead, EMPTY_KEY, kd.keys),
             slots=jnp.where(dead, -1, kd.slots),
             free=free,

@@ -28,9 +28,14 @@ from real_time_fraud_detection_system_tpu.config import Config
 from real_time_fraud_detection_system_tpu.core.batch import (
     TxBatch,
     bucket_size,
+    device_keys,
+    host_keys,
+    join_key,
     make_batch,
     pack_batch,
+    packed_rows,
     unpack_batch,
+    wide_id_rows,
 )
 from real_time_fraud_detection_system_tpu.features.online import (
     FeatureState,
@@ -157,11 +162,14 @@ class ColdPromoteError(RuntimeError):
 PROMOTE_LANES_MAX = 16384
 
 
-def blank_lanes(shape: tuple, rows: tuple) -> list:
+def blank_lanes(shape: tuple, rows: tuple, key_bits: int = 32) -> list:
     """An all-padding promote payload of lane shape ``shape`` (``(W,)``,
     or ``(n_dev, W)`` on the mesh): ``EMPTY_KEY`` keys and empty rows as
-    wide as ``rows``' (bd, cnt, amt, frd), for the caller to fill."""
-    return [np.full(shape, 0xFFFFFFFF, np.uint32)] + [
+    wide as ``rows``' (bd, cnt, amt, frd), for the caller to fill. At
+    ``key_bits=64`` the key lanes are ``[2, W]``, both words
+    ``EMPTY_KEY``: the reserved pattern."""
+    key_shape = ((2,) if key_bits == 64 else ()) + tuple(shape)
+    return [np.full(key_shape, 0xFFFFFFFF, np.uint32)] + [
         np.full(shape + r.shape[1:], fill, r.dtype)
         for r, fill in zip(rows, (-1, 0.0, 0.0, 0.0))]
 
@@ -516,10 +524,25 @@ class ScoringEngine:
             raise ValueError(
                 f"emit_dtype must be float32|bfloat16, "
                 f"got {cfg.runtime.emit_dtype!r}")
+        self._key_bits = int(cfg.features.key_bits)
+        # rows with an id past 32 bits, counted at either width; at 32
+        # they are folded, and the first such batch says so once
+        self._m_wide_ids = self.metrics.counter(
+            "rtfds_wide_id_rows_total",
+            "rows whose customer or terminal id does not fit 32 bits "
+            "(counted on the host from the decoded int64 columns): "
+            "carried whole at key_bits=64, xor-folded — and possibly "
+            "merged with another id — at key_bits=32")
+        self._wide_ids_warned = False
         if kind == "sequence":
             # Long-context serving: per-customer event histories in HBM
             # scored by the causal transformer — a different state and
             # step shape, built in its own branch.
+            if self._key_bits == 64:
+                raise ValueError(
+                    "key_bits=64 is carried by the windows plane's key "
+                    "directory only; kind='sequence' keys its history "
+                    "ring by one folded word (keep key_bits=32)")
             if cfg.features.key_mode == "exact":
                 raise ValueError(
                     "key_mode='exact' is the windows-plane tiered "
@@ -830,6 +853,7 @@ class ScoringEngine:
         fcfg = self.cfg.features
         self._m_tier = None
         self._m_claim_rounds = None
+        self._m_alias = None
         self._m_slots_occ = None
         self._m_slots_rec = None
         self._m_compactions = None
@@ -867,6 +891,21 @@ class ScoringEngine:
                     "no round can place", table=t)
                 for t, present in tables if present
             }
+            if fcfg.key_bits == 64:
+                self._m_alias = {
+                    "rows": reg.counter(
+                        "rtfds_keydir_alias_rows_total",
+                        "rows whose first fingerprint match in the key "
+                        "directory held ANOTHER 64-bit key (an id that "
+                        "folds like theirs), so that the lookup verified "
+                        "a further match for them; both tables, the "
+                        "step's admits"),
+                    "trips": reg.counter(
+                        "rtfds_keydir_alias_trips_total",
+                        "trips of the wide lookup's verify loop, both "
+                        "tables: one an admit whose batch holds a known "
+                        "key, more only while an alias row is left"),
+                }
             self._m_slots_occ = {
                 t: reg.gauge(
                     "rtfds_feature_slots_occupied",
@@ -928,7 +967,8 @@ class ScoringEngine:
         )
 
         self._cold = ColdStore(fcfg.cold_store,
-                               segment_mb=fcfg.cold_segment_mb)
+                               segment_mb=fcfg.cold_segment_mb,
+                               key_bits=fcfg.key_bits)
         self._cold_writer = SegmentWriter(self._cold)
         self._promote_widths = promote_widths(
             max(self.cfg.runtime.batch_buckets))
@@ -985,8 +1025,12 @@ class ScoringEngine:
             pay = payload.get(table)
             if pay is None:
                 continue
-            keys = np.asarray(pay[0]).reshape(-1)
-            if not (keys != np.uint32(0xFFFFFFFF)).any():
+            keys = np.asarray(pay[0])
+            # [2, K] words at key_bits=64 -> uint64 [K]; the mesh's
+            # stacked one-word lanes flatten
+            keys = (join_key(keys) if self._key_bits == 64
+                    else keys.reshape(-1))
+            if not (keys != np.iinfo(keys.dtype).max).any():
                 continue  # nothing demoted: the rows are not fetched
             for leaf in pay[1:]:
                 leaf.copy_to_host_async()
@@ -1018,8 +1062,9 @@ class ScoringEngine:
         for lo in range(0, keys.size, top):
             n = min(top, keys.size - lo)
             w = next(w for w in self._promote_widths if w >= n)
-            lanes = blank_lanes((w,), rows)
-            for lane, src in zip(lanes, (keys,) + rows):
+            lanes = blank_lanes((w,), rows, self._key_bits)
+            lanes[0][..., :n] = device_keys(keys[lo:lo + n])
+            for lane, src in zip(lanes[1:], rows):
                 lane[:n] = src[lo:lo + n]
             out.append((w, one_table_payload(table, lanes)))
         return out
@@ -1032,10 +1077,6 @@ class ScoringEngine:
         returns and nothing more is done."""
         if self._cold is None:
             return None
-        from real_time_fraud_detection_system_tpu.core.batch import (
-            fold_key,
-        )
-
         hits, cold_row = {}, None
         with self._phase("cold_detect"):
             for table, col in (("customer", "customer_id"),
@@ -1044,12 +1085,15 @@ class ScoringEngine:
                 if (table not in self._cold_tables() or ids is None
                         or not len(ids)):
                     continue
-                keys = fold_key(np.asarray(ids))
-                # the directory canonicalizes EMPTY_KEY collisions the
-                # same way (ops/keydir._canon) — mirror it or miss those
-                # keys
-                keys = np.where(keys == np.uint32(0xFFFFFFFF),
-                                np.uint32(0xFFFFFFFE), keys)
+                keys = host_keys(ids, self._key_bits)
+                if self._key_bits == 32:
+                    # the directory canonicalizes EMPTY_KEY collisions
+                    # the same way (ops/keydir._canon) — mirror it or
+                    # miss those keys. (A wide key is stored as it is;
+                    # the one reserved pattern is never admitted, so
+                    # never demoted, so never found here.)
+                    keys = np.where(keys == np.uint32(0xFFFFFFFF),
+                                    np.uint32(0xFFFFFFFE), keys)
                 mask = self._cold.cold_mask(table, keys)
                 if mask.any():
                     hits[table] = np.unique(keys[mask])
@@ -1362,14 +1406,15 @@ class ScoringEngine:
         (kind, z_mode, selective packing, emission dtype, donation
         layout, Pallas gating) are fixed at build — so the runtime
         dispatch key is always ``("step", 7, bucket)`` for an enumerable
-        bucket. :meth:`precompile` compiles exactly this list and
+        bucket (``("step", 9, bucket)`` at ``key_bits=64``: the packed
+        batch's two more rows, ``core.batch.packed_rows``). :meth:`precompile` compiles exactly this list and
         ``tools/rtfdsverify`` proves contracts over exactly this list;
         neither re-derives its own enumeration, so they cannot drift.
         """
         zmode_kinds = ("tree", "forest", "gbt")
         sigs = [
             DispatchSignature(
-                key=("step", 7, int(b)),
+                key=("step", packed_rows(self._key_bits), int(b)),
                 variant="step",
                 kind=self.kind,
                 z_mode=self.z_mode if self.kind in zmode_kinds else None,
@@ -1427,7 +1472,8 @@ class ScoringEngine:
         """Shape-only template of one promote payload (the sharded
         engine overrides with its stacked per-shard layout)."""
         nb = self.cfg.features.n_day_buckets
-        lanes = (jax.ShapeDtypeStruct((width,), jnp.uint32),
+        key_shape = ((2,) if self._key_bits == 64 else ()) + (width,)
+        lanes = (jax.ShapeDtypeStruct(key_shape, jnp.uint32),
                  jax.ShapeDtypeStruct((width, nb), jnp.int32)) + (
             jax.ShapeDtypeStruct((width, nb), jnp.float32),) * 3
         return one_table_payload(table, lanes)
@@ -1453,7 +1499,8 @@ class ScoringEngine:
             self._sds(self.state.feature_state),
             self._sds(self.state.params),
             self._sds(self.state.scaler),
-            jax.ShapeDtypeStruct((7, sig.bucket), jnp.int32),
+            jax.ShapeDtypeStruct(
+                (packed_rows(self._key_bits), sig.bucket), jnp.int32),
         )
 
     def signature_step(self, sig: DispatchSignature):
@@ -1856,12 +1903,13 @@ class ScoringEngine:
             validate_ingest_rows(cols)
             returning = self._returning_keys(cols)
             n = len(cols["tx_id"])
+            self._count_wide_ids(cols)
             pad = bucket_size(n, self.cfg.runtime.batch_buckets)
             if use_native:
                 packed = native.pack_rows(
                     cols["tx_datetime_us"], cols["customer_id"],
                     cols["terminal_id"], cols["tx_amount_cents"],
-                    cols.get("label"), pad,
+                    cols.get("label"), pad, self._key_bits,
                 )
             else:
                 packed = pack_batch(make_batch(
@@ -1871,6 +1919,7 @@ class ScoringEngine:
                     amount_cents=cols["tx_amount_cents"],
                     label=cols.get("label"),
                     pad_to=pad,
+                    key_bits=self._key_bits,
                 ))
             # the phase closes after ALL host packing on both paths, so
             # prep_s/dispatch_s attribute the same stages either way
@@ -1912,6 +1961,30 @@ class ScoringEngine:
                 "dispatch_s": disp.seconds, "pre_state": pre_state,
                 "fetch_issue_t": t_fetch,
                 "promote_checks": promoted}
+
+    def _count_wide_ids(self, cols: dict) -> None:
+        """Host prep's one look at the ids' width: rows with an id past
+        32 bits go on ``rtfds_wide_id_rows_total``; a 32-bit deployment
+        that meets one says once what its fold does to it."""
+        wide = wide_id_rows(cols["customer_id"], cols["terminal_id"])
+        if not wide:
+            return
+        self._m_wide_ids.inc(wide)
+        if self._key_bits == 32 and not self._wide_ids_warned:
+            self._wide_ids_warned = True
+            from real_time_fraud_detection_system_tpu.utils import (
+                get_logger,
+            )
+
+            get_logger("engine").warning(
+                "%d row(s) of this batch carry a customer or terminal id "
+                "that does not fit 32 bits. key_bits=32 xor-folds every "
+                "id to one word: two ids whose words xor alike are ONE "
+                "key from here on (one window history, one sketch row), "
+                "~n^2/2^33 pairs among n keys, and nothing downstream "
+                "can tell. Serve wide ids with --key-mode exact "
+                "--key-bits 64 (rtfds_wide_id_rows_total counts them)",
+                wide)
 
     def _finish_batch(self, handle: dict) -> BatchResult:
         """Block on the handle's device futures (``device_wait``), then
@@ -2095,7 +2168,11 @@ class ScoringEngine:
             t = np.asarray(tier)
             self._m_tier["dense"].inc(float(t[0]))
             self._m_tier["cms"].inc(float(t[1]))
-            self._count_claim_rounds(t[2:])
+            self._count_claim_rounds(t[2:4])
+            if self._m_alias is not None:
+                # key_bits=64: the lookups' [alias rows, verify trips]
+                self._m_alias["rows"].inc(float(t[4]))
+                self._m_alias["trips"].inc(float(t[5]))
         self.state.batches_done += 1
         self.state.rows_done += n
         self._m_batches.inc()
@@ -2177,7 +2254,6 @@ class ScoringEngine:
         label < 0 (pending) and buckets whose ring slot has already
         advanced past the transaction's day.
         """
-        from real_time_fraud_detection_system_tpu.core.batch import fold_key
         from real_time_fraud_detection_system_tpu.features.online import (
             apply_feedback as state_feedback,
         )
@@ -2205,8 +2281,10 @@ class ScoringEngine:
         for s in range(0, len(y), biggest):
             n = len(y[s : s + biggest])
             pad = bucket_size(n, self.cfg.runtime.batch_buckets)
-            tk = np.zeros(pad, dtype=np.uint32)
-            tk[:n] = fold_key(t_ids[s : s + n])
+            tk = np.zeros(
+                ((2,) if self._key_bits == 64 else ()) + (pad,), np.uint32)
+            tk[..., :n] = device_keys(
+                host_keys(t_ids[s : s + n], self._key_bits))
             dd = np.zeros(pad, dtype=np.int32)
             dd[:n] = d[s : s + n]
             yy = np.zeros(pad, dtype=np.int32)

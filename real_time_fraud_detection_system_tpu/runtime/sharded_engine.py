@@ -135,6 +135,16 @@ class ShardedScoringEngine(ScoringEngine):
                 "multi-host serving is not wired for kind='sequence' "
                 "(history-state process adoption does not exist yet); "
                 "serve the sequence scorer single-process")
+        if cfg.features.key_bits == 64:
+            # No path folds a wide id silently: the owner exchange, the
+            # stacked per-device directories and the multi-process
+            # fleet's ownership (key % n) all carry one-word keys.
+            raise ValueError(
+                "key_bits=64 is not wired for the sharded engine "
+                "(--devices > 1 / multi-host): its owner exchange and "
+                "per-device directories carry one-word keys. Serve "
+                "64-bit ids on one chip with key_mode='exact', or keep "
+                "key_bits=32 on the mesh (ROADMAP B14)")
         if cfg.runtime.nan_guard:
             # The sharded step donates state inside shard_map and a batch
             # spans several chunk steps — there is no pre-batch anchor to
@@ -863,6 +873,7 @@ class ShardedScoringEngine(ScoringEngine):
             self._validate_sharded(cols)
             returning = self._returning_keys(cols)
             n = len(cols["tx_id"])
+            self._count_wide_ids(cols)
             self._ensure_sharded()
             if n:
                 # Same placement rule as partition_batch_spill

@@ -494,10 +494,6 @@ STEP_SCOPES = (
     "unpack",                                      # engine.step
     "customer", "terminal",                        # which table
     "update", "stamp", "reset", "scatter",         # ops/windows
-    # no stage opens this one since PR 25 (the columns are stored in the
-    # layout the update works in); the benchmark's step_relayout_ms
-    # names it and reads 0, and a later layout move goes under it
-    "relayout",
     "query", "gather", "sum",                      # ops/windows
     "keydir", "cms",                               # ops/keydir, ops/cms
     "lookup", "claim", "grant",                    # the parts of keydir

@@ -609,3 +609,123 @@ def test_window_tables_are_cap_by_nb_on_disk(tmp_path, written_by):
     assert not np.array_equal(  # the batches did land in the tables
         np.asarray(out.feature_state.terminal.count),
         np.zeros(128 * nb, np.float32))
+
+
+# -- the key width travels with the state (PR 41) --------------------------
+
+
+def _exact_engine(key_bits, batches=3):
+    """A small ``key_mode="exact"`` engine after a few batches of ids of
+    card-number width in fold-twin pairs."""
+    from real_time_fraud_detection_system_tpu.config import (
+        Config,
+        FeatureConfig,
+        RuntimeConfig,
+    )
+    from real_time_fraud_detection_system_tpu.runtime import ScoringEngine
+
+    cfg = Config(
+        features=FeatureConfig(customer_capacity=64, terminal_capacity=128,
+                               cms_width=1 << 10, key_mode="exact",
+                               keydir_probes=16, key_bits=key_bits),
+        runtime=RuntimeConfig(batch_buckets=(64,), max_batch_rows=64))
+    eng = ScoringEngine(cfg, kind="logreg", params=init_logreg(15),
+                        scaler=Scaler(mean=np.zeros(15, np.float32),
+                                      scale=np.ones(15, np.float32)))
+    rng = np.random.default_rng(7)
+    base = rng.integers(10 ** 15, 10 ** 16, 20, dtype=np.int64)
+    ids = np.concatenate([base, base ^ 0x5 ^ (0x5 << 32)])  # fold twins
+    for b in range(batches):
+        us = ((20_000 + b) * 86400 + np.arange(50) * 60).astype(
+            np.int64) * 1_000_000
+        eng.process_batch({
+            "tx_id": np.arange(50, dtype=np.int64) + 1000 * b,
+            "tx_datetime_us": us,
+            "customer_id": ids[rng.integers(0, 40, 50)],
+            "terminal_id": ids[rng.integers(0, 40, 50)] + 1,
+            "tx_amount_cents": rng.integers(100, 90_000, 50).astype(
+                np.int64),
+            "kafka_ts_ms": us // 1000,
+        })
+    return eng
+
+
+def test_wide_state_saves_and_restores_with_its_whole_keys(tmp_path):
+    """A ``key_bits=64`` state round-trips to the bit — the directories'
+    two key-word leaves among the rest — and an engine restored from it serves the next batch as the engine that
+    never stopped does."""
+    eng = _exact_engine(64)
+    ck = Checkpointer(str(tmp_path / "ck"))
+    ck.save(eng.state)
+    fresh = _exact_engine(64, batches=0)
+    out = ck.restore(fresh.state)
+    assert out is not None and out.batches_done == 3
+    leaves_equal(out, eng.state)
+    kd = out.feature_state.customer_dir
+    assert kd.keys_lo is not None and kd.keys_hi is not None
+    live = np.asarray(kd.slots) >= 0
+    assert (np.asarray(kd.keys_hi)[live] != 0).all()  # whole keys
+    # the restored engine and the one that never stopped agree on what
+    # comes next, fold twins and all
+    rng = np.random.default_rng(99)
+    us = ((20_003) * 86400 + np.arange(50) * 60).astype(
+        np.int64) * 1_000_000
+    kd0 = eng.state.feature_state.customer_dir
+    stored = (np.asarray(kd0.keys_lo)[live].astype(np.uint64)
+              | (np.asarray(kd0.keys_hi)[live].astype(np.uint64)
+                 << np.uint64(32))).view(np.int64)
+    cols = {"tx_id": np.arange(50, dtype=np.int64) + 9000,
+            "tx_datetime_us": us,
+            "customer_id": stored[rng.integers(0, len(stored), 50)],
+            "terminal_id": stored[rng.integers(0, len(stored), 50)] + 1,
+            "tx_amount_cents": np.full(50, 1234, np.int64),
+            "kafka_ts_ms": us // 1000}
+    a = eng.process_batch(dict(cols))
+    b = fresh.process_batch(dict(cols))
+    np.testing.assert_array_equal(a.features, b.features)
+    np.testing.assert_array_equal(a.probs, b.probs)
+
+
+def test_a_32_bit_checkpoint_restores_unchanged_and_says_no_width(tmp_path):
+    """The default width writes what it always wrote: no ``key_bits`` in
+    the meta (absent = 32), the parent's leaves — and restores into a
+    32-bit engine to the bit."""
+    from real_time_fraud_detection_system_tpu.io.checkpoint import (
+        _state_arrays,
+    )
+
+    eng = _exact_engine(32)
+    arrays, meta = _state_arrays(eng.state)
+    assert "key_bits" not in meta
+    assert meta["n_fs"] == 23 and not [
+        v for v in meta["fs_leaves"].values() if "keys_" in v]
+    _, meta64 = _state_arrays(_exact_engine(64, batches=0).state)
+    assert meta64["key_bits"] == 64 and meta64["n_fs"] == 27
+    ck = Checkpointer(str(tmp_path / "ck"))
+    ck.save(eng.state)
+    out = ck.restore(_exact_engine(32, batches=0).state)
+    assert out is not None
+    leaves_equal(out, eng.state)
+
+
+@pytest.mark.parametrize("written,restored", [(32, 64), (64, 32)])
+def test_a_width_mismatch_is_refused_by_name(tmp_path, written, restored):
+    """A checkpoint of the other width is healthy and not this engine's:
+    restore REFUSES, naming both widths — it neither quarantines the
+    entry nor falls back down a lineage that shares the width."""
+    from real_time_fraud_detection_system_tpu.io.checkpoint import (
+        CheckpointKeyWidthError,
+    )
+
+    ck = Checkpointer(str(tmp_path / "ck"))
+    ck.save(_exact_engine(written, batches=1).state)
+    ck.save(_exact_engine(written, batches=2).state)
+    before = corrupt_base("incompatible")
+    with pytest.raises(CheckpointKeyWidthError,
+                       match=f"key_bits={written}.*key_bits={restored}"):
+        ck.restore(_exact_engine(restored, batches=0).state)
+    assert corrupt_base("incompatible") == before
+    assert len(ck.list_checkpoints()) == 2  # nothing quarantined
+    # and it still restores where it belongs
+    assert ck.restore(
+        _exact_engine(written, batches=0).state).batches_done == 2

@@ -203,6 +203,90 @@ def test_cold_armed_equals_all_hot(tmp_path, devices, depth, precompile):
     assert back_to_back > 0
 
 
+def _wide_ids(k):
+    """Dense test ids → 64-bit ids in FOLD-TWIN pairs: 2j and 2j + 1 get
+    different words with the same xor, so ``key_bits=32`` would serve
+    each pair as one key — in the directory and in the cold store."""
+    k = np.asarray(k, np.uint64)
+    hi = np.uint64(0x2386F2) + (k % np.uint64(2)) * np.uint64(0x3039)
+    fold = ((k // np.uint64(2)) * np.uint64(2654435761)
+            + np.uint64(1)) & np.uint64(0xFFFFFFFF)
+    return ((hi << np.uint64(32)) | (fold ^ hi)).view(np.int64)
+
+
+@pytest.mark.parametrize("depth,precompile", [(2, True), (1, False)],
+                         ids=["d2-aot", "d1-jit"])
+def test_cold_armed_equals_all_hot_on_wide_ids(tmp_path, depth, precompile):
+    """``key_bits=64`` through the tier: fold twins are demoted as two
+    keys with two rows, one of a pair comes back while its twin stays
+    cold, the segment files and manifests carry whole uint64 keys — and
+    every delivered row equals the all-hot engine's bit for bit."""
+    import json
+
+    from real_time_fraud_detection_system_tpu.core.batch import fold_key
+
+    rt = RuntimeConfig(batch_buckets=(64,), max_batch_rows=64,
+                       pipeline_depth=depth, precompile=precompile)
+    fcfg = dict(_fcfg(str(tmp_path / "cold"), cap=256, demote=64),
+                key_bits=64, cold_segment_mb=0.01)
+    batches = _churn(7, 24, 64, 1024)
+    for b in batches:
+        b["customer_id"] = _wide_ids(b["customer_id"])
+        b["terminal_id"] = _wide_ids(b["terminal_id"])
+    reg = MetricsRegistry()
+    eng = _build(fcfg, rt, reg)
+    demoted, promoted = [], []
+    append, mark = eng._cold.append, eng._cold.mark_promoted
+
+    def spy_append(table, keys, *rows, **kw):
+        keys = np.asarray(keys)
+        assert keys.dtype == np.uint64 and keys.ndim == 1
+        live = keys[keys != np.iinfo(np.uint64).max]
+        assert (np.diff(live.astype(object)) > 0).all()  # key order
+        demoted.append((table, live))
+        return append(table, keys, *rows, **kw)
+
+    def spy_mark(table, keys):
+        promoted.append((table, np.asarray(keys)))
+        return mark(table, keys)
+
+    eng._cold.append, eng._cold.mark_promoted = spy_append, spy_mark
+    sink, ctrl_sink = _Sink(), _Sink()
+    stats = eng.run(_Source(batches), sink)
+    _all_hot(fcfg, rt).run(_Source(batches), ctrl_sink)
+    _assert_same_rows(sink.results, ctrl_sink.results)
+    _assert_exact_counters(eng, reg, stats, precompile)
+    assert reg.get("rtfds_feature_cold_demotions_total").value > 200
+    assert reg.get("rtfds_feature_cold_promotions_total").value > 50
+    for table in ("customer", "terminal"):
+        gone = np.concatenate([k for t, k in demoted if t == table])
+        back = np.concatenate([k for t, k in promoted if t == table])
+        assert gone.dtype == back.dtype == np.uint64
+        assert (gone >> np.uint64(32) != 0).all()  # whole keys, not folds
+        # a pair demoted as two keys (one fold, two rows) ...
+        folds, n = np.unique(fold_key(np.unique(gone).view(np.int64)),
+                             return_counts=True)
+        assert (n == 2).sum() > 10
+        # ... and one of the two promoted back without its twin
+        twin = (back.view(np.int64) ^ np.int64(0x3039 << 32 | 0x3039)
+                ).view(np.uint64)
+        assert (~np.isin(twin, back)).sum() > 10
+    # the durable copy carries whole keys too
+    mans = sorted((tmp_path / "cold").glob("seg-*.json"))
+    assert mans
+    man = json.loads(mans[0].read_text())
+    assert man["key_bits"] == 64
+    assert all(k >> 32 for ks in man["keys"].values() for k in ks)
+    # the same stream at 32 bits merges the pairs: rows differ
+    narrow = _build(dict(fcfg, key_bits=32,
+                         cold_store=str(tmp_path / "cold32")), rt,
+                    MetricsRegistry())
+    narrow_sink = _Sink()
+    narrow.run(_Source(batches), narrow_sink)
+    assert any((np.asarray(a.features) != np.asarray(b.features)).any()
+               for a, b in zip(narrow_sink.results, ctrl_sink.results))
+
+
 @pytest.mark.parametrize("devices", [1, 4], ids=["one-device", "mesh"])
 def test_claim_rounds_of_steps_and_promotes_are_counted(tmp_path, devices):
     """``rtfds_keydir_claim_rounds_total{table=…}`` is every round every

@@ -545,3 +545,90 @@ def test_checkpoint_cold_lineage_inspect_and_restore(tmp_path):
         f"seg-{lin['segments'][0]['seq']:08d}.json")).read())
     t, ks = next((t, ks) for t, ks in seg_man["keys"].items() if ks)
     assert ks and all(eng2._cold.contains(t, k) for k in ks)
+
+
+# -- 64-bit keys: the store carries the whole id (PR 41) -------------------
+
+WIDE_A = 0x0011_2233_4455_6677  # a 16-digit-ish id
+WIDE_B = WIDE_A ^ 0x5 ^ (0x5 << 32)  # its fold twin: same xor of words
+PAD64 = 0xFFFF_FFFF_FFFF_FFFF
+
+
+def test_wide_store_keeps_fold_twins_apart_through_flush_and_reopen(
+        tmp_path):
+    """``key_bits=64``: the index, the segment's key arrays and the
+    manifest's key lists hold uint64 keys — two ids whose words xor alike
+    are two rows, the all-ones pattern is the padding lane, and a reopen
+    rebuilds the same index from the manifests alone."""
+    import json
+
+    from real_time_fraud_detection_system_tpu.core.batch import fold_key
+
+    twins = np.asarray([WIDE_A, WIDE_B], np.uint64)
+    assert len(set(fold_key(twins.view(np.int64)).tolist())) == 1
+    d = str(tmp_path / "cold")
+    cs = ColdStore(d, key_bits=64)
+    bd, cnt, amt, frd = _rows(0, 4)
+    keys = np.asarray([WIDE_B, PAD64, WIDE_A, 1 << 63], np.uint64)
+    assert cs.append("customer", keys, bd, cnt, amt, frd) == 3  # pad skipped
+    assert cs.index_snapshot("customer").dtype == np.uint64
+    np.testing.assert_array_equal(
+        cs.cold_mask("customer", np.asarray(
+            [WIDE_A, WIDE_B, WIDE_A ^ 1, 1 << 63, PAD64], np.uint64)),
+        [True, True, False, True, False])
+    got = cs.get_rows("customer", twins)
+    np.testing.assert_array_equal(got[WIDE_A][0], bd[2])
+    np.testing.assert_array_equal(got[WIDE_B][0], bd[0])
+    seq = cs.flush()
+    man = json.loads((tmp_path / "cold" / f"seg-{seq:08d}.json").read_text())
+    assert man["key_bits"] == 64
+    assert sorted(man["keys"]["customer"]) == sorted(
+        [WIDE_A, WIDE_B, 1 << 63])
+    with np.load(tmp_path / "cold" / man["blob"]) as z:
+        assert z["customer_keys"].dtype == np.uint64
+    # one of the two promoted back: the other stays, whole
+    cs.mark_promoted("customer", np.asarray([WIDE_A], np.uint64))
+    assert not cs.contains("customer", WIDE_A)
+    assert cs.contains("customer", WIDE_B)
+    cs2 = ColdStore(d, key_bits=64)
+    assert cs2.keys_count == 3  # manifests are immutable: A is back
+    found, bd2, *_ = cs2.read_rows("customer", twins)
+    assert found.all()
+    np.testing.assert_array_equal(bd2, bd[[2, 0]])
+    assert cs2.lineage()["total_keys"] == 3
+
+
+def test_a_store_of_the_other_width_is_refused_by_name(tmp_path):
+    """A store written at 32 reads as before at 32 (no ``key_bits`` in
+    its manifests: the bytes of a segment are what they always were) and
+    is refused at 64; one written at 64 is refused at 32. Refused, not
+    quarantined: the files stay where they are."""
+    import json
+
+    from real_time_fraud_detection_system_tpu.io.coldstore import (
+        ColdStoreKeyWidthError,
+    )
+
+    narrow, wide = str(tmp_path / "n"), str(tmp_path / "w")
+    cs = ColdStore(narrow)
+    cs.append("customer", [10, 20], *_rows(3, 2))
+    seq = cs.flush()
+    man = json.loads((tmp_path / "n" / f"seg-{seq:08d}.json").read_text())
+    assert "key_bits" not in man and man["format"] == 1
+    assert ColdStore(narrow).contains("customer", 20)  # as before
+    assert ColdStore(narrow, key_bits=32).keys_count == 2
+    with pytest.raises(ColdStoreKeyWidthError, match="key_bits=32.*"
+                       "key_bits=64"):
+        ColdStore(narrow, key_bits=64)
+    cw = ColdStore(wide, key_bits=64)
+    cw.append("terminal", np.asarray([WIDE_A], np.uint64), *_rows(4, 1))
+    cw.flush()
+    with pytest.raises(ColdStoreKeyWidthError, match="key_bits=64.*"
+                       "key_bits=32"):
+        ColdStore(wide)
+    # nothing was moved aside by the refusals
+    assert sorted(p.name for p in (tmp_path / "n").iterdir()) == [
+        "seg-00000000.json", "seg-00000000.npz"]
+    assert ColdStore(wide, key_bits=64).contains("terminal", WIDE_A)
+    with pytest.raises(ValueError, match="key_bits must be 32 or 64"):
+        ColdStore(str(tmp_path / "x"), key_bits=16)

@@ -459,7 +459,7 @@ def _built_state(cfg, customers, terminals, seed=0):
     placed = {}
     for table, held in (("customer", customers), ("terminal", terminals)):
         keys = jnp.asarray(np.fromiter(held, np.uint32, len(held)))
-        kd, slot, adm, _ = admit_slots(
+        kd, slot, adm, _, _ = admit_slots(
             getattr(st, f"{table}_dir"), keys, jnp.ones(len(held), bool),
             n_probes=cfg.keydir_probes)
         assert np.asarray(adm).all()
@@ -718,7 +718,7 @@ def test_a_vacated_probe_prefix_still_resolves_after_the_pass():
     st2, rec = _assert_pass_equals_oracle(st, NOW, cfg)
     assert tuple(rec) == (30, 30)
     keys = jnp.asarray(np.fromiter(held, np.uint32, len(held)))
-    kd, slot, adm, _ = admit_slots(st2.customer_dir, keys,
+    kd, slot, adm, _, _ = admit_slots(st2.customer_dir, keys,
                                    jnp.ones(len(held), bool), n_probes=16)
     assert np.asarray(adm).all()
     before = st.customer.tables()

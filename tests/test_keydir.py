@@ -8,6 +8,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from real_time_fraud_detection_system_tpu.core.batch import (
+    join_key,
+    split_key,
+)
 from real_time_fraud_detection_system_tpu.ops.keydir import (
     EMPTY_KEY,
     KeyDirectory,
@@ -20,14 +24,49 @@ from real_time_fraud_detection_system_tpu.ops.keydir import (
 )
 
 
+def wide_id(k):
+    """Test key k as a 64-bit id: keys 2j and 2j + 1 are FOLD TWINS —
+    different words, the same xor of them — so every case that runs at
+    width 64 runs on pairs a 32-bit deployment would merge."""
+    j, b = divmod(int(k), 2)
+    hi = 0x10 + b * 0x3039
+    fold = (j * 2654435761 + 1) & 0xFFFFFFFF
+    return (hi << 32) | (fold ^ hi)
+
+
+def _keys(keys, width=32):
+    """The device's key column for test keys: uint32 ``[n]`` as they
+    stand, or their :func:`wide_id` s split words-first, ``[2, n]``."""
+    if width == 32:
+        return jnp.asarray(np.asarray(keys, np.uint32))
+    ids = np.asarray([wide_id(k) for k in np.asarray(keys).tolist()],
+                     np.uint64)
+    return jnp.asarray(split_key(ids.view(np.int64)))
+
+
+def _stored(kd):
+    """The keys of the occupied entries, as the directory holds them."""
+    live = np.asarray(kd.slots) >= 0
+    if kd.wide:
+        return join_key(np.stack([np.asarray(kd.keys_lo)[live],
+                                  np.asarray(kd.keys_hi)[live]]))
+    return np.asarray(kd.keys)[live]
+
+
+@pytest.fixture(params=[32, 64])
+def width(request):
+    return request.param
+
+
 def _admit(kd, keys, valid=None):
-    k = jnp.asarray(np.asarray(keys, np.uint32))
-    v = jnp.ones(k.shape, bool) if valid is None else jnp.asarray(valid)
+    k = _keys(keys, 64 if kd.wide else 32)
+    v = (jnp.ones(k.shape[-1], bool) if valid is None
+         else jnp.asarray(valid))
     return admit_slots(kd, k, v)[:3]
 
 
-def test_admit_assigns_unique_slots_and_coalesces_duplicates():
-    kd = init_keydir(64, 16)
+def test_admit_assigns_unique_slots_and_coalesces_duplicates(width):
+    kd = init_keydir(64, 16, width)
     kd, slot, adm = _admit(kd, [5, 5, 7, 9, 5, 11])
     slot, adm = np.asarray(slot), np.asarray(adm)
     assert adm.all()
@@ -37,8 +76,8 @@ def test_admit_assigns_unique_slots_and_coalesces_duplicates():
     assert int(occupied_slots(kd)) == 4
 
 
-def test_admit_is_stable_across_batches():
-    kd = init_keydir(64, 16)
+def test_admit_is_stable_across_batches(width):
+    kd = init_keydir(64, 16, width)
     kd, s1, _ = _admit(kd, [100, 200, 300])
     kd, s2, adm = _admit(kd, [300, 100, 200])
     np.testing.assert_array_equal(
@@ -47,8 +86,8 @@ def test_admit_is_stable_across_batches():
     assert int(occupied_slots(kd)) == 3  # no double-allocation
 
 
-def test_admission_bounded_by_free_list_then_recovers():
-    kd = init_keydir(64, 8)
+def test_admission_bounded_by_free_list_then_recovers(width):
+    kd = init_keydir(64, 8, width)
     kd, _, adm = _admit(kd, np.arange(12))
     # exactly slot_capacity keys admitted; the rest overflow gracefully
     assert int(np.asarray(adm).sum()) == 8
@@ -64,21 +103,19 @@ def test_admission_bounded_by_free_list_then_recovers():
     assert np.asarray(adm3).all()
 
 
-def test_invalid_rows_never_place():
-    kd = init_keydir(64, 16)
+def test_invalid_rows_never_place(width):
+    kd = init_keydir(64, 16, width)
     kd, slot, adm = _admit(kd, [1, 2, 3], valid=[True, False, True])
     assert not bool(np.asarray(adm)[1])
     assert int(occupied_slots(kd)) == 2
-    _, hit = lookup_slots(kd, jnp.asarray(np.uint32(2))[None],
-                          jnp.ones(1, bool))
+    _, hit = lookup_slots(kd, _keys([2], width), jnp.ones(1, bool))
     assert not bool(hit[0])
 
 
-def test_lookup_is_read_only_and_exact():
-    kd = init_keydir(64, 16)
-    kd, slot, _ = _admit(kd, [42, 43])
-    got, hit = lookup_slots(kd, jnp.asarray(np.array([43, 42, 44],
-                                                     np.uint32)),
+def test_lookup_is_read_only_and_exact(width):
+    kd = init_keydir(64, 16, width)
+    kd, slot, _ = _admit(kd, [42, 43])  # fold twins at width 64
+    got, hit = lookup_slots(kd, _keys([43, 42, 44], width),
                             jnp.ones(3, bool))
     hit = np.asarray(hit)
     assert bool(hit[0]) and bool(hit[1]) and not bool(hit[2])
@@ -88,20 +125,18 @@ def test_lookup_is_read_only_and_exact():
     assert int(occupied_slots(kd)) == 2
 
 
-def test_reclaim_frees_entries_and_slots_consistently():
-    kd = init_keydir(64, 16)
+def test_reclaim_frees_entries_and_slots_consistently(width):
+    kd = init_keydir(64, 16, width)
     kd, slot, _ = _admit(kd, [1, 2, 3, 4])
     # vacate exactly key 2's entry
     target = int(np.asarray(slot)[1])
     dead_entry = np.asarray(kd.slots) == target
     kd, dead, n = reclaim_entries(kd, jnp.asarray(dead_entry))
     assert int(n) == 1 and int(occupied_slots(kd)) == 3
-    _, hit = lookup_slots(kd, jnp.asarray(np.array([2], np.uint32)),
-                          jnp.ones(1, bool))
+    _, hit = lookup_slots(kd, _keys([2], width), jnp.ones(1, bool))
     assert not bool(hit[0])
-    # the other keys are untouched
-    got, hit = lookup_slots(kd, jnp.asarray(np.array([1, 3, 4],
-                                                     np.uint32)),
+    # the other keys are untouched (3 is 2's fold twin at width 64)
+    got, hit = lookup_slots(kd, _keys([1, 3, 4], width),
                             jnp.ones(3, bool))
     assert np.asarray(hit).all()
     # the freed slot is re-grantable
@@ -109,14 +144,16 @@ def test_reclaim_frees_entries_and_slots_consistently():
     assert bool(np.asarray(adm)[0])
 
 
-def test_readmission_survives_probe_prefix_vacancy():
+def test_readmission_survives_probe_prefix_vacancy(width):
     """Review-pass regression: reclaiming an entry that sits on a LIVE
     key's probe-path prefix must not make re-admission duplicate the key
     (claim the vacancy, pop a fresh slot, reset its history). The insert
     path must look up the FULL probe depth before claiming anything."""
-    kd = init_keydir(64, 32)
+    kd = init_keydir(64, 32, width)
     rng = np.random.default_rng(11)
     keys = rng.integers(0, 10_000, 24).astype(np.uint32)
+    if width == 64:  # half the keys come with their fold twin
+        keys[12:] = keys[:12] ^ 1
     kd, slot0, adm0 = _admit(kd, keys)
     assert np.asarray(adm0).all()
     occ0 = int(occupied_slots(kd))
@@ -139,7 +176,7 @@ def test_readmission_survives_probe_prefix_vacancy():
             assert s1 == slots_by_key[k], \
                 f"live key {k} moved {slots_by_key[k]} -> {s1}"
     # every key owns exactly ONE directory entry (no duplicates)
-    stored = np.asarray(kd.keys)[np.asarray(kd.slots) >= 0]
+    stored = _stored(kd)
     assert len(stored) == len(np.unique(stored))
     assert int(occupied_slots(kd)) == occ0
 
@@ -157,28 +194,28 @@ def test_sentinel_key_is_remapped_not_lost():
                       == np.uint32(0xFFFFFFFF))
 
 
-def test_admit_under_jit_matches_eager():
-    kd_e = init_keydir(128, 32)
-    kd_j = init_keydir(128, 32)
+def test_admit_under_jit_matches_eager(width):
+    kd_e = init_keydir(128, 32, width)
+    kd_j = init_keydir(128, 32, width)
     rng = np.random.default_rng(3)
     jitted = jax.jit(admit_slots, static_argnames="n_probes")
     for _ in range(4):
         keys = rng.integers(0, 200, 64).astype(np.uint32)
         kd_e, s_e, a_e = _admit(kd_e, keys)
-        kd_j, s_j, a_j, _ = jitted(kd_j, jnp.asarray(keys),
-                                   jnp.ones(64, bool))
+        kd_j, s_j, a_j, *_ = jitted(kd_j, _keys(keys, width),
+                                    jnp.ones(64, bool))
         np.testing.assert_array_equal(np.asarray(s_e), np.asarray(s_j))
         np.testing.assert_array_equal(np.asarray(a_e), np.asarray(a_j))
-    np.testing.assert_array_equal(np.asarray(kd_e.keys),
-                                  np.asarray(kd_j.keys))
+    for a, b in zip(jax.tree.leaves(kd_e), jax.tree.leaves(kd_j)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("n_keys,slot_cap", [(500, 512), (2000, 256)])
-def test_admission_exactness_property(n_keys, slot_cap):
+def test_admission_exactness_property(n_keys, slot_cap, width):
     """Random stream: every admitted key maps to a UNIQUE slot; the
     mapping is a function (same key → same slot, always); occupancy
     equals the number of distinct admitted keys."""
-    kd = init_keydir(2 * 1024, slot_cap)
+    kd = init_keydir(2 * 1024, slot_cap, width)
     rng = np.random.default_rng(7)
     seen = {}
     for _ in range(12):
@@ -194,6 +231,11 @@ def test_admission_exactness_property(n_keys, slot_cap):
     slots = list(seen.values())
     assert len(set(slots)) == len(slots) <= slot_cap
     assert int(occupied_slots(kd)) == len(seen)
+    # a key never gets two entries, and the directory names it whole
+    stored = _stored(kd)
+    assert len(np.unique(stored)) == len(stored) == len(seen)
+    if width == 64:
+        assert set(stored.tolist()) == {wide_id(k) for k in seen}
 
 
 @pytest.mark.parametrize("n_probes", [2, 4, 8])
@@ -216,8 +258,8 @@ def test_keys_that_miss_admission_follow_load_and_probe_depth(load,
     kd = init_keydir(dir_cap, dir_cap // 2)  # the free stack never runs dry
     missed = 0
     for i in range(0, n_keys, batch):
-        kd, _, adm, _ = admit(kd, jnp.asarray(keys[i:i + batch]),
-                              jnp.ones(batch, bool), n_probes=n_probes)
+        kd, _, adm, *_ = admit(kd, jnp.asarray(keys[i:i + batch]),
+                               jnp.ones(batch, bool), n_probes=n_probes)
         missed += batch - int(np.asarray(adm).sum())
     assert int(occupied_slots(kd)) == n_keys - missed
     expected = dir_cap * load ** (n_probes + 1) / (n_probes + 1)
@@ -295,7 +337,7 @@ def test_claim_rounds_as_a_loop_equal_the_unrolled_rounds_bit_for_bit(
     for step in range(12):
         keys = jnp.asarray(rng.integers(0, 400, 128).astype(np.uint32))
         valid = jnp.asarray(rng.random(128) < 0.9)
-        kd_a, slot_a, adm_a, _ = loop(kd_a, keys, valid, n_probes=n_probes)
+        kd_a, slot_a, adm_a, *_ = loop(kd_a, keys, valid, n_probes=n_probes)
         kd_b, slot_b, adm_b = plain(kd_b, keys, valid, n_probes=n_probes)
         ran_dry = ran_dry or int(kd_a.free_top) == 0
         if step % 4 == 3:  # vacate a third of the live entries
@@ -454,7 +496,7 @@ def test_claim_rounds_end_when_every_row_is_placed(case):
     loop = jax.jit(admit_slots, static_argnames="n_probes")
     plain = jax.jit(_admit_slots_unrolled, static_argnames="n_probes")
 
-    *got, ran = loop(kd, key, ok, n_probes=P)
+    *got, ran, _ = loop(kd, key, ok, n_probes=P)
     want = plain(kd, key, ok, n_probes=P)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
@@ -463,8 +505,188 @@ def test_claim_rounds_end_when_every_row_is_placed(case):
     assert holds(got[0], np.asarray(got[1]), np.asarray(got[2]))
 
     admitted = bool(np.asarray(got[2])[np.asarray(ok)].all())
-    *again, ran_again = loop(got[0], key, ok, n_probes=P)
+    *again, ran_again, _ = loop(got[0], key, ok, n_probes=P)
     want_again = plain(want[0], key, ok, n_probes=P)
     for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(want_again)):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
     assert int(ran_again) == (0 if admitted else rounds)
+
+
+# -- 64-bit keys: two ids are one key only if all 64 bits agree (PR 41) -----
+
+
+def _ids64(ids):
+    """int64 / uint64 ids as they come → the device's ``[2, n]`` column."""
+    return jnp.asarray(split_key(
+        np.asarray(ids, dtype=np.uint64).view(np.int64)))
+
+
+def _admit64(kd, ids, valid=None, n_probes=16):
+    v = jnp.ones(len(ids), bool) if valid is None else jnp.asarray(valid)
+    kd, slot, adm, rounds, alias = admit_slots(kd, _ids64(ids), v, n_probes)
+    return kd, np.asarray(slot), np.asarray(adm), int(rounds), np.asarray(
+        alias)
+
+
+def _twins(n, seed=0):
+    """n pairs of ids of card-number width whose words xor alike."""
+    rng = np.random.default_rng(seed)
+    a = rng.integers(10 ** 15, 10 ** 16, n).astype(np.uint64)
+    m = rng.integers(1, 1 << 20, n).astype(np.uint64)
+    b = a ^ m ^ (m << np.uint64(32))
+    return a, b
+
+
+def test_fold_twins_get_two_slots_and_keep_them_through_a_reclaim():
+    from real_time_fraud_detection_system_tpu.core.batch import fold_key
+
+    a, b = _twins(20, seed=1)
+    assert (fold_key(a.view(np.int64)) == fold_key(b.view(np.int64))).all()
+    kd = init_keydir(256, 128, 64)
+    kd, slot_a, adm, _, alias = _admit64(kd, a)
+    assert adm.all() and alias[0] == 0  # nothing to mistake them for yet
+    kd, slot_b, adm, _, alias = _admit64(kd, b)
+    # every twin met its sibling under its fingerprint: one trip struck
+    # the sibling's entry and left nothing to verify, so they were claimed
+    assert adm.all() and alias[0] == 20 and alias[1] == 1
+    # now both are resident: the second of a pair looks twice
+    _, _, _, _, alias = _admit64(kd, b)
+    assert alias[0] == 20 and alias[1] == 2
+    assert len(set(slot_a) | set(slot_b)) == 40
+    # vacate the FIRST-admitted sibling of every other pair: it sits on
+    # the probe-path prefix of the second, which must keep its own entry
+    gone = np.zeros(256, bool)
+    gone[np.isin(np.asarray(kd.slots), slot_a[::2])] = True
+    kd, _, n = reclaim_entries(kd, jnp.asarray(gone))
+    assert int(n) == 10
+    kd, again, adm, rounds, _ = _admit64(kd, b)
+    np.testing.assert_array_equal(again, slot_b)
+    assert adm.all() and rounds == 0 and int(occupied_slots(kd)) == 30
+    _, hit = lookup_slots(kd, _ids64(a), jnp.ones(20, bool), 16)
+    np.testing.assert_array_equal(np.asarray(hit), np.arange(20) % 2 == 1)
+    stored = _stored(kd)
+    assert len(np.unique(stored)) == len(stored) == 30
+
+
+@pytest.mark.parametrize("order", ["ab", "ba"])
+def test_fold_twins_racing_in_one_batch_end_as_sequential_insertion(order):
+    """Both NEW, in one batch, with duplicates of each: the rounds elect
+    both at one position (one fingerprint), the grant's owner names the
+    entry, the other is unplaced again and takes a second pass. What
+    comes out — who is admitted, one slot a key, the directory's
+    contents — is what one-at-a-time insertion gives."""
+    a, b = _twins(6, seed=2)
+    first, second = (a, b) if order == "ab" else (b, a)
+    batch = np.concatenate([first, second, first[:3], second[3:]])
+    kd, slot, adm, rounds, _ = _admit64(init_keydir(128, 64, 64), batch)
+    assert adm.all()
+    by_id = {}
+    for i, s in zip(batch.tolist(), slot.tolist()):
+        assert by_id.setdefault(i, s) == s  # duplicates share the slot
+    assert len(set(by_id.values())) == len(by_id) == 12
+    assert int(occupied_slots(kd)) == 12
+    seq = init_keydir(128, 64, 64)
+    for i in batch:
+        seq, _, ok, _, _ = _admit64(seq, [i])
+        assert ok.all()
+    assert int(occupied_slots(seq)) == 12
+    np.testing.assert_array_equal(np.sort(_stored(kd)), np.sort(_stored(seq)))
+    np.testing.assert_array_equal(np.sort(_stored(kd)),
+                                  np.sort(np.concatenate([a, b])))
+    # and they are found again, every one at its own slot
+    got, hit = lookup_slots(kd, _ids64(batch), jnp.ones(len(batch), bool),
+                            16)
+    assert np.asarray(hit).all()
+    np.testing.assert_array_equal(np.asarray(got), slot)
+
+
+def test_three_new_ids_of_one_fingerprint_take_three_passes():
+    rng = np.random.default_rng(3)
+    base = np.uint64(rng.integers(10 ** 15, 10 ** 16))
+    trio = np.asarray([base ^ np.uint64(m) ^ (np.uint64(m) << np.uint64(32))
+                       for m in (0, 5, 9)], np.uint64)
+    kd, slot, adm, rounds, _ = _admit64(init_keydir(64, 32, 64), trio)
+    assert adm.all() and len(set(slot.tolist())) == 3
+    assert rounds == 1 + 2 + 3  # each pass walks past the entries before
+    np.testing.assert_array_equal(np.sort(_stored(kd)), np.sort(trio))
+
+
+def test_more_ids_of_one_fingerprint_than_probes_miss_and_never_share():
+    rng = np.random.default_rng(4)
+    base = np.uint64(rng.integers(10 ** 15, 10 ** 16))
+    ids = np.asarray([base ^ np.uint64(m) ^ (np.uint64(m) << np.uint64(32))
+                      for m in range(6)], np.uint64)
+    kd, slot, adm, _, _ = _admit64(init_keydir(64, 32, 64), ids, n_probes=4)
+    # one probe path of 4 positions: four of the six are resident, the
+    # other two are the sketch tier's, and no two share a slot
+    assert adm.sum() == 4 and len(set(slot[adm].tolist())) == 4
+    assert int(occupied_slots(kd)) == 4
+    kd, slot2, adm2, _, _ = _admit64(kd, ids, n_probes=4)
+    np.testing.assert_array_equal(adm2, adm)
+    np.testing.assert_array_equal(slot2[adm], slot[adm])
+
+
+BOUNDARY_IDS = [
+    0, 1, 1 << 32, (1 << 32) + 1,
+    0xFFFFFFFE, (1 << 32) - 1,  # 0xFFFFFFFF: folds to the vacant marker
+    0x00000001_FFFFFFFE,  # folds to 0xFFFFFFFF too: an alias of the last
+    0x00000001_FFFFFFFF,  # folds to 0xFFFFFFFE: what _canon maps it to
+    0xFFFFFFFF_00000000,  # its words xor to the vacant marker as well
+    (1 << 63) - 1, 1 << 63,  # int64 max, int64 min read as uint64
+    0xFFFFFFFF_FFFFFFFE,  # int64 -2: the reserved pattern's neighbour
+    0xFFFFFFFE_FFFFFFFF,
+]
+RESERVED_ID = 0xFFFFFFFF_FFFFFFFF  # int64 -1
+
+
+def test_boundary_ids_are_distinct_keys_and_the_reserved_one_is_refused():
+    ids = np.asarray(BOUNDARY_IDS + [RESERVED_ID], np.uint64)
+    # negative int64s are their bit patterns
+    as_int64 = ids.view(np.int64)
+    assert as_int64[-1] == -1 and as_int64[-3] == -2
+    np.testing.assert_array_equal(join_key(split_key(as_int64)), ids)
+    kd, slot, adm, _, _ = _admit64(init_keydir(128, 64, 64), ids)
+    assert adm[:-1].all() and not adm[-1]  # refused, not merged unseen
+    assert len(set(slot[:-1].tolist())) == len(BOUNDARY_IDS)
+    np.testing.assert_array_equal(np.sort(_stored(kd)),
+                                  np.sort(ids[:-1]))
+    # the stored fingerprints never hold the vacant marker
+    live = np.asarray(kd.slots) >= 0
+    assert not (np.asarray(kd.keys)[live] == EMPTY_KEY).any()
+    # again, one at a time and all at once: the same slots, no new entry
+    kd2, slot2, adm2, rounds, _ = _admit64(kd, ids)
+    np.testing.assert_array_equal(slot2, slot)
+    np.testing.assert_array_equal(adm2, adm)
+    assert rounds == 0 and int(occupied_slots(kd2)) == len(BOUNDARY_IDS)
+    got, hit = lookup_slots(kd, _ids64(ids), jnp.ones(len(ids), bool), 16)
+    np.testing.assert_array_equal(np.asarray(hit), adm)
+    np.testing.assert_array_equal(np.asarray(got)[:-1], slot[:-1])
+    # a 32-bit directory merges what the fold merges: fewer slots
+    from real_time_fraud_detection_system_tpu.core.batch import fold_key
+
+    narrow = init_keydir(128, 64)
+    narrow, _, _, _, none = admit_slots(
+        narrow, jnp.asarray(fold_key(as_int64)), jnp.ones(len(ids), bool),
+        n_probes=16)
+    assert none is None  # a one-word directory has no alias to count
+    assert int(occupied_slots(narrow)) < len(BOUNDARY_IDS)
+
+
+def test_the_32_bit_directory_is_the_parents_pytree():
+    """At ``key_bits=32`` the directory has the parent's four leaves,
+    shapes and dtypes; the two key words exist at 64 only."""
+    kd = init_keydir(64, 16)
+    leaves = jax.tree.leaves(kd)
+    assert [(leaf.shape, str(leaf.dtype)) for leaf in leaves] == [
+        ((64,), "uint32"), ((64,), "int32"), ((16,), "int32"),
+        ((), "int32")]
+    assert kd.keys_lo is None and kd.keys_hi is None and not kd.wide
+    kd = admit_slots(kd, jnp.arange(8, dtype=jnp.uint32),
+                     jnp.ones(8, bool))[0]
+    assert len(jax.tree.leaves(kd)) == 4
+    assert len(jax.tree.leaves(reclaim_entries(
+        kd, jnp.ones(64, bool))[0])) == 4
+    wide = init_keydir(64, 16, 64)
+    assert [(leaf.shape, str(leaf.dtype))
+            for leaf in jax.tree.leaves(wide)[4:]] == [
+        ((64,), "uint32")] * 2

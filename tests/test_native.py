@@ -241,9 +241,12 @@ def test_hostprep_latest_wins_matches_numpy_fuzz():
             latest_wins_mask_np(tx, ts))
 
 
-def test_hostprep_pack_rows_bitexact_fuzz():
-    """C++ fused pack ≡ make_batch + pack_batch bit-for-bit (key folds,
-    floor day/tod split, cents→f32, labels, zero padding)."""
+@pytest.mark.parametrize("key_bits", [32, 64])
+def test_hostprep_pack_rows_bitexact_fuzz(key_bits):
+    """C++ fused pack ≡ make_batch + pack_batch bit-for-bit (key folds —
+    at ``key_bits=64`` the two words of every id, negative ids as their
+    bit patterns — floor day/tod split, cents→f32, labels, zero
+    padding)."""
     from real_time_fraud_detection_system_tpu.core import native
     from real_time_fraud_detection_system_tpu.core.batch import (
         make_batch,
@@ -256,14 +259,16 @@ def test_hostprep_pack_rows_bitexact_fuzz():
     for trial in range(20):
         n = int(rng.integers(1, 3000))
         dt = rng.integers(0, 2**45, n)
-        cu = rng.integers(0, 2**63 - 1, n)
+        cu = rng.integers(-2**63, 2**63 - 1, n)
         te = rng.integers(0, 2**63 - 1, n)
+        cu[:3] = (-1, 0, 2**32)[:min(3, n)]
         am = rng.integers(0, 10**9, n)
         lab = rng.integers(-1, 2, n) if trial % 2 else None
         pad = int(n + rng.integers(0, 64))
         ref = pack_batch(make_batch(cu, te, dt, am, label=lab,
-                                    pad_to=pad))
-        got = native.pack_rows(dt, cu, te, am, lab, pad)
+                                    pad_to=pad, key_bits=key_bits))
+        got = native.pack_rows(dt, cu, te, am, lab, pad, key_bits)
+        assert got.shape == (9 if key_bits == 64 else 7, pad)
         np.testing.assert_array_equal(got, ref, err_msg=f"trial {trial}")
 
 

@@ -855,7 +855,7 @@ def test_sharded_exact_claims_like_the_fixed_rounds_behind_the_exchange(
 
     def fixed_rounds(kd, key, valid, n_probes):
         return _admit_slots_unrolled(kd, key, valid, n_probes) + (
-            jnp.int32(n_probes),)
+            jnp.int32(n_probes), None)
 
     params, scaler = _model()
     for caps in (dict(), dict(cust_cap=64, term_cap=64)):

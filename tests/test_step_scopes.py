@@ -64,9 +64,9 @@ from test_tpu_compile import (  # noqa: E402 (pytest adds tests/ to path)
 
 PKG = "real_time_fraud_detection_system_tpu"
 TABLE = {"customer", "terminal"}
-# `relayout` is in the vocabulary and in no variant: the window columns
-# are stored flat, the layout `update_windows` works in, so the step has
-# nothing to open it around (benchmark/metrics/step_relayout_ms.* read 0)
+# `relayout` left the vocabulary with the stage it named: the window
+# columns are stored flat, the layout `update_windows` works in (PR 25),
+# and the metric files that read it went in PR 40
 UPDATE = {"update", "stamp", "reset", "scatter"}
 QUERY = {"query", "gather", "sum"}
 COMMON = {"unpack", "assemble", "scale", "classify"} | TABLE | UPDATE | QUERY
@@ -82,6 +82,12 @@ VARIANTS = {
     "logreg": ("logreg", {}, {}, 0, COMMON),
     "exact": ("logreg", {"key_mode": "exact", "compact_every": 4}, {}, 0,
               COMMON | {"keydir", "cms"} | KEYDIR_PARTS),
+    # key_bits=64: the same stages, the same names — the lookup's verify
+    # loop under lookup, the owner's key words under grant, and a further
+    # pass's rounds and grant under claim and grant (ops/keydir.admit_wide)
+    "exact64": ("logreg", {"key_mode": "exact", "compact_every": 4,
+                           "key_bits": 64}, {}, 0,
+                COMMON | {"keydir", "cms"} | KEYDIR_PARTS),
     "cms": ("logreg", {"customer_source": "cms"}, {}, 0,
             COMMON | {"cms"}),
     "selective": ("forest", {}, {"emit_threshold": 0.4}, 0,
@@ -178,7 +184,7 @@ def test_step_hlo_carries_the_variants_scopes(variant):
         assert not _column_passes_outside_reset(said_text, column_sizes)
         compiled = _op_names(low.compile().as_text())
         kept = {s for n in compiled for s in _scopes(n)}
-        assert "relayout" in STEP_SCOPES and "relayout" not in said
+        assert "relayout" not in STEP_SCOPES and "relayout" not in said
         # the compiler keeps the names on what it keeps of the ops
         assert (want - {"unpack"}) <= kept, sorted(want - kept)
         updates = [_scopes(n) for n in compiled if "rtfds.update" in n]
@@ -200,14 +206,15 @@ def test_step_hlo_carries_the_variants_scopes(variant):
             assert paths == chip, sorted(paths ^ chip)
 
 
-def test_exact_key_path_names_its_parts_under_their_table():
-    """``key_mode="exact"``: every op of ``admit_slots`` sits under
-    ``<table>/rtfds.keydir/<part>`` with one of the three parts (the
+@pytest.mark.parametrize("variant", ["exact", "exact64"])
+def test_exact_key_path_names_its_parts_under_their_table(variant):
+    """``key_mode="exact"``, at either key width: every op of
+    ``admit_slots`` sits under ``<table>/rtfds.keydir/<part>`` with one of the three parts (the
     benchmark's ``step_keydir_{lookup,claim,grant}_ms`` add up to
     ``step_keydir_ms``), the sketch's update AND its query sit under
     ``<table>/rtfds.cms``, and the compaction — a program of its own —
     carries ``rtfds.compact`` on everything it names."""
-    eng = _engine("exact")
+    eng = _engine(variant)
     (low,) = _lowered_steps(eng)
     paths = [_scopes(n) for n in _op_names(low.compile().as_text())]
     keydir = [p for p in paths if "keydir" in p]
@@ -227,6 +234,16 @@ def test_exact_key_path_names_its_parts_under_their_table():
     # the query's gathers as well as the update's scatter-adds
     assert any(n.endswith("gather") for _, n in cms)
     assert any("scatter" in n.rsplit("/", 1)[-1] for _, n in cms)
+    if variant == "exact64":
+        # the admit's further passes (a loop beside rtfds.keydir): their
+        # rounds and their grant carry the two names side by side, as a
+        # reader of "rtfds.keydir/rtfds.<part>" needs them
+        later = [n for _, n in said if "/while/body/rtfds.keydir/" in n]
+        for part in ("claim", "grant"):
+            assert any(f"/while/body/rtfds.keydir/rtfds.{part}/" in n
+                       for n in later), part
+        assert all(re.search(r"rtfds\.keydir/rtfds\.(lookup|claim|grant)(/|$)",
+                             n) for p, n in said if "keydir" in p)
     (sig,) = [s for s in eng.dispatch_inventory() if s.variant == "compact"]
     compact = eng.signature_step(sig).lower(*eng.signature_templates(sig))
     named = [_scopes(n) for n in _op_names(
@@ -244,8 +261,10 @@ def test_exact_key_path_names_its_parts_under_their_table():
     assert all(_scopes(n)[:1] == ["compact"] for _, n in flow), flow
 
 
-@pytest.mark.parametrize("n_dev", [0, 2], ids=["one-chip", "mesh"])
-def test_cold_tier_programs_name_their_stages_as_siblings(n_dev, tmp_path):
+@pytest.mark.parametrize("n_dev,key_bits", [(0, 32), (2, 32), (0, 64)],
+                         ids=["one-chip", "mesh", "one-chip-64"])
+def test_cold_tier_programs_name_their_stages_as_siblings(n_dev, key_bits,
+                                                          tmp_path):
     """With the cold store armed the compaction gains the demote pass
     and the engine a family of promote programs. Every named op of the
     compaction sits under ``rtfds.compact`` OR ``rtfds.demote`` — never
@@ -256,7 +275,8 @@ def test_cold_tier_programs_name_their_stages_as_siblings(n_dev, tmp_path):
     no device time is read twice. Without the store the compaction names
     ``rtfds.compact`` alone, as before."""
     feat = {"key_mode": "exact", "compact_every": 4, "keydir_probes": 16,
-            "cold_store": str(tmp_path / "cold"), "cold_demote_slots": 16}
+            "cold_store": str(tmp_path / "cold"), "cold_demote_slots": 16,
+            "key_bits": key_bits}
     VARIANTS["_cold"] = ("logreg", feat, {}, n_dev, set())
     try:
         eng = _engine("_cold")
@@ -272,8 +292,13 @@ def test_cold_tier_programs_name_their_stages_as_siblings(n_dev, tmp_path):
             assert paths and {s for p in paths for s in p} <= set(
                 STEP_SCOPES)
             if not n_dev:  # (shard_map's own squeezes carry no stage)
-                assert not [n for n in names if n.startswith("jit(")
-                            and "/" in n and not _scopes(n)]
+                bare = [n for n in names if n.startswith("jit(")
+                        and "/" in n and not _scopes(n)]
+                # key_bits=64: the loop of the admit's further passes
+                # stands beside rtfds.keydir — the `while` alone; its
+                # condition and body name keydir/claim and keydir/grant
+                assert set(bare) <= ({"jit(promote)/while"}
+                                     if key_bits == 64 else set()), bare
             text = low.compile().as_text()
             kept = [_scopes(n) for n in _op_names(text)]
             by_variant.setdefault(sig.variant, []).append((paths, kept))

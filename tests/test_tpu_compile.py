@@ -477,14 +477,16 @@ def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
 EXACT_ROWS = 65536
 
 
-def _compiled_exact(cache, one_chip, variant, rows=EXACT_ROWS, cold=False):
+def _compiled_exact(cache, one_chip, variant, rows=EXACT_ROWS, cold=False,
+                    key_bits=32):
     """``key_mode="exact"`` at the size of the benchmark's
     ``forest-rf100-d8-exact`` (2^22 + 2^23 slots, directories of twice
     that, 16 probes, sketches at their defaults): the ``rows``-row step
     or the ``("compact",)`` program of the engine itself, compiled for
     one v5e → (features config, compiled). ``cold``: with
     ``forest-rf100-d8-cold``'s tier armed (a pass every 4 batches that
-    demotes up to 131,072 keys a table over a fifth of the slots)."""
+    demotes up to 131,072 keys a table over a fifth of the slots).
+    ``key_bits=64``: ``forest-rf100-d8-id64``'s width."""
     from real_time_fraud_detection_system_tpu.config import (
         Config,
         FeatureConfig,
@@ -498,8 +500,9 @@ def _compiled_exact(cache, one_chip, variant, rows=EXACT_ROWS, cold=False):
         else dict(compact_every=64)
     fcfg = FeatureConfig(customer_capacity=1 << 22,
                          terminal_capacity=1 << 23, key_mode="exact",
-                         keydir_probes=16, **tier)
-    if ("exact", variant, rows, cold) not in cache:
+                         keydir_probes=16, key_bits=key_bits, **tier)
+    key = ("exact", variant, rows, cold, key_bits)
+    if key not in cache:
         eng = ScoringEngine(
             Config(features=fcfg, runtime=RuntimeConfig(
                 z_mode="int8", batch_buckets=(rows,), max_batch_rows=rows)),
@@ -509,9 +512,9 @@ def _compiled_exact(cache, one_chip, variant, rows=EXACT_ROWS, cold=False):
             feature_state=_on(one_chip, _state_shapes(fcfg)))
         (sig,) = [s for s in eng.dispatch_inventory()
                   if s.variant == variant]
-        cache["exact", variant, rows, cold] = eng.signature_step(sig).lower(
+        cache[key] = eng.signature_step(sig).lower(
             *_on(one_chip, eng.signature_templates(sig))).compile()
-    return fcfg, cache["exact", variant, rows, cold]
+    return fcfg, cache[key]
 
 
 @pytest.mark.parametrize("variant", ["step", "compact"])
@@ -671,3 +674,43 @@ def test_exact_step_runs_its_claim_rounds_while_a_row_is_unplaced(
                   if re.search(
                       rf"= \w+\[{dir_cap}\]\S* copy(-start)?\(", ln)]
         assert not copies, copies[:2]
+
+
+@pytest.mark.parametrize("variant", ["step", "compact"])
+def test_wide_key_programs_fit_the_chip_at_the_benchmarks_size(
+        topo, one_chip, as_on_chip, compiled_steps, variant):
+    """``key_bits=64`` at ``forest-rf100-d8-id64``'s size: the step and
+    the compaction compile for one v5e with their state — 8 bytes a
+    directory entry more than the 32-bit deployment's — donated and
+    updated in place, and the loops the wide admit adds (the lookup's
+    verify trips, the passes after the first) copy no directory-sized
+    array a trip: the owner's key words are scattered into the leaves
+    they live in."""
+    fcfg, compiled = _compiled_exact(compiled_steps, one_chip, variant,
+                                     key_bits=64)
+    mem = compiled.memory_analysis()
+    state = 8_610_906_440  # features/online.state_bytes
+    assert mem.argument_size_in_bytes >= state
+    assert mem.alias_size_in_bytes >= state
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.7e9
+    if variant == "compact":
+        assert mem.temp_size_in_bytes <= 4_363_527_680
+        return
+    text = compiled.as_text()
+    bodies = _computations(text)
+    keydir = _loops_under(text, "rtfds.keydir")
+    # a table: the verify loop, the first pass's rounds and the rounds of
+    # a further pass under rtfds.keydir; the loop of further passes
+    # beside it, under the table alone (its body names keydir/<part>)
+    assert len(keydir) == 6, [op for op, _, _ in keydir]
+    passes = [loop for loop in _loops_under(text, "rtfds.")
+              if re.search(r"rtfds\.(customer|terminal)/while$", loop[0])]
+    assert len(passes) == 2, [op for op, _, _ in passes]
+    for op, cond, body in keydir + passes:
+        inside = _reached_from(bodies, (cond, body)).values()
+        for dir_cap in (2 * fcfg.customer_capacity,
+                        2 * fcfg.terminal_capacity):
+            copies = [ln for c in inside for ln in c.splitlines()
+                      if re.search(
+                          rf"= \w+\[{dir_cap}\]\S* copy(-start)?\(", ln)]
+            assert not copies, (op, copies[:2])

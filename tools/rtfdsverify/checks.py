@@ -68,7 +68,8 @@ class AotCoverageCheck:
                           "precompile() would silently skip one variant"))
         # Reachable keys, derived INDEPENDENTLY from the dispatch-site
         # contract (engine.py::_start_batch keys on ("step", 7, pad)
-        # with pad from core.batch.bucket_size; the sharded engine on
+        # with pad from core.batch.bucket_size — 9 rows at key_bits=64,
+        # both words of the two keys; the sharded engine on
         # ("sharded", routed)) — the inventory must cover them, and the
         # derivation deliberately does NOT call dispatch_inventory(), so
         # a drifted enumeration cannot vacuously agree with itself.
@@ -77,8 +78,10 @@ class AotCoverageCheck:
             expected = {("sharded", False), ("sharded", True)} \
                 if eng.kind != "sequence" else set()
         else:
+            rows = 9 if getattr(eng.cfg.features, "key_bits", 32) == 64 \
+                else 7
             expected = {
-                ("step", 7, int(b))
+                ("step", rows, int(b))
                 for b in sorted(set(eng.cfg.runtime.batch_buckets))
             }
         fcfg = eng.cfg.features
