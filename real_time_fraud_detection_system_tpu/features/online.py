@@ -74,10 +74,15 @@ class FeatureState(NamedTuple):
 # day stamps and counts, as ``update_windows``' keywords. Decided here and
 # nowhere else, from the 15-feature spec: customer features are count and
 # average amount, terminal features count and risk (the fraud share). The
-# set is fixed for a table's life: the update neither scatters into nor
-# resets an unmaintained column (a table-wide pass a step and a ~6 ms
-# scatter saved, each), so customer ``fraud`` and terminal ``amount`` stay
-# the zeros ``init_window_state`` made and no feature may read them. Every
+# set is fixed for a table's life: the update neither reads nor writes an
+# unmaintained column (a gather and a write saved, each: 2.9 ms for the
+# customer table's, 4.8 for the terminal table's at the benchmark's size
+# and 65,536 rows, PERF.md, PR 43), so customer ``fraud`` and terminal
+# ``amount`` stay the zeros ``init_window_state`` made and no feature may
+# read them. It also decides how the update orders a bucket's in-batch
+# additions: the table that keeps a dollar sum sorts its batch by
+# (bucket, lane), the other by the bucket alone — ``fraud`` is 0/1
+# (:func:`fraud_of`) and counts are integers, exact in any order. Every
 # update of either table, one chip (below) and sharded
 # (``parallel/step.py``), passes these; late labels
 # (:func:`apply_feedback_at_slot`) write terminal ``fraud``, a column its

@@ -35,32 +35,49 @@ The reference's pandas ``rolling('Nd')`` is a trailing wall-clock window;
 day-granular buckets are the streaming-friendly approximation, and training
 uses the SAME kernel via replay, so there is zero train/serve skew.
 
-Updates are O(B) scatters plus a lazy reset that is a pass over the table;
-queries are O(B × max_window) gathers — fully vectorized, jit/shard_map
-friendly, no data-dependent shapes.
+Updates follow the batch: it is merged by bucket first, and the program
+reads and writes a column at the batch's buckets only — it makes no pass
+over a table; queries are O(B × max_window) gathers — fully vectorized,
+jit/shard_map friendly, no data-dependent shapes.
 
-Cost of :func:`update_windows` on a v5e (PERF.md, PR 29). What follows the
-batch: ~6 ms a scatter of 65,536 rows, whatever the table's size (one for
-the stamps, one a maintained column). What follows the table: the reset,
-which is written table-wide — set the old stamps aside (4 bytes a bucket
-read + 4 written), reset the first maintained column where the stamp
-advanced (old stamps, new stamps and the column read, the column and a
-1-byte mask written: 12 + 5), reset the second from the mask (5 + 4):
-**34 bytes a bucket** for a table that maintains two aggregate columns,
-counted from the operand and result shapes of the step compiled for the
-chip (``tests/test_tpu_compile.py`` holds it there). The passes run at
-~675 of the chip's 819 GB/s (25.4 ms a step for 2^22 + 2^23 slots × 40
-buckets), so their time is their bytes: an unmaintained column is not
-touched at all, and the stamps make one round trip beside the reset, not
-two (until PR 29: 50 bytes, 37.3 ms). The chip's compiler keeps the two
-resets apart, with the mask between them, however the selects are
-written; one fused pass would be 32. A reset that follows the batch
-instead — gather the old stamps at the batch's buckets, scatter zeros
-into those that advance, a scatter a maintained column — costs ~12.5 ms
-a table at 65,536 rows whatever its size, against 34 bytes × buckets ÷
-675 GB/s table-wide: 8.5 ms at 2^22 slots, 16.9 ms at 2^23. By that
-arithmetic the two meet near 6 M slots × 40 buckets; the batch-following
-form has not been measured (ROADMAP A1).
+Cost of :func:`update_windows` on a v5e (PERF.md, PR 43; the figures of
+the forms are the refused PR 42's builder's, its ledger lines the cells':
+65,536 rows, the 2^22-slot customer and the 2^23-slot terminal table, 40
+buckets: 24.5 ms a step for both, where combining into the table — stamps
+scatter-maxed, a table-wide compare and reset, scatter-adds — was 63.2):
+
+- *The merge, 0.4 ms a table.* One ``lax.sort`` of the bucket indices
+  with the lane beside them runs in 0.03 ms; what a sort costs is its
+  compile for the chip, which grows with its keys and with what it
+  carries (the whole forest step at 65,536 rows: the table-wide form 6.7
+  s, one key unstable 7.9, (bucket, lane) as two keys unstable 11.5, one
+  key stable 14.2; a carried operand ~6 s each). So nothing is carried —
+  the payload is taken by the permutation (:func:`_picker`, 0.15 ms a
+  column) — and only the table that keeps a dollar sum sorts by two
+  keys. The run totals are two loops of dense steps, 0.04 ms each
+  (:func:`_run_totals`); as ``segment_max`` / ``segment_sum`` into
+  ``[rows]`` arrays they were 0.57 ms each.
+- *The old values, 0.55 ms a column and table* (:func:`_picker`),
+  whatever the table's size: 1.7 ms a table.
+- *The writes: 2.3 ms into a 168 M-element column, 4.3 into a
+  335 M-element one*, three a table — 19.7 of the 24.5 ms. ``set`` on
+  indices marked sorted is the chip's sorted scatter, and that streams
+  its operand through: 0.32 ms + 12.75 ps an element (8 bytes at ~680
+  GB/s), a pass inside the fusion, whatever the number of updates. Its
+  other scatter — what an index vector that promises nothing gets, the
+  table-wide form's — takes 89.6 ns an update whatever the operand: 5.9
+  ms at 65,536 rows, and ``unique_indices`` alone changes nothing. The
+  update takes the cheaper of the two from its shapes
+  (:func:`_sorted_write_pays`): sorted at 65,536 rows, plain at 16,384
+  and below.
+
+What the update and the query cost the host: each is traced once a table
+and batch bucket, ten times in ``engine.precompile()``, so their helpers
+are written in ``lax`` and share what they can (the update: 72 equations
+a trace; on this sandbox's CPU 8 ms of tracing and 22 of lowering where
+``jnp`` indexing, ``.at[].set`` and ``fori_loop`` made it 30 + 37, and
+the table-wide form was 7 + 12; :meth:`WindowState.rows`: 294 equations
+and 53 ms where it was 420 and 110).
 """
 
 from __future__ import annotations
@@ -73,11 +90,19 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from real_time_fraud_detection_system_tpu.ops.numerics import sum_fixed_order
 from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
 COLUMNS = ("bucket_day", "count", "amount", "fraud")
+# whole rows of a column's ``[n/128, 128]`` view; single elements of a
+# flat column
+_ROW_TAKE = lax.GatherDimensionNumbers(
+    offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,))
+_ELEMENT_WRITE = lax.ScatterDimensionNumbers(
+    update_window_dims=(), inserted_window_dims=(0,),
+    scatter_dims_to_operand_dims=(0,))
 
 
 @partial(jax.tree_util.register_dataclass, data_fields=list(COLUMNS),
@@ -141,19 +166,36 @@ class WindowState:
         lanes = math.gcd(n, 128)
         step = math.gcd(nb, lanes)  # a slot starts at a multiple of this
         n_take = -(-(lanes - step + nb) // lanes)
+        b = int(slot.shape[0])
         start = slot.astype(jnp.int32) * nb
         row = start // lanes
         lane = start - row * lanes
-        last = n // lanes - 1
+        # What every column shares is built once, in ``lax``: the step is
+        # traced for every batch bucket (``engine.precompile``), and four
+        # columns × 15 start lanes of ``jnp`` indexing and ``where`` were
+        # 420 equations and four fifths of what a step costs to lower
+        # (PERF.md, PR 43). The program is the same.
+        n_rows = n // lanes
+        takes = []
+        for i in range(n_take):
+            at = jnp.minimum(row + i, n_rows - 1)
+            at = lax.select(lax.lt(at, np.int32(0)),
+                            lax.add(at, np.int32(n_rows)), at)
+            takes.append(lax.reshape(at, (b, 1)))
+        starts_at = {
+            k: lax.broadcast_in_dim(lax.eq(lane, np.int32(k)), (b, nb), (0,))
+            for k in range(step, lanes, step)}
 
         def take(col):
-            view = col.reshape(-1, lanes)
-            win = jnp.concatenate(
-                [view[jnp.minimum(row + i, last)] for i in range(n_take)],
-                axis=1)
-            out = win[:, :nb]
-            for k in range(step, lanes, step):
-                out = jnp.where((lane == k)[:, None], win[:, k:k + nb], out)
+            view = lax.reshape(col, (n_rows, lanes))
+            win = lax.concatenate([
+                lax.gather(view, at, _ROW_TAKE, (1, lanes),
+                           mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+                for at in takes], 1)
+            out = lax.slice(win, (0, 0), (b, nb))
+            for k, here in starts_at.items():
+                out = lax.select(here, lax.slice(win, (0, k), (b, k + nb)),
+                                 out)
             return out
 
         return tuple(take(c) for c in self.columns())
@@ -217,6 +259,124 @@ def init_window_state(capacity: int, n_buckets: int,
     return jax.jit(empty, out_shardings=sharding)()
 
 
+def _sorted_write_pays(rows: int, n: int) -> bool:
+    """Whether ``rows`` updates on sorted indices go into a column of
+    ``n`` elements faster as the chip's sorted scatter than as its plain
+    one — the one decision of :func:`update_windows` that follows sizes,
+    taken at trace time from the two shapes it has. On a v5e (PERF.md,
+    PR 43, measured by PR 42's builder on ``.set`` into float32 and int32
+    columns): a scatter whose indices are marked sorted streams its
+    operand through, 0.32 ms + 12.75 ps an operand element whatever the
+    number of updates (2.30 ms into 167.8 M elements, 4.28 into 335.5 M);
+    one that promises nothing takes 89.6 ns an update whatever the
+    operand (5.87 ms at 65,536 rows). Sorted at 65,536 rows for both of
+    the benchmark's tables; plain at 16,384 rows and below, where the
+    passes would cost a 256-row batch 19.7 ms; plain into a column past
+    ~460 M elements at any batch the engine pads to."""
+    return 0.32e-3 + 12.75e-12 * n < 89.6e-9 * rows
+
+
+def _picker(idx: jnp.ndarray, n: int):
+    """``pick(col) = col[idx]`` for flat columns of ``n`` elements and
+    ``idx`` int32 [B] in ``[0, n]`` (one past the end reads as zero:
+    callers drop those lanes): a take of whole rows of the
+    ``[n/128, 128]`` view and a select of the lane, the gather the chip's
+    compiler emits well (:meth:`WindowState.rows`). On a v5e, 65,536
+    elements: 0.55 ms from a 335 M-element column where the element
+    gather takes 0.80-0.93, 0.15 from a ``[65,536]`` array where it takes
+    0.56 (PERF.md, PR 43). The row numbers and the lane mask are built
+    once for every column picked at ``idx``, in ``lax``: the update is
+    traced ten times a process (``engine.precompile``), and what it costs
+    there is its number of equations."""
+    rows = int(idx.shape[0])
+    lanes = math.gcd(n, 128)
+    row = lax.min(lax.div(idx, np.int32(lanes)), np.int32(n // lanes - 1))
+    hit = lax.eq(
+        lax.broadcast_in_dim(lax.sub(idx, lax.mul(row, np.int32(lanes))),
+                             (rows, lanes), (0,)),
+        lax.broadcasted_iota(jnp.int32, (rows, lanes), 1))
+    row = lax.reshape(row, (rows, 1))
+
+    def pick(col):
+        taken = lax.gather(lax.reshape(col, (n // lanes, lanes)), row,
+                           _ROW_TAKE, (1, lanes),
+                           mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+        return lax.reduce_sum(
+            lax.select(hit, taken, lax.full_like(taken, 0)), (1,))
+
+    return pick
+
+
+def _run_totals(first: jnp.ndarray, day: jnp.ndarray,
+                values: Tuple[jnp.ndarray, ...]):
+    """For rows sorted by bucket, ``first`` flagging each run's first
+    lane: at every lane of a run the run's newest day, and over its rows
+    of that day their number and the sum of each of ``values`` — the
+    rows that can count (:func:`update_windows`).
+
+    Two doubling loops of log2(rows) dense steps, written in ``lax``
+    (every op keeps the stage's scope, and the loops' bodies are most of
+    what tracing the update costs): forward, a lane gathers the lanes
+    from its run's first to itself, 1, 2, 4, ... back at a time (a lane
+    stops looking back once its window holds the run's first lane);
+    backward, every lane takes what its run's last lane gathered. A
+    run's additions happen in the order this tree gives them, a function
+    of the run's own offsets: the same bits on every run, whatever else
+    the batch holds. 0.04 ms a loop at 65,536 rows on a v5e, where one
+    ``segment_sum`` into a ``[rows]`` array is 0.57 and
+    ``lax.associative_scan`` 0.33 (and four times the loop's compile;
+    PERF.md, PR 43). Never differences of a batch-wide prefix sum: dollar
+    amounts would cancel."""
+    rows = int(day.shape[0])
+    steps = (rows - 1).bit_length()
+    zero = lax.full((rows,), 0.0, jnp.float32)
+
+    def seen_from(xs, start):
+        """Lane ``l`` sees lane ``l + start`` of every x, around the end."""
+        return [lax.dynamic_slice(lax.concatenate([x, x], 0), (start,),
+                                  (rows,), allow_negative_indices=False)
+                for x in xs]
+
+    def gather_back(i, carry):
+        whole, newest, *sums = carry
+        d = lax.shift_left(np.int32(1), i)
+        whole_a, newest_a, *sums_a = seen_from(
+            carry, lax.sub(np.int32(rows), d))
+        top = lax.max(newest_a, newest)
+        keep_a = lax.bitwise_and(lax.eq(newest_a, top),
+                                 lax.bitwise_not(whole))
+        keep = lax.bitwise_or(lax.eq(newest, top), whole)
+        return (lax.bitwise_or(whole, whole_a),
+                lax.select(whole, newest, top),
+                *(lax.add(lax.select(keep_a, a, zero),
+                          lax.select(keep, b, zero))
+                  for a, b in zip(sums_a, sums)))
+
+    def take_from_last(i, carry):
+        done, *totals = carry
+        done_b, *totals_b = seen_from(carry, lax.shift_left(np.int32(1), i))
+        return (lax.bitwise_or(done, done_b),
+                *(lax.select(done, t, t_b)
+                  for t, t_b in zip(totals, totals_b)))
+
+    def loop(body, carry):
+        """``fori_loop(0, steps, body, carry)`` as the ``while`` it ends
+        as: through ``scan`` the body is traced a second time when the
+        program is lowered."""
+        def step(at):
+            return lax.add(at[0], np.int32(1)), tuple(body(*at))
+        return lax.while_loop(lambda at: lax.lt(at[0], np.int32(steps)),
+                              step, (np.int32(0), tuple(carry)))[1]
+
+    _, *totals = loop(gather_back, (
+        first, day, lax.full((rows,), 1.0, jnp.float32), *values))
+    # a run's last lane is the one before a first lane; lane rows - 1
+    # sees lane 0, which is one
+    (last,) = seen_from((first,), np.int32(1 % rows))
+    _, newest, on_it, *sums = loop(take_from_last, (last, *totals))
+    return newest, on_it, sums
+
+
 def update_windows(
     state: WindowState,
     slot: jnp.ndarray,  # int32 [B] in [0, capacity)
@@ -227,76 +387,116 @@ def update_windows(
     track_amount: bool = True,
     track_fraud: bool = True,
 ) -> WindowState:
-    """Scatter one micro-batch into the ring buffers.
+    """Merge one micro-batch into the ring buffers.
 
     Semantics: a bucket is (lazily) reset the first time a *newer* day maps
     onto it; rows older than what a bucket currently holds are dropped
     (bounded-lateness policy — the ring holds n_buckets days of history).
-    Duplicate (slot, day) rows within the batch accumulate correctly
-    (jnp scatter-add applies all duplicates).
+    Of two days that share a bucket inside one batch only the newer
+    counts. Duplicate (slot, day) rows within the batch accumulate. A row
+    that is not ``valid`` counts nowhere. Stamps only grow.
 
     ``track_amount`` / ``track_fraud`` say which aggregate columns the
     table MAINTAINS (day stamps and counts always are). That set is fixed
     for a table's life and decided in one place per key space
     (``features/online.CUSTOMER_COLUMNS`` / ``TERMINAL_COLUMNS``: the
     15-feature spec reads customer (count, amount) and terminal (count,
-    fraud) only). An unmaintained column is not touched: no scatter, no
-    reset — it leaves the step as the donated buffer it came in as and
+    fraud) only). An unmaintained column is not touched: no gather, no
+    write — it leaves the step as the donated buffer it came in as and
     stays what :func:`init_window_state` made it, so no feature may read
     it, and a table whose flags changed mid-life would mix days in it.
 
-    What the update costs follows two things (module docstring, "Cost").
-    The batch: a scatter of 65,536 rows is ~6.2 ms a column on a v5e
-    whatever the table's size (ledger, PR 28: ``step_scatter_ms`` 25.5
-    for four, ``step_stamp_ms`` 12.0 for two). The table: the reset is a
-    pass over every bucket, 34 bytes a bucket with two maintained
-    columns.
+    The batch is merged first and a column is touched at the batch's
+    buckets only (module docstring, "Cost"): rows sorted by bucket, a run
+    of equal buckets reduced to its newest day ``m``, its rows of that day
+    and their sums — a row of an older day than ``m`` can never count, and
+    the rows of ``m`` count iff ``m`` is at least the bucket's stamp — so a
+    bucket's final value is ``where(advanced, 0, old) + the run's total``
+    from one gather of the old value, written once with a ``set``: every
+    lane of a run writes the run's one final value, so duplicate indices
+    are harmless, and the indices are sorted, which the write says where
+    the chip's sorted scatter is the faster one
+    (:func:`_sorted_write_pays`).
+
+    The order of a bucket's in-batch additions is a fixed tree over the
+    bucket's rows (:func:`_run_totals`) in the order the sort left them.
+    Only a float sum cares — counts and 0/1 ``fraud`` labels are small
+    integers, exact in any order — so a table that maintains ``amount``
+    sorts by (bucket, lane): a bucket's rows stay in batch order, the same
+    bits on every run, whatever else the batch holds and however slots
+    are numbered (key modes that number slots differently agree to the
+    bit) — not the sequential sum in batch order. A table that does not
+    sorts by the bucket alone, which compiles faster for the chip (module
+    docstring); its columns are the same bits under any permutation of
+    the batch's rows only while ``fraud`` holds integers.
     """
     nb = state.n_buckets
+    n, rows = int(state.bucket_day.shape[0]), int(slot.shape[0])
+    if rows == 0:
+        return state
+    # the sums the table maintains beside its counts, and what a row adds
+    added = {}
+    if track_amount:
+        added["amount"] = amount
+    if track_fraud:
+        added["fraud"] = fraud
+    maintained = ("count", *added)
+    sorted_write = _sorted_write_pays(rows, n)
     with step_scope("update"):
-        bucket = jnp.remainder(day, nb)
-        flat = (slot * nb + bucket).astype(jnp.int32)
-        # invalid rows stamp -1 which never wins
-        day_in = jnp.where(valid, day, -1).astype(jnp.int32)
-        bd, count, amt, frd = state.columns()
+        with step_scope("merge"):
+            # A row that is not valid takes the key past the end: it sorts
+            # last, into a run whose writes are dropped.
+            flat = jnp.where(valid, slot * nb + jnp.remainder(day, nb),
+                             n).astype(jnp.int32)
+            flat, order = lax.sort(
+                (flat, lax.iota(jnp.int32, rows)),
+                num_keys=2 if track_amount else 1, is_stable=False)
+            first = lax.concatenate(
+                [lax.full((1,), True),
+                 lax.ne(lax.slice(flat, (1,), (rows,)),
+                        lax.slice(flat, (0,), (rows - 1,)))], 0)
+            # the payload is taken by the permutation, not carried
+            # through the sort: a carried operand is ~6 s of the chip's
+            # compile and nothing at run time
+            from_batch = _picker(order, rows)
+            newest, on_it, sums = _run_totals(
+                first, from_batch(day.astype(jnp.int32)),
+                tuple(from_batch(v) for v in added.values()))
+            totals = {"count": on_it, **dict(zip(added, sums))}
+            at = lax.reshape(flat, (rows, 1))
 
-        with step_scope("reset"):
-            # The stamps as they were, set aside in ONE pass before the
-            # scatter-max overwrites them: the reset compares old with
-            # new. Written as a clamp at the empty stamp (a no-op: stamps
-            # are >= -1) behind a barrier so that it is a pass of its
-            # own, ahead of the scatter. Left to itself the compiler
-            # reads the old stamps from the donated buffer, scatters in a
-            # copy of it and copies the result back: two table passes.
-            old_bd = jax.lax.optimization_barrier(jnp.maximum(bd, -1))
+        def write(col, value):
+            return lax.scatter(col, at, value, _ELEMENT_WRITE,
+                               indices_are_sorted=sorted_write, mode="drop")
 
         # Day stamp each touched bucket with max(existing, incoming), in
         # the donated buffer.
         with step_scope("stamp"):
-            new_bd = bd.at[flat].max(day_in)
+            from_table = _picker(flat, n)
+            old_bd = from_table(state.bucket_day)
+            new_bd = lax.max(old_bd, newest)
+            bd = write(state.bucket_day, new_bd)
 
-        # Buckets whose stamp advanced hold a stale (older) day: reset
-        # the aggregates the table maintains.
+        # A bucket whose stamp advanced holds a stale (older) day: the
+        # aggregates the table maintains start again from zero.
         with step_scope("reset"):
-            advanced = new_bd > old_bd
-            count = jnp.where(advanced, 0.0, count)
-            if track_amount:
-                amt = jnp.where(advanced, 0.0, amt)
-            if track_fraud:
-                frd = jnp.where(advanced, 0.0, frd)
+            kept = lax.le(new_bd, old_bd)
+            zero = lax.full((rows,), 0.0, jnp.float32)
+            base = {name: lax.select(kept, from_table(getattr(state, name)),
+                                     zero)
+                    for name in maintained}
 
         with step_scope("scatter"):
-            # A row contributes only if its day is the bucket's (possibly
-            # new) stamp.
-            fresh = valid & (day_in == new_bd[flat])
-            w = fresh.astype(jnp.float32)
-            count = count.at[flat].add(w)
-            if track_amount:
-                amt = amt.at[flat].add(amount * w)
-            if track_fraud:
-                frd = frd.at[flat].add(fraud * w)
+            # The rows of the run's newest day count iff that day is the
+            # bucket's (possibly new) stamp.
+            counts = lax.eq(newest, new_bd)
+            written = {
+                name: write(getattr(state, name),
+                            lax.add(base[name],
+                                    lax.select(counts, totals[name], zero)))
+                for name in maintained}
 
-        return WindowState(new_bd, count, amt, frd, n_buckets=nb)
+        return dataclasses.replace(state, bucket_day=bd, **written)
 
 
 def gather_state_rows(

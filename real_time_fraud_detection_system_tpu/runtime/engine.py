@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import copy
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional
 
@@ -160,6 +161,11 @@ class ColdPromoteError(RuntimeError):
 
 
 PROMOTE_LANES_MAX = 16384
+
+# ``precompile()``'s compiles run beside its lowering and each other: an
+# inventory is 5 programs (10-11 with a directory's compaction and a cold
+# tier's promotes), and the hosts have 13 cores a chip.
+_COMPILE_THREADS = 4
 
 
 def blank_lanes(shape: tuple, rows: tuple, key_bits: int = 32) -> list:
@@ -1537,20 +1543,41 @@ class ScoringEngine:
         # arrays once so runtime calls match the AOT signature.
         self.state.params = jax.tree.map(jnp.asarray, self.state.params)
         self._aot_params_sig = self._params_sig(self.state.params)
-        done = []
-        with self.tracer.span("precompile"):
-            for sig in self.dispatch_inventory():
-                if sig.key in self._aot:
-                    continue
-                self._aot[sig.key] = self.signature_step(sig).lower(
-                    *self.signature_templates(sig)).compile()
-                self._m_precompiled.inc()
-                done.append(sig.bucket)
+        done = self._compile_signatures(self.dispatch_inventory())
         return {
-            "buckets": done,
+            "buckets": [sig.bucket for sig in done],
             "variants": 1,
             "seconds": round(time.perf_counter() - t0, 3),
         }
+
+    def _compile_signatures(self, inventory) -> list:
+        """``.lower(...).compile()`` every signature of ``inventory``
+        that has no executable yet, and return those. The lowering is
+        Python and runs here, one signature after another; each lowered
+        program's ``compile()`` — XLA's, or the persistent cache's read,
+        both of which release the interpreter lock — goes to a small
+        pool, so it runs beside the next signature's lowering and beside
+        the other compiles. Last of the inventory first: it lists the
+        batch buckets in rising order and a program's compile grows with
+        its rows, so the longest compile starts first. The same programs
+        under the same keys, taken in the inventory's order, as one after
+        another; a compile that raises is raised from here, and the
+        compiles not yet started are dropped."""
+        todo = [sig for sig in inventory if sig.key not in self._aot]
+        pool = ThreadPoolExecutor(max_workers=_COMPILE_THREADS,
+                                  thread_name_prefix="rtfds-compile")
+        try:
+            with self.tracer.span("precompile"):
+                compiling = {
+                    sig.key: pool.submit(self.signature_step(sig).lower(
+                        *self.signature_templates(sig)).compile)
+                    for sig in reversed(todo)}
+                for sig in todo:
+                    self._aot[sig.key] = compiling[sig.key].result()
+                    self._m_precompiled.inc()
+        finally:
+            pool.shutdown(wait=True, cancel_futures=True)
+        return todo
 
     def _note_params_swap(self, params):
         """Hot-reload hook: keep AOT serving only while the swapped-in

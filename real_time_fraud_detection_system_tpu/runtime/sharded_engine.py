@@ -802,16 +802,7 @@ class ShardedScoringEngine(ScoringEngine):
         self._ensure_sharded()
         self.state.params = jax.tree.map(jnp.asarray, self.state.params)
         self._aot_params_sig = self._params_sig(self.state.params)
-        variants = 0
-        with self.tracer.span("precompile"):
-            for sig in inventory:
-                if sig.key in self._aot:
-                    continue
-                step = self.signature_step(sig)
-                self._aot[sig.key] = step.lower(
-                    *self.signature_templates(sig)).compile()
-                self._m_precompiled.inc()
-                variants += 1
+        variants = len(self._compile_signatures(inventory))
         return {
             "buckets": sorted({s.bucket for s in inventory}),
             "variants": variants,

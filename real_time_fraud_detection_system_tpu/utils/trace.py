@@ -493,7 +493,7 @@ def current_ids() -> Tuple[str, int]:
 STEP_SCOPES = (
     "unpack",                                      # engine.step
     "customer", "terminal",                        # which table
-    "update", "stamp", "reset", "scatter",         # ops/windows
+    "update", "merge", "stamp", "reset", "scatter",  # ops/windows
     "query", "gather", "sum",                      # ops/windows
     "keydir", "cms",                               # ops/keydir, ops/cms
     "lookup", "claim", "grant",                    # the parts of keydir

@@ -9,6 +9,7 @@ pins the equivalence at runtime too.
 """
 
 import dataclasses as dc
+import re
 
 import numpy as np
 import pytest
@@ -77,6 +78,88 @@ def test_single_engine_inventory_matches_precompile(z_mode, selective):
     eng.precompile()
     assert reg.get("rtfds_precompiled_steps_total").value - before \
         == len(inv)
+
+
+def test_precompile_compiles_on_its_pool_what_one_after_another_would(
+        monkeypatch):
+    """``precompile()`` lowers on the calling thread and compiles on its
+    pool: the same programs under the same ``_aot`` keys, counted once
+    each, as ``lower().compile()`` one signature after another."""
+    import threading
+
+    import jax
+
+    reg = MetricsRegistry()
+    cfg = _cfg(z_mode="int8")
+    cfg = dc.replace(cfg, runtime=dc.replace(
+        cfg.runtime, batch_buckets=(16, 32, 64, 128, 256)))
+    eng = ScoringEngine(cfg, "forest", _forest_params(), _scaler(),
+                        metrics=reg)
+    one_by_one = ScoringEngine(cfg, "forest", _forest_params(), _scaler(),
+                               metrics=MetricsRegistry())
+    inv = eng.dispatch_inventory()
+    assert len(inv) == 5
+    one_by_one.state.params = jax.tree.map(jax.numpy.asarray,
+                                           one_by_one.state.params)
+
+    def program(compiled):
+        """The compiled computations without the call stacks that
+        lowered them (this test's are not ``precompile()``'s)."""
+        text = re.sub(r" stack_frame_id=\d+", "", compiled.as_text())
+        return re.split(r"\n(?=%|ENTRY )", text, maxsplit=1)[1]
+
+    want = {sig.key: program(one_by_one.signature_step(sig).lower(
+        *one_by_one.signature_templates(sig)).compile())
+        for sig in one_by_one.dispatch_inventory()}
+
+    compiled_on = []
+    compile_ = jax.stages.Lowered.compile
+
+    def compile_and_note(self, *a, **kw):
+        compiled_on.append(threading.current_thread().name)
+        return compile_(self, *a, **kw)
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", compile_and_note)
+    man = eng.precompile()
+    assert man["buckets"] == [16, 32, 64, 128, 256]
+    assert len(compiled_on) == 5
+    assert all(n.startswith("rtfds-compile") for n in compiled_on)
+    assert list(eng._aot) == [sig.key for sig in inv]
+    assert reg.get("rtfds_precompiled_steps_total").value == 5
+    assert {k: program(c) for k, c in eng._aot.items()} == want
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("rtfds-compile")]
+
+
+def test_precompile_raises_what_a_compile_raises(monkeypatch):
+    """A compile that raises on the pool is raised from ``precompile()``;
+    the signatures before it keep their executables, as they would one
+    after another, and the pool's threads are gone."""
+    import threading
+
+    import jax
+
+    reg = MetricsRegistry()
+    eng = ScoringEngine(_cfg(), "forest", _forest_params(), _scaler(),
+                        metrics=reg)
+    compile_ = jax.stages.Lowered.compile
+
+    def refuse_256(self, *a, **kw):
+        if "tensor<7x256xi32>" in self.as_text():  # the packed batch
+            raise RuntimeError("the compiler refuses this one")
+        return compile_(self, *a, **kw)
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", refuse_256)
+    with pytest.raises(RuntimeError, match="refuses this one"):
+        eng.precompile()
+    assert list(eng._aot) == [("step", 7, 64)]
+    assert reg.get("rtfds_precompiled_steps_total").value == 1
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("rtfds-compile")]
+    monkeypatch.setattr(jax.stages.Lowered, "compile", compile_)
+    eng.precompile()  # takes up where it stopped
+    assert sorted(eng._aot) == [("step", 7, 64), ("step", 7, 256)]
+    assert reg.get("rtfds_precompiled_steps_total").value == 2
 
 
 def test_sharded_engine_inventory_matches_precompile():

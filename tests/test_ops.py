@@ -1,5 +1,7 @@
 """Op-level tests: window ring buffers vs brute force, CMS bounds, dedup."""
 
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -340,6 +342,254 @@ def test_flat_state_matches_tables_oracle(case):
                             windows, delay=delay)
         for g, w in zip(got, oracle.query(slot, day, windows, delay)):
             np.testing.assert_array_equal(np.asarray(g), w)
+
+
+def _table_wide_update(state, slot, day, amount, fraud, valid,
+                       track_amount=True, track_fraud=True):
+    """``update_windows`` as it was written until PR 43, kept as the
+    oracle of the batch-merged form: combine INTO the table — scatter-max
+    the stamps, compare old and new stamps over the whole table, zero
+    what advanced, scatter-add."""
+    from real_time_fraud_detection_system_tpu.ops.windows import WindowState
+
+    nb = state.n_buckets
+    flat = (slot * nb + jnp.remainder(day, nb)).astype(jnp.int32)
+    day_in = jnp.where(valid, day, -1).astype(jnp.int32)
+    bd, count, amt, frd = state.columns()
+    new_bd = bd.at[flat].max(day_in)
+    advanced = new_bd > bd
+    w = (valid & (day_in == new_bd[flat])).astype(jnp.float32)
+    count = jnp.where(advanced, 0.0, count).at[flat].add(w)
+    if track_amount:
+        amt = jnp.where(advanced, 0.0, amt).at[flat].add(amount * w)
+    if track_fraud:
+        frd = jnp.where(advanced, 0.0, frd).at[flat].add(fraud * w)
+    return WindowState(new_bd, count, amt, frd, n_buckets=nb)
+
+
+def _random_batches(n, rows, cap, seed=7):
+    """Days rolling over the ring, rows older than their bucket, a hot
+    slot's duplicates, a fifth of the rows invalid; dollars and cents."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for i in range(n):
+        slot = rng.integers(0, cap, rows).astype(np.int32)
+        slot[rng.random(rows) < 0.15] = cap - 1
+        out.append((
+            slot, (100 + 3 * i + rng.integers(-10, 3, rows)).astype(np.int32),
+            rng.uniform(1, 500, rows).round(2).astype(np.float32),
+            (rng.random(rows) < 0.3).astype(np.float32),
+            rng.random(rows) < 0.8))
+    return out
+
+
+_CAP = 16
+# case → batches, applied in turn; cents in the amounts, so that the order
+# of a bucket's additions shows wherever a bucket takes two rows
+_MERGED_UPDATE_CASES = {
+    "duplicates_of_one_slot_and_day": [
+        _rows((3, 100, 5.25, 1, True), (3, 100, 7.1, 0, True),
+              (3, 100, 2.3, 1, True), (9, 100, 4.7, 0, True),
+              (3, 101, 1.9, 0, True)),
+        _rows((3, 100, 3.3, 0, True), (3, 100, 3.3, 1, True))],
+    "two_days_share_a_bucket_in_one_batch": [
+        _rows((2, 100, 5.5, 0, True), (2, 108, 7.7, 1, True),
+              (5, 100, 2.2, 0, True), (2, 108, 1.1, 0, True),
+              (2, 116, 9.9, 1, True), (2, 100, 3.3, 1, True))],
+    "a_row_older_than_its_buckets_stamp": [
+        _rows((4, 108, 5.5, 0, True), (6, 107, 2.2, 1, True)),
+        _rows((4, 100, 9.9, 1, True), (6, 99, 9.9, 1, True),
+              (4, 108, 1.1, 1, True), (6, 106, 4.4, 0, True))],
+    "one_bucket_advances_and_one_does_not": [
+        _rows((2, 100, 5.5, 1, True), (5, 103, 2.2, 0, True),
+              (7, 101, 6.6, 1, True)),
+        _rows((2, 108, 7.7, 0, True), (5, 103, 3.3, 1, True),
+              (7, 101, 1.1, 0, True), (7, 109, 8.8, 1, True),
+              (7, 101, 4.4, 1, True))],
+    "invalid_rows": [
+        _rows((1, 100, 5.5, 1, True), (1, 100, 9.9, 1, False),
+              (1, 108, 9.9, 1, False), (7, 101, 9.9, 0, False)),
+        _rows((1, 116, 9.9, 1, False), (1, 100, 2.2, 0, True))],
+    "an_all_invalid_batch": [
+        _rows((1, 100, 5.5, 1, True), (2, 101, 1.1, 0, True)),
+        _rows((1, 108, 9.9, 1, False), (2, 101, 9.9, 1, False),
+              (3, 102, 9.9, 0, False))],
+    "a_batch_of_one_run": [
+        _rows((6, 100, 1.1, 1, True)),
+        _rows(*[(6, 108, 0.1 * (i + 1), i % 2, True) for i in range(7)])],
+    "the_last_slot": [
+        _rows((_CAP - 1, 100 + _NB - 1, 5.5, 1, True),
+              (_CAP - 1, 100 + _NB - 1, 2.2, 0, True), (0, 96, 1.1, 1, True)),
+        _rows((_CAP - 1, 100 + 2 * _NB - 1, 3.3, 1, True),
+              (_CAP - 1, 100, 4.4, 0, True), (_CAP - 1, 100, 6.6, 0, False))],
+    "padded_rows": [
+        # what core.batch pads a bucket with: slot 0, day 0, not valid
+        _rows((0, 100, 5.5, 1, True), (3, 101, 2.2, 0, True),
+              *[(0, 0, 0.0, 0, False)] * 5),
+        _rows((0, 100, 1.1, 0, True), *[(0, 0, 0.0, 0, False)] * 7)],
+    "a_batch_of_no_rows": [
+        _rows((1, 100, 5.5, 1, True), (2, 101, 1.1, 0, True)),
+        tuple(np.zeros(0, dt) for dt in (np.int32, np.int32, np.float32,
+                                         np.float32, bool)),
+        _rows((1, 100, 2.2, 0, True))],
+    "random_batches": _random_batches(10, 96, _CAP),
+}
+_COLUMN_SETS = {
+    "customer": {"track_amount": True, "track_fraud": False},
+    "terminal": {"track_amount": False, "track_fraud": True},
+    "all_three": {},
+}
+
+
+@pytest.mark.parametrize("write", ["sorted", "plain"])
+@pytest.mark.parametrize("jitted", [True, False], ids=["jit", "eager"])
+@pytest.mark.parametrize("columns", sorted(_COLUMN_SETS))
+@pytest.mark.parametrize("case", sorted(_MERGED_UPDATE_CASES))
+def test_merged_update_matches_the_table_wide_form(case, columns, jitted,
+                                                   write, monkeypatch):
+    """The update merges its batch first and touches the table at the
+    batch's buckets only; the table-wide form it replaced is the oracle.
+    Stamps, counts and fraud sums — integers — to the bit after every
+    batch; a dollar sum to the bit where its bucket took one row of the
+    batch, else within a rounding an addition (the additions of a bucket's
+    rows run in another order), and to the bit between two runs of one
+    batch; an unmaintained column stays the buffer's bytes. Under both
+    forms of the column write, which the update chooses between from its
+    shapes (``_sorted_write_pays``)."""
+    import jax
+
+    from real_time_fraud_detection_system_tpu.ops import windows
+
+    monkeypatch.setattr(windows, "_sorted_write_pays",
+                        lambda rows, n: write == "sorted")
+    kw = _COLUMN_SETS[columns]
+    update = lambda st, *cols: update_windows(st, *cols, **kw)  # noqa: E731
+    if jitted:
+        update = jax.jit(update)
+    got = want = init_window_state(_CAP, _NB)
+    mark = jnp.arange(_CAP * _NB, dtype=jnp.float32) + 0.5
+    for batch in _MERGED_UPDATE_CASES[case]:
+        cols = tuple(map(jnp.asarray, batch))
+        before = got
+        got = update(before, *cols)
+        want = _table_wide_update(want, *cols, **kw)
+        again = update(before, *cols)
+        slot, day, _, _, valid = batch
+        taken = np.bincount((slot * _NB + day % _NB)[valid],
+                            minlength=_CAP * _NB)
+        for name in ("bucket_day", "count", "amount", "fraud"):
+            g, w = (np.asarray(getattr(st, name)) for st in (got, want))
+            assert g.tobytes() == np.asarray(getattr(again, name)).tobytes()
+            if name == "amount" and kw.get("track_amount", True):
+                one = taken <= 1
+                np.testing.assert_array_equal(g[one], w[one])
+                assert (np.abs(g - w) <= taken * np.float32(2.0 ** -23)
+                        * np.abs(w)).all(), name
+                want = dataclasses.replace(want, amount=got.amount)
+            else:
+                np.testing.assert_array_equal(g, w, err_msg=name)
+    for name, tracked in (("amount", "track_amount"),
+                          ("fraud", "track_fraud")):
+        if not kw.get(tracked, True):
+            marked = dataclasses.replace(got, **{name: mark})
+            cols = tuple(map(jnp.asarray, _MERGED_UPDATE_CASES[case][-1]))
+            assert (np.asarray(getattr(update(marked, *cols), name))
+                    .tobytes() == np.asarray(mark).tobytes())
+
+
+def test_merged_update_adds_a_buckets_rows_in_their_own_order():
+    """The order of a bucket's in-batch amount additions is a fixed tree
+    over ITS rows in batch order: renumbering the slots (what another key
+    mode does) and interleaving other keys' rows leaves every bucket's
+    sum the same bits — ``key_mode=exact`` equals ``direct`` bit for bit
+    (tests/test_exact_store.py) because of this."""
+    rng = np.random.default_rng(11)
+    rows, cap = 256, 64
+    slot = rng.integers(0, 8, rows).astype(np.int32)  # ~32 rows a bucket
+    day = np.full(rows, 100, np.int32)
+    amount = rng.uniform(1, 500, rows).round(2).astype(np.float32)
+    zero, ok = np.zeros(rows, np.float32), np.ones(rows, bool)
+    renumber = rng.permutation(cap).astype(np.int32)
+    a = update_windows(init_window_state(cap, _NB), *map(
+        jnp.asarray, (slot, day, amount, zero, ok)))
+    # the same rows under other slot numbers, other keys' rows between
+    other = (8 + rng.integers(0, 8, rows)).astype(np.int32)
+    mixed = np.stack([renumber[slot], renumber[other]], 1).reshape(-1)
+    twice = lambda x: np.repeat(x, 2)  # noqa: E731
+    b = update_windows(init_window_state(cap, _NB), *map(
+        jnp.asarray, (mixed, twice(day), twice(amount), twice(zero),
+                      twice(ok))))
+    ta, tb = np.asarray(a.tables()[2]), np.asarray(b.tables()[2])
+    for s in range(8):
+        assert ta[s].tobytes() == tb[renumber[s]].tobytes(), s
+
+
+def test_terminal_columns_are_the_same_bits_under_any_row_order():
+    """The table that maintains no dollar sum sorts its batch by the
+    bucket alone, so the order of a bucket's rows is the sort's: counts
+    and 0/1 fraud labels are small integers, exact in any order, and the
+    columns come out the same bytes under any permutation of the rows
+    (and equal the table-wide form's)."""
+    rng = np.random.default_rng(5)
+    kw = _COLUMN_SETS["terminal"]
+    for batch in _random_batches(4, 192, _CAP, seed=13):
+        cols = tuple(map(jnp.asarray, batch))
+        start = update_windows(init_window_state(_CAP, _NB), *map(
+            jnp.asarray, _random_batches(1, 64, _CAP, seed=3)[0]), **kw)
+        want = _table_wide_update(start, *cols, **kw)
+        for _ in range(3):
+            perm = rng.permutation(len(batch[0]))
+            got = update_windows(start, *(c[perm] for c in cols), **kw)
+            for name in ("bucket_day", "count", "amount", "fraud"):
+                assert (np.asarray(getattr(got, name)).tobytes()
+                        == np.asarray(getattr(want, name)).tobytes()), name
+
+
+@pytest.mark.parametrize("rows,n,sorted_write", [
+    # the benchmark's tables, 2^22 and 2^23 slots of 40 buckets
+    (65536, 40 << 22, True), (65536, 40 << 23, True),
+    (16384, 40 << 22, False), (16384, 40 << 23, False),
+    (256, 40 << 22, False), (256, 40 << 23, False),
+    # a shard of the four-chip cell's tables: a quarter of 2^24 / 2^25
+    (65536, 40 << 23, True), (32768, 40 << 22, True),
+    # past ~460 M elements a pass costs more than 65,536 plain updates
+    (65536, 40 << 24, False),
+    # toy tables: a pass's fixed 0.32 ms is 3,571 plain updates
+    (256, 1 << 20, False), (4096, 1 << 20, True), (4, 1 << 10, False)])
+def test_the_column_write_is_chosen_from_the_two_shapes(rows, n,
+                                                        sorted_write):
+    """``.set`` on sorted indices is a pass over the column on a v5e
+    (0.32 ms + 12.75 ps an element), the plain one 89.6 ns an update: the
+    update marks its writes sorted where the pass is the cheaper."""
+    from real_time_fraud_detection_system_tpu.ops.windows import (
+        _sorted_write_pays,
+    )
+
+    assert _sorted_write_pays(rows, n) is sorted_write
+
+
+def test_update_and_query_trace_in_few_equations():
+    """``engine.precompile()`` traces the step once a batch bucket, and the
+    update and the query once a table in each: what they cost there is
+    their number of equations (PERF.md, PR 43: the update was 125 as first
+    written over ``jnp`` indexing and ``fori_loop``, the query 420, and
+    with them the warm set-up went over its bound). A budget with a
+    little room, at the benchmark's 40 buckets."""
+    import jax
+
+    rows, cap, nb = 4096, 1 << 12, 40
+    state = jax.eval_shape(lambda: init_window_state(cap, nb))
+    col = lambda dt: jax.ShapeDtypeStruct((rows,), dt)  # noqa: E731
+    batch = (col(jnp.int32), col(jnp.int32), col(jnp.float32),
+             col(jnp.float32), col(jnp.bool_))
+    for kw in _COLUMN_SETS.values():
+        update = jax.make_jaxpr(
+            lambda st, *c: update_windows(st, *c, **kw))(state, *batch)
+        assert len(update.jaxpr.eqns) <= 90, len(update.jaxpr.eqns)
+    query = jax.make_jaxpr(
+        lambda st, s, d: query_windows(st, s, d, (1, 7, 30)))(
+            state, col(jnp.int32), col(jnp.int32))
+    assert len(query.jaxpr.eqns) <= 310, len(query.jaxpr.eqns)
 
 
 def test_cms_overestimates_and_windows(rng):

@@ -12,11 +12,10 @@ stage. For every variant of the step that compiles here at toy size:
   and no ``rtfds.`` component outside the vocabulary;
 - in the COMPILED HLO every ``rtfds.update`` op sits under exactly one of
   ``rtfds.customer`` / ``rtfds.terminal``;
-- every pass over a whole window column that the program wrote (the old
-  stamps set aside, the compare, the resets) sits under
-  ``rtfds.update/rtfds.reset``; the scatters, which follow the batch, keep
-  ``stamp`` / ``scatter``. What the chip then runs over a table with no
-  ``rtfds.`` name is the compiler's own (``step_unscoped_pct``);
+- the update writes no pass over a whole window column: it merges its
+  batch first (``rtfds.update/rtfds.merge``) and touches a column at the
+  batch's buckets only, so the one instruction under ``rtfds.update``
+  whose result is a column is a scatter (``stamp`` / ``scatter``);
 - scopes are metadata: the step's outputs are bit-equal to a build with
   the scopes patched to no-ops.
 """
@@ -67,7 +66,7 @@ TABLE = {"customer", "terminal"}
 # `relayout` left the vocabulary with the stage it named: the window
 # columns are stored flat, the layout `update_windows` works in (PR 25),
 # and the metric files that read it went in PR 40
-UPDATE = {"update", "stamp", "reset", "scatter"}
+UPDATE = {"update", "merge", "stamp", "reset", "scatter"}
 QUERY = {"query", "gather", "sum"}
 COMMON = {"unpack", "assemble", "scale", "classify"} | TABLE | UPDATE | QUERY
 # admit_slots names its three parts: the full-depth probe, the claim
@@ -146,16 +145,14 @@ _COLUMN_OP = re.compile(
     r'op_name="([^"]*rtfds\.update[^"]*)"')
 
 
-def _column_passes_outside_reset(hlo_text, column_sizes):
+def _column_passes_under_update(hlo_text, column_sizes):
     """``(op, op_name)`` of every instruction under ``rtfds.update`` whose
-    result is a whole window column, the scatters aside, that is not
-    under ``rtfds.update/rtfds.reset``."""
+    result is a whole window column, the scatters aside."""
     out = []
     for line in hlo_text.splitlines():
         m = _COLUMN_OP.match(line)
         if (m and int(m.group(1)) in column_sizes
-                and m.group(2) != "scatter"
-                and "rtfds.update/rtfds.reset/" not in m.group(3)):
+                and m.group(2) != "scatter"):
             out.append((m.group(2), m.group(3)))
     return out
 
@@ -181,7 +178,7 @@ def test_step_hlo_carries_the_variants_scopes(variant):
         assert said <= set(STEP_SCOPES)
         assert want <= said, sorted(want - said)
         assert f"[{max(column_sizes)}]" in said_text  # the sizes are right
-        assert not _column_passes_outside_reset(said_text, column_sizes)
+        assert not _column_passes_under_update(said_text, column_sizes)
         compiled = _op_names(low.compile().as_text())
         kept = {s for n in compiled for s in _scopes(n)}
         assert "relayout" not in STEP_SCOPES and "relayout" not in said
@@ -193,6 +190,11 @@ def test_step_hlo_carries_the_variants_scopes(variant):
             assert len(TABLE & set(path)) == 1, path
             assert path.index("update") > min(
                 path.index(t) for t in TABLE & set(path)), path
+        # every table's update holds operations under each of its four
+        # stages: a ``step_*_ms`` metric that reads none is a null
+        for table in {t for path in updates for t in TABLE & set(path)}:
+            held = {s for path in updates if table in path for s in path}
+            assert UPDATE <= held, (table, sorted(UPDATE - held))
     if variant == "sharded_1dev":
         # one body, two engines: a per-layer metric file that names a
         # scope path reads both for as long as they share it

@@ -114,9 +114,10 @@ def compiled_steps():
     return {}
 
 
-def _compiled_step(cache, one_chip, kind, bucket):
+def _compiled_step(cache, one_chip, kind, bucket, fcfg=None):
     """The DEFAULT served step (`rtfds score`, no flags) of the engine
-    itself, at 2^20 + 2^21 state slots and the on-chip z_mode."""
+    itself, at 2^20 + 2^21 state slots (or ``fcfg``'s) and the on-chip
+    z_mode."""
     from real_time_fraud_detection_system_tpu.config import (
         Config,
         RuntimeConfig,
@@ -130,10 +131,11 @@ def _compiled_step(cache, one_chip, kind, bucket):
     from real_time_fraud_detection_system_tpu.models.scaler import Scaler
     from real_time_fraud_detection_system_tpu.runtime import ScoringEngine
 
-    if (kind, bucket) in cache:
-        return cache[kind, bucket]
+    key = (kind, bucket, fcfg)
+    if key in cache:
+        return cache[key]
     assert resolve_z_mode("auto") == "int8"  # what the chip resolves
-    fcfg = _fcfg()
+    fcfg = fcfg or _fcfg()
     cfg = Config(features=fcfg, runtime=RuntimeConfig(
         z_mode="int8", batch_buckets=(bucket,), max_batch_rows=bucket))
     eng = ScoringEngine(
@@ -145,9 +147,9 @@ def _compiled_step(cache, one_chip, kind, bucket):
         feature_state=_on(one_chip, _state_shapes(fcfg)))
     (sig,) = eng.dispatch_inventory()
     assert sig.bucket == bucket
-    cache[kind, bucket] = eng.signature_step(sig).lower(
+    cache[key] = eng.signature_step(sig).lower(
         *_on(one_chip, eng.signature_templates(sig))).compile()
-    return cache[kind, bucket]
+    return cache[key]
 
 
 @pytest.mark.parametrize("bucket", [256, 4096, 65536])
@@ -191,17 +193,15 @@ def test_step_moves_no_table_between_layouts(topo, one_chip, as_on_chip,
     (flat, slot-major), so the chip's compiler has no column to re-lay
     out: before PR 25 each stored [cap, 40] column went copy → reshape →
     update → reshape → copy, 206.7 of a 305.4 ms step at the benchmark's
-    size (PERF.md). What may remain, a table: one flat int32 copy of the
-    day stamps (until PR 29 the compiler made two around the scatter-max;
-    the update now sets the old stamps aside itself, in a pass it
-    names)."""
+    size (PERF.md). Nor is a column copied: the update reads and writes
+    it in place at the batch's buckets (until PR 43 one flat int32 copy
+    of the day stamps a table remained, the old stamps set aside for the
+    table-wide compare)."""
     fcfg = _fcfg()
     nb = fcfg.n_day_buckets
     text = _compiled_step(compiled_steps, one_chip, kind, bucket).as_text()
     for cap in (fcfg.customer_capacity, fcfg.terminal_capacity):
-        moves = whole_column_moves(text, {cap * nb})
-        assert all(m == ("copy", "s32", (cap * nb,)) for m in moves), moves
-        assert len(moves) <= 1, moves
+        assert not whole_column_moves(text, {cap * nb})
 
 
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([\w\-]+)\((.*)$")
@@ -341,21 +341,18 @@ def _root_operands(hlo_text):
 
 @pytest.mark.parametrize("bucket", [4096, 65536])
 @pytest.mark.parametrize("kind", ["forest", "logreg"])
-def test_update_moves_at_most_34_bytes_a_bucket(topo, one_chip, as_on_chip,
-                                                compiled_steps, kind,
-                                                bucket):
-    """What of the step follows the tables' size and not the batch: the
-    passes over whole window columns. Until PR 29 they moved 50 bytes a
-    bucket and table (two copies of the stamps, 8 + 8; the three resets
-    in two fusions with a materialized mask between them, 17 + 17) — 37
-    of a 100 ms step at 2^22 + 2^23 slots, at 83 % of the chip's HBM
-    bandwidth, so only fewer bytes shorten it. Now: the old stamps set
-    aside once (4 + 4), the reset of the first maintained column with the
-    compare (12 read, the column and the mask written: 17), the reset of
-    the second from the mask (5 + 4); the column a table does not
-    maintain goes from the step's input to its output as the same
-    buffer. (At most one table-sized copy a table:
-    test_step_moves_no_table_between_layouts.)"""
+def test_update_makes_no_pass_over_a_window_column(topo, one_chip,
+                                                   as_on_chip,
+                                                   compiled_steps, kind,
+                                                   bucket):
+    """Nothing the step's program says follows the tables' size: the
+    update merges its batch first and reads and writes a column at the
+    batch's buckets only, so no instruction of the entry computation
+    streams a whole window column — until PR 43 the reset did, 34 bytes a
+    bucket and table (25 of an 89 ms step at 2^22 + 2^23 slots), and
+    until PR 29 50. The column a table does not maintain goes from the
+    step's input to its output as the same buffer. (What the chip's
+    sorted scatter does inside its fusion is the next test's.)"""
     fcfg = _fcfg()
     nb = fcfg.n_day_buckets
     text = _compiled_step(compiled_steps, one_chip, kind, bucket).as_text()
@@ -363,12 +360,115 @@ def test_update_moves_at_most_34_bytes_a_bucket(topo, one_chip, as_on_chip,
     for cap, table, unmaintained in (
             (fcfg.customer_capacity, "customer", "fraud"),
             (fcfg.terminal_capacity, "terminal", "amount")):
-        passes = column_passes(text, cap * nb)
-        assert passes, table
-        assert sum(p[2] + p[3] for p in passes) <= 34, passes
+        assert not column_passes(text, cap * nb), table
         (param,) = {o for o in root
                     if o.startswith(f"fstate_{table}_{unmaintained}")}
-        assert not [p for p in passes if param in p[4]], (param, passes)
+        assert re.search(rf"%{re.escape(param)} = \S+ parameter\(", text)
+
+
+def test_column_passes_counts_the_table_wide_update(topo, one_chip):
+    """``column_passes`` finds nothing in the step; that it can find
+    something: the table-wide form the update had until PR 43 (kept as
+    ``tests/test_ops.py``'s oracle), compiled for the chip, streams its
+    columns — the compare and a reset a maintained column."""
+    from real_time_fraud_detection_system_tpu.ops.windows import (
+        init_window_state,
+    )
+    from test_ops import _table_wide_update
+
+    cap, nb, rows = 1 << 16, 40, 4096
+    state = _on(one_chip, jax.eval_shape(
+        lambda: init_window_state(cap, nb)))
+    cols = [jax.ShapeDtypeStruct((rows,), dt, sharding=one_chip)
+            for dt in (jnp.int32, jnp.int32, jnp.float32, jnp.float32,
+                       jnp.bool_)]
+    text = jax.jit(_table_wide_update, donate_argnums=(0,)).lower(
+        state, *cols).compile().as_text()
+    passes = column_passes(text, cap * nb)
+    assert sum(p[2] + p[3] for p in passes) >= 3 * 8, passes
+
+
+def window_column_scatters(hlo_text, column_sizes):
+    """The text of every ``scatter`` of a compiled program whose operand
+    is a whole window column (a result of ``column_sizes`` elements)."""
+    sizes = "|".join(str(n) for n in sorted(column_sizes))
+    return re.findall(
+        rf"^\s*(?:ROOT )?%[\w.\-]+ = \w+\[(?:{sizes})\]\S* scatter\(.*$",
+        hlo_text, re.M)
+
+
+def merge_sort_compares(hlo_text):
+    """table → the number of ``compare`` instructions in the comparator
+    of the sort under ``rtfds.<table>/rtfds.update/rtfds.merge``: one for
+    a sort by one key, three (``a < b or (a == b and c < d)``) for two."""
+    bodies = _computations(hlo_text)
+    out = {}
+    for region, table in re.findall(
+            r' sort\(.*to_apply=%([\w.\-]+),.*op_name="[^"]*'
+            r'rtfds\.(\w+)/rtfds\.update/rtfds\.merge/sort"', hlo_text):
+        assert table not in out, table
+        out[table] = bodies[region].count(" compare(")
+    return out
+
+
+def test_update_writes_its_columns_sorted_at_the_benchmarks_size(
+        topo, one_chip, as_on_chip, compiled_steps):
+    """What the chip's compiler made of the update at the benchmark's
+    2^22 + 2^23 slots and 65,536 rows, which the 2^20 + 2^21 fixtures of
+    this file cannot show: the compiler sorts a scatter's indices itself,
+    and marks it sorted, only under ~1,024 operand elements an update —
+    42 M / 84 M-element columns at 65,536 rows are on that side, the
+    benchmark's 168 M / 335 M are not, and an ``x.at[i].add(v)`` there
+    is the bare scatter that costs 89.6 ns a row (PERF.md, PR 43). The
+    update sorts its batch once a table and says so on every write
+    where a pass over the column is the cheaper (``_sorted_write_pays``:
+    here it is): three scatters a table into window columns, each
+    ``indices_are_sorted=true`` (every lane of a run writes the run's
+    one value, so the indices are not unique and do not say so); the
+    two sorts under ``rtfds.update`` are the program's own
+    (``rtfds.merge/sort``), and the compiler added none to any
+    scatter of the update. The customer table, which maintains a dollar
+    sum, sorts by (bucket, lane); the terminal table by the bucket
+    alone: ~4 s less of the chip's compile."""
+    fcfg, compiled = _compiled_exact(compiled_steps, one_chip, "step")
+    text = compiled.as_text()
+    nb = fcfg.n_day_buckets
+    writes = window_column_scatters(
+        text, {fcfg.customer_capacity * nb, fcfg.terminal_capacity * nb})
+    assert len(writes) == 6, writes
+    for w in writes:
+        assert "indices_are_sorted=true" in w, w
+        assert "rtfds.update/" in w, w
+    sorts = re.findall(r' sort\(.*op_name="([^"]*rtfds\.update[^"]*)"',
+                       text)
+    assert sorted(s.split("jit(step)/")[-1] for s in sorts) == [
+        f"rtfds.{t}/rtfds.update/rtfds.merge/sort"
+        for t in ("customer", "terminal")], sorts
+    assert merge_sort_compares(text) == {"customer": 3, "terminal": 1}
+
+
+def test_update_writes_its_columns_plain_at_256_rows(topo, one_chip,
+                                                     as_on_chip,
+                                                     compiled_steps):
+    """The same tables under the engine's smallest bucket: three passes a
+    table would be 19.7 ms for a batch that 6 × 256 plain updates serve
+    in 0.14 (PERF.md, PR 43), so no write says ``indices_are_sorted`` —
+    and the compiler, which would sort them itself only into a column of
+    under ~1,024 elements an update, adds no sort of its own."""
+    from real_time_fraud_detection_system_tpu.config import FeatureConfig
+
+    fcfg = FeatureConfig(customer_capacity=1 << 22,
+                         terminal_capacity=1 << 23)
+    text = _compiled_step(compiled_steps, one_chip, "logreg", 256,
+                          fcfg).as_text()
+    nb = fcfg.n_day_buckets
+    writes = window_column_scatters(
+        text, {fcfg.customer_capacity * nb, fcfg.terminal_capacity * nb})
+    assert len(writes) == 6, writes
+    for w in writes:
+        assert "indices_are_sorted" not in w, w
+    assert len(re.findall(r" sort\(", text)) == 2
+    assert merge_sort_compares(text) == {"customer": 3, "terminal": 1}
 
 
 @pytest.mark.parametrize("z_mode", ["f32", "bf16", "int8"])
