@@ -98,18 +98,26 @@ def init_feature_state(
     n_shards: int = 1, window_sharding=None,
 ) -> FeatureState:
     """``window_sharding`` (a mesh's slot-axis ``NamedSharding``) creates
-    the window tables — all but a few MB of the state — already spread
-    over that mesh, each device allocating only its share
-    (:func:`~..parallel.mesh.init_sharded_feature_state` is the caller).
+    the window tables — all but a few MB of the state in ``direct`` mode
+    — already spread over that mesh, each device allocating only its
+    share (:func:`~..parallel.mesh.init_sharded_feature_state` is the
+    caller). With ``n_shards > 1`` the stacked directories and the
+    sketches (then born in the per-device layout ``shard_feature_state``
+    would give them) are created the same way: at 2^22 + 2^23 slots a
+    device they are 0.36 GB a device, and built on the default device
+    first they stood there four times over beside its share of the
+    tables (``peak_bytes_in_use`` 9.79 GB on the first of four v5e chips
+    against 8.63 born sharded; PERF.md, PR 44).
 
     ``n_shards > 1`` builds the SHARDED exact layout: the window
     tables stay global ``[capacity · NB]`` columns (placed ``P(axis)``, so
     shard s owns slots ``[s*cap/n, (s+1)*cap/n)``), but each shard gets
     its OWN key directory over its local slot range — stacked
     ``[n_shards, ...]`` leaves (:func:`~..ops.keydir.
-    init_stacked_keydir`). Sketches keep the single-chip layout here;
-    :func:`~..parallel.mesh.shard_feature_state` expands them
-    per-device at placement time. Non-exact key modes ignore
+    init_stacked_keydir`). Without ``window_sharding`` sketches keep the
+    single-chip layout here and :func:`~..parallel.mesh.
+    shard_feature_state` expands them per-device at placement time.
+    Non-exact key modes ignore
     ``n_shards`` (their layouts are width-independent)."""
     exact = cfg.key_mode == "exact"
     if with_cms is None:
@@ -117,6 +125,27 @@ def init_feature_state(
         # overflow tier for rows that miss hot-tier admission
         with_cms = cfg.customer_source == "cms" or exact
     customer_dir = terminal_dir = terminal_cms = None
+    born_sharded = window_sharding is not None and n_shards > 1
+
+    def spread(build):
+        """``build()``, whose leaves carry a leading shard axis — each
+        device allocating its own block where the state is born
+        sharded."""
+        if not born_sharded:
+            return build()
+        return jax.jit(build, out_shardings=window_sharding)()
+
+    def sketch(track_fraud: bool = False):
+        def one():
+            return cms_init(cfg.cms_depth, cfg.cms_width,
+                            cfg.n_day_buckets, track_fraud=track_fraud)
+
+        if not born_sharded:
+            return one()
+        return spread(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a[None], (n_shards,) + a.shape),
+            one()))
+
     if exact:
         # Directory at 2x the slot capacity: load factor <= 0.5 keeps
         # fixed-depth probing effectively lossless until the free-slot
@@ -136,22 +165,20 @@ def init_feature_state(
                     import init_stacked_keydir
 
                 local = cap // n_shards
-                return init_stacked_keydir(2 * local, local, n_shards)
+                return spread(lambda: init_stacked_keydir(
+                    2 * local, local, n_shards))
             return init_keydir(2 * cap, cap, cfg.key_bits)
 
         if cfg.customer_source != "cms":
             customer_dir = _dir(cfg.customer_capacity)
         terminal_dir = _dir(cfg.terminal_capacity)
-        terminal_cms = cms_init(cfg.cms_depth, cfg.cms_width,
-                                cfg.n_day_buckets, track_fraud=True)
+        terminal_cms = sketch(track_fraud=True)
     return FeatureState(
         customer=init_window_state(cfg.customer_capacity, cfg.n_day_buckets,
                                    window_sharding),
         terminal=init_window_state(cfg.terminal_capacity, cfg.n_day_buckets,
                                    window_sharding),
-        cms=cms_init(cfg.cms_depth, cfg.cms_width, cfg.n_day_buckets)
-        if with_cms
-        else None,
+        cms=sketch() if with_cms else None,
         customer_dir=customer_dir,
         terminal_dir=terminal_dir,
         terminal_cms=terminal_cms,

@@ -541,7 +541,11 @@ def make_sharded_compact(
             "terminal": leaf,
         }
 
-    def outer(fstate: FeatureState, now_day: jnp.ndarray):
+    # named as the one-chip programs are (``jit_compact``, ``jit_promote``
+    # in a trace's ``XLA Modules`` line): ``jit_outer`` is the mesh's STEP,
+    # and a reader that counts step executions by that name would take a
+    # pass that falls inside its trace for one
+    def compact(fstate: FeatureState, now_day: jnp.ndarray):
         def local(customer, terminal, c_kd, t_kd, day):
             st = FeatureState(
                 customer=customer, terminal=terminal, cms=None,
@@ -595,7 +599,7 @@ def make_sharded_compact(
             return new_state, reclaimed, outs[5]
         return new_state, reclaimed
 
-    return jax.jit(outer, donate_argnums=(0,))
+    return jax.jit(compact, donate_argnums=(0,))
 
 
 def make_sharded_promote(
@@ -637,7 +641,7 @@ def make_sharded_promote(
         return {t: (leaf if payload.get(t) is not None else None)
                 for t in ("customer", "terminal")}
 
-    def outer(fstate: FeatureState, payload):
+    def promote(fstate: FeatureState, payload):
         def local(customer, terminal, c_kd, t_kd, pay):
             st = FeatureState(
                 customer=customer, terminal=terminal, cms=None,
@@ -679,4 +683,4 @@ def make_sharded_promote(
             customer_dir=c_kd if has_cdir else fstate.customer_dir,
             terminal_dir=t_kd), stats
 
-    return jax.jit(outer, donate_argnums=(0,))
+    return jax.jit(promote, donate_argnums=(0,))

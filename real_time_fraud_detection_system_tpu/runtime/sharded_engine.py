@@ -435,6 +435,7 @@ class ShardedScoringEngine(ScoringEngine):
         self._m_tier_shard = None
         self._m_slots_occ_shard = None
         self._m_slots_rec_shard = None
+        self._m_claim_rounds_shard = None
         if not self._exact:
             return
         reg = self.metrics
@@ -469,6 +470,28 @@ class ShardedScoringEngine(ScoringEngine):
                 table=t, **self._shard_labels(s))
             for t in tables for s in range(n)
         }
+        # A name of its own, not a ``shard`` label on the table-level
+        # series: a reader that sums every series of a name
+        # (rounds ÷ batches) would count each round twice.
+        self._m_claim_rounds_shard = {
+            (t, s): reg.counter(
+                "rtfds_keydir_shard_claim_rounds_total",
+                "claim rounds this shard's admit ran in the step (each "
+                "device's loop ends on its own rows; the step ends when "
+                "the slowest has): the fullest shard against the mean "
+                "says whether one chip's admission holds the mesh",
+                table=t, **self._shard_labels(s))
+            for t in tables for s in range(n)
+        }
+        for t in tables:
+            local = getattr(fcfg, f"{t}_capacity") // n
+            for s in range(n):
+                reg.gauge(
+                    "rtfds_feature_slots_capacity",
+                    "hot-tier slots this shard's directory can grant "
+                    "(table capacity / n_devices): what "
+                    "rtfds_feature_slots_occupied fills",
+                    table=t, **self._shard_labels(s)).set(local)
 
     def _record_compaction(self, fstate, reclaimed) -> None:
         """Per-shard compaction metering: ``reclaimed`` arrives
@@ -1048,8 +1071,9 @@ class ShardedScoringEngine(ScoringEngine):
                 # chunks): shard-labeled counters get their own rows, the
                 # base table-level counters get the shard sums — so the
                 # global healthz/dashboard contract is identical on the
-                # mesh. The claim rounds (columns 2, 3) have table-level
-                # series only.
+                # mesh. The claim rounds (columns 2, 3: customer,
+                # terminal) keep their per-shard counts under a name of
+                # their own.
                 tier = np.zeros((self.n_dev, 4), np.float64)
                 for t in tier_parts:
                     tier += np.asarray(t)
@@ -1059,6 +1083,9 @@ class ShardedScoringEngine(ScoringEngine):
                             float(tier[s, 0]))
                         self._m_tier_shard[("cms", s)].inc(
                             float(tier[s, 1]))
+                    col = {"customer": 2, "terminal": 3}
+                    for (table, s), m in self._m_claim_rounds_shard.items():
+                        m.inc(float(tier[s, col[table]]))
                 handle["tier"] = tier.sum(axis=0)  # global, as one chip's
             return self._emit_result(handle, probs_np, feats_np)
 

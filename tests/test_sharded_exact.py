@@ -800,6 +800,7 @@ def test_claim_rounds_counter_is_what_the_fixed_rounds_need(n_dev):
     dirs = {t: [init_keydir(2 * cap, cap) for _ in range(n)]
             for t in ("customer", "terminal")}
     want = dict.fromkeys(dirs, 0)
+    want_shard = {(t, s): 0 for t in dirs for s in range(n)}
     batches = _batches(4, rows=rows)
     for b in batches:
         eng.process_batch(b)
@@ -813,12 +814,24 @@ def test_claim_rounds_counter_is_what_the_fixed_rounds_need(n_dev):
                 dirs[table][s] = fixed(
                     kd, jnp.asarray(padded),
                     jnp.arange(rows) < own.size, n_probes=probes)[0]
-                want[table] += _rounds_needed(kd, dirs[table][s], own,
-                                              probes)
+                ran = _rounds_needed(kd, dirs[table][s], own, probes)
+                want[table] += ran
+                want_shard[(table, s)] += ran
     got = {t: reg.get("rtfds_keydir_claim_rounds_total", table=t).value
            for t in dirs}
     assert got == want and all(0 < v < probes * n * len(batches)
                                for v in got.values()), (got, want)
+    # the mesh keeps each shard's rounds too, under a name of its own:
+    # the benchmark's rounds ÷ batches sums every series of a name
+    by_shard = "rtfds_keydir_shard_claim_rounds_total"
+    if n_dev:
+        assert want_shard == {
+            (t, s): reg.get(by_shard, table=t, shard=str(s)).value
+            for t, s in want_shard}
+        assert reg.family_total(
+            "rtfds_keydir_claim_rounds_total") == sum(want.values())
+    else:
+        assert reg.family_total(by_shard) is None
     for b in batches:  # every key known now: not one round more
         eng.process_batch(b)
     assert got == {
@@ -878,3 +891,132 @@ def test_sharded_exact_claims_like_the_fixed_rounds_behind_the_exchange(
                 for leaf in kd])
         for a, b in zip(*outs):
             assert a.tobytes() == b.tobytes()
+
+
+# -- the benchmark's four-chip exact cell, through the harness's own path ----
+
+# forest-rf100-d8-x4-exact at toy size: 40 fill days of 256 rows, 4,096 +
+# 8,192 active keys in a universe of 16,384 + 32,768 ids over 8,192 +
+# 16,384 slots (every shard's directory at a load under 0.25); keys arrive
+# in every batch; the first compaction of the window comes after batch 42
+X4_EXACT_TOY = {
+    "config": {
+        "features": {"customer_capacity": 8192, "terminal_capacity": 16384,
+                     "compact_every": 42},
+        "key_universe": {"customers": 16384, "terminals": 32768},
+        "active_keys": {"customers": 4096, "terminals": 8192},
+        "runtime": {"precompile": True, "batch_buckets": [256],
+                    "max_batch_rows": 256},
+        "model_params": {"fit_rows": 512, "nominal_rows_per_day": 256},
+    },
+    "traffic": {
+        "fill_batches": 40, "fill_batch_rows": 256, "pool_envelopes": 2048,
+        "draw_rows": 65536, "max_poll_rows": 256,
+        "check_window_rows": 1 << 20,
+    },
+}
+
+
+@pytest.fixture()
+def harness_on_cpu():
+    """``benchmark.harness`` with what ``claim_device`` sets for a chip
+    run (the persistent cache's directory and floors) put back after."""
+    import jax
+
+    from benchmark import harness
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    was = {n: getattr(jax.config, n) for n in names}
+    yield harness
+    for n, v in was.items():
+        jax.config.update(n, v)
+
+
+@pytest.mark.parametrize("key_mode", ["exact", "direct"])
+def test_x4_exact_cell_through_the_harness_and_the_plain_reference(
+        harness_on_cpu, key_mode):
+    """``forest-x4-exact.saturate`` as the benchmark runs it —
+    ``harness.build_engine`` from the cell's configuration on a four-device
+    mesh, ``precompile()``, the active-set generator, the Parquet sink, a
+    compaction inside the window — with every row of every batch held to
+    ``benchmark/reference.py`` under the configuration's limits. The same
+    ids under ``key_mode=direct`` are the case the deployment exists to
+    prevent: ids past the slot count merge, and the comparison says so."""
+    import time
+
+    harness = harness_on_cpu
+    seen = {}
+    over = X4_EXACT_TOY if key_mode == "exact" else harness.merge(
+        X4_EXACT_TOY, {"config": {"features": {"key_mode": "direct"}}})
+    result = harness.run_cell(
+        "forest-x4-exact.saturate", 4_400_000_123, 1.5, False,
+        time.perf_counter(), allow_cpu=True, overrides=over,
+        sabotage=lambda engine, sink: seen.update(engine=engine))
+    eng = seen["engine"]
+    assert isinstance(eng, ShardedScoringEngine) and eng.n_dev == N_DEV
+    assert result["device"]["count"] == N_DEV and result["failed"] == 0
+    checks = {c["name"]: c for c in result["checks"]}
+    assert checks["rows_compared"]["value"] >= result["attempted"] > 0
+    assert checks["recompiles_in_window"]["value"] == 0
+    if key_mode == "direct":
+        assert result["correct"] is False
+        assert checks["exact_columns_wrong"]["value"] > 0
+        return
+    assert result["correct"] is True, result["checks"]
+    # what the cell's registry metrics read on a mesh, whose engine keeps
+    # tier, occupancy and reclaim series twice (table-level, then by shard)
+    snap = eng.metrics.snapshot()
+    ctx = {"registry_before": {}, "registry_after": snap}
+
+    cell = harness.Cell(harness.ROOT, harness.load_manifest(),
+                        "forest-x4-exact.saturate")
+
+    def metric(name):  # a metric file through its reader, as a traced run
+        spec = harness.load_json(harness.find(
+            cell.root, cell.manifest, "metrics", name, ".json"))
+        return cell.plugin("readers", spec["reader"]).read(
+            ctx, **spec["args"])
+
+    def series(name, **labels):
+        return [r for r in snap[name]["series"] if all(
+            r["labels"].get(k) == v for k, v in labels.items())]
+
+    shards = [str(s) for s in range(N_DEV)]
+    passes = eng.metrics.get("rtfds_state_compactions_total").value
+    assert metric("compactions.sat") == passes >= 1
+    # `registry` takes the FIRST series that carries the file's labels:
+    # the table-level one, registered before any shard's — the mesh-wide
+    # count, equal to its shards' sum
+    tiers = series("rtfds_feature_tier_rows_total", tier="cms")
+    assert "shard" not in tiers[0]["labels"] and len(tiers) == 1 + N_DEV
+    assert metric("tier_cms_rows.sat") == tiers[0]["value"] == sum(
+        r["value"] for r in tiers[1:]) == 0.0
+    dense = series("rtfds_feature_tier_rows_total", tier="dense")
+    assert dense[0]["value"] == sum(r["value"] for r in dense[1:]) > 0
+    # `registry_ratio` sums EVERY series of a name, so the accepted
+    # slots_reclaimed.sat would count each slot twice here: the cell
+    # reports slots_reclaimed_mesh.sat, the shard series alone
+    rec = series("rtfds_feature_slots_reclaimed_total")
+    by_table = sum(r["value"] for r in rec if "shard" not in r["labels"])
+    assert by_table > 0
+    assert metric("slots_reclaimed_mesh.sat") == by_table / passes
+    assert metric("slots_reclaimed.sat") == 2 * by_table / passes
+    # rounds a batch: the table-level series alone carry that name
+    rounds = eng.metrics.family_total("rtfds_keydir_claim_rounds_total")
+    assert metric("keydir_claim_rounds.sat") == rounds / eng.metrics.get(
+        "rtfds_batches_total").value
+    per_shard = [sum(r["value"] for r in series(
+        "rtfds_keydir_shard_claim_rounds_total", shard=s)) for s in shards]
+    assert sum(per_shard) == rounds
+    assert metric("keydir_claim_rounds_spread.sat") == pytest.approx(
+        max(per_shard) / (rounds / N_DEV))
+    assert 1.0 <= metric("keydir_claim_rounds_spread.sat") < 1.5
+    occ = {(r["labels"]["table"], r["labels"]["shard"]): r["value"]
+           for r in series("rtfds_feature_slots_occupied")
+           if "shard" in r["labels"]}
+    caps = {"customer": 8192 // N_DEV, "terminal": 16384 // N_DEV}
+    assert metric("keydir_occupancy_max.sat") == pytest.approx(
+        max(v / caps[t] for (t, _), v in occ.items()))
+    assert 0.0 < metric("keydir_occupancy_max.sat") < 0.5

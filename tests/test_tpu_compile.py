@@ -574,6 +574,49 @@ def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
     assert 0.2 * 1.9e9 < per_device < 0.3 * 2.1e9  # ~a quarter of the state
 
 
+def test_sharded_exact_compaction_fits_four_chips(topo, as_on_chip):
+    """The mesh's ``("compact",)`` program at the size of the benchmark's
+    ``forest-rf100-d8-x4-exact`` (2^24 + 2^25 slots over four chips,
+    stacked directories of twice that): per device the one-chip exact
+    cell's pass over the one-chip cell's state, donated, with its padded
+    ``[2^23, 40]`` view as the temporaries — the program PR 32 found the
+    chip's compiler refusing outright in its first form, and which no
+    chip had run inside ``shard_map`` before PR 44. (The two step variants
+    take ~45 s each to compile here: the verify skill has the recipe.)"""
+    from real_time_fraud_detection_system_tpu.config import (
+        Config,
+        FeatureConfig,
+    )
+    from real_time_fraud_detection_system_tpu.parallel.step import (
+        make_sharded_compact,
+    )
+
+    n_dev = 4
+    mesh = Mesh(np.asarray(topo.devices[:n_dev]), ("data",))
+    fcfg = FeatureConfig(customer_capacity=1 << 24,
+                         terminal_capacity=1 << 25, key_mode="exact",
+                         keydir_probes=16, compact_every=64)
+    dev, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    shapes = _state_shapes(fcfg, n_shards=n_dev)
+    # the sketches in the per-device layout the mesh gives them
+    shapes = shapes._replace(**{
+        name: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct((n_dev,) + a.shape, a.dtype),
+            getattr(shapes, name)) for name in ("cms", "terminal_cms")})
+    compact = make_sharded_compact(Config(features=fcfg), mesh)
+    compiled = compact.lower(
+        _on(dev, shapes),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+    assert compiled.as_text().startswith("HloModule jit_compact")
+    mem = compiled.memory_analysis()
+    state = 8_409_579_848  # features/online.state_bytes(n_shards=4) / 4
+    assert state <= mem.argument_size_in_bytes < state + 1e6
+    assert mem.alias_size_in_bytes >= state  # donated, updated in place
+    assert mem.temp_size_in_bytes <= 4_363_527_680  # the one-chip pass's
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+    assert "all-to-all" not in compiled.as_text()  # a shard's own business
+
+
 EXACT_ROWS = 65536
 
 
