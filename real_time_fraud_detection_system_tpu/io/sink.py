@@ -32,7 +32,10 @@ class _SinkTelemetry:
     """Shared sink instrumentation: write latency, rows, bytes, failures
     (labeled by sink kind). Series resolve once per sink instance."""
 
-    def _init_sink_metrics(self, sink_kind: str) -> None:
+    def _init_sink_metrics(self, sink_kind: str,
+                           parts: bool = False) -> None:
+        """``parts``: the sink writes Parquet part files itself, and counts
+        how :func:`_encode_part` stored their columns."""
         from real_time_fraud_detection_system_tpu.utils.trace import (
             get_tracer,
         )
@@ -49,6 +52,19 @@ class _SinkTelemetry:
             "rtfds_sink_bytes_total", "bytes written", sink=sink_kind)
         self._m_failures = reg.counter(
             "rtfds_sink_failures_total", "failed appends", sink=sink_kind)
+        if parts:
+            self._m_plain_cols = reg.counter(
+                "rtfds_sink_plain_columns_total",
+                "part-file columns stored plain: no dictionary page, or "
+                "the writer left its dictionary with nine tenths of the "
+                "rows still to write (uncompressed chunk bytes less the "
+                "dictionary page limit >= 0.9 x rows x value width)",
+                sink=sink_kind)
+            self._m_dict_cols = reg.counter(
+                "rtfds_sink_dict_columns_total",
+                "part-file columns that kept their dictionary (every "
+                "column that rtfds_sink_plain_columns_total does not "
+                "count)", sink=sink_kind)
 
     def _begin_write(self, res) -> tuple:
         """→ ``(t0, span)``: the append's start and its open
@@ -65,13 +81,18 @@ class _SinkTelemetry:
         span ``sink/<name>``."""
         return self._tracer.span(f"sink/{name}")
 
-    def _observe_write(self, t0: float, span, rows: int,
-                       nbytes: int) -> None:
+    def _observe_write(self, t0: float, span, rows: int, nbytes: int,
+                       columns: Optional[tuple] = None) -> None:
+        """``columns``: the part's ``(plain, dictionary)`` column counts,
+        from a sink that has ``parts``."""
         t1 = time.perf_counter()
         self._m_write.observe(t1 - t0)
         self._m_rows.inc(rows)
         if nbytes:
             self._m_bytes.inc(nbytes)
+        if columns is not None:
+            self._m_plain_cols.inc(columns[0])
+            self._m_dict_cols.inc(columns[1])
         span.close(t0, t1, rows=rows, bytes=nbytes)
 
     def _fail_write(self, t0: float, span) -> None:
@@ -99,6 +120,71 @@ def _result_to_columns(res) -> dict:
     cols["processed_at_us"] = np.full(n, now_us, dtype=np.int64)
     cols["prediction"] = res.probs.astype(np.float64)
     return cols
+
+
+# A column keeps its dictionary while the dictionary page is smaller than
+# this many bytes: 1,024 eight-byte values, as many as Arrow's writer takes
+# between two looks at the limit, so a smaller one does the same. Past it
+# the writer leaves the page as it is and stores the rest of the column
+# plain. pyarrow's own limit is 1 MiB, under which a 65,536-row column of
+# ids, averages or probabilities hashes every value into a table that
+# outgrows the cache and then writes a dictionary as large as the values:
+# 21 of a 58 ms write on the benchmark's host, and a larger file (PERF.md,
+# PR 45, which also tried 4,096 to 65,536 and a list of columns).
+_DICTIONARY_PAGE_LIMIT = 8_192
+
+# Parquet physical type -> bytes a value stored plain
+_PLAIN_WIDTH = {"INT32": 4, "FLOAT": 4, "INT64": 8, "DOUBLE": 8}
+
+
+def _encode_part(table, where, tracer) -> tuple:
+    """Encode one batch's table as its Parquet part, under the span
+    ``sink/encode``: the one way a sink writes a part. ``where`` is a path,
+    or None for the bytes. → ``(data, columns)``: the bytes (None for a
+    path) and how many columns ended up stored each way, ``(plain,
+    dictionary)`` (:func:`_part_columns`).
+
+    pyarrow's defaults (format and data-page version, snappy, column
+    statistics), but for ``_DICTIONARY_PAGE_LIMIT``: what a column holds
+    decides its encoding, column by column and batch by batch, and a
+    reader sees the same table either way."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    collected: list = []
+    with tracer.span("sink/encode"):
+        out = pa.BufferOutputStream() if where is None else where
+        pq.write_table(table, out,
+                       dictionary_pagesize_limit=_DICTIONARY_PAGE_LIMIT,
+                       metadata_collector=collected)
+        data = out.getvalue().to_pybytes() if where is None else None
+        columns = _part_columns(collected[0])
+    return data, columns
+
+
+def _part_columns(metadata) -> tuple:
+    """→ ``(plain, dictionary)`` column counts of a part, from the
+    ``FileMetaData`` its writer returned (nothing is read back).
+
+    A column that fell back to plain keeps its first dictionary page, so
+    the chunk's ``encodings`` and ``has_dictionary_page`` read alike either
+    way; its size tells. With a kept dictionary (under the limit, so at
+    most 11-bit indices) the chunk's uncompressed bytes less the limit stay
+    under 1.4 bytes a row; after a fall-back they are at least the width
+    of every row written after it. Plain: no dictionary page, or those
+    bytes reach nine tenths of rows x width."""
+    groups = [metadata.row_group(g) for g in range(metadata.num_row_groups)]
+    plain = 0
+    for j in range(metadata.num_columns):
+        chunks = [group.column(j) for group in groups]  # one, as a rule
+        width = _PLAIN_WIDTH.get(metadata.schema.column(j).physical_type)
+        stored = sum(c.total_uncompressed_size for c in chunks)
+        rows = sum(c.num_values for c in chunks)
+        plain += not any(c.has_dictionary_page for c in chunks) or (
+            width is not None
+            and stored - _DICTIONARY_PAGE_LIMIT * len(chunks)
+            >= 0.9 * rows * width)
+    return plain, metadata.num_columns - plain
 
 
 class FanoutSink:
@@ -395,11 +481,10 @@ class ParquetSink(_SinkTelemetry):
         self.directory = directory
         os.makedirs(directory, exist_ok=True)
         self._seq = 0
-        self._init_sink_metrics("parquet")
+        self._init_sink_metrics("parquet", parts=True)
 
     def append(self, res) -> None:
         import pyarrow as pa
-        import pyarrow.parquet as pq
 
         t0, span = self._begin_write(res)
         try:
@@ -415,15 +500,14 @@ class ParquetSink(_SinkTelemetry):
                 self._seq += 1
             path = os.path.join(self.directory, name)
             tmp = path + ".tmp"
-            with self._part("encode"):
-                pq.write_table(table, tmp)
+            _, columns = _encode_part(table, tmp, self._tracer)
             with self._part("commit"):
                 nbytes = os.path.getsize(tmp)
                 os.replace(tmp, path)
         except Exception:
             self._fail_write(t0, span)
             raise
-        self._observe_write(t0, span, len(res.tx_id), nbytes)
+        self._observe_write(t0, span, len(res.tx_id), nbytes, columns)
 
     def truncate_after(self, batch_index: int) -> None:
         """Drop indexed parts beyond ``batch_index`` — the sink-side
@@ -471,11 +555,10 @@ class StoreParquetSink(_SinkTelemetry):
     def __init__(self, store):
         self.store = store
         self._seq = 0
-        self._init_sink_metrics("store_parquet")
+        self._init_sink_metrics("store_parquet", parts=True)
 
     def append(self, res) -> None:
         import pyarrow as pa
-        import pyarrow.parquet as pq
 
         t0, span = self._begin_write(res)
         try:
@@ -489,16 +572,13 @@ class StoreParquetSink(_SinkTelemetry):
                 name = (f"part-{int(time.time() * 1e3)}-"
                         f"{self._seq:06d}.parquet")
                 self._seq += 1
-            with self._part("encode"):
-                buf = pa.BufferOutputStream()
-                pq.write_table(table, buf)
-                data = buf.getvalue().to_pybytes()
+            data, columns = _encode_part(table, None, self._tracer)
             with self._part("commit"):
                 self.store.put(name, data)
         except Exception:
             self._fail_write(t0, span)
             raise
-        self._observe_write(t0, span, len(res.tx_id), len(data))
+        self._observe_write(t0, span, len(res.tx_id), len(data), columns)
 
     def truncate_after(self, batch_index: int) -> None:
         for key in self.store.list(""):
