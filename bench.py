@@ -68,8 +68,9 @@ def _section_error(section: str, e: BaseException) -> dict:
 
 # Peak dense bf16 matmul FLOP/s per chip, by device_kind substring
 # (public spec sheets; v5e: Google Cloud documentation, "TPU v5e"). MFU
-# here is model-FLOPs / (wall · peak): a lower bound, since the
-# f32-HIGHEST proj pass runs below bf16 peak.
+# here is model-FLOPs / (wall · peak): a lower bound, since the proj
+# pass (one bf16 pass 45 deep since PR 47, six at f32 HIGHEST before)
+# fills a third of the MXU's depth.
 _PEAK_FLOPS = (
     ("v6", 918e12),
     ("v5p", 459e12),

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from real_time_fraud_detection_system_tpu.ops.numerics import sum_fixed_order
+from real_time_fraud_detection_system_tpu.utils.trace import step_scope
 
 
 class TreeEnsemble(NamedTuple):
@@ -277,13 +278,32 @@ def gemm_leaf_sum(
     """[B, F] → Σ_t leaf value [B] via three contractions (MXU formulation).
 
     Sum-reduction shared by bagging (÷ n_trees) and boosting (+ base logit).
+    Two named parts (``utils/trace.STEP_SCOPES``): ``decide`` — the
+    selector contraction and the threshold compare — and ``leaves`` — the
+    z contraction, the leaf match and select, the pinned-order sum.
 
     Mixed precision, chosen to stay bit-exact (verified on v5e: max |Δ| = 0
     vs all-HIGHEST, incl. inputs placed exactly on thresholds):
 
-    - proj MUST be f32 HIGHEST: the decision ``proj <= thresh`` flips for
-      inputs near thresholds under any bf16-pass scheme (measured: HIGH
-      flips ~1% of decisions on threshold-valued inputs);
+    - the decision ``proj <= thresh`` needs ``proj[b, t, i]`` to BE the f32
+      value ``x[b, feat(t, i)]``: a pass that rounds a feature to bf16
+      flips decisions near thresholds (measured: ``HIGH`` flips ~1% of
+      decisions on threshold-valued inputs). ``sel`` is one-hot, so the
+      contraction only has to carry x through unrounded, and two forms do
+      (:func:`_selector` picks by backend, the only thing it looks at):
+        * on TPU, **one bf16 pass with an f32 accumulator**: x is split
+          into three bfloat16 parts whose sum is x to the bit
+          (:func:`split_bf16x3`), the parts lie side by side along the
+          contraction axis (``[B, 3F]``, 45 deep in an array 128 deep) and
+          meet the 0/1 selector repeated three times. Every product is a
+          bf16 value times 0 or 1 and every partial sum of the three
+          non-zero terms is an f32 value, so the accumulator holds x
+          exactly. f32 ``HIGHEST`` did the same split inside the compiler
+          and spent six passes on it, three of them on the selector's
+          zero low parts: 18.3 → 7.2 ms a 65,536-row step (PERF.md §6,
+          PR 47).
+        * elsewhere, the f32 contraction: CPU XLA multiplies f32 by f32
+          exactly (x·1 + Σ 0), and has no use for the split.
     - the dominant z contraction is exact in EVERY reduced-precision mode
       because its operands are tiny integers: d is 0/1, path is ±1/0, and
       z counts ≤ depth. ``z_mode`` selects the arithmetic:
@@ -306,14 +326,16 @@ def gemm_leaf_sum(
         z_mode = "bf16" if jax.default_backend() == "tpu" else "f32"
     if z_mode not in ("bf16", "int8", "f32"):
         raise ValueError(f"unknown z_mode {z_mode!r}")
+    with step_scope("decide"):
+        pick = _selector(g.sel)  # once a call, not once a slab
     b = x.shape[0]
     if b <= LEAF_SLAB_ROWS:
-        return _leaf_sum_slab(g, x, z_mode)
+        return _leaf_sum_slab(g, pick, x, z_mode)
     pad = -b % LEAF_SLAB_ROWS
     if pad:
         x = jnp.concatenate([x, jnp.zeros((pad, x.shape[1]), x.dtype)])
     slabs = x.reshape(-1, LEAF_SLAB_ROWS, x.shape[1])
-    return jax.lax.map(lambda xs: _leaf_sum_slab(g, xs, z_mode),
+    return jax.lax.map(lambda xs: _leaf_sum_slab(g, pick, xs, z_mode),
                        slabs).reshape(-1)[:b]
 
 
@@ -326,36 +348,101 @@ def gemm_leaf_sum(
 # 32,768, and right again over 8,192-row slabs at any batch size). No
 # compile test and no CPU test can see that. Slabs also bound the step's
 # temporaries, and measured 20% faster than one pass at 65,536 rows
-# (19.2 ms vs 24.1 ms, same scratch run, PR 21).
+# (19.2 ms vs 24.1 ms, same scratch run, PR 21). With the selector in
+# one MXU pass (PR 47) the slab still hardly matters below the fault:
+# 4,096 / 8,192 / 16,384 rows read 7.136 / 7.279 / 7.479 ms for 65,536
+# rows on the chip, bit-equal answers — 8,192 stays (2% is 0.14 ms, and
+# 16,384 is one doubling from the extent that returned wrong sums).
 LEAF_SLAB_ROWS = 8192
 
 
-def _leaf_sum_slab(g: GemmEnsemble, x: jnp.ndarray, z_mode: str):
-    """:func:`gemm_leaf_sum` for one slab of at most ``LEAF_SLAB_ROWS``."""
-    hi = jax.lax.Precision.HIGHEST
-    proj = jnp.einsum("bf,tfi->bti", x, g.sel, precision=hi)
-    if z_mode == "int8":
-        d = (proj <= g.thresh[None]).astype(jnp.int8)
-        z = jnp.einsum(
-            "bti,til->btl", d, g.path.astype(jnp.int8),
-            preferred_element_type=jnp.int32,
-        )
-        match = z == g.target.astype(jnp.int32)[None]
-    else:
-        on_tpu = jax.default_backend() == "tpu"
-        zdt = jnp.bfloat16 if (z_mode == "bf16" and on_tpu) else jnp.float32
-        d = (proj <= g.thresh[None]).astype(zdt)
-        z = jnp.einsum(
-            "bti,til->btl", d, g.path.astype(zdt),
-            preferred_element_type=jnp.float32,
-        )
-        match = jnp.abs(z - g.target[None]) < 0.5
-    # Exactly one leaf per tree matches, so the sum over L adds zeros to
-    # one value: exact in any order. The sum over T is the only rounding
-    # step, and its order is pinned (sum_fixed_order), so two programs
-    # that see bit-equal features emit bit-equal probabilities.
-    per_tree = jnp.sum(jnp.where(match, g.leaf_val[None], 0.0), axis=2)
-    return sum_fixed_order(per_tree, axis=1)
+def split_bf16x3(x: jnp.ndarray) -> jnp.ndarray:
+    """f32 ``[B, F]`` → bfloat16 ``[B, 3F]``: ``h | m | l`` side by side,
+    three parts of every value with ``(h + m) + l == x`` to the bit.
+
+    Each part is the top eight significant bits of what is left, cut and
+    not rounded (a rounded ``h`` of the largest finite f32 is inf): ``h``
+    keeps the sign, the exponent and seven stored mantissa bits of x,
+    ``x - h`` is exact in f32 (Sterbenz: same sign, no more than 16
+    significant bits), ``m`` is its top eight, and ``l`` has eight or
+    fewer left, which bfloat16 holds as they are. The parts have one sign
+    and disjoint bits, so any sum of them, in any order, is an f32 value.
+    Lossless for every finite f32 whose lowest set bit is worth 2^-126 or
+    more (|x| ≥ 2^-103, and ±0): below that a low part is subnormal and a
+    backend that flushes subnormals drops it, as f32 ``HIGHEST`` on the
+    chip did."""
+    cut = jnp.uint32(0xFFFF0000)
+
+    def top(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & cut, jnp.float32)
+
+    h = top(x)
+    m = top(x - h)
+    l = x - h - m
+    return jnp.concatenate([h, m, l], axis=1).astype(jnp.bfloat16)
+
+
+def selector_bf16x3(sel: jnp.ndarray) -> jnp.ndarray:
+    """One-hot f32 ``[T, F, I]`` → bfloat16 ``[T, 3F, I]``: ``sel`` (0/1:
+    exact) three times along the contraction axis, one for each part of
+    :func:`split_bf16x3`."""
+    return jnp.tile(sel.astype(jnp.bfloat16), (1, 3, 1))
+
+
+def _selector(sel: jnp.ndarray) -> jnp.ndarray:
+    """The selector in the form this backend's contraction takes: on TPU
+    :func:`selector_bf16x3`, elsewhere ``sel`` itself. Derived from the
+    live params inside the jitted program, like ``to_pallas``'s tables — a
+    checkpoint restore or a reload is served without a stale copy,
+    ``GemmEnsemble`` and a checkpoint hold what they held, and model build
+    runs no device program for it: a convert and a broadcast of 382,500
+    entries a call, beside the slab loop."""
+    return selector_bf16x3(sel) if jax.default_backend() == "tpu" else sel
+
+
+def _project(pick: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """f32 ``[B, F]`` → f32 ``[B, T, I]``, ``x[b, feat(t, i)]`` to the bit
+    (0 where a padding node selects nothing; a selected -0.0 comes out
+    +0.0, the sum of it and the other features' zeros, which no compare
+    tells apart). ``pick`` is :func:`_selector`'s form of the selector
+    and says which contraction runs."""
+    if pick.dtype == jnp.bfloat16:
+        return jnp.einsum("bf,tfi->bti", split_bf16x3(x), pick,
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("bf,tfi->bti", x, pick,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _leaf_sum_slab(g: GemmEnsemble, pick: jnp.ndarray, x: jnp.ndarray,
+                   z_mode: str):
+    """:func:`gemm_leaf_sum` for one slab of at most ``LEAF_SLAB_ROWS``;
+    ``pick`` is :func:`_selector`'s form of ``g.sel``."""
+    with step_scope("decide"):
+        go_left = _project(pick, x) <= g.thresh[None]
+    with step_scope("leaves"):
+        if z_mode == "int8":
+            z = jnp.einsum(
+                "bti,til->btl", go_left.astype(jnp.int8),
+                g.path.astype(jnp.int8), preferred_element_type=jnp.int32,
+            )
+            match = z == g.target.astype(jnp.int32)[None]
+        else:
+            on_tpu = jax.default_backend() == "tpu"
+            zdt = (jnp.bfloat16 if (z_mode == "bf16" and on_tpu)
+                   else jnp.float32)
+            z = jnp.einsum(
+                "bti,til->btl", go_left.astype(zdt), g.path.astype(zdt),
+                preferred_element_type=jnp.float32,
+            )
+            match = jnp.abs(z - g.target[None]) < 0.5
+        # Exactly one leaf per tree matches, so the sum over L adds zeros
+        # to one value: exact in any order. The sum over T is the only
+        # rounding step, and its order is pinned (sum_fixed_order), so two
+        # programs that see bit-equal features emit bit-equal
+        # probabilities.
+        per_tree = jnp.sum(jnp.where(match, g.leaf_val[None], 0.0), axis=2)
+        return sum_fixed_order(per_tree, axis=1)
 
 
 def gemm_predict_proba(

@@ -504,6 +504,9 @@ STEP_SCOPES = (
     "assemble",                                    # features/online
     "scale",                                       # models/scaler
     "classify", "fused_step", "learn", "emit",     # engine.step
+    # the two parts of the tree ensembles' classify (models/forest.py):
+    # selector contraction + threshold compare; z contraction + leaf sum
+    "decide", "leaves",
     "exchange", "route", "pack",                   # parallel/step
 )
 

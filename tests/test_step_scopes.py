@@ -22,6 +22,8 @@ stage. For every variant of the step that compiles here at toy size:
 
 import contextlib
 import dataclasses as dc
+import json
+import os
 import re
 import sys
 
@@ -54,6 +56,9 @@ from real_time_fraud_detection_system_tpu.utils.trace import (
     step_scope,
 )
 
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+from benchmark.readers import device_scopes  # noqa: E402
+
 from test_tpu_compile import (  # noqa: E402 (pytest adds tests/ to path)
     claim_loops,
     reads_a_mask_of,
@@ -69,6 +74,10 @@ TABLE = {"customer", "terminal"}
 UPDATE = {"update", "merge", "stamp", "reset", "scatter"}
 QUERY = {"query", "gather", "sum"}
 COMMON = {"unpack", "assemble", "scale", "classify"} | TABLE | UPDATE | QUERY
+# a tree ensemble's classify names its two parts (models/forest.py): the
+# selector contraction with the threshold compare, and the z contraction
+# with the leaf match, select and pinned-order sum
+FOREST_PARTS = {"decide", "leaves"}
 # admit_slots names its three parts: the full-depth probe, the claim
 # rounds (a loop that ends when every row is placed), and the owner /
 # free-stack / roll-back / final resolution
@@ -77,7 +86,7 @@ KEYDIR_PARTS = {"lookup", "claim", "grant"}
 # variant → (kind, FeatureConfig overrides, RuntimeConfig overrides,
 #            sharded over n virtual devices, scopes it must carry)
 VARIANTS = {
-    "forest": ("forest", {}, {}, 0, COMMON),
+    "forest": ("forest", {}, {}, 0, COMMON | FOREST_PARTS),
     "logreg": ("logreg", {}, {}, 0, COMMON),
     "exact": ("logreg", {"key_mode": "exact", "compact_every": 4}, {}, 0,
               COMMON | {"keydir", "cms"} | KEYDIR_PARTS),
@@ -90,16 +99,16 @@ VARIANTS = {
     "cms": ("logreg", {"customer_source": "cms"}, {}, 0,
             COMMON | {"cms"}),
     "selective": ("forest", {}, {"emit_threshold": 0.4}, 0,
-                  COMMON | {"emit"}),
+                  COMMON | FOREST_PARTS | {"emit"}),
     "online_sgd": ("logreg", {}, {"online_lr": 0.01}, 0,
                    COMMON | {"learn"}),
     # the exchange names its parts: ranking rows by owner, packing the
     # send buffer, and (under the engine's own `unpack`) the back-gather
     "sharded": ("forest", {}, {}, 2,
-                COMMON | {"exchange", "route", "pack"}),
+                COMMON | FOREST_PARTS | {"exchange", "route", "pack"}),
     # a mesh of one reaches both tables by a local call: the one-chip
     # step's body inside shard_map, scope path for scope path
-    "sharded_1dev": ("forest", {}, {}, 1, COMMON),
+    "sharded_1dev": ("forest", {}, {}, 1, COMMON | FOREST_PARTS),
 }
 
 
@@ -206,6 +215,63 @@ def test_step_hlo_carries_the_variants_scopes(variant):
         assert len(mesh) == 2
         for paths in mesh:
             assert paths == chip, sorted(paths ^ chip)
+
+
+@pytest.mark.parametrize("variant,rows", [
+    ("forest", 64), ("forest", 2 * 8192 + 64), ("sharded", 64)])
+def test_classify_names_its_two_parts(variant, rows):
+    """``rtfds.decide`` and ``rtfds.leaves`` are opened inside
+    ``rtfds.classify`` and nowhere else, directly under it as the scopes
+    go (past ``LEAF_SLAB_ROWS`` the slab loop's own ``while/body/…`` sit
+    between, which is why the benchmark's metric files name the part
+    alone), every contraction of the stage is in one of them, and the
+    files that read them find them."""
+    eng = _engine(variant)
+    if rows != 64:
+        eng = ScoringEngine(
+            dc.replace(eng.cfg, runtime=dc.replace(
+                eng.cfg.runtime, batch_buckets=(rows,),
+                max_batch_rows=rows)),
+            kind="forest", params=eng.state.params,
+            scaler=eng.state.scaler, metrics=MetricsRegistry())
+    metrics = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "metrics")
+    for low in _lowered_steps(eng):
+        names = _op_names(low.compile().as_text())
+        paths = [_scopes(n) for n in names]
+        held = {part: [n for n, p in zip(names, paths) if part in p]
+                for part in FOREST_PARTS}
+        for n, p in zip(names, paths):
+            for part in FOREST_PARTS & set(p):
+                # (this backend's compiler names what the slab loop calls
+                # from the call down; the chip's keeps the whole path:
+                # tests/test_tpu_compile.py reads it at 65,536 rows)
+                assert p[-2:] == ["classify", part] or (
+                    rows != 64 and p == [part]), n
+            if "classify" in p and n.endswith("dot_general"):
+                assert FOREST_PARTS & set(p), n
+        for part, under in held.items():
+            assert any(n.endswith("dot_general") for n in under), part
+            if rows != 64:  # the parts live in the slab loop's body
+                assert all("while" in n.split("/") for n in under
+                           if n.endswith("dot_general")), part
+            for regime in ("sat", "steady"):
+                with open(os.path.join(
+                        metrics,
+                        f"step_classify_{part}_ms.{regime}.json")) as f:
+                    spec = json.load(f)
+                assert spec["reader"] == "device_scopes"
+                read = [n for n in names if any(
+                    device_scopes.matches(n, s)
+                    for s in spec["args"]["scopes"])]
+                assert read == under, (part, regime)
+        if rows != 64:
+            continue
+        # the whole stage is still one reading: both parts are inside it
+        with open(os.path.join(metrics, "step_classify_ms.sat.json")) as f:
+            whole = json.load(f)["args"]["scopes"]
+        assert all(any(device_scopes.matches(n, s) for s in whole)
+                   for under in held.values() for n in under)
 
 
 @pytest.mark.parametrize("variant", ["exact", "exact64"])

@@ -164,6 +164,65 @@ def test_xla_forest_step_compiles(topo, one_chip, as_on_chip,
     assert "tpu_custom_call" not in compiled.as_text()  # pure XLA
 
 
+_HLO_CONV = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* convolution\("
+    r"%([\w.\-]+), %([\w.\-]+)\)(.*)$")
+_HLO_TYPED = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]")
+
+
+def convolutions_under(hlo_text, scope):
+    """``{"op_name", "result": (dtype, dims), "operands": [(dtype, dims),
+    (dtype, dims)], "highest": bool}`` of every convolution (what a
+    contraction compiles to on the chip) whose ``op_name`` holds
+    ``scope``."""
+    typed = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_TYPED.match(line)
+        if m:
+            typed[m.group(1)] = (
+                m.group(2), tuple(int(d) for d in m.group(3).split(",") if d))
+    out = []
+    for line in hlo_text.splitlines():
+        m = _HLO_CONV.match(line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not m or not name or scope not in name.group(1).split("/"):
+            continue
+        out.append({
+            "op_name": name.group(1),
+            "result": typed[m.group(1)],
+            "operands": [typed[m.group(4)], typed[m.group(5)]],
+            "highest": "highest" in m.group(6).lower()})
+    return out
+
+
+def check_one_pass_selector(hlo_text, slab_rows):
+    """The forest's ``classify`` as the chip's compiler kept it: ONE
+    selector contraction, under ``rtfds.classify`` › ``rtfds.decide``, of
+    bfloat16 operands 3·F deep (x in three parts beside the selector
+    three times, ``models/forest.split_bf16x3``) into float32 — one pass
+    of the MXU — and no float32 × float32 contraction left in the stage:
+    at ``HIGHEST`` that is six passes, 11 ms more a 65,536-row step (my
+    chip runs, PR 47), at anything less a rounded feature."""
+    convs = convolutions_under(hlo_text, "rtfds.classify")
+    decide = [c for c in convs if "rtfds.decide" in c["op_name"].split("/")]
+    leaves = [c for c in convs if "rtfds.leaves" in c["op_name"].split("/")]
+    assert len(decide) == 1 and len(leaves) == 1 and len(convs) == 2, convs
+    (c,) = decide
+    assert c["result"][0] == "f32", c
+    assert sorted(c["result"][1]) == sorted(
+        (slab_rows, N_TREES, 2 ** DEPTH - 1)), c
+    for dtype, dims in c["operands"]:
+        assert dtype == "bf16" and 3 * N_FEAT in dims, c
+        assert N_FEAT not in dims, c
+    # the stage's own metric still reads both parts: the whole path is on
+    # every operation, the slab loop's `while/body/…` between the two
+    for c in convs:
+        scopes = [p for p in c["op_name"].split("/") if p.startswith("rtfds.")]
+        assert scopes[-2] == "rtfds.classify", c
+        assert not c["highest"], c
+        assert all(dtype != "f32" for dtype, _ in c["operands"]), c
+
+
 _HLO_RESULT = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
 _LAYOUT_MOVES = {"copy", "reshape", "transpose", "dynamic-update-slice"}
@@ -202,6 +261,18 @@ def test_step_moves_no_table_between_layouts(topo, one_chip, as_on_chip,
     text = _compiled_step(compiled_steps, one_chip, kind, bucket).as_text()
     for cap in (fcfg.customer_capacity, fcfg.terminal_capacity):
         assert not whole_column_moves(text, {cap * nb})
+
+
+@pytest.mark.parametrize("bucket", [256, 4096, 65536])
+def test_forest_picks_its_split_features_in_one_bf16_pass(
+        topo, one_chip, as_on_chip, compiled_steps, bucket):
+    from real_time_fraud_detection_system_tpu.models.forest import (
+        LEAF_SLAB_ROWS,
+    )
+
+    text = _compiled_step(compiled_steps, one_chip, "forest",
+                          bucket).as_text()
+    check_one_pass_selector(text, min(bucket, LEAF_SLAB_ROWS))
 
 
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([\w\-]+)\((.*)$")
@@ -570,6 +641,9 @@ def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
         fstate, params, scaler, packed).compile()
     text = compiled.as_text()
     assert text.count("all-to-all") >= 2
+    # 32,768 rows a chip: four slabs of the one-chip step's classify
+    assert rows_per_shard == 4 * 8192
+    check_one_pass_selector(text, 8192)
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert 0.2 * 1.9e9 < per_device < 0.3 * 2.1e9  # ~a quarter of the state
 

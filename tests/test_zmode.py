@@ -3,7 +3,7 @@
 ``gemm_leaf_sum``'s dominant z contraction is exact in EVERY reduced-
 precision mode (d is 0/1, path is ±1/0, z counts ≤ depth), and the int8
 mode is additionally BIT-identical to f32: integer z arithmetic, the same
-leaf match, the same f32-HIGHEST proj and pinned-order leaf sum. These tests pin
+leaf match, the same exact proj and pinned-order leaf sum. These tests pin
 that contract across every configured batch-bucket size — including
 threshold-edge inputs — and re-assert the engine-level AOT≡jit parity
 with ``z_mode="int8"`` forced, so the serving default flip on TPU
@@ -35,12 +35,16 @@ BUCKETS = (64, 256, 1024)
 
 
 @pytest.fixture(scope="module")
-def gemm_forest():
+def tree_forest():
     rng = np.random.default_rng(21)
     x = rng.normal(size=(600, N_FEAT)).astype(np.float32)
     y = (x[:, 0] + 0.4 * x[:, 2] > 0.3).astype(np.int32)
-    ens = fit_forest(x, y, n_trees=7, max_depth=5)
-    return for_device(ens, N_FEAT)
+    return fit_forest(x, y, n_trees=7, max_depth=5)
+
+
+@pytest.fixture(scope="module")
+def gemm_forest(tree_forest):
+    return for_device(tree_forest, N_FEAT)
 
 
 def _edge_rows(g, rng, n):
@@ -114,6 +118,215 @@ def test_gbt_int8_bit_identical(gemm_forest):
     a = np.asarray(gbt_predict_proba(model, x, z_mode="f32"))
     b = np.asarray(gbt_predict_proba(model, x, z_mode="int8"))
     assert float(np.abs(a - b).max()) == 0.0
+
+
+# -- the selector contraction in one bf16 pass (PR 47) ----------------------
+#
+# On the chip ``proj`` is one bfloat16 pass over x split into three parts;
+# here the f32 contraction runs. CPU XLA multiplies bf16 by bf16 into f32
+# too, so the chip's FORM is held here by steering the one backend check
+# (``forest._selector``) in the test, on inputs drawn to break a split.
+
+F32_MAX = np.finfo(np.float32).max
+ROW_KINDS = ("threshold", "above", "below", "zero", "pow2", "max", "bits")
+
+
+@pytest.fixture()
+def split_form(monkeypatch):
+    """``forest._selector`` answers as on the chip. (Not a patched
+    ``jax.default_backend``: that also asks for the bf16 z contraction,
+    which has a batch dimension and no CPU kernel.)"""
+    from real_time_fraud_detection_system_tpu.models import forest
+
+    monkeypatch.setattr(forest, "_selector", forest.selector_bf16x3)
+
+
+def _jitted(fn, *args):
+    """``fn`` traced anew (jit's cache may hold the other form's trace)
+    and compiled: both forms go through the same compiler."""
+    import jax
+
+    return np.asarray(jax.jit(lambda *a: fn(*a))(*args))
+
+
+def _thresholds(g):
+    th = np.asarray(g.thresh).ravel()
+    return th[np.isfinite(th)]
+
+
+def _rows_of(kind, g, rng, n):
+    """``[n, N_FEAT]`` f32 rows of one adversarial kind."""
+    th = rng.choice(_thresholds(g), size=(n, N_FEAT)).astype(np.float32)
+    if kind == "threshold":
+        return th
+    if kind == "above":
+        return np.nextafter(th, np.float32(np.inf), dtype=np.float32)
+    if kind == "below":
+        return np.nextafter(th, np.float32(-np.inf), dtype=np.float32)
+    if kind == "zero":
+        return np.where(rng.random((n, N_FEAT)) < 0.5,
+                        np.float32(0.0), np.float32(-0.0))
+    sign = np.where(rng.random((n, N_FEAT)) < 0.5, 1, -1).astype(np.float32)
+    if kind == "pow2":
+        return sign * np.exp2(rng.integers(-120, 121, size=(n, N_FEAT))
+                              ).astype(np.float32)
+    if kind == "max":
+        return sign * F32_MAX
+    assert kind == "bits"  # any f32 bit pattern but NaN / Inf
+    x = rng.integers(0, 1 << 32, size=(n, N_FEAT), dtype=np.uint64
+                     ).astype(np.uint32).view(np.float32)
+    return np.where(np.isfinite(x), x, th)
+
+
+def _adversarial(g, rng, rows):
+    """Every kind in one batch of ``rows``, in equal shares."""
+    per = -(-rows // len(ROW_KINDS))
+    return np.concatenate(
+        [_rows_of(k, g, rng, per) for k in ROW_KINDS])[:rows]
+
+
+def _bits(a):
+    return np.ascontiguousarray(a, dtype=np.float32).view(np.uint32)
+
+
+def _lossless(x):
+    """Where the three parts hold every bit of x on a backend that flushes
+    subnormals: x is 0, or normal with its lowest set bit worth 2^-126 or
+    more. (Below, ``split_bf16x3`` says what is lost.)"""
+    bits = _bits(x).astype(np.int64)
+    exp, man = (bits >> 23) & 0xFF, (bits & 0x7FFFFF) | (1 << 23)
+    low = exp - 127 - 23 + np.log2(man & -man).astype(np.int64)
+    return (x == 0) | ((exp > 0) & (low >= -126))
+
+
+@pytest.mark.parametrize("kind", ROW_KINDS)
+def test_three_bf16_parts_sum_to_x_bitwise(gemm_forest, kind):
+    from real_time_fraud_detection_system_tpu.models.forest import (
+        split_bf16x3,
+    )
+
+    x = _rows_of(kind, gemm_forest, np.random.default_rng(47), 512)
+    parts = split_bf16x3(jnp.asarray(x))
+    assert parts.dtype == jnp.bfloat16 and parts.shape == (512, 3 * N_FEAT)
+    h, m, l = np.split(np.asarray(parts.astype(jnp.float32)), 3, axis=1)
+    assert np.isfinite(h).all()  # a ROUNDED top part of F32_MAX is inf
+    ok = _lossless(x)
+    for total in ((h + m) + l, h + (m + l), (h + l) + m):  # any order
+        # (-0.0 - -0.0 is +0.0: a zero's sign ends with the top part,
+        # and no contraction would carry it past the other features' +0)
+        assert np.array_equal(total[ok], x[ok])
+        assert np.array_equal(_bits(total)[ok & (x != 0)],
+                              _bits(x)[ok & (x != 0)])
+        # what a flushed low part leaves is less than the smallest normal
+        assert (np.abs(total - x)[~ok] < np.finfo(np.float32).tiny).all()
+    if kind != "bits":
+        assert ok.all()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+@pytest.mark.parametrize("form", ["f32", "split"])
+def test_proj_is_the_selected_feature_bitwise(gemm_forest, form, rows,
+                                              monkeypatch):
+    import jax
+
+    from real_time_fraud_detection_system_tpu.models.forest import (
+        _project,
+        _selector,
+    )
+
+    g = gemm_forest
+    if form == "split":  # the compile test's way: the chip's own branch
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pick = _selector(g.sel)
+    assert pick.dtype == (jnp.bfloat16 if form == "split" else jnp.float32)
+    assert pick.shape[1] == (3 if form == "split" else 1) * N_FEAT
+    x = _adversarial(g, np.random.default_rng(rows), rows)
+    proj = np.asarray(_project(pick, jnp.asarray(x)))
+    sel = np.asarray(g.sel)
+    want = np.where(sel.any(axis=1)[None],  # a padding node selects nothing
+                    x[:, sel.argmax(axis=1)], np.float32(0.0))
+    assert proj.shape == want.shape == (rows,) + sel.shape[::2]
+    ok = np.broadcast_to(_lossless(x)[:, sel.argmax(axis=1)], want.shape)
+    assert np.array_equal(proj[ok], want[ok])  # ±0 compare equal
+    nonzero = ok & (want != 0)
+    assert np.array_equal(_bits(proj)[nonzero], _bits(want)[nonzero])
+    # and the descent's own comparison follows, whatever was flushed
+    th = np.asarray(g.thresh)[None]
+    assert np.array_equal(proj <= th, want <= th)
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+@pytest.mark.parametrize("z_mode", ["f32", "bf16", "int8"])
+def test_split_form_scores_as_the_f32_form_and_the_descent(
+        tree_forest, gemm_forest, z_mode, rows, monkeypatch, request):
+    from real_time_fraud_detection_system_tpu.models.forest import (
+        ensemble_predict_proba,
+    )
+
+    g = gemm_forest
+    x = jnp.asarray(_adversarial(g, np.random.default_rng(rows + 1), rows))
+
+    def score(g, x):
+        return gemm_predict_proba(g, x, z_mode=z_mode)
+
+    p_f32 = _jitted(score, g, x)
+    descent = np.asarray(ensemble_predict_proba(tree_forest, x))
+    request.getfixturevalue("split_form")
+    p_split = _jitted(score, g, x)
+    assert np.array_equal(_bits(p_split), _bits(p_f32))
+    # one flipped node moves a row by a leaf's vote, 1/7 of ~0.1 and more;
+    # the two forms add the seven votes in different orders
+    assert float(np.abs(p_split - descent).max()) < 1e-6
+    sure = np.abs(descent - 0.5) > 1e-6
+    assert np.array_equal((p_split >= 0.5)[sure], (descent >= 0.5)[sure])
+
+
+@pytest.mark.parametrize("z_mode", ["f32", "bf16", "int8"])
+def test_gbt_split_form_bit_identical(tree_forest, gemm_forest, z_mode,
+                                      request):
+    from real_time_fraud_detection_system_tpu.models.gbt import (
+        GBTModel,
+        gbt_predict_proba,
+    )
+
+    model = GBTModel(trees=gemm_forest, base_score=jnp.float32(-0.7))
+    walked = GBTModel(trees=tree_forest, base_score=jnp.float32(-0.7))
+    x = jnp.asarray(_adversarial(gemm_forest, np.random.default_rng(9), 256))
+
+    def score(m, x):
+        return gbt_predict_proba(m, x, z_mode=z_mode)
+
+    a = _jitted(score, model, x)
+    w = np.asarray(gbt_predict_proba(walked, x))
+    request.getfixturevalue("split_form")
+    b = _jitted(score, model, x)
+    assert np.array_equal(_bits(a), _bits(b))
+    assert float(np.abs(b - w).max()) < 1e-6
+
+
+def test_slabs_share_one_selector(gemm_forest, split_form):
+    """The tripled selector is made once a call, beside the slab loop: the
+    loop takes it as an input and no slab makes it again."""
+    import jax
+
+    from real_time_fraud_detection_system_tpu.models.forest import (
+        LEAF_SLAB_ROWS,
+        gemm_leaf_sum,
+    )
+
+    x = jnp.zeros((2 * LEAF_SLAB_ROWS, N_FEAT), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda g, x: gemm_leaf_sum(g, x, "int8"))(gemm_forest, x)
+    (loop,) = [e for e in jaxpr.jaxpr.eqns if str(e.primitive) == "scan"]
+    t, f, i = gemm_forest.sel.shape
+
+    def tripled(v):
+        return (getattr(v.aval, "shape", None) == (t, 3 * f, i)
+                and v.aval.dtype == jnp.bfloat16)
+
+    assert sum(tripled(v) for v in loop.invars) == 1
+    body = loop.params["jaxpr"].jaxpr
+    assert not any(tripled(v) for e in body.eqns for v in e.outvars)
 
 
 def test_resolve_z_mode():

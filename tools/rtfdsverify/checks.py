@@ -138,7 +138,15 @@ class AotCoverageCheck:
 class ZModeExactnessCheck:
     """The PR-9 exactness contract, structurally: walk every
     ``dot_general``/``convert_element_type`` in the traced scoring
-    program and prove the dtype lattice."""
+    program and prove the dtype lattice.
+
+    What is walked is the program as traced HERE, off the chip, where
+    the decision projection is the f32-HIGHEST contraction. On a TPU
+    ``models/forest._selector`` picks the projection's other exact form,
+    x in three bfloat16 parts through one bf16 pass (PR 47): its
+    losslessness is a VALUE fact (``h + m + l == x``) no dtype lattice
+    can carry — ``tests/test_zmode.py`` holds it on inputs drawn to break
+    it and ``tests/test_tpu_compile.py`` reads the compiled contraction."""
 
     name = "zmode-exactness"
     doc = ("int8/bf16 z arithmetic stays exact by construction: integer "

@@ -3,7 +3,8 @@
 The GEMM forest (forest.py:226-256) measures ~5% MFU on v5e. Its three
 stages have very different hardware shapes:
 
-  proj  einsum bf,tfi->bti  f32 HIGHEST  (K=15: thin, 6-pass)
+  proj  einsum bf,tfi->bti  on the chip x in three bf16 parts, K=45,
+                            ONE pass (PR 47; f32 HIGHEST, K=15, was six)
   z     einsum bti,til->btl bf16->f32    (the FLOPs; K=I~100)
   leaf  einsum btl,tl->b    f32 HIGHEST  (reduction)
 
@@ -49,6 +50,8 @@ def main() -> None:
     from sklearn.ensemble import RandomForestClassifier
 
     from real_time_fraud_detection_system_tpu.models.forest import (
+        _project,
+        _selector,
         ensemble_from_sklearn,
         gemm_predict_proba,
         to_gemm,
@@ -82,8 +85,8 @@ def main() -> None:
     # (their thresh is +inf so the decision is always True — same as the
     # matmul form where proj=0 <= inf).
 
-    def stage_proj(x):
-        return jnp.einsum("bf,tfi->bti", x, g.sel, precision=hi)
+    def stage_proj(x):  # the shipping form of this backend
+        return _project(_selector(g.sel), x)
 
     def stage_z(d):
         return jnp.einsum("bti,til->btl", d, g.path.astype(zdt),
