@@ -6,7 +6,6 @@
 #   make train       — offline training                     (≈ notebooks)
 #   make score       — stream-score through the engine      (≈ make fraud_detection)
 #   make run-all     — datagen + train + score              (≈ make run-all)
-#   make bench       — benchmark harness (full JSON line + compact headline)
 #   make chip-smoke  — the main path end to end on the attached TPU
 #   make test        — pytest on a virtual 8-device CPU mesh
 #   make install     — editable install incl. the `rtfds` console script
@@ -64,9 +63,6 @@ dryrun:
 trace-demo:
 	@mkdir -p $(OUT)
 	JAX_PLATFORMS=cpu $(PY) tools/trace_demo.py --out $(OUT)/trace_demo.json
-
-bench:
-	$(PY) bench.py
 
 # the quickest proof that the system still starts on the chip: datagen →
 # train → score (envelope mode, 2^20+2^21-slot state, 65,536-row batches)
@@ -221,4 +217,4 @@ install:
 clean:
 	rm -rf $(OUT)
 
-.PHONY: demo datagen train score run-all query dashboard connectors dryrun trace-demo bench chip-smoke perf-smoke chaos-smoke recovery-smoke overload-smoke state-smoke learn-smoke multihost-smoke elastic-smoke lint-static verify-static test integration integration-up integration-down sqlcheck install clean
+.PHONY: demo datagen train score run-all query dashboard connectors dryrun trace-demo chip-smoke perf-smoke chaos-smoke recovery-smoke overload-smoke state-smoke learn-smoke multihost-smoke elastic-smoke lint-static verify-static test integration integration-up integration-down sqlcheck install clean

@@ -9,8 +9,7 @@ Reference targets (``Makefile:2-58``) → subcommands:
 - ``make fraud_detection`` → ``score --scorer {cpu,tpu}`` (the north-star
   switch): stream a table through the engine, Parquet out;
 - ``make job3`` (CDC ingestion incl. envelope decode) → ``score
-  --mode envelope`` replays through Debezium-format envelopes;
-- benchmarking → ``bench`` (delegates to the repo-root harness).
+  --mode envelope`` replays through Debezium-format envelopes.
 
 Usage::
 
@@ -1314,7 +1313,7 @@ def cmd_score(args) -> int:
             server.stop()
         if args.metrics_dump:
             # success or failure: the registry snapshot is how the
-            # multihost bench/smoke assert recompile counts per worker
+            # multihost smoke asserts recompile counts per worker
             # without scraping a live port
             from real_time_fraud_detection_system_tpu.utils.metrics \
                 import get_registry
@@ -2344,16 +2343,6 @@ def cmd_select(args) -> int:
     return 0
 
 
-def cmd_bench(args) -> int:
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo_root)
-    import bench
-
-    # in this process: bench starts no child, so one process holds the chip
-    bench.main(["--quick"] if args.quick else [])
-    return 0
-
-
 def cmd_lint(args) -> int:
     """Project-native static analysis (tools/rtfdslint).
 
@@ -2755,9 +2744,9 @@ def main(argv=None) -> int:
                    help="cap assembled micro-batches at this many rows "
                         "(0 = config default 65536). The sharded "
                         "engine's per-chunk step width derives from it "
-                        "(2x the balanced per-device load), so smoke/"
-                        "bench fleets size their compiled step with "
-                        "this knob")
+                        "(2x the balanced per-device load), so smoke "
+                        "fleets size their compiled step with this "
+                        "knob")
     p.add_argument("--coordinator", default="",
                    help="host:port of process 0's jax.distributed "
                         "coordination service — multi-host fleets "
@@ -2777,8 +2766,8 @@ def main(argv=None) -> int:
                    help="write the final registry snapshot "
                         "(/metrics.json content) to this path at run "
                         "end, success or failure — the artifact the "
-                        "multihost bench/smoke assert zero recompiles "
-                        "from without scraping a live port")
+                        "multihost smoke asserts zero recompiles from "
+                        "without scraping a live port")
     p.add_argument("--trace-dir", default="",
                    help="capture a jax.profiler/TensorBoard trace of the "
                         "serving run into this directory")
@@ -3074,10 +3063,6 @@ def main(argv=None) -> int:
     p.add_argument("--folds", type=int, default=4)
     p.add_argument("--epochs", type=int, default=3)
     p.set_defaults(fn=cmd_select)
-
-    p = sub.add_parser("bench", help="run the benchmark harness")
-    p.add_argument("--quick", action="store_true")
-    p.set_defaults(fn=cmd_bench)
 
     p = sub.add_parser(
         "lint",

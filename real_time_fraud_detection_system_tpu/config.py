@@ -417,19 +417,17 @@ class RuntimeConfig:
     # Fused Pallas featurize+score kernels (ops/pallas_kernels.py for the
     # linear scorer, ops/pallas_forest.py::fused_forest_leaf_sum for tree
     # ensembles). Interpreted (slow, exact) off-TPU.
-    # Stays opt-in by measurement, not neglect: on a real v5e the linear
-    # fused kernel and the plain-jnp composition are within ±2% (bench
-    # detail `pallas_fused`, 2026-07-30: 2.94M vs 2.91M rows/s,
-    # max|Δ| 2.4e-7) — XLA's automatic fusion already captures the win
-    # there. The forest fused step attacks the scatter boundary XLA
-    # cannot fuse through; its A/B lives in bench detail `device_plane`.
+    # Stays opt-in: no ledger cell runs either kernel, so their speed
+    # against the XLA composition is not measured on chip (ROADMAP C3);
+    # chip_smoke.py holds their results to XLA's. The forest fused step
+    # attacks the scatter boundary XLA cannot fuse through.
     use_pallas: bool = False
     # MXU arithmetic for the tree-ensemble z contraction
     # (models/forest.py::gemm_leaf_sum — the dominant classify matmul,
     # exact in EVERY mode because its operands are tiny integers):
-    # "auto" = int8 on TPU (2× bf16 MXU peak on v5e, measured bit-exact
-    # vs f32 — bench detail z_mode/device_plane), f32 elsewhere (the
-    # only float mode CPU XLA lowers natively). Forced "int8"/"bf16"/
+    # "auto" = int8 on TPU (2× bf16 MXU peak on v5e, bit-exact vs f32;
+    # the chip's sweep of the three is a tie, ROADMAP C4), f32 elsewhere
+    # (the only float mode CPU XLA lowers natively). Forced "int8"/"bf16"/
     # "f32" pin the mode on any backend; decisions are identical by the
     # exactness contract (README § Device plane).
     z_mode: str = "auto"

@@ -447,7 +447,7 @@ def zipf_stream_cols(
     tx_id_start: int = 0,
 ) -> dict:
     """One micro-batch of engine-ready columns from a Zipf-skewed key
-    universe (the ``bench.py detail.state_scale`` load shape): customer
+    universe (the load shape of ``tests/test_state_smoke.py``): customer
     keys from ``customers``, terminals Zipf-skewed over ``n_terminals``
     with the same exponent, timestamps uniform inside ``day``."""
     cust = customers.sample(rng, n)

@@ -191,9 +191,8 @@ def state_bytes(cfg: FeatureConfig, n_shards: int = 1) -> dict:
     access, no allocation). Keys: ``dense`` (window tables),
     ``directory`` (key directories + free lists), ``cms`` (all
     sketches), ``total``. The ``--state-hbm-budget-mb`` engine-build
-    check and bench's ``detail.state_scale`` both read this, so the
-    budget the operator sets and the bytes the bench reports cannot
-    drift. ``n_shards``: the sharded engine passes its width — window
+    check reads this; the bytes a chip really holds are the ledger's
+    ``peak_hbm_gb``. ``n_shards``: the sharded engine passes its width — window
     tables and directories partition (same total bytes, plus one
     free_top scalar per shard), but each shard carries its OWN sketch
     replica, so the cms tier multiplies."""

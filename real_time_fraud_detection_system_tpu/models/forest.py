@@ -258,9 +258,9 @@ def to_gemm(ens: TreeEnsemble, n_features: int) -> GemmEnsemble:
 def resolve_z_mode(mode: str | None) -> str:
     """``RuntimeConfig.z_mode`` → a concrete :func:`gemm_leaf_sum` mode.
 
-    ``"auto"`` (and None) picks int8 on TPU — the measured MXU winner
-    (bench ``detail.z_mode``: int8 peaks ~2× bf16 on v5e with
-    ``max_abs_delta_int8_vs_f32 == 0``) — and f32 elsewhere (the only
+    ``"auto"`` (and None) picks int8 on TPU — int8 peaks ~2× bf16 on
+    v5e and is bit-equal to f32; in the served forest the three modes
+    tie on the chip (ROADMAP C4) — and f32 elsewhere (the only
     float mode CPU XLA lowers natively). Every mode is decision-exact by
     the contract documented on :func:`gemm_leaf_sum`; int8 is
     additionally BIT-identical to f32 (integer z arithmetic, same

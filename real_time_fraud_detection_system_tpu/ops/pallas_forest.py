@@ -46,8 +46,8 @@ decisions equal, count/flag/amount/risk columns bit-identical — and the
 three average-amount columns NOT bit-identical (last-bit differences:
 Mosaic and XLA sum the 40 day buckets' f32 dollar amounts in a different
 order; interpret mode on the CPU cannot show this). Its SPEED against the
-XLA composition is **not measured** (``bench.py`` ``detail.device_plane``
-is wired for it); the kernel stays **opt-in**
+XLA composition is **not measured** (no ledger cell runs it, ROADMAP
+C3); the kernel stays **opt-in**
 (``RuntimeConfig.use_pallas``) until a chip measurement says otherwise.
 Interpret-mode parity vs the unfused jit composition (same rows, all
 buckets) is pinned in ``tests/test_pallas_forest.py``.

@@ -834,8 +834,7 @@ class ScoringEngine:
     def _check_state_budget(self) -> None:
         """``features.state_hbm_budget_mb``: fail the BUILD, not the
         stream, when the configured feature state cannot fit the budget
-        (static ``state_bytes`` accounting; the same numbers bench's
-        ``detail.state_scale`` reports)."""
+        (static ``state_bytes`` accounting)."""
         fcfg = self.cfg.features
         if fcfg.state_hbm_budget_mb <= 0:
             return
@@ -2565,8 +2564,8 @@ class ScoringEngine:
         t_start = time.perf_counter()
         # CPU time of the serving loop proper (precompile excluded —
         # the AOT block above ran before this line). rows / cpu_s is the
-        # load-immune per-process rate the multihost scaling bench
-        # gates on: on shared CI cores, wall-clock rows/s of N
+        # load-immune per-process rate tools/multihost_launcher.py
+        # reports: on shared CI cores, wall-clock rows/s of N
         # concurrent processes measures the box, not the coordination
         # cost this repo is accountable for.
         t_cpu0 = time.process_time()

@@ -39,7 +39,7 @@ def enable_compilation_cache() -> str:
     One rule. ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself and
     this function sets no directory in code. Unset: the fixed
     in-checkout ``.jax_cache`` (git-ignored). Every entry point — the
-    CLI, ``bench.py``, ``chip_smoke.py``, the tools — goes through here.
+    CLI, ``chip_smoke.py``, the tools — goes through here.
 
     The minimum-compile-time threshold drops from jax's 1 s to
     ``CACHE_MIN_COMPILE_S`` so the serving step programs are actually

@@ -394,7 +394,8 @@ def test_engine_populates_registry_and_flight_record(
 
 def test_engine_run_stats_shape_unchanged(engine_cfg, trained_logreg):
     """The LatencyTracker-backed stats keep the report contract that
-    bench.py / pipeline.py consume."""
+    the benchmark's readers (benchmark/readers/run_stats.py) and
+    pipeline.py consume."""
     from real_time_fraud_detection_system_tpu.runtime import (
         ReplaySource,
         ScoringEngine,

@@ -9,6 +9,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -28,20 +30,6 @@ def test_hw_parity_check_cpu():
     assert out["forest_gemm_max_abs_diff"] < 1e-5
     assert out["feature_kernel_max_abs_diff"] < 1e-4
     assert out["auc_abs_gap"] < 1e-3
-
-
-def test_step_profile_variants_exact_cpu():
-    p = _run(
-        [sys.executable, "tools/tpu_step_profile.py"],
-        extra_env={"PROFILE_ROWS": "512"},
-        timeout=560,
-    )
-    assert p.returncode == 0, p.stderr[-800:]
-    out = json.loads(p.stdout.strip().splitlines()[-1])
-    for variant in ("current", "projHIGH", "gatherD", "flatproj", "int8z"):
-        assert out[variant]["max_abs_diff_vs_sklearn"] < 1e-5, (
-            variant, out[variant],
-        )
 
 
 def test_parquet_sql_check():
@@ -93,10 +81,10 @@ def test_parquet_sql_check_dedups_replayed_parts(tmp_path):
     assert out["ok"] is True and out["rows"] == 100
 
 
-def _run_chip_smoke(cwd, script):
+def _run_chip_smoke(cwd, script, *args):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
-    return subprocess.run([sys.executable, script], cwd=cwd, env=env,
+    return subprocess.run([sys.executable, script, *args], cwd=cwd, env=env,
                           capture_output=True, text=True, timeout=300)
 
 
@@ -108,6 +96,48 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     assert "no TPU" in r.stderr
     assert '"ok"' not in r.stdout
     assert "[main]" not in r.stdout and "[kernels]" not in r.stdout
+
+
+def test_benchmark_refuses_to_run_without_a_tpu():
+    """`python benchmark/run.py`, the one way to measure, in a sandbox with
+    no accelerator: exit 3, no result line, never a CPU figure under a
+    device metric's name."""
+    r = _run_chip_smoke(REPO, os.path.join(REPO, "benchmark", "run.py"),
+                        "--workload", "forest.saturate", "--seed", "1",
+                        "--seconds", "4", "--trace", "0")
+    assert r.returncode == 3, r.stderr[-800:]
+    assert "never measures on the CPU" in r.stderr
+    assert "rows_per_s" not in r.stdout and '"correct"' not in r.stdout
+
+
+class _FakeChip:
+    platform = "tpu"
+
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+@pytest.mark.parametrize("kind,seen,chips,says", [
+    ("Quantum Abacus 9000", 1, 1, "no peaks on record"),
+    ("TPU v5 lite", 1, 4, "needs 4 chips"),
+    ("TPU v5 lite", 4, 4, None),
+])
+def test_benchmark_claims_only_chips_it_can_judge(monkeypatch, kind, seen,
+                                                  chips, says):
+    """A device that is not in ``benchmark/peaks.json`` is an error, never
+    an assumed v5e; so are fewer chips than the cell asks for."""
+    import jax
+
+    from benchmark import harness
+
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeChip(kind)] * seen)
+    # claim_device points the persistent cache at the benchmark's own
+    monkeypatch.setattr(jax.config, "update", lambda name, value: None)
+    if says is None:
+        assert len(harness.claim_device(REPO, chips, allow_cpu=False)) == chips
+    else:
+        with pytest.raises(harness.HarnessError, match=says):
+            harness.claim_device(REPO, chips, allow_cpu=False)
 
 
 def test_chip_smoke_alone_without_the_program_fails(tmp_path):
