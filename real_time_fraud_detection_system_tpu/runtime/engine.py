@@ -864,6 +864,8 @@ class ScoringEngine:
         self._m_compactions = None
         self._m_compact_s = None
         self._m_sweeps = None
+        self._m_day_rollovers = None
+        self._m_multi_day = None
         if self._exact:
             self._m_compactions = reg.counter(
                 "rtfds_state_compactions_total",
@@ -937,6 +939,14 @@ class ScoringEngine:
                         table=t)
                     for t, present in tables if present
                 }
+                self._m_day_rollovers = reg.counter(
+                    "rtfds_event_day_rollovers_total",
+                    "event days by which the newest day the stream has "
+                    "seen (compaction's now_day) moved forward; the "
+                    "stream's first batch sets it and counts nothing")
+                self._m_multi_day = reg.counter(
+                    "rtfds_batches_multi_day_total",
+                    "batches that held rows of more than one event day")
         if self._exact or fcfg.state_hbm_budget_mb > 0:
             sb = state_bytes(fcfg, n_shards=self._state_shards())
             for tier in ("dense", "directory", "cms", "total"):
@@ -1291,7 +1301,9 @@ class ScoringEngine:
 
     def _note_batch_days(self, cols: dict) -> None:
         """Track the newest day the stream has seen — compaction's
-        recency cutoff input (one vectorized max per batch)."""
+        recency cutoff input (one vectorized max and min per batch) —
+        and count how far it moves: live, a day is 86,400 s of batches;
+        under a replay it may be a handful."""
         if not self._compact_every:
             return
         us = cols.get("tx_datetime_us")
@@ -1300,8 +1312,13 @@ class ScoringEngine:
                 US_PER_DAY,
             )
 
-            self._max_day = max(self._max_day,
-                                int(np.max(us) // US_PER_DAY))
+            newest = int(np.max(us) // US_PER_DAY)
+            if int(np.min(us) // US_PER_DAY) != newest:
+                self._m_multi_day.inc()
+            if newest > self._max_day:
+                if self._max_day:
+                    self._m_day_rollovers.inc(newest - self._max_day)
+                self._max_day = newest
 
     def _maybe_compact(self) -> None:
         """Run the recency-compaction step on its cadence (called once
