@@ -255,7 +255,8 @@ class TableState(NamedTuple):
     directory: Optional[KeyDirectory] = None  # key_mode="exact"
     sketch: Optional[CountMinSketch] = None
     tier: Optional[jnp.ndarray] = None  # exact: [dense, cms] rows served
-    rounds: Optional[jnp.ndarray] = None  # exact: claim rounds the admit ran
+    # exact: [claim rounds the admit ran, those of them run narrow]
+    rounds: Optional[jnp.ndarray] = None
     # key_bits=64: [rows that met another key under their fingerprint,
     # verify trips] of the admit's lookup
     alias: Optional[jnp.ndarray] = None
@@ -303,7 +304,7 @@ class TablePlane:
         exact = self.cfg.key_mode == "exact"
         return TableState(win, kd, sk,
                           jnp.zeros(2, jnp.float32) if exact else None,
-                          jnp.zeros((), jnp.float32) if exact else None,
+                          jnp.zeros(2, jnp.float32) if exact else None,
                           jnp.zeros(2, jnp.float32)
                           if self.cfg.key_bits == 64 else None)
 
@@ -389,11 +390,12 @@ def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
     rows stand (one chip; a mesh's owner-placed customers), the sharded
     step passes its exchange. ``state`` is one owner's view (a mesh
     unstacks its per-device leaves first). Returns ``(state', customer
-    [B, 2·NW], terminal [B, 2·NW], tier [4] | None, overflows)`` — under
+    [B, 2·NW], terminal [B, 2·NW], tier [6] | None, overflows)`` — under
     ``exact`` ``tier`` is ``[dense rows, cms rows, customer claim rounds,
-    terminal claim rounds]``, the one small vector a batch's finish
-    fetches for the registry; at ``key_bits=64`` two more ride behind,
-    ``[…, alias rows, alias verify trips]``, both tables summed.
+    terminal claim rounds, customer narrow rounds, terminal narrow
+    rounds]``, the one small vector a batch's finish fetches for the
+    registry; at ``key_bits=64`` two more ride behind, ``[…, alias rows,
+    alias verify trips]``, both tables summed.
     """
     fraud = fraud_of(batch)
 
@@ -408,7 +410,7 @@ def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
     t, t_mat, t_over = (reach_terminal or local)(
         t_plane, t_plane.of(state), batch.terminal_key, fraud)
     tier = None if t.tier is None else jnp.concatenate(
-        [c.tier + t.tier, jnp.stack([c.rounds, t.rounds])]
+        [c.tier + t.tier, jnp.stack([c.rounds, t.rounds], 1).reshape(-1)]
         + ([] if t.alias is None else
            [t.alias if c.alias is None else c.alias + t.alias]))
     state = FeatureState(
@@ -466,12 +468,14 @@ def update_and_featurize_exact(
 ) -> Tuple[FeatureState, jnp.ndarray, jnp.ndarray]:
     """:func:`update_and_featurize` under ``key_mode="exact"``.
 
-    Returns (new_state, features [B, 15], tier [4] float32) where
+    Returns (new_state, features [B, 15], tier [6] float32) where
     ``tier[:2] = [dense, cms]`` counts (row × keyspace) admissions this
     batch — the device-side source of
-    ``rtfds_feature_tier_rows_total{tier=…}`` — and ``tier[2:]`` the claim
+    ``rtfds_feature_tier_rows_total{tier=…}`` — ``tier[2:4]`` the claim
     rounds the customer and the terminal admit ran
-    (``rtfds_keydir_claim_rounds_total{table=…}``). With the hot tier
+    (``rtfds_keydir_claim_rounds_total{table=…}``) and ``tier[4:6]``
+    those of them that ran narrow
+    (``rtfds_keydir_narrow_rounds_total{table=…}``). With the hot tier
     sized to hold every key this path is bit-identical to ``direct`` mode.
     """
     state, c_mat, t_mat, tier, _ = run_planes(state, batch, cfg)
@@ -885,11 +889,12 @@ def promote_rows(
     required every cold bucket to be strictly pre-eviction-day, and
     post-return writes land on days >= the return day, so cold and hot
     buckets never contend for the same day. Returns ``(state,
-    stats [2, 3] int32)`` = per-table ``[admitted, dropped, claim
-    rounds]`` (dropped: the free list ran dry or every probe position was
-    taken — the engine stops the run before that batch is delivered;
-    rounds: what the admit ran, for
-    ``rtfds_keydir_claim_rounds_total``). The caller guarantees unique
+    stats [2, 4] int32)`` = per-table ``[admitted, dropped, claim
+    rounds, narrow rounds]`` (dropped: the free list ran dry or every
+    probe position was taken — the engine stops the run before that
+    batch is delivered; rounds: what the admit ran, for
+    ``rtfds_keydir_claim_rounds_total`` and
+    ``rtfds_keydir_narrow_rounds_total``). The caller guarantees unique
     keys per dispatch.
     """
     out = {}
@@ -901,7 +906,7 @@ def promote_rows(
         pay = payload.get(ws_name)
         if kd is None or pay is None:
             out[dir_name], out[ws_name] = kd, ws
-            stats.append(jnp.zeros((3,), jnp.int32))
+            stats.append(jnp.zeros((4,), jnp.int32))
             continue
         keys, bd, cnt, amt, frd = pay
         # the admit carries rtfds.keydir and its parts, as in the step;
@@ -922,7 +927,8 @@ def promote_rows(
                 for cold, row in zip((bd, cnt, amt, frd), hot)))
             adm_n = jnp.sum(adm.astype(jnp.int32))
             drop_n = jnp.sum((valid & ~adm).astype(jnp.int32))
-            stats.append(jnp.stack([adm_n, drop_n, rounds]))
+            stats.append(jnp.concatenate(
+                [jnp.stack([adm_n, drop_n]), rounds]))
     with step_scope("promote"):
         stats = jnp.stack(stats)
     return (

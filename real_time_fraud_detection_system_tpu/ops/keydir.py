@@ -24,10 +24,15 @@ Everything is vectorized, fixed-shape and jit/shard_map-friendly:
   win the same entry; a scatter-min of the row index picks ONE owner to
   pop the free-slot stack, so one key costs one slot. The rounds do end
   early, and exactly: they follow the full-depth lookup, only rows whose
-  key it did not find take part, and a round with no unplaced row is an
-  identity — so the loop runs while a round is left and a row is
+  key it did not find take part, and a round is an identity for every
+  placed row — so the rounds run while one is left and a row is
   unplaced (P at most; none for a batch of known keys; ~log(new keys) ÷
-  log(1 ÷ load) while keys arrive) and its answers are the P rounds';
+  log(1 ÷ load) while keys arrive) and their answers are the P rounds'.
+  And they run as wide as what is unplaced: over the batch while more
+  rows are unplaced than ``CLAIM_LANES``, then over those rows alone,
+  packed once into that many lanes — what is unplaced thins by the
+  directory's load a round, a round costs what its rows cost, so all
+  but a batch's first round or two are narrow (``_claim_rounds``);
 - **the free-slot stack** (``free``/``free_top``) is the admission
   bound: when it runs dry the claimed entry is rolled back and the row
   reports ``admitted=False`` — a full hot tier degrades to the sketch
@@ -290,15 +295,17 @@ def admit_slots(
            Optional[jnp.ndarray]]:
     """Lookup-or-insert a batch of keys; the hot path's admission op.
 
-    Returns ``(kd', slot [B] int32, admitted [B] bool, rounds [] int32,
+    Returns ``(kd', slot [B] int32, admitted [B] bool, rounds [2] int32,
     alias)``.
     A row is admitted iff its key already owned a slot or could claim a
     directory entry within ``n_probes`` probes AND a free slot remained;
     batch duplicates of one key share a single slot. Non-admitted rows
     return slot 0 and MUST be masked out of dense-tier scatters (the
-    caller serves them from the sketch tier). ``rounds`` is how many claim
-    rounds ran, 0..``n_probes``: none when the lookup found every key
-    (``rtfds_keydir_claim_rounds_total`` counts them). A wide directory
+    caller serves them from the sketch tier). ``rounds`` is ``[claim
+    rounds run, those of them run narrow]`` (:func:`_claim_rounds`), each
+    0..``n_probes``: none when the lookup found every key
+    (``rtfds_keydir_claim_rounds_total`` and
+    ``rtfds_keydir_narrow_rounds_total`` count them). A wide directory
     (``key`` is ``[2, B]``) goes through :func:`admit_wide`, whose
     ``alias`` counts are the fifth value; a one-word directory has none
     to count and returns ``None`` there.
@@ -328,45 +335,9 @@ def admit_slots(
                 hit0,
                 jnp.take_along_axis(pos, pidx[:, None], axis=1)[:, 0], 0)
         with step_scope("claim"):
-            # The rounds as ONE loop body (unrolled, 2 x 16 rounds of a
-            # scatter and two gathers made each of the five bucket programs
-            # compile ~16 s on the chip: PERF.md, PR 32), run WHILE a round
-            # is left and a row is still unplaced. With every row placed
-            # ``hit`` and ``want`` are all false and ``cand`` is all
-            # EMPTY_KEY, whose scatter-min changes nothing: the rounds not
-            # run are identities, so the answers are the fixed P rounds' to
-            # the bit (tests/test_keydir.py pins them) and a batch of known
-            # keys runs none. A key no round can place holds its batch to
-            # all P.
-            def claim_round(carry):
-                j, keys, entry, placed, claimed = carry
-                p = _probe_position(  # pos[:, j]
-                    key, j.astype(jnp.uint32), dir_cap)
-                cur = keys[p]
-                # batch duplicates of a key claimed in an EARLIER round
-                # match here (pre-call lookup could not see that claim)
-                hit = (~placed) & (cur == key)
-                entry = jnp.where(hit, p, entry)
-                placed = placed | hit
-                # Claim attempt: scatter-min our key into still-empty
-                # positions; among racing writers the smallest key wins,
-                # losers re-probe.
-                want = (~placed) & (cur == EMPTY_KEY)
-                cand = jnp.where(want, key, EMPTY_KEY)
-                keys = keys.at[p].min(cand)
-                won = want & (keys[p] == key)
-                entry = jnp.where(won, p, entry)
-                return j + 1, keys, entry, placed | won, claimed | won
-
-            def unplaced_with_a_round_left(carry):
-                j, _, _, placed, _ = carry
-                return (j < n_probes) & ~placed.all()
-
             # claimed: matched via a claim made NOW
-            rounds, keys, entry, placed, claimed = jax.lax.while_loop(
-                unplaced_with_a_round_left, claim_round,
-                (jnp.int32(0), keys, entry, ~valid | hit0,
-                 jnp.zeros(B, dtype=bool)))
+            keys, entry, placed, claimed, rounds = _claim_rounds(
+                keys, key, entry, ~valid | hit0, n_probes, match=True)
         with step_scope("grant"):
             # One owner per newly claimed entry (batch duplicates of one
             # new key all carry claimed=True on the same entry; exactly one
@@ -419,7 +390,7 @@ def admit_wide(
 ) -> Tuple[KeyDirectory, jnp.ndarray, jnp.ndarray, jnp.ndarray,
            jnp.ndarray]:
     """:func:`admit_slots` for 64-bit keys → ``(kd', slot, admitted,
-    rounds, alias [2] int32)``: two ids are one key only if both words
+    rounds [2], alias [2] int32)``: two ids are one key only if both words
     agree. ``alias`` is the lookup's ``[rows that met another key under
     their fingerprint, verify trips]``
     (``rtfds_keydir_alias_rows_total`` / ``…_trips_total``).
@@ -450,28 +421,12 @@ def admit_wide(
         entry, hit0, alias = _find_wide(kd, key, fp, valid, n_probes)
         unplaced = valid & ~hit0
 
-    def claim_round(carry):
-        j, keys, entry, placed, claimed = carry
-        p = _probe_position(fp, j.astype(jnp.uint32), dir_cap)
-        want = ~placed & (keys[p] == EMPTY_KEY)
-        keys = keys.at[p].min(
-            jax.lax.select(want, fp, jnp.full_like(fp, EMPTY_KEY)))
-        won = want & (keys[p] == fp)
-        return (j + 1, keys, jax.lax.select(won, p, entry),
-                placed | won, claimed | won)
-
-    def unplaced_with_a_round_left(carry):
-        j, _, _, placed, _ = carry
-        return (j < n_probes) & ~placed.all()
-
     def one_pass(carry):
         (n, rounds, keys, klo, khi, slots, free_top, entry, todo,
          got) = carry
         with _part("claim"):
-            ran, keys, entry, _, claimed = jax.lax.while_loop(
-                unplaced_with_a_round_left, claim_round,
-                (jnp.int32(0), keys, entry, ~todo,
-                 jnp.zeros(B, dtype=bool)))
+            keys, entry, _, claimed, ran = _claim_rounds(
+                keys, fp, entry, ~todo, n_probes, match=False)
         with _part("grant"):
             rows = jnp.arange(B, dtype=jnp.int32)
             drop = jnp.full_like(entry, dir_cap)
@@ -514,9 +469,9 @@ def admit_wide(
     (_, rounds, keys, klo, khi, slots, free_top, entry, _,
      got) = jax.lax.while_loop(
         a_twin_is_unplaced, one_pass,
-        one_pass((jnp.int32(0), jnp.int32(0), kd.keys, kd.keys_lo,
-                  kd.keys_hi, kd.slots, kd.free_top, entry, unplaced,
-                  hit0)))
+        one_pass((jnp.int32(0), jnp.zeros(2, jnp.int32), kd.keys,
+                  kd.keys_lo, kd.keys_hi, kd.slots, kd.free_top, entry,
+                  unplaced, hit0)))
     with _part("grant"):
         # An entry a row got holds that row's key for good, or was
         # rolled back (the free stack ran dry: its fingerprint is
@@ -573,6 +528,113 @@ def packed_entries(running: jnp.ndarray, first, lanes: int) -> jnp.ndarray:
         0, n.bit_length(), halve,
         (jnp.zeros((lanes,), jnp.int32), jnp.full((lanes,), n, jnp.int32)))
     return lo
+
+
+# The lanes the claim rounds narrow to once no more rows than that are
+# unplaced. A round's cost follows its rows (a hash, a gather, a
+# scatter-min, a gather) and what is unplaced thins by the directory's
+# load a round, so all but the first round or two of a batch place a few
+# thousand rows and fewer. One static width chosen on the chip (PERF.md,
+# PR 51: a round over the batch's 65,536 rows is 1.6 ms, over 2,048 lanes
+# 0.13; the pack's binary search 0.3 — at 8,192 lanes 0.49 and 1.1, at
+# 512 lanes every batch with more new keys runs another wide round), and
+# no option.
+CLAIM_LANES = 2048
+
+
+def claim_lanes(n: int) -> int:
+    """The claim rounds' narrow width for an ``n``-row batch:
+    :data:`CLAIM_LANES`, or by :func:`pack_lanes`' rule half of a
+    smaller batch."""
+    return max(1, min(CLAIM_LANES, n // 2))
+
+
+def _claim_rounds(keys, key, entry, placed, n_probes: int, match: bool,
+                  lanes: Optional[int] = None):
+    """The claim rounds of one admit → ``(keys', entry', placed',
+    claimed, rounds [2])``: round j's unplaced rows scatter-min their
+    ``key`` (a wide directory's fingerprint) into probe position j where
+    it is vacant, re-read, and the losers go on to j + 1. ``claimed``
+    flags the rows placed by a claim made now, ``rounds`` is ``[rounds
+    run, those of them run narrow]``. ``match``: a row also takes a
+    position that holds its key already — a batch duplicate of a key
+    claimed in an EARLIER round, which the lookup could not see
+    (:func:`admit_wide` says why its rows never need that).
+
+    ONE loop body (unrolled, 2 x 16 rounds made each of the five bucket
+    programs compile ~16 s on the chip: PERF.md, PR 32), run while a
+    round is left and a row is unplaced. With a row placed ``hit`` and
+    ``want`` are false and its candidate is EMPTY_KEY, whose scatter-min
+    changes nothing: a round is an identity for every placed row and the
+    rounds not run are identities, so the answers are the fixed P
+    rounds' to the bit (tests/test_keydir.py pins them), a batch of
+    known keys runs none, and a key no round can place holds its batch
+    to all P.
+
+    And a round may leave the placed rows out. Two loops in a row: the
+    body over the batch's ``[B]`` rows while MORE than K =
+    :func:`claim_lanes` of them are unplaced, then — the unplaced rows'
+    indices packed into K lanes by rank (:func:`packed_entries`), their
+    keys gathered once — the same body over the ``[K]`` lanes, ``j``
+    carried on, while a lane is unplaced; lanes past the count are
+    placed from the start. One K-lane scatter brings what the lanes
+    placed back to ``[B]``. Every round still sees every unplaced row
+    at once, so nothing is decided differently; a batch with more than
+    K rows no round can place runs the wide loop to P and the narrow
+    loop no trip. (Not a ``lax.cond`` around the directory, which copies
+    it; not a round chunked over time, whose later chunks would read the
+    earlier ones' claims.)"""
+    (dir_cap,), (B,) = keys.shape, key.shape
+    K = claim_lanes(B) if lanes is None else lanes
+    assert dir_cap <= 1 << 30  # an entry and a flag share an int32 below
+
+    def rounds_over(key):
+        vacant = jnp.full_like(key, EMPTY_KEY)
+
+        def claim_round(carry):
+            j, keys, entry, placed, claimed = carry
+            p = _probe_position(key, j.astype(jnp.uint32), dir_cap)
+            cur = keys[p]
+            if match:
+                hit = ~placed & (cur == key)
+                entry = jax.lax.select(hit, p, entry)
+                placed = placed | hit
+            # among racing writers the smallest key wins, losers re-probe
+            want = ~placed & (cur == EMPTY_KEY)
+            keys = keys.at[p].min(jax.lax.select(want, key, vacant))
+            won = want & (keys[p] == key)
+            return (j + 1, keys, jax.lax.select(won, p, entry),
+                    placed | won, claimed | won)
+
+        return claim_round
+
+    def more_than_k_unplaced(carry):
+        j, _, _, placed, _ = carry
+        return (j < n_probes) & (
+            jnp.sum((~placed).astype(jnp.int32)) > K)
+
+    def a_lane_unplaced(carry):
+        j, _, _, placed, _ = carry
+        return (j < n_probes) & ~placed.all()
+
+    wide, keys, entry, placed, claimed = jax.lax.while_loop(
+        more_than_k_unplaced, rounds_over(key),
+        (jnp.int32(0), keys, entry, placed, jnp.zeros(B, dtype=bool)))
+    row = packed_entries(  # [K]: the unplaced rows in row order, then B
+        jax.lax.cumsum((~placed).astype(jnp.int32)), 0, K)
+    empty = row >= B
+    ran, keys, at, got, mine = jax.lax.while_loop(
+        a_lane_unplaced, rounds_over(key[jnp.minimum(row, B - 1)]),
+        (wide, keys, jnp.zeros(K, jnp.int32), empty,
+         jnp.zeros(K, dtype=bool)))
+    # what the lanes placed, back to their rows: entry and flag in one
+    code = jnp.full((B,), -1, jnp.int32).at[
+        jax.lax.select(got & ~empty, row, jnp.full_like(row, B))].set(
+            at * 2 + mine.astype(jnp.int32), mode="drop")
+    back = code >= 0
+    return (keys, jax.lax.select(back, code >> 1, entry), placed | back,
+            claimed | (back & ((code & 1) == 1)),
+            jnp.stack([ran, ran - wide]))
 
 
 def reclaim_entries(

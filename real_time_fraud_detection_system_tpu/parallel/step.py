@@ -30,7 +30,7 @@ comes from that shard's private key directory instead of
 its received (key, row) records through ``admit_slots`` locally,
 admission misses are served from the owner's per-device sketch replica,
 and per-shard [dense, cms] tier counts, with the claim rounds each
-shard's two admits ran, leave the step stacked [n_dev, 4]. :func:`make_sharded_compact` runs the recency-compaction
+shard's two admits ran, leave the step stacked [n_dev, 6]. :func:`make_sharded_compact` runs the recency-compaction
 pass per shard under the same ``shard_map``.
 """
 
@@ -415,7 +415,7 @@ def make_sharded_step(
         # resolves slots through ITS directory and serves admission
         # misses from ITS sketch replica, and the tier counts accumulate
         # OWNER-side (skew is a per-shard property), leaving as a
-        # [n_dev, 4] stack (the claim rounds beside them: each device's
+        # [n_dev, 6] stack (the claim rounds beside them: each device's
         # loops end on its own rows, no collective is in them).
         fstate, c_mat, t_mat, tier, overflows = run_planes(
             per_device(fstate, lambda x: jnp.squeeze(x, 0)),
@@ -473,7 +473,7 @@ def make_sharded_step(
             in_specs[1],
             P(axis),
             P(axis, None),
-        ) + ((P(axis, None),) if exact else ()  # [n_dev, 4] tier rows
+        ) + ((P(axis, None),) if exact else ()  # [n_dev, 6] tier rows
              ) + (P(),)  # exchange overflows
         fn = _shard_map(local_step, in_specs, out_specs)
 
@@ -610,7 +610,7 @@ def make_sharded_promote(
     """Per-shard cold-tier promotion under ``shard_map`` — the sharded
     twin of the single-chip ``("promote",)`` dispatch variant.
 
-    ``promote(fstate, payload) -> (fstate', stats [n_dev, 2, 3])``: the
+    ``promote(fstate, payload) -> (fstate', stats [n_dev, 2, 4])``: the
     engine groups promoted keys host-side by owner shard (the same
     ``key % n_shards`` modulo the ingest router uses) and pads each
     shard's block to a width of its lane ladder with ``EMPTY_KEY`` (the
@@ -618,8 +618,8 @@ def make_sharded_promote(
     so every device
     runs :func:`~..features.online.promote_rows` over ITS block and ITS
     directory — purely local, zero collectives, one fixed shape. Stats
-    come back stacked per shard ([admitted, dropped, claim rounds] per
-    table) for the promotion counters.
+    come back stacked per shard ([admitted, dropped, claim rounds, narrow
+    rounds] per table) for the promotion counters.
     """
     from real_time_fraud_detection_system_tpu.features.online import (
         promote_rows,
@@ -661,7 +661,7 @@ def make_sharded_promote(
                 jax.tree.map(lambda x: x[None], new.customer_dir)
                 if new.customer_dir is not None else None,
                 jax.tree.map(lambda x: x[None], new.terminal_dir),
-                stats[None],  # [1, 2, 3] → [n_dev, 2, 3]
+                stats[None],  # [1, 2, 4] → [n_dev, 2, 4]
             )
 
         dev = P(axis)

@@ -858,6 +858,7 @@ class ScoringEngine:
         fcfg = self.cfg.features
         self._m_tier = None
         self._m_claim_rounds = None
+        self._m_narrow_rounds = None
         self._m_alias = None
         self._m_slots_occ = None
         self._m_slots_rec = None
@@ -897,6 +898,15 @@ class ScoringEngine:
                     "known keys, keydir_probes for one that holds a key "
                     "no round can place", table=t)
                 for t, present in tables if present
+            }
+            self._m_narrow_rounds = {
+                t: reg.counter(
+                    "rtfds_keydir_narrow_rounds_total",
+                    "those of the claim rounds that ran over the packed "
+                    "lanes of the rows still unplaced "
+                    "(ops/keydir.CLAIM_LANES), not over the batch",
+                    table=t)
+                for t in self._m_claim_rounds
             }
             if fcfg.key_bits == 64:
                 self._m_alias = {
@@ -1184,9 +1194,10 @@ class ScoringEngine:
         the run stops here, before that batch is delivered, rather than
         hand on an inexact row as exact."""
         for table, stats in handle.pop("promote_checks", ()):
-            # [admitted, dropped, claim rounds] a table, summed over shards
-            stats = np.asarray(stats).reshape(-1, 2, 3).sum(axis=0)
-            self._count_claim_rounds(stats[:, 2])
+            # [admitted, dropped, claim rounds, narrow rounds] a table,
+            # summed over shards
+            stats = np.asarray(stats).reshape(-1, 2, 4).sum(axis=0)
+            self._count_claim_rounds(stats[:, 2], stats[:, 3])
             dropped = int(stats[:, 1].sum())
             if dropped:
                 raise ColdPromoteError(
@@ -1198,11 +1209,13 @@ class ScoringEngine:
                     "cold_demote_slots, lower compact_every or "
                     "cold_highwater, or add slots (README, Cold tier)")
 
-    def _count_claim_rounds(self, rounds) -> None:
-        """``rounds`` = [customer, terminal] claim rounds one program ran."""
-        for table, n in zip(("customer", "terminal"), rounds):
+    def _count_claim_rounds(self, rounds, narrow) -> None:
+        """``rounds`` = [customer, terminal] claim rounds one program ran,
+        ``narrow`` = those of them that ran narrow."""
+        for table, n, k in zip(("customer", "terminal"), rounds, narrow):
             if table in self._m_claim_rounds:
                 self._m_claim_rounds[table].inc(float(n))
+                self._m_narrow_rounds[table].inc(float(k))
 
     def _settle_cold(self) -> None:
         """Everything demoted so far is in the store and durable, and the
@@ -2206,16 +2219,17 @@ class ScoringEngine:
         tier = handle.get("tier")
         if tier is not None and self._m_tier is not None:
             # [dense, cms] row x keyspace admissions this batch, then
-            # the two admits' claim rounds; the step already
-            # materialized, so this tiny fetch is free
+            # the two admits' claim rounds and how many of them ran
+            # narrow; the step already materialized, so this tiny fetch
+            # is free
             t = np.asarray(tier)
             self._m_tier["dense"].inc(float(t[0]))
             self._m_tier["cms"].inc(float(t[1]))
-            self._count_claim_rounds(t[2:4])
+            self._count_claim_rounds(t[2:4], t[4:6])
             if self._m_alias is not None:
                 # key_bits=64: the lookups' [alias rows, verify trips]
-                self._m_alias["rows"].inc(float(t[4]))
-                self._m_alias["trips"].inc(float(t[5]))
+                self._m_alias["rows"].inc(float(t[6]))
+                self._m_alias["trips"].inc(float(t[7]))
         self.state.batches_done += 1
         self.state.rows_done += n
         self._m_batches.inc()

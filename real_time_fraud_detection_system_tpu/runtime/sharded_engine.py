@@ -915,7 +915,7 @@ class ShardedScoringEngine(ScoringEngine):
         # dispatch phase over all chunk launches (the per-chunk jit calls
         # are its children on the profiler timeline).
         parts = []
-        tier_parts = []  # exact mode: per-chunk [n_dev, 4] tier vectors
+        tier_parts = []  # exact mode: per-chunk [n_dev, 6] tier vectors
         overflow_parts = []  # per-chunk exchange-overflow scalars
         with self._phase("dispatch", chunks=len(chunks)) as disp:
             t_fetch = self._dispatch_chunks(
@@ -1002,8 +1002,9 @@ class ShardedScoringEngine(ScoringEngine):
                 )
             fstate, params, probs, feats = out[:4]
             if self._exact:
-                # [n_dev, 4] per-shard [dense, cms] rows served this
-                # chunk and the two admits' claim rounds — accumulated
+                # [n_dev, 6] per-shard [dense, cms] rows served this
+                # chunk and the two admits' claim rounds, all of them
+                # and the narrow ones — accumulated
                 # across chunks, materialized at finish (scalar-sized;
                 # no async fetch needed)
                 tier_parts.append(out[4])
@@ -1067,14 +1068,15 @@ class ShardedScoringEngine(ScoringEngine):
                 self._m_xchg_overflow.inc(int(x))
             tier_parts = handle.pop("tier_shard", None)
             if tier_parts is not None:
-                # per-shard tier accounting ([n_dev, 4] summed over
+                # per-shard tier accounting ([n_dev, 6] summed over
                 # chunks): shard-labeled counters get their own rows, the
                 # base table-level counters get the shard sums — so the
                 # global healthz/dashboard contract is identical on the
                 # mesh. The claim rounds (columns 2, 3: customer,
                 # terminal) keep their per-shard counts under a name of
-                # their own.
-                tier = np.zeros((self.n_dev, 4), np.float64)
+                # their own; the narrow ones (4, 5) have the table-level
+                # series alone.
+                tier = np.zeros((self.n_dev, 6), np.float64)
                 for t in tier_parts:
                     tier += np.asarray(t)
                 if self._m_tier_shard is not None:

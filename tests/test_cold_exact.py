@@ -293,23 +293,27 @@ def test_claim_rounds_of_steps_and_promotes_are_counted(tmp_path, devices):
     admit ran: the step's, carried beside the tier rows a batch's finish
     fetches anyway, and each promote program's, the third column of the
     stats ``_check_promotes`` reads — summed over the mesh's shards. A
-    promoted key is new to its directory, so the promotes run rounds."""
+    promoted key is new to its directory, so the promotes run rounds.
+    ``rtfds_keydir_narrow_rounds_total{table=…}`` is those of them that
+    ran over the packed lanes, from the columns beside: at 64 rows a few
+    new keys a batch fit the lanes, so most rounds are narrow."""
     rt = RuntimeConfig(batch_buckets=(64,), max_batch_rows=64)
     reg = MetricsRegistry()
     eng = _build(_fcfg(str(tmp_path / "cold"), cap=256, demote=64), rt,
                  reg, devices)
-    ran = {"step": np.zeros(2), "promote": np.zeros(2)}
+    ran = {"step": np.zeros(4), "promote": np.zeros(4)}
     programs = {"step": 0, "promote": 0}
     dispatch = eng._dispatch_step
 
     def spy(key, fn, *args):
         out = dispatch(key, fn, *args)
-        if key[0] in ("step", "sharded"):  # [shards,] [dense, cms, c, t]
-            ran["step"] += np.asarray(out[4]).reshape(-1, 4).sum(0)[2:]
+        # → [customer, terminal] rounds, then the narrow ones of them
+        if key[0] in ("step", "sharded"):  # [shards,] [dense, cms, + 4]
+            ran["step"] += np.asarray(out[4]).reshape(-1, 6).sum(0)[2:]
             programs["step"] += 1
-        elif key[0] == "promote":  # [shards,] table x [adm, drop, rounds]
+        elif key[0] == "promote":  # [shards,] table x [adm, drop, + 2]
             ran["promote"] += np.asarray(out[1]).reshape(
-                -1, 2, 3).sum(0)[:, 2]
+                -1, 2, 4).sum(0)[:, 2:].T.reshape(-1)
             programs["promote"] += 1
         return out
 
@@ -322,6 +326,10 @@ def test_claim_rounds_of_steps_and_promotes_are_counted(tmp_path, devices):
         assert got == ran["step"][i] + ran["promote"][i], table
         # two admits a step and one a promote, 16 rounds each at most
         assert got < 16 * devices * (programs["step"] + programs["promote"])
+        narrow = reg.get("rtfds_keydir_narrow_rounds_total",
+                         table=table).value
+        assert narrow == ran["step"][2 + i] + ran["promote"][2 + i], table
+        assert got / 2 < narrow <= got, (table, narrow, got)
 
 
 @pytest.mark.parametrize("devices,ladder", [

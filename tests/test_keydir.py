@@ -3,11 +3,15 @@ resolution, duplicate coalescing, free-list-bounded admission, read-only
 lookup, and reclaim — the primitives the tiered feature store
 (key_mode="exact") is built from."""
 
+import functools
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from real_time_fraud_detection_system_tpu.ops import keydir
 from real_time_fraud_detection_system_tpu.core.batch import (
     join_key,
     split_key,
@@ -271,7 +275,13 @@ def test_keys_that_miss_admission_follow_load_and_probe_depth(load,
 def _admit_slots_unrolled(kd, key, valid, n_probes):
     """``admit_slots`` as it was written before PR 32: the P claim rounds
     unrolled in Python over the ``[B, P]`` probe positions. Kept here as
-    the pin for the loop the program now runs."""
+    the pin for the loops the program now runs."""
+    return _unrolled(kd, key, valid, n_probes)[:3]
+
+
+def _unrolled(kd, key, valid, n_probes):
+    """→ ``(kd', slot, admitted, left [P] int32)``: the fixed rounds'
+    answers, and how many rows were unplaced as each round began."""
     from real_time_fraud_detection_system_tpu.ops.keydir import (
         KeyDirectory,
         _canon,
@@ -290,7 +300,9 @@ def _admit_slots_unrolled(kd, key, valid, n_probes):
         hit0, jnp.take_along_axis(pos, pidx[:, None], axis=1)[:, 0], 0)
     placed = ~valid | hit0
     claimed = jnp.zeros(B, dtype=bool)
+    left = []
     for j in range(n_probes):
+        left.append(jnp.sum(~placed))
         p = pos[:, j]
         cur = keys[p]
         hit = (~placed) & (cur == key)
@@ -319,26 +331,68 @@ def _admit_slots_unrolled(kd, key, valid, n_probes):
     admitted = placed & valid & (keys[entry] == key) & (slot >= 0)
     return (KeyDirectory(keys=keys, slots=slots, free=kd.free,
                          free_top=avail - jnp.sum(has.astype(jnp.int32))),
-            jnp.where(admitted, slot, 0), admitted)
+            jnp.where(admitted, slot, 0), admitted, jnp.stack(left))
 
 
+def _jit_admit(n_probes, lanes=None, rounds=None):
+    """``admit_slots`` under jit with its claim rounds narrowing at
+    ``lanes`` unplaced rows (None: ``claim_lanes``' own choice for the
+    batch), or with ``rounds`` standing in for them. The width is the
+    private helper's keyword; a fresh function a call, so no trace made
+    under another width answers."""
+    stand_in = rounds or functools.partial(keydir._claim_rounds,
+                                           lanes=lanes)
+
+    def admit(kd, key, valid):
+        with mock.patch.object(keydir, "_claim_rounds", stand_in):
+            return admit_slots(kd, key, valid, n_probes=n_probes)
+
+    return jax.jit(admit)
+
+
+def _rounds_of(left, lanes, n_probes):
+    """``[rounds run, those of them run narrow]`` that ``left`` (the
+    fixed rounds' unplaced rows as each round began) asks for: rounds
+    while a row is unplaced, the wide ones while more than ``lanes``
+    are."""
+    left = np.asarray(left).tolist()
+    ran = next((j for j in range(n_probes) if left[j] == 0), n_probes)
+    wide = next((j for j in range(n_probes) if left[j] <= lanes), n_probes)
+    return [ran, ran - min(wide, ran)]
+
+
+# how the widths can fall at a 128-row batch: claim_lanes' own half of a
+# small batch; no fewer lanes than rows (nothing runs wide); wide rounds,
+# then narrow ones; so few lanes that most batches run wide to the end
+LANES_128 = [None, 128, 16, 2]
+
+
+@pytest.mark.parametrize("lanes", LANES_128)
 @pytest.mark.parametrize("n_probes", [1, 3, 16])
 def test_claim_rounds_as_a_loop_equal_the_unrolled_rounds_bit_for_bit(
-        n_probes):
+        n_probes, lanes):
     """Racing new keys, batch duplicates of a new key, returning keys,
     invalid rows, reclaimed vacancies on a probe path and a free stack
-    that runs dry mid-batch: the directory and the answers of the loop
-    are the unrolled rounds' to the bit, batch after batch."""
+    that runs dry mid-batch: the directory and the answers of the two
+    loops are the unrolled rounds' to the bit, batch after batch,
+    wherever the rounds go narrow — and they go narrow where the
+    unplaced rows of the fixed rounds say."""
     rng = np.random.default_rng(n_probes)
-    loop = jax.jit(admit_slots, static_argnames="n_probes")
-    plain = jax.jit(_admit_slots_unrolled, static_argnames="n_probes")
+    loop = _jit_admit(n_probes, lanes)
+    plain = jax.jit(_unrolled, static_argnames="n_probes")
+    k = keydir.claim_lanes(128) if lanes is None else lanes
+    assert k == (64 if lanes is None else lanes)
     kd_a = kd_b = init_keydir(256, 96)
     ran_dry = False
+    ran = np.zeros(2, int)
     for step in range(12):
         keys = jnp.asarray(rng.integers(0, 400, 128).astype(np.uint32))
         valid = jnp.asarray(rng.random(128) < 0.9)
-        kd_a, slot_a, adm_a, *_ = loop(kd_a, keys, valid, n_probes=n_probes)
-        kd_b, slot_b, adm_b = plain(kd_b, keys, valid, n_probes=n_probes)
+        kd_a, slot_a, adm_a, rounds, _ = loop(kd_a, keys, valid)
+        kd_b, slot_b, adm_b, left = plain(kd_b, keys, valid,
+                                          n_probes=n_probes)
+        assert np.asarray(rounds).tolist() == _rounds_of(left, k, n_probes)
+        ran += np.asarray(rounds)
         ran_dry = ran_dry or int(kd_a.free_top) == 0
         if step % 4 == 3:  # vacate a third of the live entries
             dead = jnp.asarray(rng.random(256) < 0.33)
@@ -348,6 +402,13 @@ def test_claim_rounds_as_a_loop_equal_the_unrolled_rounds_bit_for_bit(
                         jax.tree.leaves((kd_b, slot_b, adm_b))):
             assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
     assert ran_dry or n_probes == 1  # one probe loses keys first
+    # the case is the one its width names: every round narrow, or both
+    # kinds, or (a dry stack's rolled-back claims come again, more than
+    # two a batch) nearly every round wide
+    wide, narrow = ran[0] - ran[1], ran[1]
+    assert (wide == 0) == (k >= 128)
+    assert narrow > 0 or n_probes < 16
+    assert wide > narrow or k > 2
 
 
 # -- the claim rounds end when every row is placed (PR 35) -----------------
@@ -422,6 +483,15 @@ def _case_unplaceable():
         lambda kd2, slot, adm: not adm[0])
 
 
+def _case_two_unplaceable():
+    # two keys with all P positions taken: more rows than one lane are
+    # unplaced to the end, so with one lane every round runs wide
+    taken = {pos: FILLER + i for i, pos in enumerate(
+        dict.fromkeys(_probes(777) + _probes(778)))}
+    return _built(taken), [777, 778], None, P, (
+        lambda kd2, slot, adm: not adm[:2].any())
+
+
 def _case_mixed():
     # a known key, a new key placed in round 0 and one that needs three:
     # the loop runs for the slowest row, not the first
@@ -467,6 +537,10 @@ def _case_vacated_prefix():
         and np.asarray(kd2.keys)[a] == EMPTY_KEY)
 
 
+# at 8 rows: claim_lanes' own 4, which holds every case's new keys from
+# the start; one lane (wide rounds while two rows are unplaced); no
+# fewer lanes than rows
+@pytest.mark.parametrize("lanes", [None, 1, 8])
 @pytest.mark.parametrize("case", [
     pytest.param(_case_known, id="all-keys-known"),
     pytest.param(_case_padding, id="padding-only"),
@@ -474,42 +548,50 @@ def _case_vacated_prefix():
     pytest.param(_case_chain(3), id="chain-3"),
     pytest.param(_case_chain(P), id="chain-P"),
     pytest.param(_case_unplaceable, id="no-round-can-place"),
+    pytest.param(_case_two_unplaceable, id="no-round-can-place-two"),
     pytest.param(_case_mixed, id="slowest-row-decides"),
     pytest.param(_case_duplicates, id="duplicates-of-a-new-key"),
     pytest.param(_case_race, id="two-keys-race-for-one-position"),
     pytest.param(_case_dry_stack, id="dry-free-stack"),
     pytest.param(_case_vacated_prefix, id="vacated-probe-prefix"),
 ])
-def test_claim_rounds_end_when_every_row_is_placed(case):
+def test_claim_rounds_end_when_every_row_is_placed(case, lanes):
     """``admit_slots`` runs its claim rounds while a row is unplaced, P
     at most: ``(kd', slot, admitted)`` are the fixed P rounds' bit for
     bit under jit, the round count is what the case's construction
-    needs, and a second admit of the same batch on the first's
-    directory agrees again (a rolled-back claim comes again; a placed
-    key runs no round)."""
+    needs — and of it the narrow rounds are those that began with no
+    more rows unplaced than there are lanes — and a second admit of the
+    same batch on the first's directory agrees again (a rolled-back
+    claim comes again; a placed key runs no round)."""
     kd, keys, valid, rounds, holds = case()
+    k = keydir.claim_lanes(ROWS) if lanes is None else lanes
+    assert k == (4 if lanes is None else lanes)
     key = np.zeros(ROWS, np.uint32)
     key[:len(keys)] = keys
     ok = np.zeros(ROWS, bool)
     ok[:len(keys)] = True if valid is None else valid
     key, ok = jnp.asarray(key), jnp.asarray(ok)
-    loop = jax.jit(admit_slots, static_argnames="n_probes")
-    plain = jax.jit(_admit_slots_unrolled, static_argnames="n_probes")
+    loop = _jit_admit(P, lanes)
+    plain = jax.jit(_unrolled, static_argnames="n_probes")
 
-    *got, ran, _ = loop(kd, key, ok, n_probes=P)
-    want = plain(kd, key, ok, n_probes=P)
+    *got, ran, _ = loop(kd, key, ok)
+    *want, left = plain(kd, key, ok, n_probes=P)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
-    assert ran.dtype == jnp.int32 and ran.shape == ()
-    assert int(ran) == rounds
+    assert ran.dtype == jnp.int32 and ran.shape == (2,)
+    assert np.asarray(ran).tolist() == _rounds_of(left, k, P)
+    assert int(ran[0]) == rounds
+    if len(keys) <= k:  # nothing to run wide for
+        assert int(ran[1]) == rounds
     assert holds(got[0], np.asarray(got[1]), np.asarray(got[2]))
 
     admitted = bool(np.asarray(got[2])[np.asarray(ok)].all())
-    *again, ran_again, _ = loop(got[0], key, ok, n_probes=P)
-    want_again = plain(want[0], key, ok, n_probes=P)
+    *again, ran_again, _ = loop(got[0], key, ok)
+    *want_again, left = plain(want[0], key, ok, n_probes=P)
     for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(want_again)):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
-    assert int(ran_again) == (0 if admitted else rounds)
+    assert np.asarray(ran_again).tolist() == _rounds_of(left, k, P)
+    assert int(ran_again[0]) == (0 if admitted else rounds)
 
 
 # -- 64-bit keys: two ids are one key only if all 64 bits agree (PR 41) -----
@@ -524,7 +606,7 @@ def _ids64(ids):
 def _admit64(kd, ids, valid=None, n_probes=16):
     v = jnp.ones(len(ids), bool) if valid is None else jnp.asarray(valid)
     kd, slot, adm, rounds, alias = admit_slots(kd, _ids64(ids), v, n_probes)
-    return kd, np.asarray(slot), np.asarray(adm), int(rounds), np.asarray(
+    return kd, np.asarray(slot), np.asarray(adm), int(rounds[0]), np.asarray(
         alias)
 
 
@@ -598,6 +680,94 @@ def test_fold_twins_racing_in_one_batch_end_as_sequential_insertion(order):
                             16)
     assert np.asarray(hit).all()
     np.testing.assert_array_equal(np.asarray(got), slot)
+
+
+def _rounds_over_the_batch(keys, key, entry, placed, n_probes, match):
+    """``ops/keydir._claim_rounds`` as the rounds stood before PR 51: one
+    loop, every round over the whole batch, while a row is unplaced. The
+    pin for the wide admit, which has no unrolled twin."""
+    dir_cap = keys.shape[0]
+
+    def claim_round(carry):
+        j, keys, entry, placed, claimed = carry
+        p = keydir._probe_position(key, j.astype(jnp.uint32), dir_cap)
+        cur = keys[p]
+        if match:
+            hit = ~placed & (cur == key)
+            entry, placed = jnp.where(hit, p, entry), placed | hit
+        want = ~placed & (cur == EMPTY_KEY)
+        keys = keys.at[p].min(jnp.where(want, key, EMPTY_KEY))
+        won = want & (keys[p] == key)
+        return (j + 1, keys, jnp.where(won, p, entry), placed | won,
+                claimed | won)
+
+    ran, keys, entry, placed, claimed = jax.lax.while_loop(
+        lambda c: (c[0] < n_probes) & ~c[3].all(), claim_round,
+        (jnp.int32(0), keys, entry, placed, jnp.zeros_like(placed)))
+    return keys, entry, placed, claimed, jnp.stack([ran, jnp.int32(0)])
+
+
+@pytest.mark.parametrize("lanes", LANES_128)
+@pytest.mark.parametrize("width", [32, 64])
+def test_narrowed_claim_rounds_equal_the_rounds_over_the_batch(width,
+                                                               lanes):
+    """Both admits, wherever their rounds go narrow, against the one
+    loop over the batch they ran until PR 51: directory, slots,
+    admissions, round count and alias counts to the bit, batch after
+    batch. At 64 bits every key comes with its fold twin (``wide_id``),
+    so new twins race in one batch, the loser is unplaced again and the
+    second pass's rounds find it among the packed rows — or, with fewer
+    lanes than losers, run wide for it."""
+    rng = np.random.default_rng(width)
+    narrowed = _jit_admit(16, lanes)
+    whole = _jit_admit(16, rounds=_rounds_over_the_batch)
+    k = keydir.claim_lanes(128) if lanes is None else lanes
+    kd_a = kd_b = init_keydir(512, 192, width)
+    ran = np.zeros(2, int)
+    for step in range(8):
+        keys = _keys(rng.integers(0, 600, 128), width)
+        valid = jnp.asarray(rng.random(128) < 0.9)
+        kd_a, slot_a, adm_a, rounds_a, alias_a = narrowed(kd_a, keys, valid)
+        kd_b, slot_b, adm_b, rounds_b, alias_b = whole(kd_b, keys, valid)
+        if step % 4 == 3:
+            dead = jnp.asarray(rng.random(512) < 0.33)
+            kd_a, kd_b = (reclaim_entries(kd, dead)[0]
+                          for kd in (kd_a, kd_b))
+        for a, b in zip(
+                jax.tree.leaves((kd_a, slot_a, adm_a, rounds_a[0], alias_a)),
+                jax.tree.leaves((kd_b, slot_b, adm_b, rounds_b[0], alias_b))):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        ran += np.asarray(rounds_a)
+    wide, narrow = ran[0] - ran[1], ran[1]
+    assert narrow > 0 and (wide == 0) == (k >= 128)
+
+
+@pytest.mark.parametrize("lanes", [None, 1, 24])
+def test_a_fingerprint_twin_among_the_packed_rows_takes_its_second_pass(
+        lanes):
+    """Six pairs of NEW fold twins and duplicates of each in one 24-row
+    batch: the first pass's rounds elect both of a pair at one position,
+    the grant unplaces the six losers with their duplicates (12 rows),
+    and the second pass places them one position on — from the packed
+    lanes when they fit (``claim_lanes(24)`` is 12: exactly), over the
+    batch when there is one lane. The answers are the same."""
+    a, b = _twins(6, seed=2)
+    batch = np.concatenate([a, b, a[:3], b[3:]])
+    key, valid = _ids64(batch), jnp.ones(len(batch), bool)
+    kd = init_keydir(128, 64, 64)
+    *got, ran, alias = _jit_admit(16, lanes)(kd, key, valid)
+    *want, ran_whole, alias_whole = _jit_admit(
+        16, rounds=_rounds_over_the_batch)(kd, key, valid)
+    for x, y in zip(jax.tree.leaves((got, alias)),
+                    jax.tree.leaves((want, alias_whole))):
+        assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+    assert np.asarray(got[2]).all()
+    assert int(ran[0]) == int(ran_whole[0]) >= 3  # a second pass ran
+    k = keydir.claim_lanes(24) if lanes is None else lanes
+    wide, narrow = int(ran[0] - ran[1]), int(ran[1])
+    # 24 rows unplaced as the first pass begins, 12 as the second does
+    assert {12: wide >= 1 and narrow >= 2, 1: wide >= 3,
+            24: wide == 0}[k], (wide, narrow)
 
 
 def test_three_new_ids_of_one_fingerprint_take_three_passes():

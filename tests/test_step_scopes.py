@@ -79,8 +79,9 @@ COMMON = {"unpack", "assemble", "scale", "classify"} | TABLE | UPDATE | QUERY
 # with the leaf match, select and pinned-order sum
 FOREST_PARTS = {"decide", "leaves"}
 # admit_slots names its three parts: the full-depth probe, the claim
-# rounds (a loop that ends when every row is placed), and the owner /
-# free-stack / roll-back / final resolution
+# rounds (over the batch while more rows are unplaced than the narrow
+# lanes hold, the pack, then over the lanes until every row is placed),
+# and the owner / free-stack / roll-back / final resolution
 KEYDIR_PARTS = {"lookup", "claim", "grant"}
 
 # variant → (kind, FeatureConfig overrides, RuntimeConfig overrides,
@@ -508,26 +509,39 @@ def test_profile_to_turns_the_tracer_on_for_the_capture(tmp_path):
         tracer.configure(enabled=was)
 
 
-def test_claim_rounds_are_one_loop_a_table_that_ends_on_the_placed_mask():
+def test_claim_rounds_are_two_loops_a_table_that_end_on_the_placed_masks():
     """``key_mode="exact"``: the COMPILED step holds the claim rounds as
-    one ``while`` a table whose condition reduces the batch's placed mask
-    (not P unrolled rounds, not a fixed trip count: a batch of known keys
-    runs no round), and the loop, its condition and every named op of
-    its body sit under ``<table>/rtfds.keydir/rtfds.claim`` — the scope
+    two ``while`` s a table — not P unrolled rounds, not a fixed trip
+    count: a batch of known keys runs no round. The first's condition
+    counts the batch's placed mask against the lanes (rounds over the
+    batch while more rows are unplaced than the lanes hold), the
+    second's reduces the ``[lanes]`` mask of the packed rows; between
+    them the pack, whose binary search is a third loop with no scatter
+    in it. The loops, their conditions and every named op of their
+    bodies sit under ``<table>/rtfds.keydir/rtfds.claim`` — the scope
     ``step_keydir_claim_ms`` reads, which is in the vocabulary already.
     The steps that take their slot from ``key_slot`` name no
     ``rtfds.keydir`` op and hold no such loop."""
+    from real_time_fraud_detection_system_tpu.ops.keydir import claim_lanes
+
     eng = _engine("exact")
     (low,) = _lowered_steps(eng)
     text = low.compile().as_text()
     loops = claim_loops(text)
-    assert sorted(_scopes(op)[0] for op, _, _ in loops) == sorted(TABLE)
+    rows, lanes = 64, claim_lanes(64)
+    for table in TABLE:
+        mine = [loop for loop in loops if _scopes(loop[0])[0] == table]
+        rounds = [loop for loop in mine
+                  if any(" scatter(" in c for c in loop[2])]
+        assert len(mine) == 3 and len(rounds) == 2, [op for op, *_ in mine]
+        assert sorted(reads_a_mask_of(cond, rows) + 2 * reads_a_mask_of(
+            cond, lanes) for _, cond, _ in rounds) == [1, 2]
     for op, condition, inside in loops:
         assert _scopes(op)[1:] == ["keydir", "claim"], op
-        assert reads_a_mask_of(condition, 64), condition
         named = [n for c in [condition] + inside for n in _op_names(c)
                  if n.startswith("jit(")]  # the rest: reducers' bodies
-        assert any(n.endswith("/scatter-min") for n in named)
+        if any(" scatter(" in c for c in inside):
+            assert any(n.endswith("/scatter-min") for n in named)
         off = [n for n in named if _scopes(n)[:3] != _scopes(op)]
         assert not off, off[:3]
     assert "claim" in STEP_SCOPES

@@ -858,39 +858,63 @@ def test_exact_step_reads_the_sketch_a_chunk_at_a_time_inside_its_loops(
         gathers
 
 
-@pytest.mark.parametrize("rows", [256, EXACT_ROWS])
-def test_exact_step_runs_its_claim_rounds_while_a_row_is_unplaced(
-        topo, one_chip, as_on_chip, compiled_steps, rows):
-    """What the chip's compiler made of ``admit_slots``' claim rounds:
-    one ``while`` a table — not 16 unrolled rounds, not a fixed trip
-    count: the condition reduces the ``[rows]`` placed mask beside its
-    ``j < 16`` — with every named op of its body under
-    ``<table>/rtfds.keydir/rtfds.claim`` (``step_keydir_claim_ms`` holds
-    the loop and nothing else), the scatter-min updating the directory's
-    keys in place: no copy of a directory-sized array a trip (PERF.md,
-    PR 35: the 48.6 ms the 2 x 16 fixed rounds cost a 168.9 ms step)."""
-    fcfg, compiled = _compiled_exact(compiled_steps, one_chip, "step", rows)
-    loops = claim_loops(compiled.as_text())
-    assert sorted(op.split("/")[1] for op, _, _ in loops) == [
-        "rtfds.customer", "rtfds.terminal"], [op for op, _, _ in loops]
-    for op, condition, inside in loops:
+def check_claim_rounds(hlo_text, loops, rows, probes, dir_caps):
+    """``loops`` (``claim_loops``' triples, one table's, one admit's) are
+    the two loops of rounds and the pack's search between them; nothing
+    in them or anywhere in the program copies a directory column."""
+    from real_time_fraud_detection_system_tpu.ops.keydir import claim_lanes
+
+    lanes = claim_lanes(rows)
+    rounds = [loop for loop in loops
+              if sum(c.count(" scatter(") for c in loop[2])]
+    # the search holds no scatter and is handed no directory
+    assert len(loops) == 3 and len(rounds) == 2, [op for op, *_ in loops]
+    (wide,) = [loop for loop in rounds if reads_a_mask_of(loop[1], rows)]
+    (narrow,) = [loop for loop in rounds if reads_a_mask_of(loop[1], lanes)]
+    # the wide rounds run while MORE rows are unplaced than the lanes hold
+    assert f"constant({lanes})" in wide[1]
+    for op, condition, inside in rounds:
         table = op.split("/")[1]
-        assert reads_a_mask_of(condition, rows), condition
-        assert f"constant({fcfg.keydir_probes})" in condition
+        assert f"constant({probes})" in condition
         named = [n for c in inside for n in re.findall(
             r'op_name="([^"]*)"', c) if n.startswith("jit(")]
         # one round a trip: ONE scatter in everything the body runs
         assert sum(c.count(" scatter(") for c in inside) == 1
         assert any(n.endswith("/scatter-min") for n in named)
-        off = [n for n in named
-               if f"{table}/rtfds.keydir/rtfds.claim/" not in n]
+        off = [n for n in named if n.split("/")[1] != table
+               or "/rtfds.keydir/rtfds.claim/" not in n]
         assert not off, off[:3]
-        dir_cap = 2 * (fcfg.customer_capacity if "customer" in table
-                       else fcfg.terminal_capacity)
-        copies = [ln for c in inside for ln in c.splitlines()
-                  if re.search(
-                      rf"= \w+\[{dir_cap}\]\S* copy(-start)?\(", ln)]
-        assert not copies, copies[:2]
+    copies = [ln.strip()[:160] for ln in hlo_text.splitlines() if re.search(
+        r"= \w+\[(%s)\]\S* copy(-start)?\(" % "|".join(
+            map(str, dir_caps)), ln)]
+    assert not copies, copies[:2]
+
+
+@pytest.mark.parametrize("rows", [256, EXACT_ROWS])
+def test_exact_step_runs_its_claim_rounds_while_a_row_is_unplaced(
+        topo, one_chip, as_on_chip, compiled_steps, rows):
+    """What the chip's compiler made of ``admit_slots``' claim rounds:
+    two ``while`` s a table — not 16 unrolled rounds, not a fixed trip
+    count: the first's condition counts the ``[rows]`` placed mask
+    against the narrow lanes beside its ``j < 16``, the second's reduces
+    the ``[lanes]`` mask of the packed rows — with every named op of
+    their bodies under ``<table>/rtfds.keydir/rtfds.claim``
+    (``step_keydir_claim_ms`` holds the two loops, the pack between them
+    and nothing else), the scatter-min updating the directory's keys in
+    place: no copy of a directory-sized array in a trip, between the
+    loops or anywhere else (PERF.md, PR 35: the 48.6 ms the 2 x 16 fixed
+    rounds cost a 168.9 ms step; PR 51: a round costs what its rows
+    cost)."""
+    fcfg, compiled = _compiled_exact(compiled_steps, one_chip, "step", rows)
+    text = compiled.as_text()
+    loops = claim_loops(text)
+    dirs = {"rtfds.customer": 2 * fcfg.customer_capacity,
+            "rtfds.terminal": 2 * fcfg.terminal_capacity}
+    assert {op.split("/")[1] for op, _, _ in loops} == set(dirs)
+    for table in dirs:
+        check_claim_rounds(
+            text, [loop for loop in loops if loop[0].split("/")[1] == table],
+            rows, fcfg.keydir_probes, dirs.values())
 
 
 @pytest.mark.parametrize("variant", ["step", "compact"])
@@ -917,9 +941,19 @@ def test_wide_key_programs_fit_the_chip_at_the_benchmarks_size(
     bodies = _computations(text)
     keydir = _loops_under(text, "rtfds.keydir")
     # a table: the verify loop, the first pass's rounds and the rounds of
-    # a further pass under rtfds.keydir; the loop of further passes
-    # beside it, under the table alone (its body names keydir/<part>)
-    assert len(keydir) == 6, [op for op, _, _ in keydir]
+    # a further pass (each: the rounds over the batch, the pack's search,
+    # the rounds over the packed lanes) under rtfds.keydir; the loop of
+    # further passes beside it, under the table alone (its body names
+    # keydir/<part>)
+    assert len(keydir) == 14, [op for op, _, _ in keydir]
+    claims = claim_loops(text)
+    for table in ("customer", "terminal"):
+        for a_pass in (f"rtfds.{table}/rtfds.keydir/",
+                       f"rtfds.{table}/while/body/rtfds.keydir/"):
+            check_claim_rounds(
+                text, [loop for loop in claims if a_pass in loop[0]],
+                EXACT_ROWS, fcfg.keydir_probes,
+                (2 * fcfg.customer_capacity, 2 * fcfg.terminal_capacity))
     passes = [loop for loop in _loops_under(text, "rtfds.")
               if re.search(r"rtfds\.(customer|terminal)/while$", loop[0])]
     assert len(passes) == 2, [op for op, _, _ in passes]
