@@ -385,12 +385,14 @@ def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
     """unpacked batch → reach(customer plane) → reach(terminal plane):
     the state half of THE device step, written once.
 
-    ``reach(plane, tstate, key, fraud) -> (tstate', mat, overflowed)`` is
+    ``reach(plane, tstate, key, fraud) -> (tstate', mat, exchange)`` is
     how a table's owner is reached: ``None`` calls the plane where the
     rows stand (one chip; a mesh's owner-placed customers), the sharded
-    step passes its exchange. ``state`` is one owner's view (a mesh
+    step passes its exchange, whose int32 counts (``parallel/step.py``'s
+    ``EXCHANGE_TELEMETRY``) the two tables' reaches sum to ``exchange``,
+    0 where nothing travelled. ``state`` is one owner's view (a mesh
     unstacks its per-device leaves first). Returns ``(state', customer
-    [B, 2·NW], terminal [B, 2·NW], tier [6] | None, overflows)`` — under
+    [B, 2·NW], terminal [B, 2·NW], tier [6] | None, exchange)`` — under
     ``exact`` ``tier`` is ``[dense rows, cms rows, customer claim rounds,
     terminal claim rounds, customer narrow rounds, terminal narrow
     rounds]``, the one small vector a batch's finish fetches for the
@@ -405,9 +407,9 @@ def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
 
     c_plane = TablePlane("customer", cfg, n_shards)
     t_plane = TablePlane("terminal", cfg, n_shards)
-    c, c_mat, c_over = (reach_customer or local)(
+    c, c_mat, c_xchg = (reach_customer or local)(
         c_plane, c_plane.of(state), batch.customer_key, fraud)
-    t, t_mat, t_over = (reach_terminal or local)(
+    t, t_mat, t_xchg = (reach_terminal or local)(
         t_plane, t_plane.of(state), batch.terminal_key, fraud)
     tier = None if t.tier is None else jnp.concatenate(
         [c.tier + t.tier, jnp.stack([c.rounds, t.rounds], 1).reshape(-1)]
@@ -417,7 +419,7 @@ def run_planes(state: FeatureState, batch: TxBatch, cfg: FeatureConfig,
         customer=c.windows, terminal=t.windows, cms=c.sketch,
         customer_dir=c.directory, terminal_dir=t.directory,
         terminal_cms=t.sketch)
-    return state, c_mat, t_mat, tier, c_over + t_over
+    return state, c_mat, t_mat, tier, c_xchg + t_xchg
 
 
 def update_and_featurize(

@@ -202,6 +202,23 @@ def _make_xchg(axis, n_dev: int, cap: int):
     return xchg
 
 
+# What the step's last output carries about its exchanges, uniform over
+# the mesh: exchanges that took the full-capacity branch, the
+# receive-buffer lanes served on all devices together, the valid rows
+# that travelled.
+EXCHANGE_TELEMETRY = ("overflows", "lanes", "rows")
+
+
+def tight_bucket(bl: int, n_dev: int, batch_rows: int) -> int:
+    """The per-(sender, owner) bucket the window exchange runs at unless
+    a pair outgrows it: 2 × the balanced load a pair carries when a batch
+    of at most ``batch_rows`` rows is spread evenly over the mesh
+    (``ceil(batch_rows / n_dev)`` rows a sender, whatever headroom the
+    chunk's width ``bl`` adds), and never more than ``bl``, which holds
+    any skew."""
+    return min(bl, 2 * -(-(-(-batch_rows // n_dev)) // n_dev))
+
+
 def owner_route(
     dest: jnp.ndarray,  # int32 [bl] owner device per row
     valid: jnp.ndarray,  # bool [bl]
@@ -236,16 +253,27 @@ def make_sharded_step(
     axis: "str | Tuple[str, ...]" = "data",
     route_customers: bool = False,
     packed: bool = False,
+    *,
+    batch_rows: int,
 ):
     """Build the jitted multi-chip step.
 
     step(feature_state, params, scaler, batch) -> (feature_state, params,
-    probs, features[, tier rows in exact mode], overflows); batch leaves
-    are [n_dev*B_local] sharded on axis 0. ``overflows``, always last, is
-    one int32 scalar: how many of this step's exchanges took the
-    full-capacity branch (0 when every (sender, owner) pair fitted its
-    bucket). It is the psum'd flag the ``lax.cond`` already branches on;
-    the serving engine counts it (``rtfds_exchange_overflow_total``).
+    probs, features[, tier rows in exact mode], exchange); batch leaves
+    are [n_dev*B_local] sharded on axis 0. ``exchange``, always last, is
+    one int32 ``[3]`` vector (:data:`EXCHANGE_TELEMETRY`), uniform over
+    the mesh: how many of this step's exchanges took the full-capacity
+    branch (0 when every (sender, owner) pair fitted
+    :func:`tight_bucket`), the receive-buffer lanes they served on all
+    devices together, and the valid rows that travelled. It is the psum
+    the ``lax.cond`` already branches on; the serving engine counts the
+    three (``rtfds_exchange_overflow_total``, ``rtfds_exchange_lanes_total``,
+    ``rtfds_exchange_rows_total``).
+
+    ``batch_rows`` is the bound on the rows of ONE batch that the caller
+    cuts its chunks from (the engine sized ``rows_per_shard`` from it):
+    it places the tight bucket, in the owner-placed and the routed
+    program alike.
 
     ``packed=True`` makes the built step take ONE ``[7, n_dev*B_local]``
     int32 array (:func:`~..core.batch.pack_batch` layout) instead of a
@@ -310,7 +338,7 @@ def make_sharded_step(
             device, run ``fn(state, key, day, amount, fraud, valid) ->
             (state', mat)`` there, and route ``mat``'s per-row aggregates
             back to the sending rows: → (state', local_mat [bl, K],
-            overflowed int32 scalar: 1 when the full-capacity branch ran).
+            int32 [3] of :data:`EXCHANGE_TELEMETRY` for this exchange).
 
             Wire format: ONE all_to_all carries the 5 forward fields as
             a packed [*, 5] uint32 matrix (32-bit fields travel as bit
@@ -324,23 +352,28 @@ def make_sharded_step(
             equals a SINGLE chip processing the whole batch, so adding
             chips stops helping (measured: the virtual-mesh curve decayed
             ~4× from width 1 → 8). Under the balanced load a uniform key
-            hash delivers, each sender holds only ~bl/n_dev rows per
-            owner — so the common case runs with bucket capacity
-            ``2·ceil(bl/n_dev)`` (2× balanced headroom, receive buffer
-            2·bl regardless of width: per-device work now SHRINKS with
-            width). Skew beyond the headroom (hot terminal) is detected
-            with a psum'd overflow flag — uniform across devices, so the
-            ``lax.cond`` fallback to the always-correct full-capacity
+            hash delivers, a sender holds only its rows ÷ n_dev for each
+            owner — so the common case runs with the bucket of
+            :func:`tight_bucket`: 2 × the balanced load of the BATCH
+            spread evenly over the mesh, not of the chunk's padded width
+            (per-device work SHRINKS with width, and does not grow back
+            with the chunk's headroom). ``rank`` counts valid rows only,
+            so padding fills no bucket. Skew beyond the headroom (a hot
+            terminal, a chunk dense to its width) is detected with a
+            psum'd overflow flag — uniform across devices, so the
+            ``lax.cond`` fallback to the always-correct ``bl``-a-pair
             exchange takes the same branch everywhere and the collectives
-            inside stay matched. Exactness is never capacity-dependent.
+            inside stay matched. The branches differ in the bucket alone:
+            the rows, their order inside a bucket and the answers are the
+            same in both. Exactness is never capacity-dependent.
             """
-            no_overflow = jnp.zeros((), jnp.int32)
             if n_dev == 1:
                 # Width-1 mesh: every key is owner-local already; the
                 # exchange machinery is pure overhead (measured as most
                 # of the round-4 29% single-device tax).
                 return fn(state, key, batch.day, batch.amount, fraud,
-                          batch.valid) + (no_overflow,)
+                          batch.valid) + (
+                    jnp.zeros(len(EXCHANGE_TELEMETRY), jnp.int32),)
             with _exchange_scope("route"):
                 dest = (key % jnp.uint32(n_dev)).astype(jnp.int32)
                 # Rank VALID rows only (invalid rows sort into a trailing
@@ -398,14 +431,24 @@ def make_sharded_step(
 
                 return go
 
-            cap_pair = min(bl, 2 * -(-bl // n_dev))
-            if cap_pair >= bl:
-                return run(bl)(state) + (no_overflow,)
+            cap_pair = tight_bucket(bl, n_dev, batch_rows)
             with _exchange_scope("route"):
-                over = (batch.valid & (rank >= cap_pair)).any()
-                over = jax.lax.psum(over.astype(jnp.int32), axis) > 0
-            return jax.lax.cond(over, run(bl), run(cap_pair), state) + (
-                over.astype(jnp.int32),)
+                # ONE psum: the senders with a pair that outgrew the
+                # tight bucket, and the valid rows that travel
+                over, rows = jax.lax.psum(jnp.stack([
+                    (batch.valid & (rank >= cap_pair)).any().astype(
+                        jnp.int32),
+                    batch.valid.sum(dtype=jnp.int32)]), axis)
+                over = over > 0
+            if cap_pair < bl:
+                state, mat = jax.lax.cond(over, run(bl), run(cap_pair),
+                                          state)
+            else:  # the tight bucket is the chunk: nothing to choose
+                state, mat = run(bl)(state)
+            with _exchange_scope("route"):
+                lanes = jnp.where(over, bl, cap_pair) * (n_dev * n_dev)
+                return state, mat, jnp.stack(
+                    [over.astype(jnp.int32), lanes, rows])
 
         # unstack → reach(customer plane) → reach(terminal plane) → tail
         # → restack. Customers are owner-local (chunk 0: rows placed by
@@ -417,16 +460,16 @@ def make_sharded_step(
         # OWNER-side (skew is a per-shard property), leaving as a
         # [n_dev, 6] stack (the claim rounds beside them: each device's
         # loops end on its own rows, no collective is in them).
-        fstate, c_mat, t_mat, tier, overflows = run_planes(
+        fstate, c_mat, t_mat, tier, exchange = run_planes(
             per_device(fstate, lambda x: jnp.squeeze(x, 0)),
             batch, fcfg, n_dev,
             reach_customer=exchanged_compute if route_customers else None,
             reach_terminal=exchanged_compute)
         params, probs, feats = tail(params, scaler, batch, c_mat, t_mat)
         fstate = per_device(fstate, lambda x: x[None])
-        # overflows: uniform over the mesh (psum'd), leaves replicated
+        # exchange: uniform over the mesh (psum'd), leaves replicated
         return (fstate, params, probs, feats) + (
-            () if tier is None else (tier[None],)) + (overflows,)
+            () if tier is None else (tier[None],)) + (exchange,)
 
     from real_time_fraud_detection_system_tpu.parallel.mesh import (
         compat_shard_map,
@@ -474,13 +517,13 @@ def make_sharded_step(
             P(axis),
             P(axis, None),
         ) + ((P(axis, None),) if exact else ()  # [n_dev, 6] tier rows
-             ) + (P(),)  # exchange overflows
+             ) + (P(),)  # EXCHANGE_TELEMETRY
         fn = _shard_map(local_step, in_specs, out_specs)
 
         def outer(fstate, params, scaler, batch_in):
             with step_scope("unpack"):
                 batch = unpack_batch(batch_in) if packed else batch_in
-            # after the four: the tier rows (exact), the overflow count
+            # after the four: the tier rows (exact), the exchange's counts
             fstate, params, probs, feats, *extra = fn(
                 fstate, params, scaler, batch)
             if selective(cfg):

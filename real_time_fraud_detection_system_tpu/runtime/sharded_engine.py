@@ -297,8 +297,9 @@ class ShardedScoringEngine(ScoringEngine):
         # host_prep / result_wait, the chunks a batch became, the slots
         # the devices computed on against the rows in them (the rest is
         # padding), the fullest shard's rows and the mean shard's
-        # (imbalance = max ÷ mean), and how often the step's exchange
-        # outgrew its buckets.
+        # (imbalance = max ÷ mean), and what the step's exchanges ran at:
+        # the receive-buffer lanes they served against the rows in them,
+        # and how often one outgrew its buckets.
         self._m_phase.update({
             ph: self.metrics.histogram(
                 "rtfds_phase_seconds",
@@ -328,10 +329,21 @@ class ShardedScoringEngine(ScoringEngine):
             "rtfds_shard_rows_mean_total",
             "each batch's rows / n_devices, summed (max / mean = "
             "imbalance, at whatever width each batch was served)")
-        self._m_xchg_overflow = self.metrics.counter(
-            "rtfds_exchange_overflow_total",
-            "exchanges that took the full-capacity branch: a (sender, "
-            "owner) pair held more rows than its bucket")
+        # one counter a field of parallel/step.EXCHANGE_TELEMETRY
+        self._m_xchg = (
+            self.metrics.counter(
+                "rtfds_exchange_overflow_total",
+                "exchanges that took the full-capacity branch: a (sender, "
+                "owner) pair held more rows than its bucket"),
+            self.metrics.counter(
+                "rtfds_exchange_lanes_total",
+                "receive-buffer lanes the exchanges served, all devices "
+                "(n_devices^2 x the bucket each ran at)"),
+            self.metrics.counter(
+                "rtfds_exchange_rows_total",
+                "valid rows that travelled in those lanes; the rest is "
+                "padding every owner computes on"),
+        )
         # Commit replicated leaves (params, scaler) to the mesh NOW: the
         # step's out_specs return them mesh-committed, so leaving the
         # build-time copies on the default device makes the SECOND step
@@ -402,6 +414,9 @@ class ShardedScoringEngine(ScoringEngine):
                 online_lr=self.online_lr, mesh=self.mesh, axis=self.axis,
                 route_customers=routed,
                 packed=True,  # one H2D copy per chunk (see _start_batch)
+                # what rows_per_shard was sized from: the exchange's
+                # bucket follows the batch, not the chunk's headroom
+                batch_rows=self.cfg.runtime.max_batch_rows,
             ) for routed in (False, True))
         self._sharded_step = None
         self._sharded_step_routed = None
@@ -916,17 +931,17 @@ class ShardedScoringEngine(ScoringEngine):
         # are its children on the profiler timeline).
         parts = []
         tier_parts = []  # exact mode: per-chunk [n_dev, 6] tier vectors
-        overflow_parts = []  # per-chunk exchange-overflow scalars
+        exchange_parts = []  # per-chunk [3] exchange counts
         with self._phase("dispatch", chunks=len(chunks)) as disp:
             t_fetch = self._dispatch_chunks(
-                chunks, parts, tier_parts, overflow_parts)
+                chunks, parts, tier_parts, exchange_parts)
         handle = {"cols": cols, "n": n, "parts": parts, "t0": prep.t0,
                   "prep_s": prep.seconds, "dispatch_s": disp.seconds,
                   "fetch_issue_t": t_fetch}
         if tier_parts:
             handle["tier_shard"] = tier_parts
-        if overflow_parts:
-            handle["exchange_overflow"] = overflow_parts
+        if exchange_parts:
+            handle["exchange"] = exchange_parts
         # notify compaction's recency cutoff (the base engine does this
         # in its own _start_batch; the sharded path overrides it wholesale)
         self._note_batch_days(cols)
@@ -934,7 +949,7 @@ class ShardedScoringEngine(ScoringEngine):
         return handle
 
     def _dispatch_chunks(self, chunks, parts: list, tier_parts: list,
-                         overflow_parts: list) -> Optional[float]:
+                         exchange_parts: list) -> Optional[float]:
         """Launch one sharded step a chunk, filling the three lists the
         batch's finish reads; → the last async fetch's issue time."""
         t_fetch = None
@@ -1008,9 +1023,9 @@ class ShardedScoringEngine(ScoringEngine):
                 # across chunks, materialized at finish (scalar-sized;
                 # no async fetch needed)
                 tier_parts.append(out[4])
-            # scalar beside probs, read at finish like the tier rows
+            # three counts beside probs, read at finish like the tier rows
             out[-1].copy_to_host_async()
-            overflow_parts.append(out[-1])
+            exchange_parts.append(out[-1])
             self.state.feature_state = fstate
             self.state.params = params
             # async D2H per chunk: each chunk's transfer starts the
@@ -1064,8 +1079,9 @@ class ShardedScoringEngine(ScoringEngine):
                     # (engine.py: "batches whose flagged-row count
                     # overflowed")
                     self.selective_overflows += 1
-            for x in handle.pop("exchange_overflow", ()):
-                self._m_xchg_overflow.inc(int(x))
+            for x in handle.pop("exchange", ()):
+                for counter, v in zip(self._m_xchg, np.asarray(x)):
+                    counter.inc(int(v))
             tier_parts = handle.pop("tier_shard", None)
             if tier_parts is not None:
                 # per-shard tier accounting ([n_dev, 6] summed over

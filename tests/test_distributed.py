@@ -103,7 +103,7 @@ def test_hybrid_step_matches_single_device(hybrid_mesh, cfg, rng):
     axes = mesh_axes(hybrid_mesh)
     build = make_sharded_step(
         cfg, logreg_predict_proba, loss_fn=logreg_loss, online_lr=1e-2,
-        mesh=hybrid_mesh, axis=axes,
+        mesh=hybrid_mesh, axis=axes, batch_rows=n,
     )
     part_cols, pos = partition_batch_by_customer(cols, N_DEV, 256)
     batch = make_batch(

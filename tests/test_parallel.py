@@ -4,6 +4,8 @@ The sharded step's (customer-local + terminal-all_to_all) feature values
 must equal the single-device kernel's on identically routed data.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,6 +29,7 @@ from real_time_fraud_detection_system_tpu.parallel import (
     partition_batch_by_customer,
     shard_feature_state,
 )
+from real_time_fraud_detection_system_tpu.parallel.step import tight_bucket
 
 N_DEV = 8
 
@@ -81,7 +84,7 @@ def test_sharded_step_matches_single_device(mesh, cfg, rng):
     params = init_logreg(15)
     scaler = Scaler(mean=jnp.zeros(15), scale=jnp.ones(15))
     build = make_sharded_step(
-        cfg, logreg_predict_proba, mesh=mesh
+        cfg, logreg_predict_proba, mesh=mesh, batch_rows=n
     )
     part_cols, pos = partition_batch_by_customer(cols, N_DEV, rows_per_shard)
     batch = make_batch(
@@ -110,7 +113,7 @@ def test_sharded_online_sgd_replicated_params(mesh, cfg, rng):
     scaler = Scaler(mean=jnp.zeros(15), scale=jnp.ones(15))
     build = make_sharded_step(
         cfg, logreg_predict_proba, loss_fn=logreg_loss, online_lr=1e-2,
-        mesh=mesh,
+        mesh=mesh, batch_rows=n,
     )
     part_cols, pos = partition_batch_by_customer(cols, N_DEV, 256)
     batch = make_batch(
@@ -137,7 +140,8 @@ def test_state_stays_sharded_across_steps(mesh, cfg, rng):
     (HBM residency contract)."""
     params = init_logreg(15)
     scaler = Scaler(mean=jnp.zeros(15), scale=jnp.ones(15))
-    build = make_sharded_step(cfg, logreg_predict_proba, mesh=mesh)
+    build = make_sharded_step(cfg, logreg_predict_proba, mesh=mesh,
+                              batch_rows=256)
     cols = _random_cols(rng, 256)
     part_cols, _ = partition_batch_by_customer(cols, N_DEV, 128)
     batch = make_batch(
@@ -154,3 +158,53 @@ def test_state_stays_sharded_across_steps(mesh, cfg, rng):
         fstate, params, probs, feats = step(fstate, params, scaler, jb)[:4]
     shard_count = len(fstate.customer.count.addressable_shards)
     assert shard_count == N_DEV
+
+
+# -- the exchange's bucket -----------------------------------------------------
+
+
+@pytest.mark.parametrize("bl,n_dev,batch_rows,want", [
+    # the benchmark's mesh: 65,536 rows a batch, 2 x 16,384 slots a chip
+    (32768, 4, 65536, 8192),
+    # a chunk dense to its width: 2 x ceil(bl / n_dev), the parent's law
+    (32768, 4, 4 * 32768, 16384),
+    # a chunk no wider than the batch's share of a device
+    (16, 4, 64, 8),
+    (16, 4, 1000, 16),
+    # two devices: 2 x half a chunk is the chunk
+    (65536, 2, 2 * 65536, 65536),
+    (64, 8, 256, 8),
+    # the tight bucket is the chunk: nothing to choose
+    (2, 4, 8, 2),
+    (3, 2, 6, 3),
+])
+def test_tight_bucket(bl, n_dev, batch_rows, want):
+    assert tight_bucket(bl, n_dev, batch_rows) == want <= bl
+
+
+def _exchange_buckets(text):
+    """Per-(sender, owner) buckets of the forward all_to_alls in a
+    lowered step: each carries ``[n_dev, bucket, 5]`` uint32."""
+    return sorted(int(b) for b in re.findall(
+        r"all_to_all.*\(tensor<\d+x(\d+)x5xui32>\)", text))
+
+
+@pytest.mark.parametrize("n_dev,bl,batch_rows,route_customers,buckets", [
+    (8, 64, 256, False, [8, 64]),  # the tight bucket and the chunk
+    (8, 64, 256, True, [8, 8, 64, 64]),  # the routed program's two tables
+    (4, 2, 8, False, [2]),  # the two coincide: no branch at all
+    (1, 64, 64, False, []),  # one device: no exchange either
+])
+def test_sharded_step_lowers_one_branch_a_bucket(cfg, n_dev, bl, batch_rows,
+                                                 route_customers, buckets):
+    build = make_sharded_step(cfg, logreg_predict_proba,
+                              mesh=make_mesh(n_dev), packed=True,
+                              route_customers=route_customers,
+                              batch_rows=batch_rows)
+    templates = (jax.eval_shape(lambda: init_feature_state(cfg.features)),
+                 init_logreg(15),
+                 Scaler(mean=jnp.zeros(15), scale=jnp.ones(15)),
+                 jax.ShapeDtypeStruct((7, n_dev * bl), jnp.int32))
+    text = build(*templates).lower(*templates).as_text()
+    assert _exchange_buckets(text) == buckets
+    assert text.count("stablehlo.case") == len(buckets) // 2

@@ -518,26 +518,45 @@ def test_dense_spill_matches_single_chip(source):
                                rtol=1e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("skew", ["balanced", "hot_terminal"])
-def test_exchange_capacity_branches_match_single_chip(skew):
+@pytest.mark.parametrize("skew,bucket", [("balanced", 8), ("dense", 64),
+                                         ("hot_terminal", 64)])
+def test_exchange_capacity_branches_match_single_chip(skew, bucket):
     """The owner exchange's two capacity branches both reproduce
-    single-chip results: balanced terminals ride the 2x-headroom compact
-    buffers (per-device work shrinks with width), a hot terminal
-    overflows the per-pair capacity and takes the psum-uniform fallback
-    to the always-correct full-capacity exchange."""
+    single-chip results, and the rows pick the branch: balanced terminals
+    ride the tight bucket (2x the balanced load of the BATCH spread over
+    the mesh, whatever headroom the chunk's width adds), terminals that
+    lean on one owner as a chunk dense to its width would, and a hot
+    terminal, outgrow it and take the psum-uniform fallback to the
+    always-correct full-capacity exchange."""
     from real_time_fraud_detection_system_tpu.core.batch import US_PER_DAY
+    from real_time_fraud_detection_system_tpu.parallel.step import (
+        tight_bucket,
+    )
+    from real_time_fraud_detection_system_tpu.utils.metrics import (
+        MetricsRegistry,
+    )
 
-    n, rps, n_dev = 256, 32, N_DEV
-    # bl=32, cap_pair = 2*ceil(32/8) = 8: balanced (%97) sends ~4 rows
-    # per (sender, owner) pair -> compact; hot sends all 32 -> fallback
+    n, n_dev = 256, N_DEV
+    # the engine's own chunk width, 2*ceil(256/8) = 64 slots a device for
+    # the 32 rows it holds: the tight bucket is 2*ceil(32/8) = 8
+    assert tight_bucket(64, n_dev, n) == 8
     rng = np.random.default_rng(5)
-    terminal = (np.full(n, 5, np.int64) if skew == "hot_terminal"
-                else (np.arange(n) % 97).astype(np.int64))
+    i = np.arange(n)
+    # row i stands on device i % 8 as that sender's k-th row; 40 = 0 mod 8
+    k = i // n_dev
+    spread = k + 40 * (i % n_dev)  # owner k % 8: 4 rows a (sender, owner)
+    terminal = {
+        "balanced": spread,
+        # 3 rows in 8 of every sender pay owner 0's terminals: 12 a pair
+        "dense": np.where(k % 8 < 3, 8 * (i % 11), spread),
+        # all 32 rows of every sender pay one terminal
+        "hot_terminal": np.full(n, 5),
+    }[skew].astype(np.int64)
     cols = {
         "tx_id": np.arange(n, dtype=np.int64),
         "tx_datetime_us": np.full(n, 20200, np.int64) * US_PER_DAY
         + np.arange(n, dtype=np.int64) * 1_000_000,
-        "customer_id": np.arange(n, dtype=np.int64) % 200,
+        "customer_id": np.arange(n, dtype=np.int64),
         "terminal_id": terminal,
         "tx_amount_cents": rng.integers(100, 30000, n).astype(np.int64),
         "kafka_ts_ms": np.zeros(n, dtype=np.int64),
@@ -551,12 +570,24 @@ def test_exchange_capacity_branches_match_single_chip(skew):
 
     single = ScoringEngine(cfg, kind="logreg", params=params,
                            scaler=scaler).process_batch(cols)
-    res = ShardedScoringEngine(
+    reg = MetricsRegistry()
+    eng = ShardedScoringEngine(
         cfg, kind="logreg", params=params, scaler=scaler,
-        n_devices=n_dev, rows_per_shard=rps).process_batch(cols)
+        n_devices=n_dev, metrics=reg)
+    assert eng.rows_per_shard == 64
+    res = eng.process_batch(cols)
     np.testing.assert_allclose(res.probs, single.probs, atol=1e-6)
     np.testing.assert_allclose(res.features, single.features,
                                rtol=1e-5, atol=1e-4)
+    # which branch ran, from what the step handed the host
+    snap = reg.snapshot()
+
+    def count(name):
+        return sum(r["value"] for r in snap[name]["series"])
+
+    assert count("rtfds_exchange_lanes_total") == n_dev * n_dev * bucket
+    assert count("rtfds_exchange_rows_total") == n
+    assert count("rtfds_exchange_overflow_total") == (bucket == 64)
 
 
 def test_sharded_alerts_only_same_probs_zero_features(small_dataset):

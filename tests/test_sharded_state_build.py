@@ -195,11 +195,14 @@ def _counter(reg, name, **labels):
                if all(r["labels"].get(k) == v for k, v in labels.items()))
 
 
-# bl = 128 slots a device, 64 rows a (sender, owner) bucket: the hot
-# customer's device holds ~100 rows, so 0.9 of them to one terminal owner
-# outgrow a bucket; 0.6 of 256 rows on one customer outgrow its device
+# bl = 128 slots a device for the 64 rows a balanced batch leaves it, 32
+# rows a (sender, owner) bucket: a tenth of the rows on one terminal fits
+# (~20 of a device's 64 go to its owner); the hot customer's device holds
+# ~100 rows, so 0.9 of them to one terminal owner outgrow a bucket; 0.6 of
+# 256 rows on one customer outgrow its device, whose 128 slots, dense to
+# their width, outgrow a bucket too
 CASES = {
-    "capacity_branch": dict(hot_customer=0.2, hot_terminal=0.2),
+    "capacity_branch": dict(hot_customer=0.0, hot_terminal=0.1),
     "overflow_branch": dict(hot_customer=0.2, hot_terminal=0.9),
     "routed_spill": dict(hot_customer=0.6, hot_terminal=0.2),
 }
@@ -274,7 +277,7 @@ def test_four_devices_agree_with_the_plain_reference(case):
         assert off[0]["name"] == "exact_columns_wrong" and off[0]["value"] > 0
     overflows = _counter(reg, "rtfds_exchange_overflow_total")
     routed = _counter(reg, "rtfds_shard_chunks_total", routed="1")
-    assert (overflows > 0) == (case == "overflow_branch"), overflows
+    assert (overflows > 0) == (case != "capacity_branch"), overflows
     assert (routed > 0) == (case == "routed_spill"), routed
 
 
@@ -343,6 +346,13 @@ def test_mesh_counters_read_what_the_batch_implies():
     # chunk sends 6 a device to the customers' owner and on to the
     # terminal's (both fit)
     assert _counter(reg, "rtfds_exchange_overflow_total") == 1
+    # the lanes those three exchanges served on the four devices together
+    # (64 rows a batch over four devices: the tight bucket is 8 of a
+    # chunk's 16 slots a device, in the routed program as in the
+    # owner-placed one) and the rows in them
+    assert _counter(reg, "rtfds_exchange_lanes_total") == (
+        N_DEV * N_DEV * 16 + 2 * N_DEV * N_DEV * 8)
+    assert _counter(reg, "rtfds_exchange_rows_total") == 16 + 2 * 24
     phases = {r["labels"]["phase"]: r for r in
               reg.snapshot()["rtfds_phase_seconds"]["series"]}
     for phase in ("partition", "assemble"):
@@ -353,6 +363,9 @@ def test_mesh_counters_read_what_the_batch_implies():
     assert _counter(reg, "rtfds_shard_chunks_total", routed="0") == 2
     assert _counter(reg, "rtfds_shard_chunks_total", routed="1") == 1
     assert _counter(reg, "rtfds_exchange_overflow_total") == 1
+    assert _counter(reg, "rtfds_exchange_lanes_total") == (
+        512 + N_DEV * N_DEV * 8)
+    assert _counter(reg, "rtfds_exchange_rows_total") == 64 + 40
     assert _counter(reg, "rtfds_shard_rows_max_total") == n + int(
         np.bincount(cols2["customer_id"] % N_DEV, minlength=N_DEV).max())
     assert _counter(reg, "rtfds_shard_rows_mean_total") == 2 * n / N_DEV

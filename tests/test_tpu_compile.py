@@ -610,8 +610,8 @@ def test_fused_logreg_step_compiles(topo, one_chip, as_on_chip):
 def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
     """What `score --devices 4` serves for a 65,536-row micro-batch: the
     shard_map step over a four-device mesh (chunk = 4 × 2 × 16,384 rows),
-    with the terminal exchange's two all_to_alls and a quarter of the
-    state on each device."""
+    with the terminal exchange's two all_to_alls at the tight bucket and
+    at the chunk's width, and a quarter of the state on each device."""
     from real_time_fraud_detection_system_tpu.config import Config
     from real_time_fraud_detection_system_tpu.models.scaler import Scaler
     from real_time_fraud_detection_system_tpu.parallel.step import (
@@ -636,11 +636,20 @@ def test_sharded_step_compiles_on_four_chips(topo, as_on_chip):
     packed = _packed(n_dev * rows_per_shard,
                      NamedSharding(mesh, P(None, "data")))
     build = make_sharded_step(cfg, predict_fn_for("forest", z_mode="int8"),
-                              mesh=mesh, axis="data", packed=True)
+                              mesh=mesh, axis="data", packed=True,
+                              batch_rows=cfg.runtime.max_batch_rows)
     compiled = build(fstate, params, scaler, packed).lower(
         fstate, params, scaler, packed).compile()
     text = compiled.as_text()
-    assert text.count("all-to-all") >= 2
+    # the owner-placed program's terminal planes: one a branch, the
+    # smaller n_dev x 2 x the balanced load of the batch's 16,384 rows a
+    # chip ([n_dev, bucket, 5] uint32 forward, [n_dev, bucket, 6] float32
+    # back)
+    widths = sorted(n_dev * int(b) for b in re.findall(
+        r"= u32\[4,(\d+),5\]\S* all-to-all\(", text))
+    assert widths == [32768, 131072], widths
+    assert text.count(" all-to-all(") == 2 * len(widths)
+    assert len(re.findall(r" conditional\(", text)) == 1
     # 32,768 rows a chip: four slabs of the one-chip step's classify
     assert rows_per_shard == 4 * 8192
     check_one_pass_selector(text, 8192)
