@@ -131,9 +131,15 @@ class FeatureConfig:
     # in several payloads), so zero mid-stream recompiles. A pass's
     # payload is landed by the loop thread before _maybe_compact
     # returns (readable at once; the segment write follows on the
-    # store's writer thread). Resident on the host, with no eviction:
-    # the rows of every key in the store, 4 + 16 x n_day_buckets bytes
-    # each — the tier reaches as far as host memory does.
+    # store's writer thread). Resident on the host: the rows of every
+    # key in the store, 4 + 16 x n_day_buckets bytes each — and the
+    # store has the windows' own horizon: after every pass it drops the
+    # keys whose newest event day is older than now_day - (delay_days +
+    # max(windows)), rows no query can see (a key back later than that
+    # is admitted afresh, as one the pass reclaimed from the hot tier).
+    # So it holds the keys touched inside the horizon and not hot, not
+    # every key ever demoted; the tier reaches as far as host memory
+    # does over ONE horizon.
     # Empty string disables the tier (evictions discard, PR 13 behavior).
     # Accepts a local directory, an s3:// URL (flaky-store retries and
     # CRC verification inherited from the checkpoint backends), or
@@ -149,6 +155,11 @@ class FeatureConfig:
     # / compact_every is the demotion capacity a batch, and it has to
     # exceed the keys a batch admits (new keys + promotions) or the hot
     # tier fills and a promote lane finds no slot (ColdPromoteError).
+    # Under a calendar that moves the promotions are most of it: the
+    # hot set is the keys of the newest few event days, and whoever
+    # pays again after longer comes back through a lane (the
+    # benchmark's forest-rf100-d8-cold-replay: ~35,500 customers a
+    # 65,536-row batch, so 262,144 at a pass every 6 batches).
     cold_demote_slots: int = 1024
     # Hot-tier occupancy target: compaction demotes oldest-first down to
     # ceil(highwater * slot_capacity) occupied slots per table. Tied to
@@ -157,6 +168,14 @@ class FeatureConfig:
     # taken is served from the sketch for good — expected keys lost over
     # a fill to load a of D entries: D a^(P+1)/(P+1). 0.5 with P = 16 is
     # exact in practice (3e-5 keys at 2^23 entries); 0.75 needs P >= 16.
+    # The target is what a pass leaves; between two passes occupancy is
+    # cold_highwater + compact_every x admissions a batch / slots, and
+    # THAT is what the probes are sized for. A key touched on the
+    # stream's newest event day is never demoted: on one event day its
+    # distinct keys sit on top of the target (forest-rf100-d8-cold: 0.2,
+    # peak 0.46); under a calendar that moves yesterday's keys go today
+    # and the target holds (forest-rf100-d8-cold-replay: 0.35, peak
+    # 0.40) — README, Cold tier, has both worked.
     cold_highwater: float = 0.75
     # Count-min sketch for unbounded key cardinality (velocity features).
     cms_depth: int = 4

@@ -29,6 +29,18 @@ most ``cold keys x (4 + 16 x n_day_buckets)`` bytes plus the rows
 promoted since their segment's last live key left. A segment found on
 disk at open is read — and CRC-checked — on its first use.
 
+**What no window can see is forgotten.** The index keeps, beside each
+key, the newest event day its rows hold. A compaction pass hands the
+store the day before which history is dead (``now_day - (delay_days +
+max(windows))``, the pass's own cutoff) and :meth:`ColdStore.expire`
+drops every key whose newest day is older: no query can see its rows, so
+if it comes back it is admitted afresh, as a key reclaimed from the hot
+tier is. What stays resident is then bounded by the keys touched inside
+the horizon, not by every key ever demoted. A manifest carries one day a
+table (``newest_day``: no key of the segment is newer), which is what a
+reopened store knows of its keys — they expire late, never early; a
+manifest without it never expires.
+
 Contracts, in the order people get them wrong:
 
 - **Newest wins.** A key demoted twice resolves to its newest rows.
@@ -74,6 +86,7 @@ import numpy as np
 SEG_FORMAT = 1
 TMP_SCHEME = "tmp://"
 _BUF = -1  # segment id of rows still in the flush buffer
+_NO_DAY = np.iinfo(np.int32).max  # newest day unknown: never expires
 _ROW_DTYPES = (np.int32, np.float32, np.float32, np.float32)
 
 
@@ -114,14 +127,15 @@ def resolve_location(path_or_url: str) -> Tuple[str, bool]:
 class _TableIndex:
     """One table's membership: ``keys`` sorted, and beside each key the
     segment (``_BUF`` = the flush buffer) and the row that hold its
-    newest demoted rows."""
+    newest demoted rows, and the newest event day those rows hold."""
 
-    __slots__ = ("keys", "seg", "row")
+    __slots__ = ("keys", "seg", "row", "day")
 
     def __init__(self, key_dtype=np.uint32):
         self.keys = np.empty(0, key_dtype)
         self.seg = np.empty(0, np.int64)
         self.row = np.empty(0, np.int64)
+        self.day = np.empty(0, np.int32)
 
     def find(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """→ (position of each key, whether it is there)."""
@@ -132,14 +146,16 @@ class _TableIndex:
         pos_c = np.minimum(pos, self.keys.size - 1)
         return pos_c, self.keys[pos_c] == keys
 
-    def put(self, keys: np.ndarray, seg: int,
-            rows: np.ndarray) -> np.ndarray:
-        """``keys`` (unique) now live at ``(seg, rows)``: newest wins.
+    def put(self, keys: np.ndarray, seg: int, rows: np.ndarray,
+            days: np.ndarray) -> np.ndarray:
+        """``keys`` (unique) now live at ``(seg, rows)``, their newest
+        event days ``days``: newest wins.
         → the segments that the keys already stored were in before."""
         pos, hit = self.find(keys)
         left = self.seg[pos[hit]]
         self.seg[pos[hit]] = seg
         self.row[pos[hit]] = rows[hit]
+        self.day[pos[hit]] = days[hit]
         if hit.all():
             return left
         new_k = keys[~hit]
@@ -149,13 +165,14 @@ class _TableIndex:
         self.keys = np.insert(self.keys, at, new_k)
         self.seg = np.insert(self.seg, at, seg)
         self.row = np.insert(self.row, at, new_r)
+        self.day = np.insert(self.day, at, days[~hit][order])
         return left
 
     def keep(self, mask: np.ndarray) -> np.ndarray:
         """Keep ``mask``'s entries → the segments of those dropped."""
         gone = self.seg[~mask]
-        self.keys, self.seg, self.row = (
-            self.keys[mask], self.seg[mask], self.row[mask])
+        self.keys, self.seg, self.row, self.day = (
+            self.keys[mask], self.seg[mask], self.row[mask], self.day[mask])
         return gone
 
 
@@ -259,11 +276,14 @@ class ColdStore:
                 self._quarantine_name(n)
                 continue
             self._seg_meta[seq] = man
+            newest = man.get("newest_day", {})
             for table, ks in keys.items():
                 # a key listed twice in one manifest: the later row wins
                 uk, first_rev = np.unique(ks[::-1], return_index=True)
                 self._put(table, uk, seq,
-                          (ks.size - 1 - first_rev).astype(np.int64))
+                          (ks.size - 1 - first_rev).astype(np.int64),
+                          np.full(uk.size, newest.get(table, _NO_DAY),
+                                  np.int32))
             self._next_seq = max(self._next_seq, seq + 1)
         committed = {m["blob"] for m in self._seg_meta.values()}
         for b in blobs:
@@ -301,9 +321,9 @@ class ColdStore:
                     self._data.pop(seq, None)
 
     def _put(self, table: str, keys: np.ndarray, seg: int,
-             rows: np.ndarray) -> None:
+             rows: np.ndarray, days: np.ndarray) -> None:
         self._live[seg] = self._live.get(seg, 0) + int(keys.size)
-        self._left(self._table(table).put(keys, seg, rows))
+        self._left(self._table(table).put(keys, seg, rows, days))
 
     def _keep(self, table: str, mask: np.ndarray) -> int:
         if mask.all():
@@ -315,12 +335,14 @@ class ColdStore:
     # -- append (compaction demotions) ----------------------------------
 
     def append(self, table: str, keys, bd, cnt, amt, frd,
-               flush: bool = True) -> int:
+               flush: bool = True, days=None) -> int:
         """Land demoted rows (``EMPTY_KEY`` lanes skipped; a key given
         twice keeps its last row): readable at once. A segment is
         flushed once the buffer crosses the segment threshold — here, or
         (``flush=False``) when the caller says :meth:`flush_if_full`.
-        Returns the rows appended."""
+        ``days`` [n] is the newest ``bucket_day`` of each lane where the
+        caller has it already (:func:`newest_days`). Returns the rows
+        appended."""
         keys = self._canon_keys(keys)
         live = np.flatnonzero(keys != self._empty_key)
         if not live.size:
@@ -335,10 +357,13 @@ class ColdStore:
             dense = 2 * live.size >= keys.size
             rows = tuple(a[:live.size] if dense else a[:live.size].copy()
                          for a in rows)
+            take = slice(live.size)
         else:
             uk, first_rev = np.unique(keys[live][::-1], return_index=True)
             take = live[live.size - 1 - first_rev]  # last lane of each key
             rows = tuple(a[take] for a in rows)
+        days = (newest_days(rows[0]) if days is None
+                else np.asarray(days, np.int32).reshape(-1)[take])
         with self._lock:
             buf = self._data.setdefault(_BUF, {})
             old = buf.get(table)
@@ -346,7 +371,7 @@ class ColdStore:
             buf[table] = rows if old is None else tuple(
                 np.concatenate([o, r]) for o, r in zip(old, rows))
             self._put(table, uk, _BUF,
-                      base + np.arange(uk.size, dtype=np.int64))
+                      base + np.arange(uk.size, dtype=np.int64), days)
             self._buf_bytes += uk.size * (
                 keys.itemsize + 16 * rows[0].shape[1])
         if flush:
@@ -366,7 +391,8 @@ class ColdStore:
             with self._lock:
                 buf = self._data.pop(_BUF, None)
                 self._buf_bytes = 0
-                arrays, man_keys, resident, where = {}, {}, {}, {}
+                arrays, man_keys, resident, where, newest = (
+                    {}, {}, {}, {}, {})
                 for table, rows in sorted((buf or {}).items()):
                     t = self._table(table)
                     at = np.flatnonzero(t.seg == _BUF)
@@ -379,6 +405,7 @@ class ColdStore:
                     resident[table] = rows if whole else tuple(
                         a[src] for a in rows)
                     man_keys[table] = t.keys[at]
+                    newest[table] = int(t.day[at].max())
                     where[table] = at
                     arrays[f"{table}_keys"] = man_keys[table]
                     for name, a in zip(("bd", "cnt", "amt", "frd"),
@@ -407,6 +434,7 @@ class ColdStore:
                 "crc": zlib.crc32(data) & 0xFFFFFFFF,
                 "bytes": len(data),
                 "keys": {t: ks.tolist() for t, ks in man_keys.items()},
+                "newest_day": newest,
             }
             if self.key_bits == 64:
                 man["key_bits"] = 64  # a 32-bit manifest stays as it was
@@ -492,11 +520,22 @@ class ColdStore:
 
     def cold_mask(self, table: str, keys) -> np.ndarray:
         """Which of ``keys`` (any order, repeats allowed) the store
-        holds: the engine's returning-key detection, one sorted-index
-        lookup a batch."""
+        holds."""
+        return self.find_days(table, keys)[0]
+
+    def find_days(self, table: str, keys) -> Tuple[np.ndarray, np.ndarray]:
+        """The engine's returning-key detection, one sorted-index lookup
+        a batch: which of ``keys`` (any order, repeats allowed) the store
+        holds, and beside it the newest event day of each (undefined
+        where the store holds none) — what tells a returning key from
+        one whose rows no window can see any more."""
         keys = self._canon_keys(keys)
         with self._lock:
-            return self._table(table).find(keys)[1]
+            t = self._table(table)
+            pos, found = t.find(keys)
+            days = t.day[pos] if t.keys.size else np.zeros(
+                keys.shape, np.int32)
+            return found, days
 
     def contains(self, table: str, key: int) -> bool:
         return bool(self.cold_mask(table, [key])[0])
@@ -520,10 +559,24 @@ class ColdStore:
         return self._keep(table, mask)
 
     def mark_promoted(self, table: str, keys: Sequence[int]) -> None:
-        """The keys' rows went back to the hot tier: drop their index
-        entries (stops re-detection; segment bytes stay until gc)."""
+        """The keys' rows went back to the hot tier — or no window can
+        see them any more and the key was admitted afresh: drop their
+        index entries (stops re-detection; segment bytes stay until
+        gc)."""
         with self._lock:
             self._forget(table, self._canon_keys(keys))
+
+    def expire(self, cutoff_day: int) -> int:
+        """The event-time horizon: drop every key whose newest event day
+        is older than ``cutoff_day`` — the compaction pass's own cutoff,
+        ``now_day - (delay_days + max(windows))``: history no query can
+        see. A segment whose last live key goes gives up its resident
+        arrays, as after a promotion. → the keys dropped."""
+        gone = 0
+        with self._lock:
+            for table, t in self._tables.items():
+                gone += self._keep(table, t.day >= cutoff_day)
+        return gone
 
     def gc(self) -> List[int]:
         """Delete segments with zero live index entries. Call AFTER a
@@ -641,6 +694,12 @@ class ColdStore:
             return seg + self._buf_bytes
 
 
+def newest_days(bucket_day: np.ndarray) -> np.ndarray:
+    """Newest event day of each window row: int32 [n] from ``bucket_day``
+    [n, NB] (−1 for an empty row)."""
+    return np.maximum.reduce(bucket_day, axis=1).astype(np.int32, copy=False)
+
+
 def consolidate_cold_stores(src_paths: Sequence[str], dest_path: str,
                             segment_mb: float = 4.0) -> ColdStore:
     """Merge the LIVE rows of several per-process cold stores into one
@@ -687,7 +746,9 @@ class SegmentWriter:
     is found where every other cold key is). What follows here, and what
     nobody waits for, is the segment write — the ``.npz`` blob, its CRC
     and the manifest of a full flush buffer. :meth:`kick` asks for one
-    :meth:`ColdStore.flush_if_full`; :meth:`wait` blocks until every
+    :meth:`ColdStore.flush_if_full` (the engine asks as it starts to
+    wait for the next pass: the write takes the interpreter's lock from
+    whoever else needs it); :meth:`wait` blocks until every
     request is served (a checkpoint save, the end of a run); a
     worker-side failure re-raises on the loop thread, with its own type,
     from the next call.
@@ -723,7 +784,7 @@ class SegmentWriter:
             raise e
 
     def kick(self) -> None:
-        """Rows were appended: write a segment if the buffer is full."""
+        """Write a segment if the buffer is full."""
         with self._lock:
             self._raise()
             self._inflight += 1

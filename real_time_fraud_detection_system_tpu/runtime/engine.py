@@ -27,6 +27,7 @@ import numpy as np
 
 from real_time_fraud_detection_system_tpu.config import Config
 from real_time_fraud_detection_system_tpu.core.batch import (
+    US_PER_DAY,
     TxBatch,
     bucket_size,
     device_keys,
@@ -100,8 +101,9 @@ PHASES = ("source_poll", "host_prep", "dispatch", "result_wait",
 # the tier is armed: cold_detect (inside host_prep), state_promote (row
 # read, payload build and promote dispatch, between host_prep and
 # dispatch), cold_append (one pass's payload fetch and landing, inside
-# state_compact).
-COLD_PHASES = ("cold_detect", "state_promote", "cold_append")
+# state_compact), cold_expire (the store forgetting, after a pass, the
+# keys no window can see any more, inside state_compact).
+COLD_PHASES = ("cold_detect", "state_promote", "cold_append", "cold_expire")
 # What the loop thread's pass is made of besides (PR 37; README, Tracing:
 # the span tree), each a span and a series of the same histogram:
 # loop_pass (one pass of run()'s loop), sink_join and sink_enqueue (the
@@ -995,6 +997,9 @@ class ScoringEngine:
                                segment_mb=fcfg.cold_segment_mb,
                                key_bits=fcfg.key_bits)
         self._cold_writer = SegmentWriter(self._cold)
+        # days of event time any window can see: what compaction reclaims
+        # in the hot tier, the store forgets
+        self._cold_horizon = int(fcfg.delay_days + max(fcfg.windows))
         self._promote_widths = promote_widths(
             max(self.cfg.runtime.batch_buckets))
 
@@ -1032,6 +1037,23 @@ class ScoringEngine:
             "rtfds_feature_cold_promote_lanes_total",
             "lanes of the promote programs dispatched, padding included "
             "(live lanes = rtfds_feature_cold_promotions_total)")
+        self._m_cold_expired = reg.counter(
+            "rtfds_feature_cold_expired_total",
+            "cold-tier keys the store dropped as dead: their newest event "
+            "day fell behind now_day - (delay_days + max(windows))")
+        self._m_cold_dead = reg.counter(
+            "rtfds_feature_cold_dead_returns_total",
+            "returning keys whose cold rows no window could see any more: "
+            "admitted afresh, no promote lane taken")
+        self._m_cold_age = reg.counter(
+            "rtfds_feature_cold_demote_age_days_total",
+            "event days since their last row, summed over the keys "
+            "demoted (over rtfds_feature_cold_demotions_total: the mean "
+            "age at demotion)")
+
+    def _meter_cold_store(self) -> None:
+        self._m_cold_keys.set(float(self._cold.keys_count))
+        self._m_cold_bytes.set(float(self._cold.bytes))
 
     def _land_demotions(self, payload: dict) -> None:
         """Land one compaction pass's demotion payload in the store, here
@@ -1042,9 +1064,14 @@ class ScoringEngine:
         ``compact_fetch``; the rows' copies off the device (up to
         ``cold_demote_slots x 16 NB`` bytes a table) are started
         together and only for a table that demoted something. The
-        segment write follows on the writer thread. The
+        segment write follows on the writer thread, asked for by the
+        NEXT pass while this thread waits for the device. The
         sharded engine's stacked ``[n_dev, K, ...]`` leaves need nothing
         special: the store flattens lanes."""
+        from real_time_fraud_detection_system_tpu.io.coldstore import (
+            newest_days,
+        )
+
         parts = []
         for table in self._cold_tables():
             pay = payload.get(table)
@@ -1055,23 +1082,39 @@ class ScoringEngine:
             # stacked one-word lanes flatten
             keys = (join_key(keys) if self._key_bits == 64
                     else keys.reshape(-1))
-            if not (keys != np.iinfo(keys.dtype).max).any():
+            live = keys != np.iinfo(keys.dtype).max
+            if not live.any():
                 continue  # nothing demoted: the rows are not fetched
             for leaf in pay[1:]:
                 leaf.copy_to_host_async()
-            parts.append((table, keys, pay[1:]))
+            parts.append((table, keys, live, pay[1:]))
         if not parts:
             return
+        total = age = 0
         with self._phase("cold_append"):
-            total = sum(
-                self._cold.append(table, keys,
-                                  *(np.asarray(r) for r in rows),
-                                  flush=False)
-                for table, keys, rows in parts)
-        self._cold_writer.kick()
+            for table, keys, live, rows in parts:
+                # the day stamps arrive first: their row maxima are taken
+                # while the three float columns are still on their way
+                stamps = np.asarray(rows[0])
+                days = newest_days(stamps.reshape(keys.size, -1))
+                total += self._cold.append(
+                    table, keys, stamps, *(np.asarray(r) for r in rows[1:]),
+                    flush=False, days=days)
+                age += int((self._max_day - days[live].astype(np.int64))
+                           .sum())
         self._m_cold_dem.inc(total)
-        self._m_cold_keys.set(float(self._cold.keys_count))
-        self._m_cold_bytes.set(float(self._cold.bytes))
+        self._m_cold_age.inc(age)
+        self._meter_cold_store()
+
+    def _expire_cold(self) -> None:
+        """After a pass: the store forgets every key whose newest event
+        day the pass's own cutoff has left behind — rows no window can
+        see, which the hot tier gives back to its free stack in the same
+        pass."""
+        with self._phase("cold_expire"):
+            gone = self._cold.expire(self._max_day - self._cold_horizon)
+        self._m_cold_expired.inc(gone)
+        self._meter_cold_store()
 
     def _promote_lanes(self, table: str, keys: np.ndarray,
                        rows: tuple) -> list:
@@ -1104,6 +1147,10 @@ class ScoringEngine:
             return None
         hits, cold_row = {}, None
         with self._phase("cold_detect"):
+            us = cols["tx_datetime_us"]
+            # older than this, no row of the batch can see it
+            dead_before = (int(np.min(us) // US_PER_DAY)
+                           - self._cold_horizon) if len(us) else 0
             for table, col in (("customer", "customer_id"),
                                ("terminal", "terminal_id")):
                 ids = cols.get(col)
@@ -1119,7 +1166,15 @@ class ScoringEngine:
                     # never demoted, so never found here.)
                     keys = np.where(keys == np.uint32(0xFFFFFFFF),
                                     np.uint32(0xFFFFFFFE), keys)
-                mask = self._cold.cold_mask(table, keys)
+                mask, newest = self._cold.find_days(table, keys)
+                dead = mask & (newest < dead_before)
+                if dead.any():
+                    # every cold row of the key is dead history: the step
+                    # admits it afresh, as it does a key a pass reclaimed
+                    gone = np.unique(keys[dead])
+                    self._cold.mark_promoted(table, gone)
+                    self._m_cold_dead.inc(int(gone.size))
+                    mask &= ~dead
                 if mask.any():
                     hits[table] = np.unique(keys[mask])
                     cold_row = mask if cold_row is None \
@@ -1182,8 +1237,7 @@ class ScoringEngine:
                         int(np.prod(payload[table][0].shape)))
                 self._cold.mark_promoted(table, keys)
                 self._m_cold_prom.inc(int(keys.size))
-        self._m_cold_keys.set(float(self._cold.keys_count))
-        self._m_cold_bytes.set(float(self._cold.bytes))
+        self._meter_cold_store()
         return checks
 
     def _check_promotes(self, handle: dict) -> None:
@@ -1204,10 +1258,16 @@ class ScoringEngine:
                     f"cold tier: {dropped} returning {table} key(s) could "
                     "not be admitted to the hot tier before their rows "
                     "were scored (free slots ran out between compaction "
-                    "passes). Size the tier so that cold_demote_slots x "
-                    "passes keeps occupancy under cold_highwater: raise "
-                    "cold_demote_slots, lower compact_every or "
-                    "cold_highwater, or add slots (README, Cold tier)")
+                    "passes). Size the tier by its three rules (README, "
+                    "Cold tier): cold_demote_slots / compact_every >= the "
+                    "keys a batch admits, returning and new; occupancy "
+                    "between two passes <= 0.5 of the slots; and every "
+                    "pass finds keys last touched before its newest event "
+                    "day to demote — under a calendar that moves that is "
+                    "the keys of the days before, so the hot set is what "
+                    "cold_highwater x slots holds of the newest days. "
+                    "Raise cold_demote_slots, lower compact_every or "
+                    "cold_highwater, or add slots")
 
     def _count_claim_rounds(self, rounds, narrow) -> None:
         """``rounds`` = [customer, terminal] claim rounds one program ran,
@@ -1256,8 +1316,7 @@ class ScoringEngine:
                     "cold tier re-homed for process %d/%d: dropped %d "
                     "foreign key(s)", topo.process_id,
                     topo.n_processes, dropped)
-        self._m_cold_keys.set(float(self._cold.keys_count))
-        self._m_cold_bytes.set(float(self._cold.bytes))
+        self._meter_cold_store()
 
     def checkpoint_state(self) -> EngineState:
         """The state a checkpoint save should persist. With a terminal-
@@ -1321,10 +1380,6 @@ class ScoringEngine:
             return
         us = cols.get("tx_datetime_us")
         if us is not None and len(us):
-            from real_time_fraud_detection_system_tpu.core.batch import (
-                US_PER_DAY,
-            )
-
             newest = int(np.max(us) // US_PER_DAY)
             if int(np.min(us) // US_PER_DAY) != newest:
                 self._m_multi_day.inc()
@@ -1352,6 +1407,14 @@ class ScoringEngine:
                     ("compact",), self._compact,
                     self.state.feature_state, day)
             fstate, reclaimed = out[:2]
+            if self._demote_slots:
+                # the durable copy of what the LAST pass landed is made
+                # now, while this thread waits for the device: a segment's
+                # serialisation holds the interpreter's lock for a few
+                # tenths of a second, and right after a landing it would
+                # take them from the next batch's poll, prep and promote
+                # with the chip idle (PERF.md, PR 53)
+                self._cold_writer.kick()
             with self._phase("compact_fetch"):
                 # the wait for the pass, and for the steps in flight
                 # ahead of it: the pass's other outputs are ready with
@@ -1359,6 +1422,7 @@ class ScoringEngine:
                 reclaimed = np.asarray(reclaimed)
             if self._demote_slots:
                 self._land_demotions(out[2])
+                self._expire_cold()
             self.state.feature_state = fstate
             self._record_compaction(fstate, reclaimed)
             self._m_compactions.inc()
@@ -2188,10 +2252,6 @@ class ScoringEngine:
         cols = handle["cols"]
         n = handle["n"]
         if self.feature_cache is not None and n:
-            from real_time_fraud_detection_system_tpu.core.batch import (
-                US_PER_DAY,
-            )
-
             in_band = cols.get("label")
             self.feature_cache.put_batch(
                 cols["tx_id"], feats_np,

@@ -175,7 +175,7 @@ def _readme_performance_rows():
     "forest.saturate", "forest.steady", "logreg.saturate",
     "forest-x4.saturate", "forest-exact.saturate", "forest-cold.saturate",
     "forest-id64.saturate", "forest-x4-exact.saturate",
-    "forest-replay.saturate"])
+    "forest-replay.saturate", "forest-cold-replay.saturate"])
 def test_readme_performance_says_what_the_ledger_says(cell):
     """Every cell has one row in README's table, its rates and latencies
     marked "(ledger, PR n)"; while the ledger still holds that PR's line
