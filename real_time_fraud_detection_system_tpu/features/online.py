@@ -612,9 +612,16 @@ def compact_feature_state(
     ``reclaimed`` is 0, which is how the host counts the sweeps
     (``rtfds_state_compact_sweeps_total``); one that does pays one
     gather a directory entry to find the entries, and packs what it
-    vacates K lanes a trip (``ops/keydir.reclaim_entries``). Either way
-    the pass ends in one dense select a window column and two over the
-    directory, which do not follow the input.
+    vacates K lanes a trip (``ops/keydir.reclaim_entries``), and then
+    sweeps its four window columns once — one flat select a column, in
+    a loop that runs no trip for a table that vacated nothing
+    (``WindowState.clear_slots``). What does not follow the input: the
+    per-slot ``newest`` of both tables, read from the flat stamps
+    (``WindowState.newest``), and two dense selects over each directory.
+    On a v5e at 2^22 + 2^23 slots this table-wide part is 17.7 ms of a
+    pass that takes nothing and 46.9 of one in which both tables give,
+    where it was 67.4 of every pass through padded ``[cap, 40]`` views
+    (PERF.md, PR 54).
 
     ``demote_slots > 0`` adds the cold tier's PRESSURE eviction behind
     the dead reclaim: when a table still sits above
@@ -674,11 +681,13 @@ def _compact_table(
 
     The two conditionals yield a ``[dir_cap]`` vector each and touch
     neither the window columns nor the directory: state a conditional
-    returned, it might copy."""
+    returned, it might copy. The columns are read and written as they
+    are stored, flat (``WindowState.newest`` / ``clear_slots``), and
+    written only by a table that gives something up."""
     horizon = int(cfg.delay_days + max(cfg.windows))
     with step_scope("compact"):
         cutoff = now_day - jnp.int32(horizon)
-        newest = jnp.max(ws.tables()[0], axis=1)  # [slot_cap]
+        newest = ws.newest()  # [slot_cap]
         live = kd.slots >= 0
         gives, demotes, n_evict = _table_gives(
             kd, newest, cutoff, now_day,
@@ -707,7 +716,7 @@ def _compact_table(
         if demote_slots:
             dead_entry = dead_entry | sel
         kd, vacated, n = reclaim_entries(kd, dead_entry)
-        ws = ws.clear_slots(vacated)
+        ws = ws.clear_slots(vacated, n)
     return kd, ws, n, payload
 
 

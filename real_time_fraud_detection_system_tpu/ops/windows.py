@@ -24,8 +24,11 @@ at 2^22 + 2^23 slots 206.7 of a 305.4 ms step moved tables between two
 layouts and computed nothing (PERF.md, PR 24 / PR 25). A flat column is
 also, byte for byte, its ``[n/128, 128]`` view, which is what the row
 gather of the query reads (:meth:`WindowState.rows`). Code that thinks in
-``[cap, NB]`` (compaction, promotion, reshard, the checkpoint's leaves)
-goes through :meth:`WindowState.tables` / ``rows`` / ``set_rows``.
+``[cap, NB]`` goes through :meth:`WindowState.rows` / ``set_rows``
+(promotion, the demote payload), :meth:`WindowState.newest` /
+``clear_slots`` (the compaction's table-wide part, on the flat bytes) or,
+on the host, :meth:`WindowState.tables` (reshard, the checkpoint's
+leaves).
 
 Canonical window semantics (documented deviation from the reference): windows
 are **trailing calendar days including the current day** — window w at day d
@@ -129,10 +132,13 @@ class WindowState:
         return tuple(getattr(self, c) for c in COLUMNS)
 
     def tables(self) -> Tuple[jnp.ndarray, ...]:
-        """The four columns as ``[cap, NB]``. Free on host arrays (a flat
-        slot-major array reshapes to rows without a copy); on the chip it
-        is a pass over the table, so nothing on the per-batch path takes
-        it."""
+        """The four columns as ``[cap, NB]``, for the host (checkpoints,
+        the mesh's reshard, tests): free on host arrays (a flat
+        slot-major array reshapes to rows without a copy). On the chip
+        the view pads 40 lanes to 128 — a pass over the table that
+        writes 3.2 × its bytes — so no device program takes it: the
+        step never did, and the compaction reads and writes the flat
+        columns (:meth:`newest`, :meth:`clear_slots`; PR 54)."""
         return tuple(c.reshape(self.capacity, self.n_buckets)
                      for c in self.columns())
 
@@ -214,26 +220,150 @@ class WindowState:
                               (bucket_day, count, amount, fraud))),
             n_buckets=nb)
 
+    def _slot_groups(self):
+        """How the compaction reads a flat column without re-laying it
+        out → ``(groups, rows, lanes, slots, seg, first, reach)``.
 
-    def clear_slots(self, vacated: jnp.ndarray) -> "WindowState":
+        A slot's NB entries are NB consecutive elements, and NB is no
+        divisor of the 128 lanes, so a slot's lanes differ from row to row
+        of the ``[n/128, 128]`` view — but the pattern repeats: lcm(NB,
+        128) elements hold whole rows AND whole slots (640 = 5 rows = 16
+        slots at NB = 40), and eight such periods are whole (8, 128)
+        tiles, so ``[groups, rows, lanes]`` with ``rows`` = 40 is the
+        stored bytes viewed in place (compiled for a v5e: a ``bitcast``)
+        and a group's ``slots`` = 128 results are whole rows of the
+        ``[cap]`` answer. Sizes by arithmetic from the column's length
+        and NB, as :meth:`rows` does it: a column whose length is no
+        multiple of 128, or that holds no eight periods (toy sizes),
+        takes the widest view that divides it.
+
+        The patterns, built once in ``lax`` for every column of a call:
+        ``seg`` int32 ``[rows, lanes]`` numbers the slots a row touches
+        0, 1, ... along its lanes, ``first`` int32 ``[rows, 1]`` is the
+        slot (of the group's) a row starts in, and ``reach`` (static) is
+        the most slots one row touches — 4 at NB = 40."""
+        nb = self.n_buckets
+        n = int(self.bucket_day.shape[0])
+        lanes = math.gcd(n, 128)
+        group = math.lcm(nb, lanes)
+        if n % (8 * group) == 0:
+            group *= 8
+        rows, slots = group // lanes, group // nb
+        at = lax.mul(lax.broadcasted_iota(jnp.int32, (rows, lanes), 0),
+                     np.int32(lanes))
+        first = lax.div(at, np.int32(nb))
+        seg = lax.sub(
+            lax.div(lax.add(at, lax.broadcasted_iota(
+                jnp.int32, (rows, lanes), 1)), np.int32(nb)), first)
+        reach = (lanes - math.gcd(nb, lanes) + nb - 1) // nb + 1
+        return (n // group, rows, lanes, slots, seg,
+                lax.slice(first, (0, 0), (rows, 1)), reach)
+
+    def newest(self) -> jnp.ndarray:
+        """Every slot's newest ``bucket_day``, int32 ``[capacity]`` —
+        ``max(tables()[0], axis=1)`` read from the flat column as it is
+        stored (:meth:`_slot_groups`): for each of the ``reach`` slots a
+        row can touch — a ``lax.while_loop``, one copy of the body in the
+        program — a maximum along the lanes under that slot's lane
+        pattern (``[groups, rows]`` partials), moved onto its slot's lane
+        of the group's result by a select and a maximum over the rows. No
+        ``[cap, NB]`` array exists: on the chip that view pads 40 lanes
+        to 128, 4.3 GB for the 2^23-slot table's stamps. On a v5e 11.6
+        ms for that table where the reduce over the padded view took
+        15.4 — lane reduces, not bytes, are what it costs (1.6 ms at
+        819 GB/s; PERF.md, PR 54)."""
+        groups, rows, lanes, slots, seg, first, reach = self._slot_groups()
+        x = lax.reshape(self.bucket_day, (groups, rows, lanes))
+        lowest = np.int32(np.iinfo(np.int32).min)
+        slot = lax.broadcasted_iota(jnp.int32, (rows, slots), 1)
+
+        def fold(carry):
+            k, out = carry
+            here = lax.broadcast_in_dim(lax.eq(seg, k), x.shape, (1, 2))
+            part = lax.reduce_max(
+                lax.select(here, x, lax.full_like(x, lowest)), (2,))
+            onto = lax.broadcast_in_dim(lax.eq(lax.add(first, k), slot),
+                                        (groups, rows, slots), (1, 2))
+            moved = lax.reduce_max(lax.select(
+                onto,
+                lax.broadcast_in_dim(part, (groups, rows, slots), (0, 1)),
+                lax.full((groups, rows, slots), lowest, jnp.int32)), (1,))
+            return lax.add(k, np.int32(1)), lax.max(out, moved)
+
+        _, out = lax.while_loop(
+            lambda carry: lax.lt(carry[0], np.int32(reach)), fold,
+            (np.int32(0), lax.full((groups, slots), lowest, jnp.int32)))
+        return lax.reshape(out, (groups * slots,))
+
+    def clear_slots(self, vacated: jnp.ndarray,
+                    n_vacated: jnp.ndarray) -> "WindowState":
         """Empty the rows of the slots flagged in ``vacated`` (bool
-        ``[capacity]``): one dense select a column, no indexed work — the
-        compaction flags the slots it gives up a packed chunk at a time
-        (``ops/keydir.reclaim_entries``) and sweeps once. For slots of
-        the order of the table :meth:`set_rows` is the wrong tool: its
+        ``[capacity]``, ``n_vacated`` int32 [] of them): the compaction
+        flags the slots it gives up a packed chunk at a time
+        (``ops/keydir.reclaim_entries``) and sweeps once — if there is
+        anything to sweep. The sweep is one flat select a column under a
+        per-element mask, the slots' flags spread over their lanes by the
+        patterns of :meth:`_slot_groups`: a one-hot product on the MXU
+        brings each row the flags of the slots it touches, bit k of a
+        word for its k-th (powers of two, exact in bfloat16; 16 to a
+        word, so the float32 sum is exact too), and an element's flag is
+        the bit its ``seg`` names. Nothing is viewed as ``[cap, NB]``
+        (on a v5e 19.2 ms for the 2^23-slot table's four columns where
+        the padded mask's way took 28.9; PERF.md, PR 54).
+        It runs as the one trip of a ``lax.while_loop`` whose carry is
+        the four columns, updated in place; ``n_vacated`` = 0, no trip,
+        and the columns leave as the buffers they came in as (a
+        ``lax.cond`` that yields a column may copy it). For slots of the
+        order of the table :meth:`set_rows` is the wrong tool: its
         ``[K, NB]`` element indices alone are 2.7 GB at 2^24 lanes, and
         the chip's compiler refuses the program (18.4 GB of 15.75;
         compiled for a described v5e, PR 32)."""
-        cap, nb = self.capacity, self.n_buckets
+        groups, rows, lanes, slots, seg, first, reach = self._slot_groups()
+        shape = (groups, rows, lanes)
+        word = 16
+        # which of a row's slots (counted from its first) is the group's j
+        nth = lax.sub(lax.broadcasted_iota(jnp.int32, (rows, slots), 1),
+                      first)
+        fills = (np.int32(-1), np.float32(0), np.float32(0), np.float32(0))
 
-        def clear(col, fill):
-            return jnp.where(vacated[:, None], fill,
-                             col.reshape(cap, nb)).reshape(-1)
+        def bit_of(k):
+            """``k`` int32 counted from a word's first bit → (whether it
+            is inside the word, ``k`` clamped into it)."""
+            inside = lax.bitwise_and(lax.ge(k, np.int32(0)),
+                                     lax.lt(k, np.int32(word)))
+            return inside, lax.clamp(np.int32(0), k, np.int32(word - 1))
 
-        return WindowState(
-            *(clear(c, f) for c, f in zip(
-                self.columns(), (jnp.int32(-1), 0.0, 0.0, 0.0))),
-            n_buckets=nb)
+        def sweep(carry):
+            _, cols = carry
+            flags = lax.convert_element_type(
+                lax.reshape(vacated, (groups, slots)), jnp.bfloat16)
+            mask = None
+            for base in range(0, reach, word):
+                inside, k = bit_of(lax.sub(nth, np.int32(base)))
+                weights = lax.select(inside,
+                                     lax.shift_left(lax.full_like(k, 1), k),
+                                     lax.full_like(k, 0))
+                words = lax.convert_element_type(lax.dot_general(
+                    flags, lax.convert_element_type(weights, jnp.bfloat16),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32), jnp.int32)
+                inside, k = bit_of(lax.sub(seg, np.int32(base)))
+                hit = lax.bitwise_and(
+                    lax.broadcast_in_dim(inside, shape, (1, 2)),
+                    lax.ne(lax.bitwise_and(lax.shift_right_logical(
+                        lax.broadcast_in_dim(words, shape, (0, 1)),
+                        lax.broadcast_in_dim(k, shape, (1, 2))),
+                        np.int32(1)), np.int32(0)))
+                mask = hit if mask is None else lax.bitwise_or(mask, hit)
+            return np.int32(0), tuple(
+                lax.reshape(lax.select(mask, lax.full(shape, fill, c.dtype),
+                                       lax.reshape(c, shape)), c.shape)
+                for c, fill in zip(cols, fills))
+
+        _, cols = lax.while_loop(
+            lambda carry: lax.gt(carry[0], np.int32(0)), sweep,
+            (lax.convert_element_type(n_vacated, jnp.int32), self.columns()))
+        return WindowState(*cols, n_buckets=self.n_buckets)
 
 
 def init_window_state(capacity: int, n_buckets: int,

@@ -44,7 +44,10 @@ from real_time_fraud_detection_system_tpu.ops.keydir import (
     admit_slots,
     lookup_slots,
 )
-from real_time_fraud_detection_system_tpu.ops.windows import query_windows
+from real_time_fraud_detection_system_tpu.ops.windows import (
+    WindowState,
+    query_windows,
+)
 from real_time_fraud_detection_system_tpu.runtime.engine import (
     ScoringEngine,
 )
@@ -592,6 +595,21 @@ def spy_on_passes(eng):
     return seen
 
 
+def _device_share(st, n_dev, s):
+    """Device ``s``'s share of a mesh's state (host leaves): its block of
+    every window column, its row of the stacked directories; the state
+    itself when ``n_dev`` is 0."""
+    if not n_dev:
+        return st
+    return st._replace(**{
+        t: dc.replace(getattr(st, t), **{
+            c: getattr(getattr(st, t), c).reshape(n_dev, -1)[s]
+            for c in ("bucket_day", "count", "amount", "fraud")})
+        for t in ("customer", "terminal")}, **{
+        f"{t}_dir": jax.tree.map(lambda x: x[s], getattr(st, f"{t}_dir"))
+        for t in ("customer", "terminal")})
+
+
 def assert_recorded_pass_equals_oracle(before, day, out, fcfg, demote,
                                        n_dev=0):
     """One recorded pass against the oracle — a device of the mesh at a
@@ -600,20 +618,10 @@ def assert_recorded_pass_equals_oracle(before, day, out, fcfg, demote,
     ``{table: count}``: those that gave something up."""
     swept = {"customer": 0, "terminal": 0}
     for s in range(max(n_dev, 1)):
-        def mine(x, flat=False):
-            if not n_dev:
-                return x
-            return x.reshape(n_dev, -1)[s] if flat else x[s]
+        def mine(x):
+            return x[s] if n_dev else x
 
-        def shard_of(st):
-            return st._replace(**{
-                t: dc.replace(getattr(st, t), **{
-                    c: mine(getattr(getattr(st, t), c), flat=True)
-                    for c in ("bucket_day", "count", "amount", "fraud")})
-                for t in ("customer", "terminal")}, **{
-                f"{t}_dir": jax.tree.map(mine, getattr(st, f"{t}_dir"))
-                for t in ("customer", "terminal")})
-
+        shard_of = functools.partial(_device_share, n_dev=n_dev, s=s)
         want, want_n, want_pay = _entry_wide_compaction(
             shard_of(before), day, fcfg, demote)
         got = shard_of(out[0])
@@ -731,6 +739,181 @@ def test_a_vacated_probe_prefix_still_resolves_after_the_pass():
         else:  # came back: a fresh grant of a cleared row
             assert (np.asarray(after[0][s]) == -1).all()
     assert int(kd.free_top) == 64 - 60
+
+
+# -- the pass's table-wide part works on the flat columns (PR 54) -------------
+#
+# ``WindowState.newest`` and ``clear_slots`` read and write a column as it
+# is stored; what they have to equal is the plain ``[cap, nb]`` view, and
+# the pass built on them the pass the parent built on that view — kept
+# here, as the parent wrote it, as the reference.
+
+FLAT_SHAPES = [
+    pytest.param(16, 40, id="16x40-one-period"),
+    pytest.param(48, 40, id="48x40-three-periods"),
+    pytest.param(4096, 40, id="4096x40-whole-tiles"),
+    pytest.param(64, 8, id="64x8-sixteen-slots-a-row"),
+    pytest.param(16, 30, id="16x30-32-lanes"),
+    pytest.param(100, 30, id="100x30-8-lanes"),
+    pytest.param(5, 40, id="5x40-no-multiple-of-128"),
+    pytest.param(64, 4, id="64x4-two-words-of-flags"),
+]
+
+
+def _random_table(cap, nb, seed):
+    rng = np.random.default_rng(seed)
+    bd = rng.integers(-1, 30000, (cap, nb)).astype(np.int32)
+    bd[rng.random(cap) < 0.2] = -1  # whole rows still empty
+    cols = [bd] + [rng.random((cap, nb), dtype=np.float32)
+                   for _ in range(3)]
+    return rng, cols, WindowState.from_tables(*map(jnp.asarray, cols))
+
+
+@pytest.mark.parametrize("cap, nb", FLAT_SHAPES)
+def test_flat_newest_equals_the_tables_row_maximum(cap, nb):
+    _, cols, ws = _random_table(cap, nb, cap + nb)
+    got = jax.jit(WindowState.newest)(ws)
+    assert got.shape == (cap,) and got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), cols[0].max(axis=1))
+
+
+@pytest.mark.parametrize("cap, nb", FLAT_SHAPES)
+def test_flat_clear_equals_the_tables_row_select(cap, nb):
+    """The flagged slots' rows at their fills and every other element
+    the bits it held; under a count of 0 no trip runs, whatever is
+    flagged."""
+    rng, cols, ws = _random_table(cap, nb, 7 * cap + nb)
+    clear = jax.jit(WindowState.clear_slots)
+    for share in (0.0, 0.3, 1.0):
+        vacated = rng.random(cap) < share
+        got = clear(ws, jnp.asarray(vacated), jnp.int32(vacated.sum()))
+        for a, col, fill in zip(got.tables(), cols, FILLS):
+            want = np.where(vacated[:, None], fill, col)
+            assert np.asarray(a).tobytes() == want.tobytes()
+    kept = clear(ws, jnp.ones(cap, bool), jnp.int32(0))
+    for a, col in zip(kept.tables(), cols):
+        assert np.asarray(a).tobytes() == col.tobytes()
+
+
+def _parents_newest(ws):
+    return jnp.max(ws.tables()[0], axis=1)
+
+
+def _parents_clear_slots(ws, vacated, n_vacated):
+    cap, nb = ws.capacity, ws.n_buckets
+
+    def clear(col, fill):
+        return jnp.where(vacated[:, None], fill,
+                         col.reshape(cap, nb)).reshape(-1)
+
+    return WindowState(*(clear(c, f) for c, f in zip(ws.columns(), FILLS)),
+                       n_buckets=nb)
+
+
+def _assert_pass_equals_the_parents(before, day, out, cfg, demote,
+                                    monkeypatch):
+    """``out`` — what the pass left of ``before`` (one chip's state, host
+    leaves) — against the parent's formulation run on the same state:
+    every leaf of the state, the counts and the payload to the bit."""
+    with monkeypatch.context() as m:
+        m.setattr(WindowState, "newest", _parents_newest)
+        m.setattr(WindowState, "clear_slots", _parents_clear_slots)
+        want = jax.jit(lambda st, d: compact_feature_state(
+            st, d, cfg, demote_slots=demote))(before, jnp.int32(day))
+    got, want = jax.tree.leaves(out), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    return np.asarray(out[1])
+
+
+@pytest.mark.parametrize("case, swept", [
+    pytest.param(_case_nothing_goes, (False, False), id="nothing-dead"),
+    pytest.param(_case_one_table_gives, (True, False),
+                 id="one-table-gives"),
+    pytest.param(_case_dead_over_k, (True, True), id="both-give"),
+    pytest.param(_case_tie_at_the_threshold, (True, False),
+                 id="demote-variant"),
+])
+def test_pass_on_flat_columns_equals_the_parents_formulation(
+        case, swept, monkeypatch):
+    (customers, terminals), demote, knobs, _ = case()
+    if "pack_lanes" in knobs:
+        monkeypatch.setattr(keydir, "PACK_LANES", knobs["pack_lanes"])
+    cfg = _fcfg(customer_capacity=64, terminal_capacity=64,
+                key_mode="exact", keydir_probes=16,
+                cold_highwater=knobs.get("cold_highwater", 0.75))
+    st = jax.tree.map(np.array, _built_state(cfg, customers, terminals))
+    out = jax.jit(lambda s, d: compact_feature_state(
+        s, d, cfg, demote_slots=demote))(st, jnp.int32(NOW))
+    n = _assert_pass_equals_the_parents(st, NOW, out, cfg, demote,
+                                        monkeypatch)
+    assert tuple(n > 0) == swept
+
+
+def test_wide_directory_passes_equal_the_parents_formulation(monkeypatch):
+    """Two key words an entry (``key_bits=64``) through ``engine.run()``:
+    the same pass, the same change."""
+    from test_sharded_exact import _Src
+
+    cfg = Config(
+        features=_fcfg(customer_capacity=512, terminal_capacity=512,
+                       cms_width=1 << 10, key_mode="exact", key_bits=64,
+                       compact_every=2),
+        runtime=RuntimeConfig(batch_buckets=(256,), max_batch_rows=256,
+                              trigger_seconds=0.0, precompile=False))
+    eng = _engine(cfg)
+    assert eng.state.feature_state.customer_dir.wide
+    seen = spy_on_passes(eng)
+    eng.run(_Src(_drifting(10)))
+    assert len(seen) == 5
+    gave = [_assert_pass_equals_the_parents(
+        before, day, out, cfg.features, 0, monkeypatch).sum()
+        for before, day, out in seen]
+    assert min(gave) == 0 < max(gave)  # empty passes and giving ones
+
+
+@pytest.mark.parametrize("cold", [False, True],
+                         ids=["dead-only", "cold-tier-demotes"])
+def test_mesh_passes_equal_the_parents_formulation(cold, tmp_path,
+                                                   monkeypatch):
+    """Four CPU devices: the pass under ``shard_map``, a device's block of
+    every column a quarter of the table — against the parent's
+    formulation run on each device's share of the state."""
+    from real_time_fraud_detection_system_tpu.runtime.sharded_engine import (
+        ShardedScoringEngine,
+    )
+    from test_sharded_exact import _Src, _model
+    from test_sharded_exact import _cfg as mesh_cfg
+
+    n_dev = 4
+    if cold:
+        from test_cold_exact import _churn
+
+        cfg = mesh_cfg(cust_cap=256, term_cap=256, rows=64,
+                       keydir_probes=16, compact_every=1,
+                       cold_store=str(tmp_path / "cold"),
+                       cold_demote_slots=64, cold_highwater=0.5)
+        batches, demote = _churn(7, 8, 64, 1024), 64
+    else:
+        cfg = mesh_cfg(compact_every=3)
+        batches, demote = _drifting(12), 0
+    cfg = dc.replace(cfg, runtime=dc.replace(cfg.runtime,
+                                             precompile=False))
+    eng = ShardedScoringEngine(cfg, "logreg", *_model(), n_devices=n_dev)
+    seen = spy_on_passes(eng)
+    eng.run(_Src(batches))
+    assert len(seen) == (8 if cold else 4)
+    gave = []
+    for before, day, out in seen:
+        for s in range(n_dev):
+            mine = functools.partial(_device_share, n_dev=n_dev, s=s)
+            share = (mine(out[0]), out[1][s]) + tuple(
+                jax.tree.map(lambda x: x[s], pay) for pay in out[2:])
+            gave.append(_assert_pass_equals_the_parents(
+                mine(before), day, share, cfg.features, demote,
+                monkeypatch).sum())
+    assert min(gave) == 0 < max(gave)
 
 
 def test_the_pass_chunks_never_hold_the_whole_input():

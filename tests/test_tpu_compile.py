@@ -661,8 +661,8 @@ def test_sharded_exact_compaction_fits_four_chips(topo, as_on_chip):
     """The mesh's ``("compact",)`` program at the size of the benchmark's
     ``forest-rf100-d8-x4-exact`` (2^24 + 2^25 slots over four chips,
     stacked directories of twice that): per device the one-chip exact
-    cell's pass over the one-chip cell's state, donated, with its padded
-    ``[2^23, 40]`` view as the temporaries — the program PR 32 found the
+    cell's pass over the one-chip cell's state, donated, its table-wide
+    part on the flat columns as on one chip — the program PR 32 found the
     chip's compiler refusing outright in its first form, and which no
     chip had run inside ``shard_map`` before PR 44. (The two step variants
     take ~45 s each to compile here: the verify skill has the recipe.)"""
@@ -695,9 +695,16 @@ def test_sharded_exact_compaction_fits_four_chips(topo, as_on_chip):
     state = 8_409_579_848  # features/online.state_bytes(n_shards=4) / 4
     assert state <= mem.argument_size_in_bytes < state + 1e6
     assert mem.alias_size_in_bytes >= state  # donated, updated in place
-    assert mem.temp_size_in_bytes <= 4_363_527_680  # the one-chip pass's
+    # the one-chip pass's 0.346 GB: no padded view, no column copied
+    assert mem.temp_size_in_bytes < 0.39e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
-    assert "all-to-all" not in compiled.as_text()  # a shard's own business
+    text = compiled.as_text()
+    assert "all-to-all" not in text  # a shard's own business
+    nb = fcfg.n_day_buckets
+    for cap in (fcfg.customer_capacity // n_dev,
+                fcfg.terminal_capacity // n_dev):
+        assert not whole_column_moves(text, {cap * nb})
+        check_swept_inside_one_trip_loop(text, cap * nb)
 
 
 EXACT_ROWS = 65536
@@ -758,11 +765,11 @@ def test_exact_key_programs_fit_the_chip_at_the_benchmarks_size(
     assert mem.argument_size_in_bytes >= state
     assert mem.alias_size_in_bytes >= state  # donated, updated in place
     # the chip has 15.75 GB for a program; the direct step's temporaries
-    # are 1.7 GB, the compaction's one padded [2^23, 40] view 4.4
+    # are 1.7 GB, the compaction's 0.35 (4.37 while it viewed a column
+    # as a padded [2^23, 40]: PERF.md, PR 54)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
     if variant == "compact":
-        # not above the entry-wide pass's (PERF.md, PR 32 and PR 38)
-        assert mem.temp_size_in_bytes <= 4_363_527_680
+        assert mem.temp_size_in_bytes < 0.39e9
 
 
 def indexed_ops(hlo_text):
@@ -788,6 +795,58 @@ def indexed_ops(hlo_text):
     return out
 
 
+def one_trip_sweeps(hlo_text, n):
+    """``[{the computations its body runs}]`` of every ``while`` of a
+    compiled pass that carries the four ``n``-element window columns of
+    a table and can run one trip at most: its condition is ``carry[0] >
+    0`` and its body hands back a constant 0 there
+    (``WindowState.clear_slots``)."""
+    bodies = _computations(hlo_text)
+    out = []
+    for m in re.finditer(
+            r"= \((.*?)\) while\(.*condition=%([\w.\-]+), "
+            r"body=%([\w.\-]+)", hlo_text):
+        carried, cond, body = m.groups()
+        if len(re.findall(rf"\w+\[{n}\]", carried)) != 4:
+            continue
+        test = re.findall(
+            r"ROOT %[\w.\-]+ = pred\[\]\S* compare\(%([\w.\-]+), "
+            r"%([\w.\-]+)\), direction=GT", bodies[cond])
+        if not test:  # the demote payload's loop reads the columns too
+            continue
+        (test,) = test
+        assert re.search(rf"%{re.escape(test[0])} = s32\[\]\S* "
+                         r"get-tuple-element\(.*index=0", bodies[cond])
+        assert re.search(rf"%{re.escape(test[1])} = s32\[\]\S* "
+                         r"constant\(0\)", bodies[cond])
+        (root,) = [ln for ln in bodies[body].splitlines()
+                   if ln.lstrip().startswith("ROOT ")]
+        left = re.search(r" tuple\(%([\w.\-]+)", root).group(1)
+        assert re.search(
+            rf"%{re.escape(left)} = s32\[\]\S* (?:copy\(%[\w.\-]+\)|"
+            r"constant\(0\))", bodies[body]), root[:200]
+        out.append(set(_reached_from(bodies, [body])))
+    return out
+
+
+def column_selects(hlo_text, n):
+    """The names of the computations that hold a ``select`` whose result
+    is a flat ``[n]`` array — a column's new value (``newest``'s masked
+    reads are ``[groups, rows, lanes]`` inside their reduce)."""
+    return {name for name, body in _computations(hlo_text).items()
+            for line in body.splitlines()
+            for m in [_HLO_RESULT.match(line)]
+            if m and m.group(3) == "select" and m.group(2) == str(n)}
+
+
+def check_swept_inside_one_trip_loop(hlo_text, n):
+    """A table's ``n``-element columns are written — one flat select
+    each — only inside the one at-most-one-trip loop that carries them."""
+    (sweep,) = one_trip_sweeps(hlo_text, n)
+    selects = column_selects(hlo_text, n)
+    assert selects and selects <= sweep, selects - sweep
+
+
 @pytest.mark.parametrize("cold", [False, True],
                          ids=["forest-exact", "forest-cold"])
 def test_compaction_is_as_wide_as_what_it_vacates(
@@ -801,7 +860,11 @@ def test_compaction_is_as_wide_as_what_it_vacates(
     other gather and scatter works on a packed chunk of 16,384 lanes, or
     on the demote payload's 131,072. The conditionals yield
     ``[dir_cap]`` vectors alone: no window column and no directory comes
-    out of one, or is copied anywhere in the program."""
+    out of one, or is copied anywhere in the program — nor viewed as
+    ``[cap, 40]``: ``newest`` and the clear work on the flat columns, and
+    the clear's selects stand inside a loop of one trip at most, a table
+    (until PR 54 the padded views were 4.37 GB of temporaries and 74-88
+    ms of every pass, whatever it took)."""
     from real_time_fraud_detection_system_tpu.ops.keydir import pack_lanes
 
     fcfg, compiled = _compiled_exact(compiled_steps, one_chip, "compact",
@@ -822,20 +885,28 @@ def test_compaction_is_as_wide_as_what_it_vacates(
     nb = fcfg.n_day_buckets
     columns = {cap * nb for cap in (fcfg.customer_capacity,
                                     fcfg.terminal_capacity)}
-    # (the dense sweep's padded [cap, 40] view and its broadcast mask
-    # are reshapes, left as they were: ROADMAP A9)
-    assert not [m for m in whole_column_moves(text, columns)
-                if m[0] != "reshape"]
+    # the table-wide part works on the columns as they are stored (PR
+    # 54): no [cap, 40] view of one, no broadcast mask re-laid flat
+    assert not whole_column_moves(text, columns)
+    assert f"[{fcfg.terminal_capacity},{nb}]" not in text
+    assert f"[{fcfg.customer_capacity},{nb}]" not in text
+    # and a table is swept — one select a column — only inside the
+    # at-most-one-trip loop that carries its four columns
+    for n in columns:
+        check_swept_inside_one_trip_loop(text, n)
     copied = [ln.strip()[:120] for ln in text.splitlines() if re.search(
         rf"= \w+\[({dirs[0]}|{dirs[1]})\]\S* copy\(", ln)]
     # the running counts' layout changes apart (s32 [n/128, 128] views),
     # the compiler copies one directory-sized vector a table: the vacated
     # ``slots`` on their way out — never a window column
     assert len(copied) <= 2, copied
-    if cold:
-        # state + payload out; the payload's loop-carried buffers are
-        # what this variant holds beyond the parent's 4.37 GB
-        assert compiled.memory_analysis().temp_size_in_bytes < 5.0e9
+    # 0.346 GB: a table's per-element mask (pred, a quarter of a column)
+    # and vectors a directory entry wide — less than the smaller table's
+    # one column (0.67 GB), so the sweep's carry is updated in place; the
+    # demote variant holds its payload's loop-carried buffers and its
+    # selection's vectors beside (1.382 GB; the parent's 4.30 / 4.80)
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < (1.53e9 if cold else 0.39e9), temps
 
 
 @pytest.mark.parametrize("rows", [256, EXACT_ROWS])
@@ -944,7 +1015,7 @@ def test_wide_key_programs_fit_the_chip_at_the_benchmarks_size(
     assert mem.alias_size_in_bytes >= state
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.7e9
     if variant == "compact":
-        assert mem.temp_size_in_bytes <= 4_363_527_680
+        assert mem.temp_size_in_bytes < 0.39e9  # the 32-bit pass's 0.346
         return
     text = compiled.as_text()
     bodies = _computations(text)
